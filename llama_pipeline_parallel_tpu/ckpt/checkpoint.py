@@ -53,6 +53,13 @@ logger = get_logger(__name__)
 LATEST_TAG = "latest"  # tag-file name, as in the reference (convert2ckpt.py:76)
 _CKPT_RE = re.compile(r"^checkpoint-(\d+)$")
 QUARANTINE_SUFFIX = ".corrupt"
+# Orbax's default lets one OCDBT data file grow to 2 GiB and writes any array
+# up to that size as a single chunk, so a 7B-width checkpoint is a handful of
+# files of hundreds of MB and more — and a host with a per-file size limit
+# refuses them (EFBIG, met on the driver's first chip run: PERF.md PR 21). With
+# this target no chunk exceeds 64 MiB and a data file closes once it reaches
+# it, so every file stays under 128 MiB; restores reshard chunk-wise as before.
+DATA_FILE_TARGET_BYTES = 64 << 20
 
 
 class CheckpointCorruptError(RuntimeError):
@@ -163,7 +170,7 @@ class CheckpointManager:
 
     def __post_init__(self) -> None:
         os.makedirs(self.root, exist_ok=True)
-        self._ckptr = ocp.StandardCheckpointer()
+        self._ckptr = ocp.AsyncCheckpointer(ocp.PyTreeCheckpointHandler())
         self._pending: Any = None  # in-flight async commit thread
         self._pending_error: Any = None  # exception raised on that thread
         self._commit_seq = 0  # collective save counter -> unique barrier keys
@@ -457,7 +464,7 @@ class CheckpointManager:
 
     def _commit(self, path: str, step: int, manifest: StageManifest,
                 cfg: LlamaConfig, **meta_extra) -> None:
-        # StandardCheckpointer writes asynchronously; the tag/meta below must
+        # The checkpointer writes asynchronously; the tag/meta below must
         # only appear once the array data is durably on disk — on EVERY
         # process, not just this one. Barrier first, then let a single
         # process write the completeness marker and tag (concurrent writers
@@ -511,7 +518,11 @@ class CheckpointManager:
 
         def save():
             faults.fire("storage_write", tag=item_path)
-            self._ckptr.save(item_path, tree, force=True)
+            self._ckptr.save(
+                item_path, force=True,
+                args=ocp.args.PyTreeSave(
+                    tree,
+                    ocdbt_target_data_file_size=DATA_FILE_TARGET_BYTES))
 
         retry.retry_call(save, policy=_storage_policy(),
                          describe=f"orbax save {os.path.basename(item_path)}")
@@ -522,7 +533,12 @@ class CheckpointManager:
 
         def restore():
             faults.fire("storage_write", tag=item_path)
-            return self._ckptr.restore(item_path, template)
+            return self._ckptr.restore(
+                item_path,
+                args=ocp.args.PyTreeRestore(
+                    template,
+                    restore_args=ocp.checkpoint_utils.construct_restore_args(
+                        template)))
 
         try:
             return retry.retry_call(

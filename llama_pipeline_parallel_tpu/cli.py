@@ -32,8 +32,10 @@ def main(argv: list[str] | None = None) -> None:
         jax.config.update("jax_platforms", args.platform)
 
     from llama_pipeline_parallel_tpu.train import run_training
+    from llama_pipeline_parallel_tpu.utils import compile_cache
     from llama_pipeline_parallel_tpu.utils.config import load_config
 
+    compile_cache.setup()
     cfg = load_config(args.config, args.overrides)
     summary = run_training(cfg)
     print(f"training done: {summary}")

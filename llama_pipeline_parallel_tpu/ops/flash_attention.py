@@ -39,14 +39,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llama_pipeline_parallel_tpu.ops.pallas_common import (
+    compiler_params,
+    interpret_mode,
+)
+
 NEG_INF = -1e30
-_INTERPRET = None  # overridden in tests; None -> auto (True off-TPU)
-
-
-def _interpret_mode() -> bool:
-    if _INTERPRET is not None:
-        return _INTERPRET
-    return jax.default_backend() != "tpu"
+# (batch, head, outer tile) programs are independent; the innermost axis
+# carries the running statistics / accumulators in scratch.
+_COMPILER_PARAMS = compiler_params("parallel", "parallel", "parallel",
+                                   "arbitrary")
 
 
 def _block_sizes(sq: int, skv: int, block_q: int, block_k: int) -> tuple[int, int]:
@@ -91,7 +93,7 @@ def _seg_tile_mask(s, segq_ref, segk_ref):
     """Mask cross-segment pairs (sequence packing): scores survive only
     where the q and kv positions carry the SAME nonzero segment id."""
     seg_q = segq_ref[0, :, :]                # [bq, 1] int32
-    seg_k = segk_ref[0, :, :][:, 0][None, :]  # [1, bk]
+    seg_k = segk_ref[0, :, :]                # [1, bk] (lane-major, see _fwd)
     ok = (seg_q == seg_k) & (seg_k != 0)
     return jnp.where(ok, s, NEG_INF)
 
@@ -162,7 +164,10 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, q_offset, kv_offset,
     lse [b, h, sq, 1]. `segments_q`/`segments_kv`: [b, s, 1] int32 segment
     ids (0 = pad) for the q rows and kv columns respectively — the SAME
     array for self-attention, DIFFERENT slabs under ring rotation
-    (parallel/ring_attention.py rotates the kv stream with its kv slab)."""
+    (parallel/ring_attention.py rotates the kv stream with its kv slab).
+    The kv stream enters the kernel lane-major ([b, 1, s], transposed here
+    by XLA) so the tile mask broadcasts [bq, 1] against [1, bk] without an
+    in-kernel sublane-to-lane relayout."""
     if (segments_q is None) != (segments_kv is None):
         raise ValueError("segments_q and segments_kv must be given together")
     b, h, sq, hd = q.shape
@@ -187,9 +192,9 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, q_offset, kv_offset,
     if segments_q is not None:
         in_specs += [
             pl.BlockSpec((1, bq, 1), lambda b_, h_, qi, ki: (b_, qi, 0)),
-            pl.BlockSpec((1, bk, 1), lambda b_, h_, qi, ki: (b_, ki, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b_, h_, qi, ki: (b_, 0, ki)),
         ]
-        args += [segments_q, segments_kv]
+        args += [segments_q, segments_kv.transpose(0, 2, 1)]
 
     out, lse = pl.pallas_call(
         kernel,
@@ -208,7 +213,8 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, q_offset, kv_offset,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=_interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret_mode(),
     )(*args)
     return out, lse
 
@@ -336,10 +342,10 @@ def _bwd(q, k_full, v_full, delta, lse, do, *, causal, scale, block_q, block_k,
 
     in_specs = [smem_spec, q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
     args = [offsets, q, k_full, v_full, do, lse, delta]
-    if segments_q is not None:
+    if segments_q is not None:  # kv stream lane-major, as in _fwd
         in_specs += [pl.BlockSpec((1, bq, 1), lambda b_, h_, qi, ki: (b_, qi, 0)),
-                     pl.BlockSpec((1, bk, 1), lambda b_, h_, qi, ki: (b_, ki, 0))]
-        args += [segments_q, segments_kv]
+                     pl.BlockSpec((1, 1, bk), lambda b_, h_, qi, ki: (b_, 0, ki))]
+        args += [segments_q, segments_kv.transpose(0, 2, 1)]
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         grid=(b, h, n_q, n_k),
@@ -347,7 +353,8 @@ def _bwd(q, k_full, v_full, delta, lse, do, *, causal, scale, block_q, block_k,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
-        interpret=_interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret_mode(),
     )(*args)
 
     # dk/dv: kv tiles outer, q tiles inner.
@@ -356,11 +363,9 @@ def _bwd(q, k_full, v_full, delta, lse, do, *, causal, scale, block_q, block_k,
     row_spec_t = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, ki, qi: (b_, h_, qi, 0))
     in_specs_t = [smem_spec, q_spec_t, k_spec_t, k_spec_t, q_spec_t, row_spec_t,
                   row_spec_t]
-    args_t = [offsets, q, k_full, v_full, do, lse, delta]
     if segments_q is not None:
         in_specs_t += [pl.BlockSpec((1, bq, 1), lambda b_, h_, ki, qi: (b_, qi, 0)),
-                       pl.BlockSpec((1, bk, 1), lambda b_, h_, ki, qi: (b_, ki, 0))]
-        args_t += [segments_q, segments_kv]
+                       pl.BlockSpec((1, 1, bk), lambda b_, h_, ki, qi: (b_, 0, ki))]
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         grid=(b, h, n_k, n_q),
@@ -370,8 +375,9 @@ def _bwd(q, k_full, v_full, delta, lse, do, *, causal, scale, block_q, block_k,
                    jax.ShapeDtypeStruct(v_full.shape, v_full.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
-        interpret=_interpret_mode(),
-    )(*args_t)
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret_mode(),
+    )(*args)  # same operands as the dq kernel, transposed grid
     return dq, dk, dv
 
 

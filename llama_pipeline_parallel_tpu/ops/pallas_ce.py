@@ -28,8 +28,7 @@ Parity contract vs `fused_ce_sum_count` (tests/test_pallas_ce.py):
 - dW: pinned tolerance — the kernel folds token blocks sequentially where
   the XLA path does one einsum per chunk over all tokens.
 
-`interpret=` gating follows ops/flash_attention.py: auto (True off-TPU),
-overridable via `_INTERPRET` for tests.
+`interpret=` gating is ops/pallas_common.py's: interpreted off-TPU only.
 """
 
 from __future__ import annotations
@@ -43,16 +42,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from llama_pipeline_parallel_tpu.ops.cross_entropy import IGNORE_INDEX
 from llama_pipeline_parallel_tpu.ops.pallas_common import (
+    compiler_params,
     interpret_mode,
     token_block,
 )
 
-_INTERPRET = None  # overridden in tests; None -> auto (True off-TPU)
-
-
-def _interpret_mode() -> bool:
-    return interpret_mode(_INTERPRET)
-
+# every kernel here: outer grid axis independent, inner axis accumulates
+_COMPILER_PARAMS = compiler_params("parallel", "arbitrary")
 
 def _token_block(n: int, block_tokens: int | None) -> int:
     return token_block(n, block_tokens)
@@ -129,7 +125,8 @@ def _fwd_stats(hN, w, safe_t, num_chunks, block_tokens):
             pltpu.VMEM((bn, 128), jnp.float32),
             pltpu.VMEM((bn, 128), jnp.float32),
         ],
-        interpret=_interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret_mode(),
     )(hN, w, safe_t[:, None])
     return lse[:, 0], tgt[:, 0]
 
@@ -232,7 +229,8 @@ def _backward(h, w, targets, lse, valid, ct_loss, num_chunks, block_tokens):
         out_specs=pl.BlockSpec((bn, d), row),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
-        interpret=_interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret_mode(),
     )(hN, w, safe_t, lse2, svec)
     # dW: vocab tiles outer, token blocks inner (accumulated in VMEM).
     row_t = lambda vi, ni: (ni, 0)
@@ -249,7 +247,8 @@ def _backward(h, w, targets, lse, valid, ct_loss, num_chunks, block_tokens):
         out_specs=pl.BlockSpec((d, bv), lambda vi, ni: (0, vi)),
         out_shape=jax.ShapeDtypeStruct((d, v), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
-        interpret=_interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret_mode(),
     )(hN, w, safe_t, lse2, svec)
     return dh.astype(h.dtype).reshape(h.shape), dw.astype(w.dtype)
 

@@ -1,24 +1,34 @@
-"""Helpers shared by the Pallas kernel modules (docs/KERNELS.md).
-
-Each kernel module keeps its own `_INTERPRET` global (tests override them
-independently, the flash_attention pattern) and delegates the resolution
-here, so the gating rule and the token-block ladder exist once.
+"""Helpers shared by the Pallas kernel modules (docs/KERNELS.md): the
+interpret gating rule and the token-block ladder exist once, here.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
+
+# Mosaic's scoped-VMEM budget for one kernel. Its default (16 MiB on a v5e)
+# is below what the kernels' 1024-wide fp32 score tiles and double-buffered
+# weight blocks need; a v5e core has 128 MiB of VMEM, so half of it.
+VMEM_LIMIT_BYTES = 64 << 20
 
 # preferred token-block heights, largest first (8k-aligned for fp32 tiles);
 # the fallback is the full token count (one block)
 TOKEN_BLOCKS = (256, 128, 64, 32, 16, 8)
 
 
-def interpret_mode(override: bool | None) -> bool:
-    """Kernel interpret gating: an explicit module override wins; None ->
-    auto (interpret everywhere but a real TPU backend)."""
-    if override is not None:
-        return override
+def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    """Mosaic parameters every kernel here passes: which grid axes are
+    independent ("parallel") and which carry an accumulator ("arbitrary"),
+    under the shared scoped-VMEM budget."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def interpret_mode() -> bool:
+    """Kernel interpret gating, decided by the backend alone: Mosaic-compiled
+    on a TPU, interpreted everywhere else (the CPU tests). There is no
+    override — nothing can make a kernel interpret on the chip."""
     return jax.default_backend() != "tpu"
 
 

@@ -45,17 +45,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from llama_pipeline_parallel_tpu.ops.pallas_common import (
+    compiler_params,
     interpret_mode,
     token_block,
 )
 from llama_pipeline_parallel_tpu.ops.rmsnorm import rms_norm
-
-_INTERPRET = None  # overridden in tests; None -> auto (True off-TPU)
-
-
-def _interpret_mode() -> bool:
-    return interpret_mode(_INTERPRET)
-
 
 def _token_block(n: int, block_tokens: int | None) -> int:
     return token_block(n, block_tokens)
@@ -136,7 +130,8 @@ def _fwd(xN, norm_w, wq, wk, wv, cosN, sinN, eps, head_dim, block_tokens):
             jax.ShapeDtypeStruct((n, dkv), xN.dtype),
             jax.ShapeDtypeStruct((n, dkv), xN.dtype),
         ],
-        interpret=_interpret_mode(),
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret_mode(),
     )(xN, norm_w[None, :], wq, wk, wv, cosN, sinN)
 
 
@@ -210,7 +205,8 @@ def _bwd(xN, norm_w, wq, wk, wv, cosN, sinN, dqN, dkN, dvN, eps, head_dim,
         ],
         out_specs=pl.BlockSpec((bn, d), row),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
-        interpret=_interpret_mode(),
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret_mode(),
     )(dqN, dkN, dvN, wq, wk, wv, cosN, sinN)
     dwq, dwk, dwv = pl.pallas_call(
         functools.partial(_dw_kernel, eps=eps, head_dim=head_dim),
@@ -239,7 +235,8 @@ def _bwd(xN, norm_w, wq, wk, wv, cosN, sinN, dqN, dkN, dvN, eps, head_dim,
             pltpu.VMEM((d, dkv_w), jnp.float32),
             pltpu.VMEM((d, dkv_w), jnp.float32),
         ],
-        interpret=_interpret_mode(),
+        compiler_params=compiler_params("arbitrary"),
+        interpret=interpret_mode(),
     )(xN, norm_w[None, :], dqN, dkN, dvN, cosN, sinN)
     # The reference's tp_copy sits between norm and projections: its
     # backward psums the hidden cotangent across tp BEFORE the norm

@@ -25,9 +25,9 @@ kernel), halving H2D bytes vs uploading fp32 and casting on device. Per-phase
 timings are kept in `last_timings`.
 
 The update kernel is C++ (csrc/host_adamw.cpp, OpenMP parallel + SIMD),
-compiled on first use with the system g++ and bound via ctypes — no pybind11
-dependency. A pure-numpy fallback keeps the path alive where no compiler
-exists.
+compiled on first use with the system g++ into `<checkout>/.lpt_native/`
+(keyed by the source's hash) and bound via ctypes — no pybind11 dependency.
+There is no fallback: where the build fails, `optimizer_offload` raises.
 
 This module is the HOST-side tier (python-driven D2H/kernel/H2D around the
 step); its IN-GRAPH sibling is `utils/host_stash.py`, which generalizes the
@@ -43,9 +43,9 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
+import hashlib
 import os
 import subprocess
-import tempfile
 import time
 from typing import Any
 
@@ -57,41 +57,57 @@ from llama_pipeline_parallel_tpu.utils.logging import get_logger
 logger = get_logger(__name__)
 
 # inside the package so installed wheels ship the kernel source too
-_CSRC = os.path.join(os.path.dirname(__file__), os.pardir,
-                     "csrc", "host_adamw.cpp")
+_CSRC = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
+                                     "csrc", "host_adamw.cpp"))
+# the build lands in the checkout (git-ignored), never in a shared /tmp
+_BUILD_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".lpt_native")
 _lib = None
-_lib_failed = False
+
+
+def native_lib_path() -> str:
+    """Where the kernel built from csrc/host_adamw.cpp AS COMMITTED lives:
+    keyed by the source's sha256, so an edited source rebuilds and a binary
+    from another checkout or an older source can never be picked up."""
+    with open(_CSRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"host_adamw-{digest}.so")
 
 
 def _load_native():
-    """Compile (once) and load the native kernel; None if unavailable."""
-    global _lib, _lib_failed
-    if _lib is not None or _lib_failed:
+    """Compile (once per source hash) and load the native kernel. A build
+    failure raises: `optimizer_offload: true` without its kernel is an
+    error, not a reason to step 65B of optimizer state in numpy."""
+    global _lib
+    if _lib is not None:
         return _lib
-    try:
-        cache_dir = os.path.join(tempfile.gettempdir(), "lpt_native")
-        os.makedirs(cache_dir, exist_ok=True)
-        so_path = os.path.join(cache_dir, "host_adamw.so")
-        src = os.path.abspath(_CSRC)
-        if (not os.path.exists(so_path)
-                or os.path.getmtime(so_path) < os.path.getmtime(src)):
-            cmd = ["g++", "-O3", "-march=native", "-fopenmp", "-shared",
-                   "-fPIC", src, "-o", so_path]
-            subprocess.run(cmd, check=True, capture_output=True)
-            logger.info("compiled host AdamW kernel -> %s", so_path)
-        lib = ctypes.CDLL(so_path)
-        lib.adamw_step.argtypes = [ctypes.POINTER(ctypes.c_float)] * 3 + [
-            ctypes.POINTER(ctypes.c_float), ctypes.c_int64,
-            ctypes.c_float, ctypes.c_float, ctypes.c_float, ctypes.c_float,
-            ctypes.c_float, ctypes.c_int64, ctypes.c_float]
-        lib.l2_norm_sq.restype = ctypes.c_double
-        lib.l2_norm_sq.argtypes = [ctypes.POINTER(ctypes.c_float), ctypes.c_int64]
-        lib.f32_to_bf16.argtypes = [ctypes.POINTER(ctypes.c_float),
-                                    ctypes.POINTER(ctypes.c_uint16), ctypes.c_int64]
-        _lib = lib
-    except Exception as e:
-        logger.warning("native host AdamW unavailable (%r); using numpy fallback", e)
-        _lib_failed = True
+    so_path = native_lib_path()
+    if not os.path.exists(so_path):
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        tmp = f"{so_path}.{os.getpid()}.tmp"  # concurrent builders: atomic rename
+        cmd = ["g++", "-O3", "-march=native", "-fopenmp", "-shared", "-fPIC",
+               _CSRC, "-o", tmp]
+        try:
+            subprocess.run(cmd, check=True, capture_output=True, text=True)
+        except (OSError, subprocess.CalledProcessError) as e:
+            raise RuntimeError(
+                f"could not build the host AdamW kernel ({' '.join(cmd)}): "
+                f"{getattr(e, 'stderr', '') or e}") from e
+        os.replace(tmp, so_path)
+        logger.info("compiled host AdamW kernel -> %s", so_path)
+    lib = ctypes.CDLL(so_path)
+    lib.adamw_step.argtypes = [ctypes.POINTER(ctypes.c_float)] * 3 + [
+        ctypes.POINTER(ctypes.c_float), ctypes.c_int64,
+        ctypes.c_float, ctypes.c_float, ctypes.c_float, ctypes.c_float,
+        ctypes.c_float, ctypes.c_int64, ctypes.c_float]
+    lib.adamw_step.restype = None
+    lib.l2_norm_sq.restype = ctypes.c_double
+    lib.l2_norm_sq.argtypes = [ctypes.POINTER(ctypes.c_float), ctypes.c_int64]
+    lib.f32_to_bf16.argtypes = [ctypes.POINTER(ctypes.c_float),
+                                ctypes.POINTER(ctypes.c_uint16), ctypes.c_int64]
+    lib.f32_to_bf16.restype = None
+    _lib = lib
     return _lib
 
 
@@ -99,27 +115,14 @@ def _fptr(a: np.ndarray):
     return a.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
 
 
-def _adamw_numpy(p, m, v, g, lr, b1, b2, eps, wd, step, grad_scale):
-    g = g * grad_scale
-    m *= b1
-    m += (1 - b1) * g
-    v *= b2
-    v += (1 - b2) * g * g
-    mhat = m / (1 - b1 ** step)
-    vhat = v / (1 - b2 ** step)
-    p -= lr * (mhat / (np.sqrt(vhat) + eps) + wd * p)
-
-
 def _cast_bf16(src: np.ndarray, native) -> np.ndarray:
-    """fp32 -> bf16 numpy array (native RNE kernel, ml_dtypes fallback)."""
+    """fp32 -> bf16 numpy array (native round-to-nearest-even kernel)."""
     import ml_dtypes
 
-    if native is not None:
-        out = np.empty(src.shape, np.uint16)
-        native.f32_to_bf16(_fptr(src), out.ctypes.data_as(
-            ctypes.POINTER(ctypes.c_uint16)), src.size)
-        return out.view(ml_dtypes.bfloat16)
-    return src.astype(ml_dtypes.bfloat16)
+    out = np.empty(src.shape, np.uint16)
+    native.f32_to_bf16(_fptr(src), out.ctypes.data_as(
+        ctypes.POINTER(ctypes.c_uint16)), src.size)
+    return out.view(ml_dtypes.bfloat16)
 
 
 def _index_key(index: tuple) -> tuple:
@@ -374,10 +377,7 @@ class HostOffloadAdamW:
                 if not shard.owner:
                     continue
                 gs = gnp[key]
-                if self._native is not None:
-                    norm_sq += self._native.l2_norm_sq(_fptr(gs), gs.size)
-                else:
-                    norm_sq += float((gs.astype(np.float64) ** 2).sum())
+                norm_sq += self._native.l2_norm_sq(_fptr(gs), gs.size)
         if jax.process_count() > 1:
             from jax.experimental import multihost_utils
 
@@ -415,16 +415,11 @@ class HostOffloadAdamW:
 
     def _apply_shard(self, shard: _Shard, g: np.ndarray, lr: float,
                      grad_scale: float) -> None:
-        if self._native is not None:
-            self._native.adamw_step(
-                _fptr(shard.p), _fptr(shard.m), _fptr(shard.v),
-                _fptr(g), shard.p.size,
-                lr, self.cfg.beta1, self.cfg.beta2, self.cfg.eps,
-                self.cfg.weight_decay, self.step_count, grad_scale)
-        else:
-            _adamw_numpy(shard.p, shard.m, shard.v, g, lr,
-                         self.cfg.beta1, self.cfg.beta2, self.cfg.eps,
-                         self.cfg.weight_decay, self.step_count, grad_scale)
+        self._native.adamw_step(
+            _fptr(shard.p), _fptr(shard.m), _fptr(shard.v),
+            _fptr(g), shard.p.size,
+            lr, self.cfg.beta1, self.cfg.beta2, self.cfg.eps,
+            self.cfg.weight_decay, self.step_count, grad_scale)
 
     def update(self, grads_tree: Any) -> None:
         """One clipped AdamW step on every process-local shard."""

@@ -26,7 +26,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from llama_pipeline_parallel_tpu.utils import compat
 from llama_pipeline_parallel_tpu.utils.logging import get_logger
 
 AXIS_PP = "pp"
@@ -157,4 +156,4 @@ def is_first_stage() -> jax.Array:
 
 
 def is_last_stage() -> jax.Array:
-    return stage_index() == compat.axis_size(AXIS_PP) - 1
+    return stage_index() == jax.lax.axis_size(AXIS_PP) - 1

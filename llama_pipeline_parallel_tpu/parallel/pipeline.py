@@ -80,6 +80,7 @@ from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.models.llama.manifest import StageManifest
 from llama_pipeline_parallel_tpu.ops.attention import attention
+from llama_pipeline_parallel_tpu.ops.pallas_common import VMEM_LIMIT_BYTES
 from llama_pipeline_parallel_tpu.ops.rope import rope_cos_sin
 from llama_pipeline_parallel_tpu.parallel.sp import make_sp_attention
 from llama_pipeline_parallel_tpu.parallel.mesh import (
@@ -89,8 +90,7 @@ from llama_pipeline_parallel_tpu.parallel.mesh import (
     AXIS_TP,
 )
 from llama_pipeline_parallel_tpu.parallel import schedule as usched
-from llama_pipeline_parallel_tpu.utils import compat, host_stash
-from llama_pipeline_parallel_tpu.utils.compat import shard_map
+from llama_pipeline_parallel_tpu.utils import host_stash
 
 Params = dict
 Batch = dict
@@ -969,7 +969,7 @@ def _mb_streams(batch: Batch, cfg: LlamaConfig, pcfg: PipelineConfig):
     if bsz % m_total:
         raise ValueError(f"per-dp batch {bsz} not divisible by microbatches {m_total}")
     mb = bsz // m_total
-    sp_size = compat.axis_size(AXIS_SP)
+    sp_size = jax.lax.axis_size(AXIS_SP)
     # seqlen here is the LOCAL slab length; fallback positions must be global
     sp_pos_base = jax.lax.axis_index(AXIS_SP) * seqlen if sp_size > 1 else 0
 
@@ -1036,8 +1036,8 @@ def _pipeline_loss_local(
     num_ticks = n_units + s_total - 1
     hidden_shape = (mb, seqlen, cfg.hidden_size)
     x_init = jnp.zeros(hidden_shape, cfg.dtype)
-    tp_size = compat.axis_size(AXIS_TP)
-    sp_size = compat.axis_size(AXIS_SP)
+    tp_size = jax.lax.axis_size(AXIS_TP)
+    sp_size = jax.lax.axis_size(AXIS_SP)
 
     def mb_loss(h, targets, take):
         """Per-microbatch loss from last-stage hiddens. Checkpointed in the
@@ -1262,9 +1262,9 @@ def _pipeline_units_local(
     stage = jax.lax.axis_index(AXIS_PP)
     is_first = stage == 0
     is_last = stage == s_total - 1
-    tp_size = compat.axis_size(AXIS_TP)
+    tp_size = jax.lax.axis_size(AXIS_TP)
     tp_axis = AXIS_TP if tp_size > 1 else None
-    sp_size = compat.axis_size(AXIS_SP)
+    sp_size = jax.lax.axis_size(AXIS_SP)
 
     mb, seqlen, mb_data = _mb_streams(batch, cfg, pcfg)
 
@@ -1599,7 +1599,10 @@ def _pipeline_units_local(
         callback is scheduled exactly at the boundary (and survives DCE).
         The where-select returns its operand unchanged — timeline ON is
         value-identical to OFF, and OFF compiles no callback at all (the
-        jaxpr pin in tests/test_timeline.py)."""
+        jaxpr pin in tests/test_timeline.py). loss_acc's dead branch is
+        NaN, not zero: at boundary 0 the whole carry is constant zeros,
+        and XLA folds `select(p, 0, 0)` to 0, which orphans the (pure)
+        callback and drops the flush_start mark."""
         if not timeline_marks:
             return carry
         x_recv, dy_recv, xbuf, gacc, loss_acc, act_stats, *wq = carry
@@ -1610,7 +1613,7 @@ def _pipeline_units_local(
         keep = ts < jnp.float32(float("inf"))
         x_recv = jnp.where(keep, x_recv, jnp.zeros_like(x_recv))
         dy_recv = jnp.where(keep, dy_recv, jnp.zeros_like(dy_recv))
-        loss_acc = jnp.where(keep, loss_acc, jnp.zeros_like(loss_acc))
+        loss_acc = jnp.where(keep, loss_acc, jnp.float32(float("nan")))
         return (x_recv, dy_recv, xbuf, gacc, loss_acc, act_stats, *wq)
 
     carry = boundary_mark(0, carry)
@@ -1651,7 +1654,7 @@ def _loss_and_grad_local(params, batch, cfg, pcfg, attn_fn,
     computed up front and the differentiated function stays psum-free.
     """
     labels = batch["labels"]
-    sp_size = compat.axis_size(AXIS_SP)
+    sp_size = jax.lax.axis_size(AXIS_SP)
     # valid-target count of this shard's slab (sp shards see boundary-crossing
     # targets via _sp_shift_labels, so counts add up exactly to the global one)
     local_count = (_sp_shift_labels(labels, sp_size) != llama.IGNORE_INDEX).sum()
@@ -1797,7 +1800,7 @@ def make_pipeline_eval_fn(
 
     def local(params, batch):
         labels = batch["labels"]
-        sp_size = compat.axis_size(AXIS_SP)
+        sp_size = jax.lax.axis_size(AXIS_SP)
         count = jax.lax.psum(
             (_sp_shift_labels(labels, sp_size) != llama.IGNORE_INDEX).sum(),
             (AXIS_DP, AXIS_SP))
@@ -1806,7 +1809,7 @@ def make_pipeline_eval_fn(
         # mean-of-means bias (the defect this module fixes vs the reference)
         return jax.lax.psum(loss_sum, (AXIS_PP, AXIS_DP, AXIS_SP)), count
 
-    return shard_map(local, mesh=mesh, in_specs=(param_specs, b_specs),
+    return jax.shard_map(local, mesh=mesh, in_specs=(param_specs, b_specs),
                      out_specs=(P(), P()), check_vma=False)
 
 
@@ -1882,38 +1885,51 @@ def make_pipeline_loss_and_grad(
             "(shard the head wider instead)")
     if pcfg.kernel_ce and jax.default_backend() == "tpu":
         # The binding VMEM term is the backward dW kernel's fp32
-        # [d, V/loss_chunks] scratch (4 B/elem regardless of the compute
-        # dtype; the fwd/dh kernels' weight blocks are smaller). Refuse at
-        # build time — with the actionable knob — instead of dying deep
-        # inside a Mosaic allocation failure. Interpret mode (every other
-        # backend) has no such limit, which is why this cannot live in
-        # PipelineConfig.__post_init__.
+        # [d, V/loss_chunks] scratch plus its double-buffered output block
+        # of the same shape (4 B/elem regardless of the compute dtype; the
+        # fwd/dh kernels' blocks are smaller) against the kernels' scoped
+        # budget (ops/pallas_common.VMEM_LIMIT_BYTES). Refuse at build time
+        # — with the actionable knob — instead of dying deep inside a
+        # Mosaic allocation failure. Interpret mode (every other backend)
+        # has no such limit, which is why this cannot live in
+        # PipelineConfig.__post_init__. On a v5e, 7B width compiles at
+        # 640-, 256- and 128-wide tiles (PERF.md "Bring-up").
         tile = cfg.hidden_size * (cfg.vocab_size // pcfg.loss_chunks) * 4
-        if tile > 16 * (1 << 20):
+        if 3 * tile > VMEM_LIMIT_BYTES:
             raise ValueError(
                 f"kernels.ce=pallas needs its fp32 [hidden, "
-                f"vocab/loss_chunks] dW scratch to fit VMEM: "
-                f"[{cfg.hidden_size}, "
+                f"vocab/loss_chunks] dW scratch and output blocks to fit "
+                f"VMEM: 3 x [{cfg.hidden_size}, "
                 f"{cfg.vocab_size // pcfg.loss_chunks}] is "
-                f"{tile / (1 << 20):.0f} MiB against ~16 MiB — raise "
+                f"{3 * tile / (1 << 20):.0f} MiB against the "
+                f"{VMEM_LIMIT_BYTES >> 20} MiB scoped budget — raise "
                 f"loss_vocab_chunks (128-wide tiles: "
                 f"loss_vocab_chunks={max(cfg.vocab_size // 128, 1)}) or "
                 f"fall back to kernels.ce=xla (docs/KERNELS.md)")
     if pcfg.kernel_prologue and jax.default_backend() == "tpu":
-        # Same build-time posture for the prologue: its backward holds the
-        # three fp32 [d, width_local] dW scratches (plus the dtype-width
-        # weight blocks) VMEM-resident at once, and the kernel has no
-        # chunking knob — the remedies are tp-sharding the projections or
-        # the XLA path (docs/KERNELS.md "when to prefer the XLA path").
+        # Same build-time posture for the prologue, which has no chunking
+        # knob: every grid step holds the WHOLE wq/wk/wv (forward, dhidden)
+        # or three fp32 [d, width_local] dW scratches plus same-shape
+        # output blocks (dW) in VMEM. On a v5e the kernel compiles and runs
+        # at tp=8 width (3 x [4096, 512]); at full 7B width Mosaic refuses
+        # it, in its own words (PERF.md "Bring-up"). Until it is re-tiled
+        # over the weight columns (ROADMAP A6) the remedies are tp-sharding
+        # the projections or the XLA path.
         widths = (cfg.hidden_size + 2 * cfg.kv_heads * cfg.head_dim) // tp
         scratch = cfg.hidden_size * widths * 4
-        if scratch > 16 * (1 << 20):
+        if 2 * scratch > VMEM_LIMIT_BYTES:
             raise ValueError(
-                f"kernels.prologue=pallas holds ~{scratch / (1 << 20):.0f} "
-                f"MiB of fp32 dW scratch ([{cfg.hidden_size}] rows x "
-                f"{widths} local q+k+v columns) against ~16 MiB VMEM — "
-                f"shard the projections wider (tp) or fall back to "
-                f"kernels.prologue=xla (docs/KERNELS.md)")
+                f"kernels.prologue=pallas keeps whole weights in VMEM: "
+                f"{2 * scratch / (1 << 20):.0f} MiB of fp32 dW scratch + "
+                f"output blocks ([{cfg.hidden_size}] rows x {widths} local "
+                f"q+k+v columns) against the {VMEM_LIMIT_BYTES >> 20} MiB "
+                f"scoped budget. Mosaic on a TPU v5e, forward kernel at "
+                f"[4096] x 3 x [4096]: \"RESOURCE_EXHAUSTED: Ran out of "
+                f"memory in memory space vmem while allocating on stack "
+                f"... Scoped allocation with size 100.25M and limit 64.00M "
+                f"exceeded scoped vmem limit by 36.25M.\" Shard the "
+                f"projections wider (tp) or use kernels.prologue=xla "
+                f"(docs/KERNELS.md)")
     if tp > 1:
         if cfg.kv_heads % tp or cfg.num_attention_heads % tp:
             raise ValueError(
@@ -1938,7 +1954,7 @@ def make_pipeline_loss_and_grad(
             stats_specs.update({"act_absmax_per_chunk": P(AXIS_PP),
                                 "act_rms_per_chunk": P(AXIS_PP)})
         out_specs += (stats_specs,)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_loss_and_grad_local, cfg=cfg, pcfg=pcfg, attn_fn=attn_fn,
                 collect_stats=collect_stats,
                 timeline_marks=timeline_segments),

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.ops import flash_attention as fa
 from llama_pipeline_parallel_tpu.parallel.mesh import AXIS_SP
-from llama_pipeline_parallel_tpu.utils import compat
 
 NEG_INF = fa.NEG_INF
 
@@ -119,7 +118,7 @@ def _slab_bwd(backend, q, k, v, do, lse, delta, *, seg_q=None, seg_kv=None, **kw
 # ---------------------------------------------------------------------------
 
 def _rotate(xs, axis_name):
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     return tuple(jax.lax.ppermute(x, axis_name, perm) for x in xs)
 
@@ -135,7 +134,7 @@ def _ring_fwd_impl(q, k, v, seg, causal, scale, axis_name, backend):
     or None. The kv copy rotates around the ring WITH its k/v slabs so the
     cross-segment test always pairs positions of the slab actually visiting;
     the q copy stays home."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     s_local = q.shape[2]
     # Slab offsets only gate CAUSAL masking (segment masking travels with the
     # seg ids). Skip axis_index entirely when non-causal: the dead equation
@@ -182,7 +181,7 @@ def _ring_vjp_fwd(q, k, v, seg, causal, scale, axis_name, backend):
 
 def _ring_vjp_bwd(causal, scale, axis_name, backend, res, dout):
     q, k, v, seg, out, lse = res
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     s_local = q.shape[2]
     rank = jax.lax.axis_index(axis_name) if causal else 0  # see fwd note
     q_off = rank * s_local

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.ops.attention import attention, repeat_kv
 from llama_pipeline_parallel_tpu.parallel.mesh import AXIS_SP
-from llama_pipeline_parallel_tpu.utils import compat
 
 
 def ulysses_attention(
@@ -51,7 +50,7 @@ def ulysses_attention(
     if q_offset != 0 or kv_offset != 0:
         raise ValueError("ulysses_attention re-shards to full sequence; offsets "
                          "are derived internally")
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     h, h_kv = q.shape[2], k.shape[2]
     if h % n:
         raise ValueError(f"num heads {h} must be divisible by sp={n}")

@@ -26,6 +26,7 @@ from typing import Any, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src import distributed as jax_distributed
 
 from llama_pipeline_parallel_tpu.ckpt.checkpoint import (
     CheckpointCorruptError,
@@ -45,6 +46,7 @@ from llama_pipeline_parallel_tpu.data.loader import (
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.models.llama.manifest import StageManifest
+from llama_pipeline_parallel_tpu.ops import pallas_common
 from llama_pipeline_parallel_tpu.optim import OptimizerConfig, make_optimizer
 from llama_pipeline_parallel_tpu.parallel import pipeline as pl
 from llama_pipeline_parallel_tpu.parallel import train_step as ts
@@ -441,12 +443,13 @@ def _measure_segments(batch: int, seq_len: int) -> jnp.ndarray:
 
 def _measure_attention(model_cfg: LlamaConfig, seq_len: int,
                        micro_batch: int = 1, packed: bool = False) -> Any:
-    """Time exact vs flash (fwd+bwd, jitted, value-fetch barrier) at this
-    run's ACTUAL (microbatch, seq) shape ON THE DEVICE — with segment-id
-    streams when the run packs sequences, since those change the flash
-    kernel's work — and return the faster. `auto` picks by measurement, not
-    by threshold folklore. Cached per shape; any failure falls back to the
-    exact path."""
+    """Time exact vs flash (fwd+bwd, jitted, block_until_ready barrier) at
+    this run's ACTUAL (microbatch, seq) shape ON THE DEVICE — with
+    segment-id streams when the run packs sequences, since those change the
+    flash kernel's work — and return the faster. `auto` picks by
+    measurement, not by threshold folklore. Cached per shape. Only reached
+    on a TPU (select_attention), where a candidate that fails to compile or
+    run is an error, not a vote for the other one."""
     from llama_pipeline_parallel_tpu.ops.attention import attention
     from llama_pipeline_parallel_tpu.ops.flash_attention import flash_attention
 
@@ -456,38 +459,33 @@ def _measure_attention(model_cfg: LlamaConfig, seq_len: int,
         return _AUTO_ATTN_CACHE[key]
 
     def measure_locally():
-        import time
+        rng = np.random.RandomState(0)
+        h, hkv, hd = (model_cfg.num_attention_heads, model_cfg.kv_heads,
+                      model_cfg.head_dim)
+        b = max(int(micro_batch), 1)
+        q = jnp.asarray(rng.randn(b, seq_len, h, hd), jnp.bfloat16)
+        k = jnp.asarray(rng.randn(b, seq_len, hkv, hd), jnp.bfloat16)
+        v = jnp.asarray(rng.randn(b, seq_len, hkv, hd), jnp.bfloat16)
+        mask = _measure_segments(b, seq_len) if packed else None
 
-        try:
-            rng = np.random.RandomState(0)
-            h, hkv, hd = (model_cfg.num_attention_heads, model_cfg.kv_heads,
-                          model_cfg.head_dim)
-            b = max(int(micro_batch), 1)
-            q = jnp.asarray(rng.randn(b, seq_len, h, hd), jnp.bfloat16)
-            k = jnp.asarray(rng.randn(b, seq_len, hkv, hd), jnp.bfloat16)
-            v = jnp.asarray(rng.randn(b, seq_len, hkv, hd), jnp.bfloat16)
-            mask = _measure_segments(b, seq_len) if packed else None
+        def time_one(fn):
+            loss = lambda q, k, v: (fn(q, k, v, mask, causal=True)
+                                    .astype(jnp.float32) ** 2).sum()
+            step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+            jax.block_until_ready(step(q, k, v))  # compile off the clock
+            t0 = time.perf_counter()
+            for _ in range(3):
+                out = step(q, k, v)
+            jax.block_until_ready(out)
+            return (time.perf_counter() - t0) / 3
 
-            def time_one(fn):
-                loss = lambda q, k, v: (fn(q, k, v, mask, causal=True)
-                                        .astype(jnp.float32) ** 2).sum()
-                step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-                float(step(q, k, v)[0])  # compile + barrier (value fetch)
-                t0 = time.perf_counter()
-                for _ in range(3):
-                    float(step(q, k, v)[0])
-                return (time.perf_counter() - t0) / 3
-
-            t_exact, t_flash = time_one(attention), time_one(flash_attention)
-            winner = flash_attention if t_flash < t_exact else attention
-            logger.info("attention=auto @ batch %d seq %d packed=%s: "
-                        "exact %.2fms, flash %.2fms -> %s",
-                        b, seq_len, packed, 1e3 * t_exact, 1e3 * t_flash,
-                        "flash" if winner is flash_attention else "exact")
-            return winner
-        except Exception as e:
-            logger.warning("attention=auto measurement failed (%r); using exact", e)
-            return attention
+        t_exact, t_flash = time_one(attention), time_one(flash_attention)
+        winner = flash_attention if t_flash < t_exact else attention
+        logger.info("attention=auto @ batch %d seq %d packed=%s: "
+                    "exact %.2fms, flash %.2fms -> %s",
+                    b, seq_len, packed, 1e3 * t_exact, 1e3 * t_flash,
+                    "flash" if winner is flash_attention else "exact")
+        return winner
 
     if jax.process_count() > 1:
         # Every process must compile the SAME program: near-equal timings (or
@@ -575,7 +573,6 @@ def select_attention(impl: str, seq_length: int, mesh,
 _STOP_SIGNALS: list[int] = []
 _INSTALLED_SIGNALS: list[int] = []
 _PREVIOUS_HANDLERS: dict = {}
-_NOTIFIER_PROBE_FAILED = False  # warn-once latch, see _cpp_notifier_owns_sigterm
 
 
 def _in_main_thread() -> bool:
@@ -606,27 +603,11 @@ def _cpp_notifier_owns_sigterm() -> bool:
     The notifier is registered with the preemption SYNC MANAGER, not the
     bare distributed client: `jax.distributed.initialize()` skips it when
     `jax_enable_preemption_service=False`, and then Python must keep owning
-    SIGTERM even though a client is active.
-
-    Reads a jax internal and is called from inside signal handlers, so it
-    must never raise: if a JAX upgrade moves the attribute, fall back to
-    False (= Python keeps SIGTERM — the pre-init behavior) and warn once —
-    via os.write, not logging: the logging stack is not async-signal-safe
-    (a signal landing mid-emit would re-enter a buffered writer), the same
-    rule _on_preemption_signal follows."""
-    try:
-        from jax._src import distributed as jax_distributed
-
-        return jax_distributed.global_state.preemption_sync_manager is not None
-    except (ImportError, AttributeError):  # jax internal moved
-        global _NOTIFIER_PROBE_FAILED
-        if not _NOTIFIER_PROBE_FAILED:
-            _NOTIFIER_PROBE_FAILED = True
-            os.write(2, b"WARNING: jax._src.distributed.global_state."
-                        b"preemption_sync_manager not found (jax internals "
-                        b"changed); assuming Python owns SIGTERM - pod "
-                        b"preemption now relies on the Python handlers\n")
-        return False
+    SIGTERM even though a client is active. Reads a jax internal (there is
+    no public accessor in the jax this repo is written for, 0.9 —
+    pyproject.toml pins it); called from inside signal handlers, where an
+    attribute read is safe."""
+    return jax_distributed.global_state.preemption_sync_manager is not None
 
 
 def _install_preemption_handlers() -> None:
@@ -676,22 +657,13 @@ def _release_preemption_handlers() -> None:
     _INSTALLED_SIGNALS.clear()
 
 
-def _reset_compilation_cache() -> None:
-    """Re-initialize jax's persistent compile cache so a mid-process
-    jax_compilation_cache_dir change takes effect. Best-effort: the helper
-    is a jax-internal module, and a miss only costs cache reuse."""
-    try:
-        from jax.experimental.compilation_cache import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception as e:  # jax internals moved — keep training
-        logger.warning("could not reset the XLA compile cache (%r); the "
-                       "compilation_cache_dir change may not apply to this "
-                       "process", e)
-
-
 def run_training(cfg: dict) -> dict:
     """The full training run; returns a summary dict for programmatic callers."""
+    if "compilation_cache_dir" in cfg:
+        raise ValueError(
+            "the compilation_cache_dir config key is gone: set "
+            "JAX_COMPILATION_CACHE_DIR in the environment, or leave it unset "
+            "for <checkout>/.jax_cache (utils/compile_cache.py)")
     _install_preemption_handlers()
     # Fault-tolerance wiring (docs/RESILIENCE.md): the env plan wins over the
     # config node — the supervisor drives chaos runs through LPT_FAULT_PLAN
@@ -701,24 +673,9 @@ def run_training(cfg: dict) -> dict:
     else:
         faults.configure(cfg.get("fault_plan"))
     set_barrier_timeout(cfg.get("barrier_timeout_s"))
-    # jax settings are process-global: save/restore around the run so a later
-    # run_training in the same process doesn't inherit this config's cache
-    prev_cache = jax.config.jax_compilation_cache_dir
-    if cfg.get("compilation_cache_dir"):
-        # Persistent XLA compile cache: a 65B pipeline step costs minutes of
-        # compile per topology; resumes/restarts on the same pod skip it.
-        jax.config.update("jax_compilation_cache_dir",
-                          str(cfg["compilation_cache_dir"]))
-        # the cache object initializes lazily ONCE per process — if an earlier
-        # run in this process already compiled anything, the dir change is
-        # silently ignored until the cache is reset
-        _reset_compilation_cache()
     try:
         return _run_training(cfg)
     finally:
-        if cfg.get("compilation_cache_dir"):
-            jax.config.update("jax_compilation_cache_dir", prev_cache)
-            _reset_compilation_cache()  # later runs must not inherit the dir
         trace.configure(None)  # close this run's spans.jsonl writer
         set_barrier_timeout(None)  # later runs must not inherit the timeout
         faults.configure(None)  # ...or this run's fault plan
@@ -752,18 +709,15 @@ def _run_training(cfg: dict) -> dict:
             "host stash enabled (wgrad=%s activations=%s): %s",
             pcfg.offload_wgrad or pl.wgrad_offloaded_units(pcfg),
             pcfg.offload_activations,
-            "pinned_host memory space — residuals tier to host DRAM"
+            "residuals tier to the pinned_host memory space"
             if host_stash.transfers_enabled() else
-            "transfers gated off (no distinct host memory space on this "
-            "backend, or LPT_HOST_STASH_FORCE=0) — same schedule, stores "
-            "stay device-resident")
+            "CPU backend — same schedule, stores stay in regular memory")
     if pcfg.kernel_ce or pcfg.kernel_prologue:
         logger.info(
             "pallas kernels enabled (ce=%s prologue=%s): %s (docs/KERNELS.md)",
             pcfg.kernel_ce, pcfg.kernel_prologue,
-            "Mosaic-compiled" if jax.default_backend() == "tpu"
-            else "interpret mode — parity semantics, no kernel speedup "
-                 "off-TPU")
+            "interpret mode — parity semantics, no kernel speedup off-TPU"
+            if pallas_common.interpret_mode() else "Mosaic-compiled")
     topology = _topology_meta(mesh, pcfg, manifest)
     # Numerics observatory (docs/OBSERVABILITY.md "Numerics"): per-stage
     # training-dynamics stats computed in-graph, anomaly detection + the

@@ -29,73 +29,45 @@ clipped warmup/drain indices never need the read-modify-write
 (`where(valid, new, old)`) the in-HBM buffers used — an RMW on a
 host-resident slot would bounce the old value H2D just to write it back.
 
-Backend gating: TPU and GPU expose a distinct `pinned_host` memory space
-and take the real tiering; XLA-CPU has ONE flat address space, where this
-jax version's sharded-jit lowering stamps placement custom calls the SPMD
-partitioner then rejects (`Side-effect HLO must have sharding` — the
-default-memory-kind canonicalization skips the sharding attach). So the
-transfers are emitted only when `supports_host_memory()` — elsewhere
-`to_host`/`to_device` are identity and the SAME schedule code runs with
-the stores in regular memory (values identical either way: the transfer
-is a copy, not a cast). `LPT_HOST_STASH_FORCE=1` forces emission (CPU
-parity tests run real round-trips under plain jit, where the annotations
-lower cleanly); `=0` forces it off — the escape hatch if a real-TPU
-compile ever trips the same partitioner check. The trainer logs the
-resolved mode once. The transfers stay structurally async: tests pin that
-the jaxpr's stash traffic is `device_put` data movement only and the
-lowered step contains no host-sync primitive (callback/infeed/outfeed).
+Backend gating — by the backend alone, with no override: on a TPU or GPU
+the transfers are always emitted, so `offload.*` either compiles there or
+the run fails with XLA's own message. They are elided only on the CPU
+backend. XLA-CPU in the jax this repo is written for (0.9) does report a
+`pinned_host` space and lowers the transfers under plain jit (the parity
+tests force them on and run the real round trip), but under the trainer's
+sharded jit its SPMD partitioner still rejects the placement custom calls
+(`RET_CHECK ... Side-effect HLO must have sharding: custom-call
+annotate_device_placement`). So there `to_host`/`to_device` are identity
+and the SAME schedule code runs with the stores in regular memory (values
+identical either way: the transfer is a copy, not a cast); the trainer
+logs the resolved mode once. The transfers stay structurally async: tests
+pin that the jaxpr's stash traffic is `device_put` data movement only and
+the lowered step contains no host-sync primitive
+(callback/infeed/outfeed).
 """
 
 from __future__ import annotations
 
-import functools
-import os
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-try:  # public export pending upstream; the impl class is stable across 0.4.x
-    from jax.sharding import TransferToMemoryKind  # type: ignore
-except ImportError:  # pragma: no cover - exercised on the installed jax
-    from jax._src.sharding_impls import TransferToMemoryKind
-
-HOST = "pinned_host"
-DEVICE = "device"
-
-
-@functools.lru_cache(maxsize=None)
-def supports_host_memory(platform: str | None = None) -> bool:
-    """Whether the default device exposes a distinct `pinned_host` memory
-    space (TPU/GPU). False on XLA-CPU, where the annotations compile to
-    no-ops — the program is identical, the tiering just isn't real. Cached:
-    the answer is a property of the backend, probed once per process."""
-    try:
-        dev = jax.devices(platform)[0] if platform else jax.devices()[0]
-        return HOST in {m.kind for m in dev.addressable_memories()}
-    except Exception:
-        return False
-
 
 def transfers_enabled() -> bool:
-    """Whether to_host/to_device emit real memory-kind transfers (see the
-    module docstring's backend gating). Read at TRACE time, once per
-    compiled program; LPT_HOST_STASH_FORCE=1/0 overrides the capability
-    probe in either direction."""
-    force = os.environ.get("LPT_HOST_STASH_FORCE", "")
-    if force:
-        return force not in ("0", "false", "False")
-    return supports_host_memory()
+    """Whether to_host/to_device emit real memory-space transfers: everywhere
+    but the CPU backend (see the module docstring). Read at TRACE time."""
+    return jax.default_backend() != "cpu"
 
 
 def to_host(tree: Any) -> Any:
     """Move every array leaf to the host memory space (async D2H inside jit;
     XLA emits copy-start/copy-done the scheduler overlaps with compute).
-    Identity where transfers are gated off — same values, device-resident."""
+    Identity on the CPU backend — same values, one memory."""
     if not transfers_enabled():
         return tree
     return jax.tree.map(
-        lambda x: jax.device_put(x, TransferToMemoryKind(HOST)), tree)
+        lambda x: jax.device_put(x, jax.memory.Space.Host), tree)
 
 
 def to_device(tree: Any) -> Any:
@@ -103,7 +75,7 @@ def to_device(tree: Any) -> Any:
     if not transfers_enabled():
         return tree
     return jax.tree.map(
-        lambda x: jax.device_put(x, TransferToMemoryKind(DEVICE)), tree)
+        lambda x: jax.device_put(x, jax.memory.Space.Device), tree)
 
 
 # ---------------------------------------------------------------------------
@@ -144,8 +116,8 @@ def measure_transfer_bandwidth(nbytes: int = 1 << 28, reps: int = 3) -> dict:
     anchor for the preflight memory model's `--host-bw-gibps` feasibility
     assumption (tools/preflight.py) — run it on a live chip (bench.py
     `extra:offload-bw` row) and feed the number back. Uses real transfers
-    with hard sync points, so on CPU it reports memcpy bandwidth (the
-    tiering there is a no-op; the row is only meaningful on TPU/GPU)."""
+    with hard sync points, so on CPU it reports memcpy bandwidth (the row
+    is only meaningful on TPU/GPU)."""
     import time
 
     import numpy as np
@@ -167,5 +139,4 @@ def measure_transfer_bandwidth(nbytes: int = 1 << 28, reps: int = 3) -> dict:
         np.asarray(dev)
     d2h = reps * host_buf.nbytes / (time.perf_counter() - t0) / gib
     return {"h2d_gibps": round(h2d, 2), "d2h_gibps": round(d2h, 2),
-            "probe_mib": round(host_buf.nbytes / (1 << 20), 1),
-            "pinned_host": supports_host_memory()}
+            "probe_mib": round(host_buf.nbytes / (1 << 20), 1)}

@@ -119,7 +119,9 @@ def device_peak_bytes() -> tuple[int | None, str]:
 
 def live_sample() -> dict:
     """One host-side poll of every live source: per-device
-    bytes_in_use / peak / largest alloc (worst device), host RSS.
+    bytes_in_use / peak / largest alloc (worst device, plus the
+    bytes_in_use of EACH local device in `jax.local_devices()` order — the
+    evidence that a sharded state really landed on every chip), host RSS.
     Purely observational — never touches a compiled program."""
     out: dict[str, Any] = {}
     try:
@@ -138,6 +140,7 @@ def live_sample() -> dict:
                 largest.append(int(stats["largest_alloc_size"]))
         if in_use:
             out["device_bytes_in_use"] = max(in_use)
+            out["device_bytes_in_use_each"] = in_use
         if peak:
             out["device_peak_bytes"] = max(peak)
         if largest:

@@ -73,6 +73,23 @@ def detect_chip_peak_flops() -> float | None:
     return None
 
 
+def require_chip_peak_flops() -> float:
+    """`detect_chip_peak_flops` for a measurement path: a device that is not
+    in the table is an error, not a default (the trainer's meter merely
+    omits `mfu` there; a benchmark number against a guessed peak is wrong)."""
+    import jax
+
+    peak = detect_chip_peak_flops()
+    if peak is None:
+        raise RuntimeError(
+            f"unknown device_kind {jax.devices()[0].device_kind!r} (backend "
+            f"{jax.default_backend()!r}): a measurement needs a chip listed "
+            f"in utils/metrics.TPU_PEAK_FLOPS "
+            f"({', '.join(sorted(TPU_PEAK_FLOPS))}) — there is no default "
+            f"peak and no CPU fallback")
+    return peak
+
+
 @dataclasses.dataclass
 class Throughput:
     """Rolling tokens/sec + MFU meter.

@@ -236,7 +236,7 @@ def rows_from_bench_file(path: str, run: str | None = None) -> list[dict]:
 
 def _summary_from_tail(tail: str) -> dict | None:
     """The LAST parseable {"metric": ...} JSON line inside a captured
-    stdout/stderr tail (the watchdog/probe error line included)."""
+    stdout/stderr tail (a failed run's error line included)."""
     found = None
     for line in tail.splitlines():
         line = line.strip()

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llama_pipeline_parallel_tpu.ckpt import checkpoint
 from llama_pipeline_parallel_tpu.ckpt.checkpoint import CheckpointManager, find_resume_checkpoint
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
@@ -59,6 +60,24 @@ def test_full_roundtrip_same_topology(tmp_path, cfg, devices):
     assert step == 2
     tree_equal(params2, state.params)
     tree_equal(opt2, state.opt_state)
+
+
+def test_data_files_stay_under_the_size_cap(tmp_path, cfg, devices, monkeypatch):
+    """No checkpoint file outgrows 2 x DATA_FILE_TARGET_BYTES (a host with a
+    per-file size limit refused Orbax's default, up-to-2-GiB data files with
+    EFBIG), and the chunked arrays restore bit-equal."""
+    target = 16 << 10
+    monkeypatch.setattr(checkpoint, "DATA_FILE_TARGET_BYTES", target)
+    manifest = StageManifest.for_config(cfg, 1)
+    params = pl.stack_stages(llama.init_params(jax.random.PRNGKey(0), cfg), manifest)
+    mgr = CheckpointManager(str(tmp_path))
+    mgr.save(1, params, manifest, cfg)
+
+    sizes = [os.path.getsize(os.path.join(d, name))
+             for d, _, names in os.walk(mgr.step_dir(1)) for name in names]
+    assert sum(sizes) > 8 * target  # the cap had something to split
+    assert max(sizes) < 2 * target
+    tree_equal(mgr.load_params(1, params, manifest), params)
 
 
 @pytest.mark.slow

@@ -9,11 +9,6 @@ from llama_pipeline_parallel_tpu.ops import flash_attention as fa
 from llama_pipeline_parallel_tpu.ops.attention import attention
 
 
-@pytest.fixture(autouse=True)
-def _interpret(monkeypatch):
-    monkeypatch.setattr(fa, "_INTERPRET", True)
-
-
 def rand_qkv(b=2, sq=128, skv=128, h=4, h_kv=None, hd=32, seed=0):
     rng = np.random.RandomState(seed)
     h_kv = h_kv or h

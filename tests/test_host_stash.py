@@ -93,10 +93,10 @@ def test_zb1_wgrad_and_acts_offload_bitexact(cfg, params, devices,
                                              flat_reference, monkeypatch):
     """Both tiers at once under zb1 (the offload conf's combination plus
     the ring): values must round-trip the host untouched — loss AND grads
-    bit-equal to the flat no-offload schedule. FORCEd transfers: on CPU
+    bit-equal to the flat no-offload schedule. Transfers forced on: on CPU
     the gate would otherwise elide them, and this test exists to run the
     real device_put round trip (plain jit lowers it cleanly there)."""
-    monkeypatch.setenv("LPT_HOST_STASH_FORCE", "1")
+    monkeypatch.setattr(host_stash, "transfers_enabled", lambda: True)
     batch, l_ref, g_ref = flat_reference
     l, g = run_schedule(params, batch, cfg, 2, "zb1", v=2,
                         offload_wgrad=True, offload_activations=True)
@@ -111,7 +111,7 @@ def test_1f1b_activation_offload_bitexact(cfg, params, devices,
                                           flat_reference, monkeypatch):
     """The flat schedule's ring buffer tiered to host: same stage inputs
     come back for every backward recompute."""
-    monkeypatch.setenv("LPT_HOST_STASH_FORCE", "1")
+    monkeypatch.setattr(host_stash, "transfers_enabled", lambda: True)
     batch, l_ref, g_ref = flat_reference
     l, g = run_schedule(params, batch, cfg, 2, "1f1b",
                         offload_activations=True)
@@ -122,10 +122,9 @@ def test_1f1b_activation_offload_bitexact(cfg, params, devices,
 @pytest.mark.slow
 def test_offload_parity_gated_off(cfg, params, devices, flat_reference,
                                   monkeypatch):
-    """The gated-off mode (what a backend without pinned_host, or
-    LPT_HOST_STASH_FORCE=0, runs): same schedule restructuring, stores
-    device-resident, still bit-exact."""
-    monkeypatch.setenv("LPT_HOST_STASH_FORCE", "0")
+    """The gated-off mode (what the CPU backend runs by default): same
+    schedule restructuring, stores device-resident, still bit-exact."""
+    assert not host_stash.transfers_enabled()
     batch, l_ref, g_ref = flat_reference
     l, g = run_schedule(params, batch, cfg, 2, "zb1", v=2,
                         offload_wgrad=True, offload_activations=True)
@@ -145,7 +144,7 @@ def test_offload_parity_grid(cfg, params, devices, flat_reference, pp,
     """The rest of the pp x schedule x v grid (round gate) — each still
     pinned against the ONE flat reference (these shapes are all bit-equal
     to it, per test_zero_bubble/test_interleaved)."""
-    monkeypatch.setenv("LPT_HOST_STASH_FORCE", "1")
+    monkeypatch.setattr(host_stash, "transfers_enabled", lambda: True)
     batch, l_ref, g_ref = flat_reference
     l, g = run_schedule(params, batch, cfg, pp, schedule, v=v, **kw)
     assert l == l_ref
@@ -162,7 +161,7 @@ def test_offload_parity_hybrids_on_vs_off(cfg, params, devices, tp, chunks,
     knob's actual contract. The tp leg drives the split head's
     vocab-parallel grads through a host-tiered W queue, the hybrid most
     likely to break independently."""
-    monkeypatch.setenv("LPT_HOST_STASH_FORCE", "1")
+    monkeypatch.setattr(host_stash, "transfers_enabled", lambda: True)
     batch = make_batch(cfg)
     l_off, g_off = run_schedule(params, batch, cfg, 2, "zb1", v=2, tp=tp,
                                 chunks=chunks)
@@ -185,8 +184,8 @@ def test_stash_transfers_async_no_host_sync(cfg, params, devices,
     these to async copy-start/copy-done pairs), and the lowered program
     contains NO host-synchronizing primitive — no callback, no
     infeed/outfeed — anywhere a blocking sync could hide. Off, the jaxpr
-    carries no memory-kind traffic at all (the knob adds nothing); gated
-    off (a no-pinned_host backend), likewise."""
+    carries no memory-space traffic at all (the knob adds nothing); gated
+    off (the CPU backend's default), likewise."""
     batch = make_batch(cfg)
     mesh = make_mesh(MeshConfig(pp=2))
     manifest = StageManifest.for_config(cfg, 2, virtual_stages=2)
@@ -197,19 +196,19 @@ def test_stash_transfers_async_no_host_sync(cfg, params, devices,
                                  schedule="zb1", virtual_stages=2, **offload)
         return pl.make_pipeline_loss_and_grad(mesh, cfg, pcfg, stacked)
 
-    monkeypatch.setenv("LPT_HOST_STASH_FORCE", "1")
+    monkeypatch.setattr(host_stash, "transfers_enabled", lambda: True)
     on = build(offload_wgrad=True, offload_activations=True)
     jaxpr_on = str(jax.make_jaxpr(on)(stacked, batch))
     # pushes D2H: ring (warmup+steady) + W-queue pair (steady+drain);
     # pops H2D: ring read, W-drain prefetch pair + its initial fetch
-    assert jaxpr_on.count("pinned_host") >= 6, \
-        jaxpr_on.count("pinned_host")
-    assert jaxpr_on.count("memory_kind='device'") >= 4
+    assert jaxpr_on.count("MemorySpace.Host") >= 6, \
+        jaxpr_on.count("MemorySpace.Host")
+    assert jaxpr_on.count("MemorySpace.Device") >= 4
     assert "device_put" in jaxpr_on
 
     off = build()
     jaxpr_off = str(jax.make_jaxpr(off)(stacked, batch))
-    assert "pinned_host" not in jaxpr_off
+    assert "MemorySpace" not in jaxpr_off
 
     # the lowered step: transfers must not smuggle in a host round-trip
     text = jax.jit(on).lower(stacked, batch).as_text()
@@ -217,14 +216,13 @@ def test_stash_transfers_async_no_host_sync(cfg, params, devices,
                    "RecvFromHost"):
         assert marker not in text, f"host-sync marker {marker!r} in HLO"
 
-    # the capability gate: on a backend with no distinct host memory space
-    # (CPU) the default mode emits no transfer at all — the program the
-    # sharded-jit partitioner sees is annotation-free
-    monkeypatch.delenv("LPT_HOST_STASH_FORCE")
+    # the backend gate: on CPU the default mode emits no transfer at all —
+    # the program the sharded-jit partitioner sees is annotation-free
+    monkeypatch.undo()
     gated = str(jax.make_jaxpr(build(offload_wgrad=True,
                                      offload_activations=True))(
                                          stacked, batch))
-    assert "pinned_host" not in gated
+    assert "MemorySpace" not in gated
 
 
 def test_wdrain_prefetches_one_unit_ahead(cfg, params, devices):
@@ -278,7 +276,7 @@ def test_wdrain_prefetches_one_unit_ahead(cfg, params, devices):
 # ---------------------------------------------------------------------------
 
 def test_stash_push_pop_roundtrip_and_garbage_slot(monkeypatch):
-    monkeypatch.setenv("LPT_HOST_STASH_FORCE", "1")  # real transfers on CPU
+    monkeypatch.setattr(host_stash, "transfers_enabled", lambda: True)  # real transfers on CPU
     v = jnp.arange(4.0)
 
     @jax.jit
@@ -299,16 +297,16 @@ def test_stash_push_pop_roundtrip_and_garbage_slot(monkeypatch):
     np.testing.assert_array_equal(np.asarray(buf)[3], 9 * np.asarray(v))
 
 
-def test_supports_host_memory_reports_backend():
-    # CPU exposes no distinct pinned_host space; the call must not raise
-    # and the staging layer must still run (every parity test above)
-    assert host_stash.supports_host_memory() is False
+def test_transfer_gate_is_the_backend_alone():
+    # elided on the CPU backend (XLA-CPU's SPMD partitioner rejects the
+    # placement annotations under sharded jit), emitted everywhere else;
+    # no environment switch can turn them off on a chip
+    assert host_stash.transfers_enabled() is False
 
 
 def test_measure_transfer_bandwidth_smoke():
     bw = host_stash.measure_transfer_bandwidth(nbytes=1 << 16, reps=1)
     assert bw["h2d_gibps"] > 0 and bw["d2h_gibps"] > 0
-    assert bw["pinned_host"] is False  # CPU
 
 
 def _pcfg(schedule, s, m, c=1, v=1, **kw):

@@ -568,7 +568,12 @@ def _super_ledger(out):
 def test_supervisor_labels_oom_outcome(tmp_path):
     """A crash whose OOM snapshot postdates the incarnation start is an
     `oom` outcome; a plain crash, or one with only a STALE snapshot from
-    a previous life, stays `crash` (capacity problem vs transient)."""
+    a previous life, stays `crash` (capacity problem vs transient).
+
+    hang_timeout_s is far above the default 5 s here: the oom child imports
+    the package (jax and all, 2-4.5 s on a loaded box), and a 5 s watchdog
+    racing that import labelled the incarnation `hang` before the snapshot
+    existed — a load-dependent flake, not a jax 0.9 behaviour change."""
     import sys
 
     import supervisor
@@ -584,13 +589,15 @@ def test_supervisor_labels_oom_outcome(tmp_path):
     out = tmp_path / "oomed"
     cmd = [sys.executable, "-c",
            oom_child.format(root=root, out=str(out))]
-    rc = supervisor.Supervisor(cmd, _super_cfg(out)).run()
+    patient = dict(hang_timeout_s=60.0)
+    rc = supervisor.Supervisor(cmd, _super_cfg(out, **patient)).run()
     assert rc == 2
     assert [r["outcome"] for r in _super_ledger(out)] == ["oom"]
 
     plain = tmp_path / "plain"
     rc = supervisor.Supervisor([sys.executable, "-c", "import sys; "
-                                "sys.exit(9)"], _super_cfg(plain)).run()
+                                "sys.exit(9)"],
+                               _super_cfg(plain, **patient)).run()
     assert rc == 2
     assert [r["outcome"] for r in _super_ledger(plain)] == ["crash"]
 
@@ -601,7 +608,8 @@ def test_supervisor_labels_oom_outcome(tmp_path):
     for name in os.listdir(d):
         os.utime(os.path.join(d, name), (old, old))
     rc = supervisor.Supervisor([sys.executable, "-c", "import sys; "
-                                "sys.exit(9)"], _super_cfg(stale)).run()
+                                "sys.exit(9)"],
+                               _super_cfg(stale, **patient)).run()
     assert rc == 2
     assert [r["outcome"] for r in _super_ledger(stale)] == ["crash"]
 

@@ -2,8 +2,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from llama_pipeline_parallel_tpu.utils.compat import shard_map
 
 from llama_pipeline_parallel_tpu.parallel import mesh as mesh_lib
 from llama_pipeline_parallel_tpu.parallel.mesh import MeshConfig, make_mesh

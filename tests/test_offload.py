@@ -1,5 +1,7 @@
 """Host-offloaded AdamW (C++ kernel, shard-aware) vs optax numerics."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,15 +37,34 @@ def optax_reference(tree, cfg, n_steps):
     return params
 
 
-def test_native_kernel_compiles():
-    assert off._load_native() is not None, "g++ compile of csrc/host_adamw.cpp failed"
+def test_native_kernel_builds_into_checkout_keyed_by_source_hash():
+    """The build rule: the .so lands in the git-ignored <checkout>/.lpt_native
+    under the sha256 of csrc/host_adamw.cpp as committed — never a shared
+    /tmp, never reused by mtime."""
+    import hashlib
+
+    assert off._load_native() is not None
+    path = off.native_lib_path()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert os.path.dirname(path) == os.path.join(repo, ".lpt_native")
+    with open(off._CSRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert os.path.basename(path) == f"host_adamw-{digest}.so"
+    assert os.path.exists(path)
 
 
-@pytest.mark.parametrize("force_numpy", [False, True])
-def test_matches_optax(tree, force_numpy, monkeypatch):
-    if force_numpy:
-        monkeypatch.setattr(off, "_lib", None)
-        monkeypatch.setattr(off, "_lib_failed", True)
+def test_native_build_failure_raises(tmp_path, monkeypatch):
+    """No numpy fallback: a source that does not compile is an error."""
+    bad = tmp_path / "host_adamw.cpp"
+    bad.write_text("this is not C++")
+    monkeypatch.setattr(off, "_CSRC", str(bad))
+    monkeypatch.setattr(off, "_BUILD_DIR", str(tmp_path / "build"))
+    monkeypatch.setattr(off, "_lib", None)
+    with pytest.raises(RuntimeError, match="could not build the host AdamW"):
+        off._load_native()
+
+
+def test_matches_optax(tree):
     cfg = OptimizerConfig(learning_rate=1e-2, weight_decay=0.1, beta1=0.9,
                           beta2=0.95, max_grad_norm=1.0, total_steps=100,
                           warmup_steps=10)

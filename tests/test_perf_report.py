@@ -77,15 +77,6 @@ def test_error_round_becomes_failure_row():
     assert len(rows) == 1 and rows[0]["reason"].startswith("no usable")
 
 
-def test_repo_bench_history_summarizes_unreachable(capsys):
-    """The five archived rounds (BENCH_r01-r05) are all TPU-unreachable;
-    the report must say so instead of printing an empty table."""
-    perf_report.main(["--bench-glob", "BENCH_r0*.json"])
-    out = capsys.readouterr().out
-    assert "round(s) produced no live number" in out
-    assert "BENCH_r0" in out
-
-
 def test_read_ledger_degrades(tmp_path):
     assert perf.read_ledger(str(tmp_path / "absent.jsonl")) == []
     p = tmp_path / "perf.jsonl"
@@ -171,9 +162,8 @@ def test_calibration_rerank_pinned(tmp_path):
 
 
 def test_bench_ledger_writer(tmp_path, monkeypatch):
-    """bench.py's _write_ledger: healthy summary -> rows; probe failure ->
-    one reason-tagged row; budget skips -> reason rows. (The full
-    --full-trajectory run is the slow-marked e2e.)"""
+    """bench.py's _write_ledger: summary -> rows; budget skips -> reason
+    rows."""
     import importlib.util
     import os
 
@@ -203,45 +193,7 @@ def test_bench_ledger_writer(tmp_path, monkeypatch):
     assert "mfu" not in perf.derive_calibration(
         [perf.make_row("mfu", measured=1e-4)])
 
-    path2 = tmp_path / "fail.jsonl"
-    bench._write_ledger(str(path2), None, [], error="no usable accelerator")
-    rows2 = perf.read_ledger(str(path2))
-    assert len(rows2) == 1 and rows2[0]["reason"] == "no usable accelerator"
     # a None path is a no-op, never an error
     bench._write_ledger(None, BENCH_SUMMARY, [])
 
 
-@pytest.mark.slow
-def test_bench_full_trajectory_cpu_runbook(tmp_path):
-    """The one-shot runbook end-to-end on CPU (several minutes — round
-    gate): `bench.py --full-trajectory` runs every extra:* row family in
-    one invocation under a per-row budget and writes the ledger; the
-    report then renders model-vs-measured pairs from it."""
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(
-        preflight.__file__)))
-    ledger = tmp_path / "perf.jsonl"
-    env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           "BENCH_MODEL": "tiny", "BENCH_BATCH": "2", "BENCH_STEPS": "1",
-           "BENCH_SEQ": "64", "BENCH_TIMEOUT_S": "1500",
-           "BENCH_RUN_LABEL": "runbook-smoke"}
-    out = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"),
-         "--full-trajectory", "--perf-ledger", str(ledger),
-         "--row-budget-s", "240"],
-        cwd=repo, env=env, capture_output=True, text=True, timeout=1500)
-    assert out.returncode == 0, out.stderr[-2000:]
-    summary = json.loads(out.stdout.strip().splitlines()[-1])
-    extras = [k for k in summary["all_configs"] if k.startswith("extra:")]
-    # one pass covers every family (offload/sched/layout/kernel/serve)
-    for fam in ("offload", "sched-", "layout-", "kernel-", "serve-"):
-        assert any(fam in k for k in extras), (fam, extras)
-    rows = perf.read_ledger(str(ledger))
-    assert any(r["metric"] == "host_bw_gibps" and r["measured"]
-               for r in rows)
-    assert any(r["metric"].startswith("transfer_ms") and r["model"]
-               for r in rows)

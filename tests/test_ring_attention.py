@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from llama_pipeline_parallel_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from llama_pipeline_parallel_tpu.ops.attention import attention
@@ -69,11 +69,8 @@ def test_ring_gradients_match_full(devices, sp):
                                    rtol=2e-3, atol=2e-3, err_msg=f"d{name}")
 
 
-def test_ring_flash_backend_matches(devices, monkeypatch):
+def test_ring_flash_backend_matches(devices):
     """The flash (Pallas) backend inside the ring — interpret mode on CPU."""
-    from llama_pipeline_parallel_tpu.ops import flash_attention as fa
-
-    monkeypatch.setattr(fa, "_INTERPRET", True)
     q, k, v = rand_qkv(b=1, s=64, h=2, hd=16)
     full = attention(q, k, v, None, causal=True)
     mesh = make_mesh(MeshConfig(sp=4))
@@ -126,14 +123,10 @@ def seg_loss(out, seg):
 
 @pytest.mark.parametrize("sp", [2, 4])
 @pytest.mark.parametrize("backend", ["exact", "flash"])
-def test_ring_segments_match_full(devices, monkeypatch, sp, backend):
+def test_ring_segments_match_full(devices, sp, backend):
     """Packed segment ids through the ring (the rotating seg slab) agree
     with full-sequence exact attention's pairwise segment mask — forward and
     input gradients, both slab backends."""
-    if backend == "flash":
-        from llama_pipeline_parallel_tpu.ops import flash_attention as fa
-
-        monkeypatch.setattr(fa, "_INTERPRET", True)
     q, k, v = rand_qkv(b=2, s=32, h=2, hd=8, seed=11)
     seg = make_packed_segments(b=2, s=32)
     mesh = make_mesh(MeshConfig(sp=sp))
@@ -183,15 +176,12 @@ def test_ring_segment_isolation(devices):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_ring_flash_adaptive_slab_blocks(devices, monkeypatch):
+def test_ring_flash_adaptive_slab_blocks(devices):
     """A 6144-seq sp=4 run hands the flash backend 1536-long slabs — not a
     1024 multiple. The adaptive block selection (fa._auto_block -> 768)
     keeps the flash path instead of erroring (round-3 verdict #5); forward
     parity vs full exact attention (interpret mode, minimal heads to bound
     CPU cost)."""
-    from llama_pipeline_parallel_tpu.ops import flash_attention as fa
-
-    monkeypatch.setattr(fa, "_INTERPRET", True)
     q, k, v = rand_qkv(b=1, s=6144, h=1, hd=8, seed=9)
     full = attention(q, k, v, None, causal=True)
     mesh = make_mesh(MeshConfig(sp=4))

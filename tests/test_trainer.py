@@ -42,27 +42,12 @@ def test_smoke_run_writes_metrics_and_ckpt(tmp_path, devices):
     assert os.path.exists(os.path.join(out, "training_config.json"))
 
 
-def test_compilation_cache_dir_knob(tmp_path, devices):
-    """`compilation_cache_dir` populates a persistent XLA compile cache —
-    restarts of a big run skip the minutes-long compiles."""
-    cache = tmp_path / "xla_cache"
-    prev = jax.config.jax_compilation_cache_dir
-    # the tiny program compiles in well under the default 1s persistence
-    # threshold — drop it so the toy run actually writes entries
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        # unique seq length: an identical program compiled by an earlier test
-        # would hit XLA's in-memory cache and never write the persistent one
-        run_training(base_cfg(
-            tmp_path, compilation_cache_dir=str(cache),
-            dataset={"synthetic": True, "seq_length": 24,
-                     "pseudo_dataset_len": 128}))
-    finally:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", prev_min)
-    assert cache.is_dir() and any(cache.iterdir())
-    # run_training save/restores the process-global jax setting itself
-    assert jax.config.jax_compilation_cache_dir == prev
+def test_compilation_cache_dir_key_is_rejected(tmp_path, devices):
+    """The old config key must not be silently ignored: the cache is placed
+    by JAX_COMPILATION_CACHE_DIR or the fixed in-checkout default
+    (utils/compile_cache.py; tests/test_bring_up.py)."""
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        run_training(base_cfg(tmp_path, compilation_cache_dir=str(tmp_path)))
 
 
 @pytest.mark.slow

@@ -587,6 +587,9 @@ def main(argv: list[str] | None = None) -> int:
     from llama_pipeline_parallel_tpu.ckpt.checkpoint import (
         load_module_checkpoint,
     )
+    from llama_pipeline_parallel_tpu.utils import compile_cache
+
+    compile_cache.setup()
     from llama_pipeline_parallel_tpu.serve import ServeConfig, ServeEngine
 
     params, cfg, _, step = load_module_checkpoint(args.checkpoint_dir,
