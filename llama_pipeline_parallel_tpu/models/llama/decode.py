@@ -40,6 +40,7 @@ from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.ops.attention import attention
 from llama_pipeline_parallel_tpu.ops.rmsnorm import rms_norm
 from llama_pipeline_parallel_tpu.ops.rope import apply_rope, rope_cos_sin
+from llama_pipeline_parallel_tpu.utils import trace
 
 Params = dict
 
@@ -67,6 +68,35 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
+def _project_qkv(layer: Params, x: jnp.ndarray, cos: jnp.ndarray,
+                 sin: jnp.ndarray, cfg: LlamaConfig):
+    """Input norm, q/k/v projections and rope of one cached layer, as every
+    decode and prefill program runs them: x [b, s, d] -> q [b, s, h, hd],
+    k/v [b, s, kv_h, hd]."""
+    b, s, _ = x.shape
+    hd, dt = cfg.head_dim, cfg.dtype
+    with jax.named_scope(trace.SCOPE_ATTN_QKV):
+        hidden = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+        q = (hidden @ llama.cast_weight(layer["attn"]["wq"], dt)
+             ).reshape(b, s, -1, hd)
+        k = (hidden @ llama.cast_weight(layer["attn"]["wk"], dt)
+             ).reshape(b, s, -1, hd)
+        v = (hidden @ llama.cast_weight(layer["attn"]["wv"], dt)
+             ).reshape(b, s, -1, hd)
+        return (*apply_rope(q, k, cos, sin), v)
+
+
+def _attn_out_and_mlp(layer: Params, x: jnp.ndarray, attn_out: jnp.ndarray,
+                      cfg: LlamaConfig) -> jnp.ndarray:
+    """Output projection, residual and the SwiGLU half that follow the
+    attention of one cached layer."""
+    b, s, _ = x.shape
+    with jax.named_scope(trace.SCOPE_ATTN_OUT):
+        x = x + attn_out.reshape(b, s, -1) @ llama.cast_weight(
+            layer["attn"]["wo"], cfg.dtype)
+    return llama.mlp_block(layer, x, cfg, scope=trace.SCOPE_DECODE_MLP)
+
+
 def _layer_forward_cached(layer: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
                           cache_v: jnp.ndarray, write_pos, kv_mask: jnp.ndarray,
                           cos: jnp.ndarray, sin: jnp.ndarray, cfg: LlamaConfig,
@@ -86,26 +116,19 @@ def _layer_forward_cached(layer: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
     x is one token attending over the whole cache, visibility is purely
     kv_mask.
     """
-    b, s, d = x.shape
-    hd = cfg.head_dim
-    dt = cfg.dtype
+    s = x.shape[1]
+    q, k, v = _project_qkv(layer, x, cos, sin, cfg)
 
-    hidden = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-    q = (hidden @ layer["attn"]["wq"].astype(dt)).reshape(b, s, -1, hd)
-    k = (hidden @ layer["attn"]["wk"].astype(dt)).reshape(b, s, -1, hd)
-    v = (hidden @ layer["attn"]["wv"].astype(dt)).reshape(b, s, -1, hd)
-    q, k = apply_rope(q, k, cos, sin)
+    with jax.named_scope(trace.SCOPE_KV_WRITE):
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, write_pos, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, write_pos, 0, 0))
 
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, write_pos, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, write_pos, 0, 0))
-
-    if causal:  # prefill: nothing precedes the block; attend within it
-        attn_out = attention(q, k, v, kv_mask[:, :s], causal=True)
-    else:       # decode: one token over the full cache, mask-gated
-        attn_out = attention(q, cache_k, cache_v, kv_mask, causal=False)
-    attn_out = attn_out.reshape(b, s, -1) @ layer["attn"]["wo"].astype(dt)
-    x = llama.mlp_block(layer, x + attn_out, cfg)
-    return x, cache_k, cache_v
+    with jax.named_scope(trace.SCOPE_DECODE_ATTN):
+        if causal:  # prefill: nothing precedes the block; attend within it
+            attn_out = attention(q, k, v, kv_mask[:, :s], causal=True)
+        else:       # decode: one token over the full cache, mask-gated
+            attn_out = attention(q, cache_k, cache_v, kv_mask, causal=False)
+    return _attn_out_and_mlp(layer, x, attn_out, cfg), cache_k, cache_v
 
 
 def forward_with_cache(params: Params, input_ids: jnp.ndarray, cache: dict,
@@ -308,24 +331,16 @@ def _layer_decode_rowwise(layer: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
     """`_layer_forward_cached`'s decode branch with write_pos: [b] — each
     slot writes its own cache position (requests at different depths share
     one decode tick), via a vmapped per-row dynamic_update_slice."""
-    b, s, d = x.shape
-    hd = cfg.head_dim
-    dt = cfg.dtype
+    q, k, v = _project_qkv(layer, x, cos, sin, cfg)
 
-    hidden = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-    q = (hidden @ layer["attn"]["wq"].astype(dt)).reshape(b, s, -1, hd)
-    k = (hidden @ layer["attn"]["wk"].astype(dt)).reshape(b, s, -1, hd)
-    v = (hidden @ layer["attn"]["wv"].astype(dt)).reshape(b, s, -1, hd)
-    q, k = apply_rope(q, k, cos, sin)
+    with jax.named_scope(trace.SCOPE_KV_WRITE):
+        row_update = lambda c, n, w: jax.lax.dynamic_update_slice(c, n, (w, 0, 0))
+        cache_k = jax.vmap(row_update)(cache_k, k, write_pos)
+        cache_v = jax.vmap(row_update)(cache_v, v, write_pos)
 
-    row_update = lambda c, n, w: jax.lax.dynamic_update_slice(c, n, (w, 0, 0))
-    cache_k = jax.vmap(row_update)(cache_k, k, write_pos)
-    cache_v = jax.vmap(row_update)(cache_v, v, write_pos)
-
-    attn_out = attention(q, cache_k, cache_v, kv_mask, causal=False)
-    attn_out = attn_out.reshape(b, s, -1) @ layer["attn"]["wo"].astype(dt)
-    x = llama.mlp_block(layer, x + attn_out, cfg)
-    return x, cache_k, cache_v
+    with jax.named_scope(trace.SCOPE_DECODE_ATTN):
+        attn_out = attention(q, cache_k, cache_v, kv_mask, causal=False)
+    return _attn_out_and_mlp(layer, x, attn_out, cfg), cache_k, cache_v
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -369,8 +384,9 @@ def decode_step(params: Params, token: jnp.ndarray, cache: dict,
     x = llama.final_norm(params, x, cfg)
     logits = llama.lm_head(params, x, cfg)[:, -1, :]
 
-    split = jax.vmap(jax.random.split)(keys)        # [b, 2, 2]
-    nxt = sample_rowwise(logits, temperature, top_k, top_p, split[:, 1])
+    with jax.named_scope(trace.SCOPE_SAMPLE):
+        split = jax.vmap(jax.random.split)(keys)        # [b, 2, 2]
+        nxt = sample_rowwise(logits, temperature, top_k, top_p, split[:, 1])
     return {"token": nxt, "cache": {"k": new_k, "v": new_v},
             "kv_mask": kv_mask, "keys": split[:, 0]}
 
@@ -460,14 +476,17 @@ def _gather_pages(pool_k, pool_v, sc_k, sc_v, page_table: jnp.ndarray,
                   dtype):
     """Reconstitute logical kv rows from the pool: [*, Pmax] page indices ->
     [*, Pmax * page_size, kv_h, hd] in the compute dtype."""
-    gk = pool_k[page_table]
-    gv = pool_v[page_table]
-    if sc_k is not None:
-        gk = dequant_page_block(gk, sc_k[page_table][..., None, :, None], dtype)
-        gv = dequant_page_block(gv, sc_v[page_table][..., None, :, None], dtype)
-    *lead, pmax, page, kvh, hd = gk.shape
-    return (gk.reshape(*lead, pmax * page, kvh, hd),
-            gv.reshape(*lead, pmax * page, kvh, hd))
+    with jax.named_scope(trace.SCOPE_KV_GATHER):
+        gk = pool_k[page_table]
+        gv = pool_v[page_table]
+        if sc_k is not None:
+            gk = dequant_page_block(gk, sc_k[page_table][..., None, :, None],
+                                    dtype)
+            gv = dequant_page_block(gv, sc_v[page_table][..., None, :, None],
+                                    dtype)
+        *lead, pmax, page, kvh, hd = gk.shape
+        return (gk.reshape(*lead, pmax * page, kvh, hd),
+                gv.reshape(*lead, pmax * page, kvh, hd))
 
 
 @partial(jax.jit, donate_argnames=("pool", "kv_mask"))
@@ -564,24 +583,17 @@ def _layer_decode_paged(layer: Params, x: jnp.ndarray, pool_k, pool_v,
     """`_layer_decode_rowwise` over the page pool: write this token's kv
     into (w_page, w_off), gather each slot's logical row from its pages,
     attend mask-gated — same arithmetic, paged residency."""
-    b, s, d = x.shape
-    hd = cfg.head_dim
-    dt = cfg.dtype
+    q, k, v = _project_qkv(layer, x, cos, sin, cfg)
 
-    hidden = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-    q = (hidden @ layer["attn"]["wq"].astype(dt)).reshape(b, s, -1, hd)
-    k = (hidden @ layer["attn"]["wk"].astype(dt)).reshape(b, s, -1, hd)
-    v = (hidden @ layer["attn"]["wv"].astype(dt)).reshape(b, s, -1, hd)
-    q, k = apply_rope(q, k, cos, sin)
+    with jax.named_scope(trace.SCOPE_KV_WRITE):
+        pool_k, sc_k = _paged_write_token(pool_k, sc_k, k[:, 0], w_page, w_off)
+        pool_v, sc_v = _paged_write_token(pool_v, sc_v, v[:, 0], w_page, w_off)
+    gk, gv = _gather_pages(pool_k, pool_v, sc_k, sc_v, page_table, cfg.dtype)
 
-    pool_k, sc_k = _paged_write_token(pool_k, sc_k, k[:, 0], w_page, w_off)
-    pool_v, sc_v = _paged_write_token(pool_v, sc_v, v[:, 0], w_page, w_off)
-    gk, gv = _gather_pages(pool_k, pool_v, sc_k, sc_v, page_table, dt)
-
-    attn_out = attention(q, gk, gv, kv_mask, causal=False)
-    attn_out = attn_out.reshape(b, s, -1) @ layer["attn"]["wo"].astype(dt)
-    x = llama.mlp_block(layer, x + attn_out, cfg)
-    return x, pool_k, pool_v, sc_k, sc_v
+    with jax.named_scope(trace.SCOPE_DECODE_ATTN):
+        attn_out = attention(q, gk, gv, kv_mask, causal=False)
+    return (_attn_out_and_mlp(layer, x, attn_out, cfg), pool_k, pool_v,
+            sc_k, sc_v)
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -639,8 +651,9 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     x = llama.final_norm(params, x, cfg)
     logits = llama.lm_head(params, x, cfg)[:, -1, :]
 
-    split = jax.vmap(jax.random.split)(keys)        # [b, 2, 2]
-    nxt = sample_rowwise(logits, temperature, top_k, top_p, split[:, 1])
+    with jax.named_scope(trace.SCOPE_SAMPLE):
+        split = jax.vmap(jax.random.split)(keys)        # [b, 2, 2]
+        nxt = sample_rowwise(logits, temperature, top_k, top_p, split[:, 1])
     new_pool = {"k": new[0], "v": new[1]}
     if quant:
         new_pool["k_scale"], new_pool["v_scale"] = new[2], new[3]
@@ -690,30 +703,26 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
             layer, pk, pv, sk, sv = xs
         else:
             (layer, pk, pv), sk, sv = xs, None, None
-        b, s, d = h.shape
-        hidden = rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q = (hidden @ layer["attn"]["wq"].astype(dt)).reshape(b, s, -1, hd)
-        k = (hidden @ layer["attn"]["wk"].astype(dt)).reshape(b, s, -1, hd)
-        v = (hidden @ layer["attn"]["wv"].astype(dt)).reshape(b, s, -1, hd)
-        q, k = apply_rope(q, k, cos, sin)
+        q, k, v = _project_qkv(layer, h, cos, sin, cfg)
 
-        kb = k[0].reshape(C // page, page, -1, hd)
-        vb = v[0].reshape(C // page, page, -1, hd)
-        if quant:
-            ks = _block_amax(kb, axes=(1, 3))                 # [C/page, kvh]
-            vs = _block_amax(vb, axes=(1, 3))
-            sk = sk.at[chunk_pages].set(ks)
-            sv = sv.at[chunk_pages].set(vs)
-            kb = quant_page_block(kb, ks[:, None, :, None])
-            vb = quant_page_block(vb, vs[:, None, :, None])
-        pk = pk.at[chunk_pages].set(kb)
-        pv = pv.at[chunk_pages].set(vb)
+        with jax.named_scope(trace.SCOPE_KV_WRITE):
+            kb = k[0].reshape(C // page, page, -1, hd)
+            vb = v[0].reshape(C // page, page, -1, hd)
+            if quant:
+                ks = _block_amax(kb, axes=(1, 3))             # [C/page, kvh]
+                vs = _block_amax(vb, axes=(1, 3))
+                sk = sk.at[chunk_pages].set(ks)
+                sv = sv.at[chunk_pages].set(vs)
+                kb = quant_page_block(kb, ks[:, None, :, None])
+                vb = quant_page_block(vb, vs[:, None, :, None])
+            pk = pk.at[chunk_pages].set(kb)
+            pv = pv.at[chunk_pages].set(vb)
 
         gk, gv = _gather_pages(pk, pv, sk, sv, page_table_row[None], dt)
-        attn_out = attention(q, gk, gv, row_mask, causal=True,
-                             q_offset=write_start)
-        attn_out = attn_out.reshape(b, s, -1) @ layer["attn"]["wo"].astype(dt)
-        h = llama.mlp_block(layer, h + attn_out, cfg)
+        with jax.named_scope(trace.SCOPE_DECODE_ATTN):
+            attn_out = attention(q, gk, gv, row_mask, causal=True,
+                                 q_offset=write_start)
+        h = _attn_out_and_mlp(layer, h, attn_out, cfg)
         return h, ((pk, pv, sk, sv) if quant else (pk, pv))
 
     x, new = jax.lax.scan(body, x, xs)
@@ -749,7 +758,6 @@ def paged_prefill_span(params: Params, input_ids: jnp.ndarray,
     cache-hit tail is exactly the work the hit did NOT save."""
     _, C = input_ids.shape
     page = pool["k"].shape[2]
-    hd = cfg.head_dim
     dt = cfg.dtype
     quant = pool["k"].dtype == jnp.int8
 
@@ -795,21 +803,17 @@ def paged_prefill_span(params: Params, input_ids: jnp.ndarray,
             layer, pk, pv, sk, sv = xs
         else:
             (layer, pk, pv), sk, sv = xs, None, None
-        b, s, d = h.shape
-        hidden = rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q = (hidden @ layer["attn"]["wq"].astype(dt)).reshape(b, s, -1, hd)
-        k = (hidden @ layer["attn"]["wk"].astype(dt)).reshape(b, s, -1, hd)
-        v = (hidden @ layer["attn"]["wv"].astype(dt)).reshape(b, s, -1, hd)
-        q, k = apply_rope(q, k, cos, sin)
+        q, k, v = _project_qkv(layer, h, cos, sin, cfg)
 
-        pk, sk = write(pk, sk, k[0])
-        pv, sv = write(pv, sv, v[0])
+        with jax.named_scope(trace.SCOPE_KV_WRITE):
+            pk, sk = write(pk, sk, k[0])
+            pv, sv = write(pv, sv, v[0])
 
         gk, gv = _gather_pages(pk, pv, sk, sv, page_table_row[None], dt)
-        attn_out = attention(q, gk, gv, row_mask, causal=True,
-                             q_offset=write_start)
-        attn_out = attn_out.reshape(b, s, -1) @ layer["attn"]["wo"].astype(dt)
-        h = llama.mlp_block(layer, h + attn_out, cfg)
+        with jax.named_scope(trace.SCOPE_DECODE_ATTN):
+            attn_out = attention(q, gk, gv, row_mask, causal=True,
+                                 q_offset=write_start)
+        h = _attn_out_and_mlp(layer, h, attn_out, cfg)
         return h, ((pk, pv, sk, sv) if quant else (pk, pv))
 
     x, new = jax.lax.scan(body, x, xs)

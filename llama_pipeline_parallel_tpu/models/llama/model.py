@@ -30,6 +30,7 @@ from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.ops.attention import attention
 from llama_pipeline_parallel_tpu.ops.rmsnorm import rms_norm
 from llama_pipeline_parallel_tpu.ops.rope import apply_rope, rope_cos_sin
+from llama_pipeline_parallel_tpu.utils import trace
 
 Params = dict
 AttnFn = Callable[..., jnp.ndarray]
@@ -74,6 +75,14 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
     }
 
 
+def cast_weight(w: jnp.ndarray, dtype) -> jnp.ndarray:
+    """Master-dtype weight -> compute dtype at its point of use, under the
+    `cast_weights` scope so the cast is its own line in a trace (a no-op,
+    and no operation, where the dtypes already agree)."""
+    with jax.named_scope(trace.SCOPE_CAST_WEIGHTS):
+        return w.astype(dtype)
+
+
 def cast_params(params: Params, dtype) -> Params:
     return jax.tree.map(lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
                         params)
@@ -85,7 +94,8 @@ def cast_params(params: Params, dtype) -> Params:
 
 def embed(params: Params, input_ids: jnp.ndarray, cfg: LlamaConfig) -> jnp.ndarray:
     """Token embedding (reference EmbeddingPipe, models/llama_ds_mp_wrap.py:128-132)."""
-    return params["embed"]["embedding"].astype(cfg.dtype)[input_ids]
+    with jax.named_scope(trace.SCOPE_EMBED):
+        return cast_weight(params["embed"]["embedding"], cfg.dtype)[input_ids]
 
 
 def decoder_layer(
@@ -119,54 +129,60 @@ def decoder_layer(
 
     if tp_axis is not None:
         from llama_pipeline_parallel_tpu.parallel.tp import tp_copy, tp_reduce
-    wq = layer["attn"]["wq"].astype(dt)
-    wk = layer["attn"]["wk"].astype(dt)
-    wv = layer["attn"]["wv"].astype(dt)
-    h_local = wq.shape[-1] // hd
-    kv_local = wk.shape[-1] // hd
-
     residual = x
-    if pallas_prologue:
-        from llama_pipeline_parallel_tpu.ops.pallas_prologue import fused_prologue
+    with jax.named_scope(trace.SCOPE_ATTN_QKV):
+        wq = cast_weight(layer["attn"]["wq"], dt)
+        wk = cast_weight(layer["attn"]["wk"], dt)
+        wv = cast_weight(layer["attn"]["wv"], dt)
+        h_local = wq.shape[-1] // hd
+        kv_local = wk.shape[-1] // hd
+        if pallas_prologue:
+            from llama_pipeline_parallel_tpu.ops.pallas_prologue import fused_prologue
 
-        q, k, v = fused_prologue(
-            x, layer["input_norm"], wq, wk, wv, cos, sin,
-            eps=cfg.rms_norm_eps, head_dim=hd, tp_axis=tp_axis)
-    else:
-        hidden = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            q, k, v = fused_prologue(
+                x, layer["input_norm"], wq, wk, wv, cos, sin,
+                eps=cfg.rms_norm_eps, head_dim=hd, tp_axis=tp_axis)
+        else:
+            hidden = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            if tp_axis is not None:
+                hidden = tp_copy(hidden, tp_axis)
+            q = (hidden @ wq).reshape(b, s, h_local, hd)
+            k = (hidden @ wk).reshape(b, s, kv_local, hd)
+            v = (hidden @ wv).reshape(b, s, kv_local, hd)
+            q, k = apply_rope(q, k, cos, sin)
+    with jax.named_scope(trace.SCOPE_ATTN_CORE):
+        attn_out = attn_fn(q, k, v, padding_mask, causal=True)
+    with jax.named_scope(trace.SCOPE_ATTN_OUT):
+        attn_out = attn_out.reshape(b, s, -1) @ cast_weight(
+            layer["attn"]["wo"], dt)
         if tp_axis is not None:
-            hidden = tp_copy(hidden, tp_axis)
-        q = (hidden @ wq).reshape(b, s, h_local, hd)
-        k = (hidden @ wk).reshape(b, s, kv_local, hd)
-        v = (hidden @ wv).reshape(b, s, kv_local, hd)
-        q, k = apply_rope(q, k, cos, sin)
-    attn_out = attn_fn(q, k, v, padding_mask, causal=True)
-    attn_out = attn_out.reshape(b, s, -1) @ layer["attn"]["wo"].astype(dt)
-    if tp_axis is not None:
-        attn_out = tp_reduce(attn_out, tp_axis)
-    x = residual + attn_out
+            attn_out = tp_reduce(attn_out, tp_axis)
+        x = residual + attn_out
 
     return mlp_block(layer, x, cfg, tp_axis=tp_axis)
 
 
 def mlp_block(layer: Params, x: jnp.ndarray, cfg: LlamaConfig,
-              tp_axis: str | None = None) -> jnp.ndarray:
+              tp_axis: str | None = None,
+              scope: str = trace.SCOPE_MLP) -> jnp.ndarray:
     """Post-norm SwiGLU half of a decoder block (shared with the KV-cache
     decode path, models/llama/decode.py — one implementation, no numerics
-    drift between training and generation)."""
+    drift between training and generation; `scope` is the name its work
+    carries in a trace, which the decode programs set to their own)."""
     dt = cfg.dtype
     residual = x
-    hidden = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
-    if tp_axis is not None:
-        from llama_pipeline_parallel_tpu.parallel.tp import tp_copy, tp_reduce
+    with jax.named_scope(scope):
+        hidden = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
+        if tp_axis is not None:
+            from llama_pipeline_parallel_tpu.parallel.tp import tp_copy, tp_reduce
 
-        hidden = tp_copy(hidden, tp_axis)
-    gate = jax.nn.silu(hidden @ layer["mlp"]["gate"].astype(dt))
-    up = hidden @ layer["mlp"]["up"].astype(dt)
-    mlp_out = (gate * up) @ layer["mlp"]["down"].astype(dt)
-    if tp_axis is not None:
-        mlp_out = tp_reduce(mlp_out, tp_axis)
-    return residual + mlp_out
+            hidden = tp_copy(hidden, tp_axis)
+        gate = jax.nn.silu(hidden @ cast_weight(layer["mlp"]["gate"], dt))
+        up = hidden @ cast_weight(layer["mlp"]["up"], dt)
+        mlp_out = (gate * up) @ cast_weight(layer["mlp"]["down"], dt)
+        if tp_axis is not None:
+            mlp_out = tp_reduce(mlp_out, tp_axis)
+        return residual + mlp_out
 
 
 def run_layers(
@@ -245,13 +261,16 @@ def resolve_remat_policy(name: str):
 
 def final_norm(params: Params, x: jnp.ndarray, cfg: LlamaConfig) -> jnp.ndarray:
     """Final RMSNorm (reference LayerNormPipe, models/llama_ds_mp_wrap.py:184-188)."""
-    return rms_norm(x, params["norm"], cfg.rms_norm_eps)
+    with jax.named_scope(trace.SCOPE_FINAL_NORM):
+        return rms_norm(x, params["norm"], cfg.rms_norm_eps)
 
 
 def lm_head(params: Params, x: jnp.ndarray, cfg: LlamaConfig) -> jnp.ndarray:
     """Logits projection (reference LMLayerPipe, models/llama_ds_mp_wrap.py:191-195).
     Returns fp32 logits for a stable softmax-CE."""
-    return (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope(trace.SCOPE_LM_HEAD):
+        return (x @ cast_weight(params["lm_head"], cfg.dtype)).astype(
+            jnp.float32)
 
 
 def forward(

@@ -43,6 +43,7 @@ from llama_pipeline_parallel_tpu.ops.pallas_common import (
     compiler_params,
     interpret_mode,
 )
+from llama_pipeline_parallel_tpu.utils import trace
 
 NEG_INF = -1e30
 # (batch, head, outer tile) programs are independent; the innermost axis
@@ -214,6 +215,7 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, q_offset, kv_offset,
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
+        name=trace.KERNEL_FLASH_FWD,
         interpret=interpret_mode(),
     )(*args)
     return out, lse
@@ -354,6 +356,7 @@ def _bwd(q, k_full, v_full, delta, lse, do, *, causal, scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name=trace.KERNEL_FLASH_BWD_DQ,
         interpret=interpret_mode(),
     )(*args)
 
@@ -376,6 +379,7 @@ def _bwd(q, k_full, v_full, delta, lse, do, *, causal, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name=trace.KERNEL_FLASH_BWD_DKV,
         interpret=interpret_mode(),
     )(*args)  # same operands as the dq kernel, transposed grid
     return dq, dk, dv

@@ -46,6 +46,7 @@ from llama_pipeline_parallel_tpu.ops.pallas_common import (
     interpret_mode,
     token_block,
 )
+from llama_pipeline_parallel_tpu.utils import trace
 
 # every kernel here: outer grid axis independent, inner axis accumulates
 _COMPILER_PARAMS = compiler_params("parallel", "arbitrary")
@@ -126,6 +127,7 @@ def _fwd_stats(hN, w, safe_t, num_chunks, block_tokens):
             pltpu.VMEM((bn, 128), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
+        name=trace.KERNEL_CE_FWD,
         interpret=interpret_mode(),
     )(hN, w, safe_t[:, None])
     return lse[:, 0], tgt[:, 0]
@@ -230,6 +232,7 @@ def _backward(h, w, targets, lse, valid, ct_loss, num_chunks, block_tokens):
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name=trace.KERNEL_CE_BWD_DH,
         interpret=interpret_mode(),
     )(hN, w, safe_t, lse2, svec)
     # dW: vocab tiles outer, token blocks inner (accumulated in VMEM).
@@ -248,6 +251,7 @@ def _backward(h, w, targets, lse, valid, ct_loss, num_chunks, block_tokens):
         out_shape=jax.ShapeDtypeStruct((d, v), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name=trace.KERNEL_CE_BWD_DW,
         interpret=interpret_mode(),
     )(hN, w, safe_t, lse2, svec)
     return dh.astype(h.dtype).reshape(h.shape), dw.astype(w.dtype)

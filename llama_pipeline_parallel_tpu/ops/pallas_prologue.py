@@ -50,6 +50,7 @@ from llama_pipeline_parallel_tpu.ops.pallas_common import (
     token_block,
 )
 from llama_pipeline_parallel_tpu.ops.rmsnorm import rms_norm
+from llama_pipeline_parallel_tpu.utils import trace
 
 def _token_block(n: int, block_tokens: int | None) -> int:
     return token_block(n, block_tokens)
@@ -131,6 +132,7 @@ def _fwd(xN, norm_w, wq, wk, wv, cosN, sinN, eps, head_dim, block_tokens):
             jax.ShapeDtypeStruct((n, dkv), xN.dtype),
         ],
         compiler_params=compiler_params("parallel"),
+        name=trace.KERNEL_PROLOGUE_FWD,
         interpret=interpret_mode(),
     )(xN, norm_w[None, :], wq, wk, wv, cosN, sinN)
 
@@ -206,6 +208,7 @@ def _bwd(xN, norm_w, wq, wk, wv, cosN, sinN, dqN, dkN, dvN, eps, head_dim,
         out_specs=pl.BlockSpec((bn, d), row),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         compiler_params=compiler_params("parallel"),
+        name=trace.KERNEL_PROLOGUE_BWD_DX,
         interpret=interpret_mode(),
     )(dqN, dkN, dvN, wq, wk, wv, cosN, sinN)
     dwq, dwk, dwv = pl.pallas_call(
@@ -236,6 +239,7 @@ def _bwd(xN, norm_w, wq, wk, wv, cosN, sinN, dqN, dkN, dvN, eps, head_dim,
             pltpu.VMEM((d, dkv_w), jnp.float32),
         ],
         compiler_params=compiler_params("arbitrary"),
+        name=trace.KERNEL_PROLOGUE_BWD_DW,
         interpret=interpret_mode(),
     )(xN, norm_w[None, :], dqN, dkN, dvN, cosN, sinN)
     # The reference's tp_copy sits between norm and projections: its

@@ -16,7 +16,10 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import optax
+
+from llama_pipeline_parallel_tpu.utils import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,19 +52,31 @@ def warmup_decay_schedule(peak_lr: float, total_steps: int, warmup_steps: int
     )
 
 
+def _scoped(name: str, tx: optax.GradientTransformation
+            ) -> optax.GradientTransformation:
+    """`tx` with its update's operations named `name` in a device trace
+    (utils/trace.py scope vocabulary); state and values are `tx`'s own."""
+    def update(updates, state, params=None):
+        with jax.named_scope(name):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
+
+
 def make_optimizer(cfg: OptimizerConfig) -> tuple[optax.GradientTransformation, optax.Schedule]:
     """AdamW + clip + schedule. Returns (transform, schedule) — the schedule is
     also returned standalone so the trainer can log lr (the reference queries
     `scheduler.get_lr()[0]`, trainer_base_ds_mp.py:362)."""
     schedule = warmup_decay_schedule(cfg.learning_rate, cfg.total_steps, cfg.warmup_steps)
     tx = optax.chain(
-        optax.clip_by_global_norm(cfg.max_grad_norm),
-        optax.adamw(
+        _scoped(trace.SCOPE_GRAD_CLIP,
+                optax.clip_by_global_norm(cfg.max_grad_norm)),
+        _scoped(trace.SCOPE_OPTIMIZER, optax.adamw(
             learning_rate=schedule,
             b1=cfg.beta1,
             b2=cfg.beta2,
             eps=cfg.eps,
             weight_decay=cfg.weight_decay,
-        ),
+        )),
     )
     return tx, schedule

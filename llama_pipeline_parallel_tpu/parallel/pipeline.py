@@ -90,7 +90,7 @@ from llama_pipeline_parallel_tpu.parallel.mesh import (
     AXIS_TP,
 )
 from llama_pipeline_parallel_tpu.parallel import schedule as usched
-from llama_pipeline_parallel_tpu.utils import host_stash
+from llama_pipeline_parallel_tpu.utils import host_stash, trace
 
 Params = dict
 Batch = dict
@@ -552,6 +552,18 @@ def _head_ce_sum_count(pcfg: PipelineConfig):
     return lambda h, w, t: fused_ce_sum_count(h, w, t, pcfg.loss_chunks)
 
 
+def _head_loss(pcfg: PipelineConfig, cfg: LlamaConfig, head_w, hn, targets):
+    """(loss sum, valid count) from final-normed hiddens: the tp=1 head
+    projection and its loss, fused or not, under the one `lm_head_loss`
+    scope (forward, recompute and backward of the head all carry it)."""
+    with jax.named_scope(trace.SCOPE_LM_HEAD_LOSS):
+        if pcfg.loss_chunks > 1 or pcfg.kernel_ce:
+            return _head_ce_sum_count(pcfg)(
+                hn, llama.cast_weight(head_w, cfg.dtype), targets)
+        logits = llama.lm_head({"lm_head": head_w}, hn, cfg)
+        return llama.token_loss_sum_and_count_preshifted(logits, targets)
+
+
 # ---------------------------------------------------------------------------
 # Param layout: [n_layers, ...] <-> [num_stages, layers_per_stage, ...]
 # (or [num_stages, virtual_stages, layers_per_chunk, ...] under interleaving)
@@ -777,6 +789,7 @@ def _sp_shift_labels(labels: jnp.ndarray, sp_size: int) -> jnp.ndarray:
     return jnp.concatenate([labels[:, 1:], tail], axis=1)
 
 
+@jax.named_scope(trace.SCOPE_LM_HEAD_LOSS)
 def _vocab_parallel_token_loss(params: Params, h: jnp.ndarray, labels: jnp.ndarray,
                                cfg: LlamaConfig, preshifted: bool = False,
                                last_stage: jnp.ndarray | None = None,
@@ -809,7 +822,7 @@ def _vocab_parallel_token_loss(params: Params, h: jnp.ndarray, labels: jnp.ndarr
     """
     from llama_pipeline_parallel_tpu.parallel.tp import tp_copy, tp_max, tp_reduce
 
-    head_local = params["lm_head"].astype(cfg.dtype)  # [d, V/n] local shard
+    head_local = llama.cast_weight(params["lm_head"], cfg.dtype)  # [d, V/n] local shard
     # column-parallel matmul input: replicated h fans into vocab shards, so dh
     # must be psum'd across tp in backward (the Megatron f operator). Must sit
     # OUTSIDE any stage-divergent cond: its backward psum has to run on every
@@ -1056,11 +1069,7 @@ def _pipeline_loss_local(
 
         def head(h_, targets_):
             hn = llama.final_norm(params, h_, cfg)
-            if pcfg.loss_chunks > 1 or pcfg.kernel_ce:
-                return _head_ce_sum_count(pcfg)(
-                    hn, params["lm_head"].astype(cfg.dtype), targets_)
-            logits = llama.lm_head(params, hn, cfg)
-            return llama.token_loss_sum_and_count_preshifted(logits, targets_)
+            return _head_loss(pcfg, cfg, params["lm_head"], hn, targets_)
 
         return jax.lax.cond(
             take, head,
@@ -1079,27 +1088,30 @@ def _pipeline_loss_local(
         mb_idx = jnp.clip(mb_idx, 0, m_total - 1)
 
         my_ids, pad_mask, cos, sin, targets = mb_data(mb_idx)
-        emb = llama.embed(params, my_ids, cfg)
-        x_in = jnp.where(is_first & (ch == 0), emb, x_prev)
-
-        tp_axis = AXIS_TP if tp_size > 1 else None
-        chunk_layers = (jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, ch, keepdims=False),
-            local_layers) if v > 1 else local_layers)
-        k_max = jax.tree.leaves(chunk_layers)[0].shape[0]
-        y = llama.run_layers(chunk_layers, x_in, pad_mask, cos, sin, cfg,
-                             attn_fn=attn_fn, remat=pcfg.remat, tp_axis=tp_axis,
-                             remat_policy=pcfg.remat_policy,
-                             slot_valid=_slot_valid(pcfg, stage, tp_size,
-                                                    sp_size, k_max)
-                             if v == 1 else None,
-                             pallas_prologue=pcfg.kernel_prologue)
-
-        # The last stage's finished microbatch contributes its loss in-tick
-        # (nothing is collected into an M-sized buffer; the head itself is
-        # cond-gated inside mb_loss so only the owning stage pays it).
         take = is_last & (ch == v - 1) & (my_idx >= 0)
-        mb_sum, mb_count = mb_loss(y, targets, take)
+        with jax.named_scope(trace.SCOPE_PP_FWD):
+            emb = llama.embed(params, my_ids, cfg)
+            x_in = jnp.where(is_first & (ch == 0), emb, x_prev)
+
+            tp_axis = AXIS_TP if tp_size > 1 else None
+            chunk_layers = (jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, ch, keepdims=False),
+                local_layers) if v > 1 else local_layers)
+            k_max = jax.tree.leaves(chunk_layers)[0].shape[0]
+            y = llama.run_layers(chunk_layers, x_in, pad_mask, cos, sin, cfg,
+                                 attn_fn=attn_fn, remat=pcfg.remat,
+                                 tp_axis=tp_axis,
+                                 remat_policy=pcfg.remat_policy,
+                                 slot_valid=_slot_valid(pcfg, stage, tp_size,
+                                                        sp_size, k_max)
+                                 if v == 1 else None,
+                                 pallas_prologue=pcfg.kernel_prologue)
+
+            # The last stage's finished microbatch contributes its loss
+            # in-tick (nothing is collected into an M-sized buffer; the head
+            # itself is cond-gated inside mb_loss so only the owning stage
+            # pays it).
+            mb_sum, mb_count = mb_loss(y, targets, take)
         loss_sum = loss_sum + jnp.where(take, mb_sum, 0.0)
         count = count + jnp.where(take, mb_count, 0)
 
@@ -1112,7 +1124,8 @@ def _pipeline_loss_local(
         # Hand off to the next stage over the ICI ring (NCCL-P2P analogue).
         if s_total > 1:
             perm = [(i, (i + 1) % s_total) for i in range(s_total)]
-            x_next = jax.lax.ppermute(y, AXIS_PP, perm)
+            with jax.named_scope(trace.SCOPE_PP_HANDOFF):
+                x_next = jax.lax.ppermute(y, AXIS_PP, perm)
         else:
             x_next = y
         return (x_next, loss_sum, count, act_stats), None
@@ -1166,6 +1179,34 @@ def flush_unit_schedule(pcfg: PipelineConfig):
     return _unit_schedule_for(dataclasses.replace(
         pcfg, num_microbatches=pcfg.num_microbatches // pcfg.accum_chunks,
         accum_chunks=1))
+
+
+def schedule_slot_counts(pcfg: PipelineConfig) -> list[dict] | None:
+    """Per stage, the F, B and W slots one optimizer step executes and how
+    many of each are masked (unit index < 0: full-price device work that
+    contributes nothing), counted from the very table slices the interpreter
+    scans, segment by segment. This is the pipeline's bubble as the
+    device sees it; `bubble_fraction` is a closed form with F = B = W. None
+    for gpipe, which scans no unit tables."""
+    import numpy as np
+
+    us = flush_unit_schedule(pcfg)
+    if us is None:
+        return None
+    counts = [{"stage": s, "f": 0, "f_masked": 0, "b": 0, "b_masked": 0,
+               "w": 0, "w_masked": 0} for s in range(pcfg.num_stages)]
+    for seg in usched.segments(us):
+        for key, scanned, table in (("f", seg.has_f, us.f_unit),
+                                    ("b", seg.has_b, us.b_unit),
+                                    ("w", seg.has_w, us.w_unit)):
+            if not scanned:
+                continue
+            rows = np.asarray(table)[seg.t0:seg.t1]
+            for s, c in enumerate(counts):
+                c[key] += int(rows.shape[0]) * pcfg.accum_chunks
+                c[key + "_masked"] += (int((rows[:, s] < 0).sum())
+                                       * pcfg.accum_chunks)
+    return counts
 
 
 @functools.lru_cache(maxsize=64)
@@ -1312,11 +1353,7 @@ def _pipeline_units_local(
         else:
             def head_branch(norm_w, head_w, y_):
                 h = llama.final_norm({"norm": norm_w}, y_, cfg)
-                if pcfg.loss_chunks > 1 or pcfg.kernel_ce:
-                    return _head_ce_sum_count(pcfg)(
-                        h, head_w.astype(cfg.dtype), targets)[0]
-                logits = llama.lm_head({"lm_head": head_w}, h, cfg)
-                return llama.token_loss_sum_and_count_preshifted(logits, targets)[0]
+                return _head_loss(pcfg, cfg, head_w, h, targets)[0]
 
             mb_sum = jax.lax.cond(
                 gate, head_branch, lambda norm_w, head_w, y_: jnp.float32(0.0),
@@ -1340,7 +1377,16 @@ def _pipeline_units_local(
     wq_slot_tbl = jnp.asarray(us.wq_slot, jnp.int32) if split else None
     off_tbl = jnp.asarray(off_np) if split and 0 < n_off < n_units else None
     use_act_stash = pcfg.offload_activations and bool(us.has_f.any())
+    # a B unit runs its stage forward inside the vjp (nested in `pp_bwd`).
+    # Where the sequence has F units that is the forward run AGAIN; where it
+    # has none (S == 1: the B units are the whole step) it is the only
+    # forward there is
+    b_fwd_scope = (trace.SCOPE_PP_RECOMPUTE if bool(us.has_f.any())
+                   else trace.SCOPE_PP_FWD)
 
+    # each half runs whole under its slot's scope: the unit's own work and
+    # the index, buffer and queue plumbing around it
+    @jax.named_scope(trace.SCOPE_PP_FWD)
     def fwd_half(f_row, x_recv, xbuf):
         f = jnp.take(f_row, stage)
         f_valid = f >= 0
@@ -1419,6 +1465,7 @@ def _pipeline_units_local(
         is_off = jnp.take(off_tbl, g_c)
         return tuple(jnp.where(is_off, h, k) for h, k in zip(hosted, kept))
 
+    @jax.named_scope(trace.SCOPE_PP_BWD)
     def bwd_half(b_row, dy_recv, xbuf, gacc, loss_acc, act_stats, wq):
         g = jnp.take(b_row, stage)
         b_valid = g >= 0
@@ -1442,15 +1489,17 @@ def _pipeline_units_local(
             return chunk_fwd(p, x_in, ch_b, ids_b, pad_b, cos_b, sin_b,
                              targets_b, with_loss=True, loss_gate=b_valid)
 
-        if split:
-            # B unit: input-grad only. Params are CLOSED OVER, so the vjp
-            # never builds the weight-grad matmuls — the tick pays just
-            # the chunk recompute + the cotangent chain the upstream stage
-            # is waiting on. The (input, cotangent) residual is stashed
-            # for the sequence's W units.
-            (y_b, mb_sum), pullback = jax.vjp(lambda x: h(params, x), x_in_b)
-        else:
-            (y_b, mb_sum), pullback = jax.vjp(h, params, x_in_b)
+        with jax.named_scope(b_fwd_scope):
+            if split:
+                # B unit: input-grad only. Params are CLOSED OVER, so the
+                # vjp never builds the weight-grad matmuls — the tick pays
+                # just the chunk recompute + the cotangent chain the
+                # upstream stage is waiting on. The (input, cotangent)
+                # residual is stashed for the sequence's W units.
+                (y_b, mb_sum), pullback = jax.vjp(lambda x: h(params, x),
+                                                  x_in_b)
+            else:
+                (y_b, mb_sum), pullback = jax.vjp(h, params, x_in_b)
         if collect_stats:
             # stage/chunk-boundary activation stats from the backward
             # recompute (covers S=1, whose forward half may not exist,
@@ -1490,11 +1539,14 @@ def _pipeline_units_local(
             return chunk_fwd(p, x_w, ch_w, ids_w, pad_w, cos_w, sin_w,
                              targets_w, with_loss=True)
 
-        _, pullback = jax.vjp(h_p, params)
-        dy_seed = jnp.where(valid, dy_w, jnp.zeros_like(dy_w))
-        (dparams,) = pullback((dy_seed, jnp.where(valid, loss_ct_w, 0.0)))
-        return jax.tree.map(jnp.add, gacc, dparams)
+        with jax.named_scope(trace.SCOPE_PP_W):
+            with jax.named_scope(trace.SCOPE_PP_RECOMPUTE):
+                _, pullback = jax.vjp(h_p, params)
+            dy_seed = jnp.where(valid, dy_w, jnp.zeros_like(dy_w))
+            (dparams,) = pullback((dy_seed, jnp.where(valid, loss_ct_w, 0.0)))
+            return jax.tree.map(jnp.add, gacc, dparams)
 
+    @jax.named_scope(trace.SCOPE_PP_W)
     def w_half(w_row, gacc, wq):
         g = jnp.take(w_row, stage)
         g_c = jnp.clip(g, 0, n_units - 1)
@@ -1516,12 +1568,13 @@ def _pipeline_units_local(
                 gacc = w_half(xs["w"], gacc, wq)
             # ring handoffs sit outside every cond and run tick-uniformly;
             # at S=1 the handoff degenerates to the scan carry itself
-            if r_f:
-                x_recv = (jax.lax.ppermute(y_f, AXIS_PP, fwd_perm)
-                          if s_total > 1 else y_f)
-            if r_b:
-                dy_recv = (jax.lax.ppermute(dx, AXIS_PP, bwd_perm)
-                           if s_total > 1 else dx)
+            with jax.named_scope(trace.SCOPE_PP_HANDOFF):
+                if r_f:
+                    x_recv = (jax.lax.ppermute(y_f, AXIS_PP, fwd_perm)
+                              if s_total > 1 else y_f)
+                if r_b:
+                    dy_recv = (jax.lax.ppermute(dx, AXIS_PP, bwd_perm)
+                               if s_total > 1 else dx)
             return (x_recv, dy_recv, xbuf, gacc, loss_acc, act_stats, *wq), None
         return body
 
@@ -1720,9 +1773,11 @@ def _loss_and_grad_local(params, batch, cfg, pcfg, attn_fn,
     # shard saw only its sequence slab, so its grads are partial). Replicated
     # leaves (embed/norm/head): reduce across pp too so every replica stays
     # identical.
-    grads["layers"] = jax.lax.psum(grads["layers"], (AXIS_DP, AXIS_SP))
-    for key in ("embed", "norm", "lm_head"):
-        grads[key] = jax.lax.psum(grads[key], (AXIS_PP, AXIS_DP, AXIS_SP))
+    with jax.named_scope(trace.SCOPE_GRAD_REDUCE):
+        grads["layers"] = jax.lax.psum(grads["layers"], (AXIS_DP, AXIS_SP))
+        for key in ("embed", "norm", "lm_head"):
+            grads[key] = jax.lax.psum(grads[key],
+                                      (AXIS_PP, AXIS_DP, AXIS_SP))
     if not collect_stats:
         return loss, grads
 

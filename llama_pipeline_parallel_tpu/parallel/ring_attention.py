@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.ops import flash_attention as fa
 from llama_pipeline_parallel_tpu.parallel.mesh import AXIS_SP
+from llama_pipeline_parallel_tpu.utils import trace
 
 NEG_INF = fa.NEG_INF
 
@@ -120,7 +121,8 @@ def _slab_bwd(backend, q, k, v, do, lse, delta, *, seg_q=None, seg_kv=None, **kw
 def _rotate(xs, axis_name):
     n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
-    return tuple(jax.lax.ppermute(x, axis_name, perm) for x in xs)
+    with jax.named_scope(trace.SCOPE_SP_COLLECTIVE):
+        return tuple(jax.lax.ppermute(x, axis_name, perm) for x in xs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))

@@ -25,6 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from llama_pipeline_parallel_tpu.utils import trace
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def tp_copy(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
@@ -36,7 +38,8 @@ def _copy_fwd(x, axis_name):
 
 
 def _copy_bwd(axis_name, _, g):
-    return (jax.lax.psum(g, axis_name),)
+    with jax.named_scope(trace.SCOPE_TP_COLLECTIVE):
+        return (jax.lax.psum(g, axis_name),)
 
 
 tp_copy.defvjp(_copy_fwd, _copy_bwd)
@@ -44,11 +47,13 @@ tp_copy.defvjp(_copy_fwd, _copy_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def tp_reduce(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
-    return jax.lax.psum(x, axis_name)
+    with jax.named_scope(trace.SCOPE_TP_COLLECTIVE):
+        return jax.lax.psum(x, axis_name)
 
 
 def _reduce_fwd(x, axis_name):
-    return jax.lax.psum(x, axis_name), None
+    with jax.named_scope(trace.SCOPE_TP_COLLECTIVE):
+        return jax.lax.psum(x, axis_name), None
 
 
 def _reduce_bwd(axis_name, _, g):
@@ -63,11 +68,13 @@ def tp_max(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Cross-rank max with ZERO gradient — for numerical-stability shifts
     (the subtracted max cancels mathematically, and `lax.pmax` has no
     differentiation rule at all, even under stop_gradient)."""
-    return jax.lax.pmax(x, axis_name)
+    with jax.named_scope(trace.SCOPE_TP_COLLECTIVE):
+        return jax.lax.pmax(x, axis_name)
 
 
 def _max_fwd(x, axis_name):
-    return jax.lax.pmax(x, axis_name), jnp.shape(x)
+    with jax.named_scope(trace.SCOPE_TP_COLLECTIVE):
+        return jax.lax.pmax(x, axis_name), jnp.shape(x)
 
 
 def _max_bwd(axis_name, shape, g):

@@ -235,7 +235,7 @@ def make_train_step(
     so the per-step host->device traffic is unchanged.
     """
     from llama_pipeline_parallel_tpu.ops.attention import attention
-    from llama_pipeline_parallel_tpu.utils import numerics
+    from llama_pipeline_parallel_tpu.utils import numerics, trace
 
     loss_grad_fn = make_pipeline_loss_and_grad(
         mesh, cfg, pcfg, params_like, attn_fn=attn_fn or attention,
@@ -250,17 +250,24 @@ def make_train_step(
             loss, grads = loss_grad_fn(state.params, batch)
         if poison_stage is not None:
             grads = numerics.poison_grads(grads, poison_stage)
+        # `tx` names its own clip and AdamW (optim/optimizer.py); the norm
+        # for the metrics line is the clip's, which XLA computes once
         updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(trace.SCOPE_OPTIMIZER):
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(trace.SCOPE_GRAD_CLIP):
+            grad_norm = optax.global_norm(grads)
         metrics = {
             "loss": loss,
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": grad_norm,
             "lr": schedule(state.step),
             "step": state.step + 1,
         }
         if collect_stats:
-            stats = numerics.step_stats(state.params, grads, updates,
-                                        virtual_stages=pcfg.virtual_stages)
+            with jax.named_scope(trace.SCOPE_NUMERICS):
+                stats = numerics.step_stats(
+                    state.params, grads, updates,
+                    virtual_stages=pcfg.virtual_stages)
             stats.update(act_stats)
             # replicate the stat vectors (a few hundred floats): the host
             # monitor reads them with np.asarray, which on a pod requires
@@ -271,13 +278,16 @@ def make_train_step(
                     x, NamedSharding(mesh, P())), stats)
             # nonfinite guard: keep the old params/opt-state when any grad
             # leaf is nonfinite — the skip happens in-graph, the same step
+            # (named as the update it guards: XLA fuses each leaf's AdamW
+            # into this select, and a fusion carries its root's name)
             finite = ~stats["nonfinite"]
-            new_params = jax.tree.map(
-                lambda new, old: jnp.where(finite, new, old),
-                new_params, state.params)
-            new_opt_state = jax.tree.map(
-                lambda new, old: jnp.where(finite, new, old),
-                new_opt_state, state.opt_state)
+            with jax.named_scope(trace.SCOPE_OPTIMIZER):
+                new_params = jax.tree.map(
+                    lambda new, old: jnp.where(finite, new, old),
+                    new_params, state.params)
+                new_opt_state = jax.tree.map(
+                    lambda new, old: jnp.where(finite, new, old),
+                    new_opt_state, state.opt_state)
             metrics["numerics"] = stats
         if timeline:
             # post-update boundary mark: probe depends on the updated
@@ -298,18 +308,20 @@ def make_train_step(
 
     batch_shardings = {k: NamedSharding(mesh, s)
                        for k, s in batch_specs(mesh).items()}
+    # `train_step` is the name the compiled module carries (`jit_train_step`
+    # in a trace and in the compile cache), whatever wraps it here
     if poison:
-        def step_fn(state, batch, poison_stage):
+        def train_step(state, batch, poison_stage):
             return _step(state, batch, poison_stage)
 
         in_shardings = (shardings, batch_shardings, None)
     else:
-        def step_fn(state, batch):
+        def train_step(state, batch):
             return _step(state, batch, None)
 
         in_shardings = (shardings, batch_shardings)
     return jax.jit(
-        step_fn,
+        train_step,
         in_shardings=in_shardings,
         out_shardings=(shardings, None),
         donate_argnums=(0,),

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.ops.attention import attention, repeat_kv
 from llama_pipeline_parallel_tpu.parallel.mesh import AXIS_SP
+from llama_pipeline_parallel_tpu.utils import trace
 
 
 def ulysses_attention(
@@ -74,9 +75,11 @@ def ulysses_attention(
         return jax.lax.all_to_all(x, axis_name, split_axis=1, concat_axis=2,
                                   tiled=True)
 
-    qg, kg, vg = scatter_heads(q), scatter_heads(k), scatter_heads(v)
-    if padding_mask is not None:
-        padding_mask = jax.lax.all_gather(padding_mask, axis_name, axis=1,
-                                          tiled=True)
+    with jax.named_scope(trace.SCOPE_SP_COLLECTIVE):
+        qg, kg, vg = scatter_heads(q), scatter_heads(k), scatter_heads(v)
+        if padding_mask is not None:
+            padding_mask = jax.lax.all_gather(padding_mask, axis_name, axis=1,
+                                              tiled=True)
     out = inner_attn(qg, kg, vg, padding_mask, causal=causal)
-    return gather_seq(out)
+    with jax.named_scope(trace.SCOPE_SP_COLLECTIVE):
+        return gather_seq(out)

@@ -384,6 +384,7 @@ class ServeEngine:
         self._tick_accum = 0.0
         self._tick_count = 0
         self._tick_active = 0
+        self._tick_phases = [0.0, 0.0, 0.0, 0.0]  # stage, dispatch, wait, emit
 
     # -- submission (any thread) ------------------------------------------
 
@@ -573,7 +574,10 @@ class ServeEngine:
         self._cancel_abandoned()
         pf_req = (self._prefilling[0].request.request_id
                   if self._prefilling else None)
-        self._advance_prefill()
+        # admission and prefill chunks: the loop's host work outside the
+        # decode tick, one profiler event a step (`serve_prefill` nests in it)
+        with trace.annotate(trace.SERVE_ADMIT):
+            self._advance_prefill()
         prefill_s = (time.perf_counter() - t0
                      if self._timeline is not None else 0.0)
         if not self._occupants:
@@ -895,9 +899,6 @@ class ServeEngine:
             return False
 
         t_first = time.time()
-        trace.recorder().emit("serve_ttft", ts=pf.request.arrival,
-                              dur=t_first - pf.request.arrival,
-                              request=pf.request.request_id)
         if rt_b is not None:
             rt_b.first_token(t_first)
         running = _Running(request=pf.request, handle=pf.handle, token=token,
@@ -912,99 +913,132 @@ class ServeEngine:
         return True
 
     def _decode_tick(self) -> None:
+        """One decode tick over every slot, in four host phases: `stage`
+        (the numpy batch), `dispatch` (page growth, the small arrays' H2D,
+        the enqueue), `wait` (blocked on this tick's tokens) and `emit`
+        (token push, finishes). Each is a profiler annotation and a sum on
+        the aggregated `serve_decode_step` span, whose `dur` stays
+        dispatch + wait."""
         scfg = self.serve_cfg
         S = scfg.max_slots
-        token = np.zeros(S, np.int32)
-        pos = np.zeros(S, np.int32)
-        write_pos = np.zeros(S, np.int32)
-        keys = np.zeros((S, 2), np.uint32)
-        temps = np.zeros(S, np.float32)
-        top_ks = np.zeros(S, np.int32)
-        top_ps = np.ones(S, np.float32)
-        for slot, r in self._occupants.items():
-            token[slot] = r.token
-            pos[slot] = r.pos
-            write_pos[slot] = r.write_pos
-            keys[slot] = r.key
-            temps[slot] = r.request.gen.temperature
-            top_ks[slot] = r.request.gen.top_k
-            top_ps[slot] = r.request.gen.top_p
+        t_entry = time.perf_counter()
+        with trace.annotate(trace.TICK_STAGE):
+            token = np.zeros(S, np.int32)
+            pos = np.zeros(S, np.int32)
+            write_pos = np.zeros(S, np.int32)
+            keys = np.zeros((S, 2), np.uint32)
+            temps = np.zeros(S, np.float32)
+            top_ks = np.zeros(S, np.int32)
+            top_ps = np.ones(S, np.float32)
+            for slot, r in self._occupants.items():
+                token[slot] = r.token
+                pos[slot] = r.pos
+                write_pos[slot] = r.write_pos
+                keys[slot] = r.key
+                temps[slot] = r.request.gen.temperature
+                top_ks[slot] = r.request.gen.top_k
+                top_ps[slot] = r.request.gen.top_p
+            n_active = len(self._occupants)
 
-        n_active = len(self._occupants)
         t_wall = time.time()
         t0 = time.perf_counter()
-        if self._paged:
-            # back the next write of every active row BEFORE the tick: the
-            # submit-time reservation guarantees these allocations succeed
-            for slot, r in self._occupants.items():
-                self.slots.ensure_capacity(slot, r.write_pos + 1)
-            # only occupant rows may write/mark kv: a mid-prefill slot
-            # already owns live pages and mask spans this tick must not touch
-            active = np.zeros(scfg.max_slots, np.int32)
-            for slot in self._occupants:
-                active[slot] = 1
-            out = decode.paged_decode_step(
-                self.params, jnp.asarray(token), self.slots.pool,
-                jnp.asarray(self.slots.page_table), jnp.asarray(pos),
-                jnp.asarray(write_pos), self.slots.kv_mask,
-                jnp.asarray(active), jnp.asarray(keys), jnp.asarray(temps),
-                jnp.asarray(top_ks), jnp.asarray(top_ps), self.cfg)
-        else:
-            out = decode.decode_step(
-                self.params, jnp.asarray(token), self.slots.cache,
-                jnp.asarray(pos), jnp.asarray(write_pos), self.slots.kv_mask,
-                jnp.asarray(keys), jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), self.cfg)
-        self.slots.update_from_step(out)
-        next_token = np.asarray(out["token"])       # blocks: real tick time
-        new_keys = np.asarray(out["keys"])
-        self._last_decode_dur = time.perf_counter() - t0
-        self._note_decode_tick(t_wall, self._last_decode_dur, n_active)
-        if self._reqtrace is not None:
-            # tick-rate but bounded by max_slots dict lookups; tracing OFF
-            # skips even the branch body (the structural free-ness pin)
-            for r in self._occupants.values():
-                b = self._rt.get(r.request.request_id)
-                if b is not None:
-                    b.decode_tick(self.steps, n_active)
+        with trace.annotate(trace.TICK_DISPATCH):
+            if self._paged:
+                # back the next write of every active row BEFORE the tick:
+                # the submit-time reservation guarantees these allocations
+                # succeed
+                for slot, r in self._occupants.items():
+                    self.slots.ensure_capacity(slot, r.write_pos + 1)
+                # only occupant rows may write/mark kv: a mid-prefill slot
+                # already owns live pages and mask spans this tick must not
+                # touch
+                active = np.zeros(scfg.max_slots, np.int32)
+                for slot in self._occupants:
+                    active[slot] = 1
+                out = decode.paged_decode_step(
+                    self.params, jnp.asarray(token), self.slots.pool,
+                    jnp.asarray(self.slots.page_table), jnp.asarray(pos),
+                    jnp.asarray(write_pos), self.slots.kv_mask,
+                    jnp.asarray(active), jnp.asarray(keys),
+                    jnp.asarray(temps), jnp.asarray(top_ks),
+                    jnp.asarray(top_ps), self.cfg)
+            else:
+                out = decode.decode_step(
+                    self.params, jnp.asarray(token), self.slots.cache,
+                    jnp.asarray(pos), jnp.asarray(write_pos),
+                    self.slots.kv_mask, jnp.asarray(keys), jnp.asarray(temps),
+                    jnp.asarray(top_ks), jnp.asarray(top_ps), self.cfg)
+            self.slots.update_from_step(out)
+        t_dispatched = time.perf_counter()
+        with trace.annotate(trace.TICK_WAIT):
+            # block inside the annotation, then convert: the device's gap
+            # while the host sleeps here belongs to this event, not to the
+            # conversion's own
+            jax.block_until_ready((out["token"], out["keys"]))
+            next_token = np.asarray(out["token"])   # real tick time
+            new_keys = np.asarray(out["keys"])
+        t_fetched = time.perf_counter()
+        self._last_decode_dur = t_fetched - t0
+        with trace.annotate(trace.TICK_EMIT):
+            if self._reqtrace is not None:
+                # tick-rate but bounded by max_slots dict lookups; tracing
+                # OFF skips even the branch body (the structural free-ness
+                # pin)
+                for r in self._occupants.values():
+                    b = self._rt.get(r.request.request_id)
+                    if b is not None:
+                        b.decode_tick(self.steps, n_active)
 
-        for slot in list(self._occupants):
-            r = self._occupants[slot]
-            tok = int(next_token[slot])
-            r.token = tok
-            r.pos += 1
-            r.write_pos += 1
-            r.key = new_keys[slot]
-            r.emitted += 1
-            r.handle._push(tok)
-            gen = r.request.gen
-            if (gen.eos_token_id is not None and tok == gen.eos_token_id) \
-                    or r.emitted >= gen.max_new_tokens:
-                self._finish(slot, r)
+            for slot in list(self._occupants):
+                r = self._occupants[slot]
+                tok = int(next_token[slot])
+                r.token = tok
+                r.pos += 1
+                r.write_pos += 1
+                r.key = new_keys[slot]
+                r.emitted += 1
+                r.handle._push(tok)
+                gen = r.request.gen
+                if (gen.eos_token_id is not None and tok == gen.eos_token_id) \
+                        or r.emitted >= gen.max_new_tokens:
+                    self._finish(slot, r)
+        self._note_decode_tick(
+            t_wall, self._last_decode_dur, n_active,
+            phases=(t0 - t_entry, t_dispatched - t0, t_fetched - t_dispatched,
+                    time.perf_counter() - t_fetched))
 
-    def _note_decode_tick(self, ts: float, dur: float, active: int) -> None:
+    def _note_decode_tick(self, ts: float, dur: float, active: int,
+                          phases: tuple) -> None:
         """Fold one decode tick into the pending aggregated
         `serve_decode_step` span; flush every `decode_span_every` ticks
         (and at idle boundaries / shutdown). The emitted span's `dur` is
-        the exact sum of its `ticks` tick durations, so RunClock's `serve`
-        bucket and the goodput fraction lose nothing to the aggregation —
-        only the spans.jsonl line rate drops from token rate."""
+        the exact sum of its `ticks` tick durations (dispatch + wait), so
+        RunClock's `serve` bucket and the goodput fraction lose nothing to
+        the aggregation — only the spans.jsonl line rate drops from token
+        rate. `phases`: this tick's (stage, dispatch, wait, emit) seconds,
+        summed the same way."""
         if self._tick_count == 0:
             self._tick_ts = ts
         self._tick_accum += dur
         self._tick_count += 1
         self._tick_active = active
+        for i, seconds in enumerate(phases):
+            self._tick_phases[i] += seconds
         if self._tick_count >= self.serve_cfg.decode_span_every:
             self._flush_decode_span()
 
     def _flush_decode_span(self) -> None:
         if self._tick_count == 0:
             return
+        stage_s, dispatch_s, wait_s, emit_s = self._tick_phases
         trace.recorder().emit("serve_decode_step", ts=self._tick_ts,
                               dur=self._tick_accum, ticks=self._tick_count,
-                              active=self._tick_active)
+                              active=self._tick_active, stage_s=stage_s,
+                              dispatch_s=dispatch_s, wait_s=wait_s,
+                              emit_s=emit_s)
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active = 0, 0
+        self._tick_phases = [0.0, 0.0, 0.0, 0.0]
 
     def _on_page_alloc(self, slot: int, pages: int) -> None:
         """pages.PagedKVCache alloc_listener (installed only when tracing

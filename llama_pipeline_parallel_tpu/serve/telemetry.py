@@ -4,10 +4,10 @@ The serving counterpart of utils/trace's goodput layer. Per-request records
 land in TWO streams the repo already owns:
 
 - **spans.jsonl** (utils/trace): the engine emits retroactive spans
-  `serve_queue_wait` (arrival -> admission), `serve_ttft` (arrival -> first
-  token), and `serve_request` (arrival -> completion, with `ttft`/`tpot`/
-  `queue_wait`/`tokens` attrs) per request, plus live `serve_prefill` /
-  `serve_decode_step` spans that feed the RunClock's `serve` bucket.
+  `serve_queue_wait` (arrival -> admission) and `serve_request` (arrival ->
+  completion, with `ttft`/`tpot`/`queue_wait`/`tokens` attrs) per request,
+  plus live `serve_prefill` / `serve_decode_step` spans that feed the
+  RunClock's `serve` bucket.
 - **metrics.jsonl** (utils/metrics.MetricsWriter): every `metrics_every`
   completions the engine logs one serving line with the rolling percentiles
   this module computes.

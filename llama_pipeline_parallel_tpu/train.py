@@ -318,6 +318,53 @@ def _schedule_health_static(pcfg: "pl.PipelineConfig", topology: dict) -> dict:
     return out
 
 
+def _profile_window_facts(cfg: dict, pcfg: "pl.PipelineConfig", mesh) -> dict | None:
+    """What a `profile_window` span carries besides its times: facts of the
+    compile that a reduction of the trace needs and the trace does not hold.
+    None unless `profile_steps` is set, so an untraced run builds nothing.
+    `schedule` (pp > 1): per stage the F/B/W slots a step executes, how many
+    are masked, and the ids of the stage's devices (the trace's planes are
+    named by them). `compiled_memory` is added by the first step
+    (`_note_compiled`)."""
+    if not cfg.get("profile_steps"):
+        return None
+    facts: dict = {}
+    counts = pl.schedule_slot_counts(pcfg) if pcfg.num_stages > 1 else None
+    if counts:
+        pp_axis = mesh.axis_names.index("pp")
+        for c in counts:
+            c["devices"] = sorted(int(d.id) for d in np.take(
+                mesh.devices, c["stage"], axis=pp_axis).flat)
+        facts["schedule"] = counts
+    return facts
+
+
+def _note_compiled(label: str, lower, mem_watch, profile_facts) -> None:
+    """One AOT compile of the step program for whoever wants its
+    `memory_analysis()`: the memory watch (docs/OBSERVABILITY.md "Memory")
+    and a configured profile window. AOT lowering reads only avals (no
+    execution, no donation); the extra compile, or cache hit, lands in the
+    first step's compile bucket, before any window. With neither configured
+    this returns at once."""
+    for_watch = mem_watch is not None and label not in mem_watch.compiled
+    for_window = (profile_facts is not None
+                  and "compiled_memory" not in profile_facts)
+    if not (for_watch or for_window):
+        return
+    if for_window:
+        profile_facts["compiled_memory"] = None   # one attempt, not one a step
+    try:
+        compiled = lower().compile()
+    except Exception as e:
+        logger.debug("compiled memory capture failed: %r", e)
+        return
+    if for_watch:
+        mem_watch.note_compiled(label, compiled)
+    if for_window:
+        profile_facts["compiled_memory"] = memwatch_mod.compiled_memory(
+            compiled, top_buffers=0, label=label)
+
+
 def build_manifest(cfg: dict, model_cfg: LlamaConfig, pp: int) -> StageManifest:
     """Stage partition policy, shared by the trainer and tools/preflight.py
     (the preflight must compile the SAME program the trainer runs): explicit
@@ -865,22 +912,16 @@ def _run_training(cfg: dict) -> dict:
 
     # ---- loop -------------------------------------------------------------
     state_box = [state]
+    profile_facts = _profile_window_facts(cfg, pcfg, mesh)
 
     def do_step(batch, step, fault=None):
         gbatch = form_global_batch(mesh, batch)
-        if mem_watch is not None and "train_step" not in mem_watch.compiled:
-            # compile-time memory evidence (docs/OBSERVABILITY.md
-            # "Memory"): AOT lowering reads only avals — no execution, no
-            # donation — and the one extra compile is the watch's
-            # documented ON cost, landing in the first step's compile
-            # bucket. OFF never reaches this branch.
-            try:
-                args = ((state_box[0], gbatch, numerics.fault_stage(None))
-                        if poison_on else (state_box[0], gbatch))
-                mem_watch.note_compiled("train_step",
-                                        step_fn.lower(*args).compile())
-            except Exception as e:
-                logger.debug("compiled memory capture failed: %r", e)
+        _note_compiled(
+            "train_step",
+            lambda: step_fn.lower(*(
+                (state_box[0], gbatch, numerics.fault_stage(None))
+                if poison_on else (state_box[0], gbatch))),
+            mem_watch, profile_facts)
         if poison_on:
             new_state, metrics = step_fn(state_box[0], gbatch,
                                          numerics.fault_stage(fault))
@@ -935,7 +976,8 @@ def _run_training(cfg: dict) -> dict:
             monitor=monitor, data_start=data_start,
             health_static={**_schedule_health_static(pcfg, topology),
                            **off_static},
-            step_timeline=step_tl, profiler=prof, mem_watch=mem_watch)
+            step_timeline=step_tl, profiler=prof, mem_watch=mem_watch,
+            profile_facts=profile_facts)
     except BaseException:
         # join the in-flight commit, but never let ITS failure replace the
         # training exception that actually killed the run
@@ -1220,7 +1262,7 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                 do_step, do_save, do_eval=None, extra_scalars=None,
                 static_scalars=None, monitor=None, data_start=(0, 0),
                 health_static=None, step_timeline=None, profiler=None,
-                mem_watch=None) -> tuple:
+                mem_watch=None, profile_facts=None) -> tuple:
     """The shared step/log/save/profile loop for both optimizer paths.
 
     `do_step(batch, step, fault=None) -> (loss_scalar, scalars_thunk)`; the
@@ -1245,6 +1287,8 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
     health.json. `profiler` (profiler.TriggeredProfiler, optional) gets
     each iteration's host wall for the step-time z-score trigger, the
     numerics-anomaly span stream, and a close() on every exit path.
+    `profile_facts` (dict, optional, `_profile_window_facts`) rides on the
+    `profile_window` span emitted when a `profile_steps` capture closes.
     `mem_watch` (memwatch.MemoryWatch, optional — the memory
     observatory) samples the live memory sources after every step and
     feeds the OOM snapshot; the RESOURCE_EXHAUSTED handler below runs
@@ -1318,6 +1362,15 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
         else:
             profile_window = (lo, hi)
     trace_active = False
+    trace_t0 = trace_first = 0
+
+    def close_profile_window(through_step: int) -> None:
+        """Stop the capture and emit its one retroactive span: the window on
+        the host's clock, the steps it covers, and the compile's facts."""
+        jax.profiler.stop_trace()
+        rec.emit("profile_window", ts=trace_t0, dur=time.time() - trace_t0,
+                 first_step=trace_first + 1,
+                 steps=through_step - trace_first, **(profile_facts or {}))
 
     # O(1) data resume (docs/RESILIENCE.md "Elastic resume"): the loader
     # opens directly at (epoch, batch) by index arithmetic — the reference's
@@ -1407,6 +1460,7 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                 break
             if profile_window and not trace_active and step >= profile_window[0] \
                     and step < profile_window[1]:
+                trace_t0, trace_first = time.time(), step
                 jax.profiler.start_trace(os.path.join(output_dir, "profile"))
                 trace_active = True
             with trace.span("data_wait", step=step):
@@ -1454,7 +1508,7 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                 heartbeat.beat(step + 1)
             if trace_active and (step + 1 >= profile_window[1] or step + 1 == end_step):
                 jax.block_until_ready(loss)
-                jax.profiler.stop_trace()
+                close_profile_window(step + 1)
                 trace_active = False
                 logger.info("profiler trace written to %s/profile", output_dir)
             losses.append(loss)
@@ -1542,7 +1596,7 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
         raise
     finally:
         if trace_active:  # preemption break / exception inside the window
-            jax.profiler.stop_trace()
+            close_profile_window(completed)
             logger.info("profiler trace (early exit) written to %s/profile", output_dir)
         if profiler is not None:
             rec.remove_listener(profiler.on_span)
@@ -1810,6 +1864,7 @@ def _run_offload(cfg, mesh, model_cfg, manifest, pcfg, ocfg, dataset, collator,
         to_replicated = lambda p: p
 
     device_params_box = [to_replicated(host.device_params(model_cfg.dtype))]
+    profile_facts = _profile_window_facts(cfg, pcfg, mesh)
     # chaos-only second dispatch: the stats must see the POISONED grads
     stats_fn = (jax.jit(
         lambda p, g: _replicate_stats(numerics.step_stats(
@@ -1819,16 +1874,12 @@ def _run_offload(cfg, mesh, model_cfg, manifest, pcfg, ocfg, dataset, collator,
 
     def do_step(batch, step, fault=None):
         gbatch = form_global_batch(mesh, batch)
-        if mem_watch is not None and "loss_and_grad" not in mem_watch.compiled:
-            # the offload path's device program is loss+grad (the
-            # optimizer lives on the host): same one-shot AOT capture as
-            # the fused path's train_step
-            try:
-                mem_watch.note_compiled(
-                    "loss_and_grad",
-                    grad_fn.lower(device_params_box[0], gbatch).compile())
-            except Exception as e:
-                logger.debug("compiled memory capture failed: %r", e)
+        # the offload path's device program is loss+grad (the optimizer
+        # lives on the host): same one-shot AOT capture as the fused path's
+        _note_compiled(
+            "loss_and_grad",
+            lambda: grad_fn.lower(device_params_box[0], gbatch),
+            mem_watch, profile_facts)
         stats = None
         if not ncfg.enabled:
             loss, grads = grad_fn(device_params_box[0], gbatch)
@@ -1893,7 +1944,8 @@ def _run_offload(cfg, mesh, model_cfg, manifest, pcfg, ocfg, dataset, collator,
         monitor=monitor, data_start=data_start,
         health_static={**_schedule_health_static(pcfg, topology),
                        **off_static},
-        step_timeline=step_tl, profiler=prof, mem_watch=mem_watch)
+        step_timeline=step_tl, profiler=prof, mem_watch=mem_watch,
+        profile_facts=profile_facts)
     _write_perf_rows(cfg, pcfg, output_dir, step_tl, mem_watch)
     return _summarize(final_loss, preempted_at, end_step, len(loader),
                       output_dir)

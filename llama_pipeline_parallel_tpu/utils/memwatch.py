@@ -236,6 +236,8 @@ def compiled_memory(compiled, top_buffers: int = 8,
         "alias_bytes": alias,
         "generated_bytes": getattr(ma, "generated_code_size_in_bytes", None),
         "peak_bytes": arg + out_b + temp - alias,
+        # the compiler's own peak of its buffer assignment, where it says
+        "compiler_peak_bytes": getattr(ma, "peak_memory_in_bytes", None),
     }
     if top_buffers:
         try:

@@ -22,6 +22,14 @@ went). Three cooperating pieces:
   on a fixed cadence, so an external watchdog can tell a hung pod from a
   slow one without attaching a debugger.
 
+Device work is named from one vocabulary (the `SCOPE_*` / `KERNEL_*`
+constants below): `jax.named_scope` at each site and `name=` on every
+`pallas_call`, so a compiled operation's `op_name` path, which the profiler
+keeps per device event, says which part of the step it belongs to
+(docs/OBSERVABILITY.md "Scopes"). Host phases too short to be worth a jsonl
+line (the serving tick's four) go through `annotate`, which only mirrors into
+the profiler.
+
 The module-level recorder is a process-global configured once per run
 (`configure(output_dir)`); instrumentation sites (`train._train_loop`,
 `data.loader.PrefetchIterator`, `ckpt.checkpoint.CheckpointManager`) call
@@ -35,7 +43,7 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Iterator
 
 from llama_pipeline_parallel_tpu.utils.logging import get_logger
@@ -66,6 +74,66 @@ BUCKETS = ("init", "compile", "train", "serve", "data_stall", "ckpt", "eval",
 # buckets that count as goodput: useful work of EITHER workload (a process
 # runs one of them, so the sum never double-counts)
 GOODPUT_BUCKETS = ("train", "serve")
+
+# -- scope vocabulary --------------------------------------------------------
+# Names on device work. They are HLO metadata only (no operation is added),
+# nest as the code nests (`pp_bwd/.../mlp/cast_weights/convert`), and are
+# read back from a trace by benchmark/scopes.py and tools/trace_summary.py.
+
+# models/llama/model.py (the decode programs reuse these for the same work)
+SCOPE_EMBED = "embed"
+SCOPE_ATTN_QKV = "attn_qkv"          # input norm, q/k/v projections, rope
+SCOPE_ATTN_CORE = "attn_core"        # scores, softmax, weighted sum
+SCOPE_ATTN_OUT = "attn_out"          # output projection + residual
+SCOPE_MLP = "mlp"
+SCOPE_FINAL_NORM = "final_norm"
+SCOPE_LM_HEAD_LOSS = "lm_head_loss"  # training: head projection + loss
+SCOPE_CAST_WEIGHTS = "cast_weights"  # master dtype -> compute dtype
+SCOPE_TP_COLLECTIVE = "tp_collective"
+SCOPE_SP_COLLECTIVE = "sp_collective"
+# parallel/train_step.py, optim/optimizer.py, parallel/pipeline.py
+SCOPE_OPTIMIZER = "optimizer"
+SCOPE_GRAD_CLIP = "grad_clip"
+SCOPE_GRAD_REDUCE = "grad_reduce"    # psum of gradients over dp / sp / pp
+SCOPE_NUMERICS = "numerics"          # the numerics observatory's in-graph statistics
+# parallel/pipeline.py: what a schedule slot does
+SCOPE_PP_FWD = "pp_fwd"
+SCOPE_PP_RECOMPUTE = "pp_recompute"  # the stage forward run again for a B / W unit
+SCOPE_PP_BWD = "pp_bwd"
+SCOPE_PP_W = "pp_w"
+SCOPE_PP_HANDOFF = "pp_handoff"      # the ring ppermutes between stages
+# models/llama/decode.py
+SCOPE_KV_GATHER = "kv_gather"
+SCOPE_KV_WRITE = "kv_write"
+SCOPE_DECODE_ATTN = "decode_attn"
+SCOPE_DECODE_MLP = "decode_mlp"
+SCOPE_LM_HEAD = "lm_head"
+SCOPE_SAMPLE = "sample"
+
+SCOPES = tuple(v for k, v in sorted(globals().items())
+               if k.startswith("SCOPE_"))
+
+# `name=` of the nine pallas_calls: the kernel's instruction in a trace is
+# `<name>.<n>`
+KERNEL_FLASH_FWD = "flash_fwd"
+KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
+KERNEL_FLASH_BWD_DKV = "flash_bwd_dkv"
+KERNEL_CE_FWD = "ce_fwd"
+KERNEL_CE_BWD_DH = "ce_bwd_dh"
+KERNEL_CE_BWD_DW = "ce_bwd_dw"
+KERNEL_PROLOGUE_FWD = "prologue_fwd"
+KERNEL_PROLOGUE_BWD_DX = "prologue_bwd_dx"
+KERNEL_PROLOGUE_BWD_DW = "prologue_bwd_dw"
+
+KERNELS = tuple(v for k, v in sorted(globals().items())
+                if k.startswith("KERNEL_"))
+
+# profiler-only annotations of the serving loop (serve/engine.py)
+TICK_STAGE = "serve_tick_stage"
+TICK_DISPATCH = "serve_tick_dispatch"
+TICK_WAIT = "serve_tick_wait"
+TICK_EMIT = "serve_tick_emit"
+SERVE_ADMIT = "serve_admit"
 
 
 class SpanRecorder:
@@ -130,6 +198,12 @@ class SpanRecorder:
             stack.pop()
             self._emit(rec)
 
+    def annotate(self, name: str):
+        """A `jax.profiler.TraceAnnotation` and nothing else: no jsonl line,
+        no listener, no nesting state. For phases that repeat at token rate
+        and only matter on the trace's clock; a no-op while no trace runs."""
+        return _trace_annotation(name) or nullcontext()
+
     def emit(self, name: str, ts: float, dur: float, **attrs: Any) -> dict:
         """Retroactive span (e.g. `init`, measured configure->loop-start
         without a with-block around model construction)."""
@@ -192,6 +266,11 @@ def recorder() -> SpanRecorder:
 def span(name: str, **attrs: Any):
     """`with trace.span("data_wait"): ...` against the process recorder."""
     return _RECORDER.span(name, **attrs)
+
+
+def annotate(name: str):
+    """`with trace.annotate("serve_tick_wait"): ...`: profiler only."""
+    return _RECORDER.annotate(name)
 
 
 # -- goodput accounting ------------------------------------------------------

@@ -13,15 +13,19 @@ from llama_pipeline_parallel_tpu.utils import compile_cache, metrics
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_compile_cache_env_set_leaves_jax_config_alone(monkeypatch, tmp_path):
+METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
+
+
+def test_compile_cache_env_set_leaves_the_directory_alone(monkeypatch, tmp_path):
     """JAX_COMPILATION_CACHE_DIR set: JAX already honours it; the helper
-    must not write jax.config (whoever owns the machine places the cache)."""
+    must not place the cache (whoever owns the machine does). The one
+    option it does write is the key's metadata rule."""
     updates = []
     monkeypatch.setattr(jax.config, "update",
                         lambda *a, **k: updates.append(a))
     monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "cache"))
     assert compile_cache.setup() == str(tmp_path / "cache")
-    assert updates == []
+    assert updates == [(METADATA_IN_KEY, True)]
 
 
 def test_compile_cache_default_is_fixed_path_in_checkout(monkeypatch):
@@ -33,6 +37,26 @@ def test_compile_cache_default_is_fixed_path_in_checkout(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == path
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compile_cache_key_includes_scope_metadata(monkeypatch, tmp_path,
+                                                   env_set):
+    """Scope names are HLO metadata: left out of the key, a cache warmed
+    before a scope changed returns an executable with the old names."""
+    if env_set:
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "cache"))
+    else:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    prev = (jax.config.jax_compilation_cache_dir,
+            getattr(jax.config, METADATA_IN_KEY))
+    try:
+        jax.config.update(METADATA_IN_KEY, False)
+        compile_cache.setup()
+        assert getattr(jax.config, METADATA_IN_KEY) is True
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update(METADATA_IN_KEY, prev[1])
 
 
 def test_compile_cache_entry_count(tmp_path):

@@ -1,90 +1,89 @@
-"""tools/trace_summary.py: the offline per-op breakdown for profiler traces
-(the first thing run after a live-chip BENCH_PROFILE capture)."""
+"""tools/trace_summary.py: the operator's command over a `profile_steps` or
+`tools/serve.py` capture, on a synthetic `.xplane.pb` (the benchmark's own
+reduction does the reading: tests/benchmark_harness)."""
 
-import jax
-import jax.numpy as jnp
+import os
+import sys
+
 import pytest
 
-import trace_summary  # importable via conftest's tools/ path insert
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "benchmark_harness"))
+
+import synthetic_xplane as sx  # noqa: E402
+import trace_summary  # noqa: E402  (tools/ is on the path: tests/conftest.py)
+
+TICK = "jit(paged_decode_step)/while/body/closed_call/"
 
 
-@pytest.fixture(scope="module")
-def trace_dir(tmp_path_factory, devices):
-    d = str(tmp_path_factory.mktemp("trace"))
-    f = jax.jit(lambda x: (x @ x).sum())
-    x = jnp.ones((64, 64))
-    float(f(x))  # compile outside the capture
-    jax.profiler.start_trace(d)
-    for _ in range(3):
-        float(f(x))
-    jax.profiler.stop_trace()
-    return d
+def _op(name, path, start, dur):
+    return (sx.instruction(name), path, start, dur)
 
 
-def test_summarize_finds_the_jit_ops(trace_dir, capsys):
-    # --top large enough to list every event: the assertion is about the
-    # jitted computation APPEARING, not about its rank (which varies with
-    # process warm-up noise in the host-side events)
-    trace_summary.main([trace_dir, "--top", "100"])
+@pytest.fixture
+def capture(tmp_path):
+    """One chip, 200 ns: two ticks with a host-owned gap between them."""
+    ops = [_op("fusion.1", TICK + "kv_gather/gather", 0, 50),
+           _op("fusion.2", TICK + "decode_mlp/cast_weights/convert", 50, 10),
+           _op("fusion.1", TICK + "kv_gather/gather", 100, 60),
+           _op("copy.9", None, 160, 40)]
+    host = {"python": [("serve_tick_wait", None, 0, 62),
+                       ("serve_tick_stage", None, 62, 33),
+                       ("serve_tick_wait", None, 95, 100)]}
+    run = tmp_path / "plugins" / "profile" / "2026_01_01"
+    run.mkdir(parents=True)
+    sx.write(run / "host.xplane.pb", {"/device:TPU:0": {"XLA Ops": ops},
+                                      "/host:CPU": host})
+    return str(tmp_path)
+
+
+def test_summary_has_busy_idle_and_both_tables(capture):
+    s = trace_summary.summarize(
+        trace_summary.xplane.find_xplane(capture), top=3)
+    assert s["chips"] == 1
+    assert s["window_s"] == pytest.approx(200e-9)
+    assert s["idle_percent"] == pytest.approx(20.0)
+    assert s["by_class"] == pytest.approx({"forward": 75.0, "other": 25.0})
+    assert s["by_scope"] == pytest.approx(
+        {"kv_gather": 68.75, "cast_weights": 6.25, "(no scope)": 25.0})
+    assert s["scoped_percent"] == pytest.approx(75.0)
+
+
+def test_top_operations_and_gap_owners(capture):
+    s = trace_summary.summarize(
+        trace_summary.xplane.find_xplane(capture), top=2)
+    assert [name for name, _ in s["top_ops"]] == ["fusion.1", "copy.9"]
+    assert s["top_ops"][0][1] == pytest.approx(110e-9)
+    assert s["idle_gaps"][0] == ["serve_tick_stage", pytest.approx(40e-9)]
+
+
+def test_command_prints_every_section(capture, capsys):
+    trace_summary.main([capture, "--top", "2"])
     out = capsys.readouterr().out
-    assert "ms total" in out
-    assert "%" in out
-    # the jitted computation must appear on some track
-    assert "PjitFunction" in out or "dot_general" in out
+    assert "idle 20.000%" in out and "75.0% of busy time under a named scope" in out
+    for heading in ("by class", "by scope", "most device time",
+                    "longest idle gaps"):
+        assert heading in out
+    assert "kv_gather" in out and "serve_tick_stage" in out
 
 
-def test_track_filter_and_missing_dir(trace_dir):
-    path, trace = trace_summary.load_latest_trace(trace_dir)
-    assert path.endswith(".trace.json.gz")
-    totals, op_dur, _ = trace_summary.summarize(trace, track_filter="cpu")
-    assert totals and all("cpu" in t.lower() for t in totals)
-    totals_none, _, _ = trace_summary.summarize(trace, track_filter="tpu-v9")
-    assert not totals_none
-    with pytest.raises(FileNotFoundError, match="trace.json.gz"):
-        trace_summary.load_latest_trace(trace_dir + "-missing")
+def test_newest_capture_wins(capture, tmp_path):
+    later = tmp_path / "plugins" / "profile" / "2026_01_02"
+    later.mkdir()
+    path = sx.write(later / "host.xplane.pb", {"/device:TPU:0": {"XLA Ops": [
+        _op("fusion.7", "jit(train_step)/optimizer/mul", 0, 10)]}})
+    os.utime(path, (2e9, 2e9))
+    assert trace_summary.xplane.find_xplane(capture) == path
+    assert trace_summary.summarize(path)["by_class"] == {"optimizer": 100.0}
 
 
-def _fake_trace() -> dict:
-    return {"traceEvents": [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "/host:CPU"}},
-        {"ph": "X", "pid": 1, "name": "fusion.1", "dur": 120.0},
-        {"ph": "X", "pid": 1, "name": "fusion.1", "dur": 80.0},
-    ]}
-
-
-def test_uncompressed_trace_json_accepted(tmp_path, capsys):
-    """Hand-saved / exporter-written *.trace.json (no gzip) loads and
-    summarizes exactly like the gzipped capture."""
-    import json as _json
-
-    p = tmp_path / "plugins" / "profile" / "run1"
-    p.mkdir(parents=True)
-    (p / "host.trace.json").write_text(_json.dumps(_fake_trace()))
-    path, trace = trace_summary.load_latest_trace(str(tmp_path))
-    assert path.endswith("host.trace.json")
-    totals, op_dur, op_count = trace_summary.summarize(trace)
-    assert totals == {"/host:CPU": 200.0}
-    assert op_count["/host:CPU"]["fusion.1"] == 2
-    trace_summary.main([str(tmp_path)])
-    out = capsys.readouterr().out
-    assert "fusion.1" in out and "ms total" in out
-
-
-def test_empty_dir_is_a_readable_message(tmp_path):
-    """An empty/partial trace dir exits with a verdict, not a traceback."""
-    with pytest.raises(SystemExit) as ei:
+def test_a_directory_without_a_capture_is_a_readable_verdict(tmp_path):
+    with pytest.raises(SystemExit, match="no .xplane.pb under"):
         trace_summary.main([str(tmp_path)])
-    assert "trace.json" in str(ei.value)
 
 
-def test_partial_capture_is_a_readable_message(tmp_path):
-    """A torn capture (killed mid-profile-window) exits with a pointer to
-    the bad file instead of a JSONDecodeError traceback."""
-    p = tmp_path / "plugins" / "profile" / "run1"
-    p.mkdir(parents=True)
-    (p / "torn.trace.json").write_text('{"traceEvents": [{"ph": "X", "du')
-    with pytest.raises(SystemExit) as ei:
-        trace_summary.load_latest_trace(str(tmp_path))
-    assert "torn.trace.json" in str(ei.value)
-    assert "partial capture" in str(ei.value)
+def test_a_capture_with_no_device_operation_says_so(tmp_path):
+    """A CPU run's capture has host events only."""
+    sx.write(tmp_path / "cpu.xplane.pb", {"/host:CPU": {"python": [
+        ("device_step", None, 0, 10)]}})
+    with pytest.raises(SystemExit, match="holds no device operation"):
+        trace_summary.main([str(tmp_path)])

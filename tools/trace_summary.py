@@ -1,110 +1,90 @@
-"""Summarize a jax.profiler trace: top ops by accumulated duration.
+"""Where a profiled window's device time went, by the program's own names.
 
-The per-op breakdown the MFU hunt needs (SURVEY.md §5.1) without opening
-TensorBoard/Perfetto: point it at a `BENCH_PROFILE=<dir>` output or a
-trainer `profile_steps` window (`<output_dir>/profile`) and it aggregates
-the Chrome-trace complete events from the newest capture.
+Point it at a capture the program wrote: the trainer's `profile_steps`
+window (`<output_dir>/profile`) or `tools/serve.py`'s profiler output. It
+reads the newest `.xplane.pb` under the directory with the benchmark's own
+reduction (benchmark/xplane.py, benchmark/scopes.py), so the numbers are the
+ones the per-layer metrics report:
+
+- busy and idle share of the traced window, mean over the chips;
+- time by class (forward / recompute / backward / weight-gradient /
+  optimizer / hand-off / other) and by leaf scope of the vocabulary in
+  llama_pipeline_parallel_tpu/utils/trace.py, as shares of busy time;
+- the operations with the most device time of their own;
+- the longest idle gaps, each with the host event that covers most of it
+  (the serving tick's `serve_tick_*` annotations, the trainer's spans).
 
 Usage:
-  python tools/trace_summary.py <trace_dir> [--top 15] [--track SUBSTR]
-
-`--track` filters to processes whose name contains SUBSTR (e.g. "TPU" to
-see only device tracks; default keeps every track and prints each track's
-total so device vs host time is visible side by side).
+  python tools/trace_summary.py <trace_dir> [--top 15]
 """
 
 from __future__ import annotations
 
 import argparse
-import collections
-import glob
-import gzip
-import json
 import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark import scopes, xplane  # noqa: E402
 
 
-def load_latest_trace(trace_dir: str) -> tuple[str, dict]:
-    """Newest capture under `trace_dir`, gzipped or plain (some exporters
-    and hand-saved Perfetto sessions write uncompressed *.trace.json).
-    A missing capture raises FileNotFoundError; an unreadable or torn one
-    (killed mid-capture) raises SystemExit with a readable message — the
-    CLI prints it instead of a traceback."""
-    paths = sorted(
-        glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                  recursive=True)
-        + glob.glob(os.path.join(trace_dir, "**", "*.trace.json"),
-                    recursive=True),
-        key=os.path.getmtime)
-    if not paths:
-        raise FileNotFoundError(
-            f"no *.trace.json.gz (or *.trace.json) under {trace_dir} (is "
-            f"this a jax.profiler output dir? expected "
-            f"plugins/profile/<ts>/*.trace.json.gz)")
-    path = paths[-1]
-    opener = gzip.open if path.endswith(".gz") else open
-    try:
-        with opener(path, "rt") as f:
-            trace = json.load(f)
-    except (OSError, ValueError) as e:
+def summarize(path: str, top: int = 15) -> dict:
+    """The summary as data: what `main` prints."""
+    trace = xplane.read(path)
+    if not any(trace["devices"].values()):
         raise SystemExit(
-            f"could not parse trace capture {path}: {e}\n(partial capture "
-            f"from an interrupted profile window? delete it and re-capture)")
-    if not isinstance(trace, dict):
-        raise SystemExit(f"trace capture {path} is not a Chrome-trace JSON "
-                         f"object (got {type(trace).__name__})")
-    return path, trace
+            f"{path} holds no device operation (planes named "
+            f"{xplane.DEVICE_PREFIX}<n>, line {xplane.OPS_LINE!r}): a capture "
+            f"of a CPU run has host events only")
+    scoped = scopes.read(path)
+    busy_s, window_s = xplane.busy_and_window(trace)
+    by_leaf = scopes.leaf_shares(scoped)
+    return {
+        "chips": len(trace["devices"]),
+        "window_s": window_s, "busy_s": busy_s,
+        "idle_percent": 100.0 * (1.0 - busy_s / window_s),
+        "by_class": scopes.class_shares(scoped),
+        "by_scope": by_leaf,
+        "scoped_percent": sum(v for k, v in by_leaf.items()
+                              if k != "(no scope)"),
+        "top_ops": xplane.top_ops(trace, top),
+        "idle_gaps": xplane.idle_gaps(trace, top),
+    }
 
 
-def summarize(trace: dict, track_filter: str | None = None):
-    """-> (per-track total us, per-track op->us Counter, per-track op->count
-    Counter)."""
-    proc_names: dict = {}
-    for e in trace.get("traceEvents", []):
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            proc_names[e.get("pid")] = e.get("args", {}).get(
-                "name", str(e.get("pid")))
-
-    track_total: collections.Counter = collections.Counter()
-    op_dur: dict = collections.defaultdict(collections.Counter)
-    op_count: dict = collections.defaultdict(collections.Counter)
-    for e in trace.get("traceEvents", []):
-        if e.get("ph") != "X":
-            continue
-        track = proc_names.get(e.get("pid"), str(e.get("pid")))
-        if track_filter and track_filter.lower() not in track.lower():
-            continue
-        dur = float(e.get("dur", 0.0))
-        name = e.get("name", "?")
-        track_total[track] += dur
-        op_dur[track][name] += dur
-        op_count[track][name] += 1
-    return track_total, op_dur, op_count
+def _table(title: str, rows: dict) -> None:
+    print(f"\n== {title} (% of busy time) ==")
+    for name, share in sorted(rows.items(), key=lambda kv: -kv[1]):
+        print(f"  {share:6.2f}%  {name}")
 
 
 def main(argv: list[str] | None = None) -> None:
-    p = argparse.ArgumentParser(description=__doc__)
+    p = argparse.ArgumentParser(description=__doc__,
+                                formatter_class=argparse.RawTextHelpFormatter)
     p.add_argument("trace_dir")
-    p.add_argument("--top", type=int, default=15)
-    p.add_argument("--track", default=None,
-                   help="only tracks whose process name contains this")
+    p.add_argument("--top", type=int, default=15,
+                   help="operations and idle gaps to list")
     args = p.parse_args(argv)
 
-    try:
-        path, trace = load_latest_trace(args.trace_dir)
-    except FileNotFoundError as e:
-        # empty/wrong dir: a readable verdict, not a traceback
-        raise SystemExit(str(e))
+    path = xplane.find_xplane(args.trace_dir)
+    if path is None:
+        raise SystemExit(
+            f"no .xplane.pb under {args.trace_dir} (is this a jax.profiler "
+            f"output dir? expected plugins/profile/<time>/*.xplane.pb)")
+    s = summarize(path, args.top)
     print(f"trace: {path}")
-    track_total, op_dur, op_count = summarize(trace, args.track)
-    if not track_total:
-        raise SystemExit("no complete events matched "
-                         f"(--track {args.track!r}); try without --track")
-    for track, total in sorted(track_total.items(), key=lambda kv: -kv[1]):
-        print(f"\n== {track}: {total / 1e3:.2f} ms total ==")
-        for name, dur in op_dur[track].most_common(args.top):
-            pct = 100 * dur / total if total else 0.0
-            print(f"  {dur / 1e3:10.2f} ms  {pct:5.1f}%  "
-                  f"x{op_count[track][name]:<5d} {name}")
+    print(f"{s['chips']} chip(s), window {s['window_s']:.4f} s, busy "
+          f"{s['busy_s']:.4f} s, idle {s['idle_percent']:.3f}%; "
+          f"{s['scoped_percent']:.1f}% of busy time under a named scope")
+    _table("by class", s["by_class"])
+    _table("by scope", s["by_scope"])
+    print("\n== operations with the most device time of their own ==")
+    for name, seconds in s["top_ops"]:
+        print(f"  {1e3 * seconds:10.3f} ms  {name}")
+    print("\n== longest idle gaps, by the host event over them ==")
+    for name, seconds in s["idle_gaps"]:
+        print(f"  {1e3 * seconds:10.3f} ms  {name}")
 
 
 if __name__ == "__main__":
