@@ -422,6 +422,17 @@ def write_slot(cache: dict, kv_mask: jnp.ndarray, slot: jnp.ndarray,
 # ever contribute through masked positions, whose scores are the same
 # NEG_INF constant and whose softmax weights are exactly 0.0).
 #
+# How the pool is walked: the three paged programs scan over (layer weights,
+# layer index) only and CARRY the whole pool through `_walk_pool`. A layer's
+# write is one scatter into the full pool at `[layer, page, offset]`, its
+# read one gather whose indices hold the layer too (`pool[layer,
+# page_table]`), so no slice of a whole layer's pages stands between the
+# pool and the work. The pool is never the scan's `xs`/`ys`: a scan's `ys`
+# is a fresh stacked array that a donated argument cannot alias, which cost
+# a layer-sized slice in, a layer-sized store out and a whole-pool copy
+# every tick (38% of the tick's busy time on the v5e, PERF.md PR 25). As a
+# carry of a donated argument the loop updates the one buffer in place.
+#
 # int8 pages (`quant="int8"`) store one fp32 scale per (layer, page,
 # kv_head): prefill writes whole pages and set the scale from the block
 # absmax; decode writes claim a fresh page at offset 0 (pages fill in
@@ -472,21 +483,55 @@ def _block_amax(x: jnp.ndarray, axes) -> jnp.ndarray:
                        _SCALE_FLOOR)
 
 
-def _gather_pages(pool_k, pool_v, sc_k, sc_v, page_table: jnp.ndarray,
-                  dtype):
-    """Reconstitute logical kv rows from the pool: [*, Pmax] page indices ->
-    [*, Pmax * page_size, kv_h, hd] in the compute dtype."""
+def _gather_pages(pool: dict, layer_idx, page_table: jnp.ndarray, dtype):
+    """Reconstitute logical kv rows of layer `layer_idx` from the full pool:
+    [*, Pmax] page indices -> [*, Pmax * page_size, kv_h, hd] in the compute
+    dtype. ONE gather per array, the layer among its indices."""
     with jax.named_scope(trace.SCOPE_KV_GATHER):
-        gk = pool_k[page_table]
-        gv = pool_v[page_table]
-        if sc_k is not None:
-            gk = dequant_page_block(gk, sc_k[page_table][..., None, :, None],
-                                    dtype)
-            gv = dequant_page_block(gv, sc_v[page_table][..., None, :, None],
-                                    dtype)
+        gk = pool["k"][layer_idx, page_table]
+        gv = pool["v"][layer_idx, page_table]
+        if "k_scale" in pool:
+            sk = pool["k_scale"][layer_idx, page_table]
+            sv = pool["v_scale"][layer_idx, page_table]
+            gk = dequant_page_block(gk, sk[..., None, :, None], dtype)
+            gv = dequant_page_block(gv, sv[..., None, :, None], dtype)
         *lead, pmax, page, kvh, hd = gk.shape
         return (gk.reshape(*lead, pmax * page, kvh, hd),
                 gv.reshape(*lead, pmax * page, kvh, hd))
+
+
+def _walk_pool(params: Params, x: jnp.ndarray, pool: dict,
+               page_table: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
+               cfg: LlamaConfig, write, attend) -> tuple[jnp.ndarray, dict]:
+    """Run the cached layers over the page pool IN PLACE: the one way the
+    paged programs walk it. The scan's `xs` are the layer weights and the
+    layer index; the pool (k, v and, for int8, their scales) rides in the
+    carry and is only ever touched through full-pool scatters and gathers.
+
+    Per layer, in the order every cached layer runs: q/k/v projection,
+    `write(pages, scales, layer_idx, rows) -> (pages, scales)` once for k
+    and once for v (`scales` is None for fp pools; `rows` is [b, s, kv_h,
+    hd]), the gather of `page_table`'s logical rows, `attend(q, gk, gv)`,
+    output projection and MLP. Returns the hidden state and the pool."""
+    def body(carry, xs):
+        h, pool = carry
+        layer, i = xs
+        q, k, v = _project_qkv(layer, h, cos, sin, cfg)
+        with jax.named_scope(trace.SCOPE_KV_WRITE):
+            pool = dict(pool)
+            for name, rows in (("k", k), ("v", v)):
+                pool[name], scales = write(
+                    pool[name], pool.get(f"{name}_scale"), i, rows)
+                if scales is not None:
+                    pool[f"{name}_scale"] = scales
+        gk, gv = _gather_pages(pool, i, page_table, cfg.dtype)
+        with jax.named_scope(trace.SCOPE_DECODE_ATTN):
+            attn_out = attend(q, gk, gv)
+        return (_attn_out_and_mlp(layer, h, attn_out, cfg), pool), None
+
+    (x, pool), _ = jax.lax.scan(
+        body, (x, pool), (params["layers"], jnp.arange(pool["k"].shape[0])))
+    return x, pool
 
 
 @partial(jax.jit, donate_argnames=("pool", "kv_mask"))
@@ -558,42 +603,28 @@ def set_kv_mask_row(kv_mask: jnp.ndarray, slot: jnp.ndarray,
                                         (slot, 0))
 
 
-def _paged_write_token(pool_k, sc_k, x1: jnp.ndarray, w_page: jnp.ndarray,
-                       w_off: jnp.ndarray):
-    """Scatter one token's kv rows ([b, kv_h, hd]) into their pages. int8:
-    offset 0 claims the page and sets its scale from this token's absmax
-    (pages fill in strict logical order, so offset 0 == a fresh page);
-    later offsets saturate against the existing scale."""
-    if sc_k is None:
-        return pool_k.at[w_page, w_off].set(x1), None
-    amax = _block_amax(x1, axes=-1)                            # [b, kvh]
-    scale = jnp.where((w_off == 0)[:, None], amax,
-                      jnp.maximum(sc_k[w_page], _SCALE_FLOOR))
-    sc_k = sc_k.at[w_page].set(scale)
-    pool_k = pool_k.at[w_page, w_off].set(
-        quant_page_block(x1, scale[:, :, None]))
-    return pool_k, sc_k
-
-
-def _layer_decode_paged(layer: Params, x: jnp.ndarray, pool_k, pool_v,
-                        sc_k, sc_v, page_table: jnp.ndarray,
-                        w_page: jnp.ndarray, w_off: jnp.ndarray,
-                        kv_mask: jnp.ndarray, cos: jnp.ndarray,
-                        sin: jnp.ndarray, cfg: LlamaConfig):
-    """`_layer_decode_rowwise` over the page pool: write this token's kv
-    into (w_page, w_off), gather each slot's logical row from its pages,
-    attend mask-gated — same arithmetic, paged residency."""
-    q, k, v = _project_qkv(layer, x, cos, sin, cfg)
-
-    with jax.named_scope(trace.SCOPE_KV_WRITE):
-        pool_k, sc_k = _paged_write_token(pool_k, sc_k, k[:, 0], w_page, w_off)
-        pool_v, sc_v = _paged_write_token(pool_v, sc_v, v[:, 0], w_page, w_off)
-    gk, gv = _gather_pages(pool_k, pool_v, sc_k, sc_v, page_table, cfg.dtype)
-
-    with jax.named_scope(trace.SCOPE_DECODE_ATTN):
-        attn_out = attention(q, gk, gv, kv_mask, causal=False)
-    return (_attn_out_and_mlp(layer, x, attn_out, cfg), pool_k, pool_v,
-            sc_k, sc_v)
+def _write_tokens(pages, scales, layer_idx, rows: jnp.ndarray,
+                  w_page: jnp.ndarray, w_off: jnp.ndarray,
+                  claimed: jnp.ndarray, claimer=None):
+    """Scatter n tokens' kv rows ([n, kv_h, hd]) into (layer_idx, w_page[n],
+    w_off[n]) of the full pool. int8: a token in a page this write CLAIMS
+    (`claimed`: [n, 1] bool; pages fill in strict logical order, so a
+    page's offset 0 is its first write) takes the page's new scale, the
+    absmax of token `claimer[n]` (None: its own); every other token
+    saturates against the scale its page already has. Duplicate pages in
+    one scatter all carry the same scale, so write order within it cannot
+    matter."""
+    if scales is None:
+        return pages.at[layer_idx, w_page, w_off].set(rows), None
+    amax = _block_amax(rows, axes=-1)                          # [n, kvh]
+    if claimer is not None:
+        amax = amax[claimer]
+    scale = jnp.where(claimed, amax,
+                      jnp.maximum(scales[layer_idx, w_page], _SCALE_FLOOR))
+    scales = scales.at[layer_idx, w_page].set(scale)
+    pages = pages.at[layer_idx, w_page, w_off].set(
+        quant_page_block(rows, scale[:, :, None]))
+    return pages, scales
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -616,7 +647,9 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     [S, pages_per_slot * page_size] == [S, max_len], so the fp path is
     token-bit-exact against the dense `decode_step` (pinned in
     tests/test_paged_serving.py); int8 pools dequantize on read and are
-    tolerance-gated instead."""
+    tolerance-gated instead. Each layer writes this token's kv into
+    (layer, w_page, w_off) and gathers each slot's logical row from its
+    pages, in place (`_walk_pool`)."""
     b = token.shape[0]
     page = pool["k"].shape[2]
     garbage = pool["k"].shape[1] - 1
@@ -632,33 +665,56 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     x = llama.embed(params, token[:, None], cfg)
     cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta,
                             dtype=cfg.dtype)
-    quant = pool["k"].dtype == jnp.int8
-    xs = ((params["layers"], pool["k"], pool["v"], pool["k_scale"],
-           pool["v_scale"]) if quant
-          else (params["layers"], pool["k"], pool["v"]))
 
-    def body(h, xs):
-        if quant:
-            layer, pk, pv, sk, sv = xs
-        else:
-            (layer, pk, pv), sk, sv = xs, None, None
-        h, pk, pv, sk, sv = _layer_decode_paged(
-            layer, h, pk, pv, sk, sv, page_table, w_page, w_off, kv_mask,
-            cos, sin, cfg)
-        return h, ((pk, pv, sk, sv) if quant else (pk, pv))
+    def write(pages, scales, i, rows):
+        # a decode write at offset 0 claims a fresh page with its own absmax
+        return _write_tokens(pages, scales, i, rows[:, 0], w_page, w_off,
+                             claimed=(w_off == 0)[:, None])
 
-    x, new = jax.lax.scan(body, x, xs)
+    def attend(q, gk, gv):
+        return attention(q, gk, gv, kv_mask, causal=False)
+
+    x, pool = _walk_pool(params, x, pool, page_table, cos, sin, cfg, write,
+                         attend)
     x = llama.final_norm(params, x, cfg)
     logits = llama.lm_head(params, x, cfg)[:, -1, :]
 
     with jax.named_scope(trace.SCOPE_SAMPLE):
         split = jax.vmap(jax.random.split)(keys)        # [b, 2, 2]
         nxt = sample_rowwise(logits, temperature, top_k, top_p, split[:, 1])
-    new_pool = {"k": new[0], "v": new[1]}
-    if quant:
-        new_pool["k_scale"], new_pool["v_scale"] = new[2], new[3]
-    return {"token": nxt, "pool": new_pool, "kv_mask": kv_mask,
+    return {"token": nxt, "pool": pool, "kv_mask": kv_mask,
             "keys": split[:, 0]}
+
+
+def _prefill_slot_row(params: Params, input_ids: jnp.ndarray,
+                      attention_mask: jnp.ndarray, positions: jnp.ndarray,
+                      pool: dict, page_table_row: jnp.ndarray,
+                      slot: jnp.ndarray, kv_mask: jnp.ndarray,
+                      write_start: jnp.ndarray, cfg: LlamaConfig,
+                      write) -> dict:
+    """What the chunk and the span prefill share: mark [write_start,
+    write_start + C) of logical row `slot` valid, run the cached layers over
+    the pool with the caller's `write`, each position attending the slot's
+    FULL gathered row with a causal offset, and return the LAST position's
+    fp32 logits."""
+    mask = attention_mask.astype(jnp.int32)
+    kv_mask = jax.lax.dynamic_update_slice(kv_mask, mask, (slot, write_start))
+    lmax = kv_mask.shape[1]
+    row_mask = jax.lax.dynamic_slice(kv_mask, (slot, 0), (1, lmax))
+
+    x = llama.embed(params, input_ids, cfg)
+    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
+                            dtype=cfg.dtype)
+
+    def attend(q, gk, gv):
+        return attention(q, gk, gv, row_mask, causal=True,
+                         q_offset=write_start)
+
+    x, pool = _walk_pool(params, x, pool, page_table_row[None], cos, sin, cfg,
+                         write, attend)
+    x = llama.final_norm(params, x[:, -1:, :], cfg)
+    logits = llama.lm_head(params, x, cfg)
+    return {"logits": logits[:, -1], "pool": pool, "kv_mask": kv_mask}
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -679,59 +735,21 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
     are consumed, to sample the request's first token)."""
     _, C = input_ids.shape
     page = pool["k"].shape[2]
-    hd = cfg.head_dim
-    dt = cfg.dtype
-    quant = pool["k"].dtype == jnp.int8
-
-    mask = attention_mask.astype(jnp.int32)
-    kv_mask = jax.lax.dynamic_update_slice(kv_mask, mask, (slot, write_start))
-    lmax = kv_mask.shape[1]
-    row_mask = jax.lax.dynamic_slice(kv_mask, (slot, 0), (1, lmax))
-
     chunk_pages = page_table_row[write_start // page +
                                  jnp.arange(C // page)]  # [C/page] physical
 
-    x = llama.embed(params, input_ids, cfg)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                            dtype=cfg.dtype)
-    xs = ((params["layers"], pool["k"], pool["v"], pool["k_scale"],
-           pool["v_scale"]) if quant
-          else (params["layers"], pool["k"], pool["v"]))
+    def write(pages, scales, i, rows):
+        # whole pages: [C / page, page, kv_h, hd] blocks, scale = block absmax
+        blocks = rows[0].reshape(C // page, page, -1, cfg.head_dim)
+        if scales is not None:
+            scale = _block_amax(blocks, axes=(1, 3))           # [C/page, kvh]
+            scales = scales.at[i, chunk_pages].set(scale)
+            blocks = quant_page_block(blocks, scale[:, None, :, None])
+        return pages.at[i, chunk_pages].set(blocks), scales
 
-    def body(h, xs):
-        if quant:
-            layer, pk, pv, sk, sv = xs
-        else:
-            (layer, pk, pv), sk, sv = xs, None, None
-        q, k, v = _project_qkv(layer, h, cos, sin, cfg)
-
-        with jax.named_scope(trace.SCOPE_KV_WRITE):
-            kb = k[0].reshape(C // page, page, -1, hd)
-            vb = v[0].reshape(C // page, page, -1, hd)
-            if quant:
-                ks = _block_amax(kb, axes=(1, 3))             # [C/page, kvh]
-                vs = _block_amax(vb, axes=(1, 3))
-                sk = sk.at[chunk_pages].set(ks)
-                sv = sv.at[chunk_pages].set(vs)
-                kb = quant_page_block(kb, ks[:, None, :, None])
-                vb = quant_page_block(vb, vs[:, None, :, None])
-            pk = pk.at[chunk_pages].set(kb)
-            pv = pv.at[chunk_pages].set(vb)
-
-        gk, gv = _gather_pages(pk, pv, sk, sv, page_table_row[None], dt)
-        with jax.named_scope(trace.SCOPE_DECODE_ATTN):
-            attn_out = attention(q, gk, gv, row_mask, causal=True,
-                                 q_offset=write_start)
-        h = _attn_out_and_mlp(layer, h, attn_out, cfg)
-        return h, ((pk, pv, sk, sv) if quant else (pk, pv))
-
-    x, new = jax.lax.scan(body, x, xs)
-    x = llama.final_norm(params, x[:, -1:, :], cfg)
-    logits = llama.lm_head(params, x, cfg)
-    new_pool = {"k": new[0], "v": new[1]}
-    if quant:
-        new_pool["k_scale"], new_pool["v_scale"] = new[2], new[3]
-    return {"logits": logits[:, -1], "pool": new_pool, "kv_mask": kv_mask}
+    return _prefill_slot_row(params, input_ids, attention_mask, positions,
+                             pool, page_table_row, slot, kv_mask, write_start,
+                             cfg, write)
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -758,13 +776,6 @@ def paged_prefill_span(params: Params, input_ids: jnp.ndarray,
     cache-hit tail is exactly the work the hit did NOT save."""
     _, C = input_ids.shape
     page = pool["k"].shape[2]
-    dt = cfg.dtype
-    quant = pool["k"].dtype == jnp.int8
-
-    mask = attention_mask.astype(jnp.int32)
-    kv_mask = jax.lax.dynamic_update_slice(kv_mask, mask, (slot, write_start))
-    lmax = kv_mask.shape[1]
-    row_mask = jax.lax.dynamic_slice(kv_mask, (slot, 0), (1, lmax))
 
     w_pos = write_start + jnp.arange(C)              # [C] logical positions
     w_page = page_table_row[w_pos // page]           # [C] physical pages
@@ -776,50 +787,11 @@ def paged_prefill_span(params: Params, input_ids: jnp.ndarray,
     in_span = (first_idx >= 0)[:, None]              # [C, 1]
     first_idx = jnp.clip(first_idx, 0, C - 1)
 
-    x = llama.embed(params, input_ids, cfg)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                            dtype=cfg.dtype)
-    xs = ((params["layers"], pool["k"], pool["v"], pool["k_scale"],
-           pool["v_scale"]) if quant
-          else (params["layers"], pool["k"], pool["v"]))
+    def write(pages, scales, i, rows):
+        # a claimed page takes the absmax of its offset-0 token
+        return _write_tokens(pages, scales, i, rows[0], w_page, w_off,
+                             claimed=in_span, claimer=first_idx)
 
-    def write(pk, sk, kv):
-        # kv: [C, kv_h, hd] — the span's freshly computed k or v rows
-        if sk is None:
-            return pk.at[w_page, w_off].set(kv), None
-        amax = _block_amax(kv, axes=-1)                        # [C, kvh]
-        # duplicate page indices in the scatter below all carry the SAME
-        # scale value (claimed pages: their offset-0 token's absmax;
-        # entered-mid-page pages: the existing scale), so write order
-        # within the scatter cannot matter
-        scale = jnp.where(in_span, amax[first_idx],
-                          jnp.maximum(sk[w_page], _SCALE_FLOOR))
-        sk = sk.at[w_page].set(scale)
-        pk = pk.at[w_page, w_off].set(quant_page_block(kv, scale[:, :, None]))
-        return pk, sk
-
-    def body(h, xs):
-        if quant:
-            layer, pk, pv, sk, sv = xs
-        else:
-            (layer, pk, pv), sk, sv = xs, None, None
-        q, k, v = _project_qkv(layer, h, cos, sin, cfg)
-
-        with jax.named_scope(trace.SCOPE_KV_WRITE):
-            pk, sk = write(pk, sk, k[0])
-            pv, sv = write(pv, sv, v[0])
-
-        gk, gv = _gather_pages(pk, pv, sk, sv, page_table_row[None], dt)
-        with jax.named_scope(trace.SCOPE_DECODE_ATTN):
-            attn_out = attention(q, gk, gv, row_mask, causal=True,
-                                 q_offset=write_start)
-        h = _attn_out_and_mlp(layer, h, attn_out, cfg)
-        return h, ((pk, pv, sk, sv) if quant else (pk, pv))
-
-    x, new = jax.lax.scan(body, x, xs)
-    x = llama.final_norm(params, x[:, -1:, :], cfg)
-    logits = llama.lm_head(params, x, cfg)
-    new_pool = {"k": new[0], "v": new[1]}
-    if quant:
-        new_pool["k_scale"], new_pool["v_scale"] = new[2], new[3]
-    return {"logits": logits[:, -1], "pool": new_pool, "kv_mask": kv_mask}
+    return _prefill_slot_row(params, input_ids, attention_mask, positions,
+                             pool, page_table_row, slot, kv_mask, write_start,
+                             cfg, write)
