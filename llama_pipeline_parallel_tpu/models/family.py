@@ -42,6 +42,10 @@ class ServingFamily:
     # whose tree is saved as it stands; None: the dense decoder, whose
     # loader undoes the trainer's pipeline stacking
     init_params: Callable | None = None
+    # (params, cfg) -> the same tree with the leaves this family's programs
+    # convert at every use already converted, made once when an engine is
+    # built; None: the tree is served in the dtype it is stored in
+    serving_weights: Callable | None = None
     # the dense slot cache, chunked / span prefill and the prefix cache's
     # row and page edits: None where the family cannot run them
     decode_step: Callable | None = None
@@ -95,6 +99,7 @@ def _llama() -> ServingFamily:
         name="llama", prefill_prompt=decode.prefill_prompt,
         paged_decode_step=decode.paged_decode_step,
         write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
+        serving_weights=decode.serving_weights,
         decode_step=decode.decode_step, init_kv_cache=decode.init_kv_cache,
         write_slot=decode.write_slot,
         paged_prefill_chunk=decode.paged_prefill_chunk,

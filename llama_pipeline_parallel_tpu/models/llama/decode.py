@@ -294,6 +294,41 @@ def generate(params: Params, input_ids: jnp.ndarray, attention_mask: jnp.ndarray
 # generate()'s — serve/engine.py leans on that for its token-parity contract.
 
 
+# The leaves every program below converts to `cfg.dtype` where it uses them
+# (`llama.cast_weight`): the table, a layer's seven products, the head. The
+# norm scales are used in float32 (ops/rmsnorm.py) and are not among them.
+_CAST_AT_USE = (("embed", "embedding"),
+                *(("layers", "attn", w) for w in ("wq", "wk", "wv", "wo")),
+                *(("layers", "mlp", w) for w in ("gate", "up", "down")),
+                ("lm_head",))
+
+
+@partial(jax.jit, static_argnames=("dtype",))
+def _cast_leaves(leaves: list, dtype) -> list:
+    return [llama.cast_weight(x, dtype) for x in leaves]
+
+
+def serving_weights(params: Params, cfg: LlamaConfig) -> Params:
+    """`params` as an engine holds them: the `_CAST_AT_USE` leaves converted
+    to `cfg.dtype` once, by one program, every other leaf the caller's own
+    array. `cast_weight` is no operation on a leaf already in `cfg.dtype`,
+    so the serving programs given this tree compile without the converts and
+    compute what they computed: `astype` of the same value gives the same
+    value whenever it runs. A tree with nothing to convert comes back as it
+    is. The caller's arrays are neither donated nor deleted."""
+    dtype = jnp.dtype(cfg.dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [x for _, x in flat]
+    todo = [i for i, (path, x) in enumerate(flat)
+            if tuple(k.key for k in path) in _CAST_AT_USE
+            and jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != dtype]
+    if not todo:
+        return params
+    for i, x in zip(todo, _cast_leaves([leaves[i] for i in todo], dtype)):
+        leaves[i] = x
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 @partial(jax.jit, static_argnames=("cfg", "max_len"))
 def prefill_prompt(params: Params, input_ids: jnp.ndarray,
                    attention_mask: jnp.ndarray, cfg: LlamaConfig,

@@ -327,6 +327,9 @@ class ServeEngine:
         supplies the prefill and tick programs, and what it cannot run yet
         (a model with recurrent layers: prefix cache, chunked and span
         prefill, int8 pages, the dense cache) is refused here, by name.
+        The engine calls every program with `self.params`: the leaves the
+        family's programs would convert to the compute dtype at each use,
+        converted once here (`_serving_weights`), the rest `params`' own.
 
         Observatory hooks (docs/OBSERVABILITY.md): `timeline` (a
         utils/timeline.TimelineWriter) gets one record per engine tick —
@@ -340,13 +343,13 @@ class ServeEngine:
         one span tree per request written to request_trace.jsonl at
         completion (docs/SERVING.md "Request tracing"); None (the
         default) keeps every per-token path free of tracing work."""
-        self.params = params
         self.cfg = cfg
         self.serve_cfg = serve_cfg
         self._family = family_of(cfg)
         self._family.check_serve_config(
             serve_cfg.kv_cache, serve_cfg.kv_quant,
             serve_cfg.prefill_chunk_tokens, serve_cfg.prefix_cache)
+        self.params = self._serving_weights(params)
         self._paged = serve_cfg.kv_cache == "paged"
         self._prefix = self._paged and serve_cfg.prefix_cache
         if self._paged:
@@ -399,6 +402,31 @@ class ServeEngine:
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
         self._tick_counters = dict.fromkeys(self._family.counters, 0)
+
+    def _serving_weights(self, params: dict) -> dict:
+        """The tree every program of this engine is called with: the
+        family's `serving_weights` where it states one (the leaves its
+        programs would convert at every use, converted here once), else
+        the caller's tree itself. The caller's arrays stay the caller's:
+        one who keeps a float32 tree alive pays for both. Recorded as one
+        `serve_weights_cast` span (docs/OBSERVABILITY.md)."""
+        with trace.span("serve_weights_cast") as rec:
+            held = params
+            if self._family.serving_weights is not None:
+                held = jax.block_until_ready(
+                    self._family.serving_weights(params, self.cfg))
+            given_leaves, held_leaves = (jax.tree.leaves(t)
+                                         for t in (params, held))
+            cast = sum(a is not b for a, b in zip(given_leaves, held_leaves))
+            rec.update(
+                leaves_cast=cast, leaves_kept=len(given_leaves) - cast,
+                bytes_given=sum(x.nbytes for x in given_leaves),
+                bytes_held=sum(x.nbytes for x in held_leaves))
+        logger.info(
+            "serve weights: %d leaves cast, %d kept as given; %.3f -> %.3f "
+            "GB in %.3f s", rec["leaves_cast"], rec["leaves_kept"],
+            rec["bytes_given"] / 1e9, rec["bytes_held"] / 1e9, rec["dur"])
+        return held
 
     # -- submission (any thread) ------------------------------------------
 

@@ -210,6 +210,10 @@ def main(argv: list[str] | None = None) -> int:
     engine = ServeEngine(params, cfg, serve_cfg, metrics_writer=writer,
                          timeline=tl_writer, profiler=prof, slo=slo,
                          reqtrace=reqtrace_rec)
+    # the engine holds what it serves from (the matmul weights and the table
+    # in the compute dtype, converted once); nothing below reads the loaded
+    # float32 tree, and keeping it would keep its bytes on the chip
+    del params
 
     server = make_server(engine, args.host, args.port)
     port = server.server_address[1]
