@@ -798,6 +798,11 @@ def main() -> None:
         # continuous-batching engine (serve/engine.py), i.e. the numbers
         # docs/SERVING.md's SLOs are made of.
         if os.environ.get("BENCH_SERVING", "1") != "0" and row_budget.allow("serve"):
+            # Paged-KV rows (docs/SERVING.md "Paged KV cache"): steady-state
+            # decode through the engine (fp and int8 pages), each row
+            # carrying the pool-vs-reservation resident byte model NEXT to
+            # the measured per-token time, so one live TPU run lands the
+            # int8 capacity doubling as a measured delta.
             try:
                 from llama_pipeline_parallel_tpu.models.llama.decode import (
                     GenerationConfig,
@@ -807,59 +812,6 @@ def main() -> None:
                     ServeEngine,
                     ServeRequest,
                 )
-
-                slots = int(os.environ.get("BENCH_SERVE_SLOTS", "8"))
-                p_len = min(128, seq)
-                decode_steps = int(os.environ.get("BENCH_SERVE_STEPS", "32"))
-                budget = decode_steps + 8  # no row finishes mid-timing
-                eng = ServeEngine(
-                    pl.unstack_stages(stacked, manifest), cfg,
-                    ServeConfig(max_slots=slots, max_len=p_len + budget + 1,
-                                prompt_buckets=(p_len,),
-                                max_queue=4 * slots))
-                rs = np.random.RandomState(0)
-                prompt = rs.randint(3, cfg.vocab_size, (p_len,)).tolist()
-
-                def req(n):
-                    return ServeRequest(input_ids=prompt,
-                                        gen=GenerationConfig(max_new_tokens=n))
-
-                # warmup: compile prefill + decode_step off the clock
-                eng.submit(req(2))
-                eng.drain(timeout_s=600)
-                # TTFT: one cold request against a warm engine
-                eng.submit(req(2))
-                eng.drain(timeout_s=600)
-                ttft = eng.stats.ttft[-1]
-                results[f"extra:serve-ttft,p={p_len}"] = {
-                    "dt": ttft, "tokens_per_step": p_len, "headline": False,
-                    "detail": {"ttft_ms": round(1000 * ttft, 2)}}
-                # steady-state decode: all slots occupied, timed ticks
-                for _ in range(slots):
-                    eng.submit(req(budget))
-                eng.step()  # admissions + first tick
-                t0 = time.perf_counter()
-                for _ in range(decode_steps):
-                    eng.step()
-                dt = (time.perf_counter() - t0) / decode_steps
-                results[f"extra:serve-decode,bs={slots}"] = {
-                    "dt": dt, "tokens_per_step": slots, "headline": False,
-                    "detail": {"per_token_ms": round(1000 * dt / slots, 3),
-                               "step_ms": round(1000 * dt, 2),
-                               "slots": slots}}
-                eng.shutdown()
-            except Exception as e:
-                failed("serving rows", e)
-
-            # Paged-KV rows (docs/SERVING.md "Paged KV cache"): the SAME
-            # steady-state decode measurement through the paged engine (fp
-            # and int8 pages), each row carrying the pool-vs-dense resident
-            # byte model NEXT to the measured per-token time — the dense
-            # `extra:serve-decode` row above is the twin, so one live TPU
-            # run lands the paged-gather cost and the int8 capacity
-            # doubling as measured deltas. Separate try: a paged failure
-            # must not eat the dense rows already recorded.
-            try:
                 from llama_pipeline_parallel_tpu.serve.pages import (
                     dense_kv_cache_bytes,
                     paged_pool_bytes,
@@ -874,7 +826,6 @@ def main() -> None:
                 # drop these rows)
                 p_len = max(page, min(128, seq) // page * page)
                 max_len_p = -(-(p_len + budget + 1) // page) * page
-                dense_twin = results.get(f"extra:serve-decode,bs={slots}")
                 dense_mib = dense_kv_cache_bytes(cfg, slots,
                                                  max_len_p) / (1 << 20)
                 rs = np.random.RandomState(0)
@@ -883,7 +834,7 @@ def main() -> None:
                     scfg = ServeConfig(
                         max_slots=slots, max_len=max_len_p,
                         prompt_buckets=(p_len,), max_queue=4 * slots,
-                        kv_cache="paged", page_size=page, kv_quant=quant)
+                        page_size=page, kv_quant=quant)
                     eng = ServeEngine(pl.unstack_stages(stacked, manifest),
                                       cfg, scfg)
                     for _ in range(slots):
@@ -906,9 +857,6 @@ def main() -> None:
                             quant) / (1 << 20), 2),
                         "dense_cache_mib": round(dense_mib, 2),
                         "kv_quant": quant}
-                    if dense_twin is not None:
-                        detail["dense_step_ms"] = round(
-                            1000 * dense_twin["dt"], 2)
                     tag = "-int8" if quant == "int8" else ""
                     results[f"extra:serve-paged{tag}-decode,bs={slots}"] = {
                         "dt": dt, "tokens_per_step": slots,
@@ -940,7 +888,7 @@ def main() -> None:
                     ServeConfig(
                         max_slots=4, max_len=max_len_t,
                         prompt_buckets=(p_small, 2 * p_small),
-                        max_queue=4 * n_req, kv_cache="paged",
+                        max_queue=4 * n_req,
                         page_size=16, prefill_chunk_tokens=chunk))
                 trace_reqs = _tr.poisson_trace(0, rate, n_req, prompt_mix,
                                                output_mix)
@@ -1002,7 +950,7 @@ def main() -> None:
                         pl.unstack_stages(stacked, manifest), cfg,
                         ServeConfig(max_slots=4, max_len=bucket + page,
                                     prompt_buckets=(tail, bucket),
-                                    max_queue=4 * n_req, kv_cache="paged",
+                                    max_queue=4 * n_req,
                                     page_size=page, prefix_cache=cache_on))
                     # pay every compile off the clock (full prefill at
                     # both buckets, and — hot — the warm span path), and
@@ -1029,7 +977,7 @@ def main() -> None:
                         ServeConfig(max_slots=4, max_len=bucket + page,
                                     prompt_buckets=(bucket,),
                                     max_queue=16 * pool_pages,
-                                    kv_cache="paged", page_size=page,
+                                    page_size=page,
                                     num_pages=pool_pages,
                                     prefix_cache=cache_on))
                     eng.submit(prefix_req(1))

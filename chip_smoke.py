@@ -449,8 +449,7 @@ def phase_d(checkpoint_dir: str, buckets: tuple = (64, 128),
     # every request's worst-case reservation, so none may be refused
     engine = ServeEngine(params, cfg, ServeConfig(
         max_slots=4, max_len=max_len, prompt_buckets=buckets,
-        kv_cache="paged", page_size=page_size,
-        num_pages=len(lengths) * max_len // page_size))
+        page_size=page_size, num_pages=len(lengths) * max_len // page_size))
     rng = np.random.RandomState(0)
     prompts = [rng.randint(3, cfg.vocab_size, size=n).tolist()
                for n in lengths]

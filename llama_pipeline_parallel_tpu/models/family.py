@@ -1,5 +1,6 @@
-"""What the serving stack asks of a block family: its prefill and tick
-programs, the stores they keep, and what it cannot do yet.
+"""What the serving stack asks of a block family: the programs that run its
+layers over the page pool, the stores they keep, and what it cannot do yet.
+What touches only the mask or the pool's page axis is `serve/pages.py`'s own.
 
 `serve/engine.py` and `serve/pages.py` take these from `family_of(cfg)` and
 call them with the arguments they always passed; they name no family's
@@ -46,16 +47,10 @@ class ServingFamily:
     # convert at every use already converted, made once when an engine is
     # built; None: the tree is served in the dtype it is stored in
     serving_weights: Callable | None = None
-    # the dense slot cache, chunked / span prefill and the prefix cache's
-    # row and page edits: None where the family cannot run them
-    decode_step: Callable | None = None
-    init_kv_cache: Callable | None = None
-    write_slot: Callable | None = None
+    # chunked prefill, and the span prefill that recomputes the tail of a
+    # prefix-cache hit: None where the family's layers cannot run them
     paged_prefill_chunk: Callable | None = None
     paged_prefill_span: Callable | None = None
-    reset_kv_mask_row: Callable | None = None
-    set_kv_mask_row: Callable | None = None
-    copy_page: Callable | None = None
     kv_quants: tuple = ("fp",)
     # names of the int32 counters a tick returns under "counters", in order
     counters: tuple = ()
@@ -64,25 +59,20 @@ class ServingFamily:
     def recurrent(self) -> bool:
         return self.init_recurrent_store is not None
 
-    def check_serve_config(self, kv_cache: str, kv_quant: str,
-                           prefill_chunk_tokens: int,
+    def check_serve_config(self, kv_quant: str, prefill_chunk_tokens: int,
                            prefix_cache: bool) -> None:
         """Refuse, by name, what this family cannot run."""
         why = (f"the {self.name} family keeps a recurrent state per slot "
                f"beside the page pool" if self.recurrent
                else f"the {self.name} family")
         refused = []
-        if kv_cache == "dense" and self.decode_step is None:
-            refused.append("kv_cache: dense (no slot cache holds a recurrent "
-                           "state; use kv_cache: paged)")
         if kv_quant not in self.kv_quants:
             refused.append(f"kv_quant: {kv_quant} (pages are {self.kv_quants} "
                            f"only)")
         if prefill_chunk_tokens and self.paged_prefill_chunk is None:
             refused.append("prefill_chunk_tokens > 0 (chunked prefill would "
                            "have to carry the state from chunk to chunk)")
-        if prefix_cache and (self.paged_prefill_span is None
-                             or self.copy_page is None):
+        if prefix_cache and self.paged_prefill_span is None:
             refused.append("prefix_cache (a shared page holds keys and "
                            "values only: the state at the divergence point "
                            "is not kept, and the span prefill that "
@@ -100,12 +90,8 @@ def _llama() -> ServingFamily:
         paged_decode_step=decode.paged_decode_step,
         write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
         serving_weights=decode.serving_weights,
-        decode_step=decode.decode_step, init_kv_cache=decode.init_kv_cache,
-        write_slot=decode.write_slot,
         paged_prefill_chunk=decode.paged_prefill_chunk,
         paged_prefill_span=decode.paged_prefill_span,
-        reset_kv_mask_row=decode.reset_kv_mask_row,
-        set_kv_mask_row=decode.set_kv_mask_row, copy_page=decode.copy_page,
         kv_quants=("fp", "int8"))
 
 

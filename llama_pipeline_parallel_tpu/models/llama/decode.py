@@ -282,16 +282,18 @@ def generate(params: Params, input_ids: jnp.ndarray, attention_mask: jnp.ndarray
     return {"tokens": tokens.T, "done": done}
 
 
-# -- continuous-batching entry points (serve/) -------------------------------
+# -- serving entry points (serve/) -------------------------------------------
 #
 # `generate()` owns a whole batch cradle-to-grave: one shared prompt bucket,
-# one scalar write position, cache re-initialized per call. Serving needs the
-# same kernels with the batch axis reinterpreted as SLOTS that requests join
-# and leave independently: the cache is allocated ONCE at [max_slots,
-# max_len], `prefill_prompt` produces a row to splice in, and `decode_step`
+# one scalar write position, a cache made anew per call. Serving runs the same
+# arithmetic with the batch axis reinterpreted as SLOTS that requests join and
+# leave independently, and with the keys and values in a page pool
+# (serve/pages.py) that is allocated once: `prefill_prompt` produces a row,
+# `write_pages` splices it into the slot's pages, and `paged_decode_step`
 # advances every slot one token with PER-ROW write positions, rope positions,
-# rng chains, and sampling knobs. The arithmetic per row is identical to
-# generate()'s — serve/engine.py leans on that for its token-parity contract.
+# rng chains and sampling knobs. The contract (serve/engine.py,
+# tests/test_serving.py): a served request emits the tokens of an independent
+# `generate()` call with its seed, on the prompt left-padded to its bucket.
 
 
 # The leaves every program below converts to `cfg.dtype` where it uses them
@@ -358,104 +360,17 @@ def prefill_prompt(params: Params, input_ids: jnp.ndarray,
             "next_pos": positions[:, -1] + 1}
 
 
-def _layer_decode_rowwise(layer: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
-                          cache_v: jnp.ndarray, write_pos: jnp.ndarray,
-                          kv_mask: jnp.ndarray, cos: jnp.ndarray,
-                          sin: jnp.ndarray, cfg: LlamaConfig
-                          ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """`_layer_forward_cached`'s decode branch with write_pos: [b] — each
-    slot writes its own cache position (requests at different depths share
-    one decode tick), via a vmapped per-row dynamic_update_slice."""
-    q, k, v = _project_qkv(layer, x, cos, sin, cfg)
-
-    with jax.named_scope(trace.SCOPE_KV_WRITE):
-        row_update = lambda c, n, w: jax.lax.dynamic_update_slice(c, n, (w, 0, 0))
-        cache_k = jax.vmap(row_update)(cache_k, k, write_pos)
-        cache_v = jax.vmap(row_update)(cache_v, v, write_pos)
-
-    with jax.named_scope(trace.SCOPE_DECODE_ATTN):
-        attn_out = attention(q, cache_k, cache_v, kv_mask, causal=False)
-    return _attn_out_and_mlp(layer, x, attn_out, cfg), cache_k, cache_v
-
-
-@partial(jax.jit, static_argnames=("cfg",),
-         donate_argnames=("cache", "kv_mask"))
-def decode_step(params: Params, token: jnp.ndarray, cache: dict,
-                pos: jnp.ndarray, write_pos: jnp.ndarray,
-                kv_mask: jnp.ndarray, keys: jnp.ndarray,
-                temperature: jnp.ndarray, top_k: jnp.ndarray,
-                top_p: jnp.ndarray, cfg: LlamaConfig) -> dict:
-    """One continuous-batching decode tick over every slot row.
-
-    token/pos/write_pos: [b] int32; cache: k/v [L, b, max_len, kv_h, hd];
-    kv_mask: [b, max_len]; keys: [b, 2] per-request rng chains;
-    temperature/top_k/top_p: [b] per-request sampling knobs. Free slots ride
-    along (static shape, one compile): their kv_mask rows are garbage and
-    their sampled tokens are discarded by the host scheduler — admission
-    rewrites the whole row.
-
-    Each row mirrors one `generate()` scan step exactly: mark write_pos
-    valid BEFORE the forward (the token attends to itself), advance the rng
-    chain with the same `split(rng) -> (chain, sub)` discipline, sample
-    with the same arithmetic. Returns {"token": [b] next tokens, "cache",
-    "kv_mask", "keys"}; rope/write positions advance by one — the caller
-    tracks them host-side.
-    """
-    b = token.shape[0]
-    kv_mask = kv_mask.at[jnp.arange(b), write_pos].set(1)
-
-    x = llama.embed(params, token[:, None], cfg)
-    cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta,
-                            dtype=cfg.dtype)
-
-    def body(h, xs):
-        layer, ck, cv = xs
-        h, ck, cv = _layer_decode_rowwise(layer, h, ck, cv, write_pos,
-                                          kv_mask, cos, sin, cfg)
-        return h, (ck, cv)
-
-    x, (new_k, new_v) = jax.lax.scan(body, x,
-                                     (params["layers"], cache["k"], cache["v"]))
-    x = llama.final_norm(params, x, cfg)
-    logits = llama.lm_head(params, x, cfg)[:, -1, :]
-
-    with jax.named_scope(trace.SCOPE_SAMPLE):
-        split = jax.vmap(jax.random.split)(keys)        # [b, 2, 2]
-        nxt = sample_rowwise(logits, temperature, top_k, top_p, split[:, 1])
-    return {"token": nxt, "cache": {"k": new_k, "v": new_v},
-            "kv_mask": kv_mask, "keys": split[:, 0]}
-
-
-@partial(jax.jit, donate_argnames=("cache", "kv_mask"))
-def write_slot(cache: dict, kv_mask: jnp.ndarray, slot: jnp.ndarray,
-               row_cache: dict, row_kv_mask: jnp.ndarray
-               ) -> tuple[dict, jnp.ndarray]:
-    """Splice one prefilled request (`prefill_prompt` output, b == 1) into
-    slot row `slot` of the long-lived serving cache. `slot` is traced, so
-    admission reuses one compiled program for every slot index."""
-    cache = {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], row_cache["k"], (0, slot, 0, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], row_cache["v"], (0, slot, 0, 0, 0)),
-    }
-    kv_mask = jax.lax.dynamic_update_slice(kv_mask, row_kv_mask, (slot, 0))
-    return cache, kv_mask
-
-
-# -- paged continuous-batching entry points (serve/pages.py) ------------------
+# -- the page pool (serve/pages.py) -------------------------------------------
 #
-# The slot cache above reserves `[max_slots, max_len]` rows up front: one
-# long request's worst case is charged to EVERY slot. The paged variants
-# below keep the same static-shape discipline (one compile per program, no
-# per-batch retracing) but back the logical rows with fixed-size PAGES from
-# a shared pool plus a slot->page table, so resident HBM tracks tokens
-# actually written. The logical view a slot sees is still `[max_len]` =
-# `pages_per_slot * page_size` — the gather below reconstitutes it per
-# layer — which is what makes the fp paged decode token-bit-exact against
-# the dense path: post-mask score arrays are identical (garbage pages only
-# ever contribute through masked positions, whose scores are the same
-# NEG_INF constant and whose softmax weights are exactly 0.0).
+# A slot's keys and values live in fixed-size PAGES from a shared pool, found
+# through a slot->page table, so resident HBM tracks tokens actually written
+# and not one worst-case row a slot. The programs keep a static shape (one
+# compile each, no per-batch retracing): the logical view a slot sees is
+# `[max_len]` = `pages_per_slot * page_size`, reconstituted per layer by the
+# gather below. That is `generate()`'s cache row, so the fp path emits
+# `generate()`'s tokens: pages a slot does not own (the garbage page
+# included) only ever contribute through masked positions, whose scores are
+# the same NEG_INF constant and whose softmax weights are exactly 0.0.
 #
 # How the pool is walked: the three paged programs scan over (layer weights,
 # layer index) only and CARRY the whole pool through `_walk_pool`. A layer's
@@ -573,13 +488,13 @@ def _walk_pool(params: Params, x: jnp.ndarray, pool: dict,
 def write_pages(pool: dict, kv_mask: jnp.ndarray, slot: jnp.ndarray,
                 page_rows: jnp.ndarray, row_cache: dict,
                 row_kv_mask: jnp.ndarray) -> tuple[dict, jnp.ndarray]:
-    """Splice one prefilled request into its physical pages: the paged
-    counterpart of `write_slot`. `row_cache` is a `prefill_prompt` result
-    taken at max_len == the prompt bucket (k/v: [L, 1, bucket, kv_h, hd],
-    bucket a multiple of page_size), `page_rows` the [bucket / page_size]
-    physical pages the slot owns for it. The logical kv_mask row `slot` is
-    rewritten WHOLE (zeros past the bucket), so whatever a previous
-    occupant left in the row is dead after admission."""
+    """Splice one prefilled request into its physical pages. `row_cache` is
+    a `prefill_prompt` result taken at max_len == the prompt bucket (k/v:
+    [L, 1, bucket, kv_h, hd], bucket a multiple of page_size), `page_rows`
+    the [bucket / page_size] physical pages the slot owns for it. The
+    logical kv_mask row `slot` is rewritten WHOLE (zeros past the bucket),
+    so whatever a previous occupant left in the row is dead after
+    admission."""
     L, _, bucket, kvh, hd = row_cache["k"].shape
     n_pages = page_rows.shape[0]
     page = bucket // n_pages
@@ -598,44 +513,6 @@ def write_pages(pool: dict, kv_mask: jnp.ndarray, slot: jnp.ndarray,
                   ((0, 0), (0, lmax - bucket)))
     kv_mask = jax.lax.dynamic_update_slice(kv_mask, row, (slot, 0))
     return out, kv_mask
-
-
-@jax.jit
-def reset_kv_mask_row(kv_mask: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
-    """Zero logical row `slot` — chunked prefill writes the row
-    incrementally, so the previous occupant's mask must die up front (the
-    single-shot `write_pages` path overwrites the whole row instead)."""
-    zeros = jnp.zeros((1, kv_mask.shape[1]), kv_mask.dtype)
-    return jax.lax.dynamic_update_slice(kv_mask, zeros, (slot, 0))
-
-
-@partial(jax.jit, donate_argnames=("pool",))
-def copy_page(pool: dict, src: jnp.ndarray, dst: jnp.ndarray) -> dict:
-    """Clone physical page `src` into `dst` across every layer — the
-    copy-on-write fork of prefix caching (serve/pages.py): a request whose
-    prompt diverges MID-page from a cached chain copies the shared page,
-    then overwrites only the divergent suffix in its private copy. int8
-    pools bring the per-page scales along, so the copied prefix dequantizes
-    identically to the source. `src`/`dst` are traced int32 scalars: one
-    compiled program serves every fork."""
-    out = dict(pool)
-    for name in list(pool):
-        blk = jax.lax.dynamic_index_in_dim(pool[name], src, axis=1,
-                                           keepdims=True)
-        out[name] = jax.lax.dynamic_update_slice_in_dim(out[name], blk, dst,
-                                                        axis=1)
-    return out
-
-
-@jax.jit
-def set_kv_mask_row(kv_mask: jnp.ndarray, slot: jnp.ndarray,
-                    row: jnp.ndarray) -> jnp.ndarray:
-    """Rewrite logical row `slot` whole from a host-built [1, max_len] row
-    — the warm-admission counterpart of `reset_kv_mask_row`: a prefix-cache
-    hit marks its shared positions valid (and everything past them dead) in
-    ONE compiled update before the span prefill fills in the tail."""
-    return jax.lax.dynamic_update_slice(kv_mask, row.astype(kv_mask.dtype),
-                                        (slot, 0))
 
 
 def _write_tokens(pages, scales, layer_idx, rows: jnp.ndarray,
@@ -670,25 +547,33 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
                       active: jnp.ndarray, keys: jnp.ndarray,
                       temperature: jnp.ndarray, top_k: jnp.ndarray,
                       top_p: jnp.ndarray, cfg: LlamaConfig) -> dict:
-    """`decode_step` over the page pool: one tick over every slot row, with
-    kv residency resolved through `page_table` ([S, pages_per_slot] physical
-    page per logical page). `active`: [S] 0/1 — rows actually decoding.
-    Inactive rows still ride the static shape, but their kv writes are
-    steered to the garbage page and their kv_mask rows left untouched:
-    unlike the dense cache (where a non-occupant row is dead until
-    admission rewrites it whole), a paged slot can be MID-CHUNKED-PREFILL
-    during the tick, already owning live pages and live mask spans that a
-    stray write_pos=0 write would corrupt. The gathered logical view is
-    [S, pages_per_slot * page_size] == [S, max_len], so the fp path is
-    token-bit-exact against the dense `decode_step` (pinned in
-    tests/test_paged_serving.py); int8 pools dequantize on read and are
-    tolerance-gated instead. Each layer writes this token's kv into
+    """One decode tick over every slot row, with kv residency resolved
+    through `page_table` ([S, pages_per_slot] physical page per logical
+    page).
+
+    token/pos/write_pos: [S] int32; kv_mask: [S, max_len]; keys: [S, 2]
+    per-request rng chains; temperature/top_k/top_p: [S] per-request
+    sampling knobs; `active`: [S] 0/1, the rows actually decoding. Each
+    active row mirrors one `generate()` scan step exactly: mark write_pos
+    valid BEFORE the forward (the token attends to itself), advance the rng
+    chain with the same `split(rng) -> (chain, sub)` discipline, sample with
+    the same arithmetic. Inactive rows still ride the static shape (one
+    compile); their sampled tokens are discarded by the host scheduler,
+    their kv writes are steered to the garbage page and their kv_mask rows
+    left untouched: a slot can be MID-CHUNKED-PREFILL during the tick,
+    already owning live pages and live mask spans that a stray write_pos=0
+    write would corrupt. The gathered logical view is [S, pages_per_slot *
+    page_size] == [S, max_len], so the fp path emits `generate()`'s tokens
+    (pinned in tests/test_paged_serving.py); int8 pools dequantize on read
+    and are tolerance-gated instead. Each layer writes this token's kv into
     (layer, w_page, w_off) and gathers each slot's logical row from its
-    pages, in place (`_walk_pool`)."""
+    pages, in place (`_walk_pool`). Returns {"token": [S] next tokens,
+    "pool", "kv_mask", "keys"}; rope and write positions advance by one,
+    and the caller tracks them host-side."""
     b = token.shape[0]
     page = pool["k"].shape[2]
     garbage = pool["k"].shape[1] - 1
-    # .max(): active rows mark write_pos valid (same as dense), inactive
+    # .max(): active rows mark write_pos valid (as a generate() step), inactive
     # rows keep whatever their mask row already says
     kv_mask = kv_mask.at[jnp.arange(b), write_pos].max(
         active.astype(kv_mask.dtype))

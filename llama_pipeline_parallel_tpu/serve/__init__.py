@@ -1,10 +1,10 @@
 """Continuous-batching serving subsystem (docs/SERVING.md).
 
 The second workload next to training: the decode stack generalized from
-one-shot batches to a long-lived service — slot-managed static KV cache
-(slots.py) or the paged KV cache (pages.py: fixed-size pages + slot->page
-table, so HBM tracks tokens actually generated; optional int8 pages),
-admission scheduler with continuous batching and chunked batched prefill
+one-shot batches to a long-lived service — one KV store (pages.py: slots
+over fixed-size pages + a slot->page table, so HBM tracks tokens actually
+generated; optional int8 pages, optional prefix sharing), admission
+scheduler with continuous batching and chunked batched prefill
 (engine.py), SLO telemetry (telemetry.py), per-request distributed
 tracing (reqtrace.py), and a stdlib HTTP front-end (frontend.py).
 `tools/serve.py` wraps it into a supervised process;
@@ -29,12 +29,11 @@ from llama_pipeline_parallel_tpu.serve.reqtrace import (
     RequestTraceRecorder,
     TraceContext,
 )
-from llama_pipeline_parallel_tpu.serve.slots import SlotKVCache
 from llama_pipeline_parallel_tpu.serve.telemetry import SLOStats
 
 __all__ = [
     "EngineShutdown", "PagedKVCache", "RequestHandle", "RequestRejected",
     "RequestTraceRecorder", "ServeConfig", "ServeEngine", "ServeLoop",
-    "ServeOverloaded", "ServePagesExhausted", "ServeRequest", "SlotKVCache",
+    "ServeOverloaded", "ServePagesExhausted", "ServeRequest",
     "SLOStats", "TraceContext",
 ]

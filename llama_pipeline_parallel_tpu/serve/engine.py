@@ -1,26 +1,23 @@
 """Continuous-batching inference engine (docs/SERVING.md).
 
-The admission/batch scheduler over the KV cache — the dense slot manager
-(`slots.SlotKVCache`) or the paged pool (`pages.PagedKVCache`, selected by
-`ServeConfig.kv_cache`): requests enter a bounded FIFO wait queue
-(`submit`, thread-safe — overload raises `ServeOverloaded`; on the paged
-cache, a worst-case page demand the pool cannot cover raises
-`ServePagesExhausted`, both mapped to HTTP 429 + Retry-After by the
+The admission/batch scheduler over the page pool (`pages.PagedKVCache`):
+requests enter a bounded FIFO wait queue (`submit`, thread-safe — overload
+raises `ServeOverloaded`, a worst-case page demand the pool cannot cover
+raises `ServePagesExhausted`, both mapped to HTTP 429 + Retry-After by the
 frontend), and at every `step()` boundary the engine
 
 1. **admits** queued requests into free slots — each admission left-pads
    the prompt to the smallest configured bucket, runs `prefill_prompt`
    (one compile per bucket), samples the request's FIRST token with its own
-   rng chain, and splices the row into the long-lived cache — prefill-
-   then-join. On the paged cache with `prefill_chunk_tokens` set, a bucket
-   larger than the budget instead prefills INCREMENTALLY: at most that
-   many prompt tokens per tick (`paged_prefill_chunk`), so in-flight
-   decodes keep producing a token every tick — chunked batched prefill,
-   no full-prefill stall;
-2. runs ONE `decode_step`/`paged_decode_step` over every slot (static
-   shape, one compile) — per-row write positions, rope positions, rng
-   chains, and sampling knobs, so requests at different depths and with
-   different `GenerationConfig`s share the tick;
+   rng chain, and splices the row into the slot's pages — prefill-
+   then-join. With `prefill_chunk_tokens` set, a bucket larger than the
+   budget instead prefills INCREMENTALLY: at most that many prompt tokens
+   per tick (`paged_prefill_chunk`), so in-flight decodes keep producing a
+   token every tick — chunked batched prefill, no full-prefill stall;
+2. runs ONE `paged_decode_step` over every slot (static shape, one
+   compile) — per-row write positions, rope positions, rng chains, and
+   sampling knobs, so requests at different depths and with different
+   `GenerationConfig`s share the tick;
 3. distributes the sampled tokens to their streaming handles and frees the
    slots of finished rows (eos or budget) immediately — pages and
    reservations included — so the next boundary can admit again.
@@ -61,7 +58,6 @@ from llama_pipeline_parallel_tpu.models.family import (
 )
 from llama_pipeline_parallel_tpu.serve.pages import PagedKVCache
 from llama_pipeline_parallel_tpu.serve.reqtrace import TraceContext
-from llama_pipeline_parallel_tpu.serve.slots import SlotKVCache
 from llama_pipeline_parallel_tpu.serve.telemetry import SLOStats, retry_after_s
 from llama_pipeline_parallel_tpu.utils import trace
 from llama_pipeline_parallel_tpu.utils.logging import get_logger
@@ -100,8 +96,8 @@ class RequestRejected(ValueError):
 
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
-    """Engine shape/scheduling budget, fixed at construction (the cache is
-    allocated once from it)."""
+    """Engine shape/scheduling budget, fixed at construction (the page pool
+    is allocated once from it)."""
 
     max_slots: int = 8
     max_len: int = 2048                # per-slot KV capacity (prompt + new)
@@ -114,16 +110,19 @@ class ServeConfig:
     # replica; durations still accumulate exactly (the RunClock listener
     # sees the aggregate), only the file granularity coarsens
     decode_span_every: int = 32
-    # -- paged KV cache (docs/SERVING.md "Paged KV cache") -----------------
-    kv_cache: str = "dense"            # "dense" | "paged"
-    page_size: int = 64                # tokens per KV page (paged only)
-    num_pages: int | None = None       # pool size; None = dense-equivalent
-    kv_quant: str = "fp"               # "fp" | "int8" pages (paged only)
-    # per-tick prefill token budget AND chunk granularity (paged only):
-    # 0 = whole-prompt admissions; > 0 = a bucket larger than this prefills
-    # in pieces of exactly this many tokens, interleaved with decode ticks
+    # -- the page pool (docs/SERVING.md "Paged KV cache") ------------------
+    # one legal value, read by nothing: the benchmark's workload files pass
+    # the key, and it goes when they drop it (ROADMAP.md, debt (a) of PR 28)
+    kv_cache: str = "paged"
+    page_size: int = 64                # tokens per KV page
+    # pool size; None = one max_len row a slot (max_slots * max_len tokens)
+    num_pages: int | None = None
+    kv_quant: str = "fp"               # "fp" | "int8" pages
+    # per-tick prefill token budget AND chunk granularity: 0 = whole-prompt
+    # admissions; > 0 = a bucket larger than this prefills in pieces of
+    # exactly this many tokens, interleaved with decode ticks
     prefill_chunk_tokens: int = 0
-    # prefix caching (paged only; docs/SERVING.md "Prefix caching"):
+    # prefix caching (docs/SERVING.md "Prefix caching"):
     # share physical pages between requests with identical padded prompt
     # prefixes — cache-hit admissions skip the shared span's prefill and
     # reserve only their new pages. Off (the default) keeps the engine
@@ -146,18 +145,11 @@ class ServeConfig:
                 f"bucket {min(self.prompt_buckets)} plus one generated token")
         if self.max_queue < 1:
             raise ValueError("max_queue must be >= 1")
-        if self.kv_cache not in ("dense", "paged"):
-            raise ValueError(f"kv_cache must be 'dense' or 'paged', got "
-                             f"{self.kv_cache!r}")
-        if self.kv_cache == "dense":
-            if self.kv_quant != "fp":
-                raise ValueError("kv_quant requires kv_cache: paged")
-            if self.prefill_chunk_tokens:
-                raise ValueError("prefill_chunk_tokens requires "
-                                 "kv_cache: paged")
-            if self.prefix_cache:
-                raise ValueError("prefix_cache requires kv_cache: paged")
-            return
+        if self.kv_cache != "paged":
+            raise ValueError(
+                f"kv_cache: {self.kv_cache!r}: the paged pool is the "
+                f"engine's only KV store since PR 28 (the dense slot cache "
+                f"is gone); drop the key")
         if self.kv_quant not in ("fp", "int8"):
             raise ValueError(f"kv_quant must be 'fp' or 'int8', got "
                              f"{self.kv_quant!r}")
@@ -192,8 +184,8 @@ class ServeConfig:
 
     @property
     def resolved_num_pages(self) -> int:
-        """The pool size: as configured, or the dense-equivalent capacity
-        (same logical tokens as the `[max_slots, max_len]` reservation)."""
+        """The pool size: as configured, or one `max_len` row a slot (the
+        logical tokens of a `[max_slots, max_len]` reservation)."""
         if self.num_pages is not None:
             return self.num_pages
         return self.max_slots * self.max_len // self.page_size
@@ -296,8 +288,8 @@ class _Running:
 
 @dataclasses.dataclass
 class _Prefilling:
-    """Host-side state of a slot whose prompt is still prefilling (paged
-    chunked admissions; at most one request is mid-prefill at a time —
+    """Host-side state of a slot whose prompt is still prefilling (chunked
+    admissions; at most one request is mid-prefill at a time —
     FIFO order makes a second partial pointless)."""
 
     request: ServeRequest
@@ -326,7 +318,7 @@ class ServeEngine:
         model's configuration object; its family (models/family.py)
         supplies the prefill and tick programs, and what it cannot run yet
         (a model with recurrent layers: prefix cache, chunked and span
-        prefill, int8 pages, the dense cache) is refused here, by name.
+        prefill, int8 pages) is refused here, by name.
         The engine calls every program with `self.params`: the leaves the
         family's programs would convert to the compute dtype at each use,
         converted once here (`_serving_weights`), the rest `params`' own.
@@ -347,19 +339,14 @@ class ServeEngine:
         self.serve_cfg = serve_cfg
         self._family = family_of(cfg)
         self._family.check_serve_config(
-            serve_cfg.kv_cache, serve_cfg.kv_quant,
-            serve_cfg.prefill_chunk_tokens, serve_cfg.prefix_cache)
+            serve_cfg.kv_quant, serve_cfg.prefill_chunk_tokens,
+            serve_cfg.prefix_cache)
         self.params = self._serving_weights(params)
-        self._paged = serve_cfg.kv_cache == "paged"
-        self._prefix = self._paged and serve_cfg.prefix_cache
-        if self._paged:
-            self.slots = PagedKVCache(
-                cfg, serve_cfg.max_slots, serve_cfg.max_len,
-                serve_cfg.page_size, serve_cfg.resolved_num_pages,
-                serve_cfg.kv_quant, prefix_cache=serve_cfg.prefix_cache)
-        else:
-            self.slots = SlotKVCache(cfg, serve_cfg.max_slots,
-                                     serve_cfg.max_len)
+        self._prefix = serve_cfg.prefix_cache
+        self.slots = PagedKVCache(
+            cfg, serve_cfg.max_slots, serve_cfg.max_len,
+            serve_cfg.page_size, serve_cfg.resolved_num_pages,
+            serve_cfg.kv_quant, prefix_cache=serve_cfg.prefix_cache)
         self.stats = SLOStats()
         self._metrics_writer = metrics_writer
         self._timeline = timeline
@@ -369,12 +356,12 @@ class ServeEngine:
         # request_id -> in-flight RequestTraceBuilder (loop thread only;
         # empty forever when tracing is OFF — the structural free-ness pin)
         self._rt: dict = {}
-        if reqtrace is not None and self._paged:
+        if reqtrace is not None:
             # attribute page-pool hand-outs to the owning slot's request
             self.slots.alloc_listener = self._on_page_alloc
         self._last_decode_dur = 0.0
         self._occupants: dict[int, _Running] = {}
-        self._prefilling: deque = deque()   # paged chunked admissions
+        self._prefilling: deque = deque()   # chunked admissions
         self._queue: deque = deque()
         # request ids the frontend saw disconnect: cancelled at the next
         # step boundary (queued, prefilling, or decoding alike)
@@ -475,20 +462,18 @@ class ServeEngine:
         storm of unservable shapes as clearly as queue overload."""
         if request.trace is None:
             request.trace = TraceContext.mint()
-        demand = 0
         try:
             if len(request.input_ids) == 0:
                 raise RequestRejected("empty prompt")
             bucket = self.pick_bucket(len(request.input_ids),
                                       request.gen.max_new_tokens)
-            if self._paged:
-                demand = self.slots.demand_pages(
-                    bucket, request.gen.max_new_tokens)
-                if demand > self.slots.num_pages:
-                    raise RequestRejected(
-                        f"worst-case demand of {demand} pages exceeds the "
-                        f"pool ({self.slots.num_pages} pages of "
-                        f"{self.slots.page_size} tokens)")
+            demand = self.slots.demand_pages(
+                bucket, request.gen.max_new_tokens)
+            if demand > self.slots.num_pages:
+                raise RequestRejected(
+                    f"worst-case demand of {demand} pages exceeds the "
+                    f"pool ({self.slots.num_pages} pages of "
+                    f"{self.slots.page_size} tokens)")
         except RequestRejected:
             self.stats.record_rejected(request.tenant)
             self._record_shed(request, "rejected")
@@ -558,7 +543,7 @@ class ServeEngine:
                 self.stats.record_prefix(match.tokens, len(match.pages),
                                          match.fork_src is not None)
                 handle.prefix_cached_tokens = match.tokens
-            elif demand and not self.slots.reserve(demand):
+            elif not self.slots.reserve(demand):
                 # refuse NOW: admitting would strand the request mid-decode
                 # when the pool runs dry under it
                 self.stats.record_rejected(request.tenant)
@@ -608,10 +593,10 @@ class ServeEngine:
     # -- scheduling (the loop thread) -------------------------------------
 
     def step(self) -> bool:
-        """One step boundary: admit (dense, and paged without a chunk
-        budget: whole prompts) or advance bounded prefill chunks (paged
-        with one), then one decode tick over all slots. Returns False when
-        there was nothing to do (caller may sleep)."""
+        """One step boundary: admit (without a chunk budget: whole prompts)
+        or advance bounded prefill chunks (with one), then one decode tick
+        over all slots. Returns False when there was nothing to do (caller
+        may sleep)."""
         t0 = time.perf_counter() if self._timeline is not None else 0.0
         self._cancel_abandoned()
         pf_req = (self._prefilling[0].request.request_id
@@ -654,13 +639,12 @@ class ServeEngine:
                "decode_s": round(decode_s, 6),
                "active": len(self._occupants),
                "queue_depth": len(self._queue)}
-        if self._paged:
-            # page-pool occupancy PER TICK: the fragmentation timeline —
-            # how the reserved-vs-allocated gap moves as requests admit,
-            # decode, and release (the snapshot gauges only show now)
-            rec["pages_used"] = self.slots.pages_used
-            rec["pages_reserved"] = self.slots.pages_reserved
-            rec["fragmentation"] = round(self.slots.fragmentation, 4)
+        # page-pool occupancy PER TICK: the fragmentation timeline — how
+        # the reserved-vs-allocated gap moves as requests admit, decode,
+        # and release (the snapshot gauges only show now)
+        rec["pages_used"] = self.slots.pages_used
+        rec["pages_reserved"] = self.slots.pages_reserved
+        rec["fragmentation"] = round(self.slots.fragmentation, 4)
         if self.prefill_chunks_last_tick:
             rec["prefill_chunks"] = self.prefill_chunks_last_tick
         if pf_req is not None:
@@ -692,7 +676,7 @@ class ServeEngine:
         for request, handle, demand, match in queued:
             if match is not None:
                 self.slots.cancel_match(match)
-            elif demand:
+            else:
                 self.slots.unreserve(demand)
             self._finish_abandoned(request, handle, discarded=0)
         for pf in [p for p in self._prefilling
@@ -720,18 +704,16 @@ class ServeEngine:
                     tokens_discarded=discarded))
         handle._finish(None)
 
-    # -- admission: the ONE prefill path for both caches -------------------
+    # -- admission: the ONE prefill path -----------------------------------
 
     def _advance_prefill(self) -> None:
         """Spend at most `prefill_chunk_tokens` prompt tokens on prefill
-        work this tick (unbounded when 0 — the dense cache and chunkless
-        paged configs admit whole prompts): continue the in-progress
-        chunked prefill first, then admit queued requests into free slots.
-        A bucket no larger than the chunk budget prefills in ONE shot (the
-        `prefill_prompt` + splice path — identical arithmetic on either
-        cache); a larger bucket (paged only) runs in chunk-sized pieces
-        across ticks, so in-flight decodes keep producing a token every
-        tick — no full-prefill stall."""
+        work this tick (unbounded when 0 — whole prompts are admitted):
+        continue the in-progress chunked prefill first, then admit queued
+        requests into free slots. A bucket no larger than the chunk budget
+        prefills in ONE shot (the `prefill_prompt` + splice path); a larger
+        bucket runs in chunk-sized pieces across ticks, so in-flight
+        decodes keep producing a token every tick — no full-prefill stall."""
         chunk = self.serve_cfg.prefill_chunk_tokens
         spent = 0
         chunks_run = 0
@@ -785,11 +767,9 @@ class ServeEngine:
             if not self._queue:
                 return None
             request, handle, demand, match = self._queue[0]
-            if match is None:   # dense, or paged with the cache off
-                slot = self.slots.acquire(request.request_id, demand)
-            else:
-                slot = self.slots.acquire(request.request_id,
-                                          match.new_demand, match=match)
+            slot = self.slots.acquire(
+                request.request_id,
+                demand if match is None else match.new_demand, match=match)
             if slot is None:
                 return None
             self._queue.popleft()
@@ -827,7 +807,7 @@ class ServeEngine:
                     self.slots.fork_page(slot, match.fork_src)
                     match.forked = True
                     self.slots.unpin_page(match.fork_src)
-            elif self._paged and chunk and bucket > chunk:
+            elif chunk and bucket > chunk:
                 # incremental writes: the previous occupant's mask must die
                 self.slots.reset_mask_row(slot)
             if self._reqtrace is not None:
@@ -858,8 +838,9 @@ class ServeEngine:
     def _run_prefill_chunk(self, pf: _Prefilling, cost: int) -> bool:
         """Run one prefill unit of `cost` tokens for `pf`; on the final
         chunk, sample the request's first token (the same `sample_rowwise`
-        program and rng discipline as the dense admission) and join the
-        decode batch. Returns True when the request finished prefilling."""
+        program and rng discipline whichever prefill produced the logits)
+        and join the decode batch. Returns True when the request finished
+        prefilling."""
         slot = pf.slot
         offset0 = pf.done
         with trace.span("serve_prefill", request=pf.request.request_id,
@@ -885,14 +866,11 @@ class ServeEngine:
                 next_pos = int(pf.positions[0, -1]) + 1
                 pf.done = c1
             elif cost == pf.bucket:
-                # single shot; the prefill logits depend only on the prompt
-                # block, so the row capacity (dense: the whole max_len row
-                # write_slot splices; paged: the bucket write_pages pages)
-                # changes residency, never arithmetic
-                row_len = pf.bucket if self._paged else self.serve_cfg.max_len
+                # single shot: a row the bucket long, which write_pages
+                # pages
                 out = self._family.prefill_prompt(
                     self.params, jnp.asarray(pf.ids), jnp.asarray(pf.mask),
-                    self.cfg, row_len)
+                    self.cfg, pf.bucket)
                 self.slots.admit(slot, out)
                 logits = out["logits"]
                 next_pos = int(out["next_pos"][0])
@@ -991,31 +969,23 @@ class ServeEngine:
         t_wall = time.time()
         t0 = time.perf_counter()
         with trace.annotate(trace.TICK_DISPATCH):
-            if self._paged:
-                # back the next write of every active row BEFORE the tick:
-                # the submit-time reservation guarantees these allocations
-                # succeed
-                for slot, r in self._occupants.items():
-                    self.slots.ensure_capacity(slot, r.write_pos + 1)
-                # only occupant rows may write/mark kv: a mid-prefill slot
-                # already owns live pages and mask spans this tick must not
-                # touch
-                active = np.zeros(scfg.max_slots, np.int32)
-                for slot in self._occupants:
-                    active[slot] = 1
-                out = self._family.paged_decode_step(
-                    self.params, jnp.asarray(token), self.slots.pool,
-                    jnp.asarray(self.slots.page_table), jnp.asarray(pos),
-                    jnp.asarray(write_pos), self.slots.kv_mask,
-                    jnp.asarray(active), jnp.asarray(keys),
-                    jnp.asarray(temps), jnp.asarray(top_ks),
-                    jnp.asarray(top_ps), self.cfg)
-            else:
-                out = self._family.decode_step(
-                    self.params, jnp.asarray(token), self.slots.cache,
-                    jnp.asarray(pos), jnp.asarray(write_pos),
-                    self.slots.kv_mask, jnp.asarray(keys), jnp.asarray(temps),
-                    jnp.asarray(top_ks), jnp.asarray(top_ps), self.cfg)
+            # back the next write of every active row BEFORE the tick: the
+            # submit-time reservation guarantees these allocations succeed
+            for slot, r in self._occupants.items():
+                self.slots.ensure_capacity(slot, r.write_pos + 1)
+            # only occupant rows may write/mark kv: a mid-prefill slot
+            # already owns live pages and mask spans this tick must not
+            # touch
+            active = np.zeros(scfg.max_slots, np.int32)
+            for slot in self._occupants:
+                active[slot] = 1
+            out = self._family.paged_decode_step(
+                self.params, jnp.asarray(token), self.slots.pool,
+                jnp.asarray(self.slots.page_table), jnp.asarray(pos),
+                jnp.asarray(write_pos), self.slots.kv_mask,
+                jnp.asarray(active), jnp.asarray(keys),
+                jnp.asarray(temps), jnp.asarray(top_ks),
+                jnp.asarray(top_ps), self.cfg)
             self.slots.update_from_step(out)
         t_dispatched = time.perf_counter()
         with trace.annotate(trace.TICK_WAIT):
@@ -1174,36 +1144,35 @@ class ServeEngine:
         snap["decode_steps"] = self.steps
         if self._degraded is not None:
             snap["degraded"] = self._degraded
-        if self._paged:
-            scfg = self.serve_cfg
-            snap["kv_cache"] = "paged"
-            snap["kv_quant"] = scfg.kv_quant
-            snap["page_size"] = scfg.page_size
-            snap["pages_total"] = self.slots.num_pages
-            snap["pages_used"] = self.slots.pages_used
-            snap["pages_free"] = self.slots.pages_free
-            snap["pages_reserved"] = self.slots.pages_reserved
-            # the reservation-vs-allocation gap: HBM promised to worst-case
-            # demand that has not materialized as written tokens (pages.py
-            # fragmentation docstring) — /healthz serves this verbatim and
-            # the fleet aggregates it across pods
-            snap["reserved_unbacked"] = self.slots.reserved_unbacked
-            snap["page_fragmentation"] = round(self.slots.fragmentation, 4)
-            snap["reserved_gap_bytes"] = (self.slots.reserved_unbacked
-                                          * self.slots.page_bytes())
-            snap["page_allocations"] = self.slots.page_allocations
-            snap["prefilling"] = len(self._prefilling)
-            snap["prefill_chunks_last_tick"] = self.prefill_chunks_last_tick
-            snap["prefill_chunks_total"] = self.prefill_chunks_total
-            snap["prefill_tokens_total"] = self.prefill_tokens_total
-            if self._prefix:
-                # cache-off snapshots stay byte-identical to the plain
-                # paged engine (the PR 13 pin) — these keys only exist
-                # when prefix caching is on
-                snap["prefix_cache"] = 1
-                snap["pages_cached"] = self.slots.pages_cached
-                snap["prefix_cow_forks"] = self.slots.cow_forks
-                snap["prefix_evictions"] = self.slots.prefix_evictions
+        scfg = self.serve_cfg
+        snap["kv_cache"] = "paged"
+        snap["kv_quant"] = scfg.kv_quant
+        snap["page_size"] = scfg.page_size
+        snap["pages_total"] = self.slots.num_pages
+        snap["pages_used"] = self.slots.pages_used
+        snap["pages_free"] = self.slots.pages_free
+        snap["pages_reserved"] = self.slots.pages_reserved
+        # the reservation-vs-allocation gap: HBM promised to worst-case
+        # demand that has not materialized as written tokens (pages.py
+        # fragmentation docstring) — /healthz serves this verbatim and
+        # the fleet aggregates it across pods
+        snap["reserved_unbacked"] = self.slots.reserved_unbacked
+        snap["page_fragmentation"] = round(self.slots.fragmentation, 4)
+        snap["reserved_gap_bytes"] = (self.slots.reserved_unbacked
+                                      * self.slots.page_bytes())
+        snap["page_allocations"] = self.slots.page_allocations
+        snap["prefilling"] = len(self._prefilling)
+        snap["prefill_chunks_last_tick"] = self.prefill_chunks_last_tick
+        snap["prefill_chunks_total"] = self.prefill_chunks_total
+        snap["prefill_tokens_total"] = self.prefill_tokens_total
+        if self._prefix:
+            # cache-off snapshots stay byte-identical to the plain
+            # paged engine (the PR 13 pin) — these keys only exist
+            # when prefix caching is on
+            snap["prefix_cache"] = 1
+            snap["pages_cached"] = self.slots.pages_cached
+            snap["prefix_cow_forks"] = self.slots.cow_forks
+            snap["prefix_evictions"] = self.slots.prefix_evictions
         return snap
 
     def drain(self, timeout_s: float = 60.0) -> None:
@@ -1229,7 +1198,7 @@ class ServeEngine:
         for request, handle, demand, match in pending:
             if match is not None:
                 self.slots.cancel_match(match)
-            elif demand:
+            else:
                 self.slots.unreserve(demand)
             self._record_shed(request, "shutdown")
             handle._finish(err)
@@ -1276,7 +1245,7 @@ class ServeLoop:
                 if not self.engine.step():
                     self.engine._work.wait(self._idle_wait)
             except Exception:
-                # decode_step/write_slot DONATE the long-lived cache, so a
+                # the tick and the splice DONATE the long-lived pool, so a
                 # failed step leaves the slot state poisoned — retrying
                 # would raise forever while blocked clients hang. Fail every
                 # handle (and future submits) instead, like the process

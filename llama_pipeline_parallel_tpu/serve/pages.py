@@ -1,11 +1,18 @@
-"""Paged KV cache: fixed-size pages + a slot->page table (docs/SERVING.md).
+"""The serving engine's KV store: fixed-size pages + a slot->page table
+(docs/SERVING.md).
 
-`SlotKVCache` reserves `[max_slots, max_len]` up front — every slot is
-charged one worst-case request whether it holds three tokens or three
-thousand. This manager backs the same logical rows with PAGES from a shared
-pool (`decode.init_page_pool`), so resident HBM tracks tokens actually
+A `[max_slots, max_len]` reservation would charge every slot one worst-case
+request whether it holds three tokens or three thousand. This manager backs
+those logical rows with PAGES from a shared pool (the family's
+`init_page_pool`), allocated ONCE, so resident HBM tracks tokens actually
 written:
 
+- `acquire()` hands out a free slot (lowest index first: deterministic for
+  tests), `release(slot)` returns it at once with no device work. A freed
+  row keeps riding the static-shape decode tick, writing to the garbage
+  page; `assignments` keeps a (slot, request_id) history and `allocations`
+  counts pool allocations (it stays 1 for the life of the engine): the
+  slot-reuse proof the serving tests pin.
 - a request's **worst-case page demand** (`page_demand`) is reserved at
   submit time — admission control, the backpressure signal the frontend
   maps to HTTP 429 + Retry-After — but physical pages are allocated
@@ -13,7 +20,7 @@ written:
   each page boundary (`ensure_capacity`). Reservation <= pool is the
   invariant that makes mid-decode allocation infallible: a request that
   was admitted can always finish.
-- `release` returns the slot's pages to the free pool, resets its
+- `release` also returns the slot's pages to the free pool, resets its
   page-table row to the GARBAGE page (index `num_pages` — the extra page
   every inactive slot scatters into while riding the static-shape decode
   step), and returns its reservation.
@@ -32,7 +39,7 @@ NEW pages past the divergence point. The engine maps the pinned pages into
 the slot's table row (a numpy edit — no kernel change, reads already
 tolerate any mapping), recomputes only the tail, and registers the freshly
 written prompt pages back into the index at prefill completion. Divergence
-mid-page forks the containing page copy-on-write (`decode.copy_page`);
+mid-page forks the containing page copy-on-write (`copy_page`);
 decode writes never touch shared pages (write_pos starts at the
 page-aligned bucket, so the first decode write always claims a fresh
 page). Every page holds a refcount while mapped/pinned; refcount-0 cached
@@ -41,12 +48,13 @@ subtree) before an allocation would fail — the committed-pages invariant
 `queued + slot_reserved + held_cached <= num_pages` keeps admitted
 requests infallible exactly as before.
 
-The interface mirrors `SlotKVCache` (acquire/admit/release/active_count/
-assignments/allocations) so `ServeEngine` and tools/serve.py treat either
-cache uniformly; the paged extras (reserve/ensure_capacity/page gauges)
-only the paged scheduler touches, and every prefix-cache structure is
-empty/byte-identical-in-behavior when `prefix_cache` is off (the PR 13
-pin).
+Every prefix-cache structure is empty/byte-identical-in-behavior when
+`prefix_cache` is off (the PR 13 pin).
+
+The format of the mask and of the pool's page axis is this module's: the
+edits that touch only those (`reset_kv_mask_row`, `set_kv_mask_row`,
+`copy_page`) live here, and a family (models/family.py) supplies only the
+programs that run its layers.
 """
 
 from __future__ import annotations
@@ -55,7 +63,9 @@ import dataclasses
 import hashlib
 import threading
 from collections import OrderedDict
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -73,7 +83,8 @@ def page_demand(bucket: int, max_new_tokens: int, page_size: int) -> int:
 
 def dense_kv_cache_bytes(cfg, max_slots: int,
                          max_len: int) -> int:
-    """Resident bytes of the dense `SlotKVCache` reservation."""
+    """Resident bytes of a `[max_slots, max_len]` reservation, one
+    worst-case row a slot: what a pool is sized against."""
     itemsize = jnp.dtype(cfg.dtype).itemsize
     return (2 * cfg.kv_cache_layers * max_slots * max_len * cfg.kv_heads
             * cfg.head_dim * itemsize)
@@ -89,6 +100,45 @@ def paged_pool_bytes(cfg, num_pages: int, page_size: int,
     if quant == "int8":
         kv += 2 * cfg.kv_cache_layers * (num_pages + 1) * cfg.kv_heads * 4
     return kv
+
+
+@jax.jit
+def reset_kv_mask_row(kv_mask: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
+    """Zero logical row `slot` — chunked prefill writes the row
+    incrementally, so the previous occupant's mask must die up front (the
+    single-shot `write_pages` path overwrites the whole row instead)."""
+    zeros = jnp.zeros((1, kv_mask.shape[1]), kv_mask.dtype)
+    return jax.lax.dynamic_update_slice(kv_mask, zeros, (slot, 0))
+
+
+@jax.jit
+def set_kv_mask_row(kv_mask: jnp.ndarray, slot: jnp.ndarray,
+                    row: jnp.ndarray) -> jnp.ndarray:
+    """Rewrite logical row `slot` whole from a host-built [1, max_len] row
+    — the warm-admission counterpart of `reset_kv_mask_row`: a prefix-cache
+    hit marks its shared positions valid (and everything past them dead) in
+    ONE compiled update before the span prefill fills in the tail."""
+    return jax.lax.dynamic_update_slice(kv_mask, row.astype(kv_mask.dtype),
+                                        (slot, 0))
+
+
+@partial(jax.jit, donate_argnames=("pages",))
+def copy_page(pages: dict, src: jnp.ndarray, dst: jnp.ndarray) -> dict:
+    """Clone physical page `src` into `dst` across every layer — the
+    copy-on-write fork of prefix caching: a request whose prompt diverges
+    MID-page from a cached chain copies the shared page, then overwrites
+    only the divergent suffix in its private copy. `pages` holds the pool's
+    page leaves and no other (every leaf has the page axis second); int8
+    pools bring the per-page scales along, so the copied prefix dequantizes
+    identically to the source. `src`/`dst` are traced int32 scalars: one
+    compiled program serves every fork."""
+    out = dict(pages)
+    for name in list(pages):
+        blk = jax.lax.dynamic_index_in_dim(pages[name], src, axis=1,
+                                           keepdims=True)
+        out[name] = jax.lax.dynamic_update_slice_in_dim(out[name], blk, dst,
+                                                        axis=1)
+    return out
 
 
 def chain_hashes(ids_row: np.ndarray, mask_row: np.ndarray,
@@ -175,11 +225,14 @@ class PagedKVCache:
         self.pages_per_slot = max_len // page_size
         self.garbage_page = num_pages
 
-        # the configuration's family supplies the programs that touch the
-        # device state (models/family.py); this manager names none
+        # the configuration's family supplies the programs that run its
+        # layers over the device state (models/family.py); this manager
+        # names none
         self.family = family_of(cfg)
         self.pool = self.family.init_page_pool(cfg, num_pages, page_size,
                                                quant)
+        # the leaves with a page axis: what a copy-on-write fork copies
+        self._page_leaves = tuple(self.pool)
         # a family with recurrent layers keeps a second store, one row a
         # slot and such layer, in the same donated tree as the pages (keys
         # `state` / `conv` beside `k` / `v`): written whole at admission,
@@ -359,7 +412,7 @@ class PagedKVCache:
 
     def unpin_page(self, page: int) -> None:
         """Release one hold on a cached page (the engine's fork-source
-        release once `decode.copy_page` has run)."""
+        release once `copy_page` has run)."""
         with self._lock:
             self._unpin_locked(page)
 
@@ -484,8 +537,9 @@ class PagedKVCache:
         base = len(self._shared.get(slot, ()))
         self.ensure_capacity(slot, base * self.page_size + 1)
         dst = int(self.page_table[slot, base])
-        self.pool = self.family.copy_page(self.pool, jnp.int32(src),
-                                     jnp.int32(dst))
+        pages = {name: self.pool[name] for name in self._page_leaves}
+        self.pool = {**self.pool,
+                     **copy_page(pages, jnp.int32(src), jnp.int32(dst))}
         self.cow_forks += 1
 
     def register_prefix(self, slot: int, hashes: list, ids_row: np.ndarray,
@@ -635,8 +689,7 @@ class PagedKVCache:
     def reset_mask_row(self, slot: int) -> None:
         """Kill the previous occupant's logical mask before a CHUNKED
         prefill starts writing the row incrementally."""
-        self.kv_mask = self.family.reset_kv_mask_row(self.kv_mask,
-                                                     jnp.int32(slot))
+        self.kv_mask = reset_kv_mask_row(self.kv_mask, jnp.int32(slot))
 
     def set_mask_row_prefix(self, slot: int, mask_row: np.ndarray,
                             tokens: int) -> None:
@@ -646,8 +699,8 @@ class PagedKVCache:
         `reset_mask_row` (the span prefill fills in the tail)."""
         row = np.zeros((1, self.max_len), np.int32)
         row[0, :tokens] = np.asarray(mask_row, np.int32).reshape(-1)[:tokens]
-        self.kv_mask = self.family.set_kv_mask_row(
-            self.kv_mask, jnp.int32(slot), jnp.asarray(row))
+        self.kv_mask = set_kv_mask_row(self.kv_mask, jnp.int32(slot),
+                                       jnp.asarray(row))
 
     def update_from_step(self, step_out: dict) -> None:
         """Adopt the pool/kv_mask a `paged_decode_step` returned (inputs

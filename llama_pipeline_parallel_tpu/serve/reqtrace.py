@@ -144,8 +144,7 @@ class RequestTraceBuilder:
         self.spans.append({"name": "admission", "ts": t_admit, "slot": slot,
                            "bucket": bucket,
                            "pages_reserved": pages_reserved,
-                           "verdict": ("reserved" if pages_reserved
-                                       else "dense")})
+                           "verdict": "reserved"})
 
     def prefix_hit(self, tokens: int, pages: int, cow: bool) -> None:
         """Prefix-cache hit at admission: `tokens` padded-row positions

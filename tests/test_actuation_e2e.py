@@ -316,7 +316,7 @@ def test_deploy_rollback_chaos_replica_kill(tmp_path):
            "--checkpoint_dir", trainer_out, "--output_dir", replica_out,
            "--host", "127.0.0.1", "--port", str(_free_port()),
            "--platform", "cpu", "--max_slots", "2", "--max_len", "320",
-           "--buckets", "8", "--metrics_every", "1",
+           "--buckets", "8", "--page_size", "8", "--metrics_every", "1",
            "--health_interval", "0.5", "--drain_s", "10"]
     sup = supervisor.Supervisor(cmd, supervisor.SupervisorConfig(
         output_dir=replica_out, max_restarts=6, hang_timeout_s=600.0,

@@ -130,7 +130,8 @@ def test_fleet_chaos_stale_alert_capture_and_checkpoint_lag(tmp_path):
                    "--host", "127.0.0.1", "--port", str(_free_port()),
                    "--platform", "cpu", "--max_slots", "2",
                    "--max_len", "320", "--buckets", "8",
-                   "--metrics_every", "1", "--health_interval", "0.5"]
+                   "--page_size", "8", "--metrics_every", "1",
+                   "--health_interval", "0.5"]
             env = dict(os.environ)
             # stretch decode steps so the kill lands mid-decode
             env["LPT_SERVE_STEP_DELAY_S"] = "0.05" if name == "a" else "0"

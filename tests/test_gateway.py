@@ -575,8 +575,11 @@ class LiveReplica:
                  **engine_kw):
         os.makedirs(outdir, exist_ok=True)
         self.output_dir = outdir
+        # a pool that covers a full queue's reservations: the queue's
+        # bound is the one these tests meet
         defaults = dict(max_slots=2, max_len=BUCKET + 8,
-                        prompt_buckets=(BUCKET,), max_queue=8)
+                        prompt_buckets=(BUCKET,), page_size=BUCKET // 2,
+                        num_pages=40, max_queue=8)
         defaults.update(engine_kw)
         extra = {"reqtrace": reqtrace} if reqtrace is not None else {}
         self.engine = ServeEngine(params, cfg, ServeConfig(**defaults),
@@ -959,7 +962,7 @@ def test_chaos_acceptance_sigkill_vs_replay(setup, tmp_path):
                    "--host", "127.0.0.1", "--port", "0",
                    "--platform", "cpu", "--max_slots", "2",
                    "--max_len", "320", "--buckets", "8",
-                   "--metrics_every", "1"]
+                   "--page_size", "8", "--metrics_every", "1"]
             env = dict(os.environ)
             # stretch decode so the SIGKILL lands mid-stream
             env["LPT_SERVE_STEP_DELAY_S"] = "0.05"

@@ -158,21 +158,74 @@ def test_the_engine_serves_the_family_through_the_same_tick_and_spans():
 
 def test_the_dense_family_is_handed_the_functions_it_always_called():
     fam = families.family_of(LlamaConfig.tiny())
-    for name in ("prefill_prompt", "paged_decode_step", "decode_step",
-                 "paged_prefill_chunk", "paged_prefill_span", "write_pages",
-                 "write_slot", "init_page_pool", "init_kv_cache", "copy_page",
-                 "reset_kv_mask_row", "set_kv_mask_row"):
+    for name in ("prefill_prompt", "paged_decode_step", "paged_prefill_chunk",
+                 "paged_prefill_span", "write_pages", "init_page_pool",
+                 "serving_weights"):
         assert getattr(fam, name) is getattr(dense_decode, name), name
+    assert fam.init_recurrent_store is None and fam.init_params is None
     assert not fam.recurrent and fam.counters == ()
     assert families.sample_rowwise is dense_decode.sample_rowwise
+
+
+def test_a_family_states_only_what_depends_on_its_layers():
+    """The twelve fields, by name: a program that runs the layers, a store
+    shaped by them, or a fact about them. What touches only the mask or the
+    pool's page axis is `serve/pages.py`'s own, and the manager reaches no
+    such thing through the family."""
+    import inspect
+
+    from llama_pipeline_parallel_tpu.serve import pages
+
+    assert [f.name for f in dataclasses.fields(families.ServingFamily)] == [
+        "name", "prefill_prompt", "paged_decode_step", "write_pages",
+        "init_page_pool", "init_recurrent_store", "init_params",
+        "serving_weights", "paged_prefill_chunk", "paged_prefill_span",
+        "kv_quants", "counters"]
+    own = ("copy_page", "reset_kv_mask_row", "set_kv_mask_row")
+    source = inspect.getsource(pages)
+    for name in own:
+        assert callable(getattr(pages, name)), name
+        assert not hasattr(dense_decode, name), name
+        assert f"family.{name}" not in source, name
+    assert "kv_cache" not in inspect.signature(
+        families.ServingFamily.check_serve_config).parameters
+
+
+def test_a_fork_copies_pages_and_leaves_the_recurrent_store_alone():
+    """`copy_page` walks the leaves `init_page_pool` returned: the `state`
+    and `conv` rows of a pool that carries a recurrent store have slots,
+    not pages, on their second axis, and stay the very arrays they were."""
+    cfg = tiny.config()
+    cache = _cache(cfg)
+    assert cache._page_leaves == ("k", "v")
+    slot = cache.acquire("r", 0)
+    assert cache.reserve(2) and cache.acquire("s", 2) == 1
+    rng = np.random.default_rng(0)
+    cache.pool = {name: jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+                  for name, x in cache.pool.items()}
+    before = {name: np.asarray(x) for name, x in cache.pool.items()}
+    store = {name: cache.pool[name] for name in ("state", "conv")}
+    src = 5
+    cache.fork_page(1, src)
+    dst = int(cache.page_table[1, 0])
+    assert dst != src and cache.cow_forks == 1
+    for name in ("k", "v"):
+        after = np.asarray(cache.pool[name])
+        np.testing.assert_array_equal(after[:, dst], before[name][:, src])
+        keep = [p for p in range(PAGES + 1) if p != dst]
+        np.testing.assert_array_equal(after[:, keep], before[name][:, keep])
+    for name, leaf in store.items():
+        assert cache.pool[name] is leaf and not leaf.is_deleted(), name
+        np.testing.assert_array_equal(np.asarray(leaf), before[name])
+    cache.release(slot)
 
 
 def test_the_engine_names_no_familys_functions():
     import inspect
 
-    from llama_pipeline_parallel_tpu.serve import engine, pages, slots
+    from llama_pipeline_parallel_tpu.serve import engine, pages
 
-    for module in (engine, pages, slots):
+    for module in (engine, pages):
         source = inspect.getsource(module)
         assert "models.llama" not in source, module.__name__
         assert "models.hybrid_moe" not in source, module.__name__
@@ -247,7 +300,6 @@ def test_the_ticks_temporaries_are_smaller_than_either_store():
     (dict(prefix_cache=True), "prefix_cache"),
     (dict(prefill_chunk_tokens=8), "prefill_chunk_tokens"),
     (dict(kv_quant="int8"), "kv_quant: int8"),
-    (dict(kv_cache="dense"), "kv_cache: dense"),
 ])
 def test_what_recurrent_layers_cannot_run_is_refused_by_name(knobs, named):
     cfg = tiny.config()
@@ -262,7 +314,7 @@ def test_what_recurrent_layers_cannot_run_is_refused_by_name(knobs, named):
 def test_the_span_prefill_is_not_among_the_familys_programs():
     fam = families.family_of(tiny.config())
     assert fam.recurrent and fam.paged_prefill_span is None
-    assert fam.paged_prefill_chunk is None and fam.decode_step is None
+    assert fam.paged_prefill_chunk is None
     assert fam.counters == hybrid.COUNTERS
 
 

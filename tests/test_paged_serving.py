@@ -1,12 +1,11 @@
-"""Paged KV cache + chunked batched prefill (serve/pages.py, the paged
-entry points in models/llama/decode.py, and the engine's paged scheduler —
+"""The page pool + chunked batched prefill (serve/pages.py, the paged
+entry points in models/llama/decode.py, and the engine's scheduler —
 docs/SERVING.md "Paged KV cache").
 
 The acceptance contracts live here:
-- fp paged decode is TOKEN-BIT-EXACT vs the dense `SlotKVCache` path on
-  the serving parity grid (staggered mixed-config requests, page-boundary
-  crossings, slot + page reuse), reusing the engine's existing parity
-  machinery (tokens == an independent generate() call per request).
+- fp paged decode emits, token for token, what an independent generate()
+  call per request emits, on the serving parity grid (staggered
+  mixed-config requests, page-boundary crossings, slot + page reuse).
 - chunked prefill admits a long-prompt request during active decode and
   every in-flight stream keeps producing a token EVERY tick, bounded by
   the per-tick chunk budget — no full-prefill stall.
@@ -14,8 +13,8 @@ The acceptance contracts live here:
   the free-page pool cannot cover a request's worst-case page demand, and
   the SAME request succeeds after a release.
 - int8 pages pass a tolerance gate vs the dequantized fp reference, and
-  the paged cache admits >= 2x the dense cache's concurrent requests at
-  the same HBM budget (>= 4x with int8 pages).
+  the pool admits >= 2x the concurrent requests of one worst-case row a
+  slot at the same HBM budget (>= 4x with int8 pages).
 """
 
 import json
@@ -116,6 +115,9 @@ def test_page_lifecycle_acquire_append_release_reuse():
     with pytest.raises(ValueError):
         cache.release(slot)             # double free
 
+    with pytest.raises(ValueError):
+        cache.release(7)                # out of range: never held
+
     # reuse: the released pages are handed out again, lowest-first
     slot2 = cache.acquire("r2", 2)      # consumes the earlier reserve(2)
     assert slot2 == 0
@@ -128,11 +130,23 @@ def test_page_lifecycle_acquire_append_release_reuse():
     assert cache.reserve(4)             # released capacity reservable again
     cache.unreserve(4)
 
+    # slots: lowest free index first, None when every row is occupied, a
+    # freed row handed out again and counted as reused; one pool throughout
+    slot3 = cache.acquire("r3", 0)
+    assert (slot2, slot3) == (0, 1) and cache.active_count == 2
+    assert cache.acquire("r4", 0) is None
+    cache.release(slot2)
+    assert cache.free_count == 1
+    assert cache.acquire("r4", 0) == slot2
+    assert cache.reused_slot_count() == 1   # slot 0: r1, r2, r4
+    assert [s for s, _ in cache.assignments] == [0, 0, 1, 0]
+    assert cache.allocations == 1
+
 
 def test_paged_config_validation():
     base = dict(max_slots=2, max_len=16, prompt_buckets=(8,),
                 kv_cache="paged", page_size=4)
-    assert ServeConfig(**base).resolved_num_pages == 8  # dense-equivalent
+    assert ServeConfig(**base).resolved_num_pages == 8  # 2 rows of 16
     with pytest.raises(ValueError):
         ServeConfig(**{**base, "max_len": 18})          # not page-aligned
     with pytest.raises(ValueError):
@@ -149,24 +163,17 @@ def test_paged_config_validation():
         ServeConfig(**{**base, "kv_quant": "int4"})
     with pytest.raises(ValueError):
         ServeConfig(max_slots=2, max_len=16, prompt_buckets=(8,),
-                    kv_quant="int8")                    # paged-only knob
-    with pytest.raises(ValueError):
-        ServeConfig(max_slots=2, max_len=16, prompt_buckets=(8,),
-                    prefill_chunk_tokens=8)             # paged-only knob
-    with pytest.raises(ValueError):
-        ServeConfig(max_slots=2, max_len=16, prompt_buckets=(8,),
                     kv_cache="rowed")
 
 
-# -- the fp parity grid: paged == dense == generate(), bit for bit -----------
+# -- the fp parity grid: paged == generate(), bit for bit --------------------
 
 
-def test_paged_token_parity_vs_dense_and_generate(setup):
-    """Staggered mixed-config requests through 2 slots on BOTH caches:
-    every paged stream must equal the dense stream AND the independent
-    generate() call token-for-token (fp pages are a residency change, not
-    an arithmetic one), with decode writes crossing page boundaries and
-    pages recycled across requests."""
+def test_paged_token_parity_vs_generate(setup):
+    """Staggered mixed-config requests through 2 slots: every served
+    stream must equal the independent generate() call token-for-token (fp
+    pages are a residency change, not an arithmetic one), with decode
+    writes crossing page boundaries and pages recycled across requests."""
     cfg, params = setup
     rs = np.random.RandomState(0)
     gens = [GenerationConfig(max_new_tokens=6),                       # greedy
@@ -176,49 +183,40 @@ def test_paged_token_parity_vs_dense_and_generate(setup):
     prompts = [rs.randint(3, cfg.vocab_size, (n,)).tolist()
                for n in (5, 8, 3, 7)]
 
-    streams = {}
-    for kind in ("dense", "paged"):
-        engine = (make_engine(cfg, params) if kind == "paged" else
-                  ServeEngine(params, cfg, ServeConfig(
-                      max_slots=2, max_len=BUCKET + 8,
-                      prompt_buckets=(BUCKET,), max_queue=8,
-                      metrics_every=1, decode_span_every=1)))
-        handles = [engine.submit(ServeRequest(input_ids=p, gen=g, seed=i))
-                   for i, (p, g) in enumerate(zip(prompts[:2], gens[:2]))]
-        engine.step()
-        engine.step()
-        handles += [engine.submit(ServeRequest(input_ids=p, gen=g,
-                                               seed=i + 2))
-                    for i, (p, g) in enumerate(zip(prompts[2:], gens[2:]))]
-        engine.drain(timeout_s=120)
-        streams[kind] = [h.result(timeout=1) for h in handles]
-        if kind == "paged":
-            # slot AND page reuse: one pool allocation, pages recycled
-            assert engine.slots.allocations == 1
-            assert engine.slots.reused_slot_count() >= 1
-            assert engine.slots.pages_free == engine.slots.num_pages
-            assert engine.slots.pages_reserved == 0
-            assert engine.slots.page_allocations > max(
-                engine.slots.demand_pages(BUCKET, g.max_new_tokens)
-                for g in gens)        # reuse, not one giant reservation
-            snap = engine.metrics_snapshot()
-            assert snap["kv_cache"] == "paged"
-            assert snap["pages_total"] == 16
-            assert snap["requests_completed"] == 4
+    engine = make_engine(cfg, params)
+    handles = [engine.submit(ServeRequest(input_ids=p, gen=g, seed=i))
+               for i, (p, g) in enumerate(zip(prompts[:2], gens[:2]))]
+    engine.step()
+    engine.step()
+    handles += [engine.submit(ServeRequest(input_ids=p, gen=g, seed=i + 2))
+                for i, (p, g) in enumerate(zip(prompts[2:], gens[2:]))]
+    engine.drain(timeout_s=120)
+    streams = [h.result(timeout=1) for h in handles]
+    # slot AND page reuse: one pool allocation, pages recycled
+    assert engine.slots.allocations == 1
+    assert engine.slots.reused_slot_count() >= 1
+    assert engine.slots.pages_free == engine.slots.num_pages
+    assert engine.slots.pages_reserved == 0
+    assert engine.slots.page_allocations > max(
+        engine.slots.demand_pages(BUCKET, g.max_new_tokens)
+        for g in gens)        # reuse, not one giant reservation
+    snap = engine.metrics_snapshot()
+    assert snap["kv_cache"] == "paged"
+    assert snap["pages_total"] == 16
+    assert snap["requests_completed"] == 4
 
-    assert streams["paged"] == streams["dense"], \
-        "paged fp decode diverged from the dense slot cache"
     for i, (p, g) in enumerate(zip(prompts, gens)):
-        assert streams["paged"][i] == reference_tokens(params, cfg, p, g, i)
+        assert streams[i] == reference_tokens(params, cfg, p, g, i), \
+            f"request {i} diverged from its independent generate() call"
 
 
 @pytest.mark.slow  # funds the Request trace tier-1 rows: this is the fp32
 # parity grid above re-run in bf16 — a dtype variant of an identical
 # contract, not a new one; it stays pinned in the slow/round gate.
-def test_paged_token_parity_bit_exact_bf16(setup):
+def test_paged_token_parity_vs_generate_bf16(setup):
     """The same bit-parity contract in the serving compute dtype: bf16
-    paged streams equal the bf16 dense streams and the bf16 generate()
-    reference token-for-token (greedy + sampled)."""
+    served streams equal the bf16 generate() reference token-for-token
+    (greedy + sampled)."""
     import jax.numpy as jnp16  # noqa: F401  (clarity: dtype-only variant)
 
     cfg = LlamaConfig.tiny(dtype=jnp.bfloat16)
@@ -228,25 +226,16 @@ def test_paged_token_parity_bit_exact_bf16(setup):
             GenerationConfig(max_new_tokens=4, temperature=0.9, top_k=6)]
     prompts = [rs.randint(3, cfg.vocab_size, (n,)).tolist() for n in (5, 8)]
 
-    streams = {}
-    for kind in ("dense", "paged"):
-        kw = dict(max_slots=2, max_len=BUCKET + 8, prompt_buckets=(BUCKET,),
-                  max_queue=8, metrics_every=1, decode_span_every=1)
-        if kind == "paged":
-            kw.update(kv_cache="paged", page_size=PAGE, num_pages=16)
-        engine = ServeEngine(params, cfg, ServeConfig(**kw))
-        handles = [engine.submit(ServeRequest(input_ids=p, gen=g, seed=i))
-                   for i, (p, g) in enumerate(zip(prompts, gens))]
-        engine.drain(timeout_s=120)
-        streams[kind] = [h.result(timeout=1) for h in handles]
-    assert streams["paged"] == streams["dense"]
-    for i, (p, g) in enumerate(zip(prompts, gens)):
-        assert streams["paged"][i] == reference_tokens(params, cfg, p, g, i)
+    engine = make_engine(cfg, params)
+    handles = [engine.submit(ServeRequest(input_ids=p, gen=g, seed=i))
+               for i, (p, g) in enumerate(zip(prompts, gens))]
+    engine.drain(timeout_s=120)
+    for i, (h, p, g) in enumerate(zip(handles, prompts, gens)):
+        assert h.result(timeout=1) == reference_tokens(params, cfg, p, g, i)
 
 
 def test_paged_eos_finishes_row_early_and_frees_pages(setup):
-    """eos frees the slot AND its pages before the budget (the paged
-    counterpart of the dense eos row, which it subsumes)."""
+    """eos frees the slot AND its pages before the budget."""
     cfg, params = setup
     engine = make_engine(cfg, params, max_slots=1)
     prompt = np.random.RandomState(2).randint(3, cfg.vocab_size, (4,)).tolist()

@@ -419,8 +419,6 @@ def test_sharing_doubles_admissions_at_fixed_pool(setup):
 
 def test_cache_off_is_the_baseline_engine(setup):
     cfg, params = setup
-    with pytest.raises(ValueError, match="prefix_cache"):
-        ServeConfig(kv_cache="dense", prefix_cache=True)
     engine = make_engine(cfg, params, prefix_cache=False, max_slots=8)
     gen = GenerationConfig(max_new_tokens=8)
     for i in range(4):                            # 16 pages / demand 4

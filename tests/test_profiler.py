@@ -194,7 +194,7 @@ def test_serve_slo_breach_capture_under_traffic(tmp_path):
     eng = ServeEngine(
         params, cfg,
         ServeConfig(max_slots=2, max_len=64, prompt_buckets=(16,),
-                    max_queue=32),
+                    page_size=16, num_pages=32, max_queue=32),
         profiler=prof,
         slo=SLOThresholds(ttft_s=0.0))  # every completion breaches
     trace_reqs = serve_traffic.poisson_trace(

@@ -258,8 +258,8 @@ def test_tick_phases_add_up_and_dur_keeps_its_meaning():
     cfg = LlamaConfig.tiny()
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     engine = ServeEngine(params, cfg, ServeConfig(
-        max_slots=2, max_len=24, prompt_buckets=(16,), max_queue=8,
-        decode_span_every=3))
+        max_slots=2, max_len=24, prompt_buckets=(16,), page_size=8,
+        max_queue=8, decode_span_every=3))
     spans, ticks = [], []
     listener = lambda rec: spans.append(dict(rec))
     trace.recorder().add_listener(listener)

@@ -4,7 +4,7 @@ docs/SERVING.md).
 The two acceptance contracts live here:
 - e2e: staggered requests through the scheduler return TOKEN-IDENTICAL
   outputs to independent generate() calls with the same per-request seeds,
-  with slot reuse (one cache allocation, a slot serving two requests) and
+  with slot reuse (one pool allocation, a slot serving two requests) and
   TTFT/TPOT/queue-wait records in the spans + metrics streams.
 - multi-replica: two serve processes under tools/supervisor.py, one
   SIGKILLed mid-decode, restarted from the same checkpoint by the
@@ -40,7 +40,6 @@ from llama_pipeline_parallel_tpu.serve import (
     ServeLoop,
     ServeOverloaded,
     ServeRequest,
-    SlotKVCache,
 )
 from llama_pipeline_parallel_tpu.serve.telemetry import (
     SLOStats,
@@ -61,8 +60,13 @@ def setup():
 
 
 def make_engine(cfg, params, **kw):
+    # a page of half the bucket: every row crosses page boundaries; a pool
+    # that covers the reservations of a full queue beside the running rows
+    # (a request reserves its worst case when it is submitted), so it is
+    # the queue's bound these tests meet, not the pool's
     defaults = dict(max_slots=2, max_len=BUCKET + 8, prompt_buckets=(BUCKET,),
-                    max_queue=8, metrics_every=1, decode_span_every=1)
+                    page_size=BUCKET // 2, num_pages=40, max_queue=8,
+                    metrics_every=1, decode_span_every=1)
     defaults.update(kw)
     return ServeEngine(params, cfg, ServeConfig(**defaults))
 
@@ -82,18 +86,22 @@ def reference_tokens(params, cfg, prompt, gen, seed):
 # -- the e2e acceptance test -------------------------------------------------
 
 
-def test_continuous_batching_token_parity_and_telemetry(setup, tmp_path):
+@pytest.mark.parametrize("page_size", [BUCKET // 2, BUCKET])
+def test_continuous_batching_token_parity_and_telemetry(setup, tmp_path,
+                                                        page_size):
     """Staggered arrivals through 2 slots: every request's stream matches
     its independent generate() call; slot reuse is proven (one allocation,
     slots serving two requests each); TTFT/TPOT/queue-wait land in both
-    telemetry streams."""
+    telemetry streams. At a page of half the bucket rows cross page
+    boundaries while slots are reused; at a page the bucket long a prompt
+    is one page and the first decode write claims the second."""
     from llama_pipeline_parallel_tpu.utils.metrics import MetricsWriter
 
     cfg, params = setup
     trace.configure(str(tmp_path))
     writer = MetricsWriter(str(tmp_path))
     try:
-        engine = make_engine(cfg, params)
+        engine = make_engine(cfg, params, page_size=page_size)
         engine._metrics_writer = writer
         rs = np.random.RandomState(0)
         gens = [GenerationConfig(max_new_tokens=6),                       # greedy
@@ -117,12 +125,18 @@ def test_continuous_batching_token_parity_and_telemetry(setup, tmp_path):
             assert h.result(timeout=1) == reference_tokens(params, cfg, p, g, i), \
                 f"request {i} diverged from its independent generate() call"
 
-        # slot reuse: the cache was allocated once and at least one slot
+        # slot reuse: the pool was allocated once and at least one slot
         # served two requests (4 requests > 2 slots force it)
         assert engine.slots.allocations == 1
         assert engine.slots.reused_slot_count() >= 1
         assert len(engine.slots.assignments) == 4
         assert engine.slots.free_count == 2  # all released
+        # ... with their pages: each request was handed exactly its worst
+        # case (no eos: every budget runs out), lazily, and none is left
+        assert engine.slots.page_size == page_size
+        assert engine.slots.pages_free == engine.slots.num_pages
+        assert engine.slots.page_allocations == sum(
+            engine.slots.demand_pages(BUCKET, g.max_new_tokens) for g in gens)
 
         snap = engine.metrics_snapshot()
         assert snap["requests_completed"] == 4
@@ -161,9 +175,9 @@ def test_continuous_batching_token_parity_and_telemetry(setup, tmp_path):
     assert last["tokens_generated"] == sum(g.max_new_tokens for g in gens)
 
 
-@pytest.mark.slow  # the paged grid's eos row (test_paged_serving.py::
-# test_paged_eos_finishes_row_early_and_frees_pages) pins the same early-
-# free semantics every tier-1 run; this dense twin stays in the round gate
+@pytest.mark.slow  # test_paged_serving.py::
+# test_paged_eos_finishes_row_early_and_frees_pages pins the same early-
+# free semantics every tier-1 run; this twin stays in the round gate
 def test_eos_finishes_row_early_and_frees_slot(setup):
     """A request hitting eos frees its slot before the budget; the emitted
     stream ends with the eos token, matching generate()'s pre-pad prefix."""
@@ -232,23 +246,6 @@ def test_shutdown_fails_pending_and_blocks_late_submits(setup):
         engine.submit(ServeRequest(input_ids=[6], gen=small))
 
 
-def test_slot_manager_acquire_release():
-    cache = SlotKVCache(LlamaConfig.tiny(), max_slots=2, max_len=4)
-    a = cache.acquire("r1")
-    b = cache.acquire("r2")
-    assert {a, b} == {0, 1}
-    assert cache.acquire("r3") is None       # full
-    cache.release(a)
-    assert cache.acquire("r4") == a          # lowest free slot, reused
-    with pytest.raises(ValueError):
-        cache.release(7)                     # never held
-    cache.release(a)
-    with pytest.raises(ValueError):
-        cache.release(a)                     # double free
-    assert cache.reused_slot_count() == 1
-    assert cache.allocations == 1
-
-
 def test_serve_config_validation():
     with pytest.raises(ValueError):
         ServeConfig(prompt_buckets=())
@@ -260,10 +257,59 @@ def test_serve_config_validation():
         ServeConfig(max_queue=0)
 
 
+def test_the_paged_pool_is_the_only_kv_store(setup):
+    """`ServeConfig()` with no arguments is a page pool of one max_len row
+    a slot, and an engine built from a config that names no store runs the
+    paged tick; any other `kv_cache` is refused by a sentence that names
+    the pool."""
+    scfg = ServeConfig()
+    assert scfg.kv_cache == "paged"
+    assert scfg.resolved_num_pages == (scfg.max_slots * scfg.max_len
+                                       // scfg.page_size)
+    cfg, params = setup
+    engine = ServeEngine(params, cfg, ServeConfig(
+        max_slots=2, max_len=16, prompt_buckets=(8,), page_size=4))
+    assert type(engine.slots).__name__ == "PagedKVCache"
+    assert engine.metrics_snapshot()["kv_cache"] == "paged"
+    assert set(engine.slots.pool) == {"k", "v"}
+    for other in ("dense", "rowed"):
+        with pytest.raises(ValueError, match="paged pool"):
+            ServeConfig(kv_cache=other)
+
+
+@pytest.mark.parametrize("tool", ["serve", "serve_traffic"])
+def test_the_tools_have_no_flag_that_chooses_a_store(tool, capsys):
+    """`--kv_cache` is gone from both command lines: argparse refuses it
+    before anything is loaded."""
+    import importlib
+
+    cli = importlib.import_module(tool)    # tools/ on sys.path via conftest
+    assert os.path.samefile(cli.__file__,
+                            os.path.join(REPO, "tools", f"{tool}.py"))
+    with pytest.raises(SystemExit) as exit_:
+        cli.main(["--checkpoint_dir", "unused", "--output_dir", "unused",
+                  "--page_size", "8", "--kv_cache", "paged"])
+    assert exit_.value.code == 2
+    assert ("unrecognized arguments: --kv_cache paged"
+            in capsys.readouterr().err)
+
+
+def test_page_alignment_is_checked_for_every_engine():
+    """No engine has a row that is not whole pages: `max_len` and every
+    bucket are multiples of `page_size`, whether or not a caller names
+    the store."""
+    with pytest.raises(ValueError, match="max_len 40 must be a multiple"):
+        ServeConfig(max_len=40, prompt_buckets=(8, 16, 32), page_size=16)
+    with pytest.raises(ValueError, match="prompt bucket 8 must be a "
+                                         "multiple"):
+        ServeConfig(max_len=320, prompt_buckets=(8,))   # page_size 64
+    ServeConfig(max_len=320, prompt_buckets=(8,), page_size=8)
+
+
 def test_pick_bucket_prefers_smallest_fitting(setup):
     cfg, params = setup
     engine = ServeEngine(params, cfg, ServeConfig(
-        max_slots=1, max_len=40, prompt_buckets=(8, 16, 32)))
+        max_slots=1, max_len=40, prompt_buckets=(8, 16, 32), page_size=8))
     assert engine.pick_bucket(5, 4) == 8
     assert engine.pick_bucket(9, 4) == 16
     # 8-token budget pushes a 30-prompt past max_len on bucket 32 -> reject
@@ -500,7 +546,7 @@ def test_multi_replica_supervised_restart(setup, tmp_path):
                    "--host", "127.0.0.1", "--port", str(port),
                    "--platform", "cpu", "--max_slots", "2",
                    "--max_len", "320", "--buckets", "8",
-                   "--metrics_every", "1"]
+                   "--page_size", "8", "--metrics_every", "1"]
             env = dict(os.environ)
             # stretch decode steps so the kill lands mid-decode deterministically
             env["LPT_SERVE_STEP_DELAY_S"] = "0.05" if name == "a" else "0"

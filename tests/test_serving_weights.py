@@ -47,22 +47,20 @@ def _paths(tree) -> dict:
             for path, leaf in jax.tree_util.tree_leaves_with_path(tree)}
 
 
-def _engine(cfg, params, kv_cache: str = "paged") -> ServeEngine:
-    kw = dict(max_slots=SLOTS, max_len=MAX_LEN, prompt_buckets=(BUCKET,),
-              max_queue=8, metrics_every=1, decode_span_every=1,
-              kv_cache=kv_cache)
-    if kv_cache == "paged":
-        kw.update(page_size=PAGE, num_pages=NUM_PAGES)
-    return ServeEngine(params, cfg, ServeConfig(**kw))
+def _engine(cfg, params) -> ServeEngine:
+    return ServeEngine(params, cfg, ServeConfig(
+        max_slots=SLOTS, max_len=MAX_LEN, prompt_buckets=(BUCKET,),
+        max_queue=8, metrics_every=1, decode_span_every=1, page_size=PAGE,
+        num_pages=NUM_PAGES))
 
 
-def _built(cfg, params, make=None, **kw):
+def _built(cfg, params, make=None):
     """(engine, its `serve_weights_cast` spans): built under a listener."""
     spans = []
     listener = lambda rec: spans.append(dict(rec))
     trace.recorder().add_listener(listener)
     try:
-        engine = make() if make else _engine(cfg, params, **kw)
+        engine = make() if make else _engine(cfg, params)
     finally:
         trace.recorder().remove_listener(listener)
     return engine, [s for s in spans if s["name"] == "serve_weights_cast"]
@@ -71,8 +69,7 @@ def _built(cfg, params, make=None, **kw):
 # -- (a) the served tokens -----------------------------------------------------
 
 
-@pytest.mark.parametrize("kv_cache", ["paged", "dense"])
-def test_served_tokens_are_those_of_the_float32_tree(setup, kv_cache):
+def test_served_tokens_are_those_of_the_float32_tree(setup):
     """Greedy and sampled requests, staggered over two slots: the engine
     that holds converted weights serves what `generate()` computes from the
     float32 tree, and what the same engine serves when every program is
@@ -92,9 +89,9 @@ def test_served_tokens_are_those_of_the_float32_tree(setup, kv_cache):
         engine.drain(timeout_s=120)
         return [h.result(timeout=1) for h in handles]
 
-    held = _engine(cfg, params, kv_cache)
+    held = _engine(cfg, params)
     assert held.params["lm_head"].dtype == jnp.bfloat16
-    given = _engine(cfg, params, kv_cache)
+    given = _engine(cfg, params)
     given.params = params           # every program converts at each use again
     served = serve(held)
     assert served == serve(given)
@@ -119,9 +116,6 @@ def _program_args(program: str, cfg, params):
     if program == "prefill_prompt":
         ids = jnp.ones((1, BUCKET), jnp.int32)
         return (params, ids, ids, cfg, MAX_LEN)
-    if program == "decode_step":
-        cache = decode.init_kv_cache(cfg, SLOTS, MAX_LEN)
-        return (params, z, cache, z, z, kv_mask, *knobs, cfg)
     pool = decode.init_page_pool(cfg, NUM_PAGES, PAGE, "fp")
     if program == "paged_decode_step":
         return (params, z, pool,
@@ -151,7 +145,7 @@ def _weight_converts(text: str, params) -> list:
 
 @pytest.mark.parametrize("program", [
     "paged_decode_step", "prefill_prompt", "paged_prefill_chunk",
-    "paged_prefill_span", "decode_step"])
+    "paged_prefill_span"])
 def test_no_serving_program_converts_a_weight_it_is_given(setup, program):
     cfg, params = setup
     held = _engine(cfg, params).params
@@ -219,7 +213,7 @@ def test_the_hybrid_engine_holds_the_callers_own_tree():
     assert families.family_of(cfg).serving_weights is None
     params = hybrid_tiny.both_sides()[0]
     scfg = ServeConfig(max_slots=2, max_len=48, prompt_buckets=(8, 16),
-                       kv_cache="paged", page_size=8, num_pages=12)
+                       page_size=8, num_pages=12)
     engine, spans = _built(cfg, params,
                            make=lambda: ServeEngine(params, cfg, scfg))
     assert engine.params is params
@@ -229,11 +223,10 @@ def test_the_hybrid_engine_holds_the_callers_own_tree():
              s["bytes_held"]) for s in spans] == [(0, n, size, size)]
 
 
-@pytest.mark.parametrize("kv_cache", ["paged", "dense"])
-def test_the_dense_engine_records_what_it_converted(setup, kv_cache):
+def test_the_dense_engine_records_what_it_converted(setup):
     cfg, params = setup
     assert families.family_of(cfg).serving_weights is decode.serving_weights
-    engine, (span,) = _built(cfg, params, kv_cache=kv_cache)
+    engine, (span,) = _built(cfg, params)
     assert engine.params is not params
     assert (span["leaves_cast"], span["leaves_kept"]) == (9, 3)
     leaves = _paths(params)
@@ -243,7 +236,7 @@ def test_the_dense_engine_records_what_it_converted(setup, kv_cache):
     assert span["bytes_held"] == cast // 2 + kept
     assert span["dur"] > 0 and span["depth"] == 0
     # an engine built from the held tree has nothing left to convert
-    second, (again,) = _built(cfg, engine.params, kv_cache=kv_cache)
+    second, (again,) = _built(cfg, engine.params)
     assert second.params is engine.params
     assert (again["leaves_cast"], again["leaves_kept"]) == (0, 12)
     assert again["bytes_given"] == again["bytes_held"] == span["bytes_held"]

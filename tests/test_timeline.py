@@ -264,7 +264,7 @@ def test_serve_timeline_ticks(tmp_path):
     writer = tl.TimelineWriter(str(path))
     eng = ServeEngine(params, cfg,
                       ServeConfig(max_slots=2, max_len=96,
-                                  prompt_buckets=(16,)),
+                                  prompt_buckets=(16,), page_size=16),
                       timeline=writer)
     rs = np.random.RandomState(0)
     prompt = rs.randint(3, cfg.vocab_size, (12,)).tolist()
@@ -278,6 +278,11 @@ def test_serve_timeline_ticks(tmp_path):
     assert ticks and all("decode_s" in t and "prefill_s" in t for t in ticks)
     assert any(t["decode_s"] > 0 for t in ticks)
     assert any(t["active"] for t in ticks)
+    # the pool's occupancy rides every tick record: two 12-token prompts in
+    # a bucket of one page each
+    assert all({"pages_used", "pages_reserved", "fragmentation"} <= set(t)
+               for t in ticks)
+    assert max(t["pages_used"] for t in ticks) == 4
 
 
 # ---------------------------------------------------------------------------

@@ -78,28 +78,24 @@ def main(argv: list[str] | None = None) -> int:
                    help="ascending prompt bucket lengths (one prefill "
                         "compile each)")
     p.add_argument("--max_queue", type=int, default=64)
-    p.add_argument("--kv_cache", default="dense", choices=("dense", "paged"),
-                   help="paged: fixed-size KV pages + slot->page table — "
-                        "HBM tracks tokens actually generated "
-                        "(docs/SERVING.md)")
     p.add_argument("--page_size", type=int, default=64,
-                   help="tokens per KV page (paged only)")
+                   help="tokens per KV page; max_len and every bucket are "
+                        "multiples of it (docs/SERVING.md)")
     p.add_argument("--num_pages", type=int, default=None,
-                   help="page-pool size; default = dense-equivalent "
+                   help="page-pool size; default = one max_len row a slot "
                         "(max_slots * max_len / page_size)")
     p.add_argument("--kv_quant", default="fp", choices=("fp", "int8"),
                    help="int8: quantized KV pages with per-page scales, "
-                        "fp32 dequant on read (paged only)")
+                        "fp32 dequant on read")
     p.add_argument("--prefill_chunk_tokens", type=int, default=0,
                    help="per-tick prefill token budget; buckets above it "
                         "prefill in chunks interleaved with decode ticks "
-                        "(paged only; 0 = whole-prompt admissions)")
+                        "(0 = whole-prompt admissions)")
     p.add_argument("--prefix_cache", action="store_true",
                    help="share physical KV pages between requests with "
                         "identical prompt prefixes: cache hits skip the "
                         "shared span's prefill and reserve only their new "
-                        "pages (paged only; docs/SERVING.md 'Prefix "
-                        "caching')")
+                        "pages (docs/SERVING.md 'Prefix caching')")
     p.add_argument("--metrics_every", type=int, default=16,
                    help="completed requests per serving metrics line")
     p.add_argument("--health_interval", type=float, default=10.0,
@@ -166,8 +162,8 @@ def main(argv: list[str] | None = None) -> int:
         max_slots=args.max_slots, max_len=args.max_len,
         prompt_buckets=tuple(int(b) for b in args.buckets.split(",")),
         max_queue=args.max_queue, metrics_every=args.metrics_every,
-        kv_cache=args.kv_cache, page_size=args.page_size,
-        num_pages=args.num_pages, kv_quant=args.kv_quant,
+        page_size=args.page_size, num_pages=args.num_pages,
+        kv_quant=args.kv_quant,
         prefill_chunk_tokens=args.prefill_chunk_tokens,
         prefix_cache=args.prefix_cache)
     writer = MetricsWriter(args.output_dir)
@@ -222,21 +218,19 @@ def main(argv: list[str] | None = None) -> int:
     write_serve_json(args.output_dir, {
         "pid": os.getpid(), "host": args.host, "port": port,
         "checkpoint_dir": args.checkpoint_dir, "checkpoint_step": step,
-        "kv_cache": serve_cfg.kv_cache, "started": t_start})
+        "kv_cache": "paged", "started": t_start})
 
     # init window accounted like the trainer's: everything before the loop
     trace.recorder().emit("init", ts=t_start, dur=time.time() - t_start)
     hb_serve_cfg = {"max_slots": serve_cfg.max_slots,
                     "max_len": serve_cfg.max_len,
                     "prompt_buckets": list(serve_cfg.prompt_buckets),
-                    "kv_cache": serve_cfg.kv_cache}
-    if serve_cfg.kv_cache == "paged":
-        hb_serve_cfg.update(
-            page_size=serve_cfg.page_size,
-            num_pages=serve_cfg.resolved_num_pages,
-            kv_quant=serve_cfg.kv_quant,
-            prefill_chunk_tokens=serve_cfg.prefill_chunk_tokens,
-            prefix_cache=serve_cfg.prefix_cache)
+                    "kv_cache": "paged",
+                    "page_size": serve_cfg.page_size,
+                    "num_pages": serve_cfg.resolved_num_pages,
+                    "kv_quant": serve_cfg.kv_quant,
+                    "prefill_chunk_tokens": serve_cfg.prefill_chunk_tokens,
+                    "prefix_cache": serve_cfg.prefix_cache}
     hb = trace.Heartbeat(
         args.output_dir, clock, interval=args.health_interval,
         static={"role": "serve", "port": port,
@@ -253,14 +247,12 @@ def main(argv: list[str] | None = None) -> int:
         signal.signal(sig, _stop)
 
     step_delay = float(os.environ.get("LPT_SERVE_STEP_DELAY_S", "0") or 0)
-    kv_desc = f"{serve_cfg.max_slots} slots x {serve_cfg.max_len} kv"
-    if serve_cfg.kv_cache == "paged":
-        kv_desc = (f"{serve_cfg.max_slots} slots over "
-                   f"{serve_cfg.resolved_num_pages} x "
-                   f"{serve_cfg.page_size}-token {serve_cfg.kv_quant} pages"
-                   + (f", prefill chunk {serve_cfg.prefill_chunk_tokens}"
-                      if serve_cfg.prefill_chunk_tokens else "")
-                   + (", prefix cache" if serve_cfg.prefix_cache else ""))
+    kv_desc = (f"{serve_cfg.max_slots} slots over "
+               f"{serve_cfg.resolved_num_pages} x "
+               f"{serve_cfg.page_size}-token {serve_cfg.kv_quant} pages"
+               + (f", prefill chunk {serve_cfg.prefill_chunk_tokens}"
+                  if serve_cfg.prefill_chunk_tokens else "")
+               + (", prefix cache" if serve_cfg.prefix_cache else ""))
     print(f"[serve] ready on {args.host}:{port} — checkpoint step {step}, "
           f"{kv_desc}, buckets {serve_cfg.prompt_buckets}", flush=True)
     try:

@@ -14,7 +14,7 @@ metadata is exactly what generated its load.
 
     python tools/serve_traffic.py --checkpoint_dir /ckpts/run1 \
         --rate 8 --requests 64 --prompt_mix 64:0.6,256:0.4 \
-        --output_mix 16:0.5,64:0.5 --kv_cache paged --page_size 64 \
+        --output_mix 16:0.5,64:0.5 --page_size 64 \
         --prefill_chunk_tokens 256
 
 Determinism: the trace depends only on (seed, rate, n, mixes) — two runs
@@ -523,7 +523,7 @@ def main(argv: list[str] | None = None) -> int:
                         "prefix); pair with --prefix_cache to measure "
                         "hit-rate TTFT wins")
     p.add_argument("--prefix_cache", action="store_true",
-                   help="enable the engine's prefix cache (paged only)")
+                   help="enable the engine's prefix cache")
     p.add_argument("--time_scale", type=float, default=1.0,
                    help="replay arrivals at 1/time_scale speed")
     p.add_argument("--output_dir", default=None,
@@ -539,7 +539,6 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--max_len", type=int, default=2048)
     p.add_argument("--buckets", default="64,128,256,512,1024")
     p.add_argument("--max_queue", type=int, default=64)
-    p.add_argument("--kv_cache", default="dense", choices=("dense", "paged"))
     p.add_argument("--page_size", type=int, default=64)
     p.add_argument("--num_pages", type=int, default=None)
     p.add_argument("--kv_quant", default="fp", choices=("fp", "int8"))
@@ -605,9 +604,8 @@ def main(argv: list[str] | None = None) -> int:
     engine = ServeEngine(params, cfg, ServeConfig(
         max_slots=args.max_slots, max_len=args.max_len,
         prompt_buckets=tuple(int(b) for b in args.buckets.split(",")),
-        max_queue=args.max_queue, kv_cache=args.kv_cache,
-        page_size=args.page_size, num_pages=args.num_pages,
-        kv_quant=args.kv_quant,
+        max_queue=args.max_queue, page_size=args.page_size,
+        num_pages=args.num_pages, kv_quant=args.kv_quant,
         prefill_chunk_tokens=args.prefill_chunk_tokens,
         prefix_cache=args.prefix_cache),
         reqtrace=reqtrace_rec)
