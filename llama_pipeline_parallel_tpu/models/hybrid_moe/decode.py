@@ -33,6 +33,9 @@ from llama_pipeline_parallel_tpu.models.hybrid_moe.config import HybridMoEConfig
 from llama_pipeline_parallel_tpu.models.llama import decode as dense_decode
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.ops.attention import attention
+from llama_pipeline_parallel_tpu.ops.paged_attention import (
+    paged_decode_attention,
+)
 from llama_pipeline_parallel_tpu.utils import trace
 
 Params = dict
@@ -175,6 +178,7 @@ def tick_logits(params: Params, token: jnp.ndarray, pool: dict,
     w_page = jnp.where(active > 0, w_page, garbage)
     w_off = write_pos % page
     valid = (active > 0)[:, None]
+    live_pages = jnp.where(active > 0, write_pos // page + 1, 0)
 
     x = llama.embed(params, token[:, None], cfg)
 
@@ -185,9 +189,10 @@ def tick_logits(params: Params, token: jnp.ndarray, pool: dict,
             for name, rows in (("k", k), ("v", v)):
                 stores[name], _ = dense_decode._write_tokens(
                     stores[name], None, p, rows[:, 0], w_page, w_off, None)
-        gk, gv = dense_decode._gather_pages(stores, p, page_table, cfg.dtype)
         with jax.named_scope(trace.SCOPE_DECODE_ATTN):
-            out = attention(q, gk, gv, kv_mask, causal=False)
+            out = paged_decode_attention(
+                q[:, 0], stores["k"], stores["v"], p, page_table, live_pages,
+                kv_mask)[:, None]
         return hybrid.attn_output(layer, h, hidden, out, cfg), stores
 
     def kda_layer(layer, h, stores, index):
@@ -219,13 +224,14 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     """One decode tick over every slot row, the arguments of the dense
     `paged_decode_step` (`pos` is unused: no layer is rotary). A softmax
     layer writes this token's keys and values into (period, w_page, w_off)
-    and gathers each slot's logical row from its pages; a KDA layer reads its
-    rows of the recurrent store, applies one step of the recurrence and
-    writes them back. Rows that are not `active` leave both stores as they
-    were (their page writes go to the garbage page; their recurrence runs
-    with b = 0, a = 1) and are routed to no expert. Returns the dense
-    tick's outputs plus "counters" (int32[5], `COUNTERS`, summed over the
-    expert layers)."""
+    and attends each slot's live pages where they lie in the pool
+    (`ops/paged_attention.py`, its 8 query heads a KV head by shape; the
+    output gate stays outside); a KDA layer reads its rows of the recurrent
+    store, applies one step of the recurrence and writes them back. Rows
+    that are not `active` leave both stores as they were (their page writes
+    go to the garbage page; their recurrence runs with b = 0, a = 1) and are
+    routed to no expert. Returns the dense tick's outputs plus "counters"
+    (int32[5], `COUNTERS`, summed over the expert layers)."""
     del pos
     logits, pool, kv_mask, counters = tick_logits(
         params, token, pool, page_table, write_pos, kv_mask, active, cfg)

@@ -38,6 +38,9 @@ import jax.numpy as jnp
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.ops.attention import attention
+from llama_pipeline_parallel_tpu.ops.paged_attention import (
+    paged_decode_attention,
+)
 from llama_pipeline_parallel_tpu.ops.rmsnorm import rms_norm
 from llama_pipeline_parallel_tpu.ops.rope import apply_rope, rope_cos_sin
 from llama_pipeline_parallel_tpu.utils import trace
@@ -367,17 +370,20 @@ def prefill_prompt(params: Params, input_ids: jnp.ndarray,
 # and not one worst-case row a slot. The programs keep a static shape (one
 # compile each, no per-batch retracing): the logical view a slot sees is
 # `[max_len]` = `pages_per_slot * page_size`, reconstituted per layer by the
-# gather below. That is `generate()`'s cache row, so the fp path emits
-# `generate()`'s tokens: pages a slot does not own (the garbage page
-# included) only ever contribute through masked positions, whose scores are
-# the same NEG_INF constant and whose softmax weights are exactly 0.0.
+# gather below (the prefills, an int8 tick) or walked page by page where it
+# lies (the fp tick, `ops/paged_attention.py`). That is `generate()`'s cache
+# row, so the fp path emits `generate()`'s tokens: pages a slot does not own
+# (the garbage page included) only ever contribute through masked positions,
+# whose scores are the same NEG_INF constant and whose softmax weights are
+# exactly 0.0, or, in the fp tick, are not read at all.
 #
 # How the pool is walked: the three paged programs scan over (layer weights,
 # layer index) only and CARRY the whole pool through `_walk_pool`. A layer's
 # write is one scatter into the full pool at `[layer, page, offset]`, its
 # read one gather whose indices hold the layer too (`pool[layer,
-# page_table]`), so no slice of a whole layer's pages stands between the
-# pool and the work. The pool is never the scan's `xs`/`ys`: a scan's `ys`
+# page_table]`) or one kernel given the pool whole and the layer as an
+# index, so no slice of a whole layer's pages stands between the pool and
+# the work. The pool is never the scan's `xs`/`ys`: a scan's `ys`
 # is a fresh stacked array that a donated argument cannot alias, which cost
 # a layer-sized slice in, a layer-sized store out and a whole-pool copy
 # every tick (38% of the tick's busy time on the v5e, PERF.md PR 25). As a
@@ -450,9 +456,20 @@ def _gather_pages(pool: dict, layer_idx, page_table: jnp.ndarray, dtype):
                 gv.reshape(*lead, pmax * page, kvh, hd))
 
 
-def _walk_pool(params: Params, x: jnp.ndarray, pool: dict,
-               page_table: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
-               cfg: LlamaConfig, write, attend) -> tuple[jnp.ndarray, dict]:
+def _attend_gathered(q: jnp.ndarray, pool: dict, layer_idx,
+                     page_table: jnp.ndarray, mask: jnp.ndarray, dtype,
+                     **causality) -> jnp.ndarray:
+    """Attention of q ([b, s, h, hd]) over `page_table`'s logical rows of
+    layer `layer_idx`, gathered whole: the read of every program whose
+    queries are longer than one token or whose pages dequantize on read."""
+    gk, gv = _gather_pages(pool, layer_idx, page_table, dtype)
+    with jax.named_scope(trace.SCOPE_DECODE_ATTN):
+        return attention(q, gk, gv, mask, **causality)
+
+
+def _walk_pool(params: Params, x: jnp.ndarray, pool: dict, cos: jnp.ndarray,
+               sin: jnp.ndarray, cfg: LlamaConfig, write,
+               attend) -> tuple[jnp.ndarray, dict]:
     """Run the cached layers over the page pool IN PLACE: the one way the
     paged programs walk it. The scan's `xs` are the layer weights and the
     layer index; the pool (k, v and, for int8, their scales) rides in the
@@ -461,8 +478,10 @@ def _walk_pool(params: Params, x: jnp.ndarray, pool: dict,
     Per layer, in the order every cached layer runs: q/k/v projection,
     `write(pages, scales, layer_idx, rows) -> (pages, scales)` once for k
     and once for v (`scales` is None for fp pools; `rows` is [b, s, kv_h,
-    hd]), the gather of `page_table`'s logical rows, `attend(q, gk, gv)`,
-    output projection and MLP. Returns the hidden state and the pool."""
+    hd]), `attend(q, pool, layer_idx)` over the pool as just written (the
+    prefills and the int8 tick gather the logical rows, `_attend_gathered`;
+    the fp tick reads the pages where they lie), output projection and MLP.
+    Returns the hidden state and the pool."""
     def body(carry, xs):
         h, pool = carry
         layer, i = xs
@@ -474,9 +493,7 @@ def _walk_pool(params: Params, x: jnp.ndarray, pool: dict,
                     pool[name], pool.get(f"{name}_scale"), i, rows)
                 if scales is not None:
                     pool[f"{name}_scale"] = scales
-        gk, gv = _gather_pages(pool, i, page_table, cfg.dtype)
-        with jax.named_scope(trace.SCOPE_DECODE_ATTN):
-            attn_out = attend(q, gk, gv)
+        attn_out = attend(q, pool, i)
         return (_attn_out_and_mlp(layer, h, attn_out, cfg), pool), None
 
     (x, pool), _ = jax.lax.scan(
@@ -539,6 +556,55 @@ def _write_tokens(pages, scales, layer_idx, rows: jnp.ndarray,
     return pages, scales
 
 
+def tick_logits(params: Params, token: jnp.ndarray, pool: dict,
+                page_table: jnp.ndarray, pos: jnp.ndarray,
+                write_pos: jnp.ndarray, kv_mask: jnp.ndarray,
+                active: jnp.ndarray, cfg: LlamaConfig):
+    """The decode tick up to its logits: (float32 logits [S, V], pool,
+    kv_mask). `paged_decode_step` samples from these; the tests compare
+    them with the gathered rows' (tests/test_paged_serving.py)."""
+    b = token.shape[0]
+    page = pool["k"].shape[2]
+    garbage = pool["k"].shape[1] - 1
+    # .max(): active rows mark write_pos valid (as a generate() step), inactive
+    # rows keep whatever their mask row already says
+    kv_mask = kv_mask.at[jnp.arange(b), write_pos].max(
+        active.astype(kv_mask.dtype))
+    w_page = jnp.take_along_axis(page_table, (write_pos // page)[:, None],
+                                 axis=1)[:, 0]
+    w_page = jnp.where(active > 0, w_page, garbage)
+    w_off = write_pos % page
+
+    x = llama.embed(params, token[:, None], cfg)
+    cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta,
+                            dtype=cfg.dtype)
+
+    def write(pages, scales, i, rows):
+        # a decode write at offset 0 claims a fresh page with its own absmax
+        return _write_tokens(pages, scales, i, rows[:, 0], w_page, w_off,
+                             claimed=(w_off == 0)[:, None])
+
+    # the leading logical pages of a row that hold tokens: its write
+    # position's page and those before it; a row that is not decoding has
+    # none. Pages past them point at the garbage page (serve/pages.py gives
+    # decode pages as write_pos crosses into them) and are masked whole.
+    live_pages = jnp.where(active > 0, write_pos // page + 1, 0)
+
+    def attend(q, pool, i):
+        if "k_scale" in pool:
+            # int8 pages dequantize on read: the gathered rows
+            return _attend_gathered(q, pool, i, page_table, kv_mask,
+                                    cfg.dtype, causal=False)
+        with jax.named_scope(trace.SCOPE_DECODE_ATTN):
+            return paged_decode_attention(
+                q[:, 0], pool["k"], pool["v"], i, page_table, live_pages,
+                kv_mask)[:, None]
+
+    x, pool = _walk_pool(params, x, pool, cos, sin, cfg, write, attend)
+    x = llama.final_norm(params, x, cfg)
+    return llama.lm_head(params, x, cfg)[:, -1, :], pool, kv_mask
+
+
 @partial(jax.jit, static_argnames=("cfg",),
          donate_argnames=("pool", "kv_mask"))
 def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
@@ -562,43 +628,20 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     their kv writes are steered to the garbage page and their kv_mask rows
     left untouched: a slot can be MID-CHUNKED-PREFILL during the tick,
     already owning live pages and live mask spans that a stray write_pos=0
-    write would corrupt. The gathered logical view is [S, pages_per_slot *
-    page_size] == [S, max_len], so the fp path emits `generate()`'s tokens
-    (pinned in tests/test_paged_serving.py); int8 pools dequantize on read
-    and are tolerance-gated instead. Each layer writes this token's kv into
-    (layer, w_page, w_off) and gathers each slot's logical row from its
-    pages, in place (`_walk_pool`). Returns {"token": [S] next tokens,
-    "pool", "kv_mask", "keys"}; rope and write positions advance by one,
-    and the caller tracks them host-side."""
-    b = token.shape[0]
-    page = pool["k"].shape[2]
-    garbage = pool["k"].shape[1] - 1
-    # .max(): active rows mark write_pos valid (as a generate() step), inactive
-    # rows keep whatever their mask row already says
-    kv_mask = kv_mask.at[jnp.arange(b), write_pos].max(
-        active.astype(kv_mask.dtype))
-    w_page = jnp.take_along_axis(page_table, (write_pos // page)[:, None],
-                                 axis=1)[:, 0]
-    w_page = jnp.where(active > 0, w_page, garbage)
-    w_off = write_pos % page
-
-    x = llama.embed(params, token[:, None], cfg)
-    cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta,
-                            dtype=cfg.dtype)
-
-    def write(pages, scales, i, rows):
-        # a decode write at offset 0 claims a fresh page with its own absmax
-        return _write_tokens(pages, scales, i, rows[:, 0], w_page, w_off,
-                             claimed=(w_off == 0)[:, None])
-
-    def attend(q, gk, gv):
-        return attention(q, gk, gv, kv_mask, causal=False)
-
-    x, pool = _walk_pool(params, x, pool, page_table, cos, sin, cfg, write,
-                         attend)
-    x = llama.final_norm(params, x, cfg)
-    logits = llama.lm_head(params, x, cfg)[:, -1, :]
-
+    write would corrupt. A row's logical view is [pages_per_slot * page_size]
+    == [max_len], `generate()`'s cache row, so the fp path emits
+    `generate()`'s tokens wherever the logits do not tie within the
+    rounding of a softmax summed page by page (pinned in
+    tests/test_paged_serving.py); int8 pools dequantize on read and are
+    tolerance-gated instead. Each layer writes this token's kv into (layer,
+    w_page, w_off), in place (`_walk_pool`), and attends each slot's live
+    pages where they lie in the pool (`ops/paged_attention.py`; the choice
+    is the pool's dtype, there is no second fp path); an int8 pool's rows
+    are gathered and dequantized as the prefills gather theirs. Returns
+    {"token": [S] next tokens, "pool", "kv_mask", "keys"}; rope and write
+    positions advance by one, and the caller tracks them host-side."""
+    logits, pool, kv_mask = tick_logits(params, token, pool, page_table, pos,
+                                        write_pos, kv_mask, active, cfg)
     with jax.named_scope(trace.SCOPE_SAMPLE):
         split = jax.vmap(jax.random.split)(keys)        # [b, 2, 2]
         nxt = sample_rowwise(logits, temperature, top_k, top_p, split[:, 1])
@@ -626,12 +669,11 @@ def _prefill_slot_row(params: Params, input_ids: jnp.ndarray,
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
                             dtype=cfg.dtype)
 
-    def attend(q, gk, gv):
-        return attention(q, gk, gv, row_mask, causal=True,
-                         q_offset=write_start)
+    def attend(q, pool, i):
+        return _attend_gathered(q, pool, i, page_table_row[None], row_mask,
+                                cfg.dtype, causal=True, q_offset=write_start)
 
-    x, pool = _walk_pool(params, x, pool, page_table_row[None], cos, sin, cfg,
-                         write, attend)
+    x, pool = _walk_pool(params, x, pool, cos, sin, cfg, write, attend)
     x = llama.final_norm(params, x[:, -1:, :], cfg)
     logits = llama.lm_head(params, x, cfg)
     return {"logits": logits[:, -1], "pool": pool, "kv_mask": kv_mask}

@@ -385,6 +385,10 @@ class ServeEngine:
         self._tick_count = 0
         self._tick_active = 0
         self._tick_tokens = 0            # rows that decoded, summed over ticks
+        # logical pages of the decoding rows that hold tokens, and that
+        # their page-table rows have: the share of a whole-row read that
+        # the tick's attention still makes (ops/paged_attention.py)
+        self._tick_pages = [0, 0]        # live, table
         self._tick_phases = [0.0, 0.0, 0.0, 0.0]  # stage, dispatch, wait, emit
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
@@ -956,6 +960,7 @@ class ServeEngine:
             temps = np.zeros(S, np.float32)
             top_ks = np.zeros(S, np.int32)
             top_ps = np.ones(S, np.float32)
+            pages_live = 0
             for slot, r in self._occupants.items():
                 token[slot] = r.token
                 pos[slot] = r.pos
@@ -964,7 +969,10 @@ class ServeEngine:
                 temps[slot] = r.request.gen.temperature
                 top_ks[slot] = r.request.gen.top_k
                 top_ps[slot] = r.request.gen.top_p
+                pages_live += r.write_pos // scfg.page_size + 1
             n_active = len(self._occupants)
+            self._tick_pages[0] += pages_live
+            self._tick_pages[1] += n_active * self.slots.page_table.shape[1]
 
         t_wall = time.time()
         t0 = time.perf_counter()
@@ -1041,7 +1049,9 @@ class ServeEngine:
         the aggregation — only the spans.jsonl line rate drops from token
         rate. `tokens` is the host's own count of the rows that decoded over
         those ticks (`active` is the last tick's alone). `phases`: this
-        tick's (stage, dispatch, wait, emit) seconds, summed the same way."""
+        tick's (stage, dispatch, wait, emit) seconds, summed the same way,
+        as are `kv_pages_live` and `kv_pages_table` (`_decode_tick` counts
+        them where it stages the rows)."""
         if self._tick_count == 0:
             self._tick_ts = ts
         self._tick_accum += dur
@@ -1060,11 +1070,15 @@ class ServeEngine:
         trace.recorder().emit("serve_decode_step", ts=self._tick_ts,
                               dur=self._tick_accum, ticks=self._tick_count,
                               active=self._tick_active,
-                              tokens=self._tick_tokens, stage_s=stage_s,
-                              dispatch_s=dispatch_s, wait_s=wait_s,
-                              emit_s=emit_s, **self._tick_counters)
+                              tokens=self._tick_tokens,
+                              kv_pages_live=self._tick_pages[0],
+                              kv_pages_table=self._tick_pages[1],
+                              stage_s=stage_s, dispatch_s=dispatch_s,
+                              wait_s=wait_s, emit_s=emit_s,
+                              **self._tick_counters)
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
+        self._tick_pages = [0, 0]
         self._tick_phases = [0.0, 0.0, 0.0, 0.0]
         self._tick_counters = dict.fromkeys(self._tick_counters, 0)
 

@@ -132,7 +132,7 @@ HYBRID_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 
-# `name=` of the nine pallas_calls: the kernel's instruction in a trace is
+# `name=` of the ten pallas_calls: the kernel's instruction in a trace is
 # `<name>.<n>`
 KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
@@ -143,6 +143,7 @@ KERNEL_CE_BWD_DW = "ce_bwd_dw"
 KERNEL_PROLOGUE_FWD = "prologue_fwd"
 KERNEL_PROLOGUE_BWD_DX = "prologue_bwd_dx"
 KERNEL_PROLOGUE_BWD_DW = "prologue_bwd_dw"
+KERNEL_PAGED_DECODE_ATTN = "paged_decode_attn"   # under SCOPE_DECODE_ATTN
 
 KERNELS = tuple(v for k, v in sorted(globals().items())
                 if k.startswith("KERNEL_"))

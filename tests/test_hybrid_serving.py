@@ -154,6 +154,11 @@ def test_the_engine_serves_the_family_through_the_same_tick_and_spans():
     assert total["experts_hit"] <= total["routed_here"]
     assert all(set(("stage_s", "dispatch_s", "wait_s", "emit_s")) <= set(s)
                for s in ticks)
+    # the engine's own count of the pages the tick's attention reads, of
+    # those its rows' tables have (no family's business)
+    assert all(0 < s["kv_pages_live"] <= s["kv_pages_table"] for s in ticks)
+    assert sum(s["kv_pages_table"] for s in ticks) == \
+        decoded * (MAX_LEN // PAGE)
 
 
 def test_the_dense_family_is_handed_the_functions_it_always_called():
@@ -279,19 +284,64 @@ def test_both_stores_ride_the_period_loops_carry():
 
 
 def test_the_ticks_temporaries_are_smaller_than_either_store():
-    """Compiled with both stores large, the tick keeps them in place: the
-    temporaries stay under a quarter of the smaller one and the outputs
-    alias the donated stores."""
+    """With both stores large the tick keeps them in place: the outputs
+    alias the donated stores, and nothing as large as a pool array is made
+    inside the period loop but the in-place writes and the views of them
+    that the attention kernel reads.
+
+    The temporaries themselves are held for the chip, in
+    tests/test_paged_attention.py `test_a_tick_compiled_for_the_chip_keeps_
+    the_pool_in_place[hybrid]`: XLA:CPU runs the kernel through Pallas's
+    interpreter, whose loop over the grid carries, and so copies, every
+    operand of the kernel, the pool's arrays among them (as
+    tests/test_pool_walk.py says of the dense tick)."""
     cfg = tiny.config()
     _, pool, args = _tick_args(cfg, pages=4096)
     compiled = hybrid_decode.paged_decode_step.lower(*args, cfg).compile()
     analysis = compiled.memory_analysis()
     if analysis is None:
         pytest.skip("this backend reports no memory analysis")
-    # the logical rows gathered for attention are MAX_LEN long whatever the
-    # pool holds, so the pool can be made much larger than they are
-    assert analysis.temp_size_in_bytes < pool["k"].nbytes // 4, analysis
     assert analysis.alias_size_in_bytes >= sum(x.nbytes for x in pool.values())
+
+    jaxpr = jax.make_jaxpr(
+        lambda *a: hybrid_decode.paged_decode_step(*a, cfg))(*args).jaxpr
+    loop, = [e for e in _equations(jaxpr) if e.primitive.name == "scan"
+             and e.params["length"] == cfg.periods]
+    body = loop.params["jaxpr"].jaxpr
+    large = [e for e in _equations(body) for out in e.outvars
+             if getattr(out.aval, "size", 0) >= pool["k"].size]
+    assert sorted(e.primitive.name for e in large) == [
+        "reshape", "reshape", "scatter", "scatter"], large
+    kernel, = [e for e in _equations(body)
+               if e.primitive.name == "pallas_call"]
+    assert {e.outvars[0] for e in large
+            if e.primitive.name == "reshape"} <= set(kernel.invars)
+
+
+def test_the_tick_reads_its_pages_where_they_lie():
+    """The softmax layer's one-query attention is the paged kernel
+    (ops/paged_attention.py): no gather of the slots' logical rows [S, Pmax,
+    page, kv_h, hd], and no `repeat_kv` broadcast of the 2 KV heads to the 4
+    query heads ([b, s, kv_h, n_rep, hd]): grouped queries share a KV
+    head's rows by shape. The output gate and the projections are outside
+    it, as they were."""
+    cfg = tiny.config()
+    _, pool, args = _tick_args(cfg)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: hybrid_decode.paged_decode_step(*a, cfg))(*args).jaxpr
+    kernels = [e for e in _equations(jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in kernels] == [
+        trace.KERNEL_PAGED_DECODE_ATTN]
+    rows = (SLOTS, MAX_LEN // PAGE) + pool["k"].shape[2:]
+    assert not [e for e in _equations(jaxpr) if e.primitive.name == "gather"
+                and tuple(e.outvars[0].aval.shape) == rows]
+    assert cfg.num_attention_heads > cfg.kv_heads
+    grouped = (cfg.kv_heads, cfg.num_attention_heads // cfg.kv_heads,
+               cfg.head_dim)
+    assert not [e for e in _equations(jaxpr)
+                if e.primitive.name == "broadcast_in_dim"
+                and tuple(e.outvars[0].aval.shape[-3:]) == grouped]
 
 
 # -- what cannot run yet ----------------------------------------------------------

@@ -1,0 +1,289 @@
+"""ops/paged_attention.py: the decode tick's one-query attention over the
+page pool, against what it replaced (`decode._gather_pages` + `attention`)
+on the same pool.
+
+Tolerances, and where they come from. Both paths keep float32 scores, softmax
+statistics and accumulation. In float32 they differ by the order of the sums
+alone (page by page against the whole row): 1e-5 on outputs of order 1. In
+bfloat16 both round the softmax weights to bfloat16 for the value product,
+the gather path after normalizing them and the kernel before (relative 2^-9
+either way, at different rounding points), and both round the result to
+bfloat16 once (half an ulp, 2^-9 relative): two ulps of the result, rtol
+2^-6, and 2^-7 absolute for results near zero, where the weights' rounding is
+an absolute error of 2^-9 x sum |p v|.
+
+The last tests compile for a DESCRIBED v5e (no chip: section 2 of the
+on-chip-measurement guide): Mosaic at both serving cells' shapes, and the
+whole fp tick's memory, which the CPU interpreter cannot show (it copies
+every operand of the kernel, the pool among them). They are kept in this
+one file: one process at a time may load the TPU's library.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from llama_pipeline_parallel_tpu.models.hybrid_moe import decode as hybrid_decode
+from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid
+from llama_pipeline_parallel_tpu.models.hybrid_moe.config import HybridMoEConfig
+from llama_pipeline_parallel_tpu.models.llama import decode
+from llama_pipeline_parallel_tpu.models.llama import model as llama
+from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
+from llama_pipeline_parallel_tpu.ops import paged_attention
+from llama_pipeline_parallel_tpu.ops.attention import attention
+
+L, PAGES, PAGE, KV_H, HD, PMAX = 2, 9, 8, 2, 128, 4
+GARBAGE = PAGES            # the pool's extra page: what dead logical pages name
+LAYER = 1
+TOL = {jnp.float32: dict(rtol=1e-5, atol=1e-5),
+       jnp.bfloat16: dict(rtol=2 ** -6, atol=2 ** -7)}
+
+
+def _upto(n):
+    """A mask row valid on [0, n)."""
+    return (np.arange(PMAX * PAGE) < n).astype(np.int32)
+
+
+def _scenario(name):
+    """(page_table [S, PMAX], live_pages [S], kv_mask [S, PMAX * PAGE]) of
+    two slot rows. Dead logical pages name the garbage page, as
+    serve/pages.py leaves them."""
+    table = np.full((2, PMAX), GARBAGE, np.int32)
+    mask = np.zeros((2, PMAX * PAGE), np.int32)
+    if name == "mask_with_holes":
+        # left pads inside a prompt bucket and stray holes, two pages live
+        table[:, :2] = [[3, 1], [0, 6]]
+        live = [2, 2]
+        mask[0] = _upto(13)
+        mask[0, [0, 1, 2, 7, 9]] = 0
+        mask[1] = _upto(16)
+        mask[1, :5] = 0
+    elif name == "wholly_masked_page_inside_live_range":
+        table[:, :3] = [[3, 1, 2], [4, 5, 6]]
+        live = [3, 3]
+        mask[0] = _upto(20)
+        mask[0, PAGE:2 * PAGE] = 0          # the middle page
+        mask[1] = _upto(17)
+        mask[1, :PAGE] = 0                  # the first page: a long left pad
+    elif name == "one_live_page":
+        table[:, 0] = [7, 2]
+        live = [1, 1]
+        mask[0] = _upto(1)                  # the token attends to itself only
+        mask[1] = _upto(PAGE)
+    elif name == "every_page_live":
+        table[:] = [[0, 1, 2, 3], [4, 5, 6, 7]]
+        live = [PMAX, PMAX]
+        mask[0] = _upto(PMAX * PAGE)
+        mask[1] = _upto(PMAX * PAGE - 3)
+        mask[1, 2] = 0
+    elif name == "inactive_row":
+        # row 0 is not decoding: mid-prefill, it owns pages and mask spans
+        table[:, :2] = [[3, 1], [0, 6]]
+        live = [0, 2]
+        mask[0] = _upto(11)
+        mask[1] = _upto(9)
+    elif name == "dead_pages_name_the_garbage_page":
+        # three of four logical pages dead, a fresh page whose tail is stale
+        table[:, 0] = [5, 8]
+        live = [1, 1]
+        mask[0] = _upto(3)
+        mask[1] = _upto(6)
+    elif name == "a_physical_page_shared_across_slots":
+        # a forked prefix: both rows read pages 2 and 4, then their own
+        table[:, :3] = [[2, 4, 1], [2, 4, 7]]
+        live = [3, 3]
+        mask[0] = _upto(21)
+        mask[1] = _upto(18)
+    else:
+        raise AssertionError(name)
+    return table, np.asarray(live, np.int32), mask
+
+
+SCENARIOS = ["mask_with_holes", "wholly_masked_page_inside_live_range",
+             "one_live_page", "every_page_live", "inactive_row",
+             "dead_pages_name_the_garbage_page",
+             "a_physical_page_shared_across_slots"]
+
+
+def _pool(dtype, seed):
+    rng = np.random.default_rng(seed)
+    shape = (L, PAGES + 1, PAGE, KV_H, HD)
+    k, v = rng.normal(size=shape), rng.normal(size=shape)
+    # what must never reach an output: it would dwarf every real value
+    k[:, GARBAGE], v[:, GARBAGE] = 3e4, -3e4
+    return jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+
+
+def _both_paths(q, k, v, table, live, mask):
+    out = paged_attention.paged_decode_attention(
+        q, k, v, jnp.int32(LAYER), jnp.asarray(table), jnp.asarray(live),
+        jnp.asarray(mask))
+    gk, gv = decode._gather_pages({"k": k, "v": v}, LAYER, jnp.asarray(table),
+                                  q.dtype)
+    ref = attention(q[:, None], gk, gv, jnp.asarray(mask), causal=False)[:, 0]
+    assert out.shape == ref.shape and out.dtype == ref.dtype == q.dtype
+    return np.asarray(out, np.float32), np.asarray(ref, np.float32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("g", [1, 4, 8])
+@pytest.mark.parametrize("scenario", SCENARIOS)
+def test_the_kernel_is_the_gather_and_the_product(scenario, g, dtype):
+    table, live, mask = _scenario(scenario)
+    k, v = _pool(dtype, seed=g)
+    q = jnp.asarray(np.random.default_rng(7).normal(size=(2, KV_H * g, HD)),
+                    dtype)
+    out, ref = _both_paths(q, k, v, table, live, mask)
+    assert np.isfinite(out).all()
+    for row in range(2):
+        if live[row] == 0:
+            # its token is discarded by the scheduler, but it rides the
+            # static shape into the layers after: zeros, never NaN
+            assert not out[row].any()
+        else:
+            np.testing.assert_allclose(out[row], ref[row], **TOL[dtype])
+            assert np.abs(out[row]).max() < 10.0     # no garbage page in it
+
+
+@pytest.mark.parametrize("step_bytes,n", [
+    (0, 1), (3 * 2 * PAGE * KV_H * HD * 4, 3), (1 << 30, PMAX)])
+def test_pages_per_step_follow_the_shapes_and_do_not_move_the_result(
+        monkeypatch, step_bytes, n):
+    """One page a step, a number that does not divide the row's pages (the
+    last step's spare blocks clamp and are skipped), the whole row a step."""
+    monkeypatch.setattr(paged_attention, "_STEP_BYTES", step_bytes)
+    assert paged_attention._pages_per_step(PMAX, PAGE * KV_H * HD * 4) == n
+    table, live, mask = _scenario("every_page_live")
+    live[1] = 3
+    mask[1] = _upto(2 * PAGE + 5)
+    k, v = _pool(jnp.float32, seed=3)
+    q = jnp.asarray(np.random.default_rng(8).normal(size=(2, KV_H * 4, HD)),
+                    jnp.float32)
+    out, ref = _both_paths(q, k, v, table, live, mask)
+    np.testing.assert_allclose(out, ref, **TOL[jnp.float32])
+
+
+def test_pages_per_step_at_the_serving_cells_shapes():
+    """bf16 pages of 64 tokens: the dense cell's 32 KV heads make a page
+    0.5 MB of keys (1 a step), the hybrid's 8 make it 0.125 MB (4)."""
+    assert paged_attention._pages_per_step(40, 64 * 32 * 128 * 2) == 1
+    assert paged_attention._pages_per_step(40, 64 * 8 * 128 * 2) == 4
+
+
+# -- compiled for a described v5e ---------------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The kernel as the chip runs it: `interpret_mode()` asks the backend,
+    which is the CPU here whatever the program is compiled for."""
+    monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+def _described(tree, sharding):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
+@pytest.mark.parametrize("cell,slots,h,kv_h,layers,pages", [
+    ("serve-closed-16.deepseek", 16, 32, 32, 4, 640),
+    ("serve-closed-64.solar-open2", 64, 64, 8, 1, 2560),
+])
+def test_mosaic_compiles_the_kernel_at_a_serving_cells_shapes(
+        one_chip, mosaic, cell, slots, h, kv_h, layers, pages):
+    """Page 64, rows of 40 logical pages, bf16: Mosaic takes the block
+    shapes (the hybrid's 8-head page block too), and XLA:TPU reads the pool
+    through a bitcast: nothing pool-sized is made in front of the kernel."""
+    pmax, page, hd = 40, 64, 128
+    pool = jax.ShapeDtypeStruct((layers, pages + 1, page, kv_h, hd),
+                                jnp.bfloat16)
+    args = _described(
+        (jax.ShapeDtypeStruct((slots, h, hd), jnp.bfloat16), pool, pool,
+         jax.ShapeDtypeStruct((), jnp.int32),
+         jax.ShapeDtypeStruct((slots, pmax), jnp.int32),
+         jax.ShapeDtypeStruct((slots,), jnp.int32),
+         jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)), one_chip)
+    compiled = jax.jit(paged_attention.paged_decode_attention).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "paged_decode_attn" in text and "tpu_custom_call" in text
+    pool_bytes = layers * (pages + 1) * page * kv_h * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 16
+
+
+def _dense_tick(slots, pmax, page, pages):
+    cfg = LlamaConfig(vocab_size=256, hidden_size=2048, intermediate_size=256,
+                      num_hidden_layers=2, num_attention_heads=16,
+                      num_key_value_heads=16, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: decode.init_page_pool(cfg, pages, page))
+    return decode.paged_decode_step, cfg, params, pool
+
+
+def _hybrid_tick(slots, pmax, page, pages):
+    cfg = HybridMoEConfig(
+        vocab_size=256, hidden_size=256, num_hidden_layers=4,
+        num_attention_heads=64, num_key_value_heads=8, kda_heads=2,
+        kda_rank=16, router_experts=16, experts_held=8,
+        num_experts_per_tok=4, moe_intermediate_size=64,
+        shared_intermediate_size=64)
+    params = jax.eval_shape(
+        lambda: hybrid.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: {
+        **hybrid_decode.init_page_pool(cfg, pages, page),
+        **hybrid_decode.init_recurrent_store(cfg, slots)})
+    return hybrid_decode.paged_decode_step, cfg, params, pool
+
+
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_a_tick_compiled_for_the_chip_keeps_the_pool_in_place(
+        one_chip, mosaic, family):
+    """What the compiled checks of tests/test_pool_walk.py and
+    tests/test_hybrid_serving.py held before the kernel (XLA:CPU's
+    interpreter copies the kernel's operands): compiled for the chip with a
+    pool many times its weights, the tick's temporaries stay under a
+    sixteenth of one pool array and the outputs are the donated stores'
+    buffers. Widths at which the pool's `[page * kv_h, hd]` view is a
+    bitcast for XLA:TPU, as at both cells': bf16 heads of 128, 16 KV heads
+    (dense) and the hybrid's own 8 under 64 query heads."""
+    slots, pmax, page = 4, 8, 64
+    tick, cfg, params, pool = (_dense_tick if family == "dense"
+                               else _hybrid_tick)(slots, pmax, page, 2048)
+    z = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    f = jax.ShapeDtypeStruct((slots,), jnp.float32)
+    args = _described(
+        (params, z, pool, jax.ShapeDtypeStruct((slots, pmax), jnp.int32), z,
+         z, jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32), z,
+         jax.ShapeDtypeStruct((slots, 2), jnp.uint32), f, z, f), one_chip)
+    compiled = tick.lower(*args, cfg).compile()
+    analysis = compiled.memory_analysis()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    assert nbytes(pool["k"]) > 5 * nbytes(params)
+    assert analysis.temp_size_in_bytes < nbytes(pool["k"]) // 16, analysis
+    assert analysis.alias_size_in_bytes >= nbytes(pool)
+    assert "paged_decode_attn" in compiled.as_text()

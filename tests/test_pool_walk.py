@@ -1,6 +1,8 @@
 """How the paged programs walk the page pool (models/llama/decode.py
-`_walk_pool`): the pool is the layer loop's CARRY, written and read through
-full-pool scatters and gathers, never the loop's `xs` / `ys`.
+`_walk_pool`): the pool is the layer loop's CARRY, written through full-pool
+scatters and read through full-pool gathers or, by the fp decode tick, by a
+kernel given the pool whole (ops/paged_attention.py), never the loop's `xs`
+/ `ys`.
 
 These are structural tests of the traced programs, not of their results (the
 parity tests in test_paged_serving.py / test_prefix_cache.py are the
@@ -118,18 +120,89 @@ def test_the_pool_is_carried_through_the_layer_loop(program, quant):
                 f"{out.aval}")
 
 
+def _gathers_of_logical_rows(jaxpr, pool, table_shape):
+    """Gathers whose result is `page_table`'s logical rows of one layer:
+    [*table_shape, page, kv_h, hd]."""
+    rows = tuple(table_shape) + pool["k"].shape[2:]
+    return [e for e in _equations(jaxpr) if e.primitive.name == "gather"
+            and tuple(e.outvars[0].aval.shape) == rows]
+
+
+def _kernels(jaxpr):
+    return [e for e in _equations(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("quant", ["fp", "int8"])
+@pytest.mark.parametrize("program", ["paged_decode_step",
+                                     "paged_prefill_chunk",
+                                     "paged_prefill_span"])
+def test_only_the_fp_tick_reads_its_pages_where_they_lie(program, quant):
+    """One query a row over fp pages: the kernel walks the page table
+    (ops/paged_attention.py) and nothing gathers a slot's logical rows. The
+    choice is made by what the program can see: an int8 pool dequantizes on
+    read and the prefills' queries are longer than one token; those three
+    gather as they did, keys and values (and an int8 pool's scales)."""
+    cfg, pool, args = _args(program, quant)
+    fn = getattr(decode, program)
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, cfg))(*args).jaxpr
+    table_shape = ((SLOTS, PAGES_PER_SLOT) if program == "paged_decode_step"
+                   else (1, PAGES_PER_SLOT))
+    gathers = _gathers_of_logical_rows(jaxpr, pool, table_shape)
+    kernels = _kernels(jaxpr)
+    if program == "paged_decode_step" and quant == "fp":
+        assert [e.params["name"] for e in kernels] == ["paged_decode_attn"]
+        assert not gathers
+        # grouped queries share a KV head's rows by shape: no `repeat_kv`
+        # broadcast of [b, s, kv_h, n_rep, hd] either
+        assert not [e for e in _equations(jaxpr)
+                    if e.primitive.name == "broadcast_in_dim"
+                    and len(e.outvars[0].aval.shape) == 5]
+    else:
+        assert not kernels
+        assert len(gathers) == 2
+
+
 @pytest.mark.parametrize("quant", ["fp", "int8"])
 def test_decode_tick_temporaries_are_smaller_than_one_pool(quant):
     """Compiled with a pool several times its weights, the tick's temporaries
-    stay under one pool array: the donated pool is updated in place."""
+    stay under one pool array: the donated pool is updated in place.
+
+    The fp tick's attention is a Pallas kernel, which XLA:CPU runs through
+    Pallas's interpreter: a loop over the grid that carries EVERY operand of
+    the kernel, the pool's two arrays among them (once a page block), and so
+    copies them. This backend's compiled memory says nothing about that
+    tick any more: its compiled check is made for the chip itself, in
+    tests/test_paged_attention.py
+    `test_the_fp_tick_compiled_for_the_chip_keeps_the_pool_in_place`. What
+    is held here for fp is the traced program: inside the layer loop
+    nothing as large as a pool array is made but the in-place writes and
+    the kernel's views of their results, which the kernel reads."""
     cfg, pool, args = _args("paged_decode_step", quant, num_pages=16384)
+    weights = sum(a.nbytes for a in jax.tree.leaves(args[0]))
+    one_pool_array = pool["k"].nbytes
+    assert one_pool_array > 5 * weights
+    if quant == "fp":
+        jaxpr = jax.make_jaxpr(
+            lambda *a: decode.paged_decode_step(*a, cfg))(*args).jaxpr
+        body = _layer_loop(jaxpr, cfg.num_hidden_layers).params["jaxpr"].jaxpr
+        large = [e for e in _equations(body) for out in e.outvars
+                 if getattr(out.aval, "size", 0) >= pool["k"].size]
+        assert sorted(e.primitive.name for e in large) == [
+            "reshape", "reshape", "scatter", "scatter"], large
+        views = {e.outvars[0] for e in large if e.primitive.name == "reshape"}
+        written = {e.outvars[0] for e in large
+                   if e.primitive.name == "scatter"}
+        assert {e.invars[0] for e in large
+                if e.primitive.name == "reshape"} == written
+        kernel, = _kernels(body)
+        assert views <= set(kernel.invars)
+        assert all(out.aval.size < pool["k"].size // 4
+                   for out in kernel.outvars)
+        return
     compiled = decode.paged_decode_step.lower(*args, cfg).compile()
     analysis = compiled.memory_analysis()
     if analysis is None:
         pytest.skip("this backend reports no memory analysis")
-    weights = sum(a.nbytes for a in jax.tree.leaves(args[0]))
-    one_pool_array = pool["k"].nbytes
-    assert one_pool_array > 5 * weights
     assert analysis.temp_size_in_bytes < one_pool_array // 4, analysis
     # and the pool's buffers are the outputs' buffers
     assert analysis.alias_size_in_bytes >= sum(

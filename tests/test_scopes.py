@@ -18,6 +18,7 @@ from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.models.llama.manifest import StageManifest
 from llama_pipeline_parallel_tpu.ops import (
     flash_attention,
+    paged_attention,
     pallas_ce,
     pallas_prologue,
 )
@@ -50,11 +51,11 @@ def _lower_train_step(pp, schedule, microbatches):
     return step.lower(state, batch)
 
 
-def _lower_paged_decode():
+def _lower_paged_decode(quant="fp"):
     cfg = LlamaConfig.tiny(dtype=jnp.bfloat16)   # fp32 masters, as the cells
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     slots, pages_per_slot, page = 2, 4, 4
-    pool = decode.init_page_pool(cfg, slots * pages_per_slot, page)
+    pool = decode.init_page_pool(cfg, slots * pages_per_slot, page, quant)
     z = jnp.zeros((slots,), jnp.int32)
     return decode.paged_decode_step.lower(
         params, z, pool, jnp.zeros((slots, pages_per_slot), jnp.int32), z, z,
@@ -108,8 +109,15 @@ PROGRAMS = {
     "train_step_pp2_zb1": (
         lambda: _lower_train_step(2, "zb1", 4), "jit_train_step",
         {"pp_fwd", "pp_recompute", "pp_bwd", "pp_w", "pp_handoff"}),
+    # the fp tick gathers nothing: its attention is the `paged_decode_attn`
+    # kernel under `decode_attn` (a custom_call, so held to a scope below);
+    # an int8 pool's rows are still gathered and dequantized
     "paged_decode_step": (
         _lower_paged_decode, "jit_paged_decode_step",
+        {"embed", "attn_qkv", "kv_write", "decode_attn", "attn_out",
+         "decode_mlp", "final_norm", "lm_head", "sample", "cast_weights"}),
+    "paged_decode_step_int8": (
+        lambda: _lower_paged_decode("int8"), "jit_paged_decode_step",
         {"embed", "attn_qkv", "kv_write", "kv_gather", "decode_attn",
          "attn_out", "decode_mlp", "final_norm", "lm_head", "sample",
          "cast_weights"}),
@@ -177,15 +185,16 @@ def test_every_product_and_kernel_call_carries_a_leaf_scope(program, devices):
     (pallas_ce, ("KERNEL_CE_FWD", "KERNEL_CE_BWD_DH", "KERNEL_CE_BWD_DW")),
     (pallas_prologue, ("KERNEL_PROLOGUE_FWD", "KERNEL_PROLOGUE_BWD_DX",
                        "KERNEL_PROLOGUE_BWD_DW")),
+    (paged_attention, ("KERNEL_PAGED_DECODE_ATTN",)),
 ])
 def test_every_pallas_call_passes_its_name(module, kernels):
     source = inspect.getsource(module)
     calls = source.count("pl.pallas_call(")
-    assert calls == len(kernels) == 3
+    assert calls == len(kernels)
     for constant in kernels:
         assert source.count(f"name=trace.{constant},") == 1
         assert getattr(trace, constant) in trace.KERNELS
-    assert len(trace.KERNELS) == 9 == len(set(trace.KERNELS))
+    assert len(trace.KERNELS) == 10 == len(set(trace.KERNELS))
 
 
 def test_flash_kernel_name_reaches_the_lowered_program():
@@ -293,3 +302,40 @@ def test_tick_phases_add_up_and_dur_keeps_its_meaning():
     # the phases tile the tick but for the clock reads between them
     assert total <= sum(ticks)
     assert total == pytest.approx(sum(ticks), rel=0.05, abs=2e-3)
+
+
+def test_the_tick_counts_the_pages_it_reads_and_the_pages_its_rows_have():
+    """`kv_pages_live` and `kv_pages_table` on every `serve_decode_step`
+    span, summed over its ticks like `tokens`: a decoding row's live pages
+    are its write position's page and those before it (what
+    ops/paged_attention.py fetches), its table row has max_len / page_size.
+    Bucket 16, page 8, rows of 5 pages, 6 tokens: 5 ticks of 2 rows write
+    at 16..20, the third page."""
+    from llama_pipeline_parallel_tpu.serve import (
+        ServeConfig,
+        ServeEngine,
+        ServeRequest,
+    )
+
+    cfg = LlamaConfig.tiny()
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    engine = ServeEngine(params, cfg, ServeConfig(
+        max_slots=2, max_len=40, prompt_buckets=(16,), page_size=8,
+        max_queue=8, decode_span_every=2))
+    spans = []
+    listener = lambda rec: spans.append(dict(rec))
+    trace.recorder().add_listener(listener)
+    try:
+        for i in range(2):
+            engine.submit(ServeRequest(
+                input_ids=[5, 6, 7], seed=i,
+                gen=decode.GenerationConfig(max_new_tokens=6)))
+        engine.drain(timeout_s=120)
+        engine.shutdown()
+    finally:
+        trace.recorder().remove_listener(listener)
+    decode_spans = [s for s in spans if s["name"] == "serve_decode_step"]
+    assert [s["ticks"] for s in decode_spans] == [2, 2, 1]
+    assert [s["tokens"] for s in decode_spans] == [4, 4, 2]
+    assert [s["kv_pages_live"] for s in decode_spans] == [12, 12, 6]
+    assert [s["kv_pages_table"] for s in decode_spans] == [20, 20, 10]
