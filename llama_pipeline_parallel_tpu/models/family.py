@@ -5,8 +5,17 @@ What touches only the mask or the pool's page axis is `serve/pages.py`'s own.
 `serve/engine.py` and `serve/pages.py` take these from `family_of(cfg)` and
 call them with the arguments they always passed; they name no family's
 functions. The dense decoder (`models/llama/`) is one family, the hybrid
-block with recurrent layers and sparse experts (`models/hybrid_moe/`) the
-other. A third registers its configuration class below.
+block with recurrent layers and sparse experts (`models/hybrid_moe/`) another,
+the latent-attention block with an indexer, window layers and sparse experts
+(`models/latent_moe/`) the third. A fourth registers its configuration class
+below.
+
+A family may keep a store with one row a SLOT beside the page pool
+(`init_recurrent_store`: the hybrid block's recurrent state, the latent
+block's rings of its window layers). Whether it can prefill in chunks is a
+separate fact (`paged_prefill_chunk`): the latent block's chunk carries its
+rings forward from chunk to chunk, the hybrid block's programs cannot carry
+their state yet.
 
 `GenerationConfig` and `sample_rowwise` are the same for every family (the
 sampling of a row of logits) and are re-exported here.
@@ -36,7 +45,8 @@ class ServingFamily:
     paged_decode_step: Callable
     write_pages: Callable
     init_page_pool: Callable            # (cfg, num_pages, page_size, quant)
-    # (cfg, max_slots) -> the recurrent store's leaves, carried in the same
+    # (cfg, max_slots) -> the leaves of the family's per-slot store (a
+    # recurrent state, a ring of the last positions), carried in the same
     # donated tree as the page pool; None: the family keeps no such state
     init_recurrent_store: Callable | None = None
     # (rng, cfg) -> the parameter tree as a checkpoint holds it, for a family
@@ -62,7 +72,8 @@ class ServingFamily:
     def check_serve_config(self, kv_quant: str, prefill_chunk_tokens: int,
                            prefix_cache: bool) -> None:
         """Refuse, by name, what this family cannot run."""
-        why = (f"the {self.name} family keeps a recurrent state per slot "
+        why = (f"the {self.name} family keeps a store with one row a slot "
+               f"(a recurrent state, or a ring of the last positions) "
                f"beside the page pool" if self.recurrent
                else f"the {self.name} family")
         refused = []
@@ -70,13 +81,14 @@ class ServingFamily:
             refused.append(f"kv_quant: {kv_quant} (pages are {self.kv_quants} "
                            f"only)")
         if prefill_chunk_tokens and self.paged_prefill_chunk is None:
-            refused.append("prefill_chunk_tokens > 0 (chunked prefill would "
-                           "have to carry the state from chunk to chunk)")
+            refused.append("prefill_chunk_tokens > 0 (its programs cannot "
+                           "carry the slot's row from chunk to chunk yet)")
         if prefix_cache and self.paged_prefill_span is None:
-            refused.append("prefix_cache (a shared page holds keys and "
-                           "values only: the state at the divergence point "
-                           "is not kept, and the span prefill that "
-                           "recomputes a tail cannot start from it)")
+            refused.append("prefix_cache (a shared page holds what its "
+                           "layers page only: the slot's row at the "
+                           "divergence point is not kept, and the span "
+                           "prefill that recomputes a tail cannot start "
+                           "from it)")
         if refused:
             raise UnsupportedForFamily(
                 f"{why}: cannot run yet: " + "; ".join(refused))
@@ -106,7 +118,21 @@ def _hybrid_moe() -> ServingFamily:
         init_params=model.init_params, counters=decode.COUNTERS)
 
 
-_FAMILIES = {"llama": _llama, "hybrid_moe": _hybrid_moe}
+def _latent_moe() -> ServingFamily:
+    from llama_pipeline_parallel_tpu.models.latent_moe import decode, model
+
+    return ServingFamily(
+        name="latent_moe", prefill_prompt=decode.prefill_prompt,
+        paged_decode_step=decode.paged_decode_step,
+        write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
+        init_recurrent_store=decode.init_recurrent_store,
+        init_params=model.init_params,
+        paged_prefill_chunk=decode.paged_prefill_chunk,
+        counters=decode.COUNTERS)
+
+
+_FAMILIES = {"llama": _llama, "hybrid_moe": _hybrid_moe,
+             "latent_moe": _latent_moe}
 
 
 def family_of(cfg) -> ServingFamily:
@@ -120,8 +146,9 @@ def family_of(cfg) -> ServingFamily:
 def config_from_meta(model_config: dict):
     """The configuration object a checkpoint's `meta.json` describes:
     `model_config["family"]` names its family (absent: the dense decoder).
-    The hybrid family keeps the saved dtypes (it is served in the dtype it
-    is stored in); the dense one drops them, as its loader always has."""
+    The hybrid and the latent family keep the saved dtypes (they are served
+    in the dtype they are stored in); the dense one drops them, as its
+    loader always has."""
     mc = dict(model_config)
     name = mc.pop("family", "llama")
     if name == "llama":
@@ -129,16 +156,20 @@ def config_from_meta(model_config: dict):
 
         mc.pop("dtype", None), mc.pop("param_dtype", None)
         return LlamaConfig(**mc)
-    if name == "hybrid_moe":
+    if name in ("hybrid_moe", "latent_moe"):
         import jax.numpy as jnp
 
-        from llama_pipeline_parallel_tpu.models.hybrid_moe.config import (
-            HybridMoEConfig,
-        )
-
+        if name == "hybrid_moe":
+            from llama_pipeline_parallel_tpu.models.hybrid_moe.config import (
+                HybridMoEConfig as config_class,
+            )
+        else:
+            from llama_pipeline_parallel_tpu.models.latent_moe.config import (
+                LatentMoEConfig as config_class,
+            )
         for key in ("dtype", "param_dtype"):
             if key in mc:
                 mc[key] = jnp.dtype(mc[key]).type
-        return HybridMoEConfig(**mc)
+        return config_class(**mc)
     raise KeyError(f"meta.json names family {name!r}; known: "
                    f"{sorted(_FAMILIES)}")

@@ -912,11 +912,10 @@ class ServeEngine:
                     first_key[None])
                 token = int(first[0])
             if "counters" in out:
-                # the expert layers' counts of this prefill (ready with the
+                # the family's counts of this prefill unit (ready with the
                 # token above: the same program's output)
-                counted = dict(zip(self._family.counters,
-                                   np.asarray(out["counters"]).tolist()))
-                sp["routed_here"] = counted["routed_here"]
+                sp.update(zip(self._family.counters,
+                              np.asarray(out["counters"]).tolist()))
 
         rt_b = (self._rt.get(pf.request.request_id)
                 if self._reqtrace is not None else None)

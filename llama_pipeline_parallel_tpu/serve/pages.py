@@ -60,6 +60,7 @@ programs that run its layers.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import threading
 from collections import OrderedDict
@@ -81,25 +82,34 @@ def page_demand(bucket: int, max_new_tokens: int, page_size: int) -> int:
     return -(-positions // page_size)
 
 
+@functools.lru_cache(maxsize=64)
+def _pool_leaf_bytes(cfg, num_pages: int, page_size: int, quant: str) -> int:
+    """Bytes of the page leaves the configuration's family would allocate
+    (`init_page_pool`, shapes only: nothing is made): keys and values of
+    every layer for one family, the pages of the layers that keep any for
+    another, latents and index keys for a third."""
+    shapes = jax.eval_shape(
+        lambda: family_of(cfg).init_page_pool(cfg, num_pages, page_size,
+                                              quant))
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+
+
 def dense_kv_cache_bytes(cfg, max_slots: int,
                          max_len: int) -> int:
     """Resident bytes of a `[max_slots, max_len]` reservation, one
-    worst-case row a slot: what a pool is sized against."""
-    itemsize = jnp.dtype(cfg.dtype).itemsize
-    return (2 * cfg.kv_cache_layers * max_slots * max_len * cfg.kv_heads
-            * cfg.head_dim * itemsize)
+    worst-case row a slot: what a pool is sized against. A token's bytes
+    are the family's own page leaves' (a pool of one page of one token);
+    the store a family keeps a slot (`recurrent_store_bytes`) is not part
+    of either side of that comparison."""
+    return max_slots * max_len * _pool_leaf_bytes(cfg, 0, 1, "fp")
 
 
 def paged_pool_bytes(cfg, num_pages: int, page_size: int,
                      quant: str = "fp") -> int:
     """Resident bytes of a page pool (garbage page and int8 scales
-    included — the capacity comparison must not hide overheads)."""
-    itemsize = 1 if quant == "int8" else jnp.dtype(cfg.dtype).itemsize
-    kv = (2 * cfg.kv_cache_layers * (num_pages + 1) * page_size
-          * cfg.kv_heads * cfg.head_dim * itemsize)
-    if quant == "int8":
-        kv += 2 * cfg.kv_cache_layers * (num_pages + 1) * cfg.kv_heads * 4
-    return kv
+    included — the capacity comparison must not hide overheads), from the
+    family's own page leaves."""
+    return _pool_leaf_bytes(cfg, num_pages, page_size, quant)
 
 
 @jax.jit
@@ -231,12 +241,17 @@ class PagedKVCache:
         self.family = family_of(cfg)
         self.pool = self.family.init_page_pool(cfg, num_pages, page_size,
                                                quant)
-        # the leaves with a page axis: what a copy-on-write fork copies
+        # the leaves with a page axis: what a copy-on-write fork copies, and
+        # what one page of the pool costs (every such leaf has the pages,
+        # the garbage page among them, on its second axis)
         self._page_leaves = tuple(self.pool)
-        # a family with recurrent layers keeps a second store, one row a
-        # slot and such layer, in the same donated tree as the pages (keys
-        # `state` / `conv` beside `k` / `v`): written whole at admission,
-        # updated in place by every tick, never freed or shared
+        self._page_bytes = sum(
+            x.nbytes for x in self.pool.values()) // (num_pages + 1)
+        # a family may keep a second store, one row a slot (a recurrent
+        # state, a ring of the last positions), in the same donated tree as
+        # the pages (`state` / `conv` beside `k` / `v`; `ring` beside
+        # `latent` / `index`): written at admission, updated in place by
+        # every tick and chunk, never freed or shared
         self.recurrent_store_bytes = 0
         if self.family.recurrent:
             store = self.family.init_recurrent_store(cfg, max_slots)
@@ -336,9 +351,7 @@ class PagedKVCache:
     def page_bytes(self) -> int:
         """Resident HBM of ONE pool page (int8 scales included) — what a
         unit of the reservation gap costs if it were backed."""
-        one = paged_pool_bytes(self.cfg, 1, self.page_size, self.quant)
-        zero = paged_pool_bytes(self.cfg, 0, self.page_size, self.quant)
-        return one - zero  # difference cancels the garbage-page constant
+        return self._page_bytes
 
     def fragmentation_gauges(self) -> dict:
         """The page-pool occupancy snapshot `/healthz` and the serve

@@ -100,9 +100,10 @@ def build_model_config(node: dict) -> LlamaConfig:
         # pipeline and optimizer walk the dense decoder's parameter tree
         raise NotImplementedError(
             f"train.py cannot train the {family!r} family yet: no backward "
-            f"pass through its chunked recurrence or its grouped expert "
-            f"products, and the pipeline's stage split assumes layers of one "
-            f"kind (ROADMAP B2 / B5); it is served only (tools/serve.py)")
+            f"pass through its layers (a chunked recurrence, a learned "
+            f"top-k selection, grouped expert products), and the pipeline's "
+            f"stage split assumes layers of one kind (ROADMAP B2 / B5); it "
+            f"is served only (tools/serve.py)")
     if model_cfg is not None:
         return model_cfg
     preset = node.pop("preset", None)

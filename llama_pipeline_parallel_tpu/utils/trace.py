@@ -129,10 +129,27 @@ HYBRID_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
                  MOE_COMBINE, KDA_PROJ, KDA_CHUNK, KDA_STEP, STATE_GATHER,
                  STATE_WRITE, ATTN_GATE)
 
+# models/latent_moe/ (reuses `attn_gate`, `attn_out`, the `moe_*` names and
+# the dense programs' names for the same work). A tuple of their own for the
+# same reason as HYBRID_SCOPES: a third vocabulary to merge.
+MLA_PROJ = "mla_proj"                # down and up projections, norms, rope, absorption
+INDEX_PROJ = "index_proj"            # the indexer's queries, key and head weights
+INDEX_SCORE = "index_score"          # queries x every index key of the row, float32
+INDEX_TOPK = "index_topk"            # the exact selection
+LATENT_WRITE = "latent_write"        # entries and index keys -> their pages
+LATENT_GATHER = "latent_gather"      # pages -> index keys / the chosen entries
+SPARSE_ATTN = "sparse_attn"          # absorbed attention over the chosen entries
+WINDOW_ATTN = "window_attn"          # a sliding layer's attention
+RING_GATHER = "ring_gather"          # ring -> a sliding layer's rows
+RING_WRITE = "ring_write"
+LATENT_SCOPES = (MLA_PROJ, INDEX_PROJ, INDEX_SCORE, INDEX_TOPK, LATENT_WRITE,
+                 LATENT_GATHER, SPARSE_ATTN, WINDOW_ATTN, RING_GATHER,
+                 RING_WRITE)
+
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 
-# `name=` of the ten pallas_calls: the kernel's instruction in a trace is
+# `name=` of the eleven pallas_calls: the kernel's instruction in a trace is
 # `<name>.<n>`
 KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
@@ -144,6 +161,7 @@ KERNEL_PROLOGUE_FWD = "prologue_fwd"
 KERNEL_PROLOGUE_BWD_DX = "prologue_bwd_dx"
 KERNEL_PROLOGUE_BWD_DW = "prologue_bwd_dw"
 KERNEL_PAGED_DECODE_ATTN = "paged_decode_attn"   # under SCOPE_DECODE_ATTN
+KERNEL_SPARSE_LATENT_ATTN = "sparse_latent_attn"  # under SPARSE_ATTN
 
 KERNELS = tuple(v for k, v in sorted(globals().items())
                 if k.startswith("KERNEL_"))

@@ -1,0 +1,431 @@
+"""The latent-attention block served through the normal path: `ServeEngine`
+/ `PagedKVCache` take its programs from `models/family.py`; whole and chunked
+prefill, then decode, through all three stores (latent pages, index pages,
+the per-slot ring) are the plain reference's full forward; every store stays
+in place in the traced programs; what it cannot run yet is refused by name;
+a checkpoint of the family loads through the loader tools/serve.py uses.
+float32 on the CPU at a tiny size (`index_topk` 8, a window of 5, a ring of
+6, pages of 4, contexts on both sides of each); logits are compared with the
+reference's at 1e-4 (both sides float32; they differ in the order of sums)."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import hybrid_tiny
+import latent_tiny as tiny
+from llama_pipeline_parallel_tpu import serve
+from llama_pipeline_parallel_tpu.models import family as families
+from llama_pipeline_parallel_tpu.models.latent_moe import decode as latent_decode
+from llama_pipeline_parallel_tpu.models.latent_moe import model as latent
+from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
+from llama_pipeline_parallel_tpu.serve import pages
+from llama_pipeline_parallel_tpu.utils import trace
+
+TOL = 1e-4
+SLOTS, MAX_LEN, PAGE, PAGES = 2, 64, 4, 40
+
+
+def _cache(cfg):
+    return serve.PagedKVCache(cfg, SLOTS, MAX_LEN, PAGE, PAGES)
+
+
+def test_the_manager_holds_two_kinds_of_page_and_a_ring_a_slot():
+    cfg = tiny.config()
+    cache = _cache(cfg)
+    # entries of 12 and 16 numbers, stored in rows of 16 (whole tiles of 8)
+    assert cache.pool["latent"].shape == (3, PAGES + 1, PAGE, 16)
+    assert cache.pool["index"].shape == (3, PAGES + 1, PAGE, 8)
+    assert cache.pool["ring"].shape == (6, SLOTS, 6, 16)
+    assert cache._page_leaves == ("latent", "index")
+    assert cache.recurrent_store_bytes == cache.pool["ring"].nbytes
+    # a page is priced by the family's own page leaves
+    assert cache.page_bytes() == 3 * PAGE * (16 + 8) * 4
+    assert pages.paged_pool_bytes(cfg, PAGES, PAGE) == (
+        cache.pool["latent"].nbytes + cache.pool["index"].nbytes)
+    assert pages.dense_kv_cache_bytes(cfg, SLOTS, MAX_LEN) == \
+        SLOTS * MAX_LEN * 3 * (16 + 8) * 4
+
+
+def test_the_other_families_pages_are_priced_as_they_always_were():
+    """`paged_pool_bytes`, `page_bytes` and `dense_kv_cache_bytes` read the
+    family's own pool leaves; for the dense and the hybrid family that is 2
+    x layers that keep keys and values x KV heads x head size, as before."""
+    dense = LlamaConfig.tiny()
+    size = jnp.dtype(dense.dtype).itemsize
+    per_token = 2 * dense.num_hidden_layers * dense.kv_heads * dense.head_dim
+    assert pages.dense_kv_cache_bytes(dense, 3, 32) == 3 * 32 * per_token * size
+    assert pages.paged_pool_bytes(dense, 10, 8) == 11 * 8 * per_token * size
+    assert pages.paged_pool_bytes(dense, 10, 8, "int8") == (
+        11 * 8 * per_token
+        + 2 * dense.num_hidden_layers * 11 * dense.kv_heads * 4)
+    hybrid = hybrid_tiny.config()
+    per_token = 2 * hybrid.periods * hybrid.kv_heads * hybrid.head_dim
+    assert pages.paged_pool_bytes(hybrid, 12, 8) == 13 * 8 * per_token * 4
+    assert pages.dense_kv_cache_bytes(hybrid, 2, 48) == 2 * 48 * per_token * 4
+    cache = serve.PagedKVCache(hybrid, 2, 48, 8, 12)
+    assert cache.page_bytes() == 8 * per_token * 4
+    assert cache.recurrent_store_bytes == (cache.pool["state"].nbytes
+                                           + cache.pool["conv"].nbytes)
+
+
+def _padded(prompt, bucket):
+    pad = bucket - len(prompt)
+    ids = np.zeros((1, bucket), np.int32)
+    ids[0, pad:] = prompt
+    mask = np.zeros((1, bucket), np.int32)
+    mask[0, pad:] = 1
+    positions = np.clip(np.cumsum(mask, axis=1) - 1, 0, None).astype(np.int32)
+    return ids, mask, positions
+
+
+def _prefill(params, cfg, cache, slot, prompt, bucket, chunk):
+    """The engine's admission by hand: whole (chunk 0) or in chunks."""
+    ids, mask, positions = _padded(prompt, bucket)
+    if not chunk:
+        out = latent_decode.prefill_prompt(params, jnp.asarray(ids),
+                                           jnp.asarray(mask), cfg, bucket)
+        cache.admit(slot, out)
+        return out
+    cache.reset_mask_row(slot)
+    for c0 in range(0, bucket, chunk):
+        c1 = c0 + chunk
+        cache.ensure_capacity(slot, c1)
+        out = latent_decode.paged_prefill_chunk(
+            params, jnp.asarray(ids[:, c0:c1]), jnp.asarray(mask[:, c0:c1]),
+            jnp.asarray(positions[:, c0:c1]), cache.pool,
+            jnp.asarray(cache.page_table[slot]), jnp.int32(slot),
+            cache.kv_mask, jnp.int32(c0), cfg)
+        cache.pool, cache.kv_mask = out["pool"], out["kv_mask"]
+    return out
+
+
+@pytest.mark.parametrize("chunk", [0, 4, 8, 16],
+                         ids=["whole", "chunk4", "chunk8", "chunk16"])
+def test_prefill_then_ticks_through_the_three_stores_are_the_reference(chunk):
+    """Four requests over two slots: a prompt longer than `index_topk` and
+    the window, one shorter than both and left-padded past whole chunks, one
+    admitted into the slot the first left (nothing of the last occupant's
+    ring or pages may be visible), admitted at different ticks. Contexts
+    pass `index_topk`, the window and the ring (which wraps more than once:
+    6 places, rows of up to 50). At every tick the logits of every decoding
+    row are the reference's full forward over that request's tokens so
+    far."""
+    cfg = tiny.config()
+    params, top, layer_fn = tiny.both_sides()
+    cache = _cache(cfg)
+    tick = jax.jit(latent_decode.tick_logits, static_argnames=("cfg",))
+    rng = np.random.default_rng(4)
+    plan = [  # (admit at tick, slot, prompt, bucket, new tokens)
+        (0, 0, rng.integers(0, 128, 27).tolist(), 32, 9),
+        (3, 1, rng.integers(0, 128, 3).tolist(), 16, 30),
+        (12, 0, rng.integers(0, 128, 9).tolist(), 16, 20),
+        (34, 0, rng.integers(0, 128, 14).tolist(), 16, 4)]
+    rows, done = {}, []
+    for t in range(40):
+        for at, slot, prompt, bucket, new in plan:
+            if at != t:
+                continue
+            assert cache.reserve(cache.demand_pages(bucket, new))
+            assert cache.acquire(f"r{at}", cache.demand_pages(bucket, new)) == slot
+            out = _prefill(params, cfg, cache, slot, prompt, bucket, chunk)
+            rows[slot] = {"prompt": prompt, "seq": list(prompt),
+                          "logits": [np.asarray(out["logits"][0])],
+                          "left": new - 1, "write": bucket}
+            rows[slot]["seq"].append(int(np.argmax(out["logits"][0])))
+        if not rows:
+            continue
+        token, write, pos, active = (np.zeros(SLOTS, np.int32) for _ in range(4))
+        for slot, r in rows.items():
+            token[slot], write[slot], active[slot] = r["seq"][-1], r["write"], 1
+            pos[slot] = len(r["seq"]) - 1
+            cache.ensure_capacity(slot, r["write"] + 1)
+        logits, cache.pool, cache.kv_mask, counters, _ = tick(
+            params, jnp.asarray(token), cache.pool,
+            jnp.asarray(cache.page_table), jnp.asarray(pos),
+            jnp.asarray(write), cache.kv_mask, jnp.asarray(active), cfg)
+        seen = sum(len(r["seq"]) for r in rows.values())
+        kept = sum(min(len(r["seq"]), 8) for r in rows.values())
+        assert counters.tolist()[5:] == [3 * seen, 3 * kept]
+        for slot in list(rows):
+            r = rows[slot]
+            r["logits"].append(np.asarray(logits[slot]))
+            r["seq"].append(int(np.argmax(logits[slot])))
+            r["write"] += 1
+            r["left"] -= 1
+            if r["left"] == 0:
+                done.append(rows.pop(slot))
+                cache.release(slot)
+    assert len(done) == 4 and not rows
+    for r in done:
+        ids = jnp.asarray([r["seq"][:-1]])
+        want = tiny.reference.logits_fn(top, layer_fn, ids, tiny.MODEL)[0]
+        first = len(r["prompt"]) - 1
+        got = np.stack(r["logits"])
+        np.testing.assert_allclose(got, want[first:first + len(got)], atol=TOL)
+
+
+def test_a_chunk_of_nothing_but_pads_changes_nothing_a_query_can_see():
+    """The engine left-pads to the bucket and runs every chunk of it: the
+    chunks before the prompt's first token count nothing, route nothing and
+    leave the slot's mask row empty."""
+    cfg = tiny.config()
+    params, _, _ = tiny.both_sides()
+    cache = _cache(cfg)
+    assert cache.reserve(4) and cache.acquire("r", 4) == 0
+    ids, mask, positions = _padded([5, 6, 7], 16)
+    cache.reset_mask_row(0)
+    cache.ensure_capacity(0, 8)
+    out = latent_decode.paged_prefill_chunk(
+        params, jnp.asarray(ids[:, :8]), jnp.asarray(mask[:, :8]),
+        jnp.asarray(positions[:, :8]), cache.pool,
+        jnp.asarray(cache.page_table[0]), jnp.int32(0), cache.kv_mask,
+        jnp.int32(0), cfg)
+    assert out["counters"].tolist() == [0, 0, 0, 0, 8 * 8, 0, 0]
+    assert int(jnp.sum(out["kv_mask"])) == 0
+    assert bool(jnp.all(jnp.isfinite(out["logits"])))
+
+
+def test_the_engine_serves_the_family_in_chunks_with_its_counters_on_the_spans():
+    """Buckets of 8 (whole), 16 and 32 (chunks of 8 between decode ticks)
+    through `ServeEngine`: every served token is the reference's first
+    choice; the expert layers' and the indexer's counts ride the tick's and
+    every prefill unit's span, and are the host's own counts exactly."""
+    cfg = tiny.config()
+    params, top, layer_fn = tiny.both_sides()
+    scfg = serve.ServeConfig(max_slots=SLOTS, max_len=MAX_LEN,
+                             prompt_buckets=(8, 16, 32), page_size=PAGE,
+                             num_pages=PAGES, decode_span_every=4,
+                             prefill_chunk_tokens=8)
+    engine = serve.ServeEngine(params, cfg, scfg)
+    spans = []
+    listener = lambda rec: spans.append(dict(rec))
+    trace.recorder().add_listener(listener)
+    try:
+        rng = np.random.default_rng(1)
+        prompts = [rng.integers(0, 128, n).tolist() for n in (5, 27, 3, 14, 30)]
+        budgets = [9, 17, 6, 12, 5]
+        handles = []
+        for i, (prompt, n) in enumerate(zip(prompts, budgets)):
+            handles.append(engine.submit(serve.ServeRequest(
+                input_ids=prompt, seed=i,
+                gen=families.GenerationConfig(max_new_tokens=n))))
+            engine.step()
+        engine.drain()
+        engine._flush_decode_span()
+    finally:
+        trace.recorder().remove_listener(listener)
+    served = [h.result() for h in handles]
+    assert [len(s) for s in served] == budgets
+    gaps = tiny.reference.served_token_gaps(top, layer_fn, prompts, served,
+                                            tiny.MODEL, MAX_LEN)
+    assert max(max(g) for g in gaps) <= TOL
+    assert engine.slots.reused_slot_count() >= 1
+    assert engine.prefill_chunks_total == 1 + 4 + 1 + 2 + 4
+
+    ticks = [s for s in spans if s["name"] == "serve_decode_step"]
+    units = [s for s in spans if s["name"] == "serve_prefill"]
+    assert len(units) == 12
+    assert all(set(latent_decode.COUNTERS) <= set(s) for s in ticks + units)
+    total = {k: sum(s[k] for s in ticks) for k in latent_decode.COUNTERS}
+    decoded = sum(n - 1 for n in budgets)
+    assert sum(s["tokens"] for s in ticks) == decoded
+    # exact: every decoding token chooses 4 experts in each of 8 expert
+    # layers, and sees its whole context in each of 3 full layers, of which
+    # it selects at most 8
+    assert total["routed_total"] == decoded * 4 * 8
+    contexts = [len(p) + j for p, n in zip(prompts, budgets)
+                for j in range(1, n)]
+    assert total["index_visible"] == 3 * sum(contexts)
+    assert total["index_selected"] == 3 * sum(min(c, 8) for c in contexts)
+    # a prompt's tokens, whatever the units they came in
+    prefilled = {k: sum(s[k] for s in units) for k in latent_decode.COUNTERS}
+    assert prefilled["routed_total"] == sum(len(p) for p in prompts) * 4 * 8
+    assert prefilled["index_visible"] == 3 * sum(
+        t + 1 for p in prompts for t in range(len(p)))
+    assert prefilled["index_selected"] == 3 * sum(
+        min(t + 1, 8) for p in prompts for t in range(len(p)))
+    assert all(0 < s["kv_pages_live"] <= s["kv_pages_table"] for s in ticks)
+
+
+# -- structure of the traced programs -------------------------------------------
+
+def _shapes(cfg, pages=PAGES):
+    params = jax.eval_shape(lambda: latent.init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
+    pool = {**latent_decode.init_page_pool(cfg, pages, PAGE),
+            **latent_decode.init_recurrent_store(cfg, SLOTS)}
+    return params, pool
+
+
+def _tick_args(cfg, pages=PAGES):
+    params, pool = _shapes(cfg, pages)
+    z = jnp.zeros((SLOTS,), jnp.int32)
+    return pool, (
+        params, z, pool, jnp.zeros((SLOTS, MAX_LEN // PAGE), jnp.int32), z, z,
+        jnp.zeros((SLOTS, MAX_LEN), jnp.int32), z,
+        jnp.zeros((SLOTS, 2), jnp.uint32), jnp.zeros((SLOTS,), jnp.float32),
+        z, jnp.ones((SLOTS,), jnp.float32))
+
+
+def _chunk_args(cfg, pages=PAGES, C=8):
+    params, pool = _shapes(cfg, pages)
+    ids = jnp.zeros((1, C), jnp.int32)
+    return pool, (
+        params, ids, ids, ids, pool, jnp.zeros((MAX_LEN // PAGE,), jnp.int32),
+        jnp.int32(0), jnp.zeros((SLOTS, MAX_LEN), jnp.int32), jnp.int32(0))
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+PROGRAMS = {"tick": (latent_decode.paged_decode_step, _tick_args),
+            "chunk": (latent_decode.paged_prefill_chunk, _chunk_args)}
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_every_store_rides_the_period_loops_carry(program):
+    """As tests/test_pool_walk.py holds the dense pool: latent pages, index
+    pages and the ring are carried through the loop over periods whole,
+    never its `xs` / `ys` (which a donated argument cannot alias)."""
+    cfg = tiny.config()
+    fn, make = PROGRAMS[program]
+    pool, args = make(cfg)
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, cfg))(*args).jaxpr
+    leaves = {name: (leaf.shape, leaf.dtype) for name, leaf in pool.items()}
+    loops = [e for e in _equations(jaxpr) if e.primitive.name == "scan"
+             and e.params["length"] == cfg.periods
+             and leaves["ring"][0] in {v.aval.shape for v in e.invars}]
+    assert len(loops) == 1
+    loop = loops[0]
+    n_consts, n_carry = loop.params["num_consts"], loop.params["num_carry"]
+    carried = [(v.aval.shape, v.aval.dtype)
+               for v in loop.invars[n_consts:n_consts + n_carry]]
+    scanned = {v.aval.shape for v in loop.invars[n_consts + n_carry:]}
+    stacked = {v.aval.shape for v in loop.outvars[n_carry:]}
+    for name, leaf in leaves.items():
+        assert leaf in carried, name
+        assert leaf[0] not in scanned and leaf[0] not in stacked, name
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_the_programs_outputs_alias_the_donated_stores(program):
+    cfg = tiny.config()
+    fn, make = PROGRAMS[program]
+    pool, args = make(cfg, pages=2048)
+    analysis = fn.lower(*args, cfg).compile().memory_analysis()
+    if analysis is None:
+        pytest.skip("this backend reports no memory analysis")
+    assert analysis.alias_size_in_bytes >= sum(x.nbytes for x in pool.values())
+
+
+def test_the_tick_reads_the_chosen_entries_through_the_table():
+    """No gather of the slots' whole latent rows [S, Pmax, page, w] in the
+    tick: the index keys' rows are gathered whole (they are what is scored),
+    the entries by the places the selection chose."""
+    cfg = tiny.config()
+    pool, args = _tick_args(cfg)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: latent_decode.paged_decode_step(*a, cfg))(*args).jaxpr
+    gathers = [tuple(e.outvars[0].aval.shape) for e in _equations(jaxpr)
+               if e.primitive.name == "gather"]
+    rows = (SLOTS, MAX_LEN // PAGE, PAGE)
+    assert rows + (8,) in gathers                    # index keys
+    assert rows + (16,) not in gathers               # never the entries
+    assert (SLOTS, cfg.index_topk, 16) in gathers    # the chosen ones
+
+
+# -- what cannot run yet ----------------------------------------------------------
+
+@pytest.mark.parametrize("knobs,named", [
+    (dict(prefix_cache=True), "prefix_cache"),
+    (dict(kv_quant="int8"), "kv_quant: int8"),
+])
+def test_what_the_family_cannot_run_is_refused_by_name(knobs, named):
+    cfg = tiny.config()
+    params = jax.eval_shape(lambda: latent.init_params(jax.random.PRNGKey(0), cfg))
+    base = dict(max_slots=SLOTS, max_len=MAX_LEN, prompt_buckets=(8, 16),
+                page_size=PAGE, num_pages=PAGES, prefill_chunk_tokens=8)
+    with pytest.raises(families.UnsupportedForFamily, match=named) as err:
+        serve.ServeEngine(params, cfg, serve.ServeConfig(**{**base, **knobs}))
+    assert "latent_moe" in str(err.value) and "one row a slot" in str(err.value)
+    assert "prefill_chunk_tokens" not in str(err.value)
+
+
+def test_a_store_a_slot_and_chunked_prefill_are_separate_facts():
+    """The latent family keeps a per-slot store AND prefills in chunks; the
+    hybrid family keeps one and cannot yet; neither has a span prefill."""
+    fam = families.family_of(tiny.config())
+    assert fam.recurrent and fam.paged_prefill_span is None
+    assert fam.paged_prefill_chunk is latent_decode.paged_prefill_chunk
+    assert fam.counters == latent_decode.COUNTERS
+    assert fam.counters[:5] == families.family_of(hybrid_tiny.config()).counters
+    other = families.family_of(hybrid_tiny.config())
+    assert other.recurrent and other.paged_prefill_chunk is None
+    fam.check_serve_config("fp", 8, False)
+    with pytest.raises(families.UnsupportedForFamily, match="chunk to chunk"):
+        other.check_serve_config("fp", 8, False)
+
+
+def test_the_trainer_refuses_the_family_by_name():
+    from llama_pipeline_parallel_tpu import train
+
+    with pytest.raises(NotImplementedError, match="latent_moe"):
+        train.build_model_config({"family": "latent_moe", "hidden_size": 32})
+    node = {"_target_": "llama_pipeline_parallel_tpu.models.latent_moe."
+                        "config.LatentMoEConfig.tiny"}
+    with pytest.raises(NotImplementedError, match="latent_moe"):
+        train.build_model_config(node)
+
+
+def test_the_engine_names_no_familys_functions():
+    import inspect
+
+    from llama_pipeline_parallel_tpu.serve import engine
+
+    for module in (engine, pages):
+        assert "models.latent_moe" not in inspect.getsource(module)
+
+
+# -- the checkpoint ----------------------------------------------------------------
+
+def test_a_checkpoint_of_the_family_round_trips_into_the_serving_loader(tmp_path):
+    from llama_pipeline_parallel_tpu.ckpt.checkpoint import (
+        CheckpointManager,
+        load_module_checkpoint,
+    )
+
+    cfg = tiny.config(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = latent.init_params(jax.random.PRNGKey(5), cfg)
+    mgr = CheckpointManager(str(tmp_path))
+    mgr.save_module(3, params, cfg)
+    meta = mgr.load_meta(3)
+    assert meta["model_config"]["family"] == "latent_moe"
+    loaded, loaded_cfg, _, step = load_module_checkpoint(str(tmp_path))
+    assert step == 3 and loaded_cfg == cfg
+    assert dataclasses.asdict(loaded_cfg) == dataclasses.asdict(cfg)
+    flat, tree = jax.tree.flatten(params)
+    flat_loaded, tree_loaded = jax.tree.flatten(loaded)
+    assert tree == tree_loaded
+    for a, b in zip(flat, flat_loaded):
+        assert a.dtype == b.dtype          # bfloat16 stays bfloat16
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    engine = serve.ServeEngine(loaded, loaded_cfg, serve.ServeConfig(
+        max_slots=SLOTS, max_len=MAX_LEN, prompt_buckets=(8, 16),
+        page_size=PAGE, num_pages=PAGES, prefill_chunk_tokens=8))
+    handle = engine.submit(serve.ServeRequest(
+        input_ids=list(range(1, 12)),
+        gen=families.GenerationConfig(max_new_tokens=3)))
+    engine.drain()
+    assert len(handle.result()) == 3

@@ -30,7 +30,7 @@ from llama_pipeline_parallel_tpu.models.hybrid_moe.config import HybridMoEConfig
 from llama_pipeline_parallel_tpu.models.llama import decode
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
-from llama_pipeline_parallel_tpu.ops import paged_attention
+from llama_pipeline_parallel_tpu.ops import paged_attention, sparse_latent_attention
 from llama_pipeline_parallel_tpu.ops.attention import attention
 
 L, PAGES, PAGE, KV_H, HD, PMAX = 2, 9, 8, 2, 128, 4
@@ -191,6 +191,8 @@ def mosaic(monkeypatch):
     """The kernel as the chip runs it: `interpret_mode()` asks the backend,
     which is the CPU here whatever the program is compiled for."""
     monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
+    monkeypatch.setattr(sparse_latent_attention, "interpret_mode",
+                        lambda: False)
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without one
     before = jax.config.jax_enable_compilation_cache
@@ -287,3 +289,88 @@ def test_a_tick_compiled_for_the_chip_keeps_the_pool_in_place(
     assert analysis.temp_size_in_bytes < nbytes(pool["k"]) // 16, analysis
     assert analysis.alias_size_in_bytes >= nbytes(pool)
     assert "paged_decode_attn" in compiled.as_text()
+
+
+# -- the latent family, compiled for the same chip -----------------------------
+
+@pytest.mark.parametrize("queries", [128, 32], ids=["chunk-block", "tick"])
+def test_mosaic_compiles_the_sparse_read_at_the_long_cells_shapes(
+        one_chip, mosaic, queries):
+    """128 heads, 2048 chosen entries of 640 (576 stored in whole tiles),
+    bf16: a block of a chunk's queries and a tick's 32 rows."""
+    args = _described(
+        (jax.ShapeDtypeStruct((queries, 128, 640), jnp.bfloat16),
+         jax.ShapeDtypeStruct((queries, 2048, 640), jnp.bfloat16),
+         jax.ShapeDtypeStruct((queries, 2048), jnp.bool_)), one_chip)
+    compiled = jax.jit(
+        lambda q, e, ok: sparse_latent_attention.sparse_latent_attention(
+            q, e, ok, 192 ** -0.5)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "sparse_latent_attn" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def _latent_programs(slots, pmax, page, pages):
+    """The latent family at the published entry widths (576 and 1088, stored
+    as 640 and 1152) and small everything else."""
+    from llama_pipeline_parallel_tpu.models.latent_moe import decode as latent_decode
+    from llama_pipeline_parallel_tpu.models.latent_moe import model as latent
+    from llama_pipeline_parallel_tpu.models.latent_moe.config import (
+        LatentMoEConfig,
+    )
+
+    cfg = LatentMoEConfig(
+        vocab_size=256, hidden_size=256, num_hidden_layers=5,
+        intermediate_size=256, num_attention_heads=8, q_lora_rank=128,
+        index_n_heads=4, index_topk=128, swa_num_attention_heads=4,
+        swa_q_lora_rank=128, router_experts=16, experts_held=8,
+        num_experts_per_tok=4, moe_intermediate_size=64,
+        shared_intermediate_size=64)
+    params = jax.eval_shape(
+        lambda: latent.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: {
+        **latent_decode.init_page_pool(cfg, pages, page),
+        **latent_decode.init_recurrent_store(cfg, slots)})
+    return latent_decode, cfg, params, pool
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_a_latent_program_compiled_for_the_chip_keeps_its_stores_in_place(
+        one_chip, mosaic, program):
+    """The three stores of the latent family at their published entry
+    widths: compiled for the chip with pages many times the weights, the
+    outputs are the donated stores' buffers and nothing as large as the
+    latent pages is made beside them. Stored 576 wide instead of 640, the
+    same programs copy the latent pages whole on the way in and out (the
+    chip hands a store whose rows are not whole tiles over in another
+    layout: PERF.md, PR 30); `store_multiple` is what this test holds."""
+    slots, pmax, page = 4, 16, 64
+    latent_decode, cfg, params, pool = _latent_programs(slots, pmax, page, 4096)
+    assert pool["latent"].shape[-1] == 640 and pool["ring"].shape[-1] == 1152
+    z = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    f = jax.ShapeDtypeStruct((slots,), jnp.float32)
+    mask = jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    if program == "tick":
+        args = _described(
+            (params, z, pool, jax.ShapeDtypeStruct((slots, pmax), jnp.int32),
+             z, z, mask, z, jax.ShapeDtypeStruct((slots, 2), jnp.uint32), f,
+             z, f), one_chip)
+        compiled = latent_decode.paged_decode_step.lower(*args, cfg).compile()
+    else:
+        ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+        args = _described(
+            (params, ids, ids, ids, pool,
+             jax.ShapeDtypeStruct((pmax,), jnp.int32), scalar, mask, scalar),
+            one_chip)
+        compiled = latent_decode.paged_prefill_chunk.lower(*args, cfg).compile()
+    analysis = compiled.memory_analysis()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    assert nbytes(pool["latent"]) > 5 * nbytes(params)
+    assert analysis.alias_size_in_bytes >= nbytes(pool)
+    assert analysis.temp_size_in_bytes < nbytes(pool["latent"]) // 4, analysis
+    assert "sparse_latent_attn" in compiled.as_text()

@@ -1,6 +1,6 @@
 """The names on device work (utils/trace.py vocabulary): every matrix product
 and kernel call of the hot programs carries a leaf scope, the compiled modules
-and the nine kernels keep their names, the `profile_window` schedule counter
+and the kernels keep their names, the `profile_window` schedule counter
 equals counts made by hand, and the serving tick's four phases add up."""
 
 import inspect
@@ -21,6 +21,7 @@ from llama_pipeline_parallel_tpu.ops import (
     paged_attention,
     pallas_ce,
     pallas_prologue,
+    sparse_latent_attention,
 )
 from llama_pipeline_parallel_tpu.optim import OptimizerConfig, make_optimizer
 from llama_pipeline_parallel_tpu.parallel import pipeline as pl
@@ -186,6 +187,7 @@ def test_every_product_and_kernel_call_carries_a_leaf_scope(program, devices):
     (pallas_prologue, ("KERNEL_PROLOGUE_FWD", "KERNEL_PROLOGUE_BWD_DX",
                        "KERNEL_PROLOGUE_BWD_DW")),
     (paged_attention, ("KERNEL_PAGED_DECODE_ATTN",)),
+    (sparse_latent_attention, ("KERNEL_SPARSE_LATENT_ATTN",)),
 ])
 def test_every_pallas_call_passes_its_name(module, kernels):
     source = inspect.getsource(module)
@@ -194,7 +196,7 @@ def test_every_pallas_call_passes_its_name(module, kernels):
     for constant in kernels:
         assert source.count(f"name=trace.{constant},") == 1
         assert getattr(trace, constant) in trace.KERNELS
-    assert len(trace.KERNELS) == 10 == len(set(trace.KERNELS))
+    assert len(trace.KERNELS) == 11 == len(set(trace.KERNELS))
 
 
 def test_flash_kernel_name_reaches_the_lowered_program():
