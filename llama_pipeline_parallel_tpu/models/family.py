@@ -17,8 +17,9 @@ separate fact (`paged_prefill_chunk`): the latent block's chunk carries its
 rings forward from chunk to chunk, the hybrid block's programs cannot carry
 their state yet.
 
-`GenerationConfig` and `sample_rowwise` are the same for every family (the
-sampling of a row of logits) and are re-exported here.
+`GenerationConfig`, `sample_rowwise` and `sampler_branch` are the same for
+every family (the sampling of a row of logits, and what a batch's knobs ask of
+it) and are re-exported here.
 """
 
 from __future__ import annotations
@@ -29,6 +30,7 @@ from typing import Callable
 from llama_pipeline_parallel_tpu.models.llama.decode import (  # noqa: F401
     GenerationConfig,
     sample_rowwise,
+    sampler_branch,
 )
 
 

@@ -230,8 +230,10 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     store, applies one step of the recurrence and writes them back. Rows
     that are not `active` leave both stores as they were (their page writes
     go to the garbage page; their recurrence runs with b = 0, a = 1) and are
-    routed to no expert. Returns the dense tick's outputs plus "counters"
-    (int32[5], `COUNTERS`, summed over the expert layers)."""
+    routed to no expert. The sampler's cost is the batch's own
+    (`sample_rowwise`: an argmax a row unless an active row samples, a sort
+    only where one filters). Returns the dense tick's outputs plus
+    "counters" (int32[5], `COUNTERS`, summed over the expert layers)."""
     del pos
     logits, pool, kv_mask, counters = tick_logits(
         params, token, pool, page_table, write_pos, kv_mask, active, cfg)

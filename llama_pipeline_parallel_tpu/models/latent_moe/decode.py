@@ -314,7 +314,9 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     entry at `write_pos % R` of the row's ring and attends the ring. Rows
     that are not `active` leave the stores as they were (page writes go to
     the garbage page, the ring place is rewritten with what it held), are
-    routed to no expert and count for nothing. Returns the dense tick's
+    routed to no expert and count for nothing. The sampler's cost is the
+    batch's own (`sample_rowwise`: an argmax a row unless an active row
+    samples, a sort only where one filters). Returns the dense tick's
     outputs plus "counters" (int32[7], `COUNTERS`) and "selection" (the
     places each row's query selected in each full layer, and which of them
     hold a position: read by tests and by the benchmark's check, never by

@@ -194,35 +194,86 @@ def _sample(logits: jnp.ndarray, gen: GenerationConfig, rng: jax.Array) -> jnp.n
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+def _top_p_mask_sorted(logits: jnp.ndarray, sorted_desc: jnp.ndarray,
+                       top_p) -> jnp.ndarray:
+    """`_top_p_mask` for a caller that already holds the row's descending
+    sort: the same arithmetic on the same array, without the sort."""
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    before = jnp.cumsum(probs, axis=-1) - probs
+    keep = before < top_p
+    cutoff = jnp.min(jnp.where(keep, sorted_desc, jnp.inf), axis=-1,
+                     keepdims=True)
+    return jnp.where(logits < cutoff, -jnp.inf, logits)
+
+
 def _sample_row(logits: jnp.ndarray, temperature, top_k, top_p,
-                key: jax.Array) -> jnp.ndarray:
+                key: jax.Array, filters: bool = True) -> jnp.ndarray:
     """[V] logits -> scalar token, with PER-REQUEST knobs as traced values.
 
     The serving batch mixes requests with different GenerationConfigs, so
     the static branches of `_sample` become data: greedy is selected by
     `where(temperature > 0)`, the top-k threshold is the k-th largest VALUE
     (the same element `lax.top_k` finds, read off a descending sort), and
-    the nucleus filter is the shared `_top_p_mask`. Every arithmetic path
+    the nucleus filter is `_top_p_mask`'s arithmetic. Every arithmetic path
     mirrors `_sample` exactly, which is what makes a slot-served request
     reproduce an independent `generate()` call token-for-token.
+
+    Cost: ONE sort of the row, and none with `filters=False`, which
+    `sample_rowwise` passes for a batch in which no sampling row has a
+    top-k or a top-p (both `where`s would pass the row through). The
+    nucleus filter wants the descending sort of the top-k-masked row;
+    masking the sort gives that array, ties included (what lies below the
+    k-th value is a suffix of the sort), so the row is not sorted again.
     """
-    vocab = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     safe_t = jnp.where(temperature > 0.0, temperature, 1.0)
     l = logits / safe_t
-    sorted_desc = jnp.sort(l, axis=-1)[..., ::-1]
-    kth = sorted_desc[jnp.clip(top_k, 1, vocab) - 1]
-    l = jnp.where((top_k > 0) & (l < kth), -jnp.inf, l)
-    l = jnp.where(top_p < 1.0, _top_p_mask(l, top_p), l)
+    if filters:
+        sorted_desc = jnp.sort(l, axis=-1)[..., ::-1]
+        kth = sorted_desc[jnp.clip(top_k, 1, logits.shape[-1]) - 1]
+        l = jnp.where((top_k > 0) & (l < kth), -jnp.inf, l)
+        sorted_desc = jnp.where((top_k > 0) & (sorted_desc < kth), -jnp.inf,
+                                sorted_desc)
+        l = jnp.where(top_p < 1.0,
+                      _top_p_mask_sorted(l, sorted_desc, top_p), l)
     sampled = jax.random.categorical(key, l, axis=-1).astype(jnp.int32)
     return jnp.where(temperature > 0.0, sampled, greedy)
+
+
+def _greedy_rows(logits, temperature, top_k, top_p, keys):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def sampler_branch(temperature, top_k, top_p):
+    """What a batch's knobs ask of the sampler: 0, no row samples (an
+    argmax a row); 1, some row samples and no sampling row filters (a
+    draw); 2, some sampling row has `top_k > 0` or `top_p < 1` (a sort a
+    row). Rows with temperature 0, unoccupied slots among them, never raise
+    it whatever their other knobs. Takes numpy or jax arrays:
+    `sample_rowwise` branches on it in the program and the engine counts
+    `ticks_sampled` / `ticks_sorted` with it on the host."""
+    samples = temperature > 0.0
+    filters = samples & ((top_k > 0) | (top_p < 1.0))
+    return samples.any().astype("int32") + filters.any().astype("int32")
 
 
 def sample_rowwise(logits: jnp.ndarray, temperature: jnp.ndarray,
                    top_k: jnp.ndarray, top_p: jnp.ndarray,
                    keys: jnp.ndarray) -> jnp.ndarray:
-    """[b, V] logits + [b] per-row knobs + [b, 2] keys -> [b] tokens."""
-    return jax.vmap(_sample_row)(logits, temperature, top_k, top_p, keys)
+    """[b, V] logits + [b] per-row knobs + [b, 2] keys -> [b] tokens.
+
+    The tokens are `vmap(_sample_row)`'s, bit for bit; the work is what the
+    batch asks for. One `lax.switch` on `sampler_branch` of the knobs,
+    outside the `vmap` (inside it a condition is a select and both sides
+    run), so only the chosen branch runs on the device: an all-greedy batch
+    takes an argmax a row, a batch that samples without filters adds a
+    draw, and only a batch in which a sampling row has a top-k or a top-p
+    sorts, once a row, every row."""
+    return jax.lax.switch(
+        sampler_branch(temperature, top_k, top_p),
+        (_greedy_rows, jax.vmap(partial(_sample_row, filters=False)),
+         jax.vmap(_sample_row)),
+        logits, temperature, top_k, top_p, keys)
 
 
 @partial(jax.jit, static_argnames=("cfg", "gen"))
@@ -637,7 +688,11 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     w_page, w_off), in place (`_walk_pool`), and attends each slot's live
     pages where they lie in the pool (`ops/paged_attention.py`; the choice
     is the pool's dtype, there is no second fp path); an int8 pool's rows
-    are gathered and dequantized as the prefills gather theirs. Returns
+    are gathered and dequantized as the prefills gather theirs. What the
+    sampler costs is the batch's own (`sample_rowwise`): an argmax a row
+    while no row has a temperature, a draw on top where one has, and a sort
+    of every row only in a tick where a sampling row has a top-k or a
+    top-p; inactive rows are staged greedy and never ask for more. Returns
     {"token": [S] next tokens, "pool", "kv_mask", "keys"}; rope and write
     positions advance by one, and the caller tracks them host-side."""
     logits, pool, kv_mask = tick_logits(params, token, pool, page_table, pos,

@@ -55,6 +55,7 @@ from llama_pipeline_parallel_tpu.models.family import (
     GenerationConfig,
     family_of,
     sample_rowwise,
+    sampler_branch,
 )
 from llama_pipeline_parallel_tpu.serve.pages import PagedKVCache
 from llama_pipeline_parallel_tpu.serve.reqtrace import TraceContext
@@ -389,6 +390,9 @@ class ServeEngine:
         # their page-table rows have: the share of a whole-row read that
         # the tick's attention still makes (ops/paged_attention.py)
         self._tick_pages = [0, 0]        # live, table
+        # ticks whose knobs made the program's sampler draw, and sort
+        # (`sampler_branch` of the staged arrays, as the program reads it)
+        self._tick_sampler = [0, 0]      # sampled, sorted
         self._tick_phases = [0.0, 0.0, 0.0, 0.0]  # stage, dispatch, wait, emit
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
@@ -972,6 +976,9 @@ class ServeEngine:
             n_active = len(self._occupants)
             self._tick_pages[0] += pages_live
             self._tick_pages[1] += n_active * self.slots.page_table.shape[1]
+            branch = int(sampler_branch(temps, top_ks, top_ps))
+            self._tick_sampler[0] += branch >= 1
+            self._tick_sampler[1] += branch == 2
 
         t_wall = time.time()
         t0 = time.perf_counter()
@@ -1049,8 +1056,9 @@ class ServeEngine:
         rate. `tokens` is the host's own count of the rows that decoded over
         those ticks (`active` is the last tick's alone). `phases`: this
         tick's (stage, dispatch, wait, emit) seconds, summed the same way,
-        as are `kv_pages_live` and `kv_pages_table` (`_decode_tick` counts
-        them where it stages the rows)."""
+        as are `kv_pages_live`, `kv_pages_table`, `ticks_sampled` and
+        `ticks_sorted` (`_decode_tick` counts them where it stages the
+        rows)."""
         if self._tick_count == 0:
             self._tick_ts = ts
         self._tick_accum += dur
@@ -1072,12 +1080,15 @@ class ServeEngine:
                               tokens=self._tick_tokens,
                               kv_pages_live=self._tick_pages[0],
                               kv_pages_table=self._tick_pages[1],
+                              ticks_sampled=self._tick_sampler[0],
+                              ticks_sorted=self._tick_sampler[1],
                               stage_s=stage_s, dispatch_s=dispatch_s,
                               wait_s=wait_s, emit_s=emit_s,
                               **self._tick_counters)
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
         self._tick_pages = [0, 0]
+        self._tick_sampler = [0, 0]
         self._tick_phases = [0.0, 0.0, 0.0, 0.0]
         self._tick_counters = dict.fromkeys(self._tick_counters, 0)
 
