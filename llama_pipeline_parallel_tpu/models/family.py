@@ -6,16 +6,18 @@ What touches only the mask or the pool's page axis is `serve/pages.py`'s own.
 call them with the arguments they always passed; they name no family's
 functions. The dense decoder (`models/llama/`) is one family, the hybrid
 block with recurrent layers and sparse experts (`models/hybrid_moe/`) another,
-the latent-attention block with an indexer, window layers and sparse experts
+the latent-attention block (MLA layers, with or without an indexer and
+window layers, as its configuration says) and sparse experts
 (`models/latent_moe/`) the third. A fourth registers its configuration class
 below.
 
 A family may keep a store with one row a SLOT beside the page pool
 (`init_recurrent_store`: the hybrid block's recurrent state, the latent
-block's rings of its window layers). Whether it can prefill in chunks is a
-separate fact (`paged_prefill_chunk`): the latent block's chunk carries its
-rings forward from chunk to chunk, the hybrid block's programs cannot carry
-their state yet.
+block's rings of its window layers). What a family states may depend on the
+configuration: a latent model without window layers keeps no such store.
+Whether it can prefill in chunks is a separate fact (`paged_prefill_chunk`):
+the latent block's chunk carries its rings forward from chunk to chunk, the
+hybrid block's programs cannot carry their state yet.
 
 `GenerationConfig`, `sample_rowwise` and `sampler_branch` are the same for
 every family (the sampling of a row of logits, and what a batch's knobs ask of
@@ -74,9 +76,9 @@ class ServingFamily:
     def check_serve_config(self, kv_quant: str, prefill_chunk_tokens: int,
                            prefix_cache: bool) -> None:
         """Refuse, by name, what this family cannot run."""
-        why = (f"the {self.name} family keeps a store with one row a slot "
-               f"(a recurrent state, or a ring of the last positions) "
-               f"beside the page pool" if self.recurrent
+        why = (f"the {self.name} family keeps, for this configuration, a "
+               f"store with one row a slot (a recurrent state, or a ring of "
+               f"the last positions) beside the page pool" if self.recurrent
                else f"the {self.name} family")
         refused = []
         if kv_quant not in self.kv_quants:
@@ -86,17 +88,19 @@ class ServingFamily:
             refused.append("prefill_chunk_tokens > 0 (its programs cannot "
                            "carry the slot's row from chunk to chunk yet)")
         if prefix_cache and self.paged_prefill_span is None:
-            refused.append("prefix_cache (a shared page holds what its "
-                           "layers page only: the slot's row at the "
-                           "divergence point is not kept, and the span "
-                           "prefill that recomputes a tail cannot start "
-                           "from it)")
+            refused.append(
+                "prefix_cache (a shared page holds what its layers page "
+                "only: the slot's row at the divergence point is not kept, "
+                "and the span prefill that recomputes a tail cannot start "
+                "from it)" if self.recurrent else
+                "prefix_cache (the family has no span prefill, the program "
+                "that recomputes the tail of a prefix-cache hit)")
         if refused:
             raise UnsupportedForFamily(
                 f"{why}: cannot run yet: " + "; ".join(refused))
 
 
-def _llama() -> ServingFamily:
+def _llama(cfg) -> ServingFamily:
     from llama_pipeline_parallel_tpu.models.llama import decode
 
     return ServingFamily(
@@ -109,7 +113,7 @@ def _llama() -> ServingFamily:
         kv_quants=("fp", "int8"))
 
 
-def _hybrid_moe() -> ServingFamily:
+def _hybrid_moe(cfg) -> ServingFamily:
     from llama_pipeline_parallel_tpu.models.hybrid_moe import decode, model
 
     return ServingFamily(
@@ -120,17 +124,19 @@ def _hybrid_moe() -> ServingFamily:
         init_params=model.init_params, counters=decode.COUNTERS)
 
 
-def _latent_moe() -> ServingFamily:
+def _latent_moe(cfg) -> ServingFamily:
     from llama_pipeline_parallel_tpu.models.latent_moe import decode, model
 
     return ServingFamily(
         name="latent_moe", prefill_prompt=decode.prefill_prompt,
         paged_decode_step=decode.paged_decode_step,
         write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
-        init_recurrent_store=decode.init_recurrent_store,
+        # a ring a slot only where the model has window layers
+        init_recurrent_store=(decode.init_recurrent_store
+                              if cfg.window_layers else None),
         init_params=model.init_params,
         paged_prefill_chunk=decode.paged_prefill_chunk,
-        counters=decode.COUNTERS)
+        counters=decode.counters(cfg))
 
 
 _FAMILIES = {"llama": _llama, "hybrid_moe": _hybrid_moe,
@@ -138,11 +144,12 @@ _FAMILIES = {"llama": _llama, "hybrid_moe": _hybrid_moe,
 
 
 def family_of(cfg) -> ServingFamily:
-    """The family of a configuration object, by its `family` attribute."""
+    """The family of a configuration object, by its `family` attribute, as
+    it stands for THIS configuration (its stores, its counters)."""
     if cfg.family not in _FAMILIES:
         raise KeyError(f"no serving family {cfg.family!r}; known: "
                        f"{sorted(_FAMILIES)}")
-    return _FAMILIES[cfg.family]()
+    return _FAMILIES[cfg.family](cfg)
 
 
 def config_from_meta(model_config: dict):

@@ -1,9 +1,11 @@
 """Configuration of the latent-attention block: multi-head latent attention
-(MLA) layers of two kinds in one model (a FULL kind whose queries read the
-keys a learned indexer chooses, a SLIDING kind of other sizes that sees a
-window), an output gate of one number a head on both, one leading dense
-layer, and a sparse expert feed-forward with a shared expert in every later
-layer.
+(MLA) layers, one leading dense layer, and a sparse expert feed-forward with
+a shared expert in every later layer. What the published configuration says
+decides the rest: the kinds of its layers in order (a FULL kind that reads
+the whole cache, or only the keys a learned indexer chooses where the
+configuration has one; a SLIDING kind of other sizes that sees a window),
+whether a mixer has an output gate of one number a head, whether the latents
+are rescaled, and how the rope's frequencies are scaled (YaRN).
 
 The third block family beside `models/llama/` and `models/hybrid_moe/`.
 Named for what it is: any model of this shape is served by it
@@ -17,15 +19,21 @@ from typing import Any
 
 import jax.numpy as jnp
 
-PERIOD = ("full", "sliding", "sliding", "sliding")
+from llama_pipeline_parallel_tpu.ops.rope import yarn_mscale
+
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+             "beta_slow", "mscale", "mscale_all_dim")
 
 
 @dataclasses.dataclass(frozen=True)
 class LatentMoEConfig:
     vocab_size: int = 152064
     hidden_size: int = 5120
-    # layer 0 (full, dense feed-forward), then whole periods of PERIOD
+    # layer 0 (full, dense feed-forward), then whole periods of `period`
     num_hidden_layers: int = 45
+    # the kinds of the layers that repeat after layer 0: a full layer, then
+    # the sliding layers that follow it (none: every layer is full)
+    period: tuple = ("full", "sliding", "sliding", "sliding")
     intermediate_size: int = 13824     # the dense layer's SwiGLU
     # full layers
     num_attention_heads: int = 128
@@ -35,10 +43,18 @@ class LatentMoEConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 8e7
-    # their indexer: which `index_topk` positions a query reads
+    # YaRN's numbers as sorted (key, value) pairs (`YARN_KEYS`; hashable: the
+    # configuration is a static argument of every program), None: the rope's
+    # frequencies and the softmax scale as they are
+    rope_scaling: tuple | None = None
+    # their indexer: which `index_topk` positions a query reads; 0: no
+    # indexer, a query reads every position it can see
     index_n_heads: int = 64
     index_head_dim: int = 128
     index_topk: int = 2048
+    # an output gate of one number a head, on the full and the sliding kind
+    attention_gate: bool = True
+    swa_attention_gate: bool = True
     # sliding layers
     swa_num_attention_heads: int = 64
     swa_q_lora_rank: int = 1024
@@ -77,14 +93,27 @@ class LatentMoEConfig:
     family = "latent_moe"              # class attribute, not a field
 
     def __post_init__(self) -> None:
-        if self.num_hidden_layers < 1 or \
-                (self.num_hidden_layers - 1) % len(PERIOD):
+        # a checkpoint's meta.json hands tuples back as lists
+        object.__setattr__(self, "period", tuple(self.period))
+        if self.rope_scaling is not None:
+            object.__setattr__(self, "rope_scaling", tuple(
+                (key, float(value)) for key, value in self.rope_scaling))
+            if tuple(k for k, _ in self.rope_scaling) != tuple(sorted(YARN_KEYS)):
+                raise ValueError(
+                    f"rope_scaling holds YaRN's {sorted(YARN_KEYS)} as "
+                    f"sorted pairs; got {self.rope_scaling}")
+        n = len(self.period)
+        if self.period != ("full",) + ("sliding",) * (n - 1):
+            raise ValueError(f"a period is one full layer, then its sliding "
+                             f"layers; got {self.period}")
+        if self.num_hidden_layers < 1 or (self.num_hidden_layers - 1) % n:
             raise ValueError(
                 f"num_hidden_layers ({self.num_hidden_layers}) must be the "
                 f"leading dense layer plus a whole number of periods of "
-                f"{len(PERIOD)} (full, then {len(PERIOD) - 1} sliding)")
-        if self.index_topk < 1 or self.sliding_window_size < 1:
-            raise ValueError("index_topk and sliding_window_size must be >= 1")
+                f"{n} (full, then {n - 1} sliding)")
+        if self.index_topk < 0 or self.sliding_window_size < 1:
+            raise ValueError("index_topk must be >= 0 (0: no indexer) and "
+                             "sliding_window_size >= 1")
         if self.qk_rope_head_dim % 2 or self.swa_qk_rope_head_dim % 2 or \
                 self.qk_rope_head_dim > self.index_head_dim:
             raise ValueError("rope sizes must be even, and the indexer's "
@@ -102,18 +131,23 @@ class LatentMoEConfig:
 
     @property
     def periods(self) -> int:
-        return (self.num_hidden_layers - 1) // len(PERIOD)
+        return (self.num_hidden_layers - 1) // len(self.period)
+
+    @property
+    def has_indexer(self) -> bool:
+        return self.index_topk > 0
 
     @property
     def full_layers(self) -> int:
-        """Layers that keep latent and index pages: layer 0 and one a
-        period. The page pool's depth."""
+        """Layers that keep latent pages (and index pages, under an
+        indexer): layer 0 and one a period. The page pool's depth."""
         return 1 + self.periods
 
     @property
     def window_layers(self) -> int:
-        """Layers that keep a ring a slot: the ring store's depth."""
-        return self.periods * (len(PERIOD) - 1)
+        """Layers that keep a ring a slot: the ring store's depth (0: the
+        model keeps no store a slot)."""
+        return self.periods * (len(self.period) - 1)
 
     @property
     def expert_layers(self) -> int:
@@ -161,49 +195,75 @@ class LatentMoEConfig:
                 self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
                 self.swa_qk_rope_head_dim, self.swa_v_head_dim,
                 self.swa_rope_theta, scale(self.swa_q_lora_rank),
-                scale(self.swa_kv_lora_rank))
+                scale(self.swa_kv_lora_rank), self.swa_attention_gate)
         return MixerDims(
             self.num_attention_heads, self.q_lora_rank, self.kv_lora_rank,
             self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
             self.rope_theta, scale(self.q_lora_rank),
-            scale(self.kv_lora_rank))
+            scale(self.kv_lora_rank), self.attention_gate, self.rope_scaling)
 
     @staticmethod
     def from_published(config: dict, **kw) -> "LatentMoEConfig":
-        """From the keys of a published `config.json` of this shape
-        (`layer_types`, `q_lora_rank`, the `swa_*` and `index_*` keys, ...).
+        """From the keys of a published `config.json` of this shape, as they
+        are. `layer_types` gives the kinds of the layers in order (absent:
+        every layer is full, and the `swa_*` keys are not read); the
+        `index_*` keys an indexer on the full layers (absent: none);
+        `attention_gate_type` / `swa_attention_gate_type` a gate a head
+        (absent: none); `apply_mla_qkv_lora_rescale` the latents' rescale
+        (absent: none); `rope_scaling` YaRN's numbers (null: none).
         `n_routed_experts` counts the experts HELD where `router_experts`
         gives the router's width beside it (one chip's share of an
         expert-parallel deployment, with `expert_offset`)."""
         layers = config["num_hidden_layers"]
-        kinds = [t.split("_")[0] for t in config["layer_types"][:layers]]
-        if kinds != ["full"] + list(PERIOD) * ((layers - 1) // len(PERIOD)):
+        kinds = tuple(t.split("_")[0] for t in config.get(
+            "layer_types", ["full_attention"] * layers)[:layers])
+        rest = kinds[1:]
+        period = rest[:next((i for i, kind in enumerate(rest[1:], 1)
+                             if kind == "full"), len(rest))] or ("full",)
+        if kinds != ("full",) + period * ((layers - 1) // len(period)):
             raise ValueError(
                 f"layer_types[:{layers}] is not one full layer then whole "
-                f"periods of {PERIOD}: {kinds}")
+                f"periods of a full layer and its sliding layers: {kinds}")
         if config["first_k_dense_replace"] != 1:
             raise ValueError("this block has exactly one leading dense layer")
-        for key in ("attention_gate_type", "swa_attention_gate_type"):
-            if config[key] != "headwise":
+        sliding = "sliding" in period
+        gates = ("attention_gate_type",) + (
+            ("swa_attention_gate_type",) if sliding else ())
+        for key in gates:
+            if config.get(key) not in (None, "headwise"):
                 raise ValueError(f"{key}: {config[key]!r}: the gate is one "
                                  f"number a head")
-        if config["scoring_func"] != "sigmoid" or config.get("rope_scaling"):
-            raise ValueError("the router scores with a sigmoid, and the "
-                             "rope is not rescaled")
+        if config["scoring_func"] != "sigmoid":
+            raise ValueError("the router scores with a sigmoid")
+        scaling = config.get("rope_scaling")
+        if scaling is not None:
+            if scaling.get("type", scaling.get("rope_type")) != "yarn" or sliding:
+                raise ValueError(
+                    f"rope_scaling {scaling}: the rope is rescaled by YaRN, "
+                    f"in a model without sliding layers, or not at all")
+            scaling = tuple(sorted((key, float(scaling[key]))
+                                   for key in YARN_KEYS))
         width = config["moe_intermediate_size"]
+        indexer = ("index_n_heads", "index_head_dim")
+        swa = ("swa_num_attention_heads", "swa_q_lora_rank",
+               "swa_kv_lora_rank", "swa_qk_nope_head_dim",
+               "swa_qk_rope_head_dim", "swa_v_head_dim", "sliding_window_size")
         copied = (
             "vocab_size", "hidden_size", "num_hidden_layers",
             "intermediate_size", "num_attention_heads", "q_lora_rank",
             "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
-            "v_head_dim", "index_n_heads", "index_head_dim", "index_topk",
-            "swa_num_attention_heads", "swa_q_lora_rank", "swa_kv_lora_rank",
-            "swa_qk_nope_head_dim", "swa_qk_rope_head_dim", "swa_v_head_dim",
-            "sliding_window_size", "num_experts_per_tok", "rms_norm_eps")
+            "v_head_dim", "num_experts_per_tok", "rms_norm_eps") + (
+                indexer if "index_topk" in config else ()) + (
+                    swa if sliding else ())
         base = {key: config[key] for key in copied}
+        if sliding:
+            base["swa_rope_theta"] = float(config["swa_rope_theta"])
         base.update(
-            rope_theta=float(config["rope_theta"]),
-            swa_rope_theta=float(config["swa_rope_theta"]),
-            lora_rescale=bool(config["apply_mla_qkv_lora_rescale"]),
+            period=period, index_topk=config.get("index_topk", 0),
+            rope_theta=float(config["rope_theta"]), rope_scaling=scaling,
+            attention_gate=config.get("attention_gate_type") is not None,
+            swa_attention_gate=config.get("swa_attention_gate_type") is not None,
+            lora_rescale=bool(config.get("apply_mla_qkv_lora_rescale", False)),
             router_experts=config.get("router_experts",
                                       config["n_routed_experts"]),
             moe_intermediate_size=width,
@@ -237,6 +297,9 @@ class LatentMoEConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MixerDims:
+    """The sizes of one kind of mixer, whether it has a gate, and how its
+    rope is scaled."""
+
     heads: int
     rq: int
     rkv: int
@@ -246,7 +309,15 @@ class MixerDims:
     theta: float
     rq_scale: float
     rkv_scale: float
+    gate: bool = True
+    rope_scaling: tuple | None = None
 
     @property
     def softmax_scale(self) -> float:
-        return (self.nope + self.rope) ** -0.5
+        """1 / sqrt(head) and, under YaRN, the square of its attention
+        factor at `mscale_all_dim` (as DeepSeek-V3 publishes it)."""
+        scale = (self.nope + self.rope) ** -0.5
+        if self.rope_scaling is None:
+            return scale
+        yarn = dict(self.rope_scaling)
+        return scale * yarn_mscale(yarn["factor"], yarn["mscale_all_dim"]) ** 2

@@ -4,23 +4,29 @@ signatures of their `models/llama/decode.py` namesakes, so `serve/engine.py`
 and `serve/pages.py` drive this family through `models/family.py` without
 naming it.
 
-Three stores ride one donated tree (`pool`):
+What the configuration has decides the stores and each full layer's
+program: under an indexer (`cfg.has_indexer`) a full layer keeps index pages
+and reads the positions it selects; without one it reads every position the
+query can see; the ring exists where the model has sliding layers.
+
+Up to three stores ride one donated tree (`pool`):
 
 - `latent` [full layers, pages + 1, page, 640]: what a full layer keeps of a
   token, the normed latent and the shared roped key (`model.project`'s
   `entry`, 576 numbers, stored padded to whole tiles: config.py
   `store_multiple`), in the pages the slot's table names;
-- `index` [full layers, pages + 1, page, 128]: the indexer's key of the same
-  token in the same page and place. It exists only to choose which
-  `index_topk` of the latents a query reads;
-- `ring` [sliding layers, slots, R, 1152]: a sliding layer's entries (1088
-  numbers, padded likewise) of the last R >= window positions of each slot,
-  logical position p at p % R.
+- `index` [full layers, pages + 1, page, 128] (under an indexer only): the
+  indexer's key of the same token in the same page and place. It exists
+  only to choose which `index_topk` of the latents a query reads;
+- `ring` [sliding layers, slots, R, 1152] (a model with sliding layers
+  only): a sliding layer's entries (1088 numbers, padded likewise) of the
+  last R >= window positions of each slot, logical position p at p % R.
   Older positions are overwritten: the layer never sees them again.
 
 Layer 0 (full, dense feed-forward) runs before a scan over PERIODS whose body
-unrolls a full and three sliding layers. All three stores ride its carry and
-are touched only by indexed reads and writes (never the scan's `xs` / `ys`:
+unrolls `cfg.period`: a full layer and its sliding layers (a model of one
+kind of layer scans over its layers). The stores ride its carry and are
+touched only by indexed reads and writes (never the scan's `xs` / `ys`:
 models/llama/decode.py "How the pool is walked").
 
 Positions. A slot's LOGICAL row is its left-padded prompt bucket followed by
@@ -30,16 +36,23 @@ is never visible: not to the indexer, not to the window, and it counts for
 nothing in `index_visible`. Rope takes the token's own position (pads not
 counted), as the engine passes it.
 
-A full layer's read is by TOKEN, not by page: index scores against every
-index key of the slot's table (one gather of its pages), an exact top-k
-(`lax.top_k`; a row of at most `index_topk` places selects every visible
-one without a sort: the same set), then a gather of the chosen latents:
-through the page table in the tick, from the slot's gathered row in a
-prefill, whose queries run in blocks (`model.full_span`).
+Under an indexer a full layer's read is by TOKEN, not by page: index scores
+against every index key of the slot's table (one gather of its pages), an
+exact top-k (`lax.top_k`; a row of at most `index_topk` places selects every
+visible one without a sort: the same set), then a gather of the chosen
+latents: through the page table in the tick, from the slot's gathered row in
+a prefill, whose queries run in blocks (`model.full_span`).
+
+Without one the read is DENSE. The tick computes the absorbed form in one
+kernel that walks the slot's page table over the pool's own live pages
+(`ops/paged_latent_attention.py`: no gathered row, no `top_k`); a prefill or
+a chunk computes the projected form, the earlier entries expanded to keys
+and values once for all its queries, in a kernel blocked over keys
+(`model.dense_span`, `ops/latent_prefill_attention.py`: no `[heads, T, S]`
+scores).
 
 What this family cannot do yet is refused by name where the engine is built
-(`models/family.py`): a prefix cache and the span prefill (the ring at a
-divergence point is not kept), int8 pages.
+(`models/family.py`): a prefix cache and the span prefill, int8 pages.
 """
 
 from __future__ import annotations
@@ -51,33 +64,54 @@ import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid
 from llama_pipeline_parallel_tpu.models.latent_moe import model as latent
-from llama_pipeline_parallel_tpu.models.latent_moe.config import (
-    PERIOD,
-    LatentMoEConfig,
-)
+from llama_pipeline_parallel_tpu.models.latent_moe.config import LatentMoEConfig
 from llama_pipeline_parallel_tpu.models.llama import decode as dense_decode
 from llama_pipeline_parallel_tpu.models.llama import model as llama
+from llama_pipeline_parallel_tpu.ops.paged_latent_attention import (
+    paged_latent_decode_attention,
+)
 from llama_pipeline_parallel_tpu.utils import trace
 
 Params = dict
-COUNTERS = hybrid.COUNTERS + ("index_visible", "index_selected")
 _N_MOE = len(hybrid.COUNTERS)
 KEY_REACHES = 16          # branches of a chunk's full layer, by its reach
 
 
+def counters(cfg: LatentMoEConfig) -> tuple:
+    """Names of the int32 counters every program returns, in order: the
+    expert layers' and the full layers' own. Under an indexer the positions
+    its queries could see and the ones they selected; without one the
+    positions they could see and therefore read (`latent_visible`). Summed
+    over rows and full layers; pads and rows that are not decoding count for
+    nothing."""
+    return hybrid.COUNTERS + (("index_visible", "index_selected")
+                              if cfg.has_indexer else ("latent_visible",))
+
+
+def _page_leaves(cfg: LatentMoEConfig) -> dict:
+    """{leaf with a page axis: numbers it keeps of a token}."""
+    leaves = {"latent": cfg.latent_store_width}
+    if cfg.has_indexer:
+        leaves["index"] = cfg.index_head_dim
+    return leaves
+
+
 def init_page_pool(cfg: LatentMoEConfig, num_pages: int, page_size: int,
                    quant: str = "fp") -> dict:
-    """Zeroed latent and index pages of the full layers, with the garbage
-    page (`models/llama/decode.init_page_pool`)."""
+    """Zeroed pages of the full layers (latents, and index keys under an
+    indexer), with the garbage page (`models/llama/decode.init_page_pool`)."""
     if quant != "fp":
         raise ValueError(f"the latent block keeps fp pages only, got {quant!r}")
     lead = (cfg.full_layers, num_pages + 1, page_size)
-    return {"latent": jnp.zeros(lead + (cfg.latent_store_width,), cfg.dtype),
-            "index": jnp.zeros(lead + (cfg.index_head_dim,), cfg.dtype)}
+    return {name: jnp.zeros(lead + (width,), cfg.dtype)
+            for name, width in _page_leaves(cfg).items()}
 
 
 def init_recurrent_store(cfg: LatentMoEConfig, max_slots: int) -> dict:
-    """The per-slot store: a zeroed ring a slot and sliding layer."""
+    """The per-slot store: a zeroed ring a slot and sliding layer; nothing
+    for a model without sliding layers."""
+    if not cfg.window_layers:
+        return {}
     return {"ring": jnp.zeros((cfg.window_layers, max_slots, cfg.ring_len,
                                cfg.ring_store_width), cfg.dtype)}
 
@@ -85,15 +119,17 @@ def init_recurrent_store(cfg: LatentMoEConfig, max_slots: int) -> dict:
 def _walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
           cfg: LatentMoEConfig, full_layer, window_layer, mlp_scope: str):
     """Run every layer with the stores in the carry. `full_layer(layer, h,
-    stores, depth) -> (h, stores, int32[2], selection)` (depth: the layer's
-    place in the latent and index pages) and `window_layer(layer, h, stores,
-    index) -> (h, stores)` (index: its place in the ring store) are the
-    caller's mixers; a full layer's `selection` is (chosen, ok) of each
-    row's last query. Layer 0 is followed by the dense feed-forward, every
-    other layer by its expert half. Returns the hidden state, the stores,
-    the counters summed over layers (int32[7], `COUNTERS`) and the
-    selections stacked over the full layers."""
-    n = len(PERIOD)
+    stores, depth) -> (h, stores, counted, selection)` (depth: the layer's
+    place in the latent and index pages; `counted`: the full layers' own
+    counters of `counters(cfg)`, int32[2] or int32[1]) and
+    `window_layer(layer, h, stores, index) -> (h, stores)` (index: its place
+    in the ring store) are the caller's mixers; a full layer's `selection`
+    is (chosen, ok) of each row's last query under an indexer, () without.
+    Layer 0 is followed by the dense feed-forward, every other layer by its
+    expert half. Returns the hidden state, the stores, the counters summed
+    over layers (`counters(cfg)`) and the selections stacked over the full
+    layers."""
+    n = len(cfg.period)
 
     h, stores, indexed, first_sel = full_layer(params["first"]["attn"], x,
                                                stores, 0)
@@ -125,12 +161,13 @@ def _walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
 def prefill_prompt(params: Params, input_ids: jnp.ndarray,
                    attention_mask: jnp.ndarray, cfg: LatentMoEConfig,
                    max_len: int) -> dict:
-    """Prefill LEFT-padded prompts ([b, P]) into fresh rows of the three
-    stores. Returns what the dense `prefill_prompt` returns ({"logits",
-    "cache", "kv_mask", "next_pos"}), the cache holding `latent` / `index`
-    [full layers, b, max_len, *] with the prompt at [0, P) and `ring`
-    [sliding layers, b, R, 1152] with the prompt's last positions at p % R,
-    plus "counters" (int32[7]) and "selection"."""
+    """Prefill LEFT-padded prompts ([b, P]) into fresh rows of the stores.
+    Returns what the dense `prefill_prompt` returns ({"logits", "cache",
+    "kv_mask", "next_pos"}), the cache holding `latent` (and `index`) [full
+    layers, b, max_len, *] with the prompt at [0, P) and, for a model with
+    sliding layers, `ring` [sliding layers, b, R, 1152] with the prompt's
+    last positions at p % R, plus "counters" (`counters(cfg)`) and
+    "selection"."""
     b, P = input_ids.shape
     if P > max_len:
         raise ValueError(f"prompt bucket {P} exceeds cache max_len {max_len}")
@@ -140,9 +177,9 @@ def prefill_prompt(params: Params, input_ids: jnp.ndarray,
     places = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (b, P))
     full, win = cfg.kind(False), cfg.kind(True)
     lead = (cfg.full_layers, b, max_len)
-    stores = {"latent": jnp.zeros(lead + (cfg.latent_store_width,), cfg.dtype),
-              "index": jnp.zeros(lead + (cfg.index_head_dim,), cfg.dtype),
-              "ring": init_recurrent_store(cfg, b)["ring"]}
+    stores = {name: jnp.zeros(lead + (width,), cfg.dtype)
+              for name, width in _page_leaves(cfg).items()}
+    stores.update(init_recurrent_store(cfg, b))
     prev = cfg.sliding_window_size - 1
     kept = latent.kept_positions(P, cfg)
     ring_at = (P - kept + jnp.arange(kept)) % cfg.ring_len
@@ -150,14 +187,19 @@ def prefill_prompt(params: Params, input_ids: jnp.ndarray,
 
     def full_layer(layer, h, stores, depth):
         pr = latent.project(layer, h, positions, full, cfg)
-        pr["index"] = latent.index_project(layer, pr["hidden"], pr["cq"],
-                                           positions, cfg)
+        new = {"latent": latent.stored(pr["entry"], cfg.latent_store_width)}
+        if cfg.has_indexer:
+            pr["index"] = latent.index_project(layer, pr["hidden"], pr["cq"],
+                                               positions, cfg)
+            new["index"] = pr["index"][1]
         with jax.named_scope(trace.LATENT_WRITE):
-            stores = {**stores,
-                      "latent": stores["latent"].at[depth, :, :P].set(
-                          latent.stored(pr["entry"], cfg.latent_store_width)),
-                      "index": stores["index"].at[depth, :, :P].set(
-                          pr["index"][1])}
+            stores = {**stores, **{
+                name: stores[name].at[depth, :, :P].set(rows)
+                for name, rows in new.items()}}
+        if not cfg.has_indexer:
+            h = latent.dense_span(layer, h, jnp.int32(0), pr, pr["entry"],
+                                  valid, cfg)
+            return h, stores, latent.visible_count(valid, places, valid), ()
         h, counted, sel = latent.full_span(
             layer, h, valid, places, pr, pr["entry"], pr["index"][1], valid,
             cfg)
@@ -191,21 +233,25 @@ def write_pages(pool: dict, kv_mask: jnp.ndarray, slot: jnp.ndarray,
                 page_rows: jnp.ndarray, row_cache: dict,
                 row_kv_mask: jnp.ndarray) -> tuple[dict, jnp.ndarray]:
     """Splice one prefilled request (`prefill_prompt` at b == 1, max_len ==
-    the bucket) into the three stores: its latents and index keys into the
-    slot's pages, its ring whole into row `slot` (whatever the last occupant
-    left there is gone), and the mask row rewritten whole."""
+    the bucket) into the stores: its latents (and index keys) into the
+    slot's pages, its ring, where the model has one, whole into row `slot`
+    (whatever the last occupant left there is gone), and the mask row
+    rewritten whole."""
     out = dict(pool)
     n_pages = page_rows.shape[0]
     with jax.named_scope(trace.LATENT_WRITE):
-        for name in ("latent", "index"):
+        for name in row_cache:
+            if name == "ring":
+                continue
             depth, _, bucket, width = row_cache[name].shape
             blocks = row_cache[name].reshape(depth, n_pages, bucket // n_pages,
                                              width)
             out[name] = out[name].at[:, page_rows].set(blocks)
-    with jax.named_scope(trace.RING_WRITE):
-        out["ring"] = jax.lax.dynamic_update_slice(
-            out["ring"], row_cache["ring"].astype(out["ring"].dtype),
-            (0, slot, 0, 0))
+    if "ring" in row_cache:
+        with jax.named_scope(trace.RING_WRITE):
+            out["ring"] = jax.lax.dynamic_update_slice(
+                out["ring"], row_cache["ring"].astype(out["ring"].dtype),
+                (0, slot, 0, 0))
     row = jnp.pad(row_kv_mask.astype(kv_mask.dtype),
                   ((0, 0), (0, kv_mask.shape[1] - row_kv_mask.shape[1])))
     return out, jax.lax.dynamic_update_slice(kv_mask, row, (slot, 0))
@@ -238,18 +284,42 @@ def tick_logits(params: Params, token: jnp.ndarray, pool: dict,
     ring_at = write_pos % cfg.ring_len
     by_row = jnp.arange(b)
 
+    live_pages = jnp.where(rows, write_pos // page + 1, 0)
+    visible = jnp.sum((before | own) & valid)[None].astype(jnp.int32)
+
     x = llama.embed(params, token[:, None], cfg)
 
-    def full_layer(layer, h, stores, depth):
+    def write(stores, depth, new: dict):
+        with jax.named_scope(trace.LATENT_WRITE):
+            stores = dict(stores)
+            for name, rows_new in new.items():
+                stores[name], _ = dense_decode._write_tokens(
+                    stores[name], None, depth, rows_new[:, 0], w_page, w_off,
+                    None)
+        return stores
+
+    def dense_layer(layer, h, stores, depth):
+        pr = latent.project(layer, h, positions, full, cfg)
+        stores = write(stores, depth, {
+            "latent": latent.stored(pr["entry"], cfg.latent_store_width)})
+        q_abs = latent.stored(
+            latent.absorb(layer, pr["q_nope"], pr["q_rope"], cfg),
+            cfg.latent_store_width)
+        with jax.named_scope(trace.LATENT_READ):
+            o = paged_latent_decode_attention(
+                q_abs[:, 0], stores["latent"], depth, page_table, live_pages,
+                kv_mask, full.softmax_scale, full.rkv)
+        h = latent.output(layer, h, pr["hidden"],
+                          latent.unabsorb(layer, o[:, None], cfg), cfg)
+        return h, stores, visible, ()
+
+    def indexed_layer(layer, h, stores, depth):
         pr = latent.project(layer, h, positions, full, cfg)
         qi, ki, weights = latent.index_project(layer, pr["hidden"], pr["cq"],
                                                positions, cfg)
-        with jax.named_scope(trace.LATENT_WRITE):
-            stores = dict(stores)
-            entry = latent.stored(pr["entry"], cfg.latent_store_width)
-            for name, new in (("latent", entry), ("index", ki)):
-                stores[name], _ = dense_decode._write_tokens(
-                    stores[name], None, depth, new[:, 0], w_page, w_off, None)
+        stores = write(stores, depth, {
+            "latent": latent.stored(pr["entry"], cfg.latent_store_width),
+            "index": ki})
         with jax.named_scope(trace.LATENT_GATHER):
             keys = stores["index"][depth, page_table].reshape(b, L, -1)
         scores = latent.index_scores(qi, weights, keys)[:, 0]       # [b, L]
@@ -291,7 +361,8 @@ def tick_logits(params: Params, token: jnp.ndarray, pool: dict,
         return h, {**stores, "ring": ring}
 
     x, pool, counters, selection = _walk(
-        params, x, valid, pool, cfg, full_layer, window_layer,
+        params, x, valid, pool, cfg,
+        indexed_layer if cfg.has_indexer else dense_layer, window_layer,
         trace.SCOPE_DECODE_MLP)
     x = llama.final_norm(params, x, cfg)
     return (llama.lm_head(params, x, cfg)[:, -1, :], pool, kv_mask, counters,
@@ -307,17 +378,19 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
                       temperature: jnp.ndarray, top_k: jnp.ndarray,
                       top_p: jnp.ndarray, cfg: LatentMoEConfig) -> dict:
     """One decode tick over every slot row, the arguments of the dense
-    `paged_decode_step`. A full layer writes this token's entry and index
-    key into (depth, w_page, w_off), scores the query against every index
-    key of the row's table, selects, gathers the chosen entries through the
-    table and attends them in the absorbed form; a sliding layer writes its
+    `paged_decode_step`. A full layer writes this token's entry (and index
+    key) into (depth, w_page, w_off); under an indexer it scores the query
+    against every index key of the row's table, selects, gathers the chosen
+    entries through the table and attends them in the absorbed form; without
+    one it attends every entry of the row's live pages in the absorbed form,
+    in place (`ops/paged_latent_attention.py`); a sliding layer writes its
     entry at `write_pos % R` of the row's ring and attends the ring. Rows
     that are not `active` leave the stores as they were (page writes go to
     the garbage page, the ring place is rewritten with what it held), are
     routed to no expert and count for nothing. The sampler's cost is the
     batch's own (`sample_rowwise`: an argmax a row unless an active row
     samples, a sort only where one filters). Returns the dense tick's
-    outputs plus "counters" (int32[7], `COUNTERS`) and "selection" (the
+    outputs plus "counters" (`counters(cfg)`) and "selection" (the
     places each row's query selected in each full layer, and which of them
     hold a position: read by tests and by the benchmark's check, never by
     the engine)."""
@@ -342,11 +415,13 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
     """One bounded prefill chunk of slot `slot`, the arguments of the dense
     `paged_prefill_chunk`: chunk tokens [1, C] at logical places
     [write_start, write_start + C), C a multiple of the page. A full layer
-    writes the chunk's entries and index keys into its pages, scores the
-    chunk's queries against the slot's index keys so far, selects, and
-    attends the selected entries of the slot's gathered row, both only as far
-    as the chunk's own end reaches (a sort and a gather of 4k places for a
-    chunk that ends at 4k, not of the whole row); a sliding layer
+    writes the chunk's entries (and index keys) into its pages; under an
+    indexer it scores the chunk's queries against the slot's index keys so
+    far, selects, and attends the selected entries of the slot's gathered
+    row; without one it expands the slot's entries so far to keys and values
+    and every query attends all it can see; either only as far as the
+    chunk's own end reaches (a sort, a gather or an expansion of 4k places
+    for a chunk that ends at 4k, not of the whole row); a sliding layer
     reads the ring for the window - 1 places before the chunk, attends, and
     leaves the chunk's last places in the ring. A chunk of nothing but left
     pads changes no visible state. Returns the LAST position's float32
@@ -376,14 +451,16 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
 
     def full_layer(layer, h, stores, depth):
         pr = latent.project(layer, h, positions, full, cfg)
-        pr["index"] = latent.index_project(layer, pr["hidden"], pr["cq"],
-                                           positions, cfg)
-        entry = latent.stored(pr["entry"], cfg.latent_store_width)
+        new = {"latent": latent.stored(pr["entry"], cfg.latent_store_width)}
+        if cfg.has_indexer:
+            pr["index"] = latent.index_project(layer, pr["hidden"], pr["cq"],
+                                               positions, cfg)
+            new["index"] = pr["index"][1]
         with jax.named_scope(trace.LATENT_WRITE):
             stores = dict(stores)
-            for name, new in (("latent", entry), ("index", pr["index"][1])):
+            for name, rows_new in new.items():
                 stores[name] = stores[name].at[depth, chunk_pages].set(
-                    new[0].reshape(C // page, page, -1))
+                    rows_new[0].reshape(C // page, page, -1))
 
         def over_first(n_pages: int):
             """The mixer against the row's first `n_pages` pages: all a
@@ -392,6 +469,12 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
                 rows, upto = page_table_row[:n_pages], n_pages * page
                 with jax.named_scope(trace.LATENT_GATHER):
                     entries = stores["latent"][depth, rows].reshape(1, upto, -1)
+                if not cfg.has_indexer:
+                    out = latent.dense_span(layer, h, write_start, pr, entries,
+                                            row_valid[:, :upto], cfg)
+                    return out, latent.visible_count(row_valid, places,
+                                                     valid), ()
+                with jax.named_scope(trace.LATENT_GATHER):
                     keys = stores["index"][depth, rows].reshape(1, upto, -1)
                 out, counted, (chosen, ok) = latent.full_span(
                     layer, h, valid, places, pr, entries, keys,

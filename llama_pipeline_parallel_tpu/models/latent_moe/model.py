@@ -1,11 +1,12 @@
 """Layers of the latent-attention block (config.py): the MLA mixer of either
 kind in its PROJECTED form (keys and values of every head made from the
-latent; the sliding layers' prefill) and its ABSORBED form (the up-projection
-folded into the query and the output, so attention runs over the latents as
-the cache keeps them; every full layer, and the sliding layers' tick), the
-full layers' indexer and its exact top-k, the window's mask, the headwise
-gate. The expert half of a layer is `hybrid_moe.model.moe_block`, the dense
-layer's feed-forward `llama.mlp_block`: one implementation each.
+latent; the prefill of a sliding layer and of a full layer without an
+indexer) and its ABSORBED form (the up-projection folded into the query and
+the output, so attention runs over the latents as the cache keeps them; a
+full layer under an indexer, and every layer's tick), the indexer and its
+exact top-k, the window's mask, the headwise gate, YaRN's rope. The expert
+half of a layer is `hybrid_moe.model.moe_block`, the dense layer's
+feed-forward `llama.mlp_block`: one implementation each.
 
 Parameter tree (`init_params`; the periods' leaves stacked so that the
 serving programs scan over periods with a period's layers unrolled, layers of
@@ -14,22 +15,23 @@ a period separate leaves as in models/hybrid_moe/model.py):
     embed.embedding [V, d]   norm [d]   lm_head [d, V]
     first.attn.*  first.post_norm  first.mlp.*     layer 0: full, dense
     periods.full.*    [P, ...]     the full layer of each period
-    periods.win[j].*  [P, ...]     its j-th sliding layer (3 of them)
+    periods.win[j].*  [P, ...]     its j-th sliding layer (`cfg.period`'s
+                                   sliding layers; none: an empty list)
     periods.moe[j].*  [P, ...]     the expert half of its j-th layer
 
 A mixer's leaves (H heads, latents of rank rq / rkv, a head nope + rope
 wide, values v wide): `input_norm [d]`, `wqa [d, rq]`, `q_norm [rq]`,
 `wqb [rq, H (nope + rope)]`, `wkva [d, rkv + rope]`, `kv_norm [rkv]`,
-`wkb_k [rkv, H, nope]`, `wkb_v [rkv, H, v]`, `wg [d, H]`, `wo [H v, d]`; a
-full layer adds its indexer's `wqi [rq, Hi di]`, `wki [d, di]`, `ki_norm`,
-`ki_bias [di]`, `ww [d, Hi]`.
+`wkb_k [rkv, H, nope]`, `wkb_v [rkv, H, v]`, `wo [H v, d]`; `wg [d, H]`
+where the kind has a gate; a full layer under an indexer adds `wqi [rq, Hi
+di]`, `wki [d, di]`, `ki_norm`, `ki_bias [di]`, `ww [d, Hi]`.
 
     cq = r_q rmsnorm(W_qa x);  [q^N_h; q^R_h] = W_qb,h cq,  q^R roped
     [c; k^R] = W_kva x;  c = r_kv rmsnorm(c),  k^R roped, shared by the heads
     projected:  k_h,s = [W_kb,h^K c_s; k^R_s],  v_h,s = W_kb,h^V c_s
     absorbed:   q'_h = W_kb,h^K^T q^N_h;  scores q'_h . c_s + q^R_h . k^R_s;
                 o'_h = sum_s p_s c_s;  o_h = W_kb,h^V o'_h
-    y_t = W_o [sigmoid(W_g x_t)_h o_h,t]_h
+    y_t = W_o [sigmoid(W_g x_t)_h o_h,t]_h        (no gate: W_o [o_h,t]_h)
 
 What a layer keeps of a token is the ENTRY `[c_s; k^R_s]` (after norm,
 rescale and rope): 576 numbers in a full layer, 1088 in a sliding one. A
@@ -46,12 +48,14 @@ import jax
 import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.models.latent_moe.config import (
-    PERIOD,
     LatentMoEConfig,
     MixerDims,
 )
 from llama_pipeline_parallel_tpu.models.llama.model import cast_weight
 from llama_pipeline_parallel_tpu.ops.attention import NEG_INF
+from llama_pipeline_parallel_tpu.ops.latent_prefill_attention import (
+    latent_prefill_attention,
+)
 from llama_pipeline_parallel_tpu.ops.rmsnorm import rms_norm
 from llama_pipeline_parallel_tpu.ops.rope import apply_rope, rope_cos_sin
 from llama_pipeline_parallel_tpu.ops.sparse_latent_attention import (
@@ -88,8 +92,10 @@ def init_params(rng: jax.Array, cfg: LatentMoEConfig) -> Params:
                "kv_norm": jnp.ones(lead + (kd.rkv,), pd),
                "wkb_k": proj(*lead, kd.rkv, H, kd.nope),
                "wkb_v": proj(*lead, kd.rkv, H, kd.v),
-               "wg": proj(*lead, d, H), "wo": proj(*lead, H * kd.v, d)}
-        if not sliding:
+               "wo": proj(*lead, H * kd.v, d)}
+        if kd.gate:
+            out["wg"] = proj(*lead, d, H)
+        if not sliding and cfg.has_indexer:
             nh, hd = cfg.index_n_heads, cfg.index_head_dim
             out.update(wqi=proj(*lead, kd.rq, nh * hd), wki=proj(*lead, d, hd),
                        ki_norm=jnp.ones(lead + (hd,), pd),
@@ -109,7 +115,7 @@ def init_params(rng: jax.Array, cfg: LatentMoEConfig) -> Params:
                 "shared_down": proj(P, fs, d)}
 
     ffn = cfg.intermediate_size
-    n = len(PERIOD)
+    n = len(cfg.period)
     return {"embed": {"embedding": proj(cfg.vocab_size, d)},
             "first": {"attn": mixer(False, ()), "post_norm": jnp.ones((d,), pd),
                       "mlp": {"gate": proj(d, ffn), "up": proj(d, ffn),
@@ -122,9 +128,12 @@ def init_params(rng: jax.Array, cfg: LatentMoEConfig) -> Params:
 
 # -- the mixer's projections ---------------------------------------------------
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, dtype):
-    """Rotate-half rope on x [b, s, h, n] at `positions` [b, s]."""
-    cos, sin = rope_cos_sin(positions, x.shape[-1], theta, dtype=dtype)
+def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, dtype,
+          scaling: tuple | None = None):
+    """Rotate-half rope on x [b, s, h, n] at `positions` [b, s]; `scaling`:
+    YaRN's numbers as the configuration keeps them."""
+    cos, sin = rope_cos_sin(positions, x.shape[-1], theta, dtype=dtype,
+                            scaling=dict(scaling) if scaling else None)
     return apply_rope(x, x, cos, sin)[0]
 
 
@@ -143,11 +152,13 @@ def project(layer: Params, x: jnp.ndarray, positions: jnp.ndarray,
         cq = rms_norm(hidden @ w("wqa"), layer["q_norm"], cfg.rms_norm_eps)
         cq = (cq * kd.rq_scale).astype(dt)
         q = (cq @ w("wqb")).reshape(b, s, kd.heads, kd.nope + kd.rope)
-        q_rope = _rope(q[..., kd.nope:], positions, kd.theta, dt)
+        q_rope = _rope(q[..., kd.nope:], positions, kd.theta, dt,
+                       kd.rope_scaling)
         ckv = hidden @ w("wkva")
         c = rms_norm(ckv[..., :kd.rkv], layer["kv_norm"], cfg.rms_norm_eps)
         c = (c * kd.rkv_scale).astype(dt)
-        k_rope = _rope(ckv[..., None, kd.rkv:], positions, kd.theta, dt)[:, :, 0]
+        k_rope = _rope(ckv[..., None, kd.rkv:], positions, kd.theta, dt,
+                       kd.rope_scaling)[:, :, 0]
         entry = jnp.concatenate([c, k_rope], axis=-1)
     return {"hidden": hidden, "cq": cq, "q_nope": q[..., :kd.nope],
             "q_rope": q_rope, "entry": entry}
@@ -239,14 +250,16 @@ def attend_projected(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def output(layer: Params, x: jnp.ndarray, hidden: jnp.ndarray, o: jnp.ndarray,
            cfg: LatentMoEConfig) -> jnp.ndarray:
-    """The headwise gate (one number a head, from the layer's normed input),
-    the output projection and the residual. o: [b, s, H, v]."""
+    """The headwise gate where the layer has one (one number a head, from
+    the layer's normed input), the output projection and the residual. o:
+    [b, s, H, v] or [b, s, H v]."""
     b, s, _ = x.shape
-    with jax.named_scope(trace.ATTN_GATE):
-        gate = jax.nn.sigmoid(hidden @ cast_weight(layer["wg"], cfg.dtype))
-        o = (gate[..., None] * o).reshape(b, s, -1)
+    if "wg" in layer:
+        with jax.named_scope(trace.ATTN_GATE):
+            gate = jax.nn.sigmoid(hidden @ cast_weight(layer["wg"], cfg.dtype))
+            o = gate[..., None] * o.reshape(b, s, gate.shape[-1], -1)
     with jax.named_scope(trace.SCOPE_ATTN_OUT):
-        return x + o @ cast_weight(layer["wo"], cfg.dtype)
+        return x + o.reshape(b, s, -1) @ cast_weight(layer["wo"], cfg.dtype)
 
 
 # -- the indexer ---------------------------------------------------------------
@@ -394,6 +407,44 @@ def full_span(layer: Params, x: jnp.ndarray, q_valid: jnp.ndarray,
     last = (chosen[-1, :, -1], ok[-1, :, -1])
     return (output(layer, x, pr["hidden"], o, cfg), jnp.sum(counted, axis=0),
             last)
+
+
+# -- a full layer without an indexer over a span of queries ----------------------
+
+def visible_count(key_valid: jnp.ndarray, q_place: jnp.ndarray,
+                  q_valid: jnp.ndarray) -> jnp.ndarray:
+    """int32[1]: the valid places at or before each valid query's own,
+    summed: what `latent_visible` sums for one layer. key_valid: [b, S]
+    bool; q_place: [b, T] the queries' places among the S; q_valid: [b, T]."""
+    upto = jnp.cumsum(key_valid.astype(jnp.int32), axis=1)
+    seen = jnp.take_along_axis(upto, q_place, axis=1)
+    return jnp.sum(jnp.where(q_valid, seen, 0))[None].astype(jnp.int32)
+
+
+def dense_span(layer: Params, x: jnp.ndarray, q_start: jnp.ndarray, pr: dict,
+               entries: jnp.ndarray, key_valid: jnp.ndarray,
+               cfg: LatentMoEConfig) -> jnp.ndarray:
+    """A full layer's mixer, WITHOUT an indexer, for T consecutive queries
+    of each row against S cached tokens of the same row, the queries' own
+    among them: every query reads every valid place up to its own. x: [b, T,
+    d]; `q_start`: int32 scalar, the place of the first query among the S;
+    `pr`: `project`'s result for x; entries: [b, S, w]; key_valid: [b, S]
+    bool. The projected form: each entry's keys and values are made once
+    for all the queries, and the scores live in the kernel
+    (`ops/latent_prefill_attention.py`). Returns x + y."""
+    kd = cfg.kind(False)
+    dt = cfg.dtype
+    with jax.named_scope(trace.MLA_PROJ):
+        c = entries[..., :kd.rkv]
+        k_nope = jnp.einsum("bsr,rhn->bhsn", c, cast_weight(layer["wkb_k"], dt))
+        v = jnp.einsum("bsr,rhv->bhsv", c, cast_weight(layer["wkb_v"], dt))
+        k_rope = entries[..., kd.rkv:kd.rkv + kd.rope]
+        q_nope = jnp.moveaxis(pr["q_nope"], 2, 1)
+        q_rope = jnp.moveaxis(pr["q_rope"], 2, 1)
+    with jax.named_scope(trace.LATENT_READ_PREFILL):
+        o = latent_prefill_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                     key_valid, q_start, kd.softmax_scale)
+    return output(layer, x, pr["hidden"], o, cfg)
 
 
 # -- a sliding layer over a span of queries --------------------------------------

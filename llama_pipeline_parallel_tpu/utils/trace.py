@@ -142,14 +142,17 @@ SPARSE_ATTN = "sparse_attn"          # absorbed attention over the chosen entrie
 WINDOW_ATTN = "window_attn"          # a sliding layer's attention
 RING_GATHER = "ring_gather"          # ring -> a sliding layer's rows
 RING_WRITE = "ring_write"
+# a full layer WITHOUT an indexer reads every position its query can see
+LATENT_READ = "latent_read"          # the tick: absorbed attention over the row's live pages
+LATENT_READ_PREFILL = "latent_read_prefill"  # a prefill's or a chunk's queries, projected form
 LATENT_SCOPES = (MLA_PROJ, INDEX_PROJ, INDEX_SCORE, INDEX_TOPK, LATENT_WRITE,
                  LATENT_GATHER, SPARSE_ATTN, WINDOW_ATTN, RING_GATHER,
-                 RING_WRITE)
+                 RING_WRITE, LATENT_READ, LATENT_READ_PREFILL)
 
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 
-# `name=` of the eleven pallas_calls: the kernel's instruction in a trace is
+# `name=` of the thirteen pallas_calls: the kernel's instruction in a trace is
 # `<name>.<n>`
 KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
@@ -162,6 +165,8 @@ KERNEL_PROLOGUE_BWD_DX = "prologue_bwd_dx"
 KERNEL_PROLOGUE_BWD_DW = "prologue_bwd_dw"
 KERNEL_PAGED_DECODE_ATTN = "paged_decode_attn"   # under SCOPE_DECODE_ATTN
 KERNEL_SPARSE_LATENT_ATTN = "sparse_latent_attn"  # under SPARSE_ATTN
+KERNEL_PAGED_LATENT_DECODE_ATTN = "paged_latent_decode_attn"  # under LATENT_READ
+KERNEL_LATENT_PREFILL_ATTN = "latent_prefill_attn"  # under LATENT_READ_PREFILL
 
 KERNELS = tuple(v for k, v in sorted(globals().items())
                 if k.startswith("KERNEL_"))

@@ -11,9 +11,17 @@ import numpy as np
 import pytest
 
 import latent_tiny as tiny
+import mla_tiny
 from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid
 from llama_pipeline_parallel_tpu.models.latent_moe import decode, model as latent
 from llama_pipeline_parallel_tpu.models.latent_moe.config import LatentMoEConfig
+from llama_pipeline_parallel_tpu.ops import rope
+from llama_pipeline_parallel_tpu.ops.latent_prefill_attention import (
+    latent_prefill_attention,
+)
+from llama_pipeline_parallel_tpu.ops.paged_latent_attention import (
+    paged_latent_decode_attention,
+)
 
 TOL = 2e-5
 
@@ -265,3 +273,303 @@ def test_the_sparse_read_kernel_is_the_xla_form(shape):
     got = latent.attend_chosen(q, entries, ok, kd)
     assert got.shape == (1, n, h, w - 4)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+# -- dots3's block computes what it computed before the family was generalised ----
+
+# the parent's values (commit 2b9ffa3, this file's tiny model and seed, the
+# prompt of `_prefill_logits`), pinned before the refactor of PR 32
+PINNED_PREFILL_LOGITS = [-0.41476768, -0.44767633, 0.11468326, -0.24117644,
+                         0.32801443, 0.7254978, -0.5284773, 0.6174224]
+PINNED_PREFILL_COUNTERS = [1024, 461, 60, 134, 64, 1584, 684]
+
+
+def test_dots3s_tiny_configuration_gives_the_logits_it_gave():
+    cfg = tiny.config()
+    assert cfg.period == ("full", "sliding", "sliding", "sliding")
+    assert cfg.has_indexer and cfg.attention_gate and cfg.lora_rescale
+    assert cfg.rope_scaling is None
+    assert decode.counters(cfg)[-2:] == ("index_visible", "index_selected")
+    params = tiny.weights.make_program_weights(tiny.SEED, tiny.MODEL, jnp.float32)
+    ids = np.random.default_rng(5).integers(0, 128, (1, 32)).astype(np.int32)
+    out = decode.prefill_prompt(params, jnp.asarray(ids),
+                                jnp.ones((1, 32), jnp.int32), cfg, 32)
+    np.testing.assert_allclose(out["logits"][0, :8], PINNED_PREFILL_LOGITS,
+                               atol=2e-6, rtol=0)
+    assert out["counters"].tolist() == PINNED_PREFILL_COUNTERS
+
+
+# -- one kind of layer: plain MLA under YaRN, no indexer, window or gate ----------
+
+
+AXK1_YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 32, "mscale": 1,
+             "mscale_all_dim": 1, "original_max_position_embeddings": 4096}
+
+
+def test_yarn_is_the_written_out_table():
+    """A.X-K1's numbers (rope 64, theta 1e4, factor 32 from 4096, 32 and 1
+    rotations): the correction dimensions are 64 ln(4096 / (r 2 pi)) / (2 ln
+    1e4) = 10.47 and 22.51, so frequencies 0..10 are kept, 23..31 divided by
+    32, and j between them blends by (j - 10) / 13."""
+    got = np.asarray(rope.yarn_inv_freq(64, 1e4, AXK1_YARN), np.float64)
+    plain = 1e4 ** (-np.arange(0, 64, 2) / 64.0)
+    np.testing.assert_allclose(got[:11], plain[:11], rtol=1e-6)
+    np.testing.assert_allclose(got[23:], plain[23:] / 32, rtol=1e-6)
+    for j in (11, 16, 22):
+        ramp = (j - 10) / 13
+        np.testing.assert_allclose(
+            got[j], plain[j] / 32 * ramp + plain[j] * (1 - ramp), rtol=1e-6)
+    # both sides' own tables, at the tiny sizes too
+    dm = mla_tiny.reference.dims(mla_tiny.MODEL)
+    cfg = mla_tiny.config()
+    np.testing.assert_allclose(
+        rope.yarn_inv_freq(8, 100.0, dict(cfg.rope_scaling)),
+        mla_tiny.reference.yarn_inv_freq(dm), rtol=1e-6)
+    # the attention factor: 0.1 ln 32 + 1, squared on the softmax scale
+    assert rope.yarn_mscale(32, 1) == pytest.approx(1.34657359)
+    full = LatentMoEConfig(rope_scaling=tuple(sorted(
+        (k, float(v)) for k, v in AXK1_YARN.items()))).kind(False)
+    assert full.softmax_scale == pytest.approx(192 ** -0.5 * 1.34657359 ** 2)
+    # cos and sin carry mscale / mscale_all_dim, 1 for A.X-K1
+    positions = jnp.arange(40, dtype=jnp.int32)[None]
+    cos, sin = rope.rope_cos_sin(positions, 8, 100.0,
+                                 scaling=dict(cfg.rope_scaling))
+    angle = np.arange(40)[:, None] * np.asarray(
+        rope.yarn_inv_freq(8, 100.0, dict(cfg.rope_scaling)))
+    amplitude = rope.yarn_mscale(4, 1) / rope.yarn_mscale(4, 0.5)
+    np.testing.assert_allclose(cos[0, :, :4], np.cos(angle) * amplitude,
+                               atol=1e-6)
+    np.testing.assert_allclose(sin[0, :, 4:], np.sin(angle) * amplitude,
+                               atol=1e-6)
+
+
+def test_no_rope_scaling_is_the_table_it_always_was():
+    positions = jnp.arange(300, dtype=jnp.int32).reshape(2, 150)
+    cos, sin = rope.rope_cos_sin(positions, 64, 8e7, dtype=jnp.bfloat16)
+    inv = 1.0 / (8e7 ** (jnp.arange(0, 64, 2, dtype=jnp.float32) / 64))
+    freqs = positions.astype(jnp.float32)[..., None] * inv
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    assert bool(jnp.all(cos == jnp.cos(emb).astype(jnp.bfloat16)))
+    assert bool(jnp.all(sin == jnp.sin(emb).astype(jnp.bfloat16)))
+    assert LatentMoEConfig().kind(False).softmax_scale == 192 ** -0.5
+
+
+def test_the_published_keys_of_a_model_of_one_kind_of_layer_are_read_as_they_are():
+    cfg = mla_tiny.config()
+    assert cfg.period == ("full",) and cfg.periods == 4
+    assert (cfg.full_layers, cfg.window_layers, cfg.expert_layers) == (5, 0, 4)
+    assert not cfg.has_indexer and not cfg.attention_gate
+    assert not cfg.lora_rescale and cfg.kind(False).rq_scale == 1.0
+    assert dict(cfg.rope_scaling)["factor"] == 4.0
+    assert (cfg.held, cfg.router_experts, cfg.expert_offset) == (8, 16, 4)
+    assert decode.counters(cfg)[-1] == "latent_visible"
+    assert len(decode.counters(cfg)) == 6
+    # what is neither a latent model's rope nor this block is refused
+    with pytest.raises(ValueError, match="YaRN"):
+        mla_tiny.config({**mla_tiny.MODEL, "rope_scaling": {
+            **mla_tiny.MODEL["rope_scaling"], "type": "linear"}})
+    with pytest.raises(ValueError, match="YaRN"):
+        tiny.config({**tiny.MODEL,
+                     "rope_scaling": mla_tiny.MODEL["rope_scaling"]})
+    with pytest.raises(ValueError, match="one full layer, then"):
+        LatentMoEConfig.tiny(period=("sliding", "full"), num_hidden_layers=5)
+    # the tree holds what the configuration has: no gate, no indexer, no
+    # sliding layers; the benchmark's draw has the same leaves
+    made = jax.eval_shape(lambda: latent.init_params(jax.random.PRNGKey(0), cfg))
+    assert made["periods"]["win"] == [] and len(made["periods"]["moe"]) == 1
+    assert not {"wg", "wqi", "wki", "ww"} & set(made["first"]["attn"])
+    drawn = mla_tiny.weights.make_program_weights(
+        mla_tiny.SEED, mla_tiny.MODEL, jnp.float32)
+    assert jax.tree.structure(drawn) == jax.tree.structure(made)
+    for a, b in zip(jax.tree.leaves(drawn), jax.tree.leaves(made)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+    assert mla_tiny.weights.param_count(mla_tiny.MODEL)["total"] == \
+        latent.param_count(cfg)
+
+
+def _mla_layer(index=1):
+    ref = mla_tiny.weights.make_layer(mla_tiny.SEED, index, mla_tiny.MODEL,
+                                      jnp.float32)
+    return ref, {"input_norm": ref["input_norm"], **ref["mixer"]}
+
+
+def test_the_projected_and_the_absorbed_form_are_the_references_mixer():
+    """A full layer without an indexer over a span: the projected form
+    through its kernel (`dense_span`), the absorbed form over the same
+    entries (`attend_entries`, the tick's arithmetic), and the reference's
+    mixer, left pads masked on the program's side."""
+    cfg = mla_tiny.config()
+    kd = cfg.kind(False)
+    ref, layer = _mla_layer()
+    x, positions = _inputs(s=40, seed=3)
+    want = x + mla_tiny.reference.mla_mixer(
+        ref["mixer"], mla_tiny.reference.rms_norm(x, ref["input_norm"], 1e-6),
+        positions, mla_tiny.reference.dims(mla_tiny.MODEL), "float32")
+    valid = jnp.ones(x.shape[:2], bool)
+    pr = latent.project(layer, x, positions, kd, cfg)
+    projected = latent.dense_span(layer, x, jnp.int32(0), pr, pr["entry"],
+                                  valid, cfg)
+    np.testing.assert_allclose(projected, want, atol=TOL)
+    causal = jnp.tril(jnp.ones((40, 40), bool))[None]
+    q_abs = latent.absorb(layer, pr["q_nope"], pr["q_rope"], cfg)
+    o = latent.attend_entries(q_abs, pr["entry"], jnp.broadcast_to(
+        causal, (2, 40, 40)), kd)
+    absorbed = latent.output(layer, x, pr["hidden"],
+                             latent.unabsorb(layer, o, cfg), cfg)
+    np.testing.assert_allclose(absorbed, want, atol=TOL)
+    assert latent.visible_count(valid, positions, valid).tolist() == [
+        2 * 40 * 41 // 2]
+
+
+@pytest.mark.parametrize("shape,q_start,pads", [
+    ((2, 3, 8, 24, 8, 4, 8), 16, (5, 0)),     # a chunk's queries, left pads
+    ((1, 2, 16, 16, 8, 8, 4), 0, (16,)),      # a row of nothing but pads
+    ((1, 4, 256, 768, 128, 64, 128), 512, (130,)),   # whole tiles, 2 x 2 blocks
+], ids=["chunk", "all-pads", "tiles"])
+def test_the_prefill_kernel_is_the_xla_form(shape, q_start, pads, monkeypatch):
+    """`ops/latent_prefill_attention.py` (interpreted here) against
+    `attend_projected` under the explicit mask: causal from `q_start`, the
+    row's left pads invisible, blocks past the diagonal and inside the pads
+    skipped."""
+    from llama_pipeline_parallel_tpu.models.latent_moe.config import MixerDims
+    from llama_pipeline_parallel_tpu.ops import latent_prefill_attention as lpa
+
+    b, H, T, S, nope, rope_n, dv = shape
+    monkeypatch.setattr(lpa, "BLOCK_Q", 128)
+    monkeypatch.setattr(lpa, "BLOCK_K", 256)
+    kd = MixerDims(H, 0, 0, nope, rope_n, dv, 1e4, 1.0, 1.0)
+    keys = jax.random.split(jax.random.PRNGKey(9), 5)
+    qn = jax.random.normal(keys[0], (b, H, T, nope), jnp.float32)
+    qr = jax.random.normal(keys[1], (b, H, T, rope_n), jnp.float32)
+    kn = jax.random.normal(keys[2], (b, H, S, nope), jnp.float32)
+    kr = jax.random.normal(keys[3], (b, S, rope_n), jnp.float32)
+    v = jax.random.normal(keys[4], (b, H, S, dv), jnp.float32)
+    valid = jnp.arange(S)[None, :] >= jnp.asarray(pads)[:, None]
+    got = latent_prefill_attention(qn, qr, kn, kr, v, valid,
+                                   jnp.int32(q_start), kd.softmax_scale)
+    q = jnp.moveaxis(jnp.concatenate([qn, qr], -1), 1, 2)
+    k = jnp.moveaxis(jnp.concatenate(
+        [kn, jnp.broadcast_to(kr[:, None], (b, H, S, rope_n))], -1), 1, 2)
+    place = q_start + jnp.arange(T)
+    mask = (jnp.arange(S)[None, None, :] <= place[None, :, None]) & \
+        valid[:, None, :]
+    want = latent.attend_projected(q, k, jnp.moveaxis(v, 1, 2), mask, kd)
+    seen = np.asarray(jnp.any(mask, -1))                     # [b, T]
+    got = np.asarray(got).reshape(b, T, H, dv)
+    np.testing.assert_allclose(got[seen], np.asarray(want)[seen], atol=2e-5,
+                               rtol=2e-5)
+    assert not got[~seen].any()             # a query that sees nothing: zeros
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2 ** -6)])
+def test_the_paged_latent_decode_kernel_is_the_xla_form(dtype, tol):
+    """`ops/paged_latent_attention.py` (interpreted here) against
+    `attend_entries` over each row's gathered logical row: pages out of
+    order in the pool, left pads and a hole in the mask, a row that is not
+    decoding (zeros), another layer's pages untouched."""
+    from llama_pipeline_parallel_tpu.models.latent_moe.config import MixerDims
+
+    L, pages, page, pmax, H, rank, width = 3, 40, 8, 6, 4, 24, 32
+    kd = MixerDims(H, 0, rank, 8, 4, 8, 1e4, 1.0, 1.0)
+    keys = jax.random.split(jax.random.PRNGKey(11), 2)
+    pool = jax.random.normal(keys[0], (L, pages + 1, page, width),
+                             jnp.float32).astype(dtype)
+    q = jax.random.normal(keys[1], (3, H, width), jnp.float32).astype(dtype)
+    q = q.at[..., rank + 4:].set(0)          # zeros past the entry
+    table = np.full((3, pmax), pages, np.int32)
+    table[0, :4] = [7, 3, 22, 9]
+    table[1, :6] = [1, 30, 2, 39, 5, 11]
+    mask = np.zeros((3, pmax * page), np.int32)
+    mask[0, 5:27] = 1                        # left pads, ends inside page 4
+    mask[0, 13] = 0
+    mask[1, :48] = 1
+    live = jnp.asarray([4, 6, 0])
+    got = paged_latent_decode_attention(
+        q, pool, jnp.int32(1), jnp.asarray(table), live, jnp.asarray(mask),
+        kd.softmax_scale, rank)
+    rows = pool[1][jnp.asarray(table)].reshape(3, pmax * page, width)
+    want = latent.attend_entries(q[:, None], rows, jnp.asarray(mask)[:, None] > 0,
+                                 kd)[:, 0]
+    assert got.shape == (3, H, rank) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got[:2], np.float32),
+                               np.asarray(want[:2], np.float32), atol=tol,
+                               rtol=tol)
+    assert not np.asarray(got[2], np.float32).any()
+
+
+def test_the_sixteen_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
+    """A.X-K1's deployment at a tiny width: 192 experts over sixteen chips
+    of twelve, top-8, scaled 2.5, the shared expert counted once: the sum of
+    what `moe_block` computes for each share is the uncut reference's layer
+    (guide §4)."""
+    model = {**mla_tiny.MODEL, "n_routed_experts": 192, "router_experts": 192,
+             "expert_offset": 0, "num_experts_per_tok": 8}
+    layer = mla_tiny.weights.make_layer(mla_tiny.SEED, 2, model, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, 32), jnp.float32)
+    dm = mla_tiny.reference.dims(model)
+    hidden = mla_tiny.reference.rms_norm(x, layer["post_norm"], dm["eps"])
+    want = mla_tiny.reference.moe_layer(layer["moe"], hidden, dm, "float32")
+    valid = jnp.ones(x.shape[:2], bool)
+    total, here = jnp.zeros_like(x), 0
+    for lo in range(0, 192, 12):
+        cfg = mla_tiny.config({**model, "n_routed_experts": 12,
+                               "expert_offset": lo})
+        cut = lambda name: layer["moe"][name][lo:lo + 12]
+        moe = {"post_norm": layer["post_norm"], **layer["moe"],
+               "gate": cut("gate"), "up": cut("up"), "down": cut("down")}
+        out, counters = hybrid.moe_block(moe, x, valid, cfg, shared=lo == 0)
+        total = total + (out - x)
+        here += int(counters[1])
+        assert int(counters[0]) == x.shape[0] * x.shape[1] * 8
+    assert here == x.shape[0] * x.shape[1] * 8   # every assignment, once
+    np.testing.assert_allclose(total, want, atol=TOL)
+
+
+def _mla_prefill_logits(model=None, **cfg_kw):
+    model = model or mla_tiny.MODEL
+    cfg = mla_tiny.config(model, **cfg_kw)
+    params = mla_tiny.weights.make_program_weights(mla_tiny.SEED,
+                                                   mla_tiny.MODEL, jnp.float32)
+    ids = np.random.default_rng(5).integers(0, 128, (1, 32)).astype(np.int32)
+    out = decode.prefill_prompt(params, jnp.asarray(ids),
+                                jnp.ones((1, 32), jnp.int32), cfg, 32)
+    return ids, np.asarray(out["logits"][0]), out
+
+
+def _mla_reference_logits(ids, model=None, alter=()):
+    top = mla_tiny.weights.make_top(mla_tiny.SEED, mla_tiny.MODEL, jnp.float32)
+    layer_fn = mla_tiny.weights.layer_fn(mla_tiny.SEED, mla_tiny.MODEL,
+                                         jnp.float32)
+    return np.asarray(mla_tiny.reference.logits_fn(
+        top, layer_fn, jnp.asarray(ids), model or mla_tiny.MODEL,
+        alter=alter)[0, -1])
+
+
+def test_the_model_of_one_kind_of_layer_is_the_reference():
+    ids, got, out = _mla_prefill_logits()
+    np.testing.assert_allclose(got, _mla_reference_logits(ids), atol=1e-4)
+    # 32 tokens x 4 experts x 4 expert layers; 32 * 33 / 2 pairs x 5 layers
+    assert int(out["counters"][0]) == 32 * 4 * 4
+    assert int(out["counters"][-1]) == 5 * 32 * 33 // 2
+    assert out["selection"] == () and set(out["cache"]) == {"latent"}
+
+
+MLA_ALTERATIONS = {
+    "yarn_factor": ({"rope_scaling": {**mla_tiny.MODEL["rope_scaling"],
+                                      "factor": 2}}, ()),
+    "yarn_softmax_scale": ({}, ("plain_scale",)),
+    "yarn_amplitude": ({"rope_scaling": {**mla_tiny.MODEL["rope_scaling"],
+                                         "mscale": 0.5}}, ()),
+    "rope_theta": ({"rope_theta": 1000}, ()),
+    "scaling_factor": ({"routed_scaling_factor": 1.0}, ()),
+    "held_range": ({"expert_offset": 5}, ()),
+}
+
+
+@pytest.mark.parametrize("what", sorted(MLA_ALTERATIONS))
+def test_every_term_of_the_one_kind_model_matters_under_the_seeded_draw(what):
+    changed, alter = MLA_ALTERATIONS[what]
+    ids, got, _ = _mla_prefill_logits()
+    altered = _mla_reference_logits(ids, {**mla_tiny.MODEL, **changed}, alter)
+    assert np.max(np.abs(got - altered)) > 100 * 1e-4, what

@@ -17,6 +17,7 @@ import pytest
 
 import hybrid_tiny
 import latent_tiny as tiny
+import mla_tiny
 from llama_pipeline_parallel_tpu import serve
 from llama_pipeline_parallel_tpu.models import family as families
 from llama_pipeline_parallel_tpu.models.latent_moe import decode as latent_decode
@@ -103,19 +104,17 @@ def _prefill(params, cfg, cache, slot, prompt, bucket, chunk):
     return out
 
 
-@pytest.mark.parametrize("chunk", [0, 4, 8, 16],
-                         ids=["whole", "chunk4", "chunk8", "chunk16"])
-def test_prefill_then_ticks_through_the_three_stores_are_the_reference(chunk):
-    """Four requests over two slots: a prompt longer than `index_topk` and
-    the window, one shorter than both and left-padded past whole chunks, one
-    admitted into the slot the first left (nothing of the last occupant's
-    ring or pages may be visible), admitted at different ticks. Contexts
-    pass `index_topk`, the window and the ring (which wraps more than once:
-    6 places, rows of up to 50). At every tick the logits of every decoding
-    row are the reference's full forward over that request's tokens so
-    far."""
-    cfg = tiny.config()
-    params, top, layer_fn = tiny.both_sides()
+def _serve_the_plan(family, chunk, counted):
+    """Four requests over two slots through `family`'s (a `*_tiny` module)
+    programs: a prompt longer than `index_topk` and the window, one shorter
+    than both and left-padded past whole chunks, one admitted into the slot
+    the first left (nothing of the last occupant's ring or pages may be
+    visible), admitted at different ticks; rows of up to 50 positions. At
+    every tick the logits of every decoding row are the reference's full
+    forward over that request's tokens so far, and the full layers' own
+    counters are `counted(rows)`."""
+    cfg = family.config()
+    params, top, layer_fn = family.both_sides()
     cache = _cache(cfg)
     tick = jax.jit(latent_decode.tick_logits, static_argnames=("cfg",))
     rng = np.random.default_rng(4)
@@ -147,9 +146,8 @@ def test_prefill_then_ticks_through_the_three_stores_are_the_reference(chunk):
             params, jnp.asarray(token), cache.pool,
             jnp.asarray(cache.page_table), jnp.asarray(pos),
             jnp.asarray(write), cache.kv_mask, jnp.asarray(active), cfg)
-        seen = sum(len(r["seq"]) for r in rows.values())
-        kept = sum(min(len(r["seq"]), 8) for r in rows.values())
-        assert counters.tolist()[5:] == [3 * seen, 3 * kept]
+        assert counters.tolist()[5:] == counted(
+            [len(r["seq"]) for r in rows.values()])
         for slot in list(rows):
             r = rows[slot]
             r["logits"].append(np.asarray(logits[slot]))
@@ -162,10 +160,21 @@ def test_prefill_then_ticks_through_the_three_stores_are_the_reference(chunk):
     assert len(done) == 4 and not rows
     for r in done:
         ids = jnp.asarray([r["seq"][:-1]])
-        want = tiny.reference.logits_fn(top, layer_fn, ids, tiny.MODEL)[0]
+        want = family.reference.logits_fn(top, layer_fn, ids, family.MODEL)[0]
         first = len(r["prompt"]) - 1
         got = np.stack(r["logits"])
         np.testing.assert_allclose(got, want[first:first + len(got)], atol=TOL)
+
+
+@pytest.mark.parametrize("chunk", [0, 4, 8, 16],
+                         ids=["whole", "chunk4", "chunk8", "chunk16"])
+def test_prefill_then_ticks_through_the_three_stores_are_the_reference(chunk):
+    """`_serve_the_plan` through latent pages, index pages and the ring:
+    contexts pass `index_topk`, the window and the ring (which wraps more
+    than once: 6 places, rows of up to 50); three full layers see every
+    context and select at most 8 of it."""
+    _serve_the_plan(tiny, chunk, lambda contexts: [
+        3 * sum(contexts), 3 * sum(min(c, 8) for c in contexts)])
 
 
 def test_a_chunk_of_nothing_but_pads_changes_nothing_a_query_can_see():
@@ -229,8 +238,8 @@ def test_the_engine_serves_the_family_in_chunks_with_its_counters_on_the_spans()
     ticks = [s for s in spans if s["name"] == "serve_decode_step"]
     units = [s for s in spans if s["name"] == "serve_prefill"]
     assert len(units) == 12
-    assert all(set(latent_decode.COUNTERS) <= set(s) for s in ticks + units)
-    total = {k: sum(s[k] for s in ticks) for k in latent_decode.COUNTERS}
+    assert all(set(latent_decode.counters(cfg)) <= set(s) for s in ticks + units)
+    total = {k: sum(s[k] for s in ticks) for k in latent_decode.counters(cfg)}
     decoded = sum(n - 1 for n in budgets)
     assert sum(s["tokens"] for s in ticks) == decoded
     # exact: every decoding token chooses 4 experts in each of 8 expert
@@ -242,7 +251,7 @@ def test_the_engine_serves_the_family_in_chunks_with_its_counters_on_the_spans()
     assert total["index_visible"] == 3 * sum(contexts)
     assert total["index_selected"] == 3 * sum(min(c, 8) for c in contexts)
     # a prompt's tokens, whatever the units they came in
-    prefilled = {k: sum(s[k] for s in units) for k in latent_decode.COUNTERS}
+    prefilled = {k: sum(s[k] for s in units) for k in latent_decode.counters(cfg)}
     assert prefilled["routed_total"] == sum(len(p) for p in prompts) * 4 * 8
     assert prefilled["index_visible"] == 3 * sum(
         t + 1 for p in prompts for t in range(len(p)))
@@ -368,7 +377,7 @@ def test_a_store_a_slot_and_chunked_prefill_are_separate_facts():
     fam = families.family_of(tiny.config())
     assert fam.recurrent and fam.paged_prefill_span is None
     assert fam.paged_prefill_chunk is latent_decode.paged_prefill_chunk
-    assert fam.counters == latent_decode.COUNTERS
+    assert fam.counters == latent_decode.counters(tiny.config())
     assert fam.counters[:5] == families.family_of(hybrid_tiny.config()).counters
     other = families.family_of(hybrid_tiny.config())
     assert other.recurrent and other.paged_prefill_chunk is None
@@ -421,6 +430,228 @@ def test_a_checkpoint_of_the_family_round_trips_into_the_serving_loader(tmp_path
         assert a.dtype == b.dtype          # bfloat16 stays bfloat16
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
+    engine = serve.ServeEngine(loaded, loaded_cfg, serve.ServeConfig(
+        max_slots=SLOTS, max_len=MAX_LEN, prompt_buckets=(8, 16),
+        page_size=PAGE, num_pages=PAGES, prefill_chunk_tokens=8))
+    handle = engine.submit(serve.ServeRequest(
+        input_ids=list(range(1, 12)),
+        gen=families.GenerationConfig(max_new_tokens=3)))
+    engine.drain()
+    assert len(handle.result()) == 3
+
+
+# -- dots3's programs compute what they computed before the family was generalised --
+
+# the parent's values (commit 2b9ffa3: `latent_tiny`'s model and seed, 32
+# tokens drawn from default_rng(5), chunks of 8 into pages of 4, then three
+# greedy ticks), pinned before the refactor of PR 32
+PINNED_CHUNK_LOGITS = [-0.414769, -0.44767556, 0.11468346, -0.24117672,
+                       0.3280143, 0.72549796, -0.528478, 0.61742216]
+PINNED_TICK_LOGITS = [
+    [-0.68526167, -0.19654357, 0.05581874, 0.5419101, -1.7161855, -1.9601107,
+     -0.23342179, 0.42097023],
+    [-0.36830336, 0.07497703, 0.22573265, -1.100463, 0.22639161, -0.9812339,
+     -0.9537863, 0.41808766],
+    [1.2205951, 0.2876654, 0.49328655, -0.28067613, -0.7199126, -0.4838161,
+     1.3023771, -0.29100752]]
+PINNED_TICK_COUNTERS = [[32, 15, 15, 8, 64, 99, 24], [32, 16, 16, 8, 64, 102, 24],
+                        [32, 16, 16, 8, 64, 105, 24]]
+
+
+def _one_row_tick(tick, params, cfg, cache, slot, token, write, pos):
+    token_a, write_a, pos_a, active = (np.zeros(SLOTS, np.int32)
+                                       for _ in range(4))
+    token_a[slot], write_a[slot], pos_a[slot], active[slot] = (
+        token, write, pos, 1)
+    cache.ensure_capacity(slot, write + 1)
+    logits, cache.pool, cache.kv_mask, counters, _ = tick(
+        params, jnp.asarray(token_a), cache.pool,
+        jnp.asarray(cache.page_table), jnp.asarray(pos_a),
+        jnp.asarray(write_a), cache.kv_mask, jnp.asarray(active), cfg)
+    return np.asarray(logits[slot]), counters.tolist()
+
+
+def test_dots3s_tiny_programs_give_the_logits_they_gave():
+    cfg = tiny.config()
+    params = tiny.weights.make_program_weights(tiny.SEED, tiny.MODEL, jnp.float32)
+    prompt = np.random.default_rng(5).integers(0, 128, 32).tolist()
+    cache = _cache(cfg)
+    assert cache.reserve(cache.demand_pages(32, 4))
+    slot = cache.acquire("pinned", cache.demand_pages(32, 4))
+    out = _prefill(params, cfg, cache, slot, prompt, 32, 8)
+    np.testing.assert_allclose(out["logits"][0, :8], PINNED_CHUNK_LOGITS,
+                               atol=2e-6, rtol=0)
+    assert out["counters"].tolist() == [256, 118, 49, 37, 64, 684, 192]
+    tick = jax.jit(latent_decode.tick_logits, static_argnames=("cfg",))
+    token = int(np.argmax(out["logits"][0]))
+    for t in range(3):
+        logits, counters = _one_row_tick(tick, params, cfg, cache, slot, token,
+                                         32 + t, 32 + t)
+        np.testing.assert_allclose(logits[:8], PINNED_TICK_LOGITS[t],
+                                   atol=2e-6, rtol=0)
+        assert counters == PINNED_TICK_COUNTERS[t]
+        token = int(np.argmax(logits))
+
+
+# -- one kind of layer (A.X-K1's shape) through the same stores and engine ----------
+
+
+
+def test_a_model_of_one_kind_of_layer_keeps_latent_pages_and_nothing_else():
+    cfg = mla_tiny.config()
+    cache = _cache(cfg)
+    # entries of 8 + 8 numbers, stored in rows of 16; no index leaf, no ring
+    assert set(cache.pool) == {"latent"}
+    assert cache.pool["latent"].shape == (5, PAGES + 1, PAGE, 16)
+    assert cache._page_leaves == ("latent",)
+    assert cache.recurrent_store_bytes == 0
+    assert cache.page_bytes() == 5 * PAGE * 16 * 4
+    assert pages.paged_pool_bytes(cfg, PAGES, PAGE) == cache.pool["latent"].nbytes
+    fam = families.family_of(cfg)
+    assert not fam.recurrent and fam.init_recurrent_store is None
+    assert fam.counters == latent_decode.counters(cfg)
+    assert fam.counters[-1] == "latent_visible"
+    assert fam.paged_prefill_chunk is latent_decode.paged_prefill_chunk
+    # the same family keeps a ring for the configuration that has windows
+    assert families.family_of(tiny.config()).recurrent
+    # what it cannot run is refused for what holds for THIS configuration
+    fam.check_serve_config("fp", 8, False)
+    with pytest.raises(families.UnsupportedForFamily,
+                       match="no span prefill") as err:
+        fam.check_serve_config("fp", 8, True)
+    assert "one row a slot" not in str(err.value)
+    with pytest.raises(families.UnsupportedForFamily, match="kv_quant: int8"):
+        fam.check_serve_config("int8", 8, False)
+
+
+@pytest.mark.parametrize("chunk", [0, 4, 8, 16],
+                         ids=["whole", "chunk4", "chunk8", "chunk16"])
+def test_prefill_then_ticks_of_one_kind_of_layer_are_the_reference(chunk):
+    """`_serve_the_plan` for the model without indexer, window or gate, under
+    YaRN (rows pass the original context of 16, so every YaRN regime is
+    used): five layers each read every position of every context."""
+    _serve_the_plan(mla_tiny, chunk,
+                    lambda contexts: [5 * sum(contexts)])
+
+
+def test_the_engine_serves_one_kind_of_layer_in_chunks_and_counts_what_it_read():
+    """Buckets of 8 (whole), 16 and 32 (chunks of 8 between decode ticks)
+    through `ServeEngine`: every served token is the reference's first
+    choice; `latent_visible` rides the tick's and every prefill unit's span
+    beside the expert layers' counts, and is the host's own count exactly."""
+    cfg = mla_tiny.config()
+    params, top, layer_fn = mla_tiny.both_sides()
+    scfg = serve.ServeConfig(max_slots=SLOTS, max_len=MAX_LEN,
+                             prompt_buckets=(8, 16, 32), page_size=PAGE,
+                             num_pages=PAGES, decode_span_every=4,
+                             prefill_chunk_tokens=8)
+    engine = serve.ServeEngine(params, cfg, scfg)
+    spans = []
+    listener = lambda rec: spans.append(dict(rec))
+    trace.recorder().add_listener(listener)
+    try:
+        rng = np.random.default_rng(1)
+        prompts = [rng.integers(0, 128, n).tolist() for n in (5, 27, 3, 14, 30)]
+        budgets = [9, 17, 6, 12, 5]
+        handles = []
+        for i, (prompt, n) in enumerate(zip(prompts, budgets)):
+            handles.append(engine.submit(serve.ServeRequest(
+                input_ids=prompt, seed=i,
+                gen=families.GenerationConfig(max_new_tokens=n))))
+            engine.step()
+        engine.drain()
+        engine._flush_decode_span()
+    finally:
+        trace.recorder().remove_listener(listener)
+    served = [h.result() for h in handles]
+    assert [len(s) for s in served] == budgets
+    gaps = mla_tiny.reference.served_token_gaps(
+        top, layer_fn, prompts, served, mla_tiny.MODEL, MAX_LEN)
+    assert max(max(g) for g in gaps) <= TOL
+    assert engine.prefill_chunks_total == 1 + 4 + 1 + 2 + 4
+
+    ticks = [s for s in spans if s["name"] == "serve_decode_step"]
+    units = [s for s in spans if s["name"] == "serve_prefill"]
+    names = latent_decode.counters(cfg)
+    assert len(units) == 12
+    assert all(set(names) <= set(s) for s in ticks + units)
+    assert not any("index_visible" in s for s in ticks + units)
+    decoded = sum(n - 1 for n in budgets)
+    assert sum(s["tokens"] for s in ticks) == decoded
+    assert sum(s["routed_total"] for s in ticks) == decoded * 4 * 4
+    contexts = [len(p) + j for p, n in zip(prompts, budgets)
+                for j in range(1, n)]
+    assert sum(s["latent_visible"] for s in ticks) == 5 * sum(contexts)
+    # a prompt's tokens, whatever the units they came in
+    assert sum(s["routed_total"] for s in units) == \
+        sum(len(p) for p in prompts) * 4 * 4
+    assert sum(s["latent_visible"] for s in units) == 5 * sum(
+        t + 1 for p in prompts for t in range(len(p)))
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_the_latent_pages_of_one_kind_of_layer_ride_the_layer_loops_carry(program):
+    """The model scans over its LAYERS (a period of one): the latent pages
+    are that loop's carry, never its `xs` / `ys`, and the outputs alias the
+    donated pool."""
+    cfg = mla_tiny.config()
+    fn, make = PROGRAMS[program]
+    pool, args = make(cfg)
+    assert set(pool) == {"latent"}
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, cfg))(*args).jaxpr
+    leaf = (pool["latent"].shape, pool["latent"].dtype)
+    loops = [e for e in _equations(jaxpr) if e.primitive.name == "scan"
+             and e.params["length"] == cfg.periods == 4
+             and leaf[0] in {v.aval.shape for v in e.invars}]
+    assert len(loops) == 1
+    loop = loops[0]
+    n_consts, n_carry = loop.params["num_consts"], loop.params["num_carry"]
+    assert leaf in [(v.aval.shape, v.aval.dtype)
+                    for v in loop.invars[n_consts:n_consts + n_carry]]
+    assert leaf[0] not in {v.aval.shape for v in loop.invars[n_consts + n_carry:]}
+    assert leaf[0] not in {v.aval.shape for v in loop.outvars[n_carry:]}
+    pool, args = make(cfg, pages=2048)
+    analysis = fn.lower(*args, cfg).compile().memory_analysis()
+    if analysis is not None:
+        assert analysis.alias_size_in_bytes >= pool["latent"].nbytes
+
+
+def test_the_dense_tick_gathers_no_row_and_sorts_nothing():
+    """No gather of the slots' latent rows, no index leaf, no `top_k` in the
+    tick of a model without an indexer: the kernel walks the page table."""
+    cfg = mla_tiny.config()
+    pool, args = _tick_args(cfg)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: latent_decode.paged_decode_step(*a, cfg))(*args).jaxpr
+    eqns = list(_equations(jaxpr))
+    gathers = [tuple(e.outvars[0].aval.shape) for e in eqns
+               if e.primitive.name == "gather"]
+    assert (SLOTS, MAX_LEN // PAGE, PAGE, 16) not in gathers
+    # nothing ranks a row's MAX_LEN places (the router's top-k over 16
+    # experts and the sampler's sort over the vocabulary are not that)
+    assert not [e for e in eqns if e.primitive.name in ("top_k", "sort")
+                and e.invars[0].aval.shape[-1] == MAX_LEN]
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels == ["paged_latent_decode_attn"] * 2   # layer 0, the loop's
+
+
+def test_a_checkpoint_of_one_kind_of_layer_round_trips_with_its_yarn_numbers(tmp_path):
+    """`meta.json` hands the period and YaRN's pairs back as lists: the
+    loaded configuration is the saved one, and the loader's tree is served."""
+    from llama_pipeline_parallel_tpu.ckpt.checkpoint import (
+        CheckpointManager,
+        load_module_checkpoint,
+    )
+
+    cfg = mla_tiny.config()
+    params = latent.init_params(jax.random.PRNGKey(5), cfg)
+    CheckpointManager(str(tmp_path)).save_module(2, params, cfg)
+    loaded, loaded_cfg, _, step = load_module_checkpoint(str(tmp_path))
+    assert step == 2 and loaded_cfg == cfg and hash(loaded_cfg) == hash(cfg)
+    assert loaded_cfg.period == ("full",)
+    assert dict(loaded_cfg.rope_scaling)["mscale_all_dim"] == 0.5
+    assert jax.tree.structure(loaded) == jax.tree.structure(params)
     engine = serve.ServeEngine(loaded, loaded_cfg, serve.ServeConfig(
         max_slots=SLOTS, max_len=MAX_LEN, prompt_buckets=(8, 16),
         page_size=PAGE, num_pages=PAGES, prefill_chunk_tokens=8))

@@ -30,7 +30,12 @@ from llama_pipeline_parallel_tpu.models.hybrid_moe.config import HybridMoEConfig
 from llama_pipeline_parallel_tpu.models.llama import decode
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
-from llama_pipeline_parallel_tpu.ops import paged_attention, sparse_latent_attention
+from llama_pipeline_parallel_tpu.ops import (
+    latent_prefill_attention,
+    paged_attention,
+    paged_latent_attention,
+    sparse_latent_attention,
+)
 from llama_pipeline_parallel_tpu.ops.attention import attention
 
 L, PAGES, PAGE, KV_H, HD, PMAX = 2, 9, 8, 2, 128, 4
@@ -191,8 +196,9 @@ def mosaic(monkeypatch):
     """The kernel as the chip runs it: `interpret_mode()` asks the backend,
     which is the CPU here whatever the program is compiled for."""
     monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
-    monkeypatch.setattr(sparse_latent_attention, "interpret_mode",
-                        lambda: False)
+    for module in (sparse_latent_attention, paged_latent_attention,
+                   latent_prefill_attention):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without one
     before = jax.config.jax_enable_compilation_cache
@@ -374,3 +380,112 @@ def test_a_latent_program_compiled_for_the_chip_keeps_its_stores_in_place(
     assert analysis.alias_size_in_bytes >= nbytes(pool)
     assert analysis.temp_size_in_bytes < nbytes(pool["latent"]) // 4, analysis
     assert "sparse_latent_attn" in compiled.as_text()
+
+
+# -- plain MLA that reads the whole cache, compiled for the same chip ------------
+
+def test_mosaic_compiles_the_dense_latent_tick_kernel_at_the_longdoc_cells_shapes(
+        one_chip, mosaic):
+    """32 rows of 64 heads against pages of 64 entries of 640 (576 stored in
+    whole tiles), 288 logical pages a row, the pool of five layers whole:
+    Mosaic takes the blocks, twelve pages a step, and nothing pool-sized is
+    made in front of the kernel."""
+    slots, pmax, page, pages, layers = 32, 288, 64, 9216, 5
+    assert paged_latent_attention._pages_per_step(pmax, page * 640 * 2) == 12
+    args = _described(
+        (jax.ShapeDtypeStruct((slots, 64, 640), jnp.bfloat16),
+         jax.ShapeDtypeStruct((layers, pages + 1, page, 640), jnp.bfloat16),
+         jax.ShapeDtypeStruct((), jnp.int32),
+         jax.ShapeDtypeStruct((slots, pmax), jnp.int32),
+         jax.ShapeDtypeStruct((slots,), jnp.int32),
+         jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)), one_chip)
+    compiled = jax.jit(
+        lambda q, pool, layer, table, live, mask:
+        paged_latent_attention.paged_latent_decode_attention(
+            q, pool, layer, table, live, mask, 0.13, 512)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "paged_latent_decode_attn" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("keys", [2048, 18432], ids=["bucket", "row-end"])
+def test_mosaic_compiles_the_dense_latent_prefill_kernel_at_the_longdoc_cells_shapes(
+        one_chip, mosaic, keys):
+    """A 2048-token unit of 64 heads (128 + 64 for scores, 128 for values)
+    against its own bucket and against a whole 18,432-place row: the scores
+    stay in the kernel (nothing of [64, 2048, keys] float32 exists)."""
+    b, H, T = 1, 64, 2048
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    args = _described(
+        (shape(b, H, T, 128), shape(b, H, T, 64), shape(b, H, keys, 128),
+         shape(b, keys, 64), shape(b, H, keys, 128),
+         jax.ShapeDtypeStruct((b, keys), jnp.int32),
+         jax.ShapeDtypeStruct((), jnp.int32)), one_chip)
+    compiled = jax.jit(
+        lambda qn, qr, kn, kr, v, valid, start:
+        latent_prefill_attention.latent_prefill_attention(
+            qn, qr, kn, kr, v, valid, start, 0.13)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "latent_prefill_attn" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_a_program_of_one_kind_of_layer_compiled_for_the_chip_keeps_its_pages_in_place(
+        one_chip, mosaic, program):
+    """A.X-K1's shape at the published entry width (576 stored as 640) and
+    small everything else, pages many times the weights: the outputs are the
+    donated pool's buffers, nothing as large as the latent pages is made
+    beside them (no gathered rows in the tick), and both kernels are in the
+    programs."""
+    from llama_pipeline_parallel_tpu.models.latent_moe import decode as latent_decode
+    from llama_pipeline_parallel_tpu.models.latent_moe import model as latent
+    from llama_pipeline_parallel_tpu.models.latent_moe.config import (
+        LatentMoEConfig,
+    )
+
+    slots, pmax, page = 4, 16, 64
+    cfg = LatentMoEConfig(
+        vocab_size=256, hidden_size=256, num_hidden_layers=5,
+        period=("full",), intermediate_size=256, num_attention_heads=8,
+        q_lora_rank=128, index_topk=0, attention_gate=False,
+        lora_rescale=False, rope_theta=1e4, rope_scaling=tuple(sorted({
+            "beta_fast": 32.0, "beta_slow": 1.0, "factor": 32.0,
+            "mscale": 1.0, "mscale_all_dim": 1.0,
+            "original_max_position_embeddings": 4096.0}.items())),
+        router_experts=16, experts_held=8, num_experts_per_tok=4,
+        moe_intermediate_size=64, shared_intermediate_size=64)
+    params = jax.eval_shape(
+        lambda: latent.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(
+        lambda: latent_decode.init_page_pool(cfg, 4096, page))
+    assert set(pool) == {"latent"} and pool["latent"].shape[-1] == 640
+    z = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    f = jax.ShapeDtypeStruct((slots,), jnp.float32)
+    mask = jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    if program == "tick":
+        args = _described(
+            (params, z, pool, jax.ShapeDtypeStruct((slots, pmax), jnp.int32),
+             z, z, mask, z, jax.ShapeDtypeStruct((slots, 2), jnp.uint32), f,
+             z, f), one_chip)
+        compiled = latent_decode.paged_decode_step.lower(*args, cfg).compile()
+        kernel = "paged_latent_decode_attn"
+    else:
+        ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+        args = _described(
+            (params, ids, ids, ids, pool,
+             jax.ShapeDtypeStruct((pmax,), jnp.int32), scalar, mask, scalar),
+            one_chip)
+        compiled = latent_decode.paged_prefill_chunk.lower(*args, cfg).compile()
+        kernel = "latent_prefill_attn"
+    analysis = compiled.memory_analysis()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    assert nbytes(pool) > 5 * nbytes(params)
+    assert analysis.alias_size_in_bytes >= nbytes(pool)
+    assert analysis.temp_size_in_bytes < nbytes(pool) // 4, analysis
+    assert kernel in compiled.as_text()
