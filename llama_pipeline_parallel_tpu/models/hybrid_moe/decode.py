@@ -14,7 +14,12 @@ whole at admission and updated in place every tick; nothing is ever freed.
 The layer loop scans over PERIODS, a period's layers unrolled in the body.
 Both stores ride its carry and are touched only by indexed reads and writes
 (never the scan's `xs` / `ys`, which cannot alias a donated argument:
-models/llama/decode.py "How the pool is walked").
+models/llama/decode.py "How the pool is walked"). A period's weights ride
+`xs`, where a plain matmul reads its slice of a stacked leaf in place, all
+but the routed experts' `gate` / `up` / `down`: the grouped product would be
+handed a copy of a slice, so the body closes over those leaves whole and
+`moe_block` takes the stack and the period's place in it
+(`model.split_experts`, models/hybrid_moe/model.py).
 
 What a model with recurrent layers cannot do yet is refused by name where the
 engine is built (`models/family.py`): a prefix cache, chunked and span
@@ -67,9 +72,12 @@ def _walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
     """Run every layer with both stores in the carry. `softmax_layer(layer,
     h, stores, period) -> (h, stores)` and `kda_layer(layer, h, stores,
     kda_index) -> (h, stores)` are the caller's mixers; each is followed by
-    its expert half. Returns the hidden state, the stores and the expert
-    layers' counters summed over layers (int32[5], `COUNTERS`)."""
+    its expert half, which takes the routed experts of every period whole
+    and the period's place among them. Returns the hidden state, the stores
+    and the expert layers' counters summed over layers (int32[5],
+    `COUNTERS`)."""
     n = cfg.attn_period
+    periods, experts = hybrid.split_experts(params["periods"])
 
     def body(carry, xs):
         h, stores, counters = carry
@@ -80,14 +88,15 @@ def _walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
             else:
                 h, stores = kda_layer(period["kda"][j - 1], h, stores,
                                       p * (n - 1) + j - 1)
-            h, counted = hybrid.moe_block(period["moe"][j], h, valid, cfg)
+            h, counted = hybrid.moe_block(period["moe"][j], experts[j], p, h,
+                                          valid, cfg)
             counters = counters + counted
         return (h, stores, counters), None
 
     zero = jnp.zeros((len(COUNTERS),), jnp.int32)
     (x, stores, counters), _ = jax.lax.scan(
         body, (x, stores, zero),
-        (params["periods"], jnp.arange(cfg.periods)))
+        (periods, jnp.arange(cfg.periods)))
     return x, stores, counters
 
 

@@ -4,14 +4,23 @@ layer that is told which experts it holds.
 
 Parameter tree (`init_params`; leaves stacked by period so that the serving
 programs scan over periods with a period's layers unrolled; the layers of a
-period are separate leaves, never one stacked array to be sliced, because a
-slice of the expert weights is a copy of them: the grouped product takes
-whole buffers):
+period are separate leaves):
 
     embed.embedding [V, d]   norm [d]   lm_head [d, V]
     periods.attn.*    [P, ...]     the softmax layer of each period
     periods.kda[j].*  [P, ...]     its j-th KDA layer (period - 1 of them)
     periods.moe[j].*  [P, ...]     the expert half of its j-th layer
+
+The grouped product (`jax.lax.ragged_dot`) is a kernel of its own that takes
+whole buffers: handed a slice of a stacked leaf, XLA:TPU first copies the
+slice out, a layer's experts read and written for every product. So no
+slice of the routed experts' `gate` / `up` / `down` is ever taken, within a
+period (separate leaves) or across periods: the layer loops keep those three
+leaves of every `periods.moe[j]` out of the scan's `xs` (`split_experts`)
+and close over them whole, `[P, held, d, f]`; `moe_block` gets the stack and
+the period's place in it and gives the other periods' experts groups of no
+rows. Everything else of a period (norms, router, bias, the shared expert,
+the mixers) rides `xs`: a plain matmul reads its slice of a stack in place.
 
 KDA (Kimi Delta Attention: a gated delta rule with a decay per channel), per
 head, `c(.)` a causal depthwise convolution then SiLU:
@@ -47,6 +56,7 @@ KDA_SUB = 16              # sub-block inside which decays are taken pairwise
 INIT_STD = 0.02
 COUNTERS = ("routed_total", "routed_here", "experts_hit", "expert_load_max",
             "experts_held")
+EXPERT_LEAVES = ("gate", "up", "down")   # the grouped product's operands
 
 
 # -- parameters ---------------------------------------------------------------
@@ -298,18 +308,46 @@ def route(moe: Params, hidden: jnp.ndarray, cfg: HybridMoEConfig):
         return chosen, weights * cfg.routed_scaling_factor
 
 
-def moe_block(moe: Params, x: jnp.ndarray, valid: jnp.ndarray,
-              cfg: HybridMoEConfig, shared: bool = True):
-    """Post-norm expert half of a layer, with the residual. x: [b, s, d];
-    valid: [b, s] bool (positions that are not valid are routed nowhere and
-    counted nowhere). Routes over all `router_experts`, computes the terms
-    of the experts held here ([expert_offset, expert_offset + held)) for the
-    tokens routed to them, and the shared expert; the absent experts' terms
-    are left out. Dropless: the sorted rows are sized for every assignment
-    landing here, and `ragged_dot` multiplies each run of rows by its own
-    expert. Returns (x + y, counters int32[5] in the order of COUNTERS)."""
+def split_experts(periods: Params) -> tuple[Params, list]:
+    """`params["periods"]` as the layer loops take it: (what rides the
+    scan's `xs`: every leaf but the routed experts'; the routed experts'
+    `gate` / `up` / `down` of each `moe[j]`, stacked over periods, for the
+    loop's body to close over whole)."""
+    experts = [{name: moe[name] for name in EXPERT_LEAVES}
+               for moe in periods["moe"]]
+    rest = [{name: leaf for name, leaf in moe.items()
+             if name not in EXPERT_LEAVES} for moe in periods["moe"]]
+    return {**periods, "moe": rest}, experts
+
+
+def moe_block(moe: Params, experts: Params, place, x: jnp.ndarray,
+              valid: jnp.ndarray, cfg: HybridMoEConfig, shared: bool = True):
+    """Post-norm expert half of a layer, with the residual. moe: the layer's
+    own norm, router, bias and shared expert; experts: the routed experts'
+    `gate` / `up` [P, held, d, f] and `down` [P, held, f, d] of EVERY period
+    as they are stored, and `place` (int32 scalar, may be traced) this
+    layer's period among the P: the grouped product takes the stack whole,
+    seen as P * held experts of which only the `held` at `place` get rows
+    (module docstring; a single layer is a stack of one at place 0). x:
+    [b, s, d]; valid: [b, s] bool (positions that are not valid are routed
+    nowhere and counted nowhere). Routes over all `router_experts`, computes
+    the terms of the experts held here ([expert_offset, expert_offset +
+    held)) for the tokens routed to them, and the shared expert; the absent
+    experts' terms are left out. Dropless: the sorted rows are sized for
+    every assignment landing here, and `ragged_dot` multiplies each run of
+    rows by its own expert. The stack is multiplied in the dtype it is
+    stored in, which has to be `cfg.dtype`: a conversion here would convert
+    P layers' experts at every layer, so a tree stored otherwise is refused.
+    Returns (x + y, counters int32[5] in the order of COUNTERS)."""
     b, s, d = x.shape
     T, k, held, dt = b * s, cfg.num_experts_per_tok, cfg.held, cfg.dtype
+    for name in EXPERT_LEAVES:
+        if experts[name].dtype != dt:
+            raise ValueError(
+                f"the routed experts' {name!r} is stored {experts[name].dtype} "
+                f"and cfg.dtype is {jnp.dtype(dt)}: the grouped product takes "
+                f"the stack of layers as stored; convert the tree once first")
+    stack = experts["gate"].shape[0] * held   # experts the product sees
     hidden = rms_norm(x, moe["post_norm"], cfg.rms_norm_eps).reshape(T, d)
     chosen, weights = route(moe, hidden, cfg)
     ok = valid.reshape(T, 1)
@@ -322,11 +360,16 @@ def moe_block(moe: Params, x: jnp.ndarray, valid: jnp.ndarray,
         sorted_group = group[order]
         rows = order // k                                    # token of a row
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        # the other periods' experts get no rows: the sorted rows meet the
+        # experts at [place * held, (place + 1) * held) of the stack
+        stack_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((stack,), jnp.int32), sizes, (place * held,))
         taken = hidden[rows]                                 # [T * k, d]
 
     with jax.named_scope(trace.MOE_EXPERTS):
         ragged = lambda lhs, name: jax.lax.ragged_dot(
-            lhs, cast_weight(moe[name], dt), sizes)
+            lhs, experts[name].reshape(stack, *experts[name].shape[2:]),
+            stack_sizes)
         act = jax.nn.silu(ragged(taken, "gate")) * ragged(taken, "up")
         out = ragged(act, "down")                            # [T * k, d]
 

@@ -27,7 +27,11 @@ Layer 0 (full, dense feed-forward) runs before a scan over PERIODS whose body
 unrolls `cfg.period`: a full layer and its sliding layers (a model of one
 kind of layer scans over its layers). The stores ride its carry and are
 touched only by indexed reads and writes (never the scan's `xs` / `ys`:
-models/llama/decode.py "How the pool is walked").
+models/llama/decode.py "How the pool is walked"). A period's weights ride
+`xs`, all but the routed experts' `gate` / `up` / `down`: the body closes
+over those leaves whole, `[P, held, d, f]`, and `moe_block` takes the stack
+and the period's place in it, because a slice of them in front of the
+grouped product is a copy of a layer's experts (models/hybrid_moe/model.py).
 
 Positions. A slot's LOGICAL row is its left-padded prompt bucket followed by
 what it decoded, as the mask row `kv_mask[slot]` describes it; pages, ring
@@ -126,10 +130,12 @@ def _walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
     in the ring store) are the caller's mixers; a full layer's `selection`
     is (chosen, ok) of each row's last query under an indexer, () without.
     Layer 0 is followed by the dense feed-forward, every other layer by its
-    expert half. Returns the hidden state, the stores, the counters summed
-    over layers (`counters(cfg)`) and the selections stacked over the full
-    layers."""
+    expert half, which takes the routed experts of every period whole and
+    the period's place among them. Returns the hidden state, the stores, the
+    counters summed over layers (`counters(cfg)`) and the selections stacked
+    over the full layers."""
     n = len(cfg.period)
+    periods, experts = hybrid.split_experts(params["periods"])
 
     h, stores, indexed, first_sel = full_layer(params["first"]["attn"], x,
                                                stores, 0)
@@ -144,14 +150,15 @@ def _walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
             if j:
                 h, stores = window_layer(period["win"][j - 1], h, stores,
                                          p * (n - 1) + j - 1)
-            h, counted = hybrid.moe_block(period["moe"][j], h, valid, cfg)
+            h, counted = hybrid.moe_block(period["moe"][j], experts[j], p, h,
+                                          valid, cfg)
             routed = routed + counted
         return (h, stores, routed, indexed), sel
 
     zero = jnp.zeros((_N_MOE,), jnp.int32)
     (h, stores, routed, indexed), sels = jax.lax.scan(
         body, (h, stores, zero, indexed),
-        (params["periods"], jnp.arange(cfg.periods)))
+        (periods, jnp.arange(cfg.periods)))
     selection = jax.tree.map(lambda a, rest: jnp.concatenate([a[None], rest]),
                              first_sel, sels)
     return h, stores, jnp.concatenate([routed, indexed]), selection

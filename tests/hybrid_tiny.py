@@ -6,6 +6,7 @@ import os
 import sys
 
 import jax.numpy as jnp
+import numpy as np
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
@@ -13,6 +14,7 @@ if REPO not in sys.path:
 
 from benchmark import hybrid_moe_weights as weights  # noqa: E402
 from benchmark.reference import hybrid_moe_decoder as reference  # noqa: E402
+from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid  # noqa: E402
 from llama_pipeline_parallel_tpu.models.hybrid_moe.config import (  # noqa: E402
     HybridMoEConfig,
 )
@@ -46,3 +48,42 @@ def both_sides(model=MODEL, seed=SEED):
     top = weights.make_top(seed, model, jnp.float32)
     return (weights.make_program_weights(seed, model, jnp.float32), top,
             weights.layer_fn(seed, model, jnp.float32))
+
+
+# -- the expert layer, which every tiny model of the three families shares -------
+
+def stack_of_one(moe):
+    """One layer's own routed experts as `moe_block` takes them: a stack of
+    one period."""
+    return {name: moe[name][None] for name in hybrid.EXPERT_LEAVES}
+
+
+def moe_block_alone(moe, x, valid, cfg, **kw):
+    """`moe_block` on one layer's own leaves, at place 0 of a stack of one."""
+    return hybrid.moe_block(moe, stack_of_one(moe), 0, x, valid, cfg, **kw)
+
+
+def biased(moe, case):
+    """A layer's router (16 wide, experts [4, 12) held) under a selection
+    bias that makes the case: `idle` keeps every row off held expert 6, `one`
+    sends every row to held expert 5 and to three experts that are not
+    held; any other case leaves the seeded router alone."""
+    bias = np.zeros(16, np.float32)
+    if case == "idle":
+        bias[6] = -10.0
+    elif case == "one":
+        bias[[5, 0, 1, 2]] = 10.0
+    return {**moe, "router_bias": jnp.asarray(bias)}
+
+
+def expert_operands(eqns, cfg):
+    """Of a traced program's equations: (the leading size of every grouped
+    product's right operand, the equations whose result is ONE layer's
+    experts, `[held, d, f]` or `[held, f, d]`)."""
+    d, f = cfg.hidden_size, cfg.moe_intermediate_size
+    alone = {(cfg.held, d, f), (cfg.held, f, d)}
+    products = [e.invars[1].aval.shape[0] for e in eqns
+                if e.primitive.name == "ragged_dot_general"]
+    sliced = [e for e in eqns
+              if any(tuple(v.aval.shape) in alone for v in e.outvars)]
+    return products, sliced

@@ -6,6 +6,7 @@ a sorted grouped product against a loop over experts), so 1e-4 absolute on
 values of order 1 is loose by two orders of magnitude; an alteration of any
 term moves the logits by more than 1e-2."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -129,7 +130,8 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
         cfg = tiny.config({**model, "n_routed_experts": 4, "router_experts": 16,
                            "expert_offset": lo})
         moe = {"post_norm": layer["post_norm"], **_share(layer["moe"], lo, 4)}
-        out, counters = hybrid.moe_block(moe, x, valid, cfg, shared=lo == 0)
+        out, counters = tiny.moe_block_alone(moe, x, valid, cfg,
+                                             shared=lo == 0)
         total = total + (out - x)
         here += int(counters[1])
         assert int(counters[0]) == x.shape[0] * x.shape[1] * 4
@@ -150,7 +152,7 @@ def test_every_token_on_one_held_expert_loses_none():
     hidden = tiny.reference.rms_norm(x, layer["post_norm"], dm["eps"])
     want = tiny.reference.moe_layer(moe, hidden, dm, "float32")
     cfg = tiny.config(model)
-    out, counters = hybrid.moe_block(
+    out, counters = tiny.moe_block_alone(
         {"post_norm": layer["post_norm"], **moe}, x,
         jnp.ones(x.shape[:2], bool), cfg)
     tokens = x.shape[0] * x.shape[1]
@@ -162,10 +164,59 @@ def test_positions_that_are_not_valid_are_routed_nowhere():
     model, layer, x = _uncut_moe()
     cfg = tiny.config(model)
     valid = jnp.ones(x.shape[:2], bool).at[:, :3].set(False)
-    _, counters = hybrid.moe_block(
+    _, counters = tiny.moe_block_alone(
         {"post_norm": layer["post_norm"], **layer["moe"]}, x, valid, cfg)
     live = int(valid.sum())
     assert counters[0] == counters[1] == live * 4
+
+
+@pytest.mark.parametrize("case", ["seeded", "idle", "one"])
+@pytest.mark.parametrize("place", [0, 1, 2])
+def test_a_layer_in_a_stack_of_three_is_the_layer_alone(place, case):
+    """The grouped product takes the stack of every period's experts whole
+    and gives the other periods' experts no rows: output and all five
+    counters are, bit for bit, those of the layer's own leaves as a stack of
+    one. Among the cases a held expert that gets no row and every row on one
+    expert; two positions are not valid."""
+    cfg = tiny.config()
+    layers = [tiny.weights.make_layer(tiny.SEED, index, tiny.MODEL, jnp.float32)
+              for index in (1, 2, 3)]
+    stack = {name: jnp.stack([layer["moe"][name] for layer in layers])
+             for name in hybrid.EXPERT_LEAVES}
+    assert stack["gate"].shape == (3, cfg.held, 32, 16)
+    layer = layers[place]
+    moe = tiny.biased({"post_norm": layer["post_norm"], **layer["moe"]}, case)
+    x = jnp.asarray(np.random.default_rng(place).normal(size=(2, 12, 32)),
+                    jnp.float32)
+    valid = jnp.ones(x.shape[:2], bool).at[1, :2].set(False)
+    block = jax.jit(hybrid.moe_block, static_argnames=("cfg",))   # place traced
+    want, counted = block(moe, tiny.stack_of_one(moe), jnp.int32(0), x, valid,
+                          cfg=cfg)
+    got, counters = block(moe, stack, jnp.int32(place), x, valid, cfg=cfg)
+    assert counters.tolist() == counted.tolist()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    live = int(valid.sum())
+    assert counters[0] == live * 4 and counters[4] == cfg.held
+    if case == "idle":
+        assert counters[2] < cfg.held
+    if case == "one":
+        assert counters.tolist() == [live * 4, live, 1, live, cfg.held]
+    # and the neighbours' experts matter to nothing
+    other = jax.tree.map(lambda a: a.at[(place + 1) % 3].set(7.0), stack)
+    again, _ = block(moe, other, jnp.int32(place), x, valid, cfg=cfg)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(want))
+
+
+def test_experts_stored_in_another_dtype_than_the_programs_are_refused():
+    """A conversion inside the layer would convert every period's experts at
+    every layer: a tree stored otherwise is refused by name."""
+    model, layer, x = _uncut_moe()
+    moe = {"post_norm": layer["post_norm"], **layer["moe"]}
+    stored = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                          tiny.stack_of_one(moe))
+    with pytest.raises(ValueError, match="stored bfloat16"):
+        hybrid.moe_block(moe, stored, 0, x, jnp.ones(x.shape[:2], bool),
+                         tiny.config(model))
 
 
 # -- the whole block ------------------------------------------------------------

@@ -283,6 +283,30 @@ def test_both_stores_ride_the_period_loops_carry():
         assert leaf.shape not in scanned and leaf.shape not in stacked, name
 
 
+@pytest.mark.parametrize("program", ["tick", "prefill"])
+def test_the_grouped_products_take_the_stack_of_periods_whole(program):
+    """The alarm for the slice coming back (on the chip a slice of the
+    stacked experts in front of `ragged_dot` is a copy of a layer's experts,
+    every product: PERF.md, PR 33): in both programs of the family, every
+    grouped product's right operand leads with periods x held experts, and
+    no equation inside or outside the loop over periods makes an array of
+    one layer's expert shape."""
+    cfg = tiny.config()
+    assert cfg.periods == 2
+    params, _, args = _tick_args(cfg)
+    if program == "tick":
+        jaxpr = jax.make_jaxpr(
+            lambda *a: hybrid_decode.paged_decode_step(*a, cfg))(*args).jaxpr
+    else:
+        ids = jnp.zeros((1, 16), jnp.int32)
+        jaxpr = jax.make_jaxpr(lambda *a: hybrid_decode.prefill_prompt(
+            *a, cfg, 16))(params, ids, ids).jaxpr
+    eqns = list(_equations(jaxpr))
+    products, sliced = tiny.expert_operands(eqns, cfg)
+    assert products == [cfg.periods * cfg.held] * 3 * cfg.attn_period
+    assert not sliced, sliced
+
+
 def test_the_ticks_temporaries_are_smaller_than_either_store():
     """With both stores large the tick keeps them in place: the outputs
     alias the donated stores, and nothing as large as a pool array is made

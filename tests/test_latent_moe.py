@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import hybrid_tiny
 import latent_tiny as tiny
 import mla_tiny
 from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid
@@ -158,12 +159,48 @@ def test_the_eight_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
         cut = lambda name: layer["moe"][name][lo:lo + 2]
         moe = {"post_norm": layer["post_norm"], **layer["moe"],
                "gate": cut("gate"), "up": cut("up"), "down": cut("down")}
-        out, counters = hybrid.moe_block(moe, x, valid, cfg, shared=lo == 0)
+        out, counters = hybrid_tiny.moe_block_alone(moe, x, valid, cfg,
+                                                    shared=lo == 0)
         total = total + (out - x)
         here += int(counters[1])
         assert int(counters[0]) == x.shape[0] * x.shape[1] * 4
     assert here == x.shape[0] * x.shape[1] * 4   # every assignment, once
     np.testing.assert_allclose(total, want, atol=TOL)
+
+
+@pytest.mark.parametrize("case", ["seeded", "idle", "one"])
+@pytest.mark.parametrize("place", [0, 1, 2])
+@pytest.mark.parametrize("family", ["dots3", "a.x-k1"])
+def test_a_layer_in_a_stack_of_three_is_the_layer_alone(family, place, case):
+    """At both tiny configurations of this family (`routed_scaling_factor` 1
+    and 2.5): `moe_block` on the stack of three periods' experts at `place`
+    is, bit for bit in output and in all five counters, `moe_block` on that
+    layer's own leaves as a stack of one. `idle`: held expert 6 gets no row;
+    `one`: every row on held expert 5 (and three experts that are not held);
+    two positions are not valid."""
+    mod = {"dots3": tiny, "a.x-k1": mla_tiny}[family]
+    cfg = mod.config()
+    layers = [mod.weights.make_layer(mod.SEED, index, mod.MODEL, jnp.float32)
+              for index in (1, 2, 3)]
+    stack = {name: jnp.stack([layer["moe"][name] for layer in layers])
+             for name in hybrid.EXPERT_LEAVES}
+    layer = layers[place]
+    moe = hybrid_tiny.biased(
+        {"post_norm": layer["post_norm"], **layer["moe"]}, case)
+    x = jax.random.normal(jax.random.PRNGKey(place), (2, 12, 32), jnp.float32)
+    valid = jnp.ones(x.shape[:2], bool).at[1, :2].set(False)
+    block = jax.jit(hybrid.moe_block, static_argnames=("cfg",))   # place traced
+    want, counted = block(moe, hybrid_tiny.stack_of_one(moe), jnp.int32(0), x,
+                          valid, cfg=cfg)
+    got, counters = block(moe, stack, jnp.int32(place), x, valid, cfg=cfg)
+    assert counters.tolist() == counted.tolist()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    live = int(valid.sum())
+    assert counters[0] == live * 4 and counters[4] == cfg.held == 8
+    if case == "idle":
+        assert counters[2] < cfg.held
+    if case == "one":
+        assert counters.tolist() == [live * 4, live, 1, live, cfg.held]
 
 
 # -- every term matters: an alteration of one breaks the comparison -------------
@@ -518,7 +555,8 @@ def test_the_sixteen_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
         cut = lambda name: layer["moe"][name][lo:lo + 12]
         moe = {"post_norm": layer["post_norm"], **layer["moe"],
                "gate": cut("gate"), "up": cut("up"), "down": cut("down")}
-        out, counters = hybrid.moe_block(moe, x, valid, cfg, shared=lo == 0)
+        out, counters = hybrid_tiny.moe_block_alone(moe, x, valid, cfg,
+                                                    shared=lo == 0)
         total = total + (out - x)
         here += int(counters[1])
         assert int(counters[0]) == x.shape[0] * x.shape[1] * 8

@@ -328,6 +328,26 @@ def test_every_store_rides_the_period_loops_carry(program):
 
 
 @pytest.mark.parametrize("program", sorted(PROGRAMS))
+@pytest.mark.parametrize("model", ["dots3", "a.x-k1"])
+def test_the_grouped_products_take_the_stack_of_periods_whole(model, program):
+    """The alarm for the slice coming back (on the chip a slice of the
+    stacked experts in front of `ragged_dot` is a copy of a layer's experts,
+    every product: PERF.md, PR 33): in the tick and the chunk of both tiny
+    models (two periods of four layers, four of one), every grouped
+    product's right operand leads with periods x held experts, and no
+    equation inside or outside the loop over periods makes an array of one
+    layer's expert shape."""
+    cfg = {"dots3": tiny, "a.x-k1": mla_tiny}[model].config()
+    assert cfg.periods == {"dots3": 2, "a.x-k1": 4}[model]
+    fn, make = PROGRAMS[program]
+    _, args = make(cfg)
+    eqns = list(_equations(jax.make_jaxpr(lambda *a: fn(*a, cfg))(*args).jaxpr))
+    products, sliced = hybrid_tiny.expert_operands(eqns, cfg)
+    assert products == [cfg.periods * cfg.held] * 3 * len(cfg.period)
+    assert not sliced, sliced
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
 def test_the_programs_outputs_alias_the_donated_stores(program):
     cfg = tiny.config()
     fn, make = PROGRAMS[program]
