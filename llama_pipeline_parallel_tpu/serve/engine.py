@@ -67,6 +67,13 @@ logger = get_logger(__name__)
 
 _REQUEST_IDS = itertools.count()
 
+# seconds summed over a `serve_decode_step` span's ticks, in the order
+# `_decode_tick` hands them over: the four phases, which tile the tick, and
+# the two parts of dispatch that a metric reads (the rest of it grows the
+# pages and adopts the outputs)
+TICK_SUMS = ("stage_s", "dispatch_s", "wait_s", "emit_s",
+             "h2d_s", "enqueue_s")
+
 
 class ServeOverloaded(RuntimeError):
     """Wait queue full: the backpressure signal (HTTP 429 upstream).
@@ -393,7 +400,7 @@ class ServeEngine:
         # ticks whose knobs made the program's sampler draw, and sort
         # (`sampler_branch` of the staged arrays, as the program reads it)
         self._tick_sampler = [0, 0]      # sampled, sorted
-        self._tick_phases = [0.0, 0.0, 0.0, 0.0]  # stage, dispatch, wait, emit
+        self._tick_sums = [0.0] * len(TICK_SUMS)
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
         self._tick_counters = dict.fromkeys(self._family.counters, 0)
@@ -861,13 +868,14 @@ class ServeEngine:
                 # the slot's (possibly just-forked) pages
                 c0, c1 = pf.done, pf.done + cost
                 self.slots.ensure_capacity(slot, c1)
-                out = self._family.paged_prefill_span(
-                    self.params, jnp.asarray(pf.ids[:, c0:c1]),
-                    jnp.asarray(pf.mask[:, c0:c1]),
-                    jnp.asarray(pf.positions[:, c0:c1]), self.slots.pool,
-                    jnp.asarray(self.slots.page_table[slot]),
-                    jnp.int32(slot), self.slots.kv_mask, jnp.int32(c0),
-                    self.cfg)
+                with trace.annotate(trace.PREFILL_ENQUEUE):
+                    out = self._family.paged_prefill_span(
+                        self.params, jnp.asarray(pf.ids[:, c0:c1]),
+                        jnp.asarray(pf.mask[:, c0:c1]),
+                        jnp.asarray(pf.positions[:, c0:c1]), self.slots.pool,
+                        jnp.asarray(self.slots.page_table[slot]),
+                        jnp.int32(slot), self.slots.kv_mask, jnp.int32(c0),
+                        self.cfg)
                 self.slots.pool = out["pool"]
                 self.slots.kv_mask = out["kv_mask"]
                 logits = out["logits"]
@@ -876,9 +884,10 @@ class ServeEngine:
             elif cost == pf.bucket:
                 # single shot: a row the bucket long, which write_pages
                 # pages
-                out = self._family.prefill_prompt(
-                    self.params, jnp.asarray(pf.ids), jnp.asarray(pf.mask),
-                    self.cfg, pf.bucket)
+                with trace.annotate(trace.PREFILL_ENQUEUE):
+                    out = self._family.prefill_prompt(
+                        self.params, jnp.asarray(pf.ids),
+                        jnp.asarray(pf.mask), self.cfg, pf.bucket)
                 self.slots.admit(slot, out)
                 logits = out["logits"]
                 next_pos = int(out["next_pos"][0])
@@ -886,13 +895,14 @@ class ServeEngine:
             else:
                 c0, c1 = pf.done, pf.done + cost
                 self.slots.ensure_capacity(slot, c1)
-                out = self._family.paged_prefill_chunk(
-                    self.params, jnp.asarray(pf.ids[:, c0:c1]),
-                    jnp.asarray(pf.mask[:, c0:c1]),
-                    jnp.asarray(pf.positions[:, c0:c1]), self.slots.pool,
-                    jnp.asarray(self.slots.page_table[slot]),
-                    jnp.int32(slot), self.slots.kv_mask, jnp.int32(c0),
-                    self.cfg)
+                with trace.annotate(trace.PREFILL_ENQUEUE):
+                    out = self._family.paged_prefill_chunk(
+                        self.params, jnp.asarray(pf.ids[:, c0:c1]),
+                        jnp.asarray(pf.mask[:, c0:c1]),
+                        jnp.asarray(pf.positions[:, c0:c1]), self.slots.pool,
+                        jnp.asarray(self.slots.page_table[slot]),
+                        jnp.int32(slot), self.slots.kv_mask, jnp.int32(c0),
+                        self.cfg)
                 self.slots.pool = out["pool"]
                 self.slots.kv_mask = out["kv_mask"]
                 logits = out["logits"]
@@ -908,19 +918,24 @@ class ServeEngine:
                 gen = pf.request.gen
                 chain, first_key = jax.random.split(
                     jax.random.PRNGKey(pf.request.seed))
-                first = self._sample_first(
-                    logits,
-                    jnp.asarray([gen.temperature], jnp.float32),
-                    jnp.asarray([gen.top_k], jnp.int32),
-                    jnp.asarray([gen.top_p], jnp.float32),
-                    first_key[None])
-                token = int(first[0])
+                # the one place admission waits for the device
+                with trace.annotate(trace.PREFILL_FIRST):
+                    first = self._sample_first(
+                        logits,
+                        jnp.asarray([gen.temperature], jnp.float32),
+                        jnp.asarray([gen.top_k], jnp.int32),
+                        jnp.asarray([gen.top_p], jnp.float32),
+                        first_key[None])
+                    token = int(first[0])
             if "counters" in out:
                 # the family's counts of this prefill unit (ready with the
                 # token above: the same program's output)
                 sp.update(zip(self._family.counters,
                               np.asarray(out["counters"]).tolist()))
 
+        # like the tick's flush: a span line, an anchor (a cell of long
+        # chunks flushes too seldom to anchor a capture of seconds)
+        trace.wallclock_anchor()
         rt_b = (self._rt.get(pf.request.request_id)
                 if self._reqtrace is not None else None)
         if rt_b is not None:
@@ -947,11 +962,14 @@ class ServeEngine:
 
     def _decode_tick(self) -> None:
         """One decode tick over every slot, in four host phases: `stage`
-        (the numpy batch), `dispatch` (page growth, the small arrays' H2D,
-        the enqueue), `wait` (blocked on this tick's tokens) and `emit`
-        (token push, finishes). Each is a profiler annotation and a sum on
-        the aggregated `serve_decode_step` span, whose `dur` stays
-        dispatch + wait."""
+        (the numpy batch), `dispatch` (`grow`: page growth; `h2d`: the small
+        arrays' copies; `enqueue`: the jitted call; then adopting its
+        outputs), `wait` (`block`: until this tick's tokens are ready;
+        `fetch`: their conversion) and `emit` (token push, finishes). Each is
+        a profiler annotation; the four phases, `h2d` and `enqueue` are also
+        sums on the aggregated `serve_decode_step` span (`TICK_SUMS`), whose
+        `dur` stays dispatch + wait. One clock read a boundary: no phase is
+        timed twice."""
         scfg = self.serve_cfg
         S = scfg.max_slots
         t_entry = time.perf_counter()
@@ -983,38 +1001,55 @@ class ServeEngine:
         t_wall = time.time()
         t0 = time.perf_counter()
         with trace.annotate(trace.TICK_DISPATCH):
-            # back the next write of every active row BEFORE the tick: the
-            # submit-time reservation guarantees these allocations succeed
-            for slot, r in self._occupants.items():
-                self.slots.ensure_capacity(slot, r.write_pos + 1)
-            # only occupant rows may write/mark kv: a mid-prefill slot
-            # already owns live pages and mask spans this tick must not
-            # touch
-            active = np.zeros(scfg.max_slots, np.int32)
-            for slot in self._occupants:
-                active[slot] = 1
-            out = self._family.paged_decode_step(
-                self.params, jnp.asarray(token), self.slots.pool,
-                jnp.asarray(self.slots.page_table), jnp.asarray(pos),
-                jnp.asarray(write_pos), self.slots.kv_mask,
-                jnp.asarray(active), jnp.asarray(keys),
-                jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), self.cfg)
+            with trace.annotate(trace.TICK_GROW):
+                # back the next write of every active row BEFORE the tick:
+                # the submit-time reservation guarantees these allocations
+                # succeed
+                for slot, r in self._occupants.items():
+                    self.slots.ensure_capacity(slot, r.write_pos + 1)
+                # only occupant rows may write/mark kv: a mid-prefill slot
+                # already owns live pages and mask spans this tick must not
+                # touch
+                active = np.zeros(scfg.max_slots, np.int32)
+                for slot in self._occupants:
+                    active[slot] = 1
+            t_grown = time.perf_counter()
+            with trace.annotate(trace.TICK_H2D):
+                # in the order the call takes them
+                token_d, table_d, pos_d, write_d, active_d, *knobs_d = [
+                    jnp.asarray(a) for a in (
+                        token, self.slots.page_table, pos, write_pos, active,
+                        keys, temps, top_ks, top_ps)]
+            t_copied = time.perf_counter()
+            with trace.annotate(trace.TICK_ENQUEUE):
+                # its return is the enqueue's return
+                out = self._family.paged_decode_step(
+                    self.params, token_d, self.slots.pool, table_d, pos_d,
+                    write_d, self.slots.kv_mask, active_d, *knobs_d,
+                    self.cfg)
+            t_enqueued = time.perf_counter()
+            # release the staged copies now, while the device runs the tick,
+            # as a call's own temporaries are: nine buffer releases (9 us
+            # each on the v5e's host) would otherwise wait for this
+            # function's return, between two ticks
+            del token_d, table_d, pos_d, write_d, active_d, knobs_d
             self.slots.update_from_step(out)
         t_dispatched = time.perf_counter()
         with trace.annotate(trace.TICK_WAIT):
-            # block inside the annotation, then convert: the device's gap
-            # while the host sleeps here belongs to this event, not to the
-            # conversion's own
-            jax.block_until_ready((out["token"], out["keys"]))
-            next_token = np.asarray(out["token"])   # real tick time
-            new_keys = np.asarray(out["keys"])
-            if self._tick_counters:
-                # the same program's output as the tokens above: ready
-                # with them, no further wait
-                for name, n in zip(self._family.counters,
-                                   np.asarray(out["counters"]).tolist()):
-                    self._tick_counters[name] += n
+            # block, then convert: the device's gap while the host sleeps
+            # belongs to `block` (launch before the program's first
+            # operation, wake after its last), not to the conversions
+            with trace.annotate(trace.TICK_BLOCK):
+                jax.block_until_ready((out["token"], out["keys"]))
+            with trace.annotate(trace.TICK_FETCH):
+                next_token = np.asarray(out["token"])   # real tick time
+                new_keys = np.asarray(out["keys"])
+                if self._tick_counters:
+                    # the same program's output as the tokens above: ready
+                    # with them, no further wait
+                    for name, n in zip(self._family.counters,
+                                       np.asarray(out["counters"]).tolist()):
+                        self._tick_counters[name] += n
         t_fetched = time.perf_counter()
         self._last_decode_dur = t_fetched - t0
         with trace.annotate(trace.TICK_EMIT):
@@ -1042,11 +1077,12 @@ class ServeEngine:
                     self._finish(slot, r)
         self._note_decode_tick(
             t_wall, self._last_decode_dur, n_active,
-            phases=(t0 - t_entry, t_dispatched - t0, t_fetched - t_dispatched,
-                    time.perf_counter() - t_fetched))
+            sums=(t0 - t_entry, t_dispatched - t0, t_fetched - t_dispatched,
+                  time.perf_counter() - t_fetched, t_copied - t_grown,
+                  t_enqueued - t_copied))
 
     def _note_decode_tick(self, ts: float, dur: float, active: int,
-                          phases: tuple) -> None:
+                          sums: tuple) -> None:
         """Fold one decode tick into the pending aggregated
         `serve_decode_step` span; flush every `decode_span_every` ticks
         (and at idle boundaries / shutdown). The emitted span's `dur` is
@@ -1054,9 +1090,9 @@ class ServeEngine:
         RunClock's `serve` bucket and the goodput fraction lose nothing to
         the aggregation — only the spans.jsonl line rate drops from token
         rate. `tokens` is the host's own count of the rows that decoded over
-        those ticks (`active` is the last tick's alone). `phases`: this
-        tick's (stage, dispatch, wait, emit) seconds, summed the same way,
-        as are `kv_pages_live`, `kv_pages_table`, `ticks_sampled` and
+        those ticks (`active` is the last tick's alone). `sums`: this tick's
+        seconds in the order of `TICK_SUMS`, summed the same way, as are
+        `kv_pages_live`, `kv_pages_table`, `ticks_sampled` and
         `ticks_sorted` (`_decode_tick` counts them where it stages the
         rows)."""
         if self._tick_count == 0:
@@ -1065,15 +1101,16 @@ class ServeEngine:
         self._tick_count += 1
         self._tick_active = active
         self._tick_tokens += active
-        for i, seconds in enumerate(phases):
-            self._tick_phases[i] += seconds
+        for i, seconds in enumerate(sums):
+            self._tick_sums[i] += seconds
         if self._tick_count >= self.serve_cfg.decode_span_every:
             self._flush_decode_span()
 
     def _flush_decode_span(self) -> None:
         if self._tick_count == 0:
             return
-        stage_s, dispatch_s, wait_s, emit_s = self._tick_phases
+        # the host's wall clock on a running capture's clock, one a span
+        trace.wallclock_anchor()
         trace.recorder().emit("serve_decode_step", ts=self._tick_ts,
                               dur=self._tick_accum, ticks=self._tick_count,
                               active=self._tick_active,
@@ -1082,14 +1119,13 @@ class ServeEngine:
                               kv_pages_table=self._tick_pages[1],
                               ticks_sampled=self._tick_sampler[0],
                               ticks_sorted=self._tick_sampler[1],
-                              stage_s=stage_s, dispatch_s=dispatch_s,
-                              wait_s=wait_s, emit_s=emit_s,
+                              **dict(zip(TICK_SUMS, self._tick_sums)),
                               **self._tick_counters)
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
         self._tick_pages = [0, 0]
         self._tick_sampler = [0, 0]
-        self._tick_phases = [0.0, 0.0, 0.0, 0.0]
+        self._tick_sums = [0.0] * len(TICK_SUMS)
         self._tick_counters = dict.fromkeys(self._tick_counters, 0)
 
     def _on_page_alloc(self, slot: int, pages: int) -> None:

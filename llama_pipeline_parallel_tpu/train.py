@@ -1475,6 +1475,7 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                 trace_t0, trace_first = time.time(), step
                 jax.profiler.start_trace(os.path.join(output_dir, "profile"))
                 trace_active = True
+                trace.wallclock_anchor()  # every capture holds at least one
             with trace.span("data_wait", step=step):
                 batch = next(it)
             if step_timeline is not None:
@@ -1535,6 +1536,9 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                 # dispatch/data spans already took on the host side)
                 with trace.span("device_step", step=step + 1, steps=n_window):
                     final_loss = float(losses[-1])
+                # the wall clock on a running capture's clock, so the
+                # capture joins spans.jsonl (docs/OBSERVABILITY.md)
+                trace.wallclock_anchor()
                 # pure stepping time: compile/eval/ckpt wall time inside the
                 # window is subtracted, so step_time tracks the train rate
                 # (those phases are visible in the goodput buckets instead)

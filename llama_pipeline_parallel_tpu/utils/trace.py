@@ -27,8 +27,9 @@ constants below): `jax.named_scope` at each site and `name=` on every
 `pallas_call`, so a compiled operation's `op_name` path, which the profiler
 keeps per device event, says which part of the step it belongs to
 (docs/OBSERVABILITY.md "Scopes"). Host phases too short to be worth a jsonl
-line (the serving tick's four) go through `annotate`, which only mirrors into
-the profiler.
+line (the serving tick's four and the parts nested in them) go through
+`annotate`, which only mirrors into the profiler; `wallclock_anchor` puts the
+host's wall clock on the profiler's clock, so a capture joins `spans.jsonl`.
 
 The module-level recorder is a process-global configured once per run
 (`configure(output_dir)`); instrumentation sites (`train._train_loop`,
@@ -39,6 +40,7 @@ annotate, they just aren't persisted.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -177,6 +179,18 @@ TICK_DISPATCH = "serve_tick_dispatch"
 TICK_WAIT = "serve_tick_wait"
 TICK_EMIT = "serve_tick_emit"
 SERVE_ADMIT = "serve_admit"
+# nested in the five above, which keep their extents: what a dispatch and a
+# wait are made of (benchmark/tick_gap.py partitions the device's idle time
+# by the innermost of all of these)
+TICK_GROW = "serve_tick_grow"            # in dispatch: page growth, the `active` row
+TICK_H2D = "serve_tick_h2d"              # in dispatch: the staged arrays' copies
+TICK_ENQUEUE = "serve_tick_enqueue"      # in dispatch: the jitted call alone
+TICK_BLOCK = "serve_tick_block"          # in wait: `block_until_ready` alone
+TICK_FETCH = "serve_tick_fetch"          # in wait: tokens, keys, counters to numpy
+PREFILL_ENQUEUE = "serve_prefill_enqueue"  # in `serve_prefill`: a unit's call
+PREFILL_FIRST = "serve_prefill_first"    # in `serve_prefill`: the first token's wait
+# an empty annotation NAMED `wallclock_us=<time.time() in microseconds>`
+WALLCLOCK_PREFIX = "wallclock_us="
 
 
 class SpanRecorder:
@@ -273,15 +287,23 @@ class SpanRecorder:
             self._f = None
 
 
-def _trace_annotation(name: str):
-    """jax.profiler.TraceAnnotation(name), or None when jax is unavailable
-    (offline tools importing this module must not require jax)."""
+@functools.lru_cache(maxsize=None)
+def _annotation_class():
+    """`jax.profiler.TraceAnnotation`, resolved on first use; None when jax
+    is unavailable (offline tools importing this module must not require
+    jax)."""
     try:
         import jax
 
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation
     except Exception:
         return None
+
+
+def _trace_annotation(name: str):
+    """One constructor a call: annotations repeat at token rate."""
+    cls = _annotation_class()
+    return cls(name) if cls is not None else None
 
 
 # -- process-global recorder -------------------------------------------------
@@ -314,6 +336,18 @@ def span(name: str, **attrs: Any):
 def annotate(name: str):
     """`with trace.annotate("serve_tick_wait"): ...`: profiler only."""
     return _RECORDER.annotate(name)
+
+
+def wallclock_anchor() -> None:
+    """Enter one empty annotation whose NAME is the host's wall clock, read
+    immediately before it: `wallclock_us=<int(time.time() * 1e6)>`. A reader
+    of the capture takes the median of (the event's start on the profiler's
+    clock - the stamp) over a trace's anchors as the offset between the two
+    clocks (benchmark/tick_gap.clock_offset), which places `spans.jsonl` and
+    `request_trace.jsonl` lines on the trace. Costs one `annotate` while no
+    trace runs."""
+    with annotate(f"{WALLCLOCK_PREFIX}{int(time.time() * 1e6)}"):
+        pass
 
 
 # -- goodput accounting ------------------------------------------------------

@@ -64,6 +64,44 @@ def test_command_prints_every_section(capture, capsys):
                     "longest idle gaps"):
         assert heading in out
     assert "kv_gather" in out and "serve_tick_stage" in out
+    # a capture of a build without the nested events: what lies under
+    # `serve_tick_wait` is one part, and there is no anchor to read
+    assert "wait_other" in out and "no wall-clock anchor" in out
+
+
+def test_a_serving_capture_prints_the_tick_gap_and_the_clock(tmp_path, capsys):
+    """Two ticks whose dispatch and wait hold the nested events, two
+    anchors: the partition a tick, launch apart from wake, and the offset."""
+    ms = 1_000_000
+    ops = [_op("fusion.1", TICK + "kv_gather/gather", 0 * ms, 1 * ms),
+           _op("fusion.1", TICK + "kv_gather/gather", 6 * ms, 4 * ms),
+           _op("fusion.1", TICK + "kv_gather/gather", 16 * ms, 4 * ms)]
+    host = {"python": [
+        (name, None, int((at + lo) * ms), int((hi - lo) * ms))
+        for at in (0, 10)
+        for name, lo, hi in (("serve_tick_stage", 1, 2),
+                             ("serve_tick_dispatch", 2, 5),
+                             ("serve_tick_h2d", 2, 3),
+                             ("serve_tick_enqueue", 3, 5),
+                             ("serve_tick_wait", 5, 11),
+                             ("serve_tick_block", 5, 10.5),
+                             ("serve_tick_fetch", 10.5, 11))] + [
+        (f"wallclock_us={1_790_000_000_000_000 + at}", None, at * 1000 + 250, 0)
+        for at in (1_500, 11_500)]}
+    sx.write(tmp_path / "serve.xplane.pb", {"/device:TPU:0": {"XLA Ops": ops},
+                                            "/host:CPU": host})
+    s = trace_summary.summarize(trace_summary.xplane.find_xplane(str(tmp_path)))
+    part = s["tick_gap"]
+    assert part["ticks"] == 2 and part["idle_ns"] == 11 * ms
+    assert {k: v / ms for k, v in part["parts_ns"].items() if v} == {
+        "stage": 2, "h2d": 2, "enqueue": 4, "launch": 2, "wake": 0.5,
+        "fetch": 0.5}
+    assert s["clock"]["offset_us"] == pytest.approx(-1.79e15 + 0.25, abs=1.0)
+    trace_summary.main([str(tmp_path)])
+    out = capsys.readouterr().out
+    assert "idle time a serving tick" in out and "5.500 ms a tick" in out
+    assert "1.000 ms  launch" in out and "0.250 ms  wake" in out
+    assert "over 2 anchors, spread 0.0 us" in out
 
 
 def test_newest_capture_wins(capture, tmp_path):

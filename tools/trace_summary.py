@@ -12,7 +12,14 @@ ones the per-layer metrics report:
   llama_pipeline_parallel_tpu/utils/trace.py, as shares of busy time;
 - the operations with the most device time of their own;
 - the longest idle gaps, each with the host event that covers most of it
-  (the serving tick's `serve_tick_*` annotations, the trainer's spans).
+  (the serving tick's `serve_tick_*` annotations, the trainer's spans);
+- for a serving capture (one that holds `serve_tick_wait` events), the idle
+  time a tick taken apart by the innermost event of the engine over it, with
+  launch and wake told apart inside `serve_tick_block`, after the device's
+  clock is moved to where the trace is causal (benchmark/tick_gap.py);
+- the offset between the profiler's clock and the wall clock, from the
+  capture's `wallclock_us=` anchors: add it to a `spans.jsonl` or
+  `request_trace.jsonl` time to place the line on the trace.
 
 Usage:
   python tools/trace_summary.py <trace_dir> [--top 15]
@@ -26,7 +33,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark import scopes, xplane  # noqa: E402
+from benchmark import scopes, tick_gap, xplane  # noqa: E402
 
 
 def summarize(path: str, top: int = 15) -> dict:
@@ -38,6 +45,7 @@ def summarize(path: str, top: int = 15) -> dict:
             f"{xplane.DEVICE_PREFIX}<n>, line {xplane.OPS_LINE!r}): a capture "
             f"of a CPU run has host events only")
     scoped = scopes.read(path)
+    part, shift = tick_gap.shifted_partition(trace)
     busy_s, window_s = xplane.busy_and_window(trace)
     by_leaf = scopes.leaf_shares(scoped)
     return {
@@ -50,6 +58,8 @@ def summarize(path: str, top: int = 15) -> dict:
                               if k != "(no scope)"),
         "top_ops": xplane.top_ops(trace, top),
         "idle_gaps": xplane.idle_gaps(trace, top),
+        "tick_gap": part, "device_clock_shift": shift,
+        "clock": tick_gap.clock_offset(trace),
     }
 
 
@@ -85,6 +95,16 @@ def main(argv: list[str] | None = None) -> None:
     print("\n== longest idle gaps, by the host event over them ==")
     for name, seconds in s["idle_gaps"]:
         print(f"  {1e3 * seconds:10.3f} ms  {name}")
+    if s["tick_gap"] is not None:
+        part = s["tick_gap"]
+        print(f"\n== idle time a serving tick, by the innermost engine event "
+              f"over it ==\n  {tick_gap.ms_a_tick(part):10.3f} ms a tick over "
+              f"{part['ticks']} ticks")
+        for name in tick_gap.PARTS:
+            if part["parts_ns"][name]:
+                print(f"  {tick_gap.ms_a_tick(part, name):10.3f} ms  {name}")
+        print(f"  {tick_gap.describe_shift(s['device_clock_shift'])}")
+    print(f"\nclock: {tick_gap.describe_clock(s['clock'])}")
 
 
 if __name__ == "__main__":
