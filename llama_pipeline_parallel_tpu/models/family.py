@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable
 
+from llama_pipeline_parallel_tpu.models import tick_io
 from llama_pipeline_parallel_tpu.models.llama.decode import (  # noqa: F401
     GenerationConfig,
     sample_rowwise,
@@ -43,8 +44,9 @@ class UnsupportedForFamily(ValueError):
 @dataclasses.dataclass(frozen=True)
 class ServingFamily:
     name: str
-    # the three programs of the normal path, and the splice of a prefilled
-    # row into the paged stores
+    # the three programs of the normal path (the tick with its thirteen
+    # arguments: tests and the benchmark's checks call it so; an engine runs
+    # `decode_tick`), and the splice of a prefilled row into the paged stores
     prefill_prompt: Callable
     paged_decode_step: Callable
     write_pages: Callable
@@ -72,6 +74,13 @@ class ServingFamily:
     @property
     def recurrent(self) -> bool:
         return self.init_recurrent_store is not None
+
+    @property
+    def decode_tick(self) -> Callable:
+        """The tick as an engine runs it: `paged_decode_step`'s body behind
+        one staged buffer in and one fetched vector out (`models/tick_io.py`),
+        the same jitted program for every engine of the family."""
+        return tick_io.packed(self.paged_decode_step)
 
     def check_serve_config(self, kv_quant: str, prefill_chunk_tokens: int,
                            prefix_cache: bool) -> None:

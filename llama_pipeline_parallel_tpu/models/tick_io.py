@@ -1,0 +1,128 @@
+"""The decode tick's two transfers: what the host stages goes to the device
+as ONE int32 buffer, what the host reads of the tick comes back as one.
+
+A transfer of a few kilobytes costs its fixed part, an allocation, a
+linearisation and a dispatch on the way in and a round trip on the way out
+(0.27 ms and 0.3 to 0.5 ms on a v5e's host, PERF.md), so a tick pays for the
+COUNT of its transfers and not for their bytes. `stage` lays every array
+`paged_decode_step` takes from the host side by side, a row a slot:
+
+    [S, COLUMNS + pages_per_slot] int32
+    token | pos | write_pos | active | top_k | key word 0 | key word 1 |
+    temperature | top_p | the slot's page-table row
+
+The `uint32` keys and the `float32` knobs are VIEWS of their columns on the
+host and `bitcast_convert_type`s of them in the program: every bit as it
+was, no conversion either way. `packed` builds, from a family's
+thirteen-argument `paged_decode_step`, the program the engine runs: the same
+body between `unpack` and `pack_result`, under the same name (the trace's
+`jit(paged_decode_step)`, which the benchmark's readers find the tick by).
+Written once for every family: they share the argument list to the letter.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+# the columns in front of a slot's page-table row: the five int32 fields in
+# `Staged`'s order, the key's two words, the two float32 knobs
+_INTS = 5
+_KEYS = slice(_INTS, _INTS + 2)
+_TEMPERATURE, _TOP_P = _KEYS.stop, _KEYS.stop + 1
+COLUMNS = _TOP_P + 1
+
+
+class Staged(NamedTuple):
+    """One tick's staging buffer and the typed views the host fills it
+    through (each a view of `buffer`: a write to one is a write to it)."""
+
+    buffer: np.ndarray          # [S, COLUMNS + pages_per_slot] int32
+    token: np.ndarray           # [S] int32, and the four after it
+    pos: np.ndarray
+    write_pos: np.ndarray
+    active: np.ndarray
+    top_k: np.ndarray
+    keys: np.ndarray            # [S, 2] uint32
+    temperature: np.ndarray     # [S] float32
+    top_p: np.ndarray           # [S] float32
+    page_table: np.ndarray      # [S, pages_per_slot] int32
+
+
+def stage(slots: int, pages_per_slot: int) -> Staged:
+    """A FRESH buffer, every row an unoccupied slot's (greedy: temperature
+    0, no top-k, top-p 1). Fresh every tick, never one buffer reused: on the
+    CPU backend `jnp.asarray` of an aligned numpy array shares its memory,
+    so a buffer written again would change under the program it was handed
+    to; one that nobody writes after the hand-over cannot."""
+    buffer = np.zeros((slots, COLUMNS + pages_per_slot), np.int32)
+    top_p = buffer[:, _TOP_P].view(np.float32)
+    top_p[:] = 1.0
+    return Staged(
+        buffer, *(buffer[:, j] for j in range(_INTS)),
+        keys=buffer[:, _KEYS].view(np.uint32),
+        temperature=buffer[:, _TEMPERATURE].view(np.float32), top_p=top_p,
+        page_table=buffer[:, COLUMNS:])
+
+
+def unpack(staged: jnp.ndarray) -> tuple:
+    """In the program: the staged buffer back to the nine arrays, in the
+    order `paged_decode_step` takes them (its `pool` and `kv_mask` come
+    beside them, donated): token, page_table, pos, write_pos, active, keys,
+    temperature, top_k, top_p."""
+    token, pos, write_pos, active, top_k = (
+        staged[:, j] for j in range(_INTS))
+    bits = jax.lax.bitcast_convert_type
+    return (token, staged[:, COLUMNS:], pos, write_pos, active,
+            bits(staged[:, _KEYS], jnp.uint32),
+            bits(staged[:, _TEMPERATURE], jnp.float32), top_k,
+            bits(staged[:, _TOP_P], jnp.float32))
+
+
+def pack_result(token: jnp.ndarray, keys: jnp.ndarray,
+                counters: jnp.ndarray | None) -> jnp.ndarray:
+    """In the program: what the host reads of a tick as one int32 vector,
+    [S] tokens, [S * 2] key words, then the family's counters if it has
+    any."""
+    parts = [token, jax.lax.bitcast_convert_type(keys, jnp.int32).reshape(-1)]
+    if counters is not None:
+        parts.append(counters)
+    assert all(p.dtype == jnp.int32 for p in parts), [p.dtype for p in parts]
+    return jnp.concatenate(parts)
+
+
+def split_result(fetched: np.ndarray, slots: int) -> tuple:
+    """On the host: the fetched vector's parts, as views: ([S] tokens,
+    [S, 2] uint32 keys, the counters that follow them, possibly none)."""
+    return (fetched[:slots],
+            fetched[slots:3 * slots].view(np.uint32).reshape(slots, 2),
+            fetched[3 * slots:])
+
+
+@functools.cache
+def packed(step):
+    """The program an engine runs a tick with, made from a family's jitted
+    thirteen-argument `step`: (params, staged, pool, kv_mask, cfg) ->
+    {"fetch": `pack_result` of the tick's token, keys and counters, "pool",
+    "kv_mask"}. It traces `step`'s own body (`__wrapped__`, no nested jit:
+    the operations keep their paths under `jit(paged_decode_step)`) and
+    returns only what the engine reads: a family's further outputs, such as
+    the latent tick's `selection`, are not computed for it. One jitted
+    program a `step`, whoever asks."""
+    body = step.__wrapped__
+
+    def paged_decode_step(params, staged, pool, kv_mask, cfg):
+        (token, page_table, pos, write_pos, active, keys, temperature,
+         top_k, top_p) = unpack(staged)
+        out = body(params, token, pool, page_table, pos, write_pos, kv_mask,
+                   active, keys, temperature, top_k, top_p, cfg)
+        return {"fetch": pack_result(out["token"], out["keys"],
+                                     out.get("counters")),
+                "pool": out["pool"], "kv_mask": out["kv_mask"]}
+
+    return jax.jit(paged_decode_step, static_argnames=("cfg",),
+                   donate_argnames=("pool", "kv_mask"))

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from llama_pipeline_parallel_tpu.models import tick_io
 from llama_pipeline_parallel_tpu.models.family import (
     GenerationConfig,
     family_of,
@@ -383,6 +384,8 @@ class ServeEngine:
         self._lock = threading.Lock()
         self._work = threading.Event()   # ServeLoop parks on this when idle
         self._sample_first = jax.jit(sample_rowwise)
+        # the tick's program: one staged buffer in, one fetched vector out
+        self._tick_program = self._family.decode_tick
         self.steps = 0
         self.prefill_chunks_last_tick = 0
         self.prefill_chunks_total = 0
@@ -401,6 +404,9 @@ class ServeEngine:
         # (`sampler_branch` of the staged arrays, as the program reads it)
         self._tick_sampler = [0, 0]      # sampled, sorted
         self._tick_sums = [0.0] * len(TICK_SUMS)
+        # transfers the engine's thread made over the pending span's ticks,
+        # counted where they are made: one each way a tick
+        self._tick_copies = [0, 0]       # host to device, device to host
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
         self._tick_counters = dict.fromkeys(self._family.counters, 0)
@@ -962,39 +968,38 @@ class ServeEngine:
 
     def _decode_tick(self) -> None:
         """One decode tick over every slot, in four host phases: `stage`
-        (the numpy batch), `dispatch` (`grow`: page growth; `h2d`: the small
-        arrays' copies; `enqueue`: the jitted call; then adopting its
-        outputs), `wait` (`block`: until this tick's tokens are ready;
-        `fetch`: their conversion) and `emit` (token push, finishes). Each is
-        a profiler annotation; the four phases, `h2d` and `enqueue` are also
-        sums on the aggregated `serve_decode_step` span (`TICK_SUMS`), whose
-        `dur` stays dispatch + wait. One clock read a boundary: no phase is
-        timed twice."""
+        (the rows of ONE staging buffer, `models/tick_io.py`), `dispatch`
+        (`grow`: page growth, then the page table into the buffer; `h2d`: the
+        buffer's copy, the tick's one transfer to the device; `enqueue`: the
+        jitted call; then adopting its outputs), `wait` (`block`: until this
+        tick's tokens are ready; `fetch`: token, keys and counters to numpy,
+        the tick's one transfer back) and `emit` (token push, finishes). Each
+        is a profiler annotation; the four phases, `h2d` and `enqueue` are
+        also sums on the aggregated `serve_decode_step` span (`TICK_SUMS`),
+        whose `dur` stays dispatch + wait, and the transfers are counted where
+        they are made (`h2d_copies`, `d2h_copies`). One clock read a boundary:
+        no phase is timed twice."""
         scfg = self.serve_cfg
         S = scfg.max_slots
         t_entry = time.perf_counter()
         with trace.annotate(trace.TICK_STAGE):
-            token = np.zeros(S, np.int32)
-            pos = np.zeros(S, np.int32)
-            write_pos = np.zeros(S, np.int32)
-            keys = np.zeros((S, 2), np.uint32)
-            temps = np.zeros(S, np.float32)
-            top_ks = np.zeros(S, np.int32)
-            top_ps = np.ones(S, np.float32)
+            # fresh every tick: nothing writes a buffer the device was given
+            staged = tick_io.stage(S, self.slots.page_table.shape[1])
             pages_live = 0
             for slot, r in self._occupants.items():
-                token[slot] = r.token
-                pos[slot] = r.pos
-                write_pos[slot] = r.write_pos
-                keys[slot] = r.key
-                temps[slot] = r.request.gen.temperature
-                top_ks[slot] = r.request.gen.top_k
-                top_ps[slot] = r.request.gen.top_p
+                staged.token[slot] = r.token
+                staged.pos[slot] = r.pos
+                staged.write_pos[slot] = r.write_pos
+                staged.keys[slot] = r.key
+                staged.temperature[slot] = r.request.gen.temperature
+                staged.top_k[slot] = r.request.gen.top_k
+                staged.top_p[slot] = r.request.gen.top_p
                 pages_live += r.write_pos // scfg.page_size + 1
             n_active = len(self._occupants)
             self._tick_pages[0] += pages_live
             self._tick_pages[1] += n_active * self.slots.page_table.shape[1]
-            branch = int(sampler_branch(temps, top_ks, top_ps))
+            branch = int(sampler_branch(staged.temperature, staged.top_k,
+                                        staged.top_p))
             self._tick_sampler[0] += branch >= 1
             self._tick_sampler[1] += branch == 2
 
@@ -1007,49 +1012,42 @@ class ServeEngine:
                 # succeed
                 for slot, r in self._occupants.items():
                     self.slots.ensure_capacity(slot, r.write_pos + 1)
-                # only occupant rows may write/mark kv: a mid-prefill slot
-                # already owns live pages and mask spans this tick must not
-                # touch
-                active = np.zeros(scfg.max_slots, np.int32)
-                for slot in self._occupants:
-                    active[slot] = 1
+                    # only occupant rows may write/mark kv: a mid-prefill
+                    # slot already owns live pages and mask spans this tick
+                    # must not touch
+                    staged.active[slot] = 1
+                # a copy: the table itself changes under later growth and
+                # releases
+                staged.page_table[:] = self.slots.page_table
             t_grown = time.perf_counter()
             with trace.annotate(trace.TICK_H2D):
-                # in the order the call takes them
-                token_d, table_d, pos_d, write_d, active_d, *knobs_d = [
-                    jnp.asarray(a) for a in (
-                        token, self.slots.page_table, pos, write_pos, active,
-                        keys, temps, top_ks, top_ps)]
+                staged_d = jnp.asarray(staged.buffer)
+                self._tick_copies[0] += 1
             t_copied = time.perf_counter()
             with trace.annotate(trace.TICK_ENQUEUE):
                 # its return is the enqueue's return
-                out = self._family.paged_decode_step(
-                    self.params, token_d, self.slots.pool, table_d, pos_d,
-                    write_d, self.slots.kv_mask, active_d, *knobs_d,
-                    self.cfg)
+                out = self._tick_program(self.params, staged_d,
+                                         self.slots.pool, self.slots.kv_mask,
+                                         self.cfg)
             t_enqueued = time.perf_counter()
-            # release the staged copies now, while the device runs the tick,
-            # as a call's own temporaries are: nine buffer releases (9 us
-            # each on the v5e's host) would otherwise wait for this
-            # function's return, between two ticks
-            del token_d, table_d, pos_d, write_d, active_d, knobs_d
+            # release the staged copy now, while the device runs the tick, as
+            # a call's own temporaries are, not between two ticks at this
+            # function's return
+            del staged_d
             self.slots.update_from_step(out)
         t_dispatched = time.perf_counter()
         with trace.annotate(trace.TICK_WAIT):
             # block, then convert: the device's gap while the host sleeps
             # belongs to `block` (launch before the program's first
-            # operation, wake after its last), not to the conversions
+            # operation, wake after its last), not to the conversion
             with trace.annotate(trace.TICK_BLOCK):
-                jax.block_until_ready((out["token"], out["keys"]))
+                jax.block_until_ready(out["fetch"])
             with trace.annotate(trace.TICK_FETCH):
-                next_token = np.asarray(out["token"])   # real tick time
-                new_keys = np.asarray(out["keys"])
-                if self._tick_counters:
-                    # the same program's output as the tokens above: ready
-                    # with them, no further wait
-                    for name, n in zip(self._family.counters,
-                                       np.asarray(out["counters"]).tolist()):
-                        self._tick_counters[name] += n
+                next_token, new_keys, counters = tick_io.split_result(
+                    np.asarray(out["fetch"]), S)        # real tick time
+                self._tick_copies[1] += 1
+                for name, n in zip(self._family.counters, counters.tolist()):
+                    self._tick_counters[name] += n
         t_fetched = time.perf_counter()
         self._last_decode_dur = t_fetched - t0
         with trace.annotate(trace.TICK_EMIT):
@@ -1094,7 +1092,7 @@ class ServeEngine:
         seconds in the order of `TICK_SUMS`, summed the same way, as are
         `kv_pages_live`, `kv_pages_table`, `ticks_sampled` and
         `ticks_sorted` (`_decode_tick` counts them where it stages the
-        rows)."""
+        rows) and `h2d_copies` / `d2h_copies` (where it makes them)."""
         if self._tick_count == 0:
             self._tick_ts = ts
         self._tick_accum += dur
@@ -1119,12 +1117,15 @@ class ServeEngine:
                               kv_pages_table=self._tick_pages[1],
                               ticks_sampled=self._tick_sampler[0],
                               ticks_sorted=self._tick_sampler[1],
+                              h2d_copies=self._tick_copies[0],
+                              d2h_copies=self._tick_copies[1],
                               **dict(zip(TICK_SUMS, self._tick_sums)),
                               **self._tick_counters)
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
         self._tick_pages = [0, 0]
         self._tick_sampler = [0, 0]
+        self._tick_copies = [0, 0]
         self._tick_sums = [0.0] * len(TICK_SUMS)
         self._tick_counters = dict.fromkeys(self._tick_counters, 0)
 

@@ -182,11 +182,11 @@ SERVE_ADMIT = "serve_admit"
 # nested in the five above, which keep their extents: what a dispatch and a
 # wait are made of (benchmark/tick_gap.py partitions the device's idle time
 # by the innermost of all of these)
-TICK_GROW = "serve_tick_grow"            # in dispatch: page growth, the `active` row
-TICK_H2D = "serve_tick_h2d"              # in dispatch: the staged arrays' copies
+TICK_GROW = "serve_tick_grow"            # in dispatch: page growth, `active`, the table
+TICK_H2D = "serve_tick_h2d"              # in dispatch: the staged buffer's one copy
 TICK_ENQUEUE = "serve_tick_enqueue"      # in dispatch: the jitted call alone
 TICK_BLOCK = "serve_tick_block"          # in wait: `block_until_ready` alone
-TICK_FETCH = "serve_tick_fetch"          # in wait: tokens, keys, counters to numpy
+TICK_FETCH = "serve_tick_fetch"          # in wait: token, keys, counters to numpy, once
 PREFILL_ENQUEUE = "serve_prefill_enqueue"  # in `serve_prefill`: a unit's call
 PREFILL_FIRST = "serve_prefill_first"    # in `serve_prefill`: the first token's wait
 # an empty annotation NAMED `wallclock_us=<time.time() in microseconds>`

@@ -382,6 +382,51 @@ def test_a_latent_program_compiled_for_the_chip_keeps_its_stores_in_place(
     assert "sparse_latent_attn" in compiled.as_text()
 
 
+# -- the engine's own tick: one staged buffer in, one fetched vector out --------
+
+@pytest.mark.parametrize("family", ["dense", "hybrid", "latent"])
+def test_the_engines_tick_compiled_for_the_chip_keeps_the_stores_in_place(
+        one_chip, mosaic, family):
+    """`tick_io.packed` of each family's step (what `ServeEngine` runs),
+    compiled for the chip: the slices and bitcasts that take the staged
+    buffer apart cost no store-sized temporary, the outputs are the donated
+    stores' buffers, the kernel is still the tick's attention, and what the
+    host fetches is one int32 vector of 3 a slot and the counters."""
+    from llama_pipeline_parallel_tpu.models import tick_io
+
+    slots, pmax, page = 4, 16, 64
+    if family == "latent":
+        latent_decode, cfg, params, pool = _latent_programs(
+            slots, pmax, page, 4096)
+        tick, store = latent_decode.paged_decode_step, "latent"
+        kernel, counters = "sparse_latent_attn", len(latent_decode.counters(cfg))
+    else:
+        tick, cfg, params, pool = (
+            _dense_tick if family == "dense" else _hybrid_tick)(
+                slots, pmax, page, 2048)
+        store, kernel = "k", "paged_decode_attn"
+        counters = 0 if family == "dense" else len(hybrid_decode.COUNTERS)
+    args = _described(
+        (params,
+         jax.ShapeDtypeStruct((slots, tick_io.COLUMNS + pmax), jnp.int32),
+         pool, jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)),
+        one_chip)
+    lowered = tick_io.packed(tick).lower(*args, cfg)
+    assert "module @jit_paged_decode_step" in lowered.as_text()
+    assert lowered.out_info["fetch"].shape == (3 * slots + counters,)
+    assert lowered.out_info["fetch"].dtype == jnp.int32
+    compiled = lowered.compile()
+    analysis = compiled.memory_analysis()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    assert analysis.alias_size_in_bytes >= nbytes(pool)
+    assert analysis.temp_size_in_bytes < nbytes(pool[store]) // 4, analysis
+    assert kernel in compiled.as_text()
+
+
 # -- plain MLA that reads the whole cache, compiled for the same chip ------------
 
 def test_mosaic_compiles_the_dense_latent_tick_kernel_at_the_longdoc_cells_shapes(

@@ -8,13 +8,12 @@ filters, once a row; and the engine's `ticks_sampled` / `ticks_sorted` count
 what the program branched on.
 """
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llama_pipeline_parallel_tpu.models import tick_io
 from llama_pipeline_parallel_tpu.models.llama import decode
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
@@ -242,17 +241,17 @@ def test_the_tick_span_counts_the_ticks_that_sampled_and_that_sorted():
     engine = ServeEngine(params, cfg, ServeConfig(
         max_slots=2, max_len=24, prompt_buckets=(16,), page_size=8,
         max_queue=8, decode_span_every=1))
-    program_branch = jax.jit(decode.sampler_branch)
+    program_branch = jax.jit(
+        lambda staged: decode.sampler_branch(*tick_io.unpack(staged)[-3:]))
     given = []
-    real_step = engine._family.paged_decode_step
+    real_step = engine._tick_program
 
-    def recording_step(*args):
-        # (..., keys, temperature, top_k, top_p, cfg): what the program sees
-        given.append(int(program_branch(*args[-4:-1])))
-        return real_step(*args)
+    def recording_step(params, staged, *rest):
+        # the knobs as the program unpacks them from the staged buffer
+        given.append(int(program_branch(staged)))
+        return real_step(params, staged, *rest)
 
-    engine._family = dataclasses.replace(engine._family,
-                                         paged_decode_step=recording_step)
+    engine._tick_program = recording_step
     spans = []
     listener = lambda rec: spans.append(dict(rec))
     trace.recorder().add_listener(listener)
