@@ -8,8 +8,18 @@ functions. The dense decoder (`models/llama/`) is one family, the hybrid
 block with recurrent layers and sparse experts (`models/hybrid_moe/`) another,
 the latent-attention block (MLA layers, with or without an indexer and
 window layers, as its configuration says) and sparse experts
-(`models/latent_moe/`) the third. A fourth registers its configuration class
-below.
+(`models/latent_moe/`) the third, the compressed-window block (an exact
+window beside pooled chunk summaries, `models/eva/`) the fourth. A fifth
+registers its configuration class below.
+
+A family also states what a slot's PAGES are (`table_width`,
+`table_columns`): how wide a slot's row of the page table is and which of its
+columns hold pages once so many places of the row are written. Three families
+keep one entry a position for the life of the request (a page every
+`page_size` places, in order: the defaults below); the fourth keeps a ring of
+window pages that is reused and summary pages that grow at a sixteenth of the
+rate. `serve/pages.py` reserves, grows and sizes from these two and names no
+family.
 
 A family may keep a store with one row a SLOT beside the page pool
 (`init_recurrent_store`: the hybrid block's recurrent state, the latent
@@ -27,7 +37,10 @@ it) and are re-exported here.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 from typing import Callable
+
+import numpy as np
 
 from llama_pipeline_parallel_tpu.models import tick_io
 from llama_pipeline_parallel_tpu.models.llama.decode import (  # noqa: F401
@@ -39,6 +52,19 @@ from llama_pipeline_parallel_tpu.models.llama.decode import (  # noqa: F401
 
 class UnsupportedForFamily(ValueError):
     """A serving feature this family's layers cannot run yet, named."""
+
+
+def row_table_width(cfg, max_len: int, page_size: int) -> int:
+    """A slot's row of the page table where every place of the logical row
+    keeps its entry: a page every `page_size` places of `max_len`."""
+    return max_len // page_size
+
+
+def row_table_columns(cfg, tokens: int, max_len: int,
+                      page_size: int) -> np.ndarray:
+    """The columns that hold pages once `tokens` places are written, for
+    such a row: the leading `ceil(tokens / page_size)`."""
+    return np.arange(-(-tokens // page_size))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +96,16 @@ class ServingFamily:
     kv_quants: tuple = ("fp",)
     # names of the int32 counters a tick returns under "counters", in order
     counters: tuple = ()
+    # what a slot's pages are. (cfg, max_len, page_size) -> entries of a
+    # slot's row of the page table; (cfg, tokens, max_len, page_size) -> the
+    # ascending columns of that row that hold pages once `tokens` places of
+    # the slot's logical row are written (a superset for more tokens: a
+    # request's worst-case demand is their count at its last write)
+    table_width: Callable = row_table_width
+    table_columns: Callable = row_table_columns
+    # why a shared prefix page cannot serve this family, where the reason is
+    # not the recurrent store's or a missing program's
+    prefix_cache_why: str = ""
 
     @property
     def recurrent(self) -> bool:
@@ -102,6 +138,8 @@ class ServingFamily:
                 "only: the slot's row at the divergence point is not kept, "
                 "and the span prefill that recomputes a tail cannot start "
                 "from it)" if self.recurrent else
+                f"prefix_cache ({self.prefix_cache_why})"
+                if self.prefix_cache_why else
                 "prefix_cache (the family has no span prefill, the program "
                 "that recomputes the tail of a prefix-cache hit)")
         if refused:
@@ -148,8 +186,26 @@ def _latent_moe(cfg) -> ServingFamily:
         counters=decode.counters(cfg))
 
 
+def _eva(cfg) -> ServingFamily:
+    from llama_pipeline_parallel_tpu.models.eva import decode, model
+
+    return ServingFamily(
+        name="eva", prefill_prompt=decode.prefill_prompt,
+        paged_decode_step=decode.paged_decode_step,
+        write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
+        init_params=model.init_params,
+        paged_prefill_chunk=decode.paged_prefill_chunk,
+        counters=decode.COUNTERS, table_width=decode.table_width,
+        table_columns=decode.table_columns,
+        prefix_cache_why=(
+            "a slot's window pages are a ring that is overwritten window "
+            "after window: a shared page would need the ring as it stood at "
+            "the divergence point, which is not kept, and the family has no "
+            "span prefill to recompute a tail from it"))
+
+
 _FAMILIES = {"llama": _llama, "hybrid_moe": _hybrid_moe,
-             "latent_moe": _latent_moe}
+             "latent_moe": _latent_moe, "eva": _eva}
 
 
 def family_of(cfg) -> ServingFamily:
@@ -161,12 +217,18 @@ def family_of(cfg) -> ServingFamily:
     return _FAMILIES[cfg.family](cfg)
 
 
+# families served in the dtype they are stored in: configuration class by
+# package under `models/`
+_STORED_DTYPE_CONFIGS = {"hybrid_moe": "HybridMoEConfig",
+                         "latent_moe": "LatentMoEConfig", "eva": "EvaConfig"}
+
+
 def config_from_meta(model_config: dict):
     """The configuration object a checkpoint's `meta.json` describes:
     `model_config["family"]` names its family (absent: the dense decoder).
-    The hybrid and the latent family keep the saved dtypes (they are served
-    in the dtype they are stored in); the dense one drops them, as its
-    loader always has."""
+    The hybrid, the latent and the compressed-window family keep the saved
+    dtypes (they are served in the dtype they are stored in); the dense one
+    drops them, as its loader always has."""
     mc = dict(model_config)
     name = mc.pop("family", "llama")
     if name == "llama":
@@ -174,17 +236,12 @@ def config_from_meta(model_config: dict):
 
         mc.pop("dtype", None), mc.pop("param_dtype", None)
         return LlamaConfig(**mc)
-    if name in ("hybrid_moe", "latent_moe"):
+    if name in _STORED_DTYPE_CONFIGS:
         import jax.numpy as jnp
 
-        if name == "hybrid_moe":
-            from llama_pipeline_parallel_tpu.models.hybrid_moe.config import (
-                HybridMoEConfig as config_class,
-            )
-        else:
-            from llama_pipeline_parallel_tpu.models.latent_moe.config import (
-                LatentMoEConfig as config_class,
-            )
+        config_class = getattr(importlib.import_module(
+            f"llama_pipeline_parallel_tpu.models.{name}.config"),
+            _STORED_DTYPE_CONFIGS[name])
         for key in ("dtype", "param_dtype"):
             if key in mc:
                 mc[key] = jnp.dtype(mc[key]).type

@@ -75,11 +75,13 @@ def _project_qkv(layer: Params, x: jnp.ndarray, cos: jnp.ndarray,
                  sin: jnp.ndarray, cfg: LlamaConfig):
     """Input norm, q/k/v projections and rope of one cached layer, as every
     decode and prefill program runs them: x [b, s, d] -> q [b, s, h, hd],
-    k/v [b, s, kv_h, hd]."""
+    k/v [b, s, kv_h, hd]. The normed rows enter the products in
+    `cfg.dtype` (no operation where `x` already is: every family but the one
+    with a float32 residual stream, models/eva/)."""
     b, s, _ = x.shape
     hd, dt = cfg.head_dim, cfg.dtype
     with jax.named_scope(trace.SCOPE_ATTN_QKV):
-        hidden = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+        hidden = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps).astype(dt)
         q = (hidden @ llama.cast_weight(layer["attn"]["wq"], dt)
              ).reshape(b, s, -1, hd)
         k = (hidden @ llama.cast_weight(layer["attn"]["wk"], dt)

@@ -172,7 +172,9 @@ def mlp_block(layer: Params, x: jnp.ndarray, cfg: LlamaConfig,
     dt = cfg.dtype
     residual = x
     with jax.named_scope(scope):
-        hidden = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
+        # in the compute dtype (no operation where `x` already is; a
+        # float32 residual stream, models/eva/, keeps `residual` as it is)
+        hidden = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps).astype(dt)
         if tp_axis is not None:
             from llama_pipeline_parallel_tpu.parallel.tp import tp_copy, tp_reduce
 

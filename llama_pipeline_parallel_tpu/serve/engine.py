@@ -184,20 +184,26 @@ class ServeConfig:
                         f"bucket {b} must be a multiple of "
                         f"prefill_chunk_tokens {self.prefill_chunk_tokens} "
                         f"(static chunk shapes)")
-        if self.num_pages is not None and \
-                self.num_pages < self.max_len // self.page_size:
-            raise ValueError(
-                f"num_pages {self.num_pages} cannot hold even one "
-                f"full-length request "
-                f"({self.max_len // self.page_size} pages)")
+        # whether `num_pages` holds one full-length request is the page
+        # manager's check: what such a request demands is its family's to
+        # say (serve/pages.py)
 
     @property
     def resolved_num_pages(self) -> int:
         """The pool size: as configured, or one `max_len` row a slot (the
-        logical tokens of a `[max_slots, max_len]` reservation)."""
+        logical tokens of a `[max_slots, max_len]` reservation) where the
+        family is not known; `pool_pages` asks the family."""
         if self.num_pages is not None:
             return self.num_pages
         return self.max_slots * self.max_len // self.page_size
+
+    def pool_pages(self, cfg) -> int:
+        """The pool size for a model: as configured, or one full-length
+        request a slot as the model's family states its demand."""
+        if self.num_pages is not None:
+            return self.num_pages
+        return self.max_slots * len(family_of(cfg).table_columns(
+            cfg, self.max_len, self.max_len, self.page_size))
 
 
 @dataclasses.dataclass
@@ -354,7 +360,7 @@ class ServeEngine:
         self._prefix = serve_cfg.prefix_cache
         self.slots = PagedKVCache(
             cfg, serve_cfg.max_slots, serve_cfg.max_len,
-            serve_cfg.page_size, serve_cfg.resolved_num_pages,
+            serve_cfg.page_size, serve_cfg.pool_pages(cfg),
             serve_cfg.kv_quant, prefix_cache=serve_cfg.prefix_cache)
         self.stats = SLOStats()
         self._metrics_writer = metrics_writer

@@ -5,7 +5,18 @@ A `[max_slots, max_len]` reservation would charge every slot one worst-case
 request whether it holds three tokens or three thousand. This manager backs
 those logical rows with PAGES from a shared pool (the family's
 `init_page_pool`), allocated ONCE, so resident HBM tracks tokens actually
-written:
+written.
+
+What a slot's pages ARE is the family's to state (models/family.py
+`table_width`, `table_columns`), and everything here follows from those two:
+the width of a slot's row of the page table, the columns of that row that
+hold pages once so many places of the logical row are written, and so a
+request's worst-case demand (their count at its last write) and the smallest
+pool (one full-length request's). A family that keeps an entry a position
+names a page every `page_size` places, in order (`page_demand` is that
+count); one that keeps a ring of window pages and pooled summary pages names
+the ring's columns once and two more a finished window (models/eva/). The
+manager names no family and has no branch on one:
 
 - `acquire()` hands out a free slot (lowest index first: deterministic for
   tests), `release(slot)` returns it at once with no device work. A freed
@@ -13,11 +24,12 @@ written:
   page; `assignments` keeps a (slot, request_id) history and `allocations`
   counts pool allocations (it stays 1 for the life of the engine): the
   slot-reuse proof the serving tests pin.
-- a request's **worst-case page demand** (`page_demand`) is reserved at
-  submit time — admission control, the backpressure signal the frontend
-  maps to HTTP 429 + Retry-After — but physical pages are allocated
-  LAZILY: prompt pages at admission, decode pages as `write_pos` crosses
-  each page boundary (`ensure_capacity`). Reservation <= pool is the
+- a request's **worst-case page demand** (`demand_pages`: the family's
+  columns at the request's last write) is reserved at submit time —
+  admission control, the backpressure signal the frontend maps to HTTP 429
+  + Retry-After — but physical pages are allocated LAZILY: prompt pages at
+  admission, decode pages as `write_pos` crosses into a place whose column
+  holds none yet (`ensure_capacity`). Reservation <= pool is the
   invariant that makes mid-decode allocation infallible: a request that
   was admitted can always finish.
 - `release` also returns the slot's pages to the free pool, resets its
@@ -25,8 +37,9 @@ written:
   every inactive slot scatters into while riding the static-shape decode
   step), and returns its reservation.
 - the device state is the pool + the logical `[max_slots, max_len]`
-  kv_mask; the page table itself stays HOST-side (numpy) and is shipped as
-  a small int32 array each tick — page residency changes never recompile
+  kv_mask (which a family whose reads are not a prefix of the row carries
+  untouched); the page table itself stays HOST-side (numpy) and is shipped
+  as a small int32 array each tick — page residency changes never recompile
   anything.
 
 With `prefix_cache=True` (docs/SERVING.md "Prefix caching") physical pages
@@ -73,13 +86,19 @@ import numpy as np
 from llama_pipeline_parallel_tpu.models.family import family_of
 
 
+def places_written(bucket: int, max_new_tokens: int) -> int:
+    """Places of its logical row a request can ever write: the prompt bucket
+    plus the decode writes (the budget's last token is emitted without a
+    cache write, so `max_new_tokens - 1` of them; a 1-token request writes
+    only its prompt)."""
+    return bucket + max(max_new_tokens - 1, 0)
+
+
 def page_demand(bucket: int, max_new_tokens: int, page_size: int) -> int:
-    """Worst-case pages a request can ever touch: the prompt bucket plus
-    the decode writes (the budget's last token is emitted without a cache
-    write, so `max_new_tokens - 1` of them; a 1-token request writes only
-    its prompt)."""
-    positions = bucket + max(max_new_tokens - 1, 0)
-    return -(-positions // page_size)
+    """Worst-case pages of a request whose family keeps an entry a place
+    (`family.row_table_columns`): a page every `page_size` places it can
+    write. A manager asks its own family (`PagedKVCache.demand_pages`)."""
+    return -(-places_written(bucket, max_new_tokens) // page_size)
 
 
 @functools.lru_cache(maxsize=64)
@@ -96,11 +115,14 @@ def _pool_leaf_bytes(cfg, num_pages: int, page_size: int, quant: str) -> int:
 
 def dense_kv_cache_bytes(cfg, max_slots: int,
                          max_len: int) -> int:
-    """Resident bytes of a `[max_slots, max_len]` reservation, one
-    worst-case row a slot: what a pool is sized against. A token's bytes
-    are the family's own page leaves' (a pool of one page of one token);
-    the store a family keeps a slot (`recurrent_store_bytes`) is not part
-    of either side of that comparison."""
+    """Resident bytes of a `[max_slots, max_len]` reservation with an entry
+    a PLACE, one worst-case row a slot: what a pool is sized against. An
+    entry's bytes are the family's own page leaves' (a pool of one page of
+    one entry); the store a family keeps a slot (`recurrent_store_bytes`)
+    is not part of either side of that comparison. For a family whose slot
+    demands fewer pages than places (`PagedKVCache.demand_pages` x
+    `page_bytes`) this is what the same rows would cost a cache that kept
+    every position."""
     return max_slots * max_len * _pool_leaf_bytes(cfg, 0, 1, "fp")
 
 
@@ -108,7 +130,8 @@ def paged_pool_bytes(cfg, num_pages: int, page_size: int,
                      quant: str = "fp") -> int:
     """Resident bytes of a page pool (garbage page and int8 scales
     included — the capacity comparison must not hide overheads), from the
-    family's own page leaves."""
+    family's own page leaves. How many pages a slot needs of it is the
+    family's to say (`PagedKVCache.demand_pages`)."""
     return _pool_leaf_bytes(cfg, num_pages, page_size, quant)
 
 
@@ -219,10 +242,6 @@ class PagedKVCache:
         if max_len % page_size:
             raise ValueError(f"max_len {max_len} must be a multiple of "
                              f"page_size {page_size}")
-        if num_pages < max_len // page_size:
-            raise ValueError(
-                f"num_pages {num_pages} cannot hold even one full-length "
-                f"request ({max_len // page_size} pages)")
         if quant not in ("fp", "int8"):
             raise ValueError(f"quant must be 'fp' or 'int8', got {quant!r}")
         self.cfg = cfg
@@ -232,13 +251,20 @@ class PagedKVCache:
         self.num_pages = num_pages
         self.quant = quant
         self.prefix_cache = prefix_cache
-        self.pages_per_slot = max_len // page_size
         self.garbage_page = num_pages
 
         # the configuration's family supplies the programs that run its
-        # layers over the device state (models/family.py); this manager
-        # names none
+        # layers over the device state and says what a slot's pages are
+        # (models/family.py); this manager names none
         self.family = family_of(cfg)
+        self.pages_per_slot = self.family.table_width(cfg, max_len, page_size)
+        # the family's columns by places written, in whole pages of places
+        # (they change at no finer grain), made as they are first asked for
+        self._columns: dict[int, np.ndarray] = {}
+        if num_pages < len(self._columns_at(max_len)):
+            raise ValueError(
+                f"num_pages {num_pages} cannot hold even one full-length "
+                f"request ({len(self._columns_at(max_len))} pages)")
         self.pool = self.family.init_page_pool(cfg, num_pages, page_size,
                                                quant)
         # the leaves with a page axis: what a copy-on-write fork copies, and
@@ -265,6 +291,9 @@ class PagedKVCache:
         self._free_slots = list(range(max_slots - 1, -1, -1))  # pop -> lowest
         self._free_pages = list(range(num_pages - 1, -1, -1))
         self._owned: dict[int, list[int]] = {}
+        # per slot, the pages of places (`ceil(tokens / page_size)`) its
+        # table is known to back: `ensure_capacity`'s way out before the lock
+        self._backed_to = [0] * max_slots
         self._slot_reserved: dict[int, int] = {}
         self._slot_reserved_total = 0  # sum of _slot_reserved (int reads are
         self._queued_reserved = 0      # race-safe for lock-free gauges;
@@ -368,8 +397,20 @@ class PagedKVCache:
             out["pages_cached"] = self.pages_cached
         return out
 
+    def _columns_at(self, tokens: int) -> np.ndarray:
+        """The family's table columns that hold pages once `tokens` places
+        of a slot's row are written."""
+        n = -(-tokens // self.page_size)
+        cols = self._columns.get(n)
+        if cols is None:
+            cols = self._columns[n] = np.asarray(self.family.table_columns(
+                self.cfg, n * self.page_size, self.max_len, self.page_size))
+        return cols
+
     def demand_pages(self, bucket: int, max_new_tokens: int) -> int:
-        return page_demand(bucket, max_new_tokens, self.page_size)
+        """Worst-case pages a request can ever hold: the family's columns
+        at its last write."""
+        return len(self._columns_at(places_written(bucket, max_new_tokens)))
 
     # -- reservation (admission control; any thread) -----------------------
 
@@ -644,29 +685,38 @@ class PagedKVCache:
             return slot
 
     def ensure_capacity(self, slot: int, tokens: int) -> int:
-        """Allocate physical pages until logical positions [0, tokens) are
-        backed; returns how many pages were newly allocated. Shared prefix
-        pages already back the row's front, so only the gap past them
-        allocates. Infallible for admitted requests (`tokens` within the
-        reservation + mapping); anything past it is a scheduler bug and
-        raises."""
+        """Allocate physical pages until logical places [0, tokens) of the
+        slot's row are backed, as the family says which columns of its table
+        that takes; returns how many pages were newly allocated. Shared
+        prefix pages already back the row's front, so only columns that
+        hold no page yet allocate. Infallible for admitted requests
+        (`tokens` within the reservation + mapping); anything past it is a
+        scheduler bug and raises."""
         need = -(-tokens // self.page_size)
+        if need <= self._backed_to[slot]:
+            return 0
+        if tokens > self.max_len:
+            raise RuntimeError(
+                f"slot {slot} asked for {tokens} places of a row of "
+                f"{self.max_len} — page accounting bug")
+        cols = self._columns_at(tokens)
         with self._lock:
             owned = self._owned[slot]
-            base = len(self._shared.get(slot, ()))
-            if need - base > self._slot_reserved[slot]:
+            row = self.page_table[slot]
+            todo = cols[row[cols] == self.garbage_page]
+            if len(owned) + len(todo) > self._slot_reserved[slot]:
                 raise RuntimeError(
-                    f"slot {slot} needs {need - base} new pages but "
-                    f"reserved only {self._slot_reserved[slot]} — page "
+                    f"slot {slot} needs {len(owned) + len(todo)} new pages "
+                    f"but reserved only {self._slot_reserved[slot]} — page "
                     f"accounting bug")
-            grew = 0
-            while base + len(owned) < need:
+            for col in todo.tolist():
                 page = self._alloc_page_locked()  # free, or evict-then-pop
-                self.page_table[slot, base + len(owned)] = page
+                row[col] = page
                 owned.append(page)
-                self._owned_total += 1
-                self.page_allocations += 1
-                grew += 1
+            grew = len(todo)
+            self._owned_total += grew
+            self.page_allocations += grew
+            self._backed_to[slot] = need
         if grew and self.alloc_listener is not None:
             self.alloc_listener(slot, grew)
         return grew
@@ -682,6 +732,7 @@ class PagedKVCache:
             self._owned_total -= len(freed)
             self._free_pages.sort(reverse=True)   # keep lowest-first reuse
             self.page_table[slot, :] = self.garbage_page
+            self._backed_to[slot] = 0
             self._slot_reserved_total -= self._slot_reserved.pop(slot, 0)
             self._free_slots.append(slot)
             self._free_slots.sort(reverse=True)
@@ -693,10 +744,9 @@ class PagedKVCache:
         bucket) into the slot's pages — the single-shot (bit-exact) path."""
         bucket = prefill_out["kv_mask"].shape[1]
         self.ensure_capacity(slot, bucket)
-        n = bucket // self.page_size
         self.pool, self.kv_mask = self.family.write_pages(
             self.pool, self.kv_mask, jnp.int32(slot),
-            jnp.asarray(self.page_table[slot, :n]),
+            jnp.asarray(self.page_table[slot, self._columns_at(bucket)]),
             prefill_out["cache"], prefill_out["kv_mask"])
 
     def reset_mask_row(self, slot: int) -> None:

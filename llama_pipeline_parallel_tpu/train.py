@@ -101,7 +101,8 @@ def build_model_config(node: dict) -> LlamaConfig:
         raise NotImplementedError(
             f"train.py cannot train the {family!r} family yet: no backward "
             f"pass through its layers (a chunked recurrence, a learned "
-            f"top-k selection, grouped expert products), and the pipeline's "
+            f"top-k selection, grouped expert products, a pooled read of "
+            f"chunk summaries), and the pipeline's "
             f"stage split assumes layers of one kind (ROADMAP B2 / B5); it "
             f"is served only (tools/serve.py)")
     if model_cfg is not None:

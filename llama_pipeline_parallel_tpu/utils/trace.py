@@ -151,10 +151,19 @@ LATENT_SCOPES = (MLA_PROJ, INDEX_PROJ, INDEX_SCORE, INDEX_TOPK, LATENT_WRITE,
                  LATENT_GATHER, SPARSE_ATTN, WINDOW_ATTN, RING_GATHER,
                  RING_WRITE, LATENT_READ, LATENT_READ_PREFILL)
 
+# models/eva/ (the dense programs' names above are reused for the same work:
+# projections, ring writes and gathers, feed-forward, head). A tuple of their
+# own for the same reason as HYBRID_SCOPES: a fourth vocabulary to merge.
+EVA_POOL = "eva_pool"                # a finished window's chunks -> pooled keys and values
+EVA_SUMMARY_WRITE = "eva_summary_write"  # pooled entries -> the slot's summary pages
+EVA_ATTN = "eva_attn"                # the tick: one softmax over summary and window pages
+EVA_ATTN_PREFILL = "eva_attn_prefill"  # a prefill's or a chunk's queries, both kinds of key
+EVA_SCOPES = (EVA_POOL, EVA_SUMMARY_WRITE, EVA_ATTN, EVA_ATTN_PREFILL)
+
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 
-# `name=` of the thirteen pallas_calls: the kernel's instruction in a trace is
+# `name=` of the fourteen pallas_calls: the kernel's instruction in a trace is
 # `<name>.<n>`
 KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
@@ -169,6 +178,7 @@ KERNEL_PAGED_DECODE_ATTN = "paged_decode_attn"   # under SCOPE_DECODE_ATTN
 KERNEL_SPARSE_LATENT_ATTN = "sparse_latent_attn"  # under SPARSE_ATTN
 KERNEL_PAGED_LATENT_DECODE_ATTN = "paged_latent_decode_attn"  # under LATENT_READ
 KERNEL_LATENT_PREFILL_ATTN = "latent_prefill_attn"  # under LATENT_READ_PREFILL
+KERNEL_EVA_PREFILL_ATTN = "eva_prefill_attn"  # under EVA_ATTN_PREFILL
 
 KERNELS = tuple(v for k, v in sorted(globals().items())
                 if k.startswith("KERNEL_"))

@@ -173,8 +173,9 @@ def test_the_dense_family_is_handed_the_functions_it_always_called():
 
 
 def test_a_family_states_only_what_depends_on_its_layers():
-    """The twelve fields, by name: a program that runs the layers, a store
-    shaped by them, or a fact about them. What touches only the mask or the
+    """The fifteen fields, by name: a program that runs the layers, a store
+    shaped by them, or a fact about them (since PR 36 what a slot's pages
+    are, and why a prefix cannot be shared). What touches only the mask or the
     pool's page axis is `serve/pages.py`'s own, and the manager reaches no
     such thing through the family."""
     import inspect
@@ -185,7 +186,8 @@ def test_a_family_states_only_what_depends_on_its_layers():
         "name", "prefill_prompt", "paged_decode_step", "write_pages",
         "init_page_pool", "init_recurrent_store", "init_params",
         "serving_weights", "paged_prefill_chunk", "paged_prefill_span",
-        "kv_quants", "counters"]
+        "kv_quants", "counters", "table_width", "table_columns",
+        "prefix_cache_why"]
     own = ("copy_page", "reset_kv_mask_row", "set_kv_mask_row")
     source = inspect.getsource(pages)
     for name in own:

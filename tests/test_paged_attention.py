@@ -31,6 +31,7 @@ from llama_pipeline_parallel_tpu.models.llama import decode
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.ops import (
+    eva_prefill_attention,
     latent_prefill_attention,
     paged_attention,
     paged_latent_attention,
@@ -197,7 +198,7 @@ def mosaic(monkeypatch):
     which is the CPU here whatever the program is compiled for."""
     monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
     for module in (sparse_latent_attention, paged_latent_attention,
-                   latent_prefill_attention):
+                   latent_prefill_attention, eva_prefill_attention):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without one
@@ -524,6 +525,85 @@ def test_a_program_of_one_kind_of_layer_compiled_for_the_chip_keeps_its_pages_in
             one_chip)
         compiled = latent_decode.paged_prefill_chunk.lower(*args, cfg).compile()
         kernel = "latent_prefill_attn"
+    analysis = compiled.memory_analysis()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    assert nbytes(pool) > 5 * nbytes(params)
+    assert analysis.alias_size_in_bytes >= nbytes(pool)
+    assert analysis.temp_size_in_bytes < nbytes(pool) // 4, analysis
+    assert kernel in compiled.as_text()
+
+
+# -- the compressed-window family, compiled for the same chip ---------------------
+
+def test_mosaic_compiles_the_two_kinds_prefill_kernel_at_the_bytes_cells_shapes(
+        one_chip, mosaic):
+    """A 2048-byte unit of 32 heads of 128 against the slot's 25 summary
+    pages (1,600 entries, padded to whole blocks inside), the ring as it
+    stood and its own keys: the scores stay in the kernel (nothing of [32,
+    2048, 5696] float32 exists)."""
+    T, width, n_sum, window = 2048, 4096, 1600, 2048
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    tag = lambda n: jax.ShapeDtypeStruct((1, n), jnp.int32)
+    args = _described(
+        (shape(1, T, width), shape(1, n_sum, width), shape(1, n_sum, width),
+         tag(n_sum), shape(1, window + T, width), shape(1, window + T, width),
+         tag(window + T), tag(T), tag(T)), one_chip)
+    compiled = jax.jit(
+        lambda q, ks, vs, ts, ke, ve, te, lo, hi:
+        eva_prefill_attention.eva_prefill_attention(
+            q, ks, vs, ts, ke, ve, te, lo, hi, 32, 128 ** -0.5)
+    ).lower(*args).compile()
+    text = compiled.as_text()
+    assert "eva_prefill_attn" in text and "tpu_custom_call" in text
+    # the summaries' padding to whole blocks: 448 rows of keys and of values
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_a_compressed_window_program_compiled_for_the_chip_keeps_its_pool_in_place(
+        one_chip, mosaic, program):
+    """EvaByte's widths (32 heads of 128, window 2048, chunk 16, pages of
+    64) at two layers and a small feed-forward, the pool many times the
+    weights: the outputs are the donated pool's buffers (through the tick's
+    `lax.cond` on whether a window finished too), nothing as large as a
+    quarter of the pool is made beside them, and the kernels are in the
+    programs: the dense family's paged kernel in the tick, the two-kinds
+    kernel in a unit."""
+    from llama_pipeline_parallel_tpu.models import tick_io
+    from llama_pipeline_parallel_tpu.models.eva import decode as eva_decode
+    from llama_pipeline_parallel_tpu.models.eva import model as eva
+    from llama_pipeline_parallel_tpu.models.eva.config import EvaConfig
+
+    slots, max_len, page, pages = 16, 25600, 64, 912
+    cfg = EvaConfig(num_hidden_layers=2, intermediate_size=1024)
+    width = eva_decode.table_width(cfg, max_len, page)
+    assert width == 57
+    params = jax.eval_shape(lambda: eva.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: eva_decode.init_page_pool(cfg, pages, page))
+    mask = jax.ShapeDtypeStruct((slots, max_len), jnp.int32)
+    if program == "tick":
+        args = _described(
+            (params, jax.ShapeDtypeStruct(
+                (slots, tick_io.COLUMNS + width), jnp.int32), pool, mask),
+            one_chip)
+        lowered = tick_io.packed(eva_decode.paged_decode_step).lower(*args, cfg)
+        assert lowered.out_info["fetch"].shape == (
+            3 * slots + len(eva_decode.COUNTERS),)
+        compiled = lowered.compile()
+        kernel = "paged_decode_attn"
+    else:
+        ids = jax.ShapeDtypeStruct((1, 2048), jnp.int32)
+        scalar = jax.ShapeDtypeStruct((), jnp.int32)
+        args = _described(
+            (params, ids, ids, ids, pool,
+             jax.ShapeDtypeStruct((width,), jnp.int32), scalar, mask, scalar),
+            one_chip)
+        compiled = eva_decode.paged_prefill_chunk.lower(*args, cfg).compile()
+        kernel = "eva_prefill_attn"
     analysis = compiled.memory_analysis()
 
     def nbytes(tree):

@@ -160,8 +160,10 @@ def test_paged_config_validation():
         # bucket 16 > chunk 12 but not a multiple: no static chunk shape
         ServeConfig(max_slots=2, max_len=32, prompt_buckets=(16,),
                     kv_cache="paged", page_size=4, prefill_chunk_tokens=12)
-    with pytest.raises(ValueError):
-        ServeConfig(**{**base, "num_pages": 3})         # < one full request
+    # whether a pool holds one full-length request is the page manager's
+    # check since PR 36 (what such a request demands is its family's to say)
+    with pytest.raises(ValueError, match="full-length request \\(4 pages\\)"):
+        PagedKVCache(LlamaConfig.tiny(), 2, 16, 4, 3)   # < one full request
     with pytest.raises(ValueError):
         ServeConfig(**{**base, "kv_quant": "int4"})
     with pytest.raises(ValueError):

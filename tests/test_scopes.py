@@ -17,6 +17,7 @@ from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.models.llama.manifest import StageManifest
 from llama_pipeline_parallel_tpu.ops import (
+    eva_prefill_attention,
     flash_attention,
     latent_prefill_attention,
     paged_attention,
@@ -192,6 +193,7 @@ def test_every_product_and_kernel_call_carries_a_leaf_scope(program, devices):
     (sparse_latent_attention, ("KERNEL_SPARSE_LATENT_ATTN",)),
     (paged_latent_attention, ("KERNEL_PAGED_LATENT_DECODE_ATTN",)),
     (latent_prefill_attention, ("KERNEL_LATENT_PREFILL_ATTN",)),
+    (eva_prefill_attention, ("KERNEL_EVA_PREFILL_ATTN",)),
 ])
 def test_every_pallas_call_passes_its_name(module, kernels):
     source = inspect.getsource(module)
@@ -200,7 +202,7 @@ def test_every_pallas_call_passes_its_name(module, kernels):
     for constant in kernels:
         assert source.count(f"name=trace.{constant},") == 1
         assert getattr(trace, constant) in trace.KERNELS
-    assert len(trace.KERNELS) == 13 == len(set(trace.KERNELS))
+    assert len(trace.KERNELS) == 14 == len(set(trace.KERNELS))
 
 
 def test_flash_kernel_name_reaches_the_lowered_program():

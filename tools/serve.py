@@ -227,7 +227,7 @@ def main(argv: list[str] | None = None) -> int:
                     "prompt_buckets": list(serve_cfg.prompt_buckets),
                     "kv_cache": "paged",
                     "page_size": serve_cfg.page_size,
-                    "num_pages": serve_cfg.resolved_num_pages,
+                    "num_pages": engine.slots.num_pages,
                     "kv_quant": serve_cfg.kv_quant,
                     "prefill_chunk_tokens": serve_cfg.prefill_chunk_tokens,
                     "prefix_cache": serve_cfg.prefix_cache}
@@ -248,7 +248,7 @@ def main(argv: list[str] | None = None) -> int:
 
     step_delay = float(os.environ.get("LPT_SERVE_STEP_DELAY_S", "0") or 0)
     kv_desc = (f"{serve_cfg.max_slots} slots over "
-               f"{serve_cfg.resolved_num_pages} x "
+               f"{engine.slots.num_pages} x "
                f"{serve_cfg.page_size}-token {serve_cfg.kv_quant} pages"
                + (f", prefill chunk {serve_cfg.prefill_chunk_tokens}"
                   if serve_cfg.prefill_chunk_tokens else "")
