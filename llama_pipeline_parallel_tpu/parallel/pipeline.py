@@ -20,11 +20,12 @@ Design (and why it is not a translation of DeepSpeed):
   activations hop to the next stage via `jax.lax.ppermute` over the ICI
   ring (the analogue of NCCL P2P send/recv):
   * "1f1b" (default) — the schedule DeepSpeed's engine runs: forward and
-    backward interleave in one scan with a hand-written per-stage `jax.vjp`
-    backward, bounding in-flight activations at min(2S-1, M) stage inputs.
+    backward interleave (F-only warmup, F+B steady, B-only drain scans)
+    with a hand-written per-stage `jax.vjp` backward, bounding in-flight
+    activations at min(2S-1, M) stage inputs.
   * "interleaved_1f1b" — Megatron-style virtual pipeline stages: each stage
     owns `virtual_stages` round-robin layer chunks, the activation laps the
-    ring v times per microbatch, and the flush bubble drops ~2vx
+    ring v times per microbatch, and the flush bubble drops ~vx
     (docs/SCHEDULES.md).
   * "zb1" — the interleaved clock with the backward DECOMPOSED into B
     (input-grad) and W (weight-grad) units, ZB-H1 / 2BP-style: B units
@@ -35,8 +36,8 @@ Design (and why it is not a translation of DeepSpeed):
   * "solver" — a loaded sequence file (preflight --select --emit-schedule):
     anything the validator accepts, including per-unit selective offload
     of the W residuals and reordered W placements.
-  The named three resolve to canonical generated sequences that replay the
-  deleted hand-written scans bit-exactly.
+  The named three resolve to canonical generated sequences: every live
+  unit runs on the tick the deleted hand-written scans ran it.
   * "gpipe" — forward-only scan; JAX autodiff yields the backward pipeline
     automatically (the transpose of `ppermute` is the reverse `ppermute`),
     at the cost of O(M) stored boundary activations. The one non-sequence
@@ -128,7 +129,7 @@ class PipelineConfig:
     # "interleaved_1f1b": the same hand-written backward, but each stage owns
     # `virtual_stages` round-robin layer chunks and the activation rides the
     # pp ring v times per microbatch — the flush bubble drops from
-    # 2(S-1) full-stage ticks to (S-1) chunk-tick pairs, ~2vx smaller
+    # (S-1) full-stage tick pairs to (S-1) chunk-tick pairs, ~vx smaller
     # (docs/SCHEDULES.md), at the cost of v x the ring hops and a ring
     # buffer of min(2vS-1, Mv) chunk inputs. Requires an even partition
     # with num_layers % (S*v) == 0 and microbatches-per-flush % S == 0.
@@ -357,10 +358,9 @@ def bubble_fraction(pcfg: PipelineConfig) -> float:
     across stages (in-jit scan: warmup/drain ticks take a full tick's wall
     time even where a stage's slot is masked):
 
-    - "1f1b": each flush scans m + 2(S-1) combined fwd+bwd ticks
-      (the canonical generated grid's num_ticks) of which m are useful
-      per stage
-      -> bubble = 2c(S-1) / (M + 2c(S-1)).
+    - "1f1b": interleaved_1f1b's sequence at v = 1 (one grid, two names:
+      of the m + 2(S-1) ticks only the m steady ones run both halves)
+      -> bubble = c(S-1) / (M + c(S-1)).
     - "interleaved_1f1b": each flush runs m*v chunk-sized units per stage
       (v = virtual_stages), phased as vS-1 forward-only warmup ticks +
       mv + S - 1 - (vS-1) combined ticks + vS-1 backward-only drain ticks
@@ -368,9 +368,8 @@ def bubble_fraction(pcfg: PipelineConfig) -> float:
       FORWARD and a drain tick one chunk BACKWARD, so the two phases pair
       into vS-1 full chunk ticks and the flush totals mv + S - 1 chunk-tick
       equivalents, mv useful -> bubble = c(S-1) / (Mv + c(S-1)) —
-      independent of the fwd/bwd cost split, ~2vx below flat 1f1b for
-      m >> S (the v from the shorter fill, the 2 from warmup/drain ticks no
-      longer paying the masked opposite half).
+      independent of the fwd/bwd cost split, ~vx below flat 1f1b for
+      m >> S (the shorter fill: a chunk is 1/v of a stage).
     - "zb1": the backward is SPLIT into B (input-grad) and W (weight-grad)
       units, so the cost split matters and the unit accounting goes to
       thirds: F = B = W = 1 unit (the zero-bubble family's symmetric-cost
@@ -1248,7 +1247,7 @@ def _pipeline_units_local(
     the three hand-written phase scans (flat 1f1b's one-scan
     warmup/steady/drain formulas, the interleaved three-phase clock, and
     zb1's fourth W-drain phase), which now exist only as canonical
-    sequences re-emitted by the generator and replayed here bit-exactly.
+    sequences re-emitted by the generator.
 
     Runs INSIDE shard_map; returns this shard's (normalized loss, grads)
     — the caller psums. How a sequence executes:
@@ -1258,19 +1257,19 @@ def _pipeline_units_local(
       `lax.scan` whose body contains exactly the active halves, with the
       per-tick [num_stages] unit-index rows as the scan's xs and this
       stage's entry selected by `jnp.take(row, stage)`. The canonical
-      sequences reproduce the deleted scans' phase structure exactly:
-      flat = one F+B segment (every tick both halves, warmup/drain slots
-      masked), interleaved = F-only warmup / F+B steady / B-only drain,
-      zb1 = those plus a trailing W-only segment.
+      sequences: flat and interleaved = F-only warmup / F+B steady /
+      B-only drain (a half that is -1 on EVERY stage of a tick is in no
+      body), zb1 = those plus a trailing W-only segment.
     - An idle (-1) slot is masked, not skipped: the forward computes a
       clipped unit and the predicated buffer write discards it; the
       backward seeds zero cotangents through the linear vjp; the W replay
       seeds zeros. Masked work costs a full tick slot (the lockstep-scan
       model schedule.bubble_stats charges) but contributes EXACTLY zero
       to every accumulator — which is why an interpreter run is
-      bit-identical to the old scans: the same live units fold in the
-      same order with the same masking, regardless of what masked compute
-      surrounds them.
+      bit-identical to the old scans, and why dropping a half that is
+      masked on every stage (flat 1f1b, PR 38) changes no bit: the same
+      live units fold in the same order with the same masking, regardless
+      of what masked compute surrounds them.
     - F units: chunk forward (embed cond-gated on (stage 0, chunk 0)),
       buffering the received stage input in the `ring_slots` ring for the
       later backward recompute. B units: the backward — fused schedules

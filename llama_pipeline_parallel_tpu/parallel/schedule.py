@@ -6,9 +6,10 @@ This module is the representation half of the OptPipe-style refactor
 grid of typed units that `parallel/pipeline.py`'s ONE interpreter executes
 inside the existing shard_map. The three hand-written schedules
 (flat 1f1b, interleaved 1f1b, zb1) are re-emitted here as canonical
-sequences by `canonical_schedule`, bit-exact against their deleted
-implementations because the generators reproduce the exact unit-index
-formulas the old scans computed per tick.
+sequences by `canonical_schedule`: the generators reproduce the exact
+unit-index formulas the old scans computed per tick, so every live unit
+runs on the tick it always did. Flat 1f1b and interleaved 1f1b at v=1 are
+one sequence under two names (`generate_1f1b`).
 
 Vocabulary (one scheduling unit = one (microbatch, virtual-chunk) pair
 passing through one stage):
@@ -36,9 +37,9 @@ load-bearing for both cost and bit-exactness: the lockstep scan charges
 every stage the full cost of each structurally present half (a masked slot
 computes garbage and discards it — the honest cost model `bubble_stats`
 counts), and consecutive ticks with equal flags compile into one
-`lax.scan` (so the canonical sequences reproduce the deleted phase-scan
-structure exactly: flat = one F+B scan, interleaved = warmup/steady/drain,
-zb1 = those plus the W drain).
+`lax.scan` (flat and interleaved = F-only warmup / F+B steady / B-only
+drain, zb1 = those plus the W drain: a half that is -1 on EVERY stage of a
+tick is not structurally present, so no chip computes it).
 
 Everything here is numpy/stdlib — no jax import — so tools/preflight.py
 can generate, validate, score, and serialize schedules without compiling
@@ -174,42 +175,31 @@ def _norm_costs(stage_costs, s: int):
 
 
 def generate_1f1b(m: int, s: int, stage_costs=None) -> UnitSchedule:
-    """The flat 1F1B grid the deleted `_pipeline_1f1b_local` scanned: one
-    segment of m + 2(S-1) ticks, EVERY tick structurally F+B with both
-    ring directions (warmup/drain slots are -1 = masked, exactly as the
-    old single scan masked them), forward unit t-s / backward unit
-    t-(2S-2-s). At S=1 the forward half never existed (the fused backward
-    re-embeds under its stage-0 cond), so the grid is B-only."""
-    costs = _norm_costs(stage_costs, s)
-    if s == 1:
-        f, b, w = _grids(m, 1)
-        b[:, 0] = np.arange(m)
-        t = np.zeros(m, bool)
-        return UnitSchedule(
-            num_stages=1, virtual_stages=1, num_microbatches=m,
-            split_backward=False, f_unit=f, b_unit=b, w_unit=w,
-            has_f=t.copy(), has_b=~t, has_w=t.copy(),
-            ring_fwd=t.copy(), ring_bwd=t.copy(), ring_slots=1,
-            offload_units=np.zeros(0, bool), wq_slot=np.zeros(0, np.int32),
-            wq_hbm_slots=0, wq_host_slots=0, label="1f1b",
-            stage_costs=costs)
-    num_ticks = m + 2 * (s - 1)
-    f, b, w = _grids(num_ticks, s)
-    t_idx = np.arange(num_ticks)[:, None]
-    st = np.arange(s)[None, :]
-    fu = t_idx - st
-    bu = t_idx - (2 * (s - 1) - st)
-    f[:] = np.where((fu >= 0) & (fu < m), fu, -1)
-    b[:] = np.where((bu >= 0) & (bu < m), bu, -1)
-    on = np.ones(num_ticks, bool)
+    """Flat 1F1B: forward unit t-s, backward unit t-(2S-2-s) over
+    m + 2(S-1) ticks, with the per-tick flags read off that grid — a half
+    is structurally present on a tick only if SOME stage has a unit in it.
+    That is `generate_interleaved` at v=1 under this label: S-1 F-only
+    warmup ticks (forward ring only), m F+B ticks, S-1 B-only drain ticks
+    (backward ring only). Running both halves on every tick would compute
+    S-1 backward and S-1 forward halves a flush in which every stage's
+    slot is -1: full-price work folding exact zeros
+    (tests/test_unit_schedule.py pins the bit-equality). At S=1 the forward
+    half never existed (the fused backward re-embeds under its stage-0
+    cond), so the grid is B-only."""
+    if s > 1:
+        return generate_interleaved(m, s, 1, label="1f1b",
+                                    stage_costs=stage_costs)
+    f, b, w = _grids(m, 1)
+    b[:, 0] = np.arange(m)
+    t = np.zeros(m, bool)
     return UnitSchedule(
-        num_stages=s, virtual_stages=1, num_microbatches=m,
+        num_stages=1, virtual_stages=1, num_microbatches=m,
         split_backward=False, f_unit=f, b_unit=b, w_unit=w,
-        has_f=on.copy(), has_b=on.copy(), has_w=np.zeros(num_ticks, bool),
-        ring_fwd=on.copy(), ring_bwd=on.copy(),
-        ring_slots=min(2 * s - 1, m),
+        has_f=t.copy(), has_b=~t, has_w=t.copy(),
+        ring_fwd=t.copy(), ring_bwd=t.copy(), ring_slots=1,
         offload_units=np.zeros(0, bool), wq_slot=np.zeros(0, np.int32),
-        wq_hbm_slots=0, wq_host_slots=0, label="1f1b", stage_costs=costs)
+        wq_hbm_slots=0, wq_host_slots=0, label="1f1b",
+        stage_costs=_norm_costs(stage_costs, 1))
 
 
 def generate_interleaved(m: int, s: int, v: int = 1,

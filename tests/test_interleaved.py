@@ -359,12 +359,12 @@ def _pcfg(schedule, s, m, c=1, v=1):
 
 
 @pytest.mark.parametrize("schedule,s,m,c,v,expected", [
-    # flat 1f1b: 2c(S-1) / (M + 2c(S-1))
-    ("1f1b", 4, 8, 1, 1, 6 / 14),
-    ("1f1b", 8, 256, 1, 1, 14 / 270),
-    ("1f1b", 4, 8, 2, 1, 12 / 20),
+    # flat 1f1b (= interleaved at v=1 since PR 38): c(S-1) / (M + c(S-1))
+    ("1f1b", 4, 8, 1, 1, 3 / 11),
+    ("1f1b", 8, 256, 1, 1, 7 / 263),
+    ("1f1b", 4, 8, 2, 1, 6 / 14),
     # m per flush == 1 (m == accum_chunks): every flush is pure fill+drain
-    ("1f1b", 4, 4, 4, 1, 24 / 28),
+    ("1f1b", 4, 4, 4, 1, 12 / 16),
     # gpipe: c(S-1) / (M + c(S-1))
     ("gpipe", 4, 8, 1, 1, 3 / 11),
     ("gpipe", 4, 8, 4, 1, 12 / 20),
@@ -398,17 +398,22 @@ def test_bubble_fraction_grid(schedule, s, m, c, v, expected):
 
 
 def test_bubble_fraction_interleaved_reduction():
-    """The acceptance claim: at the same (S, m), interleaving with v chunks
-    cuts the reported bubble by >= v (measured ~2v for m >> S: v from the
-    shorter fill, 2 from the fwd-only/bwd-only phase pairing)."""
+    """At the same (S, m), interleaving with v chunks cuts the reported
+    bubble by (Mv + S-1) / (M + S-1): just under v, ~v for m >> S (the
+    shorter fill). The second factor of 2 this test once claimed was flat
+    1f1b's own masked halves, which flat no longer runs (PR 38): at v=1
+    the two schedules are one sequence and report one number."""
     for s, m in [(2, 4), (4, 8), (8, 256)]:
         flat = pl.bubble_fraction(_pcfg("1f1b", s, m))
+        assert flat == pl.bubble_fraction(_pcfg("interleaved_1f1b", s, m))
         for v in (2, 4):
             if m % s:
                 continue
             inter = pl.bubble_fraction(
                 _pcfg("interleaved_1f1b", s, m, v=v))
-            assert inter <= flat / v, (s, m, v, flat, inter)
+            assert flat / v < inter < flat, (s, m, v, flat, inter)
+            assert flat / inter == pytest.approx(
+                (m * v + s - 1) / (m + s - 1)), (s, m, v, flat, inter)
 
 
 def test_bubble_fraction_monotone_in_v():

@@ -215,13 +215,15 @@ def test_flash_kernel_name_reaches_the_lowered_program():
 # -- (c) the schedule counter ------------------------------------------------
 
 def test_slot_counts_of_1f1b_pp4_m16_are_the_tables():
-    """22 ticks, every tick an F and a B slot on every stage; stage s idles
-    s warm-up F slots and 3 - s drain F slots, and the mirror for B: 6 of
-    22 masked each, on every stage."""
+    """22 ticks: 3 F-only, 16 F+B, 3 B-only (PR 38: the halves every stage
+    masked are in no scan). F is scanned in the first 19 ticks, where stage
+    s idles s slots before its first unit and 3 - s after its last, and the
+    mirror for B in the last 19: 3 of 19 masked each, on every stage (6 of
+    22 when every tick ran both halves)."""
     counts = pl.schedule_slot_counts(
         pl.PipelineConfig(num_stages=4, num_microbatches=16))
-    assert counts == [{"stage": s, "f": 22, "f_masked": 6, "b": 22,
-                       "b_masked": 6, "w": 0, "w_masked": 0}
+    assert counts == [{"stage": s, "f": 19, "f_masked": 3, "b": 19,
+                       "b_masked": 3, "w": 0, "w_masked": 0}
                       for s in range(4)]
 
 
@@ -239,8 +241,10 @@ def test_slot_counts_of_zb1_count_w_slots_and_skipped_halves():
 def test_slot_counts_scale_with_flushes_and_gpipe_has_none():
     chunked = pl.schedule_slot_counts(pl.PipelineConfig(
         num_stages=4, num_microbatches=16, accum_chunks=2))
-    # two flushes of 8 micro-batches: 14 ticks each, 6 masked each
-    assert chunked[0]["f"] == 28 and chunked[0]["f_masked"] == 12
+    # two flushes of 8 micro-batches: 11 F and 11 B slots of 14 ticks each,
+    # 3 of each masked, on every stage
+    assert [(c["f"], c["f_masked"], c["b"], c["b_masked"])
+            for c in chunked] == [(22, 6, 22, 6)] * 4
     assert pl.schedule_slot_counts(pl.PipelineConfig(
         num_stages=4, num_microbatches=16, schedule="gpipe")) is None
 

@@ -42,7 +42,8 @@ def test_segments_labels_and_grouping():
     for a, b in zip(segs, segs[1:]):
         assert a.t1 == b.t0
     flat = usched.segments(usched.canonical_schedule("1f1b", 8, 4))
-    assert [s.label for s in flat] == ["F+B"]
+    assert [s.label for s in flat] == ["F", "F+B", "B"]
+    assert [s.num_ticks for s in flat] == [3, 8, 3]
     drain_w = usched.segments(usched.list_schedule(8, 2, 2,
                                                    w_placement="drain"))
     assert "B+W" in [s.label for s in drain_w]

@@ -162,12 +162,13 @@ def test_device_peak_bytes_always_reports(devices):
 def test_bubble_fraction_hand_computed():
     mk = lambda **kw: PipelineConfig(**{"num_stages": 4, "num_microbatches": 8,
                                         **kw})
-    # 1f1b: 2c(S-1) / (M + 2c(S-1)) = 6 / 14
-    assert bubble_fraction(mk()) == pytest.approx(6 / 14)
+    # 1f1b: c(S-1) / (M + c(S-1)) = 3 / 11 (6 / 14 until PR 38: the
+    # warmup and drain ticks no longer run the half every stage masks)
+    assert bubble_fraction(mk()) == pytest.approx(3 / 11)
     # gpipe: c(S-1) / (M + c(S-1)) = 3 / 11
     assert bubble_fraction(mk(schedule="gpipe")) == pytest.approx(3 / 11)
-    # chunks multiply the flush bubble: c=2 -> 12 / 20 and 6 / 14
-    assert bubble_fraction(mk(accum_chunks=2)) == pytest.approx(12 / 20)
+    # chunks multiply the flush bubble: c=2 -> 6 / 14 for both
+    assert bubble_fraction(mk(accum_chunks=2)) == pytest.approx(6 / 14)
     assert bubble_fraction(mk(schedule="gpipe", accum_chunks=2)) \
         == pytest.approx(6 / 14)
     # no pipeline, no bubble; more microbatches amortize it monotonically
@@ -254,7 +255,7 @@ def test_trainer_emits_observability_surface(tmp_path, devices):
     for line in [json.loads(l) for l in open(out / "metrics.jsonl")]:
         assert 0.0 <= line["goodput"] <= 1.0
         assert line["device_peak_bytes"] > 0
-        assert line["bubble_fraction"] == pytest.approx(2 / 4)  # S=2, M=2
+        assert line["bubble_fraction"] == pytest.approx(1 / 3)  # S=2, M=2
 
     health = json.load(open(out / "health.json"))
     assert health["last_step"] == 4

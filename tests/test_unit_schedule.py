@@ -51,13 +51,18 @@ def make_batch(cfg, batch_size=8, seqlen=16, seed=42):
 
 
 def run_schedule(params, batch, cfg, pp, schedule, v=1, microbatches=4,
-                 chunks=1, seq=None):
+                 chunks=1, seq=None, counts=None):
     mesh = make_mesh(MeshConfig(pp=pp))
-    manifest = StageManifest.for_config(cfg, pp, virtual_stages=v)
+    if counts is None:
+        manifest = StageManifest.for_config(cfg, pp, virtual_stages=v)
+    else:
+        manifest = StageManifest(num_layers=cfg.num_hidden_layers,
+                                 num_stages=pp, layer_counts=tuple(counts))
     stacked = pl.stack_stages(params, manifest)
     pcfg = pl.PipelineConfig(num_stages=pp, num_microbatches=microbatches,
                              schedule=schedule, virtual_stages=v,
-                             accum_chunks=chunks, unit_schedule=seq)
+                             accum_chunks=chunks, unit_schedule=seq,
+                             layer_counts=counts)
     fn = jax.jit(pl.make_pipeline_loss_and_grad(mesh, cfg, pcfg, stacked))
     out = fn(stacked, batch)
     return out[0], pl.unstack_stages(out[1], manifest)
@@ -73,9 +78,10 @@ def assert_tree_bitexact(a, b):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("schedule,m,s,v,closed", [
-    ("1f1b", 4, 2, 1, 2 * 1 / (4 + 2 * 1)),
-    ("1f1b", 8, 4, 1, 2 * 3 / (8 + 2 * 3)),
-    ("1f1b", 1, 4, 1, 6 / 7),
+    # flat counts as interleaved at v=1 since PR 38: c(S-1) / (M + c(S-1))
+    ("1f1b", 4, 2, 1, 1 / (4 + 1)),
+    ("1f1b", 8, 4, 1, 3 / (8 + 3)),
+    ("1f1b", 1, 4, 1, 3 / 4),
     ("interleaved_1f1b", 4, 2, 2, 1 / (8 + 1)),
     ("interleaved_1f1b", 8, 4, 2, 3 / (16 + 3)),
     ("interleaved_1f1b", 1, 4, 1, 3 / 4),
@@ -124,6 +130,81 @@ def test_flat_s1_degenerate_sequence():
     us.validate(seq)
     assert not seq.has_f.any() and seq.num_ticks == 4
     assert us.analytic_bubble(seq) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# Flat 1f1b = interleaved at v=1 (PR 38): no half that every stage masks
+# ---------------------------------------------------------------------------
+
+FLAT_SHAPES = [(16, 4), (8, 4), (4, 2), (1, 4), (3, 4)]
+
+
+def all_fb_flat_grid(m, s, stage_costs=None):
+    """The flat 1f1b sequence as it stood until PR 38, written out by hand:
+    forward unit t-s and backward unit t-(2S-2-s) over m + 2(S-1) ticks,
+    EVERY tick structurally F+B with both ring directions."""
+    ticks = m + 2 * (s - 1)
+    t = np.arange(ticks)[:, None]
+    st = np.arange(s)[None, :]
+    fu, bu = t - st, t - (2 * (s - 1) - st)
+    on = np.ones(ticks, bool)
+    return us.UnitSchedule(
+        num_stages=s, virtual_stages=1, num_microbatches=m,
+        split_backward=False,
+        f_unit=np.where((fu >= 0) & (fu < m), fu, -1).astype(np.int32),
+        b_unit=np.where((bu >= 0) & (bu < m), bu, -1).astype(np.int32),
+        w_unit=np.full((ticks, s), -1, np.int32),
+        has_f=on, has_b=on.copy(), has_w=np.zeros(ticks, bool),
+        ring_fwd=on.copy(), ring_bwd=on.copy(), ring_slots=min(2 * s - 1, m),
+        offload_units=np.zeros(0, bool), wq_slot=np.zeros(0, np.int32),
+        wq_hbm_slots=0, wq_host_slots=0, label="flat/all-F+B",
+        stage_costs=stage_costs)
+
+
+@pytest.mark.parametrize("m,s", FLAT_SHAPES)
+def test_flat_is_interleaved_at_v1(m, s):
+    """One sequence under two names: grids, per-tick flags, ring depth and
+    the per-segment accounting are equal element for element, and the
+    grids are the old all-F+B grid's (the units never moved)."""
+    flat = us.canonical_schedule("1f1b", m, s)
+    inter = us.canonical_schedule("interleaved_1f1b", m, s, 1)
+    us.validate(flat)
+    for name in ("f_unit", "b_unit", "w_unit", "has_f", "has_b", "has_w",
+                 "ring_fwd", "ring_bwd"):
+        np.testing.assert_array_equal(getattr(flat, name),
+                                      getattr(inter, name), err_msg=name)
+    assert flat.ring_slots == inter.ring_slots
+    assert flat.label == "1f1b" and inter.label == "interleaved_1f1b"
+    assert us.segment_stats(flat) == us.segment_stats(inter)
+    old = all_fb_flat_grid(m, s)
+    us.validate(old)
+    np.testing.assert_array_equal(flat.f_unit, old.f_unit)
+    np.testing.assert_array_equal(flat.b_unit, old.b_unit)
+    assert flat.ring_slots == old.ring_slots
+    # the flags are the grid's own: a half is present iff some stage runs it
+    np.testing.assert_array_equal(flat.has_f, (flat.f_unit >= 0).any(axis=1))
+    np.testing.assert_array_equal(flat.has_b, (flat.b_unit >= 0).any(axis=1))
+    assert [(g.label, g.num_ticks) for g in us.segments(flat)] == [
+        ("F", s - 1), ("F+B", m), ("B", s - 1)]
+
+
+@pytest.mark.parametrize("pp,chunks,counts", [
+    (2, 1, None), (2, 2, None), (4, 1, None), (4, 2, None), (2, 1, (5, 3)),
+])
+def test_flat_bitexact_vs_all_fb_grid(cfg, params, devices, pp, chunks,
+                                      counts):
+    """The removed halves added exact zeros: loss and every gradient leaf
+    of `1f1b` are bit-equal to the old all-F+B grid replayed under
+    `schedule: solver` — per flush under accum_chunks, and on an unequal
+    partition (padded-slot skipping included)."""
+    batch = make_batch(cfg)
+    seq = all_fb_flat_grid(4 // chunks, pp, stage_costs=counts)
+    l_new, g_new = run_schedule(params, batch, cfg, pp, "1f1b",
+                                chunks=chunks, counts=counts)
+    l_old, g_old = run_schedule(params, batch, cfg, pp, "solver",
+                                chunks=chunks, seq=seq, counts=counts)
+    np.testing.assert_array_equal(np.asarray(l_new), np.asarray(l_old))
+    assert_tree_bitexact(g_new, g_old)
 
 
 # ---------------------------------------------------------------------------
@@ -425,15 +506,16 @@ def test_validator_rejects_degenerate_slot_metadata():
 
 def test_stage_costs_bubble_weighting_by_hand():
     """The costed accounting at a shape small enough to count by hand:
-    flat fused 1f1b, m=4, S=2, costs (2,1). Every one of the 6 ticks is
-    structurally F+B, wall per stage = (6*1 + 6*2) * cmax(2) = 36, total
-    72; useful = F (4 units * cost per stage: 4*2 + 4*1 = 12) + B (twice
-    that, fused cost 2) = 36 -> bubble 1/2, vs the even 1/3."""
+    flat fused 1f1b, m=4, S=2, costs (2,1). Of the 6 ticks one is F-only,
+    four F+B and one B-only: 5 F halves + 5 B halves, wall per stage =
+    (5*1 + 5*2) * cmax(2) = 30, total 60; useful = F (4 units * cost per
+    stage: 4*2 + 4*1 = 12) + B (twice that, fused cost 2) = 36 -> bubble
+    24/60 = 2/5, vs the even 1/5."""
     seq = us.generate_1f1b(4, 2, stage_costs=(2, 1))
     idle, wall = us.bubble_stats(seq)
-    assert (idle, wall) == (36, 72)
-    assert us.analytic_bubble(seq) == 0.5
-    assert us.analytic_bubble(us.generate_1f1b(4, 2)) == pytest.approx(1 / 3)
+    assert (idle, wall) == (24, 60)
+    assert us.analytic_bubble(seq) == 0.4
+    assert us.analytic_bubble(us.generate_1f1b(4, 2)) == pytest.approx(1 / 5)
 
 
 def test_uniform_stage_costs_bit_identical_to_uncosted():

@@ -271,14 +271,15 @@ def test_bubble_fraction_zb1_derivation_at_65b_shape():
         total 1550 units, useful 3*Mv = 1536
         -> bubble = 2(S-1) / (3Mv + 2(S-1)) = 14/1550
 
-    strictly below interleaved's 7/519 (~1.35%) and flat's 14/270 (5.19%)
+    strictly below interleaved's 7/519 (~1.35%) and flat's 7/263 (2.66%;
+    14/270 until PR 38 took the all-masked halves out of flat's flush)
     — the acceptance number of this PR."""
     zb = pl.bubble_fraction(_pcfg("zb1", 8, 256, v=2))
     inter = pl.bubble_fraction(_pcfg("interleaved_1f1b", 8, 256, v=2))
     flat = pl.bubble_fraction(_pcfg("1f1b", 8, 256))
     assert zb == pytest.approx(14 / 1550)
     assert inter == pytest.approx(7 / 519)
-    assert flat == pytest.approx(14 / 270)
+    assert flat == pytest.approx(7 / 263)
     assert zb < inter < flat
 
 
@@ -319,9 +320,12 @@ def test_bubble_fraction_ordering_zb1_interleaved_flat():
             assert zb == inter == flat == 0.0
         else:
             assert zb < inter, (s, m, c, v, zb, inter)
-            assert inter <= flat, (s, m, c, v, inter, flat)
-            # interleaved < flat needs v > 1 OR the warmup/drain pairing;
-            # both formulas agree only in the no-pipeline limit
+            # an EQUALITY at v = 1, where flat and interleaved are one
+            # sequence under two names (PR 38); strict for v > 1
+            if v == 1:
+                assert inter == flat, (s, m, c, v, inter, flat)
+            else:
+                assert inter < flat, (s, m, c, v, inter, flat)
             assert 0.0 < zb < 1.0
 
 
