@@ -114,8 +114,10 @@ class ServingFamily:
     @property
     def decode_tick(self) -> Callable:
         """The tick as an engine runs it: `paged_decode_step`'s body behind
-        one staged buffer in and one fetched vector out (`models/tick_io.py`),
-        the same jitted program for every engine of the family."""
+        one staged buffer in and one fetched vector out, a row's token and
+        key fed back from the tick before on the device
+        (`models/tick_io.py`), the same jitted program for every engine of
+        the family."""
         return tick_io.packed(self.paged_decode_step)
 
     def check_serve_config(self, kv_quant: str, prefill_chunk_tokens: int,

@@ -8,7 +8,7 @@ COUNT of its transfers and not for their bytes. `stage` lays every array
 `paged_decode_step` takes from the host side by side, a row a slot:
 
     [S, COLUMNS + pages_per_slot] int32
-    token | pos | write_pos | active | top_k | key word 0 | key word 1 |
+    token | pos | write_pos | active | top_k | fed | key word 0 | key word 1 |
     temperature | top_p | the slot's page-table row
 
 The `uint32` keys and the `float32` knobs are VIEWS of their columns on the
@@ -18,6 +18,14 @@ thirteen-argument `paged_decode_step`, the program the engine runs: the same
 body between `unpack` and `pack_result`, under the same name (the trace's
 `jit(paged_decode_step)`, which the benchmark's readers find the tick by).
 Written once for every family: they share the argument list to the letter.
+
+The engine keeps one tick in flight (`serve/engine.py`): it stages and
+enqueues tick k before it has read tick k-1's result. Everything it stages it
+knows a tick ahead but a row's `token` and `keys`, which ARE that result. So
+the program takes the previous tick's fetched vector beside the buffer, as
+the device array it still is, and a row whose `fed` column is set reads its
+token and key words from there (one `where` in `unpack`); a row that joined
+from a prefill since reads the buffer's, which the host filled.
 """
 
 from __future__ import annotations
@@ -29,9 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# the columns in front of a slot's page-table row: the five int32 fields in
+# the columns in front of a slot's page-table row: the six int32 fields in
 # `Staged`'s order, the key's two words, the two float32 knobs
-_INTS = 5
+_INTS = 6
 _KEYS = slice(_INTS, _INTS + 2)
 _TEMPERATURE, _TOP_P = _KEYS.stop, _KEYS.stop + 1
 COLUMNS = _TOP_P + 1
@@ -42,11 +50,12 @@ class Staged(NamedTuple):
     through (each a view of `buffer`: a write to one is a write to it)."""
 
     buffer: np.ndarray          # [S, COLUMNS + pages_per_slot] int32
-    token: np.ndarray           # [S] int32, and the four after it
+    token: np.ndarray           # [S] int32, and the five after it
     pos: np.ndarray
     write_pos: np.ndarray
     active: np.ndarray
     top_k: np.ndarray
+    fed: np.ndarray             # 1: token and keys are the previous tick's
     keys: np.ndarray            # [S, 2] uint32
     temperature: np.ndarray     # [S] float32
     top_p: np.ndarray           # [S] float32
@@ -69,16 +78,23 @@ def stage(slots: int, pages_per_slot: int) -> Staged:
         page_table=buffer[:, COLUMNS:])
 
 
-def unpack(staged: jnp.ndarray) -> tuple:
+def unpack(staged: jnp.ndarray, prev: jnp.ndarray) -> tuple:
     """In the program: the staged buffer back to the nine arrays, in the
     order `paged_decode_step` takes them (its `pool` and `kv_mask` come
     beside them, donated): token, page_table, pos, write_pos, active, keys,
-    temperature, top_k, top_p."""
-    token, pos, write_pos, active, top_k = (
+    temperature, top_k, top_p. `prev` is the previous tick's `pack_result`
+    (any vector of its shape where no row is `fed`): a fed row's token and
+    key words are that tick's, bit for bit, never seen by the host."""
+    token, pos, write_pos, active, top_k, fed = (
         staged[:, j] for j in range(_INTS))
+    slots = staged.shape[0]
+    fed = fed != 0
+    token = jnp.where(fed, prev[:slots], token)
+    key_words = jnp.where(fed[:, None], prev[slots:3 * slots].reshape(slots, 2),
+                          staged[:, _KEYS])
     bits = jax.lax.bitcast_convert_type
     return (token, staged[:, COLUMNS:], pos, write_pos, active,
-            bits(staged[:, _KEYS], jnp.uint32),
+            bits(key_words, jnp.uint32),
             bits(staged[:, _TEMPERATURE], jnp.float32), top_k,
             bits(staged[:, _TOP_P], jnp.float32))
 
@@ -106,18 +122,19 @@ def split_result(fetched: np.ndarray, slots: int) -> tuple:
 @functools.cache
 def packed(step):
     """The program an engine runs a tick with, made from a family's jitted
-    thirteen-argument `step`: (params, staged, pool, kv_mask, cfg) ->
+    thirteen-argument `step`: (params, staged, prev, pool, kv_mask, cfg) ->
     {"fetch": `pack_result` of the tick's token, keys and counters, "pool",
-    "kv_mask"}. It traces `step`'s own body (`__wrapped__`, no nested jit:
+    "kv_mask"}; `prev` is the tick before's "fetch" (`unpack`), which is not
+    donated: the host reads it after this call. It traces `step`'s own body (`__wrapped__`, no nested jit:
     the operations keep their paths under `jit(paged_decode_step)`) and
     returns only what the engine reads: a family's further outputs, such as
     the latent tick's `selection`, are not computed for it. One jitted
     program a `step`, whoever asks."""
     body = step.__wrapped__
 
-    def paged_decode_step(params, staged, pool, kv_mask, cfg):
+    def paged_decode_step(params, staged, prev, pool, kv_mask, cfg):
         (token, page_table, pos, write_pos, active, keys, temperature,
-         top_k, top_p) = unpack(staged)
+         top_k, top_p) = unpack(staged, prev)
         out = body(params, token, pool, page_table, pos, write_pos, kv_mask,
                    active, keys, temperature, top_k, top_p, cfg)
         return {"fetch": pack_result(out["token"], out["keys"],

@@ -14,13 +14,26 @@ frontend), and at every `step()` boundary the engine
    budget instead prefills INCREMENTALLY: at most that many prompt tokens
    per tick (`paged_prefill_chunk`), so in-flight decodes keep producing a
    token every tick — chunked batched prefill, no full-prefill stall;
-2. runs ONE `paged_decode_step` over every slot (static shape, one
-   compile) — per-row write positions, rope positions, rng chains, and
-   sampling knobs, so requests at different depths and with different
-   `GenerationConfig`s share the tick;
-3. distributes the sampled tokens to their streaming handles and frees the
-   slots of finished rows (eos or budget) immediately — pages and
-   reservations included — so the next boundary can admit again.
+2. stages and enqueues ONE `paged_decode_step` over every slot (static
+   shape, one compile) — per-row write positions, rope positions, rng
+   chains, and sampling knobs, so requests at different depths and with
+   different `GenerationConfig`s share the tick;
+3. collects the tick it enqueued at the boundary BEFORE: distributes its
+   sampled tokens to their streaming handles and frees the slots of
+   finished rows (eos or budget) immediately — pages and reservations
+   included — so the next boundary can admit again.
+
+One decode tick is always in flight: tick k is handed to the device while
+tick k-1 still runs, its rows' tokens and rng keys fed back on the device
+(`models/tick_io.py`), so the host's share of a tick (fetch, emit, the loop,
+staging, the copy in, the call) runs under the device's. What follows from
+it: a client sees a token one dispatch later than the device made it; a row
+that ends by `eos_token_id` is seen one tick late, and its row of the tick
+already enqueued is an OVERRUN (run, written inside the pages its
+reservation covers, its token discarded: `rows_overrun`); a row that ends by
+length is known a tick ahead and left out. `shutdown()`, an idle boundary
+and a cancellation collect the tick in flight first, so every row-tick the
+device ran is a token a handle received, the overruns apart.
 
 Token parity contract: a request served here emits EXACTLY the tokens of an
 independent `generate(params, padded_prompt, cfg, gen,
@@ -68,10 +81,11 @@ logger = get_logger(__name__)
 
 _REQUEST_IDS = itertools.count()
 
-# seconds summed over a `serve_decode_step` span's ticks, in the order
-# `_decode_tick` hands them over: the four phases, which tile the tick, and
-# the two parts of dispatch that a metric reads (the rest of it grows the
-# pages and adopts the outputs)
+# seconds summed over a `serve_decode_step` span's ticks: the four phases of
+# each tick (`stage` and `dispatch` at the boundary that enqueued it, `wait`
+# and `emit` at the next, where it was collected), and the two parts of
+# dispatch that a metric reads (the rest of it grows the pages and adopts the
+# outputs)
 TICK_SUMS = ("stage_s", "dispatch_s", "wait_s", "emit_s",
              "h2d_s", "enqueue_s")
 
@@ -292,13 +306,35 @@ class _Running:
 
     request: ServeRequest
     handle: RequestHandle
-    token: int               # last emitted token (the next step's input)
-    pos: int                 # its rope position
+    # the last token the host has READ and the [2] uint32 rng chain after it:
+    # the next tick's input for a row in no tick in flight (a row in one is
+    # fed the tick's own on the device)
+    token: int
+    key: np.ndarray
+    pos: int                 # rope position of the next tick to DISPATCH
     write_pos: int           # its cache row
-    key: np.ndarray          # [2] uint32 rng chain
-    emitted: int
+    emitted: int             # tokens pushed to the handle
     t_admit: float
     t_first: float
+    in_flight: int = 0       # ticks dispatched with this row, not collected
+    finished: bool = False   # left the batch: a row still in flight overran
+
+
+@dataclasses.dataclass
+class _Tick:
+    """A decode tick the device was handed and the host has not read."""
+
+    fetch: jax.Array         # its `tick_io.pack_result`, still on the device
+    rows: list               # [(slot, _Running)]: the rows it decodes
+    ts: float                # wall clock at its dispatch
+    ahead: bool              # enqueued while the tick before was in flight
+    pages: tuple             # (live, table) logical pages of its rows
+    branch: int              # `sampler_branch` of its staged knobs
+    stage_s: float
+    dispatch_s: float
+    h2d_s: float
+    enqueue_s: float
+    h2d_copies: int          # transfers in, counted where they were made
 
 
 @dataclasses.dataclass
@@ -392,6 +428,12 @@ class ServeEngine:
         self._sample_first = jax.jit(sample_rowwise)
         # the tick's program: one staged buffer in, one fetched vector out
         self._tick_program = self._family.decode_tick
+        # the tick in flight (None at start, after an idle boundary and
+        # after a cancellation), and what stands for the tick before it in a
+        # tick no row of which is fed from one
+        self._in_flight: _Tick | None = None
+        self._no_fetch = jnp.zeros(
+            3 * serve_cfg.max_slots + len(self._family.counters), jnp.int32)
         self.steps = 0
         self.prefill_chunks_last_tick = 0
         self.prefill_chunks_total = 0
@@ -413,6 +455,9 @@ class ServeEngine:
         # transfers the engine's thread made over the pending span's ticks,
         # counted where they are made: one each way a tick
         self._tick_copies = [0, 0]       # host to device, device to host
+        # ticks enqueued behind a tick in flight; row-ticks run and discarded
+        self._tick_ahead = 0
+        self._tick_overrun = 0
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
         self._tick_counters = dict.fromkeys(self._family.counters, 0)
@@ -621,8 +666,9 @@ class ServeEngine:
 
     def step(self) -> bool:
         """One step boundary: admit (without a chunk budget: whole prompts)
-        or advance bounded prefill chunks (with one), then one decode tick
-        over all slots. Returns False when there was nothing to do (caller
+        or advance bounded prefill chunks (with one), then stage and enqueue
+        one decode tick over all slots, then collect the tick enqueued at the
+        boundary before. Returns False when there was nothing to do (caller
         may sleep)."""
         t0 = time.perf_counter() if self._timeline is not None else 0.0
         self._cancel_abandoned()
@@ -635,6 +681,8 @@ class ServeEngine:
         prefill_s = (time.perf_counter() - t0
                      if self._timeline is not None else 0.0)
         if not self._occupants:
+            # rows that overran their eos may be all a tick in flight holds
+            self._collect()
             if self._prefilling:      # prefill-only tick is still work
                 self.steps += 1
                 self._note_tick(prefill_s, 0.0, pf_req)
@@ -645,7 +693,6 @@ class ServeEngine:
             if self.queue_depth():
                 self._work.set()
             return False
-        self._last_decode_dur = 0.0
         self._decode_tick()
         self.steps += 1
         self._note_tick(prefill_s, self._last_decode_dur, pf_req)
@@ -686,7 +733,8 @@ class ServeEngine:
         release their prefix-match pins), a mid-prefill or decoding slot is
         freed — unshared pages back to the pool, shared prefix pages drop
         one refcount — and the trace ends as `abandoned` with the token
-        count the client never consumed. No SLO record: the request has no
+        count the client never consumed. A decoding row is cancelled after
+        the tick in flight is collected. No SLO record: the request has no
         honest completion latency."""
         if not self._abandoned:
             return
@@ -715,6 +763,11 @@ class ServeEngine:
             self.slots.release(pf.slot)
             self._finish_abandoned(pf.request, pf.handle,
                                    discarded=len(pf.handle.tokens_out))
+        if any(r.request.request_id in doomed
+               for r in self._occupants.values()):
+            # the row's token of the tick in flight is still delivered (it
+            # may be its last); the row is out of the NEXT tick
+            self._collect()
         for slot, r in [(s, r) for s, r in self._occupants.items()
                         if r.request.request_id in doomed]:
             self._occupants.pop(slot)
@@ -973,54 +1026,70 @@ class ServeEngine:
         return True
 
     def _decode_tick(self) -> None:
-        """One decode tick over every slot, in four host phases: `stage`
-        (the rows of ONE staging buffer, `models/tick_io.py`), `dispatch`
-        (`grow`: page growth, then the page table into the buffer; `h2d`: the
-        buffer's copy, the tick's one transfer to the device; `enqueue`: the
-        jitted call; then adopting its outputs), `wait` (`block`: until this
-        tick's tokens are ready; `fetch`: token, keys and counters to numpy,
-        the tick's one transfer back) and `emit` (token push, finishes). Each
-        is a profiler annotation; the four phases, `h2d` and `enqueue` are
-        also sums on the aggregated `serve_decode_step` span (`TICK_SUMS`),
-        whose `dur` stays dispatch + wait, and the transfers are counted where
-        they are made (`h2d_copies`, `d2h_copies`). One clock read a boundary:
-        no phase is timed twice."""
+        """Hand the device its next tick, THEN read the one before: while the
+        host fetches and emits tick k-1 (and comes round the loop, admits and
+        stages), the device runs tick k."""
+        before = self._in_flight
+        self._in_flight = tick = self._dispatch_tick(before)
+        wait_s = self._collect_tick(before) if before is not None else 0.0
+        self._last_decode_dur = wait_s + (tick.dispatch_s if tick else 0.0)
+
+    def _collect(self) -> None:
+        """Read the tick in flight, if any, with none enqueued behind it."""
+        before, self._in_flight = self._in_flight, None
+        if before is not None:
+            self._collect_tick(before)
+
+    def _dispatch_tick(self, before: "_Tick | None") -> "_Tick | None":
+        """Stage and enqueue one decode tick over every row that has a token
+        left to decode once `before` (the tick in flight) lands, in two host
+        phases: `stage` (the rows of ONE staging buffer, `models/tick_io.py`)
+        and `dispatch` (`grow`: page growth, then the page table into the
+        buffer; `h2d`: the buffer's copy, the tick's one transfer to the
+        device; `enqueue`: the jitted call; then adopting its outputs). The
+        host knows everything it stages a tick ahead but the token and rng
+        key of a row `before` holds: those the program reads from `before`'s
+        fetched vector, on the device (`fed`). A row that ends by length when
+        `before` lands is left out; one that ends by eos is not known yet and
+        overruns. None where no row is left to decode."""
         scfg = self.serve_cfg
-        S = scfg.max_slots
         t_entry = time.perf_counter()
+        rows = [(slot, r) for slot, r in self._occupants.items()
+                if r.emitted + r.in_flight < r.request.gen.max_new_tokens]
+        if not rows:
+            return None
         with trace.annotate(trace.TICK_STAGE):
             # fresh every tick: nothing writes a buffer the device was given
-            staged = tick_io.stage(S, self.slots.page_table.shape[1])
+            staged = tick_io.stage(scfg.max_slots,
+                                   self.slots.page_table.shape[1])
             pages_live = 0
-            for slot, r in self._occupants.items():
-                staged.token[slot] = r.token
+            for slot, r in rows:
+                if r.in_flight:
+                    staged.fed[slot] = 1
+                else:
+                    staged.token[slot] = r.token
+                    staged.keys[slot] = r.key
                 staged.pos[slot] = r.pos
                 staged.write_pos[slot] = r.write_pos
-                staged.keys[slot] = r.key
                 staged.temperature[slot] = r.request.gen.temperature
                 staged.top_k[slot] = r.request.gen.top_k
                 staged.top_p[slot] = r.request.gen.top_p
                 pages_live += r.write_pos // scfg.page_size + 1
-            n_active = len(self._occupants)
-            self._tick_pages[0] += pages_live
-            self._tick_pages[1] += n_active * self.slots.page_table.shape[1]
             branch = int(sampler_branch(staged.temperature, staged.top_k,
                                         staged.top_p))
-            self._tick_sampler[0] += branch >= 1
-            self._tick_sampler[1] += branch == 2
 
         t_wall = time.time()
         t0 = time.perf_counter()
         with trace.annotate(trace.TICK_DISPATCH):
             with trace.annotate(trace.TICK_GROW):
-                # back the next write of every active row BEFORE the tick:
-                # the submit-time reservation guarantees these allocations
+                # back the next write of every row BEFORE the tick: the
+                # submit-time reservation guarantees these allocations
                 # succeed
-                for slot, r in self._occupants.items():
+                for slot, r in rows:
                     self.slots.ensure_capacity(slot, r.write_pos + 1)
-                    # only occupant rows may write/mark kv: a mid-prefill
-                    # slot already owns live pages and mask spans this tick
-                    # must not touch
+                    # only these rows may write/mark kv: a mid-prefill slot
+                    # already owns live pages and mask spans this tick must
+                    # not touch
                     staged.active[slot] = 1
                 # a copy: the table itself changes under later growth and
                 # releases
@@ -1028,50 +1097,72 @@ class ServeEngine:
             t_grown = time.perf_counter()
             with trace.annotate(trace.TICK_H2D):
                 staged_d = jnp.asarray(staged.buffer)
-                self._tick_copies[0] += 1
+                h2d_copies = 1
             t_copied = time.perf_counter()
             with trace.annotate(trace.TICK_ENQUEUE):
                 # its return is the enqueue's return
-                out = self._tick_program(self.params, staged_d,
-                                         self.slots.pool, self.slots.kv_mask,
-                                         self.cfg)
+                out = self._tick_program(
+                    self.params, staged_d,
+                    self._no_fetch if before is None else before.fetch,
+                    self.slots.pool, self.slots.kv_mask, self.cfg)
             t_enqueued = time.perf_counter()
             # release the staged copy now, while the device runs the tick, as
             # a call's own temporaries are, not between two ticks at this
             # function's return
             del staged_d
             self.slots.update_from_step(out)
+            for _, r in rows:
+                r.pos += 1
+                r.write_pos += 1
+                r.in_flight += 1
         t_dispatched = time.perf_counter()
+        return _Tick(
+            fetch=out["fetch"], rows=rows, ts=t_wall,
+            ahead=before is not None,
+            pages=(pages_live, len(rows) * self.slots.page_table.shape[1]),
+            branch=branch, stage_s=t0 - t_entry, dispatch_s=t_dispatched - t0,
+            h2d_s=t_copied - t_grown, enqueue_s=t_enqueued - t_copied,
+            h2d_copies=h2d_copies)
+
+    def _collect_tick(self, tick: _Tick) -> float:
+        """Read a dispatched tick, in two host phases: `wait` (`block`: until
+        its tokens are ready; `fetch`: token, keys and counters to numpy, the
+        tick's one transfer back) and `emit` (token push, finishes). A row
+        that left the batch since the dispatch (an eos the host saw a tick
+        late) overran: its token is dropped and counted. The tick's host
+        counts and the device's counters fold into the pending
+        `serve_decode_step` span here, together. Each phase is a profiler
+        annotation; the four phases, `h2d` and `enqueue` are also sums on the
+        span (`TICK_SUMS`), whose `dur` stays dispatch + wait. One clock read
+        a boundary: no phase is timed twice. Returns the wait's seconds."""
+        t_entry = time.perf_counter()
         with trace.annotate(trace.TICK_WAIT):
             # block, then convert: the device's gap while the host sleeps
             # belongs to `block` (launch before the program's first
             # operation, wake after its last), not to the conversion
             with trace.annotate(trace.TICK_BLOCK):
-                jax.block_until_ready(out["fetch"])
+                jax.block_until_ready(tick.fetch)
             with trace.annotate(trace.TICK_FETCH):
                 next_token, new_keys, counters = tick_io.split_result(
-                    np.asarray(out["fetch"]), S)        # real tick time
-                self._tick_copies[1] += 1
-                for name, n in zip(self._family.counters, counters.tolist()):
-                    self._tick_counters[name] += n
+                    np.asarray(tick.fetch), self.serve_cfg.max_slots)
+                d2h_copies = 1
         t_fetched = time.perf_counter()
-        self._last_decode_dur = t_fetched - t0
+        overrun = 0
         with trace.annotate(trace.TICK_EMIT):
-            if self._reqtrace is not None:
-                # tick-rate but bounded by max_slots dict lookups; tracing
-                # OFF skips even the branch body (the structural free-ness
-                # pin)
-                for r in self._occupants.values():
+            for slot, r in tick.rows:
+                r.in_flight -= 1
+                if r.finished:
+                    overrun += 1
+                    continue
+                if self._reqtrace is not None:
+                    # tick-rate but bounded by max_slots dict lookups; tracing
+                    # OFF skips even the branch body (the structural free-ness
+                    # pin)
                     b = self._rt.get(r.request.request_id)
                     if b is not None:
-                        b.decode_tick(self.steps, n_active)
-
-            for slot in list(self._occupants):
-                r = self._occupants[slot]
+                        b.decode_tick(self.steps, len(tick.rows))
                 tok = int(next_token[slot])
                 r.token = tok
-                r.pos += 1
-                r.write_pos += 1
                 r.key = new_keys[slot]
                 r.emitted += 1
                 r.handle._push(tok)
@@ -1080,33 +1171,50 @@ class ServeEngine:
                         or r.emitted >= gen.max_new_tokens:
                     self._finish(slot, r)
         self._note_decode_tick(
-            t_wall, self._last_decode_dur, n_active,
-            sums=(t0 - t_entry, t_dispatched - t0, t_fetched - t_dispatched,
-                  time.perf_counter() - t_fetched, t_copied - t_grown,
-                  t_enqueued - t_copied))
+            tick, counters.tolist(), overrun, d2h_copies,
+            wait_s=t_fetched - t_entry,
+            emit_s=time.perf_counter() - t_fetched)
+        return t_fetched - t_entry
 
-    def _note_decode_tick(self, ts: float, dur: float, active: int,
-                          sums: tuple) -> None:
-        """Fold one decode tick into the pending aggregated
+    def _note_decode_tick(self, tick: _Tick, counters: list, overrun: int,
+                          d2h_copies: int, wait_s: float,
+                          emit_s: float) -> None:
+        """Fold one collected decode tick into the pending aggregated
         `serve_decode_step` span; flush every `decode_span_every` ticks
         (and at idle boundaries / shutdown). The emitted span's `dur` is
-        the exact sum of its `ticks` tick durations (dispatch + wait), so
-        RunClock's `serve` bucket and the goodput fraction lose nothing to
-        the aggregation — only the spans.jsonl line rate drops from token
-        rate. `tokens` is the host's own count of the rows that decoded over
-        those ticks (`active` is the last tick's alone). `sums`: this tick's
-        seconds in the order of `TICK_SUMS`, summed the same way, as are
-        `kv_pages_live`, `kv_pages_table`, `ticks_sampled` and
-        `ticks_sorted` (`_decode_tick` counts them where it stages the
-        rows) and `h2d_copies` / `d2h_copies` (where it makes them)."""
+        the exact sum of its `ticks` ticks' dispatch + wait (the dispatch at
+        the boundary that enqueued the tick, the wait at the next: host
+        seconds that tile, so RunClock's `serve` bucket and the goodput
+        fraction lose nothing to the aggregation or to the tick in flight —
+        only the spans.jsonl line rate drops from token rate). `tokens` is
+        the host's own count of the row-ticks the device ran over those ticks
+        (`active` is the last tick's alone), `rows_overrun` the ones among
+        them whose token was discarded, `ticks_ahead` the ticks enqueued
+        behind a tick in flight. The seconds of `TICK_SUMS`,
+        `kv_pages_live`, `kv_pages_table`, `ticks_sampled`, `ticks_sorted`
+        (counted where the rows are staged), `h2d_copies` / `d2h_copies` (one
+        each a tick, where they are made) and the family's counters are
+        summed the same way, all of the SAME ticks: every one is folded here,
+        when its tick is collected."""
         if self._tick_count == 0:
-            self._tick_ts = ts
-        self._tick_accum += dur
+            self._tick_ts = tick.ts
+        self._tick_accum += tick.dispatch_s + wait_s
         self._tick_count += 1
-        self._tick_active = active
-        self._tick_tokens += active
-        for i, seconds in enumerate(sums):
+        self._tick_active = len(tick.rows)
+        self._tick_tokens += len(tick.rows)
+        self._tick_overrun += overrun
+        self._tick_ahead += tick.ahead
+        self._tick_pages[0] += tick.pages[0]
+        self._tick_pages[1] += tick.pages[1]
+        self._tick_sampler[0] += tick.branch >= 1
+        self._tick_sampler[1] += tick.branch == 2
+        self._tick_copies[0] += tick.h2d_copies
+        self._tick_copies[1] += d2h_copies
+        for i, seconds in enumerate((tick.stage_s, tick.dispatch_s, wait_s,
+                                     emit_s, tick.h2d_s, tick.enqueue_s)):
             self._tick_sums[i] += seconds
+        for name, n in zip(self._family.counters, counters):
+            self._tick_counters[name] += n
         if self._tick_count >= self.serve_cfg.decode_span_every:
             self._flush_decode_span()
 
@@ -1119,6 +1227,8 @@ class ServeEngine:
                               dur=self._tick_accum, ticks=self._tick_count,
                               active=self._tick_active,
                               tokens=self._tick_tokens,
+                              rows_overrun=self._tick_overrun,
+                              ticks_ahead=self._tick_ahead,
                               kv_pages_live=self._tick_pages[0],
                               kv_pages_table=self._tick_pages[1],
                               ticks_sampled=self._tick_sampler[0],
@@ -1129,6 +1239,7 @@ class ServeEngine:
                               **self._tick_counters)
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
+        self._tick_overrun, self._tick_ahead = 0, 0
         self._tick_pages = [0, 0]
         self._tick_sampler = [0, 0]
         self._tick_copies = [0, 0]
@@ -1192,7 +1303,12 @@ class ServeEngine:
                     tokens=r.emitted, ttft=ttft, tpot=tpot,
                     queue_wait=queue_wait, slo_breach=breaches or None,
                     capture=capture_dir))
+        # released at once, though a tick in flight may still hold the row (an
+        # eos seen a tick late): the device runs programs in the order they
+        # were enqueued, so a later prefill into the slot, or a later row's
+        # growth onto a freed page, follows the overrun's write
         self._occupants.pop(slot, None)
+        r.finished = True
         self.slots.release(slot)
         r.handle._finish(error)
         if (self._metrics_writer is not None
@@ -1243,9 +1359,11 @@ class ServeEngine:
         return snap
 
     def drain(self, timeout_s: float = 60.0) -> None:
-        """Step until queue and slots are empty (tests / synchronous use)."""
+        """Step until queue and slots are empty and no tick is in flight
+        (tests / synchronous use)."""
         deadline = time.monotonic() + timeout_s
-        while self._occupants or self._prefilling or self.queue_depth():
+        while (self._occupants or self._prefilling or self.queue_depth()
+               or self._in_flight is not None):
             if time.monotonic() > deadline:
                 raise TimeoutError("engine did not drain in time")
             self.step()
@@ -1253,7 +1371,14 @@ class ServeEngine:
     def shutdown(self) -> None:
         """Fail every queued and in-flight request (process exit path);
         later submits raise EngineShutdown instead of queueing into a dead
-        engine."""
+        engine. The decode tick in flight is collected first: its tokens
+        reach their handles (and may finish them) before the rest fail."""
+        try:
+            self._collect()
+        except Exception:
+            # a failed step left the stores poisoned (ServeLoop._run): the
+            # tick in flight cannot be read either
+            logger.exception("the decode tick in flight at shutdown is lost")
         self._flush_decode_span()
         if self._profiler is not None:
             self._profiler.close()  # finalize an open capture window

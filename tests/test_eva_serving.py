@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 import eva_tiny as tiny
+import tick_ahead
 from benchmark.reference import eva_decoder
 from llama_pipeline_parallel_tpu import serve
 from llama_pipeline_parallel_tpu.models import family as families
@@ -402,6 +403,44 @@ def test_the_engine_serves_the_family_and_its_spans_carry_the_counters():
     assert sum(s["eva_summaries_written"] for s in ticks + units) == (
         L * (W // C) * finished)
     assert engine.slots.pages_used == 0
+
+
+def test_a_tick_in_flight_serves_the_family_as_the_serial_order_does():
+    """Prompts of one to three units, greedy and sampled rows that cross
+    window, chunk and page edges while they decode (a tick then pools the
+    window it completes), one ended by its eos, with the engine's tick in
+    flight and in the serial order (`tests/tick_ahead.py`): the same
+    streams, bit for bit, and the two exact counts over every row-tick the
+    device ran, the overrun among them. The overrun writes the ring page of
+    a slot its row has left; the request admitted there next is served as
+    the serial order serves it."""
+    cfg = tiny.tiny_config()
+    params = tiny.tiny_params(cfg)
+    make = lambda: _engine(cfg, params, num_pages=2 * PAGES,
+                           decode_span_every=4)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (70, 12, 33, 25)]
+    budgets = [30, 8, 40, 2]
+    knobs = [dict(temperature=0.9), {}, dict(temperature=1.2, top_k=9), {}]
+    plain = tick_ahead.run(make(), tick_ahead.requests_of(
+        prompts, budgets, knobs), serially=True)["tokens"]
+    assert [len(t) for t in plain] == budgets
+    eos = {0: tick_ahead.eos_of(plain[0], least=20)[1]}
+    serial, ahead = tick_ahead.both_orders(
+        make, lambda: tick_ahead.requests_of(prompts, budgets, knobs, eos))
+    assert ahead["sums"]["rows_overrun"] == 1
+    assert 20 < len(ahead["tokens"][0]) < 30
+    L = cfg.num_hidden_layers
+    for result, overran in ((serial, ()), (ahead, (0,))):
+        # a row-tick at context c reads position c - 1's window and summaries
+        positions = [c - 1 for c in tick_ahead.contexts_run(
+            result, prompts, overran)]
+        assert result["sums"]["tokens"] == len(positions)
+        assert result["sums"]["eva_window_visible"] == L * sum(
+            p % W + 1 for p in positions)
+        assert result["sums"]["eva_summary_visible"] == L * sum(
+            p // W * (W // C) for p in positions)
 
 
 def test_the_engines_default_pool_is_one_full_length_request_a_slot():

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import hybrid_tiny as tiny
+import tick_ahead
 from llama_pipeline_parallel_tpu import serve
 from llama_pipeline_parallel_tpu.models import family as families
 from llama_pipeline_parallel_tpu.models.hybrid_moe import decode as hybrid_decode
@@ -106,6 +107,46 @@ def test_prefill_then_ticks_through_both_stores_are_the_reference():
         first = len(r["prompt"]) - 1
         got = np.stack(r["logits"])
         np.testing.assert_allclose(got, want[first:first + len(got)], atol=TOL)
+
+
+@pytest.mark.parametrize("ending", ["by_length", "an_eos"])
+def test_a_tick_in_flight_serves_the_family_as_the_serial_order_does(ending):
+    """Five requests over two slots, greedy and sampled, one of two tokens,
+    with the engine's tick in flight and in the serial order
+    (`tests/tick_ahead.py`): the same streams, bit for bit. The recurrent
+    store has ONE row a slot: a row that overran its eos advanced the slot's
+    state once more after it had left, and the request admitted into the
+    slot next is served as if it had not. The expert layers' counter is
+    exact over every row-tick run, the overrun among them."""
+    cfg = tiny.config()
+    params = tiny.both_sides()[0]
+    scfg = serve.ServeConfig(max_slots=SLOTS, max_len=MAX_LEN,
+                             prompt_buckets=(8, 16), page_size=PAGE,
+                             num_pages=2 * PAGES, decode_span_every=4)
+    make = lambda: serve.ServeEngine(params, cfg, scfg)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 128, n).tolist() for n in (5, 11, 3, 14, 7)]
+    budgets = [9, 12, 6, 8, 2]
+    knobs = [{}, dict(temperature=0.8), {}, dict(temperature=1.1, top_k=6), {}]
+    eos = None
+    if ending == "an_eos":
+        plain = tick_ahead.run(make(), tick_ahead.requests_of(
+            prompts, budgets, knobs), serially=True)["tokens"]
+        eos = {1: tick_ahead.eos_of(plain[1])[1]}
+    serial, ahead = tick_ahead.both_orders(
+        make, lambda: tick_ahead.requests_of(prompts, budgets, knobs, eos))
+    assert ahead["sums"]["rows_overrun"] == (ending == "an_eos")
+    if eos is None:
+        assert [len(t) for t in ahead["tokens"]] == budgets
+    else:
+        assert ahead["tokens"][1][-1] == eos[1]
+        assert len(ahead["tokens"][1]) < budgets[1]
+    for result in (serial, ahead):
+        # every row-tick chooses 4 experts in each of 8 layers
+        assert result["sums"]["routed_total"] == (
+            result["sums"]["tokens"] * 4 * 8)
+    assert ahead["sums"]["tokens"] == (
+        serial["sums"]["tokens"] + ahead["sums"]["rows_overrun"])
 
 
 def test_the_engine_serves_the_family_through_the_same_tick_and_spans():

@@ -18,6 +18,7 @@ import pytest
 import hybrid_tiny
 import latent_tiny as tiny
 import mla_tiny
+import tick_ahead
 from llama_pipeline_parallel_tpu import serve
 from llama_pipeline_parallel_tpu.models import family as families
 from llama_pipeline_parallel_tpu.models.latent_moe import decode as latent_decode
@@ -258,6 +259,50 @@ def test_the_engine_serves_the_family_in_chunks_with_its_counters_on_the_spans()
     assert prefilled["index_selected"] == 3 * sum(
         min(t + 1, 8) for p in prompts for t in range(len(p)))
     assert all(0 < s["kv_pages_live"] <= s["kv_pages_table"] for s in ticks)
+
+
+@pytest.mark.parametrize("model", ["dots3", "a.x-k1"])
+def test_a_tick_in_flight_serves_both_models_as_the_serial_order_does(model):
+    """Buckets of 8 (whole), 16 and 32 (chunks of 8 between decode ticks),
+    greedy and sampled rows, one ended by its eos, with the engine's tick in
+    flight and in the serial order (`tests/tick_ahead.py`): the same
+    streams, bit for bit; the counters that are exact a row (the experts
+    chosen, the positions seen or selected) are the host's own count over
+    every row-tick the device ran, the overrun among them."""
+    which = tiny if model == "dots3" else mla_tiny
+    cfg = which.config()
+    params = which.both_sides()[0]
+    scfg = serve.ServeConfig(max_slots=SLOTS, max_len=MAX_LEN,
+                             prompt_buckets=(8, 16, 32), page_size=PAGE,
+                             num_pages=2 * PAGES, decode_span_every=4,
+                             prefill_chunk_tokens=8)
+    make = lambda: serve.ServeEngine(params, cfg, scfg)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 128, n).tolist() for n in (5, 27, 3, 14)]
+    budgets = [9, 12, 2, 10]
+    knobs = [{}, dict(temperature=0.8), {}, dict(temperature=1.1, top_p=0.9)]
+    plain = tick_ahead.run(make(), tick_ahead.requests_of(
+        prompts, budgets, knobs), serially=True)["tokens"]
+    assert [len(t) for t in plain] == budgets
+    eos = {1: tick_ahead.eos_of(plain[1])[1]}
+    serial, ahead = tick_ahead.both_orders(
+        make, lambda: tick_ahead.requests_of(prompts, budgets, knobs, eos))
+    assert ahead["sums"]["rows_overrun"] == 1
+    assert ahead["tokens"][1][-1] == eos[1]
+    assert [len(t) for t in ahead["tokens"]] == [
+        9, len(ahead["tokens"][1]), 2, 10]
+    expert_layers = 8 if model == "dots3" else 4
+    for result, overran in ((serial, ()), (ahead, (1,))):
+        contexts = tick_ahead.contexts_run(result, prompts, overran)
+        sums = result["sums"]
+        assert sums["tokens"] == len(contexts)
+        assert sums["routed_total"] == len(contexts) * 4 * expert_layers
+        if model == "dots3":
+            assert sums["index_visible"] == 3 * sum(contexts)
+            assert sums["index_selected"] == 3 * sum(min(c, 8)
+                                                     for c in contexts)
+        else:
+            assert sums["latent_visible"] == 5 * sum(contexts)
 
 
 # -- structure of the traced programs -------------------------------------------

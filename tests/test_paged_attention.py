@@ -410,6 +410,8 @@ def test_the_engines_tick_compiled_for_the_chip_keeps_the_stores_in_place(
     args = _described(
         (params,
          jax.ShapeDtypeStruct((slots, tick_io.COLUMNS + pmax), jnp.int32),
+         # the tick before's fetched vector, fed back on the device
+         jax.ShapeDtypeStruct((3 * slots + counters,), jnp.int32),
          pool, jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)),
         one_chip)
     lowered = tick_io.packed(tick).lower(*args, cfg)
@@ -588,7 +590,10 @@ def test_a_compressed_window_program_compiled_for_the_chip_keeps_its_pool_in_pla
     if program == "tick":
         args = _described(
             (params, jax.ShapeDtypeStruct(
-                (slots, tick_io.COLUMNS + width), jnp.int32), pool, mask),
+                (slots, tick_io.COLUMNS + width), jnp.int32),
+             jax.ShapeDtypeStruct(
+                 (3 * slots + len(eva_decode.COUNTERS),), jnp.int32),
+             pool, mask),
             one_chip)
         lowered = tick_io.packed(eva_decode.paged_decode_step).lower(*args, cfg)
         assert lowered.out_info["fetch"].shape == (

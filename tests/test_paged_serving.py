@@ -354,6 +354,64 @@ def test_paged_eos_finishes_row_early_and_frees_pages(setup):
     assert ref[0] == eos and all(t == 17 for t in ref[1:])
 
 
+@pytest.mark.parametrize("kv_quant", ["fp", "int8"])
+def test_an_overruns_write_lands_inside_the_rows_own_reservation(setup,
+                                                                 kv_quant):
+    """A row's eos is read one tick late, and the tick already enqueued
+    writes the row's cache once more. Here that write is the FIRST of a new
+    page, in a pool that holds exactly the three requests' worst cases: the
+    page is backed from the row's own reservation (no accounting error), all
+    of it is free again at once, and the row decoding beside it
+    and the request that takes the slot next emit their generate() calls'
+    tokens (fp) or an undisturbed int8 engine's."""
+    cfg, params = setup
+    rs = np.random.RandomState(3)
+    prompts = [rs.randint(3, cfg.vocab_size, (n,)).tolist() for n in (5, 7, 6)]
+    plain = GenerationConfig(max_new_tokens=8)
+    drawn = GenerationConfig(max_new_tokens=8, temperature=1.1)  # no repeats
+
+    def serve_all(gens, **kw):
+        demand = page_demand(BUCKET, 8, PAGE)
+        engine = make_engine(cfg, params, num_pages=3 * demand,
+                             kv_quant=kv_quant, **kw)
+        handles = [engine.submit(ServeRequest(input_ids=p, gen=g, seed=i))
+                   for i, (p, g) in enumerate(zip(prompts[:2], gens))]
+        engine.step()
+        engine.step()
+        handles.append(engine.submit(ServeRequest(
+            input_ids=prompts[2], gen=plain, seed=2)))
+        engine.drain(timeout_s=120)
+        return engine, [h.result(timeout=1) for h in handles]
+
+    _, free = serve_all([drawn, plain])
+    # token 4 comes from the tick that writes place BUCKET + 3; the overrun
+    # writes place BUCKET + 4, the first of the row's fourth page
+    assert (BUCKET + 4) % PAGE == 0
+    assert free[0][4] not in free[0][:4]
+    gen = GenerationConfig(max_new_tokens=8, temperature=1.1,
+                           eos_token_id=free[0][4])
+    spans = []
+    listener = lambda rec: spans.append(dict(rec))
+    from llama_pipeline_parallel_tpu.utils import trace
+
+    trace.recorder().add_listener(listener)
+    try:
+        engine, got = serve_all([gen, plain], decode_span_every=64)
+        assert engine.step() is False
+    finally:
+        trace.recorder().remove_listener(listener)
+    assert got == [free[0][:5], free[1], free[2]]
+    if kv_quant == "fp":
+        assert got[1] == reference_tokens(params, cfg, prompts[1], plain, 1)
+        assert got[2] == reference_tokens(params, cfg, prompts[2], plain, 2)
+    ticks = [s for s in spans if s["name"] == "serve_decode_step"]
+    assert sum(s["rows_overrun"] for s in ticks) == 1
+    # the overrun's page was allocated, from the reservation, and released
+    assert engine.slots.page_allocations == 4 + 2 * page_demand(BUCKET, 8, PAGE)  # noqa: E501
+    assert engine.slots.pages_free == engine.slots.num_pages
+    assert engine.slots.pages_reserved == 0
+
+
 # -- chunked batched prefill: no full-prefill stall ---------------------------
 
 

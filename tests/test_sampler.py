@@ -241,15 +241,15 @@ def test_the_tick_span_counts_the_ticks_that_sampled_and_that_sorted():
     engine = ServeEngine(params, cfg, ServeConfig(
         max_slots=2, max_len=24, prompt_buckets=(16,), page_size=8,
         max_queue=8, decode_span_every=1))
-    program_branch = jax.jit(
-        lambda staged: decode.sampler_branch(*tick_io.unpack(staged)[-3:]))
+    program_branch = jax.jit(lambda staged, prev: decode.sampler_branch(
+        *tick_io.unpack(staged, prev)[-3:]))
     given = []
     real_step = engine._tick_program
 
-    def recording_step(params, staged, *rest):
+    def recording_step(params, staged, prev, *rest):
         # the knobs as the program unpacks them from the staged buffer
-        given.append(int(program_branch(staged)))
-        return real_step(params, staged, *rest)
+        given.append(int(program_branch(staged, prev)))
+        return real_step(params, staged, prev, *rest)
 
     engine._tick_program = recording_step
     spans = []

@@ -200,6 +200,45 @@ def test_eos_finishes_row_early_and_frees_slot(setup):
     assert ref[0] == eos and all(t == 17 for t in ref[1:])  # generate pads
 
 
+def test_an_eos_mid_stream_with_a_tick_in_flight_is_generates_prefix(setup):
+    """The engine reads a row's eos one tick late (the next tick is already
+    enqueued: `rows_overrun`), and frees its slot at once. What the client
+    gets is still generate()'s stream up to and including the eos, and the
+    request that takes the one slot next, prefilled and decoded behind the
+    overrun's write, emits exactly its own generate() call's tokens."""
+    cfg, params = setup
+    rs = np.random.RandomState(7)
+    prompts = [rs.randint(3, cfg.vocab_size, (n,)).tolist() for n in (4, 6)]
+    free = reference_tokens(params, cfg, prompts[0],
+                            GenerationConfig(max_new_tokens=8), 0)
+    at = next(i for i in range(2, 7) if free[i] not in free[:i])
+    gens = [GenerationConfig(max_new_tokens=8, eos_token_id=free[at],
+                             pad_token_id=17),
+            GenerationConfig(max_new_tokens=7, temperature=0.9, top_k=5)]
+    engine = make_engine(cfg, params, max_slots=1)
+    spans = []
+    listener = lambda rec: spans.append(dict(rec))
+    trace.recorder().add_listener(listener)
+    try:
+        handles = [engine.submit(ServeRequest(input_ids=p, gen=g, seed=i))
+                   for i, (p, g) in enumerate(zip(prompts, gens))]
+        engine.drain(timeout_s=120)
+        assert engine.step() is False
+    finally:
+        trace.recorder().remove_listener(listener)
+    got = [h.result(timeout=1) for h in handles]
+    refs = [reference_tokens(params, cfg, p, g, i)
+            for i, (p, g) in enumerate(zip(prompts, gens))]
+    assert got[0] == free[:at + 1] == refs[0][:at + 1]
+    assert all(t == 17 for t in refs[0][at + 1:])        # generate pads
+    assert got[1] == refs[1]
+    ticks = [s for s in spans if s["name"] == "serve_decode_step"]
+    assert sum(s["rows_overrun"] for s in ticks) == 1
+    assert sum(s["tokens"] for s in ticks) == at + 1 + 6
+    assert engine.slots.free_count == 1
+    assert engine.slots.pages_free == engine.slots.num_pages
+
+
 # -- scheduler / slot units --------------------------------------------------
 
 

@@ -93,6 +93,10 @@ def test_old_events_once_a_tick_and_new_events_inside_their_parents(captured):
         parents = _named(host, parent)
         for s, e in _named(host, child):
             assert any(ps <= s and e <= pe for ps, pe in parents), child
+    # a tick is enqueued before the tick before it is waited for
+    dispatches, waits = (_named(host, name) for name in (
+        trace.TICK_DISPATCH, trace.TICK_WAIT))
+    assert all(dispatches[i + 1][1] <= waits[i][0] for i in range(ticks - 1))
     # the four phases of a tick follow one another; the parts of a phase too
     for i in range(ticks):
         phase = [_named(host, name)[i] for name in OLD]

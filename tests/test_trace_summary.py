@@ -125,3 +125,35 @@ def test_a_capture_with_no_device_operation_says_so(tmp_path):
         ("device_step", None, 0, 10)]}})
     with pytest.raises(SystemExit, match="holds no device operation"):
         trace_summary.main([str(tmp_path)])
+
+
+def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
+                                                          capsys):
+    """`ticks_ahead` and `rows_overrun` come from `spans.jsonl`, found beside
+    or above the capture (or named); lines of other spans and of a build
+    that does not carry them are passed over, and a capture with no such
+    file prints no such section."""
+    import json
+
+    trace_summary.main([capture])
+    assert "tick in flight" not in capsys.readouterr().out
+    lines = [{"name": "serve_decode_step", "ticks": 32, "ticks_ahead": 31,
+              "tokens": 500, "rows_overrun": 0},
+             {"name": "serve_prefill", "ticks": 7},
+             {"name": "serve_decode_step", "ticks": 5, "tokens": 80},
+             {"name": "serve_decode_step", "ticks": 8, "ticks_ahead": 8,
+              "tokens": 100, "rows_overrun": 2}]
+    spans = tmp_path / "spans.jsonl"
+    spans.write_text("".join(json.dumps(r) + "\n" for r in lines))
+    assert trace_summary.find_spans(
+        str(tmp_path / "plugins" / "profile")) == str(spans)
+    assert trace_summary.tick_pipeline(str(spans)) == {
+        "ticks": 40, "ticks_ahead": 39, "tokens": 600, "rows_overrun": 2}
+    trace_summary.main([capture])
+    out = capsys.readouterr().out
+    assert "ticks_ahead 39 of 40 ticks (97.50%)" in out
+    assert "rows_overrun 2 of 600 row-ticks" in out
+    elsewhere = tmp_path / "elsewhere.jsonl"
+    elsewhere.write_text(json.dumps(lines[1]) + "\n")
+    trace_summary.main([capture, "--spans", str(elsewhere)])
+    assert "tick in flight" not in capsys.readouterr().out

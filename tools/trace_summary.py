@@ -19,10 +19,16 @@ ones the per-layer metrics report:
   clock is moved to where the trace is causal (benchmark/tick_gap.py);
 - the offset between the profiler's clock and the wall clock, from the
   capture's `wallclock_us=` anchors: add it to a `spans.jsonl` or
-  `request_trace.jsonl` time to place the line on the trace.
+  `request_trace.jsonl` time to place the line on the trace;
+- where the run's `spans.jsonl` is found (`--spans`, else the trace
+  directory or one of the two above it), how often the engine's one tick in
+  flight engaged: `ticks_ahead` of `ticks` (ticks enqueued while the tick
+  before them had not been collected) and `rows_overrun` of `tokens`
+  (row-ticks run and discarded: an eos is seen one tick late), summed over
+  the file's `serve_decode_step` lines.
 
 Usage:
-  python tools/trace_summary.py <trace_dir> [--top 15]
+  python tools/trace_summary.py <trace_dir> [--top 15] [--spans spans.jsonl]
 """
 
 from __future__ import annotations
@@ -63,6 +69,32 @@ def summarize(path: str, top: int = 15) -> dict:
     }
 
 
+PIPELINE = ("ticks", "ticks_ahead", "tokens", "rows_overrun")
+
+
+def find_spans(trace_dir: str):
+    """The `spans.jsonl` of the run that wrote `trace_dir`: in it, or in one
+    of the two directories above it (`<output_dir>/profile`); None."""
+    at = os.path.abspath(trace_dir)
+    for _ in range(3):
+        path = os.path.join(at, "spans.jsonl")
+        if os.path.isfile(path):
+            return path
+        at = os.path.dirname(at)
+    return None
+
+
+def tick_pipeline(spans_path: str):
+    """Sums of `PIPELINE` over the `serve_decode_step` lines that carry them
+    all; None where the file holds no such line (a trainer's, or a build
+    before the engine kept a tick in flight)."""
+    from llama_pipeline_parallel_tpu.utils.perf import read_jsonl
+
+    rows = read_jsonl(spans_path, keep=lambda r: (
+        r.get("name") == "serve_decode_step" and all(k in r for k in PIPELINE)))
+    return {k: sum(r[k] for r in rows) for k in PIPELINE} if rows else None
+
+
 def _table(title: str, rows: dict) -> None:
     print(f"\n== {title} (% of busy time) ==")
     for name, share in sorted(rows.items(), key=lambda kv: -kv[1]):
@@ -75,6 +107,9 @@ def main(argv: list[str] | None = None) -> None:
     p.add_argument("trace_dir")
     p.add_argument("--top", type=int, default=15,
                    help="operations and idle gaps to list")
+    p.add_argument("--spans", default=None,
+                   help="the run's spans.jsonl (default: looked for in the "
+                        "trace directory and the two above it)")
     args = p.parse_args(argv)
 
     path = xplane.find_xplane(args.trace_dir)
@@ -105,6 +140,15 @@ def main(argv: list[str] | None = None) -> None:
                 print(f"  {tick_gap.ms_a_tick(part, name):10.3f} ms  {name}")
         print(f"  {tick_gap.describe_shift(s['device_clock_shift'])}")
     print(f"\nclock: {tick_gap.describe_clock(s['clock'])}")
+    spans_path = args.spans or find_spans(args.trace_dir)
+    pipeline = tick_pipeline(spans_path) if spans_path else None
+    if pipeline is not None:
+        print(f"\n== the engine's tick in flight ({spans_path}) ==\n"
+              f"  ticks_ahead {pipeline['ticks_ahead']} of "
+              f"{pipeline['ticks']} ticks "
+              f"({100.0 * pipeline['ticks_ahead'] / max(pipeline['ticks'], 1):.2f}%)"
+              f"\n  rows_overrun {pipeline['rows_overrun']} of "
+              f"{pipeline['tokens']} row-ticks")
 
 
 if __name__ == "__main__":
