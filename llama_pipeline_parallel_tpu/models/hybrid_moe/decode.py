@@ -74,7 +74,7 @@ def _walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
     kda_index) -> (h, stores)` are the caller's mixers; each is followed by
     its expert half, which takes the routed experts of every period whole
     and the period's place among them. Returns the hidden state, the stores
-    and the expert layers' counters summed over layers (int32[5],
+    and the expert layers' counters summed over layers (int32[6],
     `COUNTERS`)."""
     n = cfg.attn_period
     periods, experts = hybrid.split_experts(params["periods"])
@@ -108,7 +108,7 @@ def prefill_prompt(params: Params, input_ids: jnp.ndarray,
     Returns what the dense `prefill_prompt` returns ({"logits", "cache",
     "kv_mask", "next_pos"}), the cache holding `k` / `v` [periods, b,
     max_len, kv_h, hd] with the prompt at [0, P) and the rows' `state` /
-    `conv` after the last position, plus "counters" (int32[5])."""
+    `conv` after the last position, plus "counters" (int32[6])."""
     b, prompt_len = input_ids.shape
     if prompt_len > max_len:
         raise ValueError(f"prompt bucket {prompt_len} exceeds cache max_len "
@@ -242,7 +242,7 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     routed to no expert. The sampler's cost is the batch's own
     (`sample_rowwise`: an argmax a row unless an active row samples, a sort
     only where one filters). Returns the dense tick's outputs plus
-    "counters" (int32[5], `COUNTERS`, summed over the expert layers)."""
+    "counters" (int32[6], `COUNTERS`, summed over the expert layers)."""
     del pos
     logits, pool, kv_mask, counters = tick_logits(
         params, token, pool, page_table, write_pos, kv_mask, active, cfg)

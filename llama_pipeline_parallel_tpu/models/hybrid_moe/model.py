@@ -11,7 +11,7 @@ period are separate leaves):
     periods.kda[j].*  [P, ...]     its j-th KDA layer (period - 1 of them)
     periods.moe[j].*  [P, ...]     the expert half of its j-th layer
 
-The grouped product (`jax.lax.ragged_dot`) is a kernel of its own that takes
+The grouped product (`ops/grouped_matmul.py`) is a kernel of its own that takes
 whole buffers: handed a slice of a stacked leaf, XLA:TPU first copies the
 slice out, a layer's experts read and written for every product. So no
 slice of the routed experts' `gate` / `up` / `down` is ever taken, within a
@@ -45,6 +45,10 @@ import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.models.hybrid_moe.config import HybridMoEConfig
 from llama_pipeline_parallel_tpu.models.llama.model import cast_weight
+from llama_pipeline_parallel_tpu.ops.grouped_matmul import (
+    group_metadata,
+    grouped_matmul,
+)
 from llama_pipeline_parallel_tpu.ops.rmsnorm import rms_norm
 from llama_pipeline_parallel_tpu.utils import trace
 
@@ -55,7 +59,7 @@ KDA_CHUNK = 64            # positions a chunk of the chunked form covers
 KDA_SUB = 16              # sub-block inside which decays are taken pairwise
 INIT_STD = 0.02
 COUNTERS = ("routed_total", "routed_here", "experts_hit", "expert_load_max",
-            "experts_held")
+            "experts_held", "expert_visits")
 EXPERT_LEAVES = ("gate", "up", "down")   # the grouped product's operands
 
 
@@ -334,11 +338,14 @@ def moe_block(moe: Params, experts: Params, place, x: jnp.ndarray,
     the terms of the experts held here ([expert_offset, expert_offset +
     held)) for the tokens routed to them, and the shared expert; the absent
     experts' terms are left out. Dropless: the sorted rows are sized for
-    every assignment landing here, and `ragged_dot` multiplies each run of
-    rows by its own expert. The stack is multiplied in the dtype it is
-    stored in, which has to be `cfg.dtype`: a conversion here would convert
-    P layers' experts at every layer, so a tree stored otherwise is refused.
-    Returns (x + y, counters int32[5] in the order of COUNTERS)."""
+    every assignment landing here, and `grouped_matmul` multiplies each run
+    of rows by its own expert, reading an expert once for each row tile it
+    has a row in and no expert without one (`expert_visits`: those (row
+    tile, expert) pairs of ONE of the three products, which share the
+    metadata). The stack is multiplied in the dtype it is stored in, which
+    has to be `cfg.dtype`: a conversion here would convert P layers' experts
+    at every layer, so a tree stored otherwise is refused.
+    Returns (x + y, counters int32[6] in the order of COUNTERS)."""
     b, s, d = x.shape
     T, k, held, dt = b * s, cfg.num_experts_per_tok, cfg.held, cfg.dtype
     for name in EXPERT_LEAVES:
@@ -367,15 +374,17 @@ def moe_block(moe: Params, experts: Params, place, x: jnp.ndarray,
         taken = hidden[rows]                                 # [T * k, d]
 
     with jax.named_scope(trace.MOE_EXPERTS):
-        ragged = lambda lhs, name: jax.lax.ragged_dot(
+        meta = group_metadata(stack_sizes, T * k)     # one for the three
+        grouped = lambda lhs, name: grouped_matmul(
             lhs, experts[name].reshape(stack, *experts[name].shape[2:]),
-            stack_sizes)
-        act = jax.nn.silu(ragged(taken, "gate")) * ragged(taken, "up")
-        out = ragged(act, "down")                            # [T * k, d]
+            meta)
+        act = jax.nn.silu(grouped(taken, "gate")) * grouped(taken, "up")
+        out = grouped(act, "down")                           # [T * k, d]
 
     with jax.named_scope(trace.MOE_COMBINE):
-        # rows past the last group belong to no held expert: what the
-        # product left there is dropped, not scaled
+        # rows past the last group belong to no held expert: the product
+        # never wrote them (they hold anything, NaN too), so what is there
+        # is dropped, not scaled
         in_group = (sorted_group < held)[:, None]
         out = jnp.where(in_group, out, 0)
         back = jnp.zeros((T * k,), jnp.int32).at[order].set(
@@ -392,7 +401,7 @@ def moe_block(moe: Params, experts: Params, place, x: jnp.ndarray,
 
     counters = jnp.stack([
         jnp.sum(ok) * k, jnp.sum(here), jnp.sum(sizes > 0), jnp.max(sizes),
-        jnp.int32(held)]).astype(jnp.int32)
+        jnp.int32(held), meta.visits]).astype(jnp.int32)
     return x + y.reshape(b, s, d).astype(x.dtype), counters
 
 

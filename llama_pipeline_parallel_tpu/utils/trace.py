@@ -163,7 +163,7 @@ EVA_SCOPES = (EVA_POOL, EVA_SUMMARY_WRITE, EVA_ATTN, EVA_ATTN_PREFILL)
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 
-# `name=` of the fourteen pallas_calls: the kernel's instruction in a trace is
+# `name=` of the fifteen pallas_calls: the kernel's instruction in a trace is
 # `<name>.<n>`
 KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
@@ -179,6 +179,7 @@ KERNEL_SPARSE_LATENT_ATTN = "sparse_latent_attn"  # under SPARSE_ATTN
 KERNEL_PAGED_LATENT_DECODE_ATTN = "paged_latent_decode_attn"  # under LATENT_READ
 KERNEL_LATENT_PREFILL_ATTN = "latent_prefill_attn"  # under LATENT_READ_PREFILL
 KERNEL_EVA_PREFILL_ATTN = "eva_prefill_attn"  # under EVA_ATTN_PREFILL
+KERNEL_GROUPED_MATMUL = "grouped_matmul"  # under MOE_EXPERTS
 
 KERNELS = tuple(v for k, v in sorted(globals().items())
                 if k.startswith("KERNEL_"))

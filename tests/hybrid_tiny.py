@@ -18,6 +18,7 @@ from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid  # noq
 from llama_pipeline_parallel_tpu.models.hybrid_moe.config import (  # noqa: E402
     HybridMoEConfig,
 )
+from llama_pipeline_parallel_tpu.utils import trace  # noqa: E402
 
 MODEL = {
     "hidden_size": 32, "num_hidden_layers": 8, "num_attention_heads": 4,
@@ -78,12 +79,15 @@ def biased(moe, case):
 
 def expert_operands(eqns, cfg):
     """Of a traced program's equations: (the leading size of every grouped
-    product's right operand, the equations whose result is ONE layer's
+    product's right operand, the one three-dimensional operand of the
+    `grouped_matmul` kernel; the equations whose result is ONE layer's
     experts, `[held, d, f]` or `[held, f, d]`)."""
     d, f = cfg.hidden_size, cfg.moe_intermediate_size
     alone = {(cfg.held, d, f), (cfg.held, f, d)}
-    products = [e.invars[1].aval.shape[0] for e in eqns
-                if e.primitive.name == "ragged_dot_general"]
+    products = [stack.aval.shape[0] for e in eqns
+                if e.primitive.name == "pallas_call"
+                and e.params["name"] == trace.KERNEL_GROUPED_MATMUL
+                for stack in e.invars if len(stack.aval.shape) == 3]
     sliced = [e for e in eqns
               if any(tuple(v.aval.shape) in alone for v in e.outvars)]
     return products, sliced

@@ -14,6 +14,7 @@ import pytest
 import hybrid_tiny as tiny
 from llama_pipeline_parallel_tpu.models.hybrid_moe import decode as hybrid_decode
 from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid
+from llama_pipeline_parallel_tpu.ops.grouped_matmul import row_tile
 
 TOL = 1e-4
 
@@ -156,7 +157,8 @@ def test_every_token_on_one_held_expert_loses_none():
         {"post_norm": layer["post_norm"], **moe}, x,
         jnp.ones(x.shape[:2], bool), cfg)
     tokens = x.shape[0] * x.shape[1]
-    assert counters.tolist() == [tokens * 4, tokens, 1, tokens, 4]
+    # one expert's run in one row tile: the product visits one pair
+    assert counters.tolist() == [tokens * 4, tokens, 1, tokens, 4, 1]
     np.testing.assert_allclose(out - x, want, atol=TOL)
 
 
@@ -174,10 +176,11 @@ def test_positions_that_are_not_valid_are_routed_nowhere():
 @pytest.mark.parametrize("place", [0, 1, 2])
 def test_a_layer_in_a_stack_of_three_is_the_layer_alone(place, case):
     """The grouped product takes the stack of every period's experts whole
-    and gives the other periods' experts no rows: output and all five
+    and gives the other periods' experts no rows: output and all six
     counters are, bit for bit, those of the layer's own leaves as a stack of
-    one. Among the cases a held expert that gets no row and every row on one
-    expert; two positions are not valid."""
+    one (the product visits the experts that have a row, wherever in the
+    stack they lie). Among the cases a held expert that gets no row and
+    every row on one expert; two positions are not valid."""
     cfg = tiny.config()
     layers = [tiny.weights.make_layer(tiny.SEED, index, tiny.MODEL, jnp.float32)
               for index in (1, 2, 3)]
@@ -200,7 +203,12 @@ def test_a_layer_in_a_stack_of_three_is_the_layer_alone(place, case):
     if case == "idle":
         assert counters[2] < cfg.held
     if case == "one":
-        assert counters.tolist() == [live * 4, live, 1, live, cfg.held]
+        assert counters.tolist() == [live * 4, live, 1, live, cfg.held, 1]
+    # an expert with a row is read once, and once more for each edge of a
+    # row tile its run crosses; an expert without one never
+    rows = x.shape[0] * x.shape[1] * 4
+    tiles = rows // row_tile(rows)
+    assert counters[2] <= counters[5] < counters[2] + tiles
     # and the neighbours' experts matter to nothing
     other = jax.tree.map(lambda a: a.at[(place + 1) % 3].set(7.0), stack)
     again, _ = block(moe, other, jnp.int32(place), x, valid, cfg=cfg)

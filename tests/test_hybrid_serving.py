@@ -193,6 +193,9 @@ def test_the_engine_serves_the_family_through_the_same_tick_and_spans():
     assert 0 < total["routed_here"] < total["routed_total"]
     assert total["experts_held"] == sum(s["ticks"] for s in ticks) * 8 * 8
     assert total["experts_hit"] <= total["routed_here"]
+    # a tick's rows lie in one row tile: an expert with a row is read once
+    assert total["expert_visits"] == total["experts_hit"]
+    assert all(s["expert_visits"] >= s["experts_hit"] > 0 for s in prefills)
     assert all(set(("stage_s", "dispatch_s", "wait_s", "emit_s")) <= set(s)
                for s in ticks)
     # the engine's own count of the pages the tick's attention reads, of
@@ -329,8 +332,10 @@ def test_both_stores_ride_the_period_loops_carry():
 @pytest.mark.parametrize("program", ["tick", "prefill"])
 def test_the_grouped_products_take_the_stack_of_periods_whole(program):
     """The alarm for the slice coming back (on the chip a slice of the
-    stacked experts in front of `ragged_dot` is a copy of a layer's experts,
-    every product: PERF.md, PR 33): in both programs of the family, every
+    stacked experts in front of the grouped product's kernel is a copy of a
+    layer's experts, every product: PERF.md, PR 33; the kernel is
+    `ops/grouped_matmul.py`'s `pallas_call` since PR 43, and its right
+    operand is still the stored leaf seen as periods x held experts): in both programs of the family, every
     grouped product's right operand leads with periods x held experts, and
     no equation inside or outside the loop over periods makes an array of
     one layer's expert shape."""
@@ -380,7 +385,8 @@ def test_the_ticks_temporaries_are_smaller_than_either_store():
     assert sorted(e.primitive.name for e in large) == [
         "reshape", "reshape", "scatter", "scatter"], large
     kernel, = [e for e in _equations(body)
-               if e.primitive.name == "pallas_call"]
+               if e.primitive.name == "pallas_call"
+               and e.params["name"] == trace.KERNEL_PAGED_DECODE_ATTN]
     assert {e.outvars[0] for e in large
             if e.primitive.name == "reshape"} <= set(kernel.invars)
 
@@ -398,8 +404,10 @@ def test_the_tick_reads_its_pages_where_they_lie():
         lambda *a: hybrid_decode.paged_decode_step(*a, cfg))(*args).jaxpr
     kernels = [e for e in _equations(jaxpr)
                if e.primitive.name == "pallas_call"]
+    # one softmax layer a period, and three grouped products an expert layer
     assert [e.params["name"] for e in kernels] == [
-        trace.KERNEL_PAGED_DECODE_ATTN]
+        trace.KERNEL_PAGED_DECODE_ATTN] + [
+        trace.KERNEL_GROUPED_MATMUL] * 3 * cfg.attn_period
     rows = (SLOTS, MAX_LEN // PAGE) + pool["k"].shape[2:]
     assert not [e for e in _equations(jaxpr) if e.primitive.name == "gather"
                 and tuple(e.outvars[0].aval.shape) == rows]

@@ -32,6 +32,7 @@ from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.ops import (
     eva_prefill_attention,
+    grouped_matmul,
     latent_prefill_attention,
     paged_attention,
     paged_latent_attention,
@@ -198,7 +199,8 @@ def mosaic(monkeypatch):
     which is the CPU here whatever the program is compiled for."""
     monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
     for module in (sparse_latent_attention, paged_latent_attention,
-                   latent_prefill_attention, eva_prefill_attention):
+                   latent_prefill_attention, eva_prefill_attention,
+                   grouped_matmul):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without one
@@ -619,3 +621,77 @@ def test_a_compressed_window_program_compiled_for_the_chip_keeps_its_pool_in_pla
     assert analysis.alias_size_in_bytes >= nbytes(pool)
     assert analysis.temp_size_in_bytes < nbytes(pool) // 4, analysis
     assert kernel in compiled.as_text()
+
+
+# -- the expert layer's grouped product, compiled for the same chip ----------------
+
+# cell: (periods, experts held, d, f, rows a tick, top-k)
+EXPERT_CELLS = {
+    "serve-closed-64.solar-open2": (1, 40, 4096, 1280, 64, 8),
+    "serve-long-32.dots3": (1, 32, 5120, 1536, 32, 8),
+    "serve-longdoc-32.a.x-k1": (4, 12, 7168, 2048, 32, 8),
+}
+
+
+@pytest.mark.parametrize("tokens", ["tick", 2048])
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_mosaic_compiles_the_grouped_product_at_the_expert_cells_shapes(
+        one_chip, mosaic, cell, tokens):
+    """bf16, the stack of every period as stored, a tick's `T * k` rows and
+    a 2048-token unit's 16,384, the `gate` / `up` orientation and `down`'s:
+    Mosaic takes the blocks (whole-width weight blocks of 2 to 4 MB, a
+    float32 accumulator beside them), and XLA:TPU hands the stack to the
+    kernel as it lies: no temporary as large as ONE expert's matrix."""
+    periods, held, d, f, tick_rows, k = EXPERT_CELLS[cell]
+    m = (tick_rows if tokens == "tick" else tokens) * k
+    stack = periods * held
+    sizes = jax.ShapeDtypeStruct((stack,), jnp.int32)
+
+    def product(lhs, rhs, sizes):
+        return grouped_matmul.grouped_matmul(
+            lhs, rhs, grouped_matmul.group_metadata(sizes, m))
+
+    for kk, nn in ((d, f), (f, d)):
+        args = _described(
+            (jax.ShapeDtypeStruct((m, kk), jnp.bfloat16),
+             jax.ShapeDtypeStruct((stack, kk, nn), jnp.bfloat16), sizes),
+            one_chip)
+        compiled = jax.jit(product).lower(*args).compile()
+        text = compiled.as_text()
+        assert "grouped_matmul" in text and "tpu_custom_call" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < kk * nn * 2
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_an_expert_layer_compiled_for_the_chip_makes_no_copy_of_the_stack(
+        one_chip, mosaic, cell):
+    """`moe_block` at a tick's rows and the cell's real expert widths, the
+    stack of every period an argument and `place` traced: three grouped
+    kernels, and temporaries in the class PR 33 left the A.X-K1 tick in
+    (8.7 MB for the whole tick; a copy of ONE layer's experts in front of
+    one product would be 210 to 352 MB)."""
+    periods, held, d, f, rows, k = EXPERT_CELLS[cell]
+    cfg = HybridMoEConfig(
+        vocab_size=256, hidden_size=d, num_hidden_layers=4 * periods,
+        num_attention_heads=64, num_key_value_heads=8, kda_heads=2,
+        kda_rank=16, router_experts=8 * held, experts_held=held,
+        num_experts_per_tok=k, moe_intermediate_size=f,
+        shared_intermediate_size=f, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    moe = {"post_norm": bf16(d),
+           "router": jax.ShapeDtypeStruct((d, 8 * held), jnp.float32),
+           "router_bias": jax.ShapeDtypeStruct((8 * held,), jnp.float32),
+           "shared_gate": bf16(d, f), "shared_up": bf16(d, f),
+           "shared_down": bf16(f, d)}
+    experts = {"gate": bf16(periods, held, d, f), "up": bf16(periods, held, d, f),
+               "down": bf16(periods, held, f, d)}
+    args = _described(
+        (moe, experts, jax.ShapeDtypeStruct((), jnp.int32), bf16(rows, 1, d),
+         jax.ShapeDtypeStruct((rows, 1), jnp.bool_)), one_chip)
+    compiled = jax.jit(
+        lambda *a: hybrid.moe_block(*a, cfg)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3 and "grouped_matmul" in text
+    assert "ragged" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20

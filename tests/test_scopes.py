@@ -19,6 +19,7 @@ from llama_pipeline_parallel_tpu.models.llama.manifest import StageManifest
 from llama_pipeline_parallel_tpu.ops import (
     eva_prefill_attention,
     flash_attention,
+    grouped_matmul,
     latent_prefill_attention,
     paged_attention,
     paged_latent_attention,
@@ -194,6 +195,7 @@ def test_every_product_and_kernel_call_carries_a_leaf_scope(program, devices):
     (paged_latent_attention, ("KERNEL_PAGED_LATENT_DECODE_ATTN",)),
     (latent_prefill_attention, ("KERNEL_LATENT_PREFILL_ATTN",)),
     (eva_prefill_attention, ("KERNEL_EVA_PREFILL_ATTN",)),
+    (grouped_matmul, ("KERNEL_GROUPED_MATMUL",)),
 ])
 def test_every_pallas_call_passes_its_name(module, kernels):
     source = inspect.getsource(module)
@@ -202,7 +204,7 @@ def test_every_pallas_call_passes_its_name(module, kernels):
     for constant in kernels:
         assert source.count(f"name=trace.{constant},") == 1
         assert getattr(trace, constant) in trace.KERNELS
-    assert len(trace.KERNELS) == 14 == len(set(trace.KERNELS))
+    assert len(trace.KERNELS) == 15 == len(set(trace.KERNELS))
 
 
 def test_flash_kernel_name_reaches_the_lowered_program():
