@@ -1167,19 +1167,6 @@ def _unit_schedule_for(pcfg: PipelineConfig):
                              pcfg.offload_wgrad, counts)
 
 
-def flush_unit_schedule(pcfg: PipelineConfig):
-    """The PER-FLUSH unit sequence this config's interpreter executes —
-    the schedule observatory's plan source (utils/timeline.py keys its
-    measured segment durations against this sequence's segment
-    decomposition, so the timed boundaries and the compiled scans share
-    one grouping). None for gpipe (no unit sequence)."""
-    if pcfg.schedule not in UNIT_SCHEDULES:
-        return None
-    return _unit_schedule_for(dataclasses.replace(
-        pcfg, num_microbatches=pcfg.num_microbatches // pcfg.accum_chunks,
-        accum_chunks=1))
-
-
 def schedule_slot_counts(pcfg: PipelineConfig) -> list[dict] | None:
     """Per stage, the F, B and W slots one optimizer step executes and how
     many of each are masked (unit index < 0: full-price device work that
@@ -1189,9 +1176,12 @@ def schedule_slot_counts(pcfg: PipelineConfig) -> list[dict] | None:
     for gpipe, which scans no unit tables."""
     import numpy as np
 
-    us = flush_unit_schedule(pcfg)
-    if us is None:
+    if pcfg.schedule not in UNIT_SCHEDULES:
         return None
+    # the PER-FLUSH unit sequence this config's interpreter executes
+    us = _unit_schedule_for(dataclasses.replace(
+        pcfg, num_microbatches=pcfg.num_microbatches // pcfg.accum_chunks,
+        accum_chunks=1))
     counts = [{"stage": s, "f": 0, "f_masked": 0, "b": 0, "b_masked": 0,
                "w": 0, "w_masked": 0} for s in range(pcfg.num_stages)]
     for seg in usched.segments(us):
@@ -1216,21 +1206,6 @@ def _canonical_cached(schedule: str, m: int, s: int, v: int,
                                      stage_costs=stage_costs)
 
 
-def _timeline_mark(boundary: int, stage, probe):
-    """One timeline boundary mark (utils/timeline.py): a host callback
-    recording (boundary, stage, perf_counter) when THIS device's execution
-    reaches the boundary. Returns a f32 scalar (always 0.0) the caller must
-    fold back into the live carry — the data dependence is what pins the
-    callback's schedule position (and keeps DCE off it); `jnp.where(ts <
-    inf, x, 0)` returns x bit-exactly, so timeline mode ON never changes a
-    value, only adds the boundary sync."""
-    from llama_pipeline_parallel_tpu.utils import timeline as tl
-
-    return jax.pure_callback(
-        tl.mark_callback, jax.ShapeDtypeStruct((), jnp.float32),
-        jnp.int32(boundary), stage, probe)
-
-
 def _pipeline_units_local(
     params: Params,
     batch: Batch,
@@ -1240,7 +1215,6 @@ def _pipeline_units_local(
     global_count: jnp.ndarray,
     us,
     collect_stats: bool = False,
-    timeline_marks: bool = False,
 ) -> tuple:
     """The unit-sequence INTERPRETER: executes any validated UnitSchedule
     (parallel/schedule.py) inside shard_map — the single replacement for
@@ -1644,31 +1618,6 @@ def _pipeline_units_local(
                                           cfg.dtype))
         carry = carry + wq0
 
-    def boundary_mark(bidx: int, carry):
-        """Timeline boundary (opt-in, `timeline.enabled`): record this
-        stage's wall clock at the edge between two compiled segments, then
-        tie the returned scalar back into the small carry heads so the
-        callback is scheduled exactly at the boundary (and survives DCE).
-        The where-select returns its operand unchanged — timeline ON is
-        value-identical to OFF, and OFF compiles no callback at all (the
-        jaxpr pin in tests/test_timeline.py). loss_acc's dead branch is
-        NaN, not zero: at boundary 0 the whole carry is constant zeros,
-        and XLA folds `select(p, 0, 0)` to 0, which orphans the (pure)
-        callback and drops the flush_start mark."""
-        if not timeline_marks:
-            return carry
-        x_recv, dy_recv, xbuf, gacc, loss_acc, act_stats, *wq = carry
-        probe = (x_recv[0, 0, 0].astype(jnp.float32)
-                 + dy_recv[0, 0, 0].astype(jnp.float32) + loss_acc
-                 + jax.tree.leaves(gacc)[0].ravel()[0])
-        ts = _timeline_mark(bidx, stage, probe)
-        keep = ts < jnp.float32(float("inf"))
-        x_recv = jnp.where(keep, x_recv, jnp.zeros_like(x_recv))
-        dy_recv = jnp.where(keep, dy_recv, jnp.zeros_like(dy_recv))
-        loss_acc = jnp.where(keep, loss_acc, jnp.float32(float("nan")))
-        return (x_recv, dy_recv, xbuf, gacc, loss_acc, act_stats, *wq)
-
-    carry = boundary_mark(0, carry)
     for seg in usched.segments(us):
         if seg.has_w and not (seg.has_f or seg.has_b):
             x_recv, dy_recv, xbuf, gacc, loss_acc, act_stats, *wq = carry
@@ -1685,7 +1634,6 @@ def _pipeline_units_local(
             carry, _ = jax.lax.scan(
                 make_seg_body(seg.has_f, seg.has_b, seg.has_w,
                               seg.ring_fwd, seg.ring_bwd), carry, xs)
-        carry = boundary_mark(seg.index + 1, carry)
     _, _, _, grads, loss_acc, act_stats, *_ = carry
 
     # loss_acc is nonzero on the last stage only (cond zero branch elsewhere)
@@ -1695,7 +1643,7 @@ def _pipeline_units_local(
 
 
 def _loss_and_grad_local(params, batch, cfg, pcfg, attn_fn,
-                         collect_stats=False, timeline_marks=False):
+                         collect_stats=False):
     """shard_map body: global-mean loss + fully reduced grads (+ per-stage
     activation stats when `collect_stats` — see utils/numerics.py).
 
@@ -1727,8 +1675,7 @@ def _loss_and_grad_local(params, batch, cfg, pcfg, attn_fn,
         def chunk_loss_and_grad(p, chunk_batch):
             out = _pipeline_units_local(p, chunk_batch, cfg, chunk_pcfg,
                                         attn_fn, global_count, us,
-                                        collect_stats=collect_stats,
-                                        timeline_marks=timeline_marks)
+                                        collect_stats=collect_stats)
             return out if collect_stats else (*out, _sched_act_stats_zero(pcfg))
     else:
         def chunk_loss(p, chunk_batch):
@@ -1874,7 +1821,6 @@ def make_pipeline_loss_and_grad(
     params_like: Params,
     attn_fn: Callable = attention,
     collect_stats: bool = False,
-    timeline_segments: bool = False,
 ) -> Callable[[Params, Batch], tuple]:
     """Build the (jit-able) SPMD loss+grad function over stage-stacked params.
 
@@ -1883,19 +1829,7 @@ def make_pipeline_loss_and_grad(
     per-stage stage-boundary activation stats, `{"act_absmax_per_stage",
     "act_rms_per_stage"}` as [num_stages] arrays sharded over pp — computed
     in-graph (utils/numerics.py; no host round-trip).
-    `timeline_segments` (the schedule observatory, utils/timeline.py)
-    compiles a host-callback boundary mark between the interpreter's
-    segment scans so the trainer can attribute a step's measured wall to
-    warmup/steady/drain/W-drain per stage; values are bit-identical either
-    way, and OFF (the default) compiles no callback at all — the program
-    is the same jaxpr as before the observatory existed. Unit-sequence
-    schedules only (gpipe's scan has no segment boundaries to mark).
     """
-    if timeline_segments and pcfg.schedule not in UNIT_SCHEDULES:
-        raise ValueError(
-            f"timeline.enabled needs a unit-sequence schedule "
-            f"({UNIT_SCHEDULES}); {pcfg.schedule!r} has no segment "
-            f"boundaries to time")
     if mesh.shape[AXIS_PP] != pcfg.num_stages:
         raise ValueError(
             f"PipelineConfig.num_stages={pcfg.num_stages} does not match the "
@@ -2010,8 +1944,7 @@ def make_pipeline_loss_and_grad(
         out_specs += (stats_specs,)
     fn = jax.shard_map(
         partial(_loss_and_grad_local, cfg=cfg, pcfg=pcfg, attn_fn=attn_fn,
-                collect_stats=collect_stats,
-                timeline_marks=timeline_segments),
+                collect_stats=collect_stats),
         mesh=mesh,
         in_specs=(param_specs, batch_specs(mesh)),
         out_specs=out_specs,

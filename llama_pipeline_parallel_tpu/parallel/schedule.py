@@ -417,11 +417,8 @@ class Segment:
     """One maximal run of ticks with identical structural flags — exactly
     the runs `pipeline._pipeline_units_local` compiles into one `lax.scan`
     each (the canonical sequences' warmup / steady / drain / W-drain
-    phases). Shared between the interpreter and the schedule observatory
-    (utils/timeline.py) so the timed boundaries and the executed scans can
-    never disagree about where a segment starts."""
+    phases)."""
 
-    index: int
     t0: int
     t1: int
     has_f: bool
@@ -429,83 +426,33 @@ class Segment:
     has_w: bool
     ring_fwd: bool
     ring_bwd: bool
-    label: str
 
     @property
     def num_ticks(self) -> int:
         return self.t1 - self.t0
 
+    @property
+    def label(self) -> str:
+        """The active halves by name: "F", "F+B", "B", "B+W", "W"."""
+        return "+".join(p for p, on in (("F", self.has_f), ("B", self.has_b),
+                                        ("W", self.has_w)) if on) or "idle"
+
 
 def segments(us: UnitSchedule) -> list[Segment]:
-    """The sequence's maximal equal-flag tick runs, in execution order.
-    Labels name the active halves ("F", "F+B", "B", "B+W", "W"); a repeated
-    label (possible for solver sequences with several same-shaped phases)
-    gets a "#k" suffix so every segment's label is unique within the
-    flush — timeline records key on it."""
+    """The sequence's maximal equal-flag tick runs, in execution order."""
     flags = list(zip(us.has_f.tolist(), us.has_b.tolist(),
                      us.has_w.tolist(), us.ring_fwd.tolist(),
                      us.ring_bwd.tolist()))
     out: list[Segment] = []
-    seen: dict[str, int] = {}
     t0 = 0
     while t0 < len(flags):
         t1 = t0
         while t1 < len(flags) and flags[t1] == flags[t0]:
             t1 += 1
         has_f, has_b, has_w, r_f, r_b = flags[t0]
-        parts = [p for p, on in (("F", has_f), ("B", has_b), ("W", has_w))
-                 if on]
-        label = "+".join(parts) if parts else "idle"
-        n = seen.get(label, 0)
-        seen[label] = n + 1
-        if n:
-            label = f"{label}#{n + 1}"
-        out.append(Segment(index=len(out), t0=t0, t1=t1, has_f=has_f,
-                           has_b=has_b, has_w=has_w, ring_fwd=r_f,
-                           ring_bwd=r_b, label=label))
+        out.append(Segment(t0=t0, t1=t1, has_f=has_f, has_b=has_b,
+                           has_w=has_w, ring_fwd=r_f, ring_bwd=r_b))
         t0 = t1
-    return out
-
-
-def segment_stats(us: UnitSchedule) -> list[dict]:
-    """Per-segment idle accounting in the same unit costs as bubble_stats:
-    for each segment, the lockstep wall units every stage is charged and
-    each stage's USEFUL units within it — so a measured per-segment
-    duration can be split into busy and idle time (the timeline layer's
-    measured bubble: weight each segment's scheduled idle fraction by its
-    measured wall instead of its scheduled one). Summing
-    (wall - useful) / wall over segments reproduces bubble_stats exactly;
-    the dicts also carry the per-stage busy fractions the straggler
-    report uses and the count of host-offloaded W units (transfer-stall
-    attribution)."""
-    bc = _cost_b(us.split_backward)
-    costs = us.stage_costs
-    s = us.num_stages
-    c = (np.ones(s, np.int64) if costs is None
-         else np.asarray(costs, np.int64))
-    cmax = int(c.max())
-    off = us.offload_units
-    out = []
-    for seg in segments(us):
-        sl = slice(seg.t0, seg.t1)
-        wall = (int(seg.has_f) * COST_F + int(seg.has_b) * bc
-                + int(seg.has_w) * COST_W) * seg.num_ticks * cmax
-        useful = (((us.f_unit[sl] >= 0) * c[None, :]).sum(0) * COST_F
-                  + ((us.b_unit[sl] >= 0) * c[None, :]).sum(0) * bc
-                  + ((us.w_unit[sl] >= 0) * c[None, :]).sum(0) * COST_W)
-        w_units = us.w_unit[sl]
-        host_w = 0
-        if us.split_backward and off.size:
-            live_w = np.unique(w_units[w_units >= 0])
-            host_w = int(off[live_w].sum()) if live_w.size else 0
-        out.append({
-            "label": seg.label,
-            "num_ticks": seg.num_ticks,
-            "wall_units": wall,
-            "useful_units": [int(u) for u in useful],
-            "busy_frac": [float(u) / wall if wall else 0.0 for u in useful],
-            "offloaded_w_units": host_w,
-        })
     return out
 
 

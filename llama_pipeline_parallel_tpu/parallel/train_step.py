@@ -211,15 +211,8 @@ def make_train_step(
     attn_fn: Callable | None = None,
     collect_stats: bool = False,
     poison: bool = False,
-    timeline: bool = False,
 ) -> Callable[..., tuple[TrainState, dict]]:
     """Build the donated, fully-sharded jitted train step.
-
-    `timeline` (the schedule observatory, utils/timeline.py) compiles the
-    pipeline's segment boundary marks into the step plus one
-    post-optimizer-update mark, so the trainer's per-step timeline can
-    split pipeline time from optimizer time. Values are bit-identical ON
-    vs OFF; OFF compiles no callbacks (the jaxpr pin).
 
     `collect_stats` (the numerics observatory, utils/numerics.py) adds
     in-graph per-stage/per-layer-group statistics under `metrics["numerics"]`
@@ -239,7 +232,7 @@ def make_train_step(
 
     loss_grad_fn = make_pipeline_loss_and_grad(
         mesh, cfg, pcfg, params_like, attn_fn=attn_fn or attention,
-        collect_stats=collect_stats, timeline_segments=timeline)
+        collect_stats=collect_stats)
     shardings = state_shardings(mesh, tx, params_like)
 
     def _step(state: TrainState, batch: dict, poison_stage
@@ -289,21 +282,6 @@ def make_train_step(
                     lambda new, old: jnp.where(finite, new, old),
                     new_opt_state, state.opt_state)
             metrics["numerics"] = stats
-        if timeline:
-            # post-update boundary mark: probe depends on the updated
-            # params (fires once the optimizer finished), tied into the
-            # loss output the loop blocks on (ordering + DCE anchor); the
-            # where returns loss bit-exactly (utils/timeline.py)
-            from llama_pipeline_parallel_tpu.utils import timeline as tl
-
-            probe = (jax.tree.leaves(new_params)[0].ravel()[0]
-                     .astype(jnp.float32) + metrics["loss"])
-            ts = jax.pure_callback(
-                tl.mark_callback, jax.ShapeDtypeStruct((), jnp.float32),
-                jnp.int32(tl.OPTIMIZER_BOUNDARY), jnp.int32(0), probe)
-            metrics["loss"] = jnp.where(ts < jnp.float32(float("inf")),
-                                        metrics["loss"],
-                                        jnp.zeros_like(metrics["loss"]))
         return TrainState(state.step + 1, new_params, new_opt_state), metrics
 
     batch_shardings = {k: NamedSharding(mesh, s)

@@ -361,7 +361,7 @@ class _Prefilling:
 
 class ServeEngine:
     def __init__(self, params: dict, cfg, serve_cfg: ServeConfig,
-                 metrics_writer=None, timeline=None, profiler=None,
+                 metrics_writer=None, profiler=None,
                  slo=None, reqtrace=None):
         """`params` in the CANONICAL (unstacked) layout —
         `ckpt.load_module_checkpoint` hands them out straight from any
@@ -374,14 +374,11 @@ class ServeEngine:
         family's programs would convert to the compute dtype at each use,
         converted once here (`_serving_weights`), the rest `params`' own.
 
-        Observatory hooks (docs/OBSERVABILITY.md): `timeline` (a
-        utils/timeline.TimelineWriter) gets one record per engine tick —
-        the prefill-chunk vs decode-step wall split, with the mid-prefill
-        request named — the serving counterpart of the trainer's
-        per-segment timeline. `slo` (telemetry.SLOThresholds) checks every
-        completed request; a breach bumps `slo_breaches` and fires
-        `profiler` (utils/profiler.TriggeredProfiler), whose bounded
-        capture window advances one tick per `step()`. `reqtrace` (a
+        Observatory hooks (docs/OBSERVABILITY.md): `slo`
+        (telemetry.SLOThresholds) checks every completed request; a breach
+        bumps `slo_breaches` and fires `profiler`
+        (utils/profiler.TriggeredProfiler), whose bounded capture window
+        advances one tick per `step()`. `reqtrace` (a
         reqtrace.RequestTraceRecorder) turns on the request observatory:
         one span tree per request written to request_trace.jsonl at
         completion (docs/SERVING.md "Request tracing"); None (the
@@ -400,7 +397,6 @@ class ServeEngine:
             serve_cfg.kv_quant, prefix_cache=serve_cfg.prefix_cache)
         self.stats = SLOStats()
         self._metrics_writer = metrics_writer
-        self._timeline = timeline
         self._profiler = profiler
         self._slo = slo
         self._reqtrace = reqtrace
@@ -410,7 +406,6 @@ class ServeEngine:
         if reqtrace is not None:
             # attribute page-pool hand-outs to the owning slot's request
             self.slots.alloc_listener = self._on_page_alloc
-        self._last_decode_dur = 0.0
         self._occupants: dict[int, _Running] = {}
         self._prefilling: deque = deque()   # chunked admissions
         self._queue: deque = deque()
@@ -670,22 +665,16 @@ class ServeEngine:
         one decode tick over all slots, then collect the tick enqueued at the
         boundary before. Returns False when there was nothing to do (caller
         may sleep)."""
-        t0 = time.perf_counter() if self._timeline is not None else 0.0
         self._cancel_abandoned()
-        pf_req = (self._prefilling[0].request.request_id
-                  if self._prefilling else None)
         # admission and prefill chunks: the loop's host work outside the
         # decode tick, one profiler event a step (`serve_prefill` nests in it)
         with trace.annotate(trace.SERVE_ADMIT):
             self._advance_prefill()
-        prefill_s = (time.perf_counter() - t0
-                     if self._timeline is not None else 0.0)
         if not self._occupants:
             # rows that overran their eos may be all a tick in flight holds
             self._collect()
             if self._prefilling:      # prefill-only tick is still work
-                self.steps += 1
-                self._note_tick(prefill_s, 0.0, pf_req)
+                self._tick_done()
                 return True
             self._flush_decode_span()  # idle boundary: publish the tail
             self._work.clear()
@@ -694,36 +683,15 @@ class ServeEngine:
                 self._work.set()
             return False
         self._decode_tick()
-        self.steps += 1
-        self._note_tick(prefill_s, self._last_decode_dur, pf_req)
+        self._tick_done()
         return True
 
-    def _note_tick(self, prefill_s: float, decode_s: float,
-                   pf_req: str | None) -> None:
-        """One serving timeline record per tick (opt-in): the prefill vs
-        decode wall split the SLO percentiles cannot show — a decode tick
-        stretched by interleaved prefill chunks is visible here per tick,
-        per mid-prefill request. Also advances an attached profiler's
-        bounded capture window."""
+    def _tick_done(self) -> None:
+        """Count the step; an attached profiler's bounded capture window
+        advances one tick per counted step."""
+        self.steps += 1
         if self._profiler is not None:
             self._profiler.observe_step(self.steps)
-        if self._timeline is None:
-            return
-        rec = {"tick": self.steps, "prefill_s": round(prefill_s, 6),
-               "decode_s": round(decode_s, 6),
-               "active": len(self._occupants),
-               "queue_depth": len(self._queue)}
-        # page-pool occupancy PER TICK: the fragmentation timeline — how
-        # the reserved-vs-allocated gap moves as requests admit, decode,
-        # and release (the snapshot gauges only show now)
-        rec["pages_used"] = self.slots.pages_used
-        rec["pages_reserved"] = self.slots.pages_reserved
-        rec["fragmentation"] = round(self.slots.fragmentation, 4)
-        if self.prefill_chunks_last_tick:
-            rec["prefill_chunks"] = self.prefill_chunks_last_tick
-        if pf_req is not None:
-            rec["prefilling_request"] = pf_req
-        self._timeline.write(rec)
 
     # -- cancellation (loop thread; the PR 18 "no-cancellation gap") --------
 
@@ -1030,9 +998,9 @@ class ServeEngine:
         host fetches and emits tick k-1 (and comes round the loop, admits and
         stages), the device runs tick k."""
         before = self._in_flight
-        self._in_flight = tick = self._dispatch_tick(before)
-        wait_s = self._collect_tick(before) if before is not None else 0.0
-        self._last_decode_dur = wait_s + (tick.dispatch_s if tick else 0.0)
+        self._in_flight = self._dispatch_tick(before)
+        if before is not None:
+            self._collect_tick(before)
 
     def _collect(self) -> None:
         """Read the tick in flight, if any, with none enqueued behind it."""
@@ -1124,7 +1092,7 @@ class ServeEngine:
             h2d_s=t_copied - t_grown, enqueue_s=t_enqueued - t_copied,
             h2d_copies=h2d_copies)
 
-    def _collect_tick(self, tick: _Tick) -> float:
+    def _collect_tick(self, tick: _Tick) -> None:
         """Read a dispatched tick, in two host phases: `wait` (`block`: until
         its tokens are ready; `fetch`: token, keys and counters to numpy, the
         tick's one transfer back) and `emit` (token push, finishes). A row
@@ -1134,7 +1102,7 @@ class ServeEngine:
         `serve_decode_step` span here, together. Each phase is a profiler
         annotation; the four phases, `h2d` and `enqueue` are also sums on the
         span (`TICK_SUMS`), whose `dur` stays dispatch + wait. One clock read
-        a boundary: no phase is timed twice. Returns the wait's seconds."""
+        a boundary: no phase is timed twice."""
         t_entry = time.perf_counter()
         with trace.annotate(trace.TICK_WAIT):
             # block, then convert: the device's gap while the host sleeps
@@ -1174,7 +1142,6 @@ class ServeEngine:
             tick, counters.tolist(), overrun, d2h_copies,
             wait_s=t_fetched - t_entry,
             emit_s=time.perf_counter() - t_fetched)
-        return t_fetched - t_entry
 
     def _note_decode_tick(self, tick: _Tick, counters: list, overrun: int,
                           d2h_copies: int, wait_s: float,

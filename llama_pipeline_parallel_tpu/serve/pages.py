@@ -383,8 +383,7 @@ class PagedKVCache:
         return self._page_bytes
 
     def fragmentation_gauges(self) -> dict:
-        """The page-pool occupancy snapshot `/healthz` and the serve
-        timeline publish each tick."""
+        """The page pool's occupancy gauges, in one dict."""
         out = {
             "pages_free": self.pages_free,
             "pages_used": self.pages_used,

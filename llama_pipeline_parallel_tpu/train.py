@@ -64,7 +64,6 @@ from llama_pipeline_parallel_tpu.utils import (
     numerics,
     perf,
     profiler as profiler_mod,
-    timeline as timeline_mod,
     trace,
 )
 from llama_pipeline_parallel_tpu.utils.config import instantiate
@@ -233,27 +232,15 @@ def _offload_static(pcfg: "pl.PipelineConfig", mb_rows: int,
             "offload_stash_resident_gib": round(resident / (1 << 30), 6)}
 
 
-def _make_observatory(cfg: dict, pcfg: "pl.PipelineConfig", output_dir: str,
+def _make_observatory(cfg: dict, output_dir: str,
                       stash_bytes: int | None = None) -> tuple:
     """The observatory's run-scoped pieces (docs/OBSERVABILITY.md): the
-    measured timeline driver (`timeline.*` config block — opt-in, blocks
-    on every step's loss when on), the triggered profiler (`profiler.*`
-    block — bounded capture windows on at_step / step-time z-score /
-    numerics-anomaly triggers), and the memory watch (`memory.*` block —
-    opt-in compiled-analysis capture + live per-step sampler; OFF
-    compiles and samples nothing). One construction for both optimizer
-    paths; `stash_bytes` is the host-stash resident estimate the
-    sampler's rows carry next to the device/host polls."""
-    tcfg = timeline_mod.TimelineConfig.from_cfg(cfg.get("timeline"))
-    step_tl = None
-    if tcfg.enabled:
-        step_tl = timeline_mod.StepTimeline(
-            pcfg, output_dir, write=jax.process_index() == 0,
-            window=tcfg.window)
-        logger.info(
-            "timeline enabled: per-segment boundary marks compiled into "
-            "the step, every step's loss fetch blocks (timeline.jsonl; "
-            "docs/OBSERVABILITY.md 'Timelines')")
+    triggered profiler (`profiler.*` block — bounded capture windows on
+    at_step / step-time z-score / numerics-anomaly triggers) and the memory
+    watch (`memory.*` block — opt-in compiled-analysis capture + live
+    per-step sampler; OFF compiles and samples nothing). One construction
+    for both optimizer paths; `stash_bytes` is the host-stash resident
+    estimate the sampler's rows carry next to the device/host polls."""
     pcap = profiler_mod.CaptureConfig.from_cfg(cfg.get("profiler"))
     if pcap is None:
         # no `profiler:` block arms ONLY the fleet trigger-file surface
@@ -273,39 +260,18 @@ def _make_observatory(cfg: dict, pcfg: "pl.PipelineConfig", output_dir: str,
             "memory watch enabled: compiled memory_analysis captured per "
             "program, live sampler every %d step(s) (memory.jsonl; "
             "docs/OBSERVABILITY.md 'Memory')", mcfg.every)
-    return step_tl, prof, mem_watch
+    return prof, mem_watch
 
 
-def _write_perf_rows(cfg: dict, pcfg: "pl.PipelineConfig", output_dir: str,
-                     step_tl, mem_watch=None) -> None:
-    """Close the run into the perf ledger (utils/perf.py): the analytic
-    bubble next to its timeline-measured counterpart plus the rolling
-    step-time percentiles, and — with the memory watch on — the
-    compiled-vs-live memory rows (`mem_peak_gib`,
+def _write_perf_rows(output_dir: str, mem_watch) -> None:
+    """Close the run into the perf ledger (utils/perf.py): with the memory
+    watch on, the compiled-vs-live memory rows (`mem_peak_gib`,
     `compiled_peak_gib:<label>`) — the trainer's contribution to the
     model-vs-measured calibration table tools/perf_report.py renders."""
-    if (step_tl is None and mem_watch is None) or jax.process_index() != 0:
+    if mem_watch is None or jax.process_index() != 0:
         return
-    rows = []
-    if step_tl is not None:
-        rows.append(perf.make_row(
-            "bubble_fraction", model=pl.bubble_fraction(pcfg),
-            measured=step_tl.measured_bubble_median(), source="train",
-            run=output_dir, schedule=pcfg.schedule,
-            virtual_stages=pcfg.virtual_stages))
-        sc = step_tl.scalars()
-        if "step_time_p50" in sc:
-            rows.append(perf.make_row(
-                "step_time_s", measured=sc["step_time_p50"], unit="s",
-                source="train", run=output_dir, p95=sc.get("step_time_p95")))
-        peak_bytes, src = trace.device_peak_bytes()
-        if peak_bytes is not None and src == "device":
-            rows.append(perf.make_row(
-                "peak_gib", measured=peak_bytes / (1 << 30), unit="GiB",
-                source="train", run=output_dir))
-    if mem_watch is not None:
-        rows.extend(mem_watch.perf_rows(run=output_dir))
-    perf.append_rows(os.path.join(output_dir, "perf.jsonl"), rows)
+    perf.append_rows(os.path.join(output_dir, "perf.jsonl"),
+                     mem_watch.perf_rows(run=output_dir))
 
 
 def _schedule_static_scalars(pcfg: "pl.PipelineConfig") -> dict:
@@ -719,6 +685,12 @@ def _release_preemption_handlers() -> None:
 
 def run_training(cfg: dict) -> dict:
     """The full training run; returns a summary dict for programmatic callers."""
+    if "timeline" in cfg:
+        raise ValueError(
+            "the timeline config block is gone with its host callbacks: the "
+            "schedule's time is read from the device trace (profiler.at_step "
+            "or profile_steps, then tools/trace_summary.py; the benchmark's "
+            "bubble_share.train)")
     if "compilation_cache_dir" in cfg:
         raise ValueError(
             "the compilation_cache_dir config key is gone: set "
@@ -910,18 +882,14 @@ def _run_training(cfg: dict) -> dict:
     # the step when the active fault plan carries such a rule — steady-state
     # runs keep the two-argument signature (no extra per-step H2D).
     poison_on = faults.has_rule("step", "grad_nonfinite")
-    step_tl, prof, mem_watch = _make_observatory(
-        cfg, pcfg, output_dir,
+    prof, mem_watch = _make_observatory(
+        cfg, output_dir,
         stash_bytes=pl.host_stash_bytes(pcfg, *pl.stash_dims(
             micro_batch, seq_length, mesh_cfg.sp, model_cfg.hidden_size,
             model_cfg.dtype)))
     step_fn = ts.make_train_step(mesh, model_cfg, pcfg, tx, schedule,
                                  stacked_template, attn_fn=attn_fn,
-                                 collect_stats=ncfg.enabled, poison=poison_on,
-                                 # gpipe has no segments: marks stay out and
-                                 # the timeline degrades to step-wall records
-                                 timeline=step_tl is not None
-                                 and step_tl.segmented)
+                                 collect_stats=ncfg.enabled, poison=poison_on)
 
     # ---- loop -------------------------------------------------------------
     state_box = [state]
@@ -989,7 +957,7 @@ def _run_training(cfg: dict) -> dict:
             monitor=monitor, data_start=data_start,
             health_static={**_schedule_health_static(pcfg, topology),
                            **off_static},
-            step_timeline=step_tl, profiler=prof, mem_watch=mem_watch,
+            profiler=prof, mem_watch=mem_watch,
             profile_facts=profile_facts)
     except BaseException:
         # join the in-flight commit, but never let ITS failure replace the
@@ -1001,7 +969,7 @@ def _run_training(cfg: dict) -> dict:
                              "unwinding a training error")
         raise
     mgr.finalize()  # surface any async-commit failure on the clean path
-    _write_perf_rows(cfg, pcfg, output_dir, step_tl, mem_watch)
+    _write_perf_rows(output_dir, mem_watch)
     return _summarize(final_loss, preempted_at, end_step, steps_per_epoch,
                       output_dir)
 
@@ -1274,7 +1242,7 @@ def _host_scalars(collator, loader) -> Any:
 def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                 do_step, do_save, do_eval=None, extra_scalars=None,
                 static_scalars=None, monitor=None, data_start=(0, 0),
-                health_static=None, step_timeline=None, profiler=None,
+                health_static=None, profiler=None,
                 mem_watch=None, profile_facts=None) -> tuple:
     """The shared step/log/save/profile loop for both optimizer paths.
 
@@ -1293,11 +1261,7 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
     `data_start` ((epoch, batch), from _resume_data_position) opens the
     repeating loader at the O(1) resume position; `health_static`
     (optional dict, e.g. the run topology) rides on every health.json write.
-    `step_timeline` (timeline.StepTimeline, optional — the schedule
-    observatory) wraps every step with the collector window, BLOCKS on each
-    step's loss (the marks-to-steps barrier), and contributes
-    `bubble_fraction_measured` / `step_time_p50/p95` to the metrics line +
-    health.json. `profiler` (profiler.TriggeredProfiler, optional) gets
+    `profiler` (profiler.TriggeredProfiler, optional) gets
     each iteration's host wall for the step-time z-score trigger, the
     numerics-anomaly span stream, and a close() on every exit path.
     `profile_facts` (dict, optional, `_profile_window_facts`) rides on the
@@ -1340,19 +1304,12 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                            already_elapsed=init_secs)
     clock.add("init", init_secs)
     rec.add_listener(clock.on_span)
-    # LIVE health.json contributions: the numerics monitor's fields plus the
-    # timeline's rolling bubble_fraction_measured / step_time percentiles —
-    # a ChainMap so both owners keep mutating their own dict between writes
-    import collections as _collections
-
-    live_fields = [m for m in (
-        monitor.health_fields if monitor is not None else None,
-        step_timeline.health_fields if step_timeline is not None else None)
-        if m is not None]
+    # the numerics monitor's health fields are LIVE: it keeps mutating its
+    # own dict between health.json writes
     heartbeat = (trace.Heartbeat(output_dir, clock,
                                  interval=cfg.get("health_interval", 10.0),
-                                 extra=(_collections.ChainMap(*live_fields)
-                                        if live_fields else None),
+                                 extra=(monitor.health_fields
+                                        if monitor is not None else None),
                                  static=health_static)
                  if jax.process_index() == 0 else None)
     peak_bytes, peak_src = trace.device_peak_bytes()
@@ -1479,8 +1436,6 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                 trace.wallclock_anchor()  # every capture holds at least one
             with trace.span("data_wait", step=step):
                 batch = next(it)
-            if step_timeline is not None:
-                step_timeline.pre_step(step + 1)
             try:
                 if step == resume_step:
                     # First step: trace+XLA-compile happen synchronously
@@ -1503,10 +1458,6 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                 completed = step + 1
                 raise
             completed = step + 1
-            if step_timeline is not None:
-                # block-on-boundary: the marks-to-steps barrier (and the
-                # measured step wall) — the timeline mode's documented cost
-                step_timeline.post_step(step + 1, loss)
             if mem_watch is not None:
                 # host-side poll only (memory_stats + RSS) — never touches
                 # the dispatched computation; `memory.every` rate-limits it
@@ -1554,8 +1505,6 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
                                       **(static_scalars or {}),
                                       **(monitor.scalars() if monitor is not None
                                          else {}),
-                                      **(step_timeline.scalars()
-                                         if step_timeline is not None else {}),
                                       "goodput": round(clock.goodput(), 4),
                                       "step_time": round(step_dur, 4),
                                       "device_peak_bytes": peak_bytes})
@@ -1618,8 +1567,6 @@ def _train_loop(cfg, model_cfg, mesh, loader, seq_length, resume_step, end_step,
         if profiler is not None:
             rec.remove_listener(profiler.on_span)
             profiler.close()  # a capture window open at exit is finalized
-        if step_timeline is not None:
-            step_timeline.close()
         if mem_watch is not None:
             mem_watch.close()
         if monitor is not None:
@@ -1822,15 +1769,14 @@ def _run_offload(cfg, mesh, model_cfg, manifest, pcfg, ocfg, dataset, collator,
                                model_cfg=model_cfg,
                                packed=_packing_factor(cfg) > 1,
                                micro_batch=cfg.get("per_device_train_batch_size", 1))
-    step_tl, prof, mem_watch = _make_observatory(
-        cfg, pcfg, output_dir,
+    prof, mem_watch = _make_observatory(
+        cfg, output_dir,
         stash_bytes=pl.host_stash_bytes(pcfg, *pl.stash_dims(
             cfg.get("per_device_train_batch_size", 1), seq_length,
             mesh.shape["sp"], model_cfg.hidden_size, model_cfg.dtype)))
     loss_and_grad = pl.make_pipeline_loss_and_grad(
         mesh, model_cfg, pcfg, stacked_template, attn_fn=attn_fn,
-        collect_stats=ncfg.enabled,
-        timeline_segments=step_tl is not None and step_tl.segmented)
+        collect_stats=ncfg.enabled)
     from jax.sharding import NamedSharding, PartitionSpec
 
     def _replicate_stats(stats):
@@ -1913,14 +1859,8 @@ def _run_offload(cfg, mesh, model_cfg, manifest, pcfg, ocfg, dataset, collator,
         # + H2D upload instead of a serial update-all-then-upload-all
         # (a nonfinite global norm skips the masters update, see
         # HostOffloadAdamW.skip_nonfinite)
-        t_opt = time.perf_counter()
         device_params_box[0] = to_replicated(
             host.update_and_refresh(grads, model_cfg.dtype))
-        if step_tl is not None:
-            # the host optimizer is outside the compiled pipeline, so its
-            # phase is measured here instead of by a boundary mark
-            step_tl.add_host_segment("optimizer_host",
-                                     time.perf_counter() - t_opt)
         if monitor is not None:
             monitor.observe(step, loss, host.last_grad_norm, stats)
         return loss, lambda: {"lr": host.last_lr,
@@ -1961,8 +1901,8 @@ def _run_offload(cfg, mesh, model_cfg, manifest, pcfg, ocfg, dataset, collator,
         monitor=monitor, data_start=data_start,
         health_static={**_schedule_health_static(pcfg, topology),
                        **off_static},
-        step_timeline=step_tl, profiler=prof, mem_watch=mem_watch,
+        profiler=prof, mem_watch=mem_watch,
         profile_facts=profile_facts)
-    _write_perf_rows(cfg, pcfg, output_dir, step_tl, mem_watch)
+    _write_perf_rows(output_dir, mem_watch)
     return _summarize(final_loss, preempted_at, end_step, len(loader),
                       output_dir)

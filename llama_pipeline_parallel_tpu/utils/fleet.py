@@ -2,7 +2,7 @@
 (docs/OBSERVABILITY.md "Fleet").
 
 The repo's observability so far is per-process — spans/goodput (PR 1),
-numerics (PR 3), timelines/perf-ledger/triggered capture (PR 14) all live
+numerics (PR 3), perf-ledger/triggered capture (PR 14) all live
 in ONE run directory. A pod is many of those at once: a supervised trainer
 plus N serve replicas, each with its own supervisor, health.json, and
 metrics stream. MPMD pipeline training at scale (PAPERS.md, arxiv
@@ -442,10 +442,8 @@ class AlertRules:
 # ---------------------------------------------------------------------------
 
 # trainer metrics-line fields the rollup keeps (last value wins)
-_TRAIN_FIELDS = ("loss", "goodput", "bubble_fraction",
-                 "bubble_fraction_measured", "step_time", "step_time_p50",
-                 "step_time_p95", "nonfinite_steps", "anomaly_count", "mfu",
-                 "tokens_per_sec")
+_TRAIN_FIELDS = ("loss", "goodput", "bubble_fraction", "step_time",
+                 "nonfinite_steps", "anomaly_count", "mfu", "tokens_per_sec")
 # serving metrics-line fields the rollup keeps
 _SERVE_FIELDS = ("requests_completed", "requests_rejected", "requests_failed",
                  "requests_page_refused", "slo_breaches", "tokens_generated",
@@ -635,19 +633,15 @@ class FleetAggregator:
         clock = health.get("clock")
         if isinstance(clock, dict):
             status["elapsed_s"] = _num(clock.get("elapsed"))
-        # step-time percentiles: the member's own rolling fields when the
-        # timeline mode publishes them, else derived from the tailed
-        # metrics step_time stream
-        p50 = _num(health.get("step_time_p50")) or _percentile(
-            tail.step_times, 50)
-        p95 = _num(health.get("step_time_p95")) or _percentile(
-            tail.step_times, 95)
+        # step-time percentiles of the tailed metrics step_time stream
+        p50 = _percentile(tail.step_times, 50)
+        p95 = _percentile(tail.step_times, 95)
         if p50 is not None:
             status["step_time_p50"] = round(p50, 4)
         if p95 is not None:
             status["step_time_p95"] = round(p95, 4)
-        for key in ("bubble_fraction", "bubble_fraction_measured",
-                    "nonfinite_steps", "anomaly_count", "mfu", "loss"):
+        for key in ("bubble_fraction", "nonfinite_steps", "anomaly_count",
+                    "mfu", "loss"):
             val = tail.train_last.get(key, health.get(key))
             if val is not None:
                 out_key = ("bubble_fraction_analytic"

@@ -18,7 +18,7 @@ counterpart, from three independent sources:
    TPU), host RSS, and the host-stash/offload resident estimate into an
    opt-in `memory.jsonl`. OFF is zero overhead: the sampler never
    touches the compiled graph (no callback, no extra output — pinned in
-   tests/test_memwatch.py like `timeline.enabled`).
+   tests/test_memwatch.py).
 3. **serving** — the page-pool occupancy / fragmentation gauges
    (serve/engine.py reads serve/pages.py; this module only defines the
    shared reader + snapshot plumbing).
@@ -57,8 +57,7 @@ OOM_KEEP_ROWS = 32
 @dataclasses.dataclass(frozen=True)
 class MemoryConfig:
     """The `memory.*` config block, parsed in one place (train.py +
-    tools/serve.py agree on the keys; unknown keys rejected like
-    `timeline.*`)."""
+    tools/serve.py agree on the keys; unknown keys rejected)."""
 
     enabled: bool = False
     every: int = 1  # sample every N steps

@@ -17,9 +17,9 @@ record rounds that produced NO number (the five TPU-unreachable bench
 rounds) so `tools/perf_report.py` can summarize "N rounds unreachable"
 instead of silently showing an empty table.
 
-Writers: train.py (timeline-measured bubble vs the analytic one, step
-walls), bench.py (every `extra:*` row family's model-vs-measured point,
-plus probe-failure rounds), tools/serve.py (SLO percentiles). Readers:
+Writers: train.py (the memory watch's compiled-vs-live rows), bench.py
+(every `extra:*` row family's model-vs-measured point, plus probe-failure
+rounds), tools/serve.py (SLO percentiles). Readers:
 tools/perf_report.py (calibration table + the recalibrated constants file
 `preflight --select --calibration` consumes).
 
@@ -82,9 +82,7 @@ def read_jsonl(path: str, keep=None) -> list[dict]:
     """THE tolerant jsonl reader (the goodput_report house rule, spelled
     once): every parseable dict record of a line stream —
     missing/empty/torn/garbage lines degrade to whatever parses. `keep`
-    (optional predicate over a parsed dict) filters records; shared by the
-    perf ledger and the timeline reader so the degrade semantics cannot
-    drift between them."""
+    (optional predicate over a parsed dict) filters records."""
     rows: list[dict] = []
     try:
         with open(path) as f:
@@ -122,7 +120,7 @@ def rows_from_bench_summary(summary: dict, run: str = "bench") -> list[dict]:
     - `extra:sched-*` / `extra:layout-*`: measured step seconds, with the
       layout rows' `score_s_model` as the model half and every sched
       row's `bubble_fraction_analytic` carried in context (its measured
-      counterpart is the trainer's timeline, not bench);
+      counterpart is the device trace's `bubble_share.train`, not bench);
     - `extra:offload-bw`: measured host-link bandwidth (`host_bw_gibps`,
       the number `--calibration` feeds back into preflight);
     - `extra:offload-wgrad-stash`: `transfer_ms_model` vs the measured

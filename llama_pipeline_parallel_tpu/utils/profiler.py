@@ -7,8 +7,7 @@ run; this layer captures the step you could not have picked — fired by:
 - config (`profiler.at_step: [N, ...]` — capture when the loop reaches N);
 - step-time z-score outliers (a rolling window of per-step wall times; a
   step `profiler.zscore` standard deviations above the mean starts a
-  capture, so the straggler/stall that skews the timeline gets a per-op
-  trace attached);
+  capture, so the straggler/stall gets a per-op trace attached);
 - numerics anomalies (the PR 3 observatory emits zero-duration
   `numerics_anomaly` spans; `TriggeredProfiler.on_span` subscribes to the
   span stream and converts them into captures);

@@ -529,7 +529,7 @@ class Heartbeat:
         self._interval = interval
         self._min_write = min_write_interval
         # identity, not truthiness: the owner may hand over a still-empty
-        # LIVE mapping (e.g. the timeline's rolling fields) it fills later
+        # LIVE mapping (e.g. the numerics monitor's fields) it fills later
         self._extra = {} if extra is None else extra
         # run constants (e.g. the mesh topology) repeated on every write so
         # an external watchdog can read the incarnation's layout from

@@ -235,7 +235,6 @@ def make_fleet(tmp_path, trainer_step_time=0.1):
                 "topology": {"layout": "pp2dp2"}},
         metrics=[{"step": s, "loss": 2.0, "step_time": trainer_step_time,
                   "bubble_fraction": 0.05,
-                  "bubble_fraction_measured": 0.07,
                   "nonfinite_steps": 0, "anomaly_count": 1}
                  for s in range(1, 9)],
         incarnations=[{"incarnation": 0, "outcome": "crash",
@@ -273,9 +272,11 @@ def test_aggregator_composes_fleet_status(tmp_path):
     tr = status["members"]["trainer:trainer0"]
     assert tr["last_step"] == 8 and tr["goodput"] == 0.9
     assert tr["latest_verified_step"] == 8
+    # the percentiles are the tailed metrics step_time stream's
     assert tr["step_time_p50"] == pytest.approx(0.1)
+    assert tr["step_time_p95"] == pytest.approx(0.1)
     assert tr["bubble_fraction_analytic"] == 0.05
-    assert tr["bubble_fraction_measured"] == 0.07
+    assert "bubble_fraction_measured" not in tr
     assert tr["anomaly_count"] == 1 and tr["nonfinite_steps"] == 0
     assert tr["incarnations"] == 2 and tr["restarts"] == 1
     assert tr["failed_incarnations"] == 1

@@ -427,10 +427,10 @@ def test_bubble_fraction_monotone_in_v():
 # Full-trainer plumbing (the CI schedule-parity gate's artifact producer)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow  # PR 14 rebalance: since PR 11 the trainer runs every
-# schedule through ONE unit interpreter, and the Observatory timeline e2e
-# exercises that trainer path (zb1-v2) every fast run — the interleaved
-# parity reps above keep this schedule's fast coverage
+@pytest.mark.slow  # since PR 11 the trainer runs every schedule through ONE
+# unit interpreter, and tests/test_zero_bubble.py's trainer e2e exercises
+# that trainer path (zb1-v2) every fast run — the interleaved parity reps
+# above keep this schedule's fast coverage
 def test_trainer_interleaved_end_to_end(tmp_path, devices):
     """run_training with schedule: interleaved_1f1b + virtual_stages: 2 —
     metrics carry the interleaved bubble_fraction, numerics.jsonl resolves

@@ -11,11 +11,11 @@ retention and the RESOURCE_EXHAUSTED matcher; THE calibration
 acceptance pin — a measured live/model peak ratio distills into
 `mem_scale` and re-ranks the 65B-shape frontier from the in-HBM zb1
 winner to its wgrad-offload twin; the page-pool fragmentation gauges
-(serve/pages.py) and their per-tick / metrics-snapshot surfaces; the
-trainer e2e (memory ON is bit-equal to OFF — the `timeline.enabled`
-zero-cost contract — while writing memory.jsonl + mem_peak_gib ledger
-rows); the OOM chaos e2e (fault op `oom` -> snapshot -> supervisor
-`oom` outcome -> fleet `oom_recent` alert firing and resolving);
+(serve/pages.py) and their metrics-snapshot surface; the trainer e2e
+(memory ON is bit-equal to OFF — the zero-cost contract — while writing
+memory.jsonl + mem_peak_gib ledger rows); the OOM chaos e2e (fault op
+`oom` -> snapshot -> supervisor `oom` outcome -> fleet `oom_recent`
+alert firing and resolving);
 `inspect_ckpt --sizes`; and the slow-marked anchored-estimate evidence
 (the 2^31-element XLA-CPU stash over-count the audit localizes)."""
 
@@ -414,9 +414,9 @@ def test_pages_fragmentation_gauges():
     assert cache.fragmentation_gauges()["pages_reserved"] == 0
 
 
-def test_serve_engine_publishes_fragmentation(tmp_path):
-    """The paged engine's metrics snapshot (the /healthz payload) and the
-    per-tick timeline both carry the occupancy gauges."""
+def test_serve_engine_publishes_fragmentation():
+    """The paged engine's metrics snapshot (the /healthz payload) carries
+    the occupancy gauges."""
     from llama_pipeline_parallel_tpu.models.llama import model as llama
     from llama_pipeline_parallel_tpu.models.llama.decode import (
         GenerationConfig,
@@ -426,17 +426,13 @@ def test_serve_engine_publishes_fragmentation(tmp_path):
         ServeEngine,
         ServeRequest,
     )
-    from llama_pipeline_parallel_tpu.utils import timeline as tl
 
     cfg = LlamaConfig.tiny(dtype=jnp.float32)
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    path = tmp_path / "timeline.jsonl"
-    writer = tl.TimelineWriter(str(path))
     eng = ServeEngine(params, cfg,
                       ServeConfig(max_slots=2, max_len=32,
                                   prompt_buckets=(16,), kv_cache="paged",
-                                  page_size=4),
-                      timeline=writer)
+                                  page_size=4))
     rs = np.random.RandomState(0)
     prompt = rs.randint(3, cfg.vocab_size, (12,)).tolist()
     for _ in range(2):
@@ -445,17 +441,11 @@ def test_serve_engine_publishes_fragmentation(tmp_path):
     eng.drain(timeout_s=300)
     snap = eng.metrics_snapshot()
     eng.shutdown()
-    writer.close()
 
     assert snap["reserved_unbacked"] >= 0
     assert 0.0 <= snap["page_fragmentation"] <= 1.0
     assert snap["reserved_gap_bytes"] == \
         snap["reserved_unbacked"] * eng.slots.page_bytes()
-    ticks = tl.read_timeline(str(path))
-    busy = [t for t in ticks if "pages_used" in t]
-    assert busy, "paged ticks must carry the occupancy gauges"
-    for t in busy:
-        assert {"pages_used", "pages_reserved", "fragmentation"} <= set(t)
 
 
 # ---------------------------------------------------------------------------
@@ -485,11 +475,10 @@ def _metric_losses(out):
 
 
 def test_trainer_memory_on_bit_equal_and_artifacts(tmp_path):
-    """The zero-cost contract (the `timeline.enabled` analogue): the
-    sampler is host-side only, so every step's loss is BIT-equal ON vs
-    OFF — while ON writes memory.jsonl (one compiled record for the train
-    step + per-step samples) and closes into the perf ledger with the
-    compiled-vs-live `mem_peak_gib` pairing."""
+    """The zero-cost contract: the sampler is host-side only, so every
+    step's loss is BIT-equal ON vs OFF — while ON writes memory.jsonl (one
+    compiled record for the train step + per-step samples) and closes into
+    the perf ledger with the compiled-vs-live `mem_peak_gib` pairing."""
     from llama_pipeline_parallel_tpu.train import run_training
 
     off_dir, on_dir = tmp_path / "off", tmp_path / "on"

@@ -163,9 +163,9 @@ def all_fb_flat_grid(m, s, stage_costs=None):
 
 @pytest.mark.parametrize("m,s", FLAT_SHAPES)
 def test_flat_is_interleaved_at_v1(m, s):
-    """One sequence under two names: grids, per-tick flags, ring depth and
-    the per-segment accounting are equal element for element, and the
-    grids are the old all-F+B grid's (the units never moved)."""
+    """One sequence under two names: grids, per-tick flags, ring depth, the
+    segments and the idle accounting are equal element for element, and
+    the grids are the old all-F+B grid's (the units never moved)."""
     flat = us.canonical_schedule("1f1b", m, s)
     inter = us.canonical_schedule("interleaved_1f1b", m, s, 1)
     us.validate(flat)
@@ -175,7 +175,8 @@ def test_flat_is_interleaved_at_v1(m, s):
                                       getattr(inter, name), err_msg=name)
     assert flat.ring_slots == inter.ring_slots
     assert flat.label == "1f1b" and inter.label == "interleaved_1f1b"
-    assert us.segment_stats(flat) == us.segment_stats(inter)
+    assert us.segments(flat) == us.segments(inter)
+    assert us.bubble_stats(flat) == us.bubble_stats(inter)
     old = all_fb_flat_grid(m, s)
     us.validate(old)
     np.testing.assert_array_equal(flat.f_unit, old.f_unit)
@@ -186,6 +187,22 @@ def test_flat_is_interleaved_at_v1(m, s):
     np.testing.assert_array_equal(flat.has_b, (flat.b_unit >= 0).any(axis=1))
     assert [(g.label, g.num_ticks) for g in us.segments(flat)] == [
         ("F", s - 1), ("F+B", m), ("B", s - 1)]
+
+
+def test_segments_labels_and_grouping():
+    """The interpreter's compile units: maximal equal-flag tick runs that
+    cover the tick axis once, named by their active halves."""
+    zb1 = us.canonical_schedule("zb1", 4, 2, 2)
+    segs = us.segments(zb1)
+    assert [s.label for s in segs] == ["F", "F+B", "B", "W"]
+    assert segs[0].t0 == 0 and segs[-1].t1 == zb1.num_ticks
+    for a, b in zip(segs, segs[1:]):
+        assert a.t1 == b.t0
+    flat = us.segments(us.canonical_schedule("1f1b", 8, 4))
+    assert [s.label for s in flat] == ["F", "F+B", "B"]
+    assert [s.num_ticks for s in flat] == [3, 8, 3]
+    drain_w = us.segments(us.list_schedule(8, 2, 2, w_placement="drain"))
+    assert "B+W" in [s.label for s in drain_w]
 
 
 @pytest.mark.parametrize("pp,chunks,counts", [

@@ -462,10 +462,8 @@ def test_trainer_accepts_zb1_virtual_stages(cfg):
 # Full-trainer plumbing (the CI schedule-parity gate's artifact producer)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow  # PR 14 rebalance: the Observatory suite's timeline e2e
-# drives run_training over the SAME zb1-v2 interpreter path every fast run
-# (tests/test_timeline.py::test_trainer_timeline_e2e, with metrics/health
-# assertions on top); the zb1 parity reps above stay fast
+# fast: the one run_training over the zb1-v2 interpreter path in every fast
+# run (tests/test_interleaved.py's trainer e2e is slow-marked against it)
 def test_trainer_zb1_end_to_end(tmp_path, devices):
     """run_training with schedule: zb1 + virtual_stages: 2 — the metrics
     line carries schedule/bubble_fraction/wgrad_queue_depth, health.json

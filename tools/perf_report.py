@@ -3,7 +3,7 @@
 (docs/OBSERVABILITY.md "Perf ledger & calibration").
 
 Reads perf.jsonl rows (utils/perf.py schema — written by train.py with
-`timeline.enabled`, by `bench.py --perf-ledger/--full-trajectory`, and by
+`memory.enabled`, by `bench.py --perf-ledger/--full-trajectory`, and by
 tools/serve.py) plus archived bench rounds (BENCH_r0*.json, error rounds
 included), and prints:
 

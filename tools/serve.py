@@ -106,11 +106,6 @@ def main(argv: list[str] | None = None) -> int:
                    help="after SIGTERM/SIGINT: seconds to finish in-flight "
                         "and queued requests before failing the remainder "
                         "(keep below the supervisor's --grace-s)")
-    p.add_argument("--timeline", action="store_true",
-                   help="write a per-tick timeline.jsonl (prefill-chunk vs "
-                        "decode-step wall split — the serving half of the "
-                        "schedule observatory, docs/OBSERVABILITY.md "
-                        "'Timelines')")
     p.add_argument("--slo_ttft_ms", type=float, default=None,
                    help="TTFT SLO in ms: breaches count on the metrics "
                         "line and fire a bounded profiler capture under "
@@ -167,12 +162,6 @@ def main(argv: list[str] | None = None) -> int:
         prefill_chunk_tokens=args.prefill_chunk_tokens,
         prefix_cache=args.prefix_cache)
     writer = MetricsWriter(args.output_dir)
-    tl_writer = None
-    if args.timeline:
-        from llama_pipeline_parallel_tpu.utils.timeline import TimelineWriter
-
-        tl_writer = TimelineWriter(
-            os.path.join(args.output_dir, "timeline.jsonl"))
     from llama_pipeline_parallel_tpu.utils.profiler import (
         CaptureConfig,
         TriggeredProfiler,
@@ -204,8 +193,7 @@ def main(argv: list[str] | None = None) -> int:
         reqtrace_rec = RequestTraceRecorder(
             args.output_dir, exemplar_k=args.trace_exemplars)
     engine = ServeEngine(params, cfg, serve_cfg, metrics_writer=writer,
-                         timeline=tl_writer, profiler=prof, slo=slo,
-                         reqtrace=reqtrace_rec)
+                         profiler=prof, slo=slo, reqtrace=reqtrace_rec)
     # the engine holds what it serves from (the matmul weights and the table
     # in the compute dtype, converted once); nothing below reads the loaded
     # float32 tree, and keeping it would keep its bytes on the chip
@@ -304,8 +292,6 @@ def main(argv: list[str] | None = None) -> int:
                  for k in ("ttft_p50_ms", "ttft_p95_ms", "tpot_p50_ms",
                            "queue_wait_p95_ms") if k in snap])
         writer.close()
-        if tl_writer is not None:
-            tl_writer.close()
         if reqtrace_rec is not None:
             reqtrace_rec.close()
         hb.stop()
