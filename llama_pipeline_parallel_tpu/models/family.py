@@ -9,25 +9,31 @@ block with recurrent layers and sparse experts (`models/hybrid_moe/`) another,
 the latent-attention block (MLA layers, with or without an indexer and
 window layers, as its configuration says) and sparse experts
 (`models/latent_moe/`) the third, the compressed-window block (an exact
-window beside pooled chunk summaries, `models/eva/`) the fourth. A fifth
-registers its configuration class below.
+window beside pooled chunk summaries, `models/eva/`) the fourth, the
+state-space / expert block (every layer ONE of a Mamba-2 mixer, a
+grouped-query softmax layer or a latent expert feed-forward, in a published
+order, `models/ssm_moe/`) the fifth. A sixth registers its configuration
+class below.
 
 A family also states what a slot's PAGES are (`table_width`,
 `table_columns`): how wide a slot's row of the page table is and which of its
-columns hold pages once so many places of the row are written. Three families
+columns hold pages once so many places of the row are written. Four families
 keep one entry a position for the life of the request (a page every
-`page_size` places, in order: the defaults below); the fourth keeps a ring of
-window pages that is reused and summary pages that grow at a sixteenth of the
-rate. `serve/pages.py` reserves, grows and sizes from these two and names no
-family.
+`page_size` places, in order: the defaults below); the compressed-window
+family keeps a ring of window pages that is reused and summary pages that grow
+at a sixteenth of the rate. `serve/pages.py` reserves, grows and sizes from
+these two and names no family.
 
 A family may keep a store with one row a SLOT beside the page pool
-(`init_recurrent_store`: the hybrid block's recurrent state, the latent
-block's rings of its window layers). What a family states may depend on the
+(`init_recurrent_store`: the hybrid and the state-space block's recurrent
+state and convolution inputs, the latent block's rings of its window
+layers): what a layer keeps of a sequence there is of constant size, so it
+is a row a slot and not pages. What a family states may depend on the
 configuration: a latent model without window layers keeps no such store.
 Whether it can prefill in chunks is a separate fact (`paged_prefill_chunk`):
-the latent block's chunk carries its rings forward from chunk to chunk, the
-hybrid block's programs cannot carry their state yet.
+the latent block's chunk carries its rings forward from chunk to chunk;
+neither recurrent family's prefill takes the state and the convolution
+inputs a chunk before it left, so neither chunks yet.
 
 `GenerationConfig`, `sample_rowwise` and `sampler_branch` are the same for
 every family (the sampling of a row of logits, and what a batch's knobs ask of
@@ -206,8 +212,19 @@ def _eva(cfg) -> ServingFamily:
             "span prefill to recompute a tail from it"))
 
 
+def _ssm_moe(cfg) -> ServingFamily:
+    from llama_pipeline_parallel_tpu.models.ssm_moe import decode, model
+
+    return ServingFamily(
+        name="ssm_moe", prefill_prompt=decode.prefill_prompt,
+        paged_decode_step=decode.paged_decode_step,
+        write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
+        init_recurrent_store=decode.init_recurrent_store,
+        init_params=model.init_params, counters=decode.COUNTERS)
+
+
 _FAMILIES = {"llama": _llama, "hybrid_moe": _hybrid_moe,
-             "latent_moe": _latent_moe, "eva": _eva}
+             "latent_moe": _latent_moe, "eva": _eva, "ssm_moe": _ssm_moe}
 
 
 def family_of(cfg) -> ServingFamily:
@@ -222,7 +239,8 @@ def family_of(cfg) -> ServingFamily:
 # families served in the dtype they are stored in: configuration class by
 # package under `models/`
 _STORED_DTYPE_CONFIGS = {"hybrid_moe": "HybridMoEConfig",
-                         "latent_moe": "LatentMoEConfig", "eva": "EvaConfig"}
+                         "latent_moe": "LatentMoEConfig", "eva": "EvaConfig",
+                         "ssm_moe": "SsmMoEConfig"}
 
 
 def config_from_meta(model_config: dict):

@@ -60,7 +60,14 @@ KDA_SUB = 16              # sub-block inside which decays are taken pairwise
 INIT_STD = 0.02
 COUNTERS = ("routed_total", "routed_here", "experts_hit", "expert_load_max",
             "experts_held", "expert_visits")
-EXPERT_LEAVES = ("gate", "up", "down")   # the grouped product's operands
+# the grouped product's operands HERE: three matrices an expert at the
+# model's width, `silu(gate) * up`, then `down`. What an expert is (how many
+# matrices, its activation, the width it reads) is this family's; the router,
+# the sort by held expert and the combine (`route`, `dispatch_rows`,
+# `combine_rows`) take what the configuration states (`router_experts`,
+# `num_experts_per_tok`, `expert_offset`, `held`, the scaling) and serve the
+# state-space family's latent experts too (models/ssm_moe/model.py)
+EXPERT_LEAVES = ("gate", "up", "down")
 
 
 # -- parameters ---------------------------------------------------------------
@@ -324,9 +331,59 @@ def split_experts(periods: Params) -> tuple[Params, list]:
     return {**periods, "moe": rest}, experts
 
 
+def dispatch_rows(chosen: jnp.ndarray, ok: jnp.ndarray, hidden: jnp.ndarray,
+                  cfg, stack: int, place):
+    """The rows the held experts multiply, sorted by expert. chosen: [T, k]
+    expert ids of the whole router; ok: [T, 1] bool, rows that are routed at
+    all; hidden: [T, w] what an expert reads of a token. Returns (`here`
+    [T, k] bool: the assignments that land on [expert_offset, expert_offset +
+    held); `order` [T * k]: the assignments sorted by held expert, the ones
+    that land nowhere last; their `sorted_group` (held: nowhere); `sizes`
+    [held] rows an expert; `stack_sizes` [stack]: `sizes` at [place * held,
+    (place + 1) * held) of a stack whose other experts get no rows; `taken`
+    [T * k, w] the sorted rows' inputs)."""
+    T, k = chosen.shape
+    held = cfg.held
+    with jax.named_scope(trace.MOE_DISPATCH):
+        local = chosen - cfg.expert_offset
+        here = (local >= 0) & (local < held) & ok            # [T, k]
+        group = jnp.where(here, local, held).reshape(T * k)
+        order = jnp.argsort(group, stable=True)
+        sorted_group = group[order]
+        rows = order // k                                    # token of a row
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        # the other periods' experts get no rows: the sorted rows meet the
+        # experts at [place * held, (place + 1) * held) of the stack
+        stack_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((stack,), jnp.int32), sizes, (place * held,))
+        taken = hidden[rows]                                 # [T * k, w]
+    return here, order, sorted_group, sizes, stack_sizes, taken
+
+
+def combine_rows(out: jnp.ndarray, order: jnp.ndarray,
+                 sorted_group: jnp.ndarray, weights: jnp.ndarray,
+                 held: int) -> jnp.ndarray:
+    """The sorted rows' products [T * k, w] back in token order and summed
+    under the router's weights [T, k]: float32 [T, w]."""
+    T, k = weights.shape
+    with jax.named_scope(trace.MOE_COMBINE):
+        # rows past the last group belong to no held expert: the product
+        # never wrote them (they hold anything, NaN too), so what is there
+        # is dropped, not scaled
+        in_group = (sorted_group < held)[:, None]
+        out = jnp.where(in_group, out, 0)
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))              # order^-1
+        terms = out[back].reshape(T, k, -1).astype(jnp.float32)
+        return jnp.sum(terms * weights[..., None], axis=1)
+
+
 def moe_block(moe: Params, experts: Params, place, x: jnp.ndarray,
               valid: jnp.ndarray, cfg: HybridMoEConfig, shared: bool = True):
-    """Post-norm expert half of a layer, with the residual. moe: the layer's
+    """Post-norm expert half of a layer, with the residual: gated experts of
+    three matrices at the model's width (`EXPERT_LEAVES`), on top of `route`,
+    `dispatch_rows` and `combine_rows`, which any family's expert layer that
+    is told what it holds can stand on. moe: the layer's
     own norm, router, bias and shared expert; experts: the routed experts'
     `gate` / `up` [P, held, d, f] and `down` [P, held, f, d] of EVERY period
     as they are stored, and `place` (int32 scalar, may be traced) this
@@ -359,19 +416,8 @@ def moe_block(moe: Params, experts: Params, place, x: jnp.ndarray,
     chosen, weights = route(moe, hidden, cfg)
     ok = valid.reshape(T, 1)
 
-    with jax.named_scope(trace.MOE_DISPATCH):
-        local = chosen - cfg.expert_offset
-        here = (local >= 0) & (local < held) & ok            # [T, k]
-        group = jnp.where(here, local, held).reshape(T * k)
-        order = jnp.argsort(group, stable=True)
-        sorted_group = group[order]
-        rows = order // k                                    # token of a row
-        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-        # the other periods' experts get no rows: the sorted rows meet the
-        # experts at [place * held, (place + 1) * held) of the stack
-        stack_sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((stack,), jnp.int32), sizes, (place * held,))
-        taken = hidden[rows]                                 # [T * k, d]
+    here, order, sorted_group, sizes, stack_sizes, taken = dispatch_rows(
+        chosen, ok, hidden, cfg, stack, place)
 
     with jax.named_scope(trace.MOE_EXPERTS):
         meta = group_metadata(stack_sizes, T * k)     # one for the three
@@ -381,16 +427,7 @@ def moe_block(moe: Params, experts: Params, place, x: jnp.ndarray,
         act = jax.nn.silu(grouped(taken, "gate")) * grouped(taken, "up")
         out = grouped(act, "down")                           # [T * k, d]
 
-    with jax.named_scope(trace.MOE_COMBINE):
-        # rows past the last group belong to no held expert: the product
-        # never wrote them (they hold anything, NaN too), so what is there
-        # is dropped, not scaled
-        in_group = (sorted_group < held)[:, None]
-        out = jnp.where(in_group, out, 0)
-        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
-            jnp.arange(T * k, dtype=jnp.int32))              # order^-1
-        terms = out[back].reshape(T, k, d).astype(jnp.float32)
-        y = jnp.sum(terms * weights[..., None], axis=1)
+    y = combine_rows(out, order, sorted_group, weights, held)
 
     if shared:
         with jax.named_scope(trace.MOE_SHARED):

@@ -160,6 +160,20 @@ EVA_ATTN = "eva_attn"                # the tick: one softmax over summary and wi
 EVA_ATTN_PREFILL = "eva_attn_prefill"  # a prefill's or a chunk's queries, both kinds of key
 EVA_SCOPES = (EVA_POOL, EVA_SUMMARY_WRITE, EVA_ATTN, EVA_ATTN_PREFILL)
 
+# models/ssm_moe/ (reuses `state_gather` / `state_write`, the `moe_*` names,
+# `kv_write`, `decode_attn`, `attn_qkv`, `attn_out`, `lm_head`, `sample` for
+# the same work). A tuple of their own for the same reason as HYBRID_SCOPES:
+# a fifth vocabulary to merge.
+SSM_PROJ = "ssm_proj"                # a Mamba-2 layer's projections, in and out
+SSM_CONV = "ssm_conv"                # the causal depthwise convolution, bias, SiLU
+SSM_SCAN = "ssm_scan"                # the chunked recurrence (prefill)
+SSM_STEP = "ssm_step"                # one step of the recurrence (decode tick)
+SSM_NORM = "ssm_norm"                # the skip, the gate, the grouped norm
+MOE_LATENT_IN = "moe_latent_in"      # token -> the width the routed experts read
+MOE_LATENT_OUT = "moe_latent_out"    # their weighted sum -> the model's width
+SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_STEP, SSM_NORM, MOE_LATENT_IN,
+              MOE_LATENT_OUT)
+
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 

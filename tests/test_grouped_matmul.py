@@ -42,6 +42,13 @@ CASES = {
     # widths no power-of-two tile divides: blocks of 640 and 768 rows
     "widths_1280_by_1536": (32, 1280, 1536, [9, 0, 20], 2),
     "widths_1536_by_1280": (32, 1536, 1280, [0, 17, 3], 2),
+    # contractions that are no power of two, 3 x 128 and 21 x 128, beside
+    # outputs 9 and 21 lanes of 128 wide (the latent experts' two products
+    # are 1024 x 2688 and 2688 x 1024)
+    "contraction_384_by_1152": (32, 384, 1152, [4, 0, 25], 2),
+    "contraction_2688_by_1152": (32, 2688, 1152, [0, 30, 1], 2),
+    "widths_1024_by_2688": (32, 1024, 2688, [9, 0, 20], 2),
+    "widths_2688_by_1024": (32, 2688, 1024, [0, 17, 3], 2),
 }
 
 
@@ -105,6 +112,38 @@ def test_tiles_at_the_three_expert_cells_shapes():
     assert gm.contraction_tile(2048, 7168, bf16) == 256
     # the tiny test widths: one block
     assert gm.contraction_tile(32, 16, 4) == 32
+
+
+def test_tiles_at_the_latent_experts_shapes():
+    """The same rule where the contraction is no power of two: 2,688 = 21 x
+    128 has the divisors 128, 384, 896 and 2,688 that are multiples of 128,
+    and a block of 896 x 1024 bf16 is 1.8 MB (2,688 rows would be 5.5 MB);
+    1,024 x 2,688 takes blocks of 512 (2.75 MB). A tick's 64 x 22 = 1,408
+    sorted rows and a 1,024-token unit's 22,528 are whole tiles of 128."""
+    bf16 = 2
+    assert [t for t in range(128, 2689, 128) if 2688 % t == 0] == [
+        128, 384, 896, 2688]
+    assert gm.contraction_tile(2688, 1024, bf16) == 896
+    assert gm.contraction_tile(1024, 2688, bf16) == 512
+    assert gm.contraction_tile(384, 1152, bf16) == 384
+    assert gm.contraction_tile(2688, 1152, bf16) == 896
+    # float32 (the CPU tests' dtype) halves what fits a block
+    assert gm.contraction_tile(2688, 1024, 4) == 896
+    assert gm.contraction_tile(1024, 2688, 4) == 256
+    assert [gm.row_tile(m) for m in (1408, 22528, 128 * 22)] == [128] * 3
+
+
+@pytest.mark.parametrize("tk", [384, 128])
+def test_a_contraction_of_three_lanes_blocked_is_the_whole_one(monkeypatch, tk):
+    """3 x 128 in one block and in three: the last block is a whole one (the
+    tile divides the width), so no step reads past the matrix."""
+    m, k, n, sizes = 32, 384, 1152, jnp.asarray([11, 0, 14], jnp.int32)
+    lhs, rhs = _operands(m, k, n, 3, jnp.float32)
+    want = jax.lax.ragged_dot(lhs, rhs, sizes)
+    monkeypatch.setattr(gm, "_BLOCK_BYTES", tk * n * 4)
+    assert gm.contraction_tile(k, n, 4) == tk
+    got = gm.grouped_matmul(lhs, rhs, gm.group_metadata(sizes, m))
+    np.testing.assert_allclose(got[:25], want[:25], rtol=1e-5, atol=1e-5)
 
 
 def test_the_metadata_walks_every_pair_that_shares_a_row_and_no_other():

@@ -13,6 +13,7 @@ import pytest
 import eva_tiny
 import hybrid_tiny
 import latent_tiny
+import ssm_tiny
 from llama_pipeline_parallel_tpu import serve
 from llama_pipeline_parallel_tpu.models import family as families
 from llama_pipeline_parallel_tpu.models.llama import model as llama
@@ -120,8 +121,13 @@ def _eva():
         num_pages=40, prefill_chunk_tokens=32)
 
 
+def _ssm():
+    return ssm_tiny.config(), ssm_tiny.both_sides()[0], dict(
+        max_len=48, prompt_buckets=(8, 16), page_size=8, num_pages=32)
+
+
 FAMILIES = {"llama": _dense, "hybrid_moe": _hybrid, "latent_moe": _latent,
-            "eva": _eva}
+            "eva": _eva, "ssm_moe": _ssm}
 
 
 # a family added to `models/family.py` fails here until it has a tiny conf

@@ -135,7 +135,7 @@ def _both_paths(q, k, v, table, live, mask):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("g", [1, 4, 8])
+@pytest.mark.parametrize("g", [1, 4, 8, 16])
 @pytest.mark.parametrize("scenario", SCENARIOS)
 def test_the_kernel_is_the_gather_and_the_product(scenario, g, dtype):
     table, live, mask = _scenario(scenario)
@@ -177,6 +177,8 @@ def test_pages_per_step_at_the_serving_cells_shapes():
     0.5 MB of keys (1 a step), the hybrid's 8 make it 0.125 MB (4)."""
     assert paged_attention._pages_per_step(40, 64 * 32 * 128 * 2) == 1
     assert paged_attention._pages_per_step(40, 64 * 8 * 128 * 2) == 4
+    # the state-space cell's 2 KV heads: a page is 32 KB of keys, 16 a step
+    assert paged_attention._pages_per_step(36, 64 * 2 * 128 * 2) == 16
 
 
 # -- compiled for a described v5e ---------------------------------------------
@@ -218,6 +220,10 @@ def _described(tree, sharding):
 @pytest.mark.parametrize("cell,slots,h,kv_h,layers,pages", [
     ("serve-closed-16.deepseek", 16, 32, 32, 4, 640),
     ("serve-closed-64.solar-open2", 64, 64, 8, 1, 2560),
+    # 16 query heads a KV head over a KV-head axis of 2: the page is read as
+    # the [128, 128] matrix it is in memory, so no second-minor size of 2
+    # reaches Mosaic
+    ("serve-reason-64.nemotron3-super", 64, 32, 2, 1, 2304),
 ])
 def test_mosaic_compiles_the_kernel_at_a_serving_cells_shapes(
         one_chip, mosaic, cell, slots, h, kv_h, layers, pages):
@@ -432,6 +438,80 @@ def test_the_engines_tick_compiled_for_the_chip_keeps_the_stores_in_place(
     assert kernel in compiled.as_text()
 
 
+# -- the state-space family: its tick and its expert layer for the same chip ------
+
+def test_a_state_space_tick_compiled_for_the_chip_keeps_its_stores_in_place(
+        one_chip, mosaic):
+    """The fifth family's tick as `ServeEngine` runs it, its layers unrolled
+    in the pattern's order: the recurrent store's rows are read and written
+    at a static index of the donated leaf (no copy of a layer's state in
+    front of the step, outputs aliased), the attention is the paged kernel
+    at 16 query heads a KV head, and the host fetches 3 a slot and seven
+    counters. Mamba-2 and attention shapes as the cell's, a small width."""
+    from llama_pipeline_parallel_tpu.models import tick_io
+    from llama_pipeline_parallel_tpu.models.ssm_moe import decode as ssm_decode
+    from llama_pipeline_parallel_tpu.models.ssm_moe import model as ssm
+    from llama_pipeline_parallel_tpu.models.ssm_moe.config import SsmMoEConfig
+
+    slots, pmax, page = 8, 16, 64
+    cfg = SsmMoEConfig(
+        vocab_size=256, hidden_size=256, pattern="MEM*E", ssm_heads=64,
+        router_experts=16, experts_held=8, num_experts_per_tok=4,
+        moe_latent_size=128, moe_intermediate_size=384,
+        shared_intermediate_size=256)
+    params = jax.eval_shape(lambda: ssm.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: {
+        **ssm_decode.init_page_pool(cfg, 2048, page),
+        **ssm_decode.init_recurrent_store(cfg, slots)})
+    counters = len(ssm_decode.COUNTERS)
+    args = _described(
+        (params,
+         jax.ShapeDtypeStruct((slots, tick_io.COLUMNS + pmax), jnp.int32),
+         jax.ShapeDtypeStruct((3 * slots + counters,), jnp.int32),
+         pool, jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)),
+        one_chip)
+    lowered = tick_io.packed(ssm_decode.paged_decode_step).lower(*args, cfg)
+    assert lowered.out_info["fetch"].shape == (3 * slots + 7,)
+    compiled = lowered.compile()
+    analysis = compiled.memory_analysis()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    assert nbytes(pool) > 5 * nbytes(params)
+    assert analysis.alias_size_in_bytes >= nbytes(pool)
+    # under half of ONE layer's state of the slots: no copy of it was made
+    assert analysis.temp_size_in_bytes < nbytes(pool["state"]) // (
+        2 * cfg.recurrent_layers), analysis
+    text = compiled.as_text()
+    assert "paged_decode_attn" in text and "grouped_matmul" in text
+
+
+def test_a_latent_expert_layer_compiled_for_the_chip_reads_its_experts_as_stored(
+        one_chip, mosaic):
+    """`latent_moe_block` at a tick's 64 rows and the cell's real widths (128
+    experts of 2 x 1024 x 2688 held of 512, top-22): two grouped kernels,
+    whose right operands are the arguments themselves, and temporaries far
+    under ONE expert's matrices (5.5 MB each; a copy of the layer's experts
+    would be 1.4 GB)."""
+    from llama_pipeline_parallel_tpu.models.ssm_moe import model as ssm
+    from llama_pipeline_parallel_tpu.models.ssm_moe.config import SsmMoEConfig
+
+    cfg = SsmMoEConfig(vocab_size=256, pattern="E", experts_held=128)
+    layer = jax.eval_shape(lambda: ssm.init_params(
+        jax.random.PRNGKey(0), cfg))["layers"][0]
+    assert layer["up"].shape == (128, 1024, 2688)
+    args = _described(
+        (layer, jax.ShapeDtypeStruct((64, 1, 4096), jnp.bfloat16),
+         jax.ShapeDtypeStruct((64, 1), jnp.bool_)), one_chip)
+    compiled = jax.jit(
+        lambda *a: ssm.latent_moe_block(*a, cfg)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 and "grouped_matmul" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 # -- plain MLA that reads the whole cache, compiled for the same chip ------------
 
 def test_mosaic_compiles_the_dense_latent_tick_kernel_at_the_longdoc_cells_shapes(
@@ -633,8 +713,15 @@ EXPERT_CELLS = {
 }
 
 
+# the latent experts' two products, 1024 x 2688 and 2688 x 1024: the first
+# contraction that is no power of two (blocks of 512 and 896) and an output
+# 21 lanes of 128 wide
+GROUPED_CELLS = {**EXPERT_CELLS,
+                 "serve-reason-64.nemotron3-super": (1, 128, 1024, 2688, 64, 22)}
+
+
 @pytest.mark.parametrize("tokens", ["tick", 2048])
-@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
 def test_mosaic_compiles_the_grouped_product_at_the_expert_cells_shapes(
         one_chip, mosaic, cell, tokens):
     """bf16, the stack of every period as stored, a tick's `T * k` rows and
@@ -642,7 +729,7 @@ def test_mosaic_compiles_the_grouped_product_at_the_expert_cells_shapes(
     Mosaic takes the blocks (whole-width weight blocks of 2 to 4 MB, a
     float32 accumulator beside them), and XLA:TPU hands the stack to the
     kernel as it lies: no temporary as large as ONE expert's matrix."""
-    periods, held, d, f, tick_rows, k = EXPERT_CELLS[cell]
+    periods, held, d, f, tick_rows, k = GROUPED_CELLS[cell]
     m = (tick_rows if tokens == "tick" else tokens) * k
     stack = periods * held
     sizes = jax.ShapeDtypeStruct((stack,), jnp.int32)
