@@ -10,7 +10,10 @@ float32 [M layers, slots, H, P, N] and `conv` [M layers, slots, width - 1,
 HP + 2 GN] (the last inputs of the convolution). A slot's row of the
 recurrent store is written whole at admission (`write_pages`, the hybrid
 block's: it splices any `state` / `conv` leaves a row a slot) and updated in
-place every tick; nothing is ever freed.
+place every tick: the float32 `state` by `ops/ssm_state_step.py`, a kernel
+that takes the whole store aliased to its output and steps one layer's rows
+where they lie (read once, written once); `conv`, 20 MB a tick in all, by
+XLA's gather and `dynamic-update-slice`. Nothing is ever freed.
 
 The layers are unrolled in the order `cfg.pattern` gives (it has no period
 in general), each reading and writing its own index of its kind's store;
@@ -38,6 +41,7 @@ from llama_pipeline_parallel_tpu.ops.attention import attention
 from llama_pipeline_parallel_tpu.ops.paged_attention import (
     paged_decode_attention,
 )
+from llama_pipeline_parallel_tpu.ops.ssm_state_step import ssm_state_step
 from llama_pipeline_parallel_tpu.utils import trace
 
 Params = dict
@@ -178,16 +182,17 @@ def tick_logits(params: Params, token: jnp.ndarray, pool: dict,
 
     def ssm_layer(layer, h, stores, index):
         with jax.named_scope(trace.STATE_GATHER):
-            state, conv = stores["state"][index], stores["conv"][index]
+            conv = stores["conv"][index]
         pr = ssm.ssm_project(layer, h, valid, conv, cfg)
-        y, state = ssm.ssm_step(pr["x"][:, 0], pr["dt"][:, 0],
-                                -jnp.exp(layer["A_log"]), pr["B"][:, 0],
-                                pr["C"][:, 0], state)
+        with jax.named_scope(trace.SSM_STEP):
+            # the float32 state is stepped where it lies in the store
+            y, state = ssm_state_step(
+                stores["state"], index, pr["x"][:, 0], pr["dt"][:, 0],
+                -jnp.exp(layer["A_log"]), pr["B"][:, 0], pr["C"][:, 0])
         with jax.named_scope(trace.STATE_WRITE):
             # a row that is not decoding keeps its convolution inputs too
             conv = jnp.where(valid[..., None], pr["conv"], conv)
-            stores = {**stores,
-                      "state": stores["state"].at[index].set(state),
+            stores = {**stores, "state": state,
                       "conv": stores["conv"].at[index].set(conv)}
         return ssm.ssm_output(layer, h, y[:, None], pr["x"], pr["z"],
                               cfg), stores
@@ -211,11 +216,12 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     layer writes this token's keys and values into (layer, w_page, w_off)
     and attends each slot's live pages where they lie in the pool
     (`ops/paged_attention.py`, its 16 query heads a KV head by shape); a
-    Mamba-2 layer reads its rows of the recurrent store, applies one step of
-    the recurrence and writes them back. Rows that are not `active` leave
-    both stores as they were (their page writes go to the garbage page;
-    their recurrence runs with dt = 0) and are routed to no expert. Returns
-    the dense tick's outputs plus "counters" (int32[7], `COUNTERS`)."""
+    Mamba-2 layer applies one step of the recurrence to its rows of the
+    recurrent store in place (`ops/ssm_state_step.py`). Rows that are not
+    `active` leave both stores as they were (their page writes go to the
+    garbage page; their recurrence runs with dt = 0) and are routed to no
+    expert. Returns the dense tick's outputs plus "counters" (int32[7],
+    `COUNTERS`)."""
     del pos
     logits, pool, kv_mask, counters = tick_logits(
         params, token, pool, page_table, write_pos, kv_mask, active, cfg)

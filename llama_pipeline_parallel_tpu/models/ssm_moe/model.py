@@ -1,5 +1,6 @@
 """Layers of the state-space / expert block (config.py): the Mamba-2 mixer
-in its chunked and its one-step form, and the expert layer whose routed
+in its chunked form (its one-step form is `ops/ssm_state_step.py`, which
+steps the decode tick's store in place), and the expert layer whose routed
 experts work in a latent width. The softmax layer's projections are the
 hybrid block's (`hybrid_moe.model.attn_project` / `attn_output`, without a
 gate), and so are the router, the sort by held expert and the combine
@@ -176,28 +177,13 @@ def ssm_output(layer: Params, x: jnp.ndarray, y: jnp.ndarray, xs: jnp.ndarray,
         return x + y @ cast_weight(layer["out_proj"], cfg.dtype)
 
 
-def ssm_step(x, dt, A, B, C, state):
-    """The recurrence for ONE position of every row. x: [b, H, P]; dt:
-    [b, H]; A: [H]; B, C: [b, G, N]; state: [b, H, P, N] float32. Returns
-    (y [b, H, P] without the skip, new state). A head reads its group's B
-    and C by shape: the state is seen as [b, G, H / G, P, N]."""
-    with jax.named_scope(trace.SSM_STEP):
-        b, H, P = x.shape
-        G, N = B.shape[1:]
-        grouped = lambda a: a.reshape(b, G, H // G, *a.shape[2:])
-        decay = grouped(jnp.exp(dt * A))[..., None, None]
-        xdt = grouped(x * dt[..., None])[..., None]
-        state = decay * grouped(state) + xdt * B[:, :, None, None, :]
-        y = jnp.sum(state * C[:, :, None, None, :], axis=-1)
-        return y.reshape(b, H, P), state.reshape(b, H, P, N)
-
-
 def ssm_chunked(x, dt, A, B, C, state, chunk: int):
-    """The same recurrence over a whole sequence in its chunked (state-space
-    dual) form. x: [b, s, H, P] float32; dt: [b, s, H]; A: [H] (< 0); B, C:
-    [b, s, G, N]; state: [b, H, P, N] float32, the state before position 0.
-    Returns (y [b, s, H, P] without the skip, the state after the last
-    position).
+    """The recurrence over a whole sequence in its chunked (state-space dual)
+    form. x: [b, s, H, P] float32; dt: [b, s, H]; A: [H] (< 0); B, C: [b, s,
+    G, N]; state: [b, H, P, N] float32, the state before position 0. Returns
+    (y [b, s, H, P] without the skip, the state after the last position). A
+    head reads its group's B and C by shape: the state is seen as [b, G,
+    H / G, P, N].
 
     With `a_t = dt_t A` and `cs` its running sum inside a chunk, a chunk
     that starts from S_0 gives

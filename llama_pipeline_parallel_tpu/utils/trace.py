@@ -177,7 +177,7 @@ SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_STEP, SSM_NORM, MOE_LATENT_IN,
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 
-# `name=` of the fifteen pallas_calls: the kernel's instruction in a trace is
+# `name=` of the sixteen pallas_calls: the kernel's instruction in a trace is
 # `<name>.<n>`
 KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
@@ -194,6 +194,7 @@ KERNEL_PAGED_LATENT_DECODE_ATTN = "paged_latent_decode_attn"  # under LATENT_REA
 KERNEL_LATENT_PREFILL_ATTN = "latent_prefill_attn"  # under LATENT_READ_PREFILL
 KERNEL_EVA_PREFILL_ATTN = "eva_prefill_attn"  # under EVA_ATTN_PREFILL
 KERNEL_GROUPED_MATMUL = "grouped_matmul"  # under MOE_EXPERTS
+KERNEL_SSM_STATE_STEP = "ssm_state_step"  # under SSM_STEP
 
 KERNELS = tuple(v for k, v in sorted(globals().items())
                 if k.startswith("KERNEL_"))

@@ -37,6 +37,7 @@ from llama_pipeline_parallel_tpu.ops import (
     paged_attention,
     paged_latent_attention,
     sparse_latent_attention,
+    ssm_state_step,
 )
 from llama_pipeline_parallel_tpu.ops.attention import attention
 
@@ -202,7 +203,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
     for module in (sparse_latent_attention, paged_latent_attention,
                    latent_prefill_attention, eva_prefill_attention,
-                   grouped_matmul):
+                   grouped_matmul, ssm_state_step):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without one
@@ -443,11 +444,13 @@ def test_the_engines_tick_compiled_for_the_chip_keeps_the_stores_in_place(
 def test_a_state_space_tick_compiled_for_the_chip_keeps_its_stores_in_place(
         one_chip, mosaic):
     """The fifth family's tick as `ServeEngine` runs it, its layers unrolled
-    in the pattern's order: the recurrent store's rows are read and written
-    at a static index of the donated leaf (no copy of a layer's state in
-    front of the step, outputs aliased), the attention is the paged kernel
-    at 16 query heads a KV head, and the host fetches 3 a slot and seven
-    counters. Mamba-2 and attention shapes as the cell's, a small width."""
+    in the pattern's order: the recurrent store's rows are stepped where
+    they lie by `ops/ssm_state_step.py`, the donated leaf aliased through
+    both Mamba-2 layers (no copy of a layer's state in front of the step and
+    no `dynamic-update-slice` of one behind it, outputs aliased), the
+    attention is the paged kernel at 16 query heads a KV head, and the host
+    fetches 3 a slot and seven counters. Mamba-2 and attention shapes as the
+    cell's, a small width."""
     from llama_pipeline_parallel_tpu.models import tick_io
     from llama_pipeline_parallel_tpu.models.ssm_moe import decode as ssm_decode
     from llama_pipeline_parallel_tpu.models.ssm_moe import model as ssm
@@ -486,6 +489,37 @@ def test_a_state_space_tick_compiled_for_the_chip_keeps_its_stores_in_place(
         2 * cfg.recurrent_layers), analysis
     text = compiled.as_text()
     assert "paged_decode_attn" in text and "grouped_matmul" in text
+    # M E M * E: two state steps, one paged attention, two products an E
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 1 + 4
+    assert "ssm_state_step" in text
+    layer_state = "f32[{},{},{},{}]".format(*pool["state"].shape[1:])
+    assert not [line for line in text.splitlines()
+                if layer_state in line and ("dynamic-update-slice(" in line
+                                            or " copy(" in line)]
+
+
+def test_mosaic_compiles_the_state_step_at_the_cells_shape_in_place(
+        one_chip, mosaic):
+    """`serve-reason-64.nemotron3-super`'s store, float32 [5, 64, 128, 64,
+    128] (1.34 GB): Mosaic takes the block `head_block` chooses and every
+    smaller one of whole groups, the store is aliased to the result and the
+    program holds nothing else of any size."""
+    layers, slots, H, P, G, N = 5, 64, 128, 64, 8, 128
+    shapes = _described(tuple(
+        jax.ShapeDtypeStruct(s, jnp.float32)
+        for s in ((layers, slots, H, P, N), (slots, H, P), (slots, H), (H,),
+                  (slots, G, N), (slots, G, N))), one_chip)
+    chosen = ssm_state_step.head_block(H, G, P, N)
+    assert chosen % (H // G) == 0 and H % chosen == 0
+    for hb in sorted({H // G, chosen}):
+        compiled = jax.jit(
+            lambda store, *a: ssm_state_step.ssm_state_step(
+                store, 3, *a, block_heads=hb),
+            donate_argnums=0).lower(*shapes).compile()
+        analysis = compiled.memory_analysis()
+        assert analysis.alias_size_in_bytes >= layers * slots * H * P * N * 4
+        assert analysis.temp_size_in_bytes < 8 << 20, analysis
+        assert "ssm_state_step" in compiled.as_text()
 
 
 def test_a_latent_expert_layer_compiled_for_the_chip_reads_its_experts_as_stored(

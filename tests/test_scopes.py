@@ -26,6 +26,7 @@ from llama_pipeline_parallel_tpu.ops import (
     pallas_ce,
     pallas_prologue,
     sparse_latent_attention,
+    ssm_state_step,
 )
 from llama_pipeline_parallel_tpu.optim import OptimizerConfig, make_optimizer
 from llama_pipeline_parallel_tpu.parallel import pipeline as pl
@@ -196,6 +197,7 @@ def test_every_product_and_kernel_call_carries_a_leaf_scope(program, devices):
     (latent_prefill_attention, ("KERNEL_LATENT_PREFILL_ATTN",)),
     (eva_prefill_attention, ("KERNEL_EVA_PREFILL_ATTN",)),
     (grouped_matmul, ("KERNEL_GROUPED_MATMUL",)),
+    (ssm_state_step, ("KERNEL_SSM_STATE_STEP",)),
 ])
 def test_every_pallas_call_passes_its_name(module, kernels):
     source = inspect.getsource(module)
@@ -204,7 +206,7 @@ def test_every_pallas_call_passes_its_name(module, kernels):
     for constant in kernels:
         assert source.count(f"name=trace.{constant},") == 1
         assert getattr(trace, constant) in trace.KERNELS
-    assert len(trace.KERNELS) == 15 == len(set(trace.KERNELS))
+    assert len(trace.KERNELS) == 16 == len(set(trace.KERNELS))
 
 
 def test_flash_kernel_name_reaches_the_lowered_program():

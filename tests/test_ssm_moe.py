@@ -15,6 +15,7 @@ import ssm_tiny as tiny
 from llama_pipeline_parallel_tpu.models.ssm_moe import decode as ssm_decode
 from llama_pipeline_parallel_tpu.models.ssm_moe import model as ssm
 from llama_pipeline_parallel_tpu.models.ssm_moe.config import SsmMoEConfig
+from llama_pipeline_parallel_tpu.ops.ssm_state_step import ssm_state_step
 
 TOL = 1e-4
 
@@ -37,6 +38,14 @@ def _sequential(x, dt, A, B, C, state):
                 state[i, h] = S
                 y[i, t, h] = S @ C[i, t, h // per]
     return y, state
+
+
+@jax.jit
+def _step(x, dt, A, B, C, state):
+    """One position through the decode tick's kernel (interpreted here), on
+    a store of one layer: (y, the rows' new state)."""
+    y, store = ssm_state_step(state[None], 0, x, dt, A, B, C)
+    return y, store[0]
 
 
 def _draw(rng, b, s, H=4, P=8, G=2, N=8, decay=1.0):
@@ -82,8 +91,7 @@ def test_one_step_form_is_the_recurrence():
     want_y, want_s = _sequential(x, dt, A, B, C, state.copy())
     s = jnp.asarray(state)
     for t in range(6):
-        y, s = ssm.ssm_step(x[:, t], dt[:, t], jnp.asarray(A), B[:, t],
-                            C[:, t], s)
+        y, s = _step(x[:, t], dt[:, t], A, B[:, t], C[:, t], s)
         np.testing.assert_allclose(y, want_y[:, t], atol=TOL)
     np.testing.assert_allclose(s, want_s, atol=TOL)
 
@@ -99,7 +107,7 @@ def test_the_chunked_scan_is_the_step_form_at_any_length(chunk):
         got_y, got_s = ssm.ssm_chunked(x, dt, A, B, C, state, chunk=chunk)
         s = state
         for t in range(length):
-            y, s = ssm.ssm_step(x[:, t], dt[:, t], A, B[:, t], C[:, t], s)
+            y, s = _step(x[:, t], dt[:, t], A, B[:, t], C[:, t], s)
             np.testing.assert_allclose(got_y[:, t], y, atol=TOL)
         np.testing.assert_allclose(got_s, s, atol=TOL)
 

@@ -323,8 +323,9 @@ def test_the_tick_keeps_both_stores_in_place_and_reads_its_pages_where_they_lie(
     """The outputs alias the donated stores; the softmax layer's one-query
     attention is the paged kernel (no gather of the slots' logical rows, no
     `repeat_kv` broadcast of the 2 KV heads); the kernels are one paged
-    attention and two grouped products an expert layer, in the pattern's
-    order."""
+    attention, two grouped products an expert layer and one step of the
+    state in place a Mamba-2 layer, in the pattern's order; nothing slices
+    a layer's state out of the store or splices one back."""
     cfg = tiny.config()
     _, pool, args = _tick_args(cfg)
     compiled = ssm_decode.paged_decode_step.lower(*args, cfg).compile()
@@ -336,9 +337,15 @@ def test_the_tick_keeps_both_stores_in_place_and_reads_its_pages_where_they_lie(
         lambda *a: ssm_decode.paged_decode_step(*a, cfg))(*args).jaxpr
     kernels = [e.params["name"] for e in _equations(jaxpr)
                if e.primitive.name == "pallas_call"]
-    grouped = [trace.KERNEL_GROUPED_MATMUL] * 2
+    step = [trace.KERNEL_SSM_STATE_STEP]
+    expert = [trace.KERNEL_GROUPED_MATMUL] * 2
     # M E M * E M E
-    assert kernels == grouped + [trace.KERNEL_PAGED_DECODE_ATTN] + 2 * grouped
+    assert kernels == (step + expert + step + [trace.KERNEL_PAGED_DECODE_ATTN]
+                       + expert + step + expert)
+    # no value anywhere is ONE layer's state of the slots
+    layer_state = pool["state"].shape[1:]
+    assert not [e for e in _equations(jaxpr) for v in (*e.invars, *e.outvars)
+                if tuple(getattr(v.aval, "shape", ())) == layer_state]
     rows = (SLOTS, MAX_LEN // PAGE) + pool["k"].shape[2:]
     assert not [e for e in _equations(jaxpr) if e.primitive.name == "gather"
                 and tuple(e.outvars[0].aval.shape) == rows]
