@@ -24,8 +24,16 @@ enqueues tick k before it has read tick k-1's result. Everything it stages it
 knows a tick ahead but a row's `token` and `keys`, which ARE that result. So
 the program takes the previous tick's fetched vector beside the buffer, as
 the device array it still is, and a row whose `fed` column is set reads its
-token and key words from there (one `where` in `unpack`); a row that joined
-from a prefill since reads the buffer's, which the host filled.
+token and key words from there (one `where` in `unpack`); a row whose first
+token the host has read reads the buffer's, which the host filled.
+
+A prefill unit is not waited for either. A row's FIRST token is drawn on the
+device (`first_token`: the request's seed to its key, the split, the family's
+sampler over the unit's logits, as the host did them one by one) and written,
+with the rng chain's two words, over the row's slot of the vector the next
+tick takes as `prev`: the row joins that tick `fed`, like a row of the tick
+before. The host reads the unit's own small vector (`split_first`) one
+hand-over later.
 """
 
 from __future__ import annotations
@@ -117,6 +125,57 @@ def split_result(fetched: np.ndarray, slots: int) -> tuple:
     return (fetched[:slots],
             fetched[slots:3 * slots].view(np.uint32).reshape(slots, 2),
             fetched[3 * slots:])
+
+
+# the first token's knobs, one int32 vector: the seed's low 32 bits (what
+# `jax.random.PRNGKey` keeps of a Python integer), top-k, the row's slot, and
+# the two float32 knobs as their bits
+FIRST_COLUMNS = 5
+
+
+def stage_first(seed: int, slot: int, temperature: float, top_k: int,
+                top_p: float) -> np.ndarray:
+    """On the host: what `first_token` takes beside the unit's logits, in
+    one buffer (one copy in)."""
+    staged = np.empty(FIRST_COLUMNS, np.int32)
+    staged[:1].view(np.uint32)[0] = seed & 0xFFFFFFFF
+    staged[1:3] = top_k, slot
+    staged[3:].view(np.float32)[:] = temperature, top_p
+    return staged
+
+
+@functools.cache
+def first_token(sample, slots: int):
+    """The program that draws a prefilled row's first token where the logits
+    lie, for an engine of `slots` slots, made from the families'
+    `sample(logits, temperature, top_k, top_p, keys)`: (logits [1, V],
+    `stage_first`'s vector, prev, the unit's counters or None) -> (the
+    unit's one read: int32 [token, chain word 0, chain word 1, then the
+    counters], `prev` with the row's slot holding that token and chain, in
+    `pack_result`'s layout). The key is `PRNGKey(seed)`'s, the
+    chain and the token's key its `split`'s: the bits the host's eager calls
+    gave. `prev` is not donated: the tick in flight's vector is still to be
+    read."""
+
+    def prefill_first(logits, staged, prev, counters):
+        bits = jax.lax.bitcast_convert_type
+        seed, slot = bits(staged[0], jnp.uint32), staged[2]
+        knobs = bits(staged[3:], jnp.float32)
+        chain, key = jax.random.split(jax.random.PRNGKey(seed))
+        token = sample(logits, knobs[:1], staged[1:2], knobs[1:], key[None])
+        words = bits(chain, jnp.int32)
+        parts = [token, words] + ([] if counters is None else [counters])
+        fed = jax.lax.dynamic_update_slice(prev, token, (slot,))
+        fed = jax.lax.dynamic_update_slice(fed, words, (slots + 2 * slot,))
+        return jnp.concatenate(parts), fed
+
+    return jax.jit(prefill_first)
+
+
+def split_first(fetched: np.ndarray) -> tuple:
+    """On the host: (token, [2] uint32 rng chain, the counters that follow,
+    possibly none) of a final unit's fetched vector."""
+    return int(fetched[0]), fetched[1:3].view(np.uint32), fetched[3:]
 
 
 @functools.cache

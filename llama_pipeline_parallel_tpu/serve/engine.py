@@ -35,6 +35,16 @@ length is known a tick ahead and left out. `shutdown()`, an idle boundary
 and a cancellation collect the tick in flight first, so every row-tick the
 device ran is a token a handle received, the overruns apart.
 
+A prefill unit is not waited for either: it is handed to the device, and the
+host reads its result (its counters; a final unit's first token and rng
+chain) only after the NEXT hand-over is enqueued behind it, the next unit of
+the step's burst or the step's decode tick. The first token is drawn on the
+device and fed to that tick there (`tick_io.first_token`), so the row joins
+the tick before the host has seen its token; a first token that is the eos
+is seen one tick late and overruns once, like any other eos. A request's
+first token reaches its handle one hand-over later than the device made it,
+never later: no unit is in flight across a step boundary.
+
 Token parity contract: a request served here emits EXACTLY the tokens of an
 independent `generate(params, padded_prompt, cfg, gen,
 rng=PRNGKey(request.seed))` call (prompt left-padded to the same bucket) —
@@ -318,6 +328,9 @@ class _Running:
     t_first: float
     in_flight: int = 0       # ticks dispatched with this row, not collected
     finished: bool = False   # left the batch: a row still in flight overran
+    # its first token is its prefill unit's result, which the host has not
+    # read yet: a tick it joins meanwhile is fed token and key on the device
+    first_unread: bool = False
 
 
 @dataclasses.dataclass
@@ -330,6 +343,7 @@ class _Tick:
     ahead: bool              # enqueued while the tick before was in flight
     pages: tuple             # (live, table) logical pages of its rows
     branch: int              # `sampler_branch` of its staged knobs
+    joined_fed: int          # rows fed their FIRST token from a unit in flight
     stage_s: float
     dispatch_s: float
     h2d_s: float
@@ -357,6 +371,23 @@ class _Prefilling:
     # prefill recomputes only its tail via decode.paged_prefill_span
     match: object = None
     warm: bool = False
+
+
+@dataclasses.dataclass
+class _Unit:
+    """A prefill unit the device was handed and the host has not read."""
+
+    pf: _Prefilling
+    # its one read, still on the device: the family's counters of the unit,
+    # behind the first token and the rng chain's two words where the unit
+    # was its request's last (`tick_io.first_token`); None: nothing to read
+    vector: jax.Array | None
+    row: _Running | None     # the row a final unit made, its token unread
+    ts: float                # wall clock at its hand-over
+    t0: float                # `perf_counter` then
+    handover_s: float        # host seconds the hand-over took
+    offset: int
+    cost: int
 
 
 class ServeEngine:
@@ -420,7 +451,9 @@ class ServeEngine:
         self._degraded: str | None = None
         self._lock = threading.Lock()
         self._work = threading.Event()   # ServeLoop parks on this when idle
-        self._sample_first = jax.jit(sample_rowwise)
+        # a prefilled row's first token, drawn where the unit's logits lie
+        self._first_token = tick_io.first_token(sample_rowwise,
+                                                serve_cfg.max_slots)
         # the tick's program: one staged buffer in, one fetched vector out
         self._tick_program = self._family.decode_tick
         # the tick in flight (None at start, after an idle boundary and
@@ -429,6 +462,12 @@ class ServeEngine:
         self._in_flight: _Tick | None = None
         self._no_fetch = jnp.zeros(
             3 * serve_cfg.max_slots + len(self._family.counters), jnp.int32)
+        # the prefill unit in flight (None at every step boundary), and the
+        # vector the step's tick takes as `prev` once a unit of the step
+        # made a row: the tick in flight's with the new rows' slots holding
+        # their first tokens and keys (None: that tick's own)
+        self._unit: _Unit | None = None
+        self._feed: jax.Array | None = None
         self.steps = 0
         self.prefill_chunks_last_tick = 0
         self.prefill_chunks_total = 0
@@ -453,6 +492,8 @@ class ServeEngine:
         # ticks enqueued behind a tick in flight; row-ticks run and discarded
         self._tick_ahead = 0
         self._tick_overrun = 0
+        # rows that joined a tick with token and key fed from a unit in flight
+        self._tick_joined_fed = 0
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
         self._tick_counters = dict.fromkeys(self._family.counters, 0)
@@ -663,15 +704,16 @@ class ServeEngine:
         """One step boundary: admit (without a chunk budget: whole prompts)
         or advance bounded prefill chunks (with one), then stage and enqueue
         one decode tick over all slots, then collect the tick enqueued at the
-        boundary before. Returns False when there was nothing to do (caller
-        may sleep)."""
+        boundary before and the step's last prefill unit. Returns False when
+        there was nothing to do (caller may sleep)."""
         self._cancel_abandoned()
         # admission and prefill chunks: the loop's host work outside the
         # decode tick, one profiler event a step (`serve_prefill` nests in it)
         with trace.annotate(trace.SERVE_ADMIT):
             self._advance_prefill()
         if not self._occupants:
-            # rows that overran their eos may be all a tick in flight holds
+            # rows that overran their eos may be all a tick in flight holds;
+            # a step that only prefills reads its unit here
             self._collect()
             if self._prefilling:      # prefill-only tick is still work
                 self._tick_done()
@@ -788,18 +830,7 @@ class ServeEngine:
             try:
                 finished = self._run_prefill_chunk(pf, cost)
             except Exception as e:
-                logger.exception("prefill of %s failed",
-                                 pf.request.request_id)
-                self.stats.record_failed(pf.request.tenant)
-                self._prefilling.remove(pf)
-                self.slots.release(pf.slot)
-                if self._reqtrace is not None:
-                    b = self._rt.pop(pf.request.request_id, None)
-                    if b is not None:
-                        self._reqtrace.write(b.build(
-                            "failed", time.time(),
-                            tokens=len(pf.handle.tokens_out)))
-                pf.handle._finish(e)
+                self._fail_prefill(pf, e)
                 continue
             spent += cost
             chunks_run += 1
@@ -884,25 +915,40 @@ class ServeEngine:
             return None
 
     def _run_prefill_chunk(self, pf: _Prefilling, cost: int) -> bool:
-        """Run one prefill unit of `cost` tokens for `pf`; on the final
-        chunk, sample the request's first token (the same `sample_rowwise`
-        program and rng discipline whichever prefill produced the logits)
-        and join the decode batch. Returns True when the request finished
-        prefilling."""
+        """Hand one prefill unit of `cost` tokens for `pf` to the device,
+        and only then read the unit handed over before it (the device runs
+        this one meanwhile): a unit's result is read one hand-over late.
+        Returns True when the request finished prefilling."""
+        unit = self._hand_over_unit(pf, cost)
+        self._collect_unit(ahead=True)
+        self._unit = unit
+        return unit.row is not None
+
+    def _hand_over_unit(self, pf: _Prefilling, cost: int) -> _Unit:
+        """Enqueue one prefill unit and wait for nothing. On the final unit
+        the request's first token is drawn on the device (the same
+        `sample_rowwise` and rng discipline whichever prefill produced the
+        logits) and written over the row's slot of the vector the step's
+        tick takes as `prev`; the row joins the decode batch with its token
+        unread (`first_unread`). Everything else the tick needs of the row
+        the host knows now."""
         slot = pf.slot
+        ts, t0 = time.time(), time.perf_counter()
         offset0 = pf.done
-        with trace.span("serve_prefill", request=pf.request.request_id,
-                        bucket=pf.bucket, slot=slot, chunk=cost,
-                        offset=pf.done) as sp:
-            if pf.warm:
-                # prefix-cache tail: recompute only [done, done + cost) —
-                # start and length are divergence-determined, not
-                # page-aligned, so the span kernel scatters per-token into
-                # the slot's (possibly just-forked) pages
+        row = None
+        with trace.annotate("serve_prefill"):
+            if pf.warm or cost < pf.bucket:
+                # a chunk, or a prefix-cache tail: recompute only [done,
+                # done + cost). A tail's start and length are
+                # divergence-determined, not page-aligned, so the span
+                # kernel scatters per-token into the slot's (possibly
+                # just-forked) pages
+                program = (self._family.paged_prefill_span if pf.warm
+                           else self._family.paged_prefill_chunk)
                 c0, c1 = pf.done, pf.done + cost
                 self.slots.ensure_capacity(slot, c1)
                 with trace.annotate(trace.PREFILL_ENQUEUE):
-                    out = self._family.paged_prefill_span(
+                    out = program(
                         self.params, jnp.asarray(pf.ids[:, c0:c1]),
                         jnp.asarray(pf.mask[:, c0:c1]),
                         jnp.asarray(pf.positions[:, c0:c1]), self.slots.pool,
@@ -911,10 +957,8 @@ class ServeEngine:
                         self.cfg)
                 self.slots.pool = out["pool"]
                 self.slots.kv_mask = out["kv_mask"]
-                logits = out["logits"]
-                next_pos = int(pf.positions[0, -1]) + 1
                 pf.done = c1
-            elif cost == pf.bucket:
+            else:
                 # single shot: a row the bucket long, which write_pages
                 # pages
                 with trace.annotate(trace.PREFILL_ENQUEUE):
@@ -922,25 +966,8 @@ class ServeEngine:
                         self.params, jnp.asarray(pf.ids),
                         jnp.asarray(pf.mask), self.cfg, pf.bucket)
                 self.slots.admit(slot, out)
-                logits = out["logits"]
-                next_pos = int(out["next_pos"][0])
                 pf.done = pf.bucket
-            else:
-                c0, c1 = pf.done, pf.done + cost
-                self.slots.ensure_capacity(slot, c1)
-                with trace.annotate(trace.PREFILL_ENQUEUE):
-                    out = self._family.paged_prefill_chunk(
-                        self.params, jnp.asarray(pf.ids[:, c0:c1]),
-                        jnp.asarray(pf.mask[:, c0:c1]),
-                        jnp.asarray(pf.positions[:, c0:c1]), self.slots.pool,
-                        jnp.asarray(self.slots.page_table[slot]),
-                        jnp.int32(slot), self.slots.kv_mask, jnp.int32(c0),
-                        self.cfg)
-                self.slots.pool = out["pool"]
-                self.slots.kv_mask = out["kv_mask"]
-                logits = out["logits"]
-                next_pos = int(pf.positions[0, -1]) + 1
-                pf.done = c1
+            vector = out.get("counters")
             if pf.done >= pf.bucket:
                 if self._prefix and pf.match is not None:
                     # index the freshly written prompt pages so later
@@ -949,49 +976,107 @@ class ServeEngine:
                     self.slots.register_prefix(slot, pf.match.hashes,
                                                pf.ids[0], pf.mask[0])
                 gen = pf.request.gen
-                chain, first_key = jax.random.split(
-                    jax.random.PRNGKey(pf.request.seed))
+                vector, self._feed = self._first_token(
+                    out["logits"],
+                    jnp.asarray(tick_io.stage_first(
+                        pf.request.seed, slot, gen.temperature, gen.top_k,
+                        gen.top_p)),
+                    self._prev(), vector)
+                # the rope position of the first generated token is the
+                # host's own count (the programs' `next_pos`, never read)
+                row = _Running(
+                    request=pf.request, handle=pf.handle, token=0,
+                    key=np.zeros(2, np.uint32),
+                    pos=int(pf.positions[0, -1]) + 1, write_pos=pf.bucket,
+                    emitted=0, t_admit=pf.t_admit, t_first=0.0,
+                    first_unread=True)
+                self._occupants[slot] = row
+        return _Unit(pf=pf, vector=vector, row=row, ts=ts, t0=t0,
+                     handover_s=time.perf_counter() - t0, offset=offset0,
+                     cost=cost)
+
+    def _prev(self) -> jax.Array:
+        """What the next tick takes as the tick before's vector."""
+        if self._feed is not None:
+            return self._feed
+        return (self._no_fetch if self._in_flight is None
+                else self._in_flight.fetch)
+
+    def _collect_unit(self, ahead: bool = False) -> None:
+        """Read the prefill unit in flight, if any: its one transfer back.
+        `ahead`: the next hand-over (a unit, or the step's tick) was enqueued
+        behind it first. The unit's `serve_prefill` span is closed here with
+        its counters (`dur`: the host's seconds of the hand-over and of this
+        read, which tile with the tick's; `ahead`; `reads`); a final unit's
+        first token is pushed, `t_first` stamped and the request trace's
+        `first_token` written here, one hand-over after the device made it.
+        A read that raises fails the unit's own request and nobody else's."""
+        unit, self._unit = self._unit, None
+        if unit is None:
+            return
+        pf, row = unit.pf, unit.row
+        t0 = time.perf_counter()
+        fetched = None
+        try:
+            if unit.vector is not None:
                 # the one place admission waits for the device
                 with trace.annotate(trace.PREFILL_FIRST):
-                    first = self._sample_first(
-                        logits,
-                        jnp.asarray([gen.temperature], jnp.float32),
-                        jnp.asarray([gen.top_k], jnp.int32),
-                        jnp.asarray([gen.top_p], jnp.float32),
-                        first_key[None])
-                    token = int(first[0])
-            if "counters" in out:
-                # the family's counts of this prefill unit (ready with the
-                # token above: the same program's output)
-                sp.update(zip(self._family.counters,
-                              np.asarray(out["counters"]).tolist()))
-
+                    fetched = np.asarray(unit.vector)
+        except Exception as e:
+            self._fail_prefill(pf, e)
+            return
+        t_read = time.perf_counter()
+        counters = fetched
+        if row is not None:
+            token, chain, counters = tick_io.split_first(fetched)
+        trace.recorder().emit(
+            "serve_prefill", ts=unit.ts, dur=unit.handover_s + t_read - t0,
+            request=pf.request.request_id, bucket=pf.bucket, slot=pf.slot,
+            chunk=unit.cost, offset=unit.offset, ahead=int(ahead),
+            reads=int(fetched is not None),
+            **(dict(zip(self._family.counters, counters.tolist()))
+               if counters is not None else {}))
         # like the tick's flush: a span line, an anchor (a cell of long
         # chunks flushes too seldom to anchor a capture of seconds)
         trace.wallclock_anchor()
         rt_b = (self._rt.get(pf.request.request_id)
                 if self._reqtrace is not None else None)
         if rt_b is not None:
-            # the span's own clock readings — chunk timing without a
-            # second timer around the device call
-            rt_b.prefill_chunk(sp["ts"], sp["dur"], offset0, cost,
-                               tick=self.steps)
-        if pf.done < pf.bucket:
-            return False
-
-        t_first = time.time()
+            # hand-over to result: what the request waited for this unit
+            rt_b.prefill_chunk(unit.ts, t_read - unit.t0, unit.offset,
+                               unit.cost, tick=self.steps)
+        if row is None:
+            return
+        row.t_first = time.time()
         if rt_b is not None:
-            rt_b.first_token(t_first)
-        running = _Running(request=pf.request, handle=pf.handle, token=token,
-                           pos=next_pos, write_pos=pf.bucket,
-                           key=np.asarray(chain), emitted=1,
-                           t_admit=pf.t_admit, t_first=t_first)
-        self._occupants[slot] = running
+            rt_b.first_token(row.t_first)
+        row.token, row.key = token, chain
+        row.emitted, row.first_unread = 1, False
         pf.handle._push(token)
+        gen = pf.request.gen
         if (gen.eos_token_id is not None and token == gen.eos_token_id) \
                 or gen.max_new_tokens == 1:
-            self._finish(slot, running)  # freed before any decode tick
-        return True
+            # an eos is seen here, a tick late if the row joined one (an
+            # overrun); a budget of one token never joined
+            self._finish(pf.slot, row)
+
+    def _fail_prefill(self, pf: _Prefilling, error: Exception) -> None:
+        """A unit of `pf` raised, at its hand-over or at its deferred read:
+        fail its request and free its slot; the rows beside it are as they
+        were (a row it had already made leaves the batch, and its row of a
+        tick enqueued meanwhile is an overrun)."""
+        logger.error("prefill of %s failed", pf.request.request_id,
+                     exc_info=error)
+        self.stats.record_failed(pf.request.tenant)
+        if pf in self._prefilling:
+            self._prefilling.remove(pf)
+        r = self._occupants.get(pf.slot)
+        if r is not None and r.handle is pf.handle:
+            self._occupants.pop(pf.slot)
+            r.finished = True
+        self.slots.release(pf.slot)
+        self._write_failed_trace(pf.request, len(pf.handle.tokens_out))
+        pf.handle._finish(error)
 
     def _decode_tick(self) -> None:
         """Hand the device its next tick, THEN read the one before: while the
@@ -999,14 +1084,21 @@ class ServeEngine:
         stages), the device runs tick k."""
         before = self._in_flight
         self._in_flight = self._dispatch_tick(before)
+        self._feed = None
         if before is not None:
             self._collect_tick(before)
+        # the step's last prefill unit, in the device's order: behind the
+        # tick before it, in front of the tick just enqueued
+        self._collect_unit(ahead=self._in_flight is not None)
 
     def _collect(self) -> None:
-        """Read the tick in flight, if any, with none enqueued behind it."""
+        """Read the tick in flight, then the prefill unit in flight, if any,
+        with nothing enqueued behind them."""
         before, self._in_flight = self._in_flight, None
+        self._feed = None
         if before is not None:
             self._collect_tick(before)
+        self._collect_unit()
 
     def _dispatch_tick(self, before: "_Tick | None") -> "_Tick | None":
         """Stage and enqueue one decode tick over every row that has a token
@@ -1017,23 +1109,29 @@ class ServeEngine:
         device; `enqueue`: the jitted call; then adopting its outputs). The
         host knows everything it stages a tick ahead but the token and rng
         key of a row `before` holds: those the program reads from `before`'s
-        fetched vector, on the device (`fed`). A row that ends by length when
+        fetched vector, on the device (`fed`), and a row a prefill unit of
+        this step made from the unit's (`_prev`: `before`'s vector with such
+        rows written over their slots). A row that ends by length when
         `before` lands is left out; one that ends by eos is not known yet and
         overruns. None where no row is left to decode."""
         scfg = self.serve_cfg
         t_entry = time.perf_counter()
         rows = [(slot, r) for slot, r in self._occupants.items()
-                if r.emitted + r.in_flight < r.request.gen.max_new_tokens]
+                if r.emitted + r.in_flight + r.first_unread
+                < r.request.gen.max_new_tokens]
         if not rows:
             return None
         with trace.annotate(trace.TICK_STAGE):
             # fresh every tick: nothing writes a buffer the device was given
             staged = tick_io.stage(scfg.max_slots,
                                    self.slots.page_table.shape[1])
-            pages_live = 0
+            pages_live = joined_fed = 0
             for slot, r in rows:
-                if r.in_flight:
+                if r.in_flight or r.first_unread:
+                    # its token and key are a result the host has not read:
+                    # the tick in flight's, or its prefill unit's
                     staged.fed[slot] = 1
+                    joined_fed += r.first_unread
                 else:
                     staged.token[slot] = r.token
                     staged.keys[slot] = r.key
@@ -1070,8 +1168,7 @@ class ServeEngine:
             with trace.annotate(trace.TICK_ENQUEUE):
                 # its return is the enqueue's return
                 out = self._tick_program(
-                    self.params, staged_d,
-                    self._no_fetch if before is None else before.fetch,
+                    self.params, staged_d, self._prev(),
                     self.slots.pool, self.slots.kv_mask, self.cfg)
             t_enqueued = time.perf_counter()
             # release the staged copy now, while the device runs the tick, as
@@ -1088,7 +1185,8 @@ class ServeEngine:
             fetch=out["fetch"], rows=rows, ts=t_wall,
             ahead=before is not None,
             pages=(pages_live, len(rows) * self.slots.page_table.shape[1]),
-            branch=branch, stage_s=t0 - t_entry, dispatch_s=t_dispatched - t0,
+            branch=branch, joined_fed=joined_fed, stage_s=t0 - t_entry,
+            dispatch_s=t_dispatched - t0,
             h2d_s=t_copied - t_grown, enqueue_s=t_enqueued - t_copied,
             h2d_copies=h2d_copies)
 
@@ -1171,6 +1269,7 @@ class ServeEngine:
         self._tick_tokens += len(tick.rows)
         self._tick_overrun += overrun
         self._tick_ahead += tick.ahead
+        self._tick_joined_fed += tick.joined_fed
         self._tick_pages[0] += tick.pages[0]
         self._tick_pages[1] += tick.pages[1]
         self._tick_sampler[0] += tick.branch >= 1
@@ -1196,6 +1295,7 @@ class ServeEngine:
                               tokens=self._tick_tokens,
                               rows_overrun=self._tick_overrun,
                               ticks_ahead=self._tick_ahead,
+                              rows_joined_fed=self._tick_joined_fed,
                               kv_pages_live=self._tick_pages[0],
                               kv_pages_table=self._tick_pages[1],
                               ticks_sampled=self._tick_sampler[0],
@@ -1207,6 +1307,7 @@ class ServeEngine:
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
         self._tick_overrun, self._tick_ahead = 0, 0
+        self._tick_joined_fed = 0
         self._tick_pages = [0, 0]
         self._tick_sampler = [0, 0]
         self._tick_copies = [0, 0]
@@ -1338,14 +1439,15 @@ class ServeEngine:
     def shutdown(self) -> None:
         """Fail every queued and in-flight request (process exit path);
         later submits raise EngineShutdown instead of queueing into a dead
-        engine. The decode tick in flight is collected first: its tokens
-        reach their handles (and may finish them) before the rest fail."""
+        engine. The decode tick and the prefill unit in flight are collected
+        first: their tokens reach their handles (and may finish them) before
+        the rest fail."""
         try:
             self._collect()
         except Exception:
             # a failed step left the stores poisoned (ServeLoop._run): the
-            # tick in flight cannot be read either
-            logger.exception("the decode tick in flight at shutdown is lost")
+            # tick and the unit in flight cannot be read either
+            logger.exception("what was in flight at shutdown is lost")
         self._flush_decode_span()
         if self._profiler is not None:
             self._profiler.close()  # finalize an open capture window

@@ -214,7 +214,9 @@ TICK_ENQUEUE = "serve_tick_enqueue"      # in dispatch: the jitted call alone
 TICK_BLOCK = "serve_tick_block"          # in wait: `block_until_ready` alone
 TICK_FETCH = "serve_tick_fetch"          # in wait: token, keys, counters to numpy, once
 PREFILL_ENQUEUE = "serve_prefill_enqueue"  # in `serve_prefill`: a unit's call
-PREFILL_FIRST = "serve_prefill_first"    # in `serve_prefill`: the first token's wait
+# round a unit's deferred read (counters; first token and chain), a hand-over
+# after its `serve_prefill` (the hand-over alone) ended: not nested in it
+PREFILL_FIRST = "serve_prefill_first"
 # an empty annotation NAMED `wallclock_us=<time.time() in microseconds>`
 WALLCLOCK_PREFIX = "wallclock_us="
 

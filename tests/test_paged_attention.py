@@ -248,6 +248,32 @@ def test_mosaic_compiles_the_kernel_at_a_serving_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 16
 
 
+@pytest.mark.parametrize("cell,slots,vocab,counters", [
+    ("serve-closed-16.deepseek", 16, 102400, 0),
+    ("serve-closed-64.solar-open2", 64, 24576, 6),
+    ("serve-reason-64.nemotron3-super", 64, 32768, 7),
+])
+def test_the_first_tokens_program_compiles_at_a_whole_bucket_cells_shapes(
+        one_chip, mosaic, cell, slots, vocab, counters):
+    """`tick_io.first_token` (a prefilled row's first token drawn where the
+    logits lie, PR 47) at the shapes of the three cells that prefill whole
+    buckets: one read of 3 + counters words, `prev` back in its own shape,
+    and temporaries of a few copies of one row of logits, nothing more."""
+    from llama_pipeline_parallel_tpu.models import family, tick_io
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    args = _described(
+        (jax.ShapeDtypeStruct((1, vocab), jnp.float32),
+         i32(tick_io.FIRST_COLUMNS), i32(3 * slots + counters),
+         i32(counters) if counters else None), one_chip)
+    compiled = tick_io.first_token(family.sample_rowwise, slots).lower(
+        *args).compile()
+    read, fed = compiled.out_info
+    assert (read.shape, read.dtype) == ((3 + counters,), jnp.int32)
+    assert (fed.shape, fed.dtype) == ((3 * slots + counters,), jnp.int32)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * vocab * 4
+
+
 def _dense_tick(slots, pmax, page, pages):
     cfg = LlamaConfig(vocab_size=256, hidden_size=2048, intermediate_size=256,
                       num_hidden_layers=2, num_attention_heads=16,

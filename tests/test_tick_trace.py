@@ -30,8 +30,7 @@ PARENT_OF = {
     trace.TICK_GROW: trace.TICK_DISPATCH, trace.TICK_H2D: trace.TICK_DISPATCH,
     trace.TICK_ENQUEUE: trace.TICK_DISPATCH,
     trace.TICK_BLOCK: trace.TICK_WAIT, trace.TICK_FETCH: trace.TICK_WAIT,
-    trace.PREFILL_ENQUEUE: "serve_prefill",
-    trace.PREFILL_FIRST: "serve_prefill"}
+    trace.PREFILL_ENQUEUE: "serve_prefill"}
 
 
 def _serve(capture_dir=None):
@@ -108,6 +107,14 @@ def test_old_events_once_a_tick_and_new_events_inside_their_parents(captured):
     # `serve_prefill` still nests in `serve_admit`
     admits = _named(host, trace.SERVE_ADMIT)
     assert all(any(a <= s and e <= b for a, b in admits) for s, e in prefills)
+    # a unit's result is read one hand-over late: `serve_prefill_first` is
+    # round the deferred read, outside the unit's `serve_prefill` event and
+    # after the next hand-over, the second unit's and then the tick's
+    reads = _named(host, trace.PREFILL_FIRST)
+    enqueues = _named(host, trace.TICK_ENQUEUE)
+    assert prefills[0][1] <= prefills[1][1] <= reads[0][0]
+    assert reads[0][1] <= enqueues[0][0] <= enqueues[0][1] <= reads[1][0]
+    assert reads[1][1] <= enqueues[1][0]        # never a second one late
 
 
 def test_the_sums_beside_the_phases(captured):

@@ -270,26 +270,6 @@ def test_the_engine_emits_what_the_thirteen_argument_step_gives(family):
 
 # -- (c) one transfer each way a tick ------------------------------------------
 
-class _Counting:
-    """A module as the engine sees it, whose `asarray` counts and lets
-    through the transfers of `kind` made inside a tick; every other
-    attribute is the module's own."""
-
-    def __init__(self, module, kind, ticking):
-        self._module, self._kind, self._ticking = module, kind, ticking
-        self.seen = []
-
-    def __getattr__(self, name):
-        return getattr(self._module, name)
-
-    def asarray(self, a, *args, **kwargs):
-        if self._ticking and isinstance(a, self._kind):
-            self.seen.append(a)
-            with jax.transfer_guard("allow"):
-                return self._module.asarray(a, *args, **kwargs)
-        return self._module.asarray(a, *args, **kwargs)
-
-
 def _guarded(engine, monkeypatch):
     """Run every dispatch and every collection of a tick of `engine` with
     explicit AND implicit transfers refused
@@ -299,8 +279,8 @@ def _guarded(engine, monkeypatch):
     and the log, in the order the calls ran: ("dispatch" | "collect", the
     tick's number, copies in, copies out)."""
     ticking = []
-    to_device = _Counting(jnp, np.ndarray, ticking)
-    to_host = _Counting(np, jax.Array, ticking)
+    to_device = tick_ahead.Counting(jnp, np.ndarray, ticking)
+    to_host = tick_ahead.Counting(np, jax.Array, ticking)
     monkeypatch.setattr(engine_module, "jnp", to_device)
     monkeypatch.setattr(engine_module, "np", to_host)
     log, dispatched = [], []        # the ticks, kept: a number is an index
@@ -437,7 +417,7 @@ def test_no_write_reaches_a_staging_buffer_the_device_was_given(
     engine = _engine("llama")
     handed, held, ticking = [], [], []
 
-    class Recording(_Counting):
+    class Recording(tick_ahead.Counting):
         def asarray(self, a, *args, **kwargs):
             if ticking and isinstance(a, np.ndarray):
                 assert not np.shares_memory(a, engine.slots.page_table)

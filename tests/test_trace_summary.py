@@ -129,10 +129,11 @@ def test_a_capture_with_no_device_operation_says_so(tmp_path):
 
 def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
                                                           capsys):
-    """`ticks_ahead` and `rows_overrun` come from `spans.jsonl`, found beside
-    or above the capture (or named); lines of other spans and of a build
-    that does not carry them are passed over, and a capture with no such
-    file prints no such section."""
+    """`ticks_ahead`, `rows_overrun` and `rows_joined_fed` come from
+    `spans.jsonl`, found beside or above the capture (or named), and the
+    prefill units' `ahead` and `reads` from its `serve_prefill` lines; lines
+    of other spans and of a build that does not carry them are passed over,
+    and a capture with no such file prints no such section."""
     import json
 
     trace_summary.main([capture])
@@ -142,17 +143,27 @@ def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
              {"name": "serve_prefill", "ticks": 7},
              {"name": "serve_decode_step", "ticks": 5, "tokens": 80},
              {"name": "serve_decode_step", "ticks": 8, "ticks_ahead": 8,
-              "tokens": 100, "rows_overrun": 2}]
+              "tokens": 100, "rows_overrun": 2, "rows_joined_fed": 3},
+             {"name": "serve_prefill", "ahead": 1, "reads": 1},
+             {"name": "serve_prefill", "ahead": 1, "reads": 0},
+             {"name": "serve_prefill", "ahead": 0, "reads": 1},
+             {"name": "serve_request", "ahead": 1, "reads": 1}]
     spans = tmp_path / "spans.jsonl"
     spans.write_text("".join(json.dumps(r) + "\n" for r in lines))
     assert trace_summary.find_spans(
         str(tmp_path / "plugins" / "profile")) == str(spans)
     assert trace_summary.tick_pipeline(str(spans)) == {
-        "ticks": 40, "ticks_ahead": 39, "tokens": 600, "rows_overrun": 2}
+        "ticks": 40, "ticks_ahead": 39, "tokens": 600, "rows_overrun": 2,
+        "rows_joined_fed": 3}
+    assert trace_summary.unit_pipeline(str(spans)) == {
+        "units": 3, "ahead": 2, "reads": 2}
     trace_summary.main([capture])
     out = capsys.readouterr().out
     assert "ticks_ahead 39 of 40 ticks (97.50%)" in out
     assert "rows_overrun 2 of 600 row-ticks" in out
+    assert "rows_joined_fed 3" in out
+    assert "units ahead 2 of 3 (66.67%)" in out
+    assert "reads 2 (0.67 a unit)" in out
     elsewhere = tmp_path / "elsewhere.jsonl"
     elsewhere.write_text(json.dumps(lines[1]) + "\n")
     trace_summary.main([capture, "--spans", str(elsewhere)])

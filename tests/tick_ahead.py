@@ -1,13 +1,35 @@
-"""Helpers for the tests of the engine's one decode tick in flight
-(`serve/engine.py`): the SERIAL order it replaced, kept here as the
-reference, a stepped run of either order with its spans, and the checks
-every family's serving tests make of the two."""
+"""Helpers for the tests of the engine's one decode tick and one prefill
+unit in flight (`serve/engine.py`): the SERIAL order they replaced, kept
+here as the reference, a stepped run of either order with its spans, and the
+checks every family's serving tests make of the two."""
+
+import jax
 
 from llama_pipeline_parallel_tpu import serve
 from llama_pipeline_parallel_tpu.utils import trace
 
-SUMMED = ("ticks", "tokens", "ticks_ahead", "rows_overrun", "h2d_copies",
-          "d2h_copies")
+SUMMED = ("ticks", "tokens", "ticks_ahead", "rows_overrun", "rows_joined_fed",
+          "h2d_copies", "d2h_copies")
+
+
+class Counting:
+    """A module as the engine sees it, whose `asarray` counts and lets
+    through the transfers of `kind` made inside a tick; every other
+    attribute is the module's own."""
+
+    def __init__(self, module, kind, ticking):
+        self._module, self._kind, self._ticking = module, kind, ticking
+        self.seen = []
+
+    def __getattr__(self, name):
+        return getattr(self._module, name)
+
+    def asarray(self, a, *args, **kwargs):
+        if self._ticking and isinstance(a, self._kind):
+            self.seen.append(a)
+            with jax.transfer_guard("allow"):
+                return self._module.asarray(a, *args, **kwargs)
+        return self._module.asarray(a, *args, **kwargs)
 
 
 def step_serially(engine) -> bool:
@@ -18,6 +40,21 @@ def step_serially(engine) -> bool:
     did = engine.step()
     engine._collect()
     return did
+
+
+def units_read_at_once(engine):
+    """The engine's order before it kept a prefill unit in flight: every
+    unit is read as soon as it is handed over, so a row joins its first tick
+    with a token and a key the host has read. Returns the undo."""
+    real = engine._run_prefill_chunk
+
+    def unit_then_read(pf, cost):
+        finished = real(pf, cost)
+        engine._collect_unit()
+        return finished
+
+    engine._run_prefill_chunk = unit_then_read
+    return lambda: setattr(engine, "_run_prefill_chunk", real)
 
 
 def busy(engine) -> bool:
@@ -31,8 +68,11 @@ def run(engine, requests, serially: bool = False, spread: int = 1,
     (`during(engine, step_index)` is called before every step). Returns
     {"handles", "tokens" (what each handle received, an unfinished or failed
     one's too), "spans" (every `serve_decode_step`, the tail flushed),
-    "restarts" (ticks dispatched with none in flight), "sums"}."""
+    "units" (every `serve_prefill`), "restarts" (ticks dispatched with none
+    in flight), "sums"}. `serially`: no tick and no prefill unit is ever in
+    flight when the host stages the next."""
     step = (lambda: step_serially(engine)) if serially else engine.step
+    undo = units_read_at_once(engine) if serially else lambda: None
     spans, restarts, steps = [], [], [0]
     listener = lambda rec: spans.append(dict(rec))
     real_dispatch = engine._dispatch_tick
@@ -64,9 +104,11 @@ def run(engine, requests, serially: bool = False, spread: int = 1,
     finally:
         trace.recorder().remove_listener(listener)
         engine._dispatch_tick = real_dispatch
+        undo()
     ticks = [s for s in spans if s["name"] == "serve_decode_step"]
     names = SUMMED + tuple(engine._family.counters)
     return {"handles": handles, "spans": ticks,
+            "units": [s for s in spans if s["name"] == "serve_prefill"],
             "tokens": [list(h.tokens_out) for h in handles],
             "restarts": len(restarts),
             "sums": {k: sum(s[k] for s in ticks) for k in names}}
@@ -77,7 +119,18 @@ def check_the_spans(result: dict, serially: bool = False) -> None:
     served: one copy each way a tick, and a tick is ahead unless it restarted
     the pipeline (in the serial order none is); over the run, every row-tick
     the device ran is a token a handle received after its first, the
-    overruns apart."""
+    overruns apart; a prefill unit makes at most one read, after the next
+    hand-over (in the serial order: before it), and a row whose first token
+    the host had not read joined its tick fed (in the serial order none)."""
+    for u in result["units"]:
+        assert u["reads"] in (0, 1) and u["ahead"] in (0, 1)
+        assert u["ahead"] == 0 or not serially
+    joined = sum(len(t) > 1 for t in result["tokens"])
+    assert serially or sum(u["ahead"] for u in result["units"]) >= joined
+    # the row of a step's LAST unit joins the step's tick with its token
+    # unread (the rows of the units before it in a burst were read since)
+    assert result["sums"]["rows_joined_fed"] <= (
+        0 if serially else len(result["units"]))
     for s in result["spans"]:
         assert s["h2d_copies"] == s["d2h_copies"] == s["ticks"]
         assert 0 <= s["ticks_ahead"] <= s["ticks"]

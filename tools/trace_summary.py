@@ -24,8 +24,13 @@ ones the per-layer metrics report:
   directory or one of the two above it), how often the engine's one tick in
   flight engaged: `ticks_ahead` of `ticks` (ticks enqueued while the tick
   before them had not been collected) and `rows_overrun` of `tokens`
-  (row-ticks run and discarded: an eos is seen one tick late), summed over
-  the file's `serve_decode_step` lines.
+  (row-ticks run and discarded: an eos is seen one tick late) and
+  `rows_joined_fed` (rows that joined a tick with their first token and key
+  fed from a prefill unit on the device), summed over the file's
+  `serve_decode_step` lines; and how often the prefill unit in flight did:
+  units whose result was read after the next hand-over was enqueued
+  (`ahead`) of all `serve_prefill` lines, and the round trips they made
+  (`reads`).
 
 Usage:
   python tools/trace_summary.py <trace_dir> [--top 15] [--spans spans.jsonl]
@@ -70,6 +75,9 @@ def summarize(path: str, top: int = 15) -> dict:
 
 
 PIPELINE = ("ticks", "ticks_ahead", "tokens", "rows_overrun")
+# on the same lines since the prefill unit in flight; and on `serve_prefill`
+JOINED = "rows_joined_fed"
+UNITS = ("ahead", "reads")
 
 
 def find_spans(trace_dir: str):
@@ -84,15 +92,32 @@ def find_spans(trace_dir: str):
     return None
 
 
-def tick_pipeline(spans_path: str):
-    """Sums of `PIPELINE` over the `serve_decode_step` lines that carry them
-    all; None where the file holds no such line (a trainer's, or a build
-    before the engine kept a tick in flight)."""
+def _span_lines(spans_path: str, name: str, carrying: tuple) -> list:
+    """The file's lines of span `name` that carry every key of `carrying`."""
     from llama_pipeline_parallel_tpu.utils.perf import read_jsonl
 
-    rows = read_jsonl(spans_path, keep=lambda r: (
-        r.get("name") == "serve_decode_step" and all(k in r for k in PIPELINE)))
-    return {k: sum(r[k] for r in rows) for k in PIPELINE} if rows else None
+    return read_jsonl(spans_path, keep=lambda r: (
+        r.get("name") == name and all(k in r for k in carrying)))
+
+
+def tick_pipeline(spans_path: str):
+    """Sums of `PIPELINE` and `JOINED` over the `serve_decode_step` lines
+    that carry all of `PIPELINE`; None where the file holds no such line (a
+    trainer's, or a build before the engine kept a tick in flight)."""
+    rows = _span_lines(spans_path, "serve_decode_step", PIPELINE)
+    if not rows:
+        return None
+    return {k: sum(r.get(k, 0) for r in rows) for k in PIPELINE + (JOINED,)}
+
+
+def unit_pipeline(spans_path: str):
+    """{"units", "ahead", "reads"} over the `serve_prefill` lines that carry
+    `UNITS`; None where the file holds none (a build whose prefill units
+    were each waited for)."""
+    rows = _span_lines(spans_path, "serve_prefill", UNITS)
+    if not rows:
+        return None
+    return {"units": len(rows), **{k: sum(r[k] for r in rows) for k in UNITS}}
 
 
 def _table(title: str, rows: dict) -> None:
@@ -148,7 +173,15 @@ def main(argv: list[str] | None = None) -> None:
               f"{pipeline['ticks']} ticks "
               f"({100.0 * pipeline['ticks_ahead'] / max(pipeline['ticks'], 1):.2f}%)"
               f"\n  rows_overrun {pipeline['rows_overrun']} of "
-              f"{pipeline['tokens']} row-ticks")
+              f"{pipeline['tokens']} row-ticks"
+              f"\n  rows_joined_fed {pipeline[JOINED]}")
+    units = unit_pipeline(spans_path) if spans_path else None
+    if units is not None:
+        print(f"\n== the engine's prefill unit in flight ==\n"
+              f"  units ahead {units['ahead']} of {units['units']} "
+              f"({100.0 * units['ahead'] / units['units']:.2f}%)"
+              f"\n  reads {units['reads']} "
+              f"({units['reads'] / units['units']:.2f} a unit)")
 
 
 if __name__ == "__main__":
