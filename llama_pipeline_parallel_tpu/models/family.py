@@ -12,12 +12,16 @@ window layers, as its configuration says) and sparse experts
 window beside pooled chunk summaries, `models/eva/`) the fourth, the
 state-space / expert block (every layer ONE of a Mamba-2 mixer, a
 grouped-query softmax layer or a latent expert feed-forward, in a published
-order, `models/ssm_moe/`) the fifth. A sixth registers its configuration
-class below.
+order, `models/ssm_moe/`) the fifth, the window / full softmax block
+(grouped-query layers of two kinds in a published order, each kind with its
+own KV heads, rotary base and mask, the window kind with a learned sink in
+its softmax; keys wider than values; sigmoid-routed experts and nothing
+beside the routed sum, `models/window_moe/`) the sixth. A seventh registers
+its configuration class below.
 
 A family also states what a slot's PAGES are (`table_width`,
 `table_columns`): how wide a slot's row of the page table is and which of its
-columns hold pages once so many places of the row are written. Four families
+columns hold pages once so many places of the row are written. Five families
 keep one entry a position for the life of the request (a page every
 `page_size` places, in order: the defaults below); the compressed-window
 family keeps a ring of window pages that is reused and summary pages that grow
@@ -27,12 +31,14 @@ these two and names no family.
 A family may keep a store with one row a SLOT beside the page pool
 (`init_recurrent_store`: the hybrid and the state-space block's recurrent
 state and convolution inputs, the latent block's rings of its window
-layers): what a layer keeps of a sequence there is of constant size, so it
-is a row a slot and not pages. What a family states may depend on the
+layers, the window block's rings of keys and values, whose shape is another
+than its pages': 8 KV heads a ring place beside 4 a page row): what a layer
+keeps of a sequence there is of constant size, so it is a row a slot and not
+pages. What a family states may depend on the
 configuration: a latent model without window layers keeps no such store.
 Whether it can prefill in chunks is a separate fact (`paged_prefill_chunk`):
-the latent block's chunk carries its rings forward from chunk to chunk;
-neither recurrent family's prefill takes the state and the convolution
+the latent and the window block's chunks carry their rings forward from
+chunk to chunk; neither recurrent family's prefill takes the state and the convolution
 inputs a chunk before it left, so neither chunks yet.
 
 `GenerationConfig`, `sample_rowwise` and `sampler_branch` are the same for
@@ -212,6 +218,19 @@ def _eva(cfg) -> ServingFamily:
             "span prefill to recompute a tail from it"))
 
 
+def _window_moe(cfg) -> ServingFamily:
+    from llama_pipeline_parallel_tpu.models.window_moe import decode, model
+
+    return ServingFamily(
+        name="window_moe", prefill_prompt=decode.prefill_prompt,
+        paged_decode_step=decode.paged_decode_step,
+        write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
+        init_recurrent_store=decode.init_recurrent_store,
+        init_params=model.init_params,
+        paged_prefill_chunk=decode.paged_prefill_chunk,
+        counters=decode.COUNTERS)
+
+
 def _ssm_moe(cfg) -> ServingFamily:
     from llama_pipeline_parallel_tpu.models.ssm_moe import decode, model
 
@@ -224,7 +243,8 @@ def _ssm_moe(cfg) -> ServingFamily:
 
 
 _FAMILIES = {"llama": _llama, "hybrid_moe": _hybrid_moe,
-             "latent_moe": _latent_moe, "eva": _eva, "ssm_moe": _ssm_moe}
+             "latent_moe": _latent_moe, "eva": _eva, "ssm_moe": _ssm_moe,
+             "window_moe": _window_moe}
 
 
 def family_of(cfg) -> ServingFamily:
@@ -240,7 +260,8 @@ def family_of(cfg) -> ServingFamily:
 # package under `models/`
 _STORED_DTYPE_CONFIGS = {"hybrid_moe": "HybridMoEConfig",
                          "latent_moe": "LatentMoEConfig", "eva": "EvaConfig",
-                         "ssm_moe": "SsmMoEConfig"}
+                         "ssm_moe": "SsmMoEConfig",
+                         "window_moe": "WindowMoEConfig"}
 
 
 def config_from_meta(model_config: dict):

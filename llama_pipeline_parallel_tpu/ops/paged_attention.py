@@ -12,7 +12,9 @@ reads it (scalar prefetch), so a page goes from the pool in HBM to VMEM once,
 in the pool's dtype, and only pages that hold tokens are fetched at all.
 
 Layout. The pool stays `[L, pages + 1, page, kv_h, hd]` (the write paths and
-`serve/pages.py` rest on it). A page is read as the matrix it already is in
+`serve/pages.py` rest on it); the values' pool may keep another width a head
+than the keys' (`hd_v`: the output's), and the scores are scaled by the
+keys' `hd ** -0.5`. A page is read as the matrix it already is in
 memory, `[page * kv_h, hd]`: row `r` is token `r // kv_h` of KV head
 `r % kv_h`. The query heads `[h, hd]` are multiplied against ALL of a page's
 rows on the MXU (`[h, page * kv_h]` scores, lane-dense) and the rows of other
@@ -28,6 +30,12 @@ page axis carries the running max / sum / accumulator (float32) in VMEM
 scratch. Steps past a row's live pages clamp their block index to the last
 live page, so the pipeline re-uses the buffer it holds and fetches nothing,
 and skip the compute (`pl.when`). A row with no live page returns zeros.
+
+A SINK (`sink`, one float32 logit a query head; None: a plain softmax) is a
+key with no value that every query of the head sees: `exp(sink)` in the
+denominator and nothing in the numerator. It is where the running state
+starts (max = the sink, sum = 1, accumulator 0) where a plain softmax starts
+from nothing; the pages then go by as they do without one.
 
 Numerics: keys and values as stored, float32 scores, softmax statistics and
 accumulator. The query is scaled in its own dtype before the kernel and the
@@ -73,8 +81,10 @@ def _pages_per_step(pmax: int, page_bytes: int) -> int:
 
 
 def _kernel(layer_ref, table_ref, live_ref, q_ref, own_ref, mask_ref, *rest,
-            n: int):
+            n: int, sink: bool = False):
     del layer_ref, table_ref            # read by the index maps only
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
     k_refs, v_refs = rest[:n], rest[n:2 * n]
     o_ref, m_scr, l_scr, acc_scr = rest[2 * n:]
     s = pl.program_id(0)
@@ -83,8 +93,12 @@ def _kernel(layer_ref, table_ref, live_ref, q_ref, own_ref, mask_ref, *rest,
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sink:
+            m_scr[:] = sink_ref[...]
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     for i in range(n):
@@ -127,49 +141,58 @@ def _kernel(layer_ref, table_ref, live_ref, q_ref, own_ref, mask_ref, *rest,
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, layer: jnp.ndarray,
                            page_table: jnp.ndarray, live_pages: jnp.ndarray,
-                           kv_mask: jnp.ndarray) -> jnp.ndarray:
+                           kv_mask: jnp.ndarray,
+                           sink: jnp.ndarray | None = None,
+                           scale: float | None = None) -> jnp.ndarray:
     """One-query softmax attention of every slot row over its live pages.
 
     q: [S, h, hd]; k_pool / v_pool: the pool's arrays whole, [L, pages + 1,
-    page, kv_h, hd] (never sliced: the layer is an index); layer: int32
-    scalar; page_table: [S, Pmax] physical page per logical page;
+    page, kv_h, hd] and [..., hd_v] (never sliced: the layer is an index);
+    sink: float32 [h] or None; scale: the scores' factor (None: the keys'
+    `hd ** -0.5`; a family that stores its keys padded to whole tiles gives
+    the factor of the width they have); layer: int32 scalar; page_table:
+    [S, Pmax] physical page per logical page;
     live_pages: [S] how many leading logical pages of a row hold tokens (0:
     the row is not decoding and gets zeros); kv_mask: [S, Pmax * page], 0 =
-    the position is not attended. Returns [S, h, hd] in q's dtype: what
+    the position is not attended. Returns [S, h, hd_v] in q's dtype: what
     `attention(q[:, None], gathered_k, gathered_v, kv_mask, causal=False)`
     gives over the gathered logical rows, for rows whose mask is zero past
     their live pages."""
     S, h, hd = q.shape
     L, pages, page, kv_h, _ = k_pool.shape
+    hd_v = v_pool.shape[-1]
     pmax = page_table.shape[1]
     g = h // kv_h
     rows = page * kv_h
-    n = _pages_per_step(pmax, rows * hd * k_pool.dtype.itemsize)
+    n = _pages_per_step(
+        pmax, rows * (hd + hd_v) * k_pool.dtype.itemsize // 2)
     steps = pl.cdiv(pmax, n)
 
     # the views the kernel reads: a page as the [page * kv_h, hd] matrix its
     # bytes already are, the mask a row of the page's rows
     k2 = k_pool.reshape(L, pages, rows, hd)
-    v2 = v_pool.reshape(L, pages, rows, hd)
+    v2 = v_pool.reshape(L, pages, rows, hd_v)
     mask = jnp.repeat(kv_mask.reshape(S, pmax, page).astype(jnp.int32), kv_h,
                       axis=-1)                              # [S, Pmax, rows]
     own = jnp.asarray(np.arange(rows)[None, :] % kv_h
                       == np.arange(h)[:, None] // g, jnp.int32)  # [h, rows]
-    q = q * jnp.asarray(hd ** -0.5, q.dtype)
+    q = q * jnp.asarray(hd ** -0.5 if scale is None else scale, q.dtype)
 
-    def page_block(i):
+    def page_block(i, width):
         def index(s, j, layer_ref, table_ref, live_ref):
             # past the live pages: the last live page again (no new fetch)
             p = jnp.minimum(j * n + i, jnp.maximum(live_ref[s] - 1, 0))
             return layer_ref[0], table_ref[s * pmax + p], 0, 0
-        return pl.BlockSpec((None, None, rows, hd), index)
+        return pl.BlockSpec((None, None, rows, width), index)
 
     def row(s, j, *_):
         return s, 0, 0
 
-    pages_in = [page_block(i) for i in range(n)]
+    # the sink a lane-wide block, as the running max is kept
+    sink_in = [] if sink is None else [
+        jnp.broadcast_to(sink.astype(jnp.float32)[:, None], (h, 128))]
     return pl.pallas_call(
-        functools.partial(_kernel, n=n),
+        functools.partial(_kernel, n=n, sink=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(S, steps),
@@ -177,20 +200,23 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                 pl.BlockSpec((None, h, hd), row),
                 pl.BlockSpec((h, rows), lambda s, j, *_: (0, 0)),
                 pl.BlockSpec((None, pmax, rows), row),
-                *pages_in, *pages_in,
+                *(pl.BlockSpec((h, 128), lambda s, j, *_: (0, 0))
+                  for _ in sink_in),
+                *(page_block(i, hd) for i in range(n)),
+                *(page_block(i, hd_v) for i in range(n)),
             ],
-            out_specs=pl.BlockSpec((None, h, hd), row),
+            out_specs=pl.BlockSpec((None, h, hd_v), row),
             scratch_shapes=[
                 pltpu.VMEM((h, 128), jnp.float32),
                 pltpu.VMEM((h, 128), jnp.float32),
-                pltpu.VMEM((h, hd), jnp.float32),
+                pltpu.VMEM((h, hd_v), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((S, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, h, hd_v), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret_mode(),
         name=trace.KERNEL_PAGED_DECODE_ATTN,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       page_table.reshape(-1).astype(jnp.int32),
-      live_pages.astype(jnp.int32), q, own, mask,
+      live_pages.astype(jnp.int32), q, own, mask, *sink_in,
       *([k2] * n), *([v2] * n))

@@ -174,10 +174,22 @@ MOE_LATENT_OUT = "moe_latent_out"    # their weighted sum -> the model's width
 SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_STEP, SSM_NORM, MOE_LATENT_IN,
               MOE_LATENT_OUT)
 
+# models/window_moe/ (reuses `attn_qkv`, `attn_out`, `kv_write`, `kv_gather`,
+# `ring_write`, `ring_gather`, `mlp`, `decode_mlp`, the `moe_*` names,
+# `lm_head`, `sample` for the same work). The attention of its two kinds of
+# softmax layer, each in the tick and in a prefill or a chunk; a tuple of
+# their own for the same reason as HYBRID_SCOPES: a sixth vocabulary to merge.
+WINDOW_DECODE_ATTN = "window_decode_attn"    # the tick: a window layer over the slot's ring
+WINDOW_PREFILL_ATTN = "window_prefill_attn"  # a prefill's or a chunk's queries, the band
+FULL_DECODE_ATTN = "full_decode_attn"        # the tick: a full layer over the row's live pages
+FULL_PREFILL_ATTN = "full_prefill_attn"      # a prefill's or a chunk's queries, causal
+WINDOW_SCOPES = (WINDOW_DECODE_ATTN, WINDOW_PREFILL_ATTN, FULL_DECODE_ATTN,
+                 FULL_PREFILL_ATTN)
+
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 
-# `name=` of the sixteen pallas_calls: the kernel's instruction in a trace is
+# `name=` of the eighteen pallas_calls: the kernel's instruction in a trace is
 # `<name>.<n>`
 KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
@@ -195,6 +207,8 @@ KERNEL_LATENT_PREFILL_ATTN = "latent_prefill_attn"  # under LATENT_READ_PREFILL
 KERNEL_EVA_PREFILL_ATTN = "eva_prefill_attn"  # under EVA_ATTN_PREFILL
 KERNEL_GROUPED_MATMUL = "grouped_matmul"  # under MOE_EXPERTS
 KERNEL_SSM_STATE_STEP = "ssm_state_step"  # under SSM_STEP
+KERNEL_WINDOW_PREFILL_ATTN = "window_prefill_attn"  # under WINDOW_PREFILL_ATTN
+KERNEL_FULL_CHUNK_ATTN = "full_chunk_attn"  # under FULL_PREFILL_ATTN
 
 KERNELS = tuple(v for k, v in sorted(globals().items())
                 if k.startswith("KERNEL_"))

@@ -9,17 +9,22 @@ import hybrid_tiny
 import latent_tiny
 import mla_tiny
 import ssm_tiny
+import window_tiny
 from llama_pipeline_parallel_tpu import serve
 from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 
 FAMILIES = ("llama", "hybrid_moe", "latent_moe.dots3", "latent_moe.a.x-k1",
-            "eva", "ssm_moe")
+            "eva", "ssm_moe", "window_moe")
 SLOTS = 3
 _ROWS = dict(max_len=48, prompt_buckets=(8, 16), page_size=8, num_pages=32)
 _LATENT = dict(max_len=64, prompt_buckets=(8, 16, 32), page_size=4,
                num_pages=64, prefill_chunk_tokens=8)
+_WINDOW = dict(max_len=64, prompt_buckets=(8, 16, 32),
+               page_size=window_tiny.PAGE, num_pages=64,
+               prefill_chunk_tokens=8)
 _TINY = {"hybrid_moe": (hybrid_tiny, _ROWS), "ssm_moe": (ssm_tiny, _ROWS),
+         "window_moe": (window_tiny, _WINDOW),
          "latent_moe.dots3": (latent_tiny, _LATENT),
          "latent_moe.a.x-k1": (mla_tiny, _LATENT)}
 

@@ -14,6 +14,7 @@ import eva_tiny
 import hybrid_tiny
 import latent_tiny
 import ssm_tiny
+import window_tiny
 from llama_pipeline_parallel_tpu import serve
 from llama_pipeline_parallel_tpu.models import family as families
 from llama_pipeline_parallel_tpu.models.llama import model as llama
@@ -126,8 +127,14 @@ def _ssm():
         max_len=48, prompt_buckets=(8, 16), page_size=8, num_pages=32)
 
 
+def _window():
+    return window_tiny.config(), window_tiny.both_sides()[0], dict(
+        max_len=48, prompt_buckets=(8, 16), page_size=window_tiny.PAGE,
+        num_pages=48, prefill_chunk_tokens=8)
+
+
 FAMILIES = {"llama": _dense, "hybrid_moe": _hybrid, "latent_moe": _latent,
-            "eva": _eva, "ssm_moe": _ssm}
+            "eva": _eva, "ssm_moe": _ssm, "window_moe": _window}
 
 
 # a family added to `models/family.py` fails here until it has a tiny conf

@@ -32,6 +32,7 @@ from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.config import LlamaConfig
 from llama_pipeline_parallel_tpu.ops import (
     eva_prefill_attention,
+    gqa_prefill_attention,
     grouped_matmul,
     latent_prefill_attention,
     paged_attention,
@@ -203,7 +204,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
     for module in (sparse_latent_attention, paged_latent_attention,
                    latent_prefill_attention, eva_prefill_attention,
-                   grouped_matmul, ssm_state_step):
+                   gqa_prefill_attention, grouped_matmul, ssm_state_step):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without one
@@ -842,3 +843,139 @@ def test_an_expert_layer_compiled_for_the_chip_makes_no_copy_of_the_stack(
     assert text.count("tpu_custom_call") >= 3 and "grouped_matmul" in text
     assert "ragged" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+# -- the window / full softmax family, compiled for the same chip ------------------
+
+@pytest.mark.parametrize("kind,keys", [("window", 2048 + 128),
+                                       ("full", 2048), ("full", 34304)],
+                         ids=["band", "bucket", "row-end"])
+def test_mosaic_compiles_the_grouped_prefill_kernels_at_the_mixed_cells_shapes(
+        one_chip, mosaic, kind, keys):
+    """A 2048-token unit of 64 heads of 192 over 8 (window) or 4 (full) KV
+    heads with values of 128: the band against its own context, the causal
+    kernel against its own bucket and against a whole 34,304-place row. The
+    scores stay in the kernel: what is made beside it is the operands laid
+    out a KV head (nothing of [64, 2048, keys] float32 exists)."""
+    G = 8 if kind == "window" else 4
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    q, k, v = shape(1, 2048, 64, 192), shape(1, keys, G, 192), shape(
+        1, keys, G, 128)
+    valid = jax.ShapeDtypeStruct((1, keys), jnp.int32)
+    if kind == "window":
+        args = _described((q, k, v, valid,
+                           jax.ShapeDtypeStruct((64,), jnp.float32)), one_chip)
+        fn = lambda q, k, v, valid, sink: (
+            gqa_prefill_attention.window_prefill_attention(
+                q, k, v, valid, sink, 128))
+        name = "window_prefill_attn"
+    else:
+        args = _described((q, k, v, valid,
+                           jax.ShapeDtypeStruct((), jnp.int32)), one_chip)
+        fn = gqa_prefill_attention.full_prefill_attention
+        name = "full_chunk_attn"
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert name in text and "tpu_custom_call" in text
+    operands = 2 * (2048 * 64 * 192 + keys * G * (192 + 128))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * operands
+
+
+@pytest.mark.parametrize("store", ["pages", "rings"])
+def test_mosaic_compiles_the_widened_kernel_at_the_mixed_cells_shapes(
+        one_chip, mosaic, store):
+    """64 rows of 64 query heads against keys stored 256 wide beside values
+    of 128: the full layers' pages (4 KV heads, 536 logical pages a row, a
+    page kept as its matrix and seen through a reshape) and the window
+    layers' rings as a pool of one page a slot (8 KV heads, 128 places, a
+    sink a head). XLA:TPU reads either through a bitcast: nothing
+    pool-sized is made in front of the kernel."""
+    slots, h = 64, 64
+    if store == "pages":
+        layers, pages, page, kv_h, pmax = 2, 2048, 64, 4, 536
+        k = jax.ShapeDtypeStruct((layers, pages + 1, page * kv_h, 256),
+                                 jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((layers, pages + 1, page * kv_h, 128),
+                                 jnp.bfloat16)
+        sink = None
+    else:
+        layers, pages, page, kv_h, pmax = 5, slots - 1, 128, 8, 1
+        k = jax.ShapeDtypeStruct((layers, slots, page, kv_h, 256), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((layers, slots, page, kv_h, 128), jnp.bfloat16)
+        sink = jax.ShapeDtypeStruct((h,), jnp.float32)
+    by_head = lambda a: a.reshape(layers, pages + 1, page, kv_h, a.shape[-1])
+    args = _described(
+        (jax.ShapeDtypeStruct((slots, h, 256), jnp.bfloat16), k, v,
+         jax.ShapeDtypeStruct((), jnp.int32),
+         jax.ShapeDtypeStruct((slots, pmax), jnp.int32),
+         jax.ShapeDtypeStruct((slots,), jnp.int32),
+         jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32))
+        + (() if sink is None else (sink,)), one_chip)
+    compiled = jax.jit(
+        lambda q, k, v, layer, table, live, mask, *sink:
+        paged_attention.paged_decode_attention(
+            q, by_head(k), by_head(v), layer, table, live, mask,
+            sink[0] if sink else None, 192 ** -0.5)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "paged_decode_attn" in text and "tpu_custom_call" in text
+    pool_bytes = layers * (pages + 1) * page * kv_h * 256 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_a_window_program_compiled_for_the_chip_keeps_its_two_stores_in_place(
+        one_chip, mosaic, program):
+    """The family at its published head shapes (64 heads, keys of 192 stored
+    256 wide, values of 128, 4 and 8 KV heads, a window of 128) and small
+    everything else, pages many times the weights: the outputs are the
+    donated stores' buffers, nothing as large as the pages is made beside
+    them (no gathered rows in the tick, no page pool turned to another
+    layout by a chunk's scatter), and the kernels are in the programs."""
+    from llama_pipeline_parallel_tpu.models.window_moe import decode as window_decode
+    from llama_pipeline_parallel_tpu.models.window_moe import model as window
+    from llama_pipeline_parallel_tpu.models.window_moe.config import (
+        WindowMoEConfig,
+    )
+
+    slots, pmax, page = 4, 32, 64
+    cfg = WindowMoEConfig(
+        vocab_size=256, hidden_size=256, pattern=(0, 1, 1, 0),
+        moe_layers=(0, 1, 1, 1), intermediate_size=256, router_experts=16,
+        experts_held=8, num_experts_per_tok=4, moe_intermediate_size=128)
+    params = jax.eval_shape(
+        lambda: window.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: {
+        **window_decode.init_page_pool(cfg, 2048, page),
+        **window_decode.init_recurrent_store(cfg, slots)})
+    assert pool["k"].shape == (2, 2049, page * 4, 256)
+    assert pool["ring_k"].shape == (2, slots, 128, 8, 256)
+    z = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    f = jax.ShapeDtypeStruct((slots,), jnp.float32)
+    mask = jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    if program == "tick":
+        args = _described(
+            (params, z, pool, jax.ShapeDtypeStruct((slots, pmax), jnp.int32),
+             z, z, mask, z, jax.ShapeDtypeStruct((slots, 2), jnp.uint32), f,
+             z, f), one_chip)
+        compiled = window_decode.paged_decode_step.lower(*args, cfg).compile()
+        kernels = ("paged_decode_attn",)
+    else:
+        ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+        args = _described(
+            (params, ids, ids, ids, pool,
+             jax.ShapeDtypeStruct((pmax,), jnp.int32), scalar, mask, scalar),
+            one_chip)
+        compiled = window_decode.paged_prefill_chunk.lower(*args, cfg).compile()
+        kernels = ("window_prefill_attn", "full_chunk_attn")
+    analysis = compiled.memory_analysis()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    assert nbytes(pool) > 5 * nbytes(params)
+    assert analysis.alias_size_in_bytes >= nbytes(pool)
+    assert analysis.temp_size_in_bytes < nbytes(pool) // 8, analysis
+    for kernel in kernels:
+        assert kernel in compiled.as_text()

@@ -19,6 +19,7 @@ from llama_pipeline_parallel_tpu.models.llama.manifest import StageManifest
 from llama_pipeline_parallel_tpu.ops import (
     eva_prefill_attention,
     flash_attention,
+    gqa_prefill_attention,
     grouped_matmul,
     latent_prefill_attention,
     paged_attention,
@@ -198,6 +199,8 @@ def test_every_product_and_kernel_call_carries_a_leaf_scope(program, devices):
     (eva_prefill_attention, ("KERNEL_EVA_PREFILL_ATTN",)),
     (grouped_matmul, ("KERNEL_GROUPED_MATMUL",)),
     (ssm_state_step, ("KERNEL_SSM_STATE_STEP",)),
+    (gqa_prefill_attention, ("KERNEL_FULL_CHUNK_ATTN",
+                             "KERNEL_WINDOW_PREFILL_ATTN")),
 ])
 def test_every_pallas_call_passes_its_name(module, kernels):
     source = inspect.getsource(module)
@@ -206,7 +209,7 @@ def test_every_pallas_call_passes_its_name(module, kernels):
     for constant in kernels:
         assert source.count(f"name=trace.{constant},") == 1
         assert getattr(trace, constant) in trace.KERNELS
-    assert len(trace.KERNELS) == 16 == len(set(trace.KERNELS))
+    assert len(trace.KERNELS) == 18 == len(set(trace.KERNELS))
 
 
 def test_flash_kernel_name_reaches_the_lowered_program():
