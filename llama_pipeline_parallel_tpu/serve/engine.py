@@ -81,6 +81,7 @@ from llama_pipeline_parallel_tpu.models.family import (
     sample_rowwise,
     sampler_branch,
 )
+from llama_pipeline_parallel_tpu.ops.paged_attention import pages_per_step
 from llama_pipeline_parallel_tpu.serve.pages import PagedKVCache
 from llama_pipeline_parallel_tpu.serve.reqtrace import TraceContext
 from llama_pipeline_parallel_tpu.serve.telemetry import SLOStats, retry_after_s
@@ -341,7 +342,7 @@ class _Tick:
     rows: list               # [(slot, _Running)]: the rows it decodes
     ts: float                # wall clock at its dispatch
     ahead: bool              # enqueued while the tick before was in flight
-    pages: tuple             # (live, table) logical pages of its rows
+    pages: tuple             # (live, table, steps) of its rows' logical pages
     branch: int              # `sampler_branch` of its staged knobs
     joined_fed: int          # rows fed their FIRST token from a unit in flight
     stage_s: float
@@ -481,7 +482,10 @@ class ServeEngine:
         # logical pages of the decoding rows that hold tokens, and that
         # their page-table rows have: the share of a whole-row read that
         # the tick's attention still makes (ops/paged_attention.py)
-        self._tick_pages = [0, 0]        # live, table
+        # and the grid steps that attention walks for them: a row's live
+        # pages over the pages the kernel takes under one softmax update
+        self._tick_pages = [0, 0, 0]     # live, table, steps
+        self._kv_step_pages = self._pages_per_kernel_step()
         # ticks whose knobs made the program's sampler draw, and sort
         # (`sampler_branch` of the staged arrays, as the program reads it)
         self._tick_sampler = [0, 0]      # sampled, sorted
@@ -497,6 +501,17 @@ class ServeEngine:
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
         self._tick_counters = dict.fromkeys(self._family.counters, 0)
+
+    def _pages_per_kernel_step(self) -> int:
+        """Pages the tick's attention takes under one grid step, asked of the
+        kernel's own rule for this pool's shapes (`ops/paged_attention.py`);
+        1 for a pool that keeps no `k` and `v` pages (the latent families,
+        whose ticks read through kernels of their own)."""
+        pool = self.slots.pool
+        if "k" not in pool or "v" not in pool:
+            return 1
+        return pages_per_step(pool["k"], pool["v"],
+                              self.slots.page_table.shape[1])
 
     def _serving_weights(self, params: dict) -> dict:
         """The tree every program of this engine is called with: the
@@ -1125,7 +1140,7 @@ class ServeEngine:
             # fresh every tick: nothing writes a buffer the device was given
             staged = tick_io.stage(scfg.max_slots,
                                    self.slots.page_table.shape[1])
-            pages_live = joined_fed = 0
+            pages_live = steps_visited = joined_fed = 0
             for slot, r in rows:
                 if r.in_flight or r.first_unread:
                     # its token and key are a result the host has not read:
@@ -1140,7 +1155,9 @@ class ServeEngine:
                 staged.temperature[slot] = r.request.gen.temperature
                 staged.top_k[slot] = r.request.gen.top_k
                 staged.top_p[slot] = r.request.gen.top_p
-                pages_live += r.write_pos // scfg.page_size + 1
+                live = r.write_pos // scfg.page_size + 1
+                pages_live += live
+                steps_visited += -(-live // self._kv_step_pages)
             branch = int(sampler_branch(staged.temperature, staged.top_k,
                                         staged.top_p))
 
@@ -1184,7 +1201,8 @@ class ServeEngine:
         return _Tick(
             fetch=out["fetch"], rows=rows, ts=t_wall,
             ahead=before is not None,
-            pages=(pages_live, len(rows) * self.slots.page_table.shape[1]),
+            pages=(pages_live, len(rows) * self.slots.page_table.shape[1],
+                   steps_visited),
             branch=branch, joined_fed=joined_fed, stage_s=t0 - t_entry,
             dispatch_s=t_dispatched - t0,
             h2d_s=t_copied - t_grown, enqueue_s=t_enqueued - t_copied,
@@ -1256,7 +1274,8 @@ class ServeEngine:
         (`active` is the last tick's alone), `rows_overrun` the ones among
         them whose token was discarded, `ticks_ahead` the ticks enqueued
         behind a tick in flight. The seconds of `TICK_SUMS`,
-        `kv_pages_live`, `kv_pages_table`, `ticks_sampled`, `ticks_sorted`
+        `kv_pages_live`, `kv_pages_table`, `kv_steps_visited`,
+        `ticks_sampled`, `ticks_sorted`
         (counted where the rows are staged), `h2d_copies` / `d2h_copies` (one
         each a tick, where they are made) and the family's counters are
         summed the same way, all of the SAME ticks: every one is folded here,
@@ -1270,8 +1289,8 @@ class ServeEngine:
         self._tick_overrun += overrun
         self._tick_ahead += tick.ahead
         self._tick_joined_fed += tick.joined_fed
-        self._tick_pages[0] += tick.pages[0]
-        self._tick_pages[1] += tick.pages[1]
+        for i, pages in enumerate(tick.pages):
+            self._tick_pages[i] += pages
         self._tick_sampler[0] += tick.branch >= 1
         self._tick_sampler[1] += tick.branch == 2
         self._tick_copies[0] += tick.h2d_copies
@@ -1298,6 +1317,8 @@ class ServeEngine:
                               rows_joined_fed=self._tick_joined_fed,
                               kv_pages_live=self._tick_pages[0],
                               kv_pages_table=self._tick_pages[1],
+                              kv_steps_visited=self._tick_pages[2],
+                              kv_pages_per_step=self._kv_step_pages,
                               ticks_sampled=self._tick_sampler[0],
                               ticks_sorted=self._tick_sampler[1],
                               h2d_copies=self._tick_copies[0],
@@ -1308,7 +1329,7 @@ class ServeEngine:
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
         self._tick_overrun, self._tick_ahead = 0, 0
         self._tick_joined_fed = 0
-        self._tick_pages = [0, 0]
+        self._tick_pages = [0, 0, 0]
         self._tick_sampler = [0, 0]
         self._tick_copies = [0, 0]
         self._tick_sums = [0.0] * len(TICK_SUMS)

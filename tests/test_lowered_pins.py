@@ -10,7 +10,9 @@ CHANGES.md which entries moved and why:
 
 (on another tree: copy this file and `tests/serving_tiny.py` there first).
 The file in the tree was written from the parent of PR 47 (d797252), so
-PR 47 passing it is the proof that it changed none of them. The text is
+PR 47 passing it is the proof that it changed none of them; PR 49, which
+rebuilt the tick's paged kernel, wrote the two tick entries of the five
+families that run it anew and moved no other. The text is
 StableHLO without locations: moving source lines does not move a hash; a
 jax upgrade does, and then the file is written anew on the parent first."""
 

@@ -156,6 +156,118 @@ def test_the_kernel_is_the_gather_and_the_product(scenario, g, dtype):
             assert np.abs(out[row]).max() < 10.0     # no garbage page in it
 
 
+# rows of seven logical pages walked n = 2 or 3 pages a step: Pmax is a
+# multiple of neither, so the last step holds one page and padding
+WIDE = 7
+
+
+def _stepped(name):
+    """(page_table [3, WIDE], live_pages [3], kv_mask [3, WIDE * PAGE]) of
+    three slot rows whose live pages end at chosen places of the steps."""
+    upto = lambda n: (np.arange(WIDE * PAGE) < n).astype(np.int32)
+    table = np.full((3, WIDE), GARBAGE, np.int32)
+    mask = np.zeros((3, WIDE * PAGE), np.int32)
+    if name == "rows_end_inside_a_step":
+        # five pages: the last live page lies INSIDE a step (n = 2: the
+        # third, n = 3: the second) and a later step is dead, with a wholly
+        # masked page inside the live range; two pages: one step only;
+        # none: the row is not decoding, yet owns pages and mask spans
+        live = [5, 2, 0]
+        table[0, :5], table[1, :2], table[2, :2] = [3, 1, 8, 0, 6], [4, 7], [2, 5]
+        mask[0] = upto(5 * PAGE - 3)
+        mask[0, [0, 1, 11]] = 0
+        mask[0, 3 * PAGE:4 * PAGE] = 0
+        mask[1] = upto(PAGE + 2)
+        # a stray span past the live pages counts for nothing: at n = 3 its
+        # block shares the last live page's step and holds that page again
+        mask[1, 2 * PAGE:2 * PAGE + 4] = 1
+        mask[2] = upto(2 * PAGE)
+    elif name == "rows_end_with_a_step":
+        # every page live (the last step's spare blocks hold the last page
+        # again and count for nothing), six pages (n = 2 and n = 3: the end
+        # of a step), and a row whose FIRST pages are wholly masked
+        live = [WIDE, 6, 4]
+        table[0], table[1, :6], table[2, :4] = (
+            [0, 1, 2, 3, 4, 5, 6], [7, 8, 0, 2, 4, 6], [1, 3, 5, 7])
+        mask[0] = upto(WIDE * PAGE)
+        mask[0, 5] = 0
+        mask[1] = upto(6 * PAGE - 1)
+        mask[2] = upto(4 * PAGE - 5)
+        mask[2, :2 * PAGE + 1] = 0
+    else:
+        raise AssertionError(name)
+    return table, np.asarray(live, np.int32), mask
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["plain", "widened"])
+@pytest.mark.parametrize("n", [2, 3])
+@pytest.mark.parametrize("scenario", ["rows_end_inside_a_step",
+                                      "rows_end_with_a_step"])
+def test_one_update_a_step_over_its_pages_is_the_gather_and_the_product(
+        monkeypatch, scenario, n, kind, dtype):
+    """n > 1 pages under ONE running-softmax update, and only the steps that
+    hold a live page visited. `widened`: what `tests/test_window_moe.py`
+    holds for one step, over several: keys of 96 stored 128 wide with the
+    scale of 96, values of 64, a learned sink a head sharing the softmax."""
+    dk, dv = (96, 64) if kind == "widened" else (HD, HD)
+    g = 4
+    rng = np.random.default_rng(n)
+    k = rng.normal(size=(L, PAGES + 1, PAGE, KV_H, HD))
+    v = rng.normal(size=(L, PAGES + 1, PAGE, KV_H, dv))
+    q = rng.normal(size=(3, KV_H * g, HD))
+    k[..., dk:], q[..., dk:] = 0.0, 0.0
+    k[:, GARBAGE, ..., :dk], v[:, GARBAGE] = 3e4, -3e4
+    k, v, q = (jnp.asarray(a, dtype) for a in (k, v, q))
+    sink = (jnp.asarray(rng.normal(size=KV_H * g), jnp.float32)
+            if kind == "widened" else None)
+    table, live, mask = _stepped(scenario)
+    monkeypatch.setattr(paged_attention, "_STEP_BYTES",
+                        n * PAGE * KV_H * (HD + dv) * k.dtype.itemsize)
+    assert paged_attention.pages_per_step(k, v, WIDE) == n
+    out = paged_attention.paged_decode_attention(
+        q, k, v, jnp.int32(LAYER), jnp.asarray(table), jnp.asarray(live),
+        jnp.asarray(mask), sink, dk ** -0.5 if kind == "widened" else None)
+    gk, gv = (pool[LAYER, table].reshape(3, WIDE * PAGE, KV_H, -1)
+              for pool in (k, v))
+    # the gathered rows see what lies in the live pages
+    mask = mask * (np.arange(WIDE * PAGE)[None, :] < live[:, None] * PAGE)
+    ref = np.asarray(attention(q[:, None, :, :dk], gk[..., :dk], gv,
+                               jnp.asarray(mask), causal=False)[:, 0],
+                     np.float32)
+    if sink is not None:
+        scores = np.einsum(
+            "bhd,bshd->bhs", np.asarray(q, np.float32),
+            np.repeat(np.asarray(gk, np.float32), g, axis=2)) * dk ** -0.5
+        scores = np.where(mask[:, None, :] > 0, scores, -np.inf)
+        top = np.maximum(scores.max(-1), np.asarray(sink))
+        total = np.exp(scores - top[..., None]).sum(-1)
+        ref = ref * (total / (total + np.exp(np.asarray(sink) - top)))[..., None]
+    assert out.shape == ref.shape and out.dtype == dtype
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    for row in range(3):
+        if live[row] == 0:
+            assert not out[row].any()
+        else:
+            np.testing.assert_allclose(out[row], ref[row], **TOL[dtype])
+            assert np.abs(out[row]).max() < 10.0     # no garbage page in it
+
+
+def test_the_grid_visits_the_steps_that_hold_a_live_page_and_no_other():
+    """A slot's `cdiv(live, n)` steps in order, one for a slot with none;
+    the entries past the visits name a slot and a step that exist."""
+    slot_of, step_of, visits = paged_attention._visits(
+        jnp.asarray([5, 0, 2, 7], jnp.int32), n=3, steps=3)
+    assert int(visits) == 2 + 1 + 1 + 3
+    assert list(np.asarray(slot_of)[:7]) == [0, 0, 1, 2, 3, 3, 3]
+    assert list(np.asarray(step_of)[:7]) == [0, 1, 0, 0, 0, 1, 2]
+    assert slot_of.shape == step_of.shape == (4 * 3,)
+    assert (np.asarray(slot_of)[7:] == 3).all()
+    assert ((0 <= np.asarray(step_of)) & (np.asarray(step_of) < 3)).all()
+
+
 @pytest.mark.parametrize("step_bytes,n", [
     (0, 1), (3 * 2 * PAGE * KV_H * HD * 4, 3), (1 << 30, PMAX)])
 def test_pages_per_step_follow_the_shapes_and_do_not_move_the_result(

@@ -360,3 +360,52 @@ def test_the_tick_counts_the_pages_it_reads_and_the_pages_its_rows_have():
     assert [s["tokens"] for s in decode_spans] == [4, 4, 2]
     assert [s["kv_pages_live"] for s in decode_spans] == [12, 12, 6]
     assert [s["kv_pages_table"] for s in decode_spans] == [20, 20, 10]
+
+
+def test_the_tick_counts_the_grid_steps_its_attention_walks(monkeypatch):
+    """`kv_steps_visited` beside them: over the rows that decoded,
+    `cdiv(live pages, n)`, with n the pages the kernel takes under one
+    softmax update for THIS pool's shapes (asked of
+    `ops/paged_attention.pages_per_step`, three here). Bucket 16, page 8,
+    11 tokens: ten ticks of 2 rows write at 16..25, eight in the third page
+    (one step) and two in the fourth (two steps)."""
+    from llama_pipeline_parallel_tpu.ops import paged_attention
+    from llama_pipeline_parallel_tpu.serve import (
+        ServeConfig,
+        ServeEngine,
+        ServeRequest,
+    )
+
+    cfg = LlamaConfig.tiny()
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    page = 8
+    kv_bytes = 2 * page * cfg.num_key_value_heads * cfg.head_dim * 4
+    monkeypatch.setattr(paged_attention, "_STEP_BYTES", 3 * kv_bytes)
+    engine = ServeEngine(params, cfg, ServeConfig(
+        max_slots=2, max_len=40, prompt_buckets=(16,), page_size=page,
+        max_queue=8, decode_span_every=4))
+    pool = engine.slots.pool
+    n = paged_attention.pages_per_step(pool["k"], pool["v"], 40 // page)
+    assert n == 3
+    spans = []
+    listener = lambda rec: spans.append(dict(rec))
+    trace.recorder().add_listener(listener)
+    try:
+        for i in range(2):
+            engine.submit(ServeRequest(
+                input_ids=[5, 6, 7], seed=i,
+                gen=decode.GenerationConfig(max_new_tokens=11)))
+        engine.drain(timeout_s=120)
+        engine.shutdown()
+    finally:
+        trace.recorder().remove_listener(listener)
+    decode_spans = [s for s in spans if s["name"] == "serve_decode_step"]
+    assert all("kv_steps_visited" in s for s in decode_spans)
+    assert sum(s["ticks"] for s in decode_spans) == 10
+    # the host's own sum over the decoding rows' write positions
+    steps = 2 * sum(-(-(write_pos // page + 1) // n)
+                    for write_pos in range(16, 26))
+    assert steps == 2 * (8 * 1 + 2 * 2)
+    assert sum(s["kv_steps_visited"] for s in decode_spans) == steps
+    assert sum(s["kv_pages_live"] for s in decode_spans) == 2 * (8 * 3 + 2 * 4)
+    assert [s["kv_steps_visited"] for s in decode_spans] == [8, 8, 8]

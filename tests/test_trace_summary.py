@@ -130,7 +130,9 @@ def test_a_capture_with_no_device_operation_says_so(tmp_path):
 def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
                                                           capsys):
     """`ticks_ahead`, `rows_overrun` and `rows_joined_fed` come from
-    `spans.jsonl`, found beside or above the capture (or named), and the
+    `spans.jsonl`, found beside or above the capture (or named), the two
+    ratios of the tick's paged attention from the lines that carry
+    `kv_steps_visited`, and the
     prefill units' `ahead` and `reads` from its `serve_prefill` lines; lines
     of other spans and of a build that does not carry them are passed over,
     and a capture with no such file prints no such section."""
@@ -143,7 +145,10 @@ def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
              {"name": "serve_prefill", "ticks": 7},
              {"name": "serve_decode_step", "ticks": 5, "tokens": 80},
              {"name": "serve_decode_step", "ticks": 8, "ticks_ahead": 8,
-              "tokens": 100, "rows_overrun": 2, "rows_joined_fed": 3},
+              "tokens": 100, "rows_overrun": 2, "rows_joined_fed": 3,
+              # rows of 536 pages walked 5 a step: 108 steps a whole table
+              "kv_pages_live": 9800, "kv_pages_table": 53600,
+              "kv_steps_visited": 2000, "kv_pages_per_step": 5},
              {"name": "serve_prefill", "ahead": 1, "reads": 1},
              {"name": "serve_prefill", "ahead": 1, "reads": 0},
              {"name": "serve_prefill", "ahead": 0, "reads": 1},
@@ -157,11 +162,15 @@ def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
         "rows_joined_fed": 3}
     assert trace_summary.unit_pipeline(str(spans)) == {
         "units": 3, "ahead": 2, "reads": 2}
+    assert trace_summary.kv_steps(str(spans)) == {
+        "pages_live": 9800, "steps_visited": 2000, "steps_table": 10800}
     trace_summary.main([capture])
     out = capsys.readouterr().out
     assert "ticks_ahead 39 of 40 ticks (97.50%)" in out
     assert "rows_overrun 2 of 600 row-ticks" in out
     assert "rows_joined_fed 3" in out
+    assert "kv_pages_live / kv_steps_visited 4.90 pages a step" in out
+    assert "kv_steps_visited 2000 of 10800 steps" in out and "(0.185)" in out
     assert "units ahead 2 of 3 (66.67%)" in out
     assert "reads 2 (0.67 a unit)" in out
     elsewhere = tmp_path / "elsewhere.jsonl"

@@ -27,7 +27,12 @@ ones the per-layer metrics report:
   (row-ticks run and discarded: an eos is seen one tick late) and
   `rows_joined_fed` (rows that joined a tick with their first token and key
   fed from a prefill unit on the device), summed over the file's
-  `serve_decode_step` lines; and how often the prefill unit in flight did:
+  `serve_decode_step` lines; from the same lines, what the tick's paged
+  attention walked: `kv_pages_live / kv_steps_visited` (pages under one
+  running-softmax update) and `kv_steps_visited` over the steps the rows'
+  whole page tables have at `kv_pages_per_step` pages a step (the share of
+  a whole-row walk the kernel's grid still makes);
+  and how often the prefill unit in flight did:
   units whose result was read after the next hand-over was enqueued
   (`ahead`) of all `serve_prefill` lines, and the round trips they made
   (`reads`).
@@ -78,6 +83,8 @@ PIPELINE = ("ticks", "ticks_ahead", "tokens", "rows_overrun")
 # on the same lines since the prefill unit in flight; and on `serve_prefill`
 JOINED = "rows_joined_fed"
 UNITS = ("ahead", "reads")
+KV_STEPS = ("tokens", "kv_pages_live", "kv_pages_table", "kv_steps_visited",
+            "kv_pages_per_step")
 
 
 def find_spans(trace_dir: str):
@@ -108,6 +115,25 @@ def tick_pipeline(spans_path: str):
     if not rows:
         return None
     return {k: sum(r.get(k, 0) for r in rows) for k in PIPELINE + (JOINED,)}
+
+
+def kv_steps(spans_path: str):
+    """{"pages_live", "steps_visited", "steps_table"} over the
+    `serve_decode_step` lines that carry `KV_STEPS`: the decoding rows' live
+    pages, the grid steps the tick's attention walked for them, and the
+    steps their whole page-table rows hold (a row's `kv_pages_table /
+    tokens` pages at `kv_pages_per_step` a step); None where the file holds
+    no such line with a decoding row (each has a live page, so no sum is 0)."""
+    rows = [r for r in _span_lines(spans_path, "serve_decode_step", KV_STEPS)
+            if r["tokens"]]
+    if not rows:
+        return None
+    return {
+        "pages_live": sum(r["kv_pages_live"] for r in rows),
+        "steps_visited": sum(r["kv_steps_visited"] for r in rows),
+        "steps_table": sum(
+            r["tokens"] * -(-(r["kv_pages_table"] // r["tokens"])
+                            // r["kv_pages_per_step"]) for r in rows)}
 
 
 def unit_pipeline(spans_path: str):
@@ -175,6 +201,14 @@ def main(argv: list[str] | None = None) -> None:
               f"\n  rows_overrun {pipeline['rows_overrun']} of "
               f"{pipeline['tokens']} row-ticks"
               f"\n  rows_joined_fed {pipeline[JOINED]}")
+    walked = kv_steps(spans_path) if spans_path else None
+    if walked is not None:
+        print(f"\n== the tick's paged attention ==\n"
+              f"  kv_pages_live / kv_steps_visited "
+              f"{walked['pages_live'] / walked['steps_visited']:.2f} "
+              f"pages a step\n  kv_steps_visited {walked['steps_visited']} of "
+              f"{walked['steps_table']} steps of the rows' whole tables "
+              f"({walked['steps_visited'] / walked['steps_table']:.3f})")
     units = unit_pipeline(spans_path) if spans_path else None
     if units is not None:
         print(f"\n== the engine's prefill unit in flight ==\n"
