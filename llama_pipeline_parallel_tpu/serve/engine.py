@@ -45,6 +45,15 @@ is seen one tick late and overruns once, like any other eos. A request's
 first token reaches its handle one hand-over later than the device made it,
 never later: no unit is in flight across a step boundary.
 
+The engine's thread accounts for its own seconds (`_HostThread`): it waits
+for the device in two places and nowhere else, `serve_tick_block` and a
+unit's deferred read, and everything else is the host working. The pending
+`serve_decode_step` span carries the partition of the steps' wall
+(`HOST_SUMS`) and what held the thread outside those waits and inside them
+(the collector, the compiler: `utils/trace.HostWatch`), and a phase of host
+work of `STALL_S` or more is a `serve_host_stall` record that names its
+phase and its causes (docs/OBSERVABILITY.md "The engine's thread").
+
 Token parity contract: a request served here emits EXACTLY the tokens of an
 independent `generate(params, padded_prompt, cfg, gen,
 rng=PRNGKey(request.seed))` call (prompt left-padded to the same bucket) —
@@ -99,6 +108,42 @@ _REQUEST_IDS = itertools.count()
 # outputs)
 TICK_SUMS = ("stage_s", "dispatch_s", "wait_s", "emit_s",
              "h2d_s", "enqueue_s")
+
+# seconds and counts of the engine's own THREAD, summed over a
+# `serve_decode_step` span's steps and flushed with `TICK_SUMS`
+# (`_HostThread`; docs/OBSERVABILITY.md "The engine's thread"). The thread
+# waits for the device in two places, `serve_tick_block` and
+# `serve_prefill_first`; everything else is the host working. First the
+# seconds the tick's phases lacked: over a run `admit_s + stage_s + dispatch_s
+# + wait_s + unit_wait_s + emit_s + loop_s` is `step_s` (`loop_s`: the thread
+# under no annotation of the engine: from a step that did work to the start of
+# the next, and inside a step the folding of a tick into the span and what
+# follows the last phase; an idle wait is not the loop's time), and `block_s`
+# is the `serve_tick_block` part of `wait_s`. Then what held the thread
+# (`utils/trace.HostWatch`): the collector OUTSIDE the two waits (`gc_s`) and
+# inside them (`wait_gc_s`), the compiler over the steps.
+HOST_SUMS = ("admit_s", "unit_wait_s", "block_s", "loop_s", "step_s",
+             "gc_s", "compile_s", "wait_gc_s")
+HOST_COUNTS = ("steps", "gc_collections", "gc_gen2", "compiles",
+               "ticks_found_ready")
+# one phase of host work this long, or a device wait in which other threads'
+# collections ran for this long, is a stall record (`serve_host_stall`): each
+# such phase costs 0.3 to 3 ms in the benchmark's cells, a unit's hand-over
+# about 3
+STALL_S = 0.020
+# a `block_until_ready` that returns within this found its tick READY: the
+# device had finished it before the host came for it. Counted
+# (`ticks_found_ready`) where no prefill unit was enqueued behind the tick:
+# then the device had nothing but the next tick to go on with, which the host
+# had enqueued a moment before, so the host set the pace. Behind a unit the
+# device was busy with the unit and the host was merely late. A block on a
+# ready array costs 1.4 us on the v5e's host, 2.9 at its 99th percentile and
+# 39 at the worst of 20,000 (PERF.md section 6, PR 50)
+READY_S = 50e-6
+# stall records carried on one `serve_decode_step` span; the rest are counted
+MAX_STALLS = 16
+# the phase of a stall record under no annotation of the engine
+LOOP = "loop"
 
 
 class ServeOverloaded(RuntimeError):
@@ -391,6 +436,227 @@ class _Unit:
     cost: int
 
 
+class _HostThread:
+    """The engine thread's account of its own seconds: `HOST_SUMS` and
+    `HOST_COUNTS` of the pending `serve_decode_step` span, and the stall
+    records.
+
+    The thread's time is a chain of STRETCHES of host work, each ended by a
+    device wait (`enter_wait` .. `leave_wait`) or by an idle boundary. The
+    collector's seconds (`utils/trace.HostWatch.gc_s`) are read on either
+    side of a wait: what they grew by over it is `wait_gc_s` (other
+    threads' collections: this one slept) and is kept OUT of `gc_s`, which
+    `flush` takes, like the watch's other totals, as the growth since
+    `_base`, their values at the last flush moved forward by what the waits
+    and the idle time saw. A stretch's phases are appended to `phases` as
+    (innermost annotation, `perf_counter` at its end) by the code that reads
+    the clock there anyway; a stretch shorter than `STALL_S` (one
+    comparison, at the wait that ends it) is dropped unread, a longer one is
+    searched for a phase that long, which makes a record with what the
+    watch's kept events say held THAT phase (`HostWatch.held`)."""
+
+    def __init__(self, context):
+        self._context = context         # () -> {"step", "active", "units"}
+        self.watch = trace.host_watch()
+        self.phases: list = []
+        self.stalls: list = []
+        self.stalls_dropped = 0
+        # running totals, for the metrics line
+        self.host_stalls = 0
+        self.host_stall_s = 0.0
+        self.ticks_found_ready = 0
+        # None outside admission; inside it the annotation over the thread:
+        # `LOOP` while abandoned requests are cancelled, then `serve_admit`
+        self.admitting: str | None = None
+        # where the step's last named phase ended: from there to the next
+        # one's start, or the step's end, the thread is under no annotation,
+        # which is `loop` too (`unnamed_until`)
+        self.tail = 0.0
+        # seconds other sums hold (a collected tick's wait and emit, a unit's
+        # deferred read): what `admit_s` leaves out of its interval
+        self.elsewhere = 0.0
+        self._elsewhere0 = 0.0
+        self._zero()
+        self._mark: float | None = None     # end of the last step that worked
+        self._t_step: float | None = None   # where the step's wall is counted from
+        self._stretch_t0 = float("inf")     # no stretch yet: none too long
+        # the watch's totals at the last flush, moved past the idle time
+        # (and `gc_s` past the waits); and as they stood when the thread
+        # last went idle
+        self._base = list(self._totals())
+        self._idle = self._totals()
+
+    def _zero(self) -> None:
+        self.admit_s = self.unit_wait_s = self.block_s = 0.0
+        self.loop_s = self.step_s = self.wait_gc_s = 0.0
+        self.steps = self.found_ready = 0
+
+    def _totals(self) -> tuple:
+        w = self.watch
+        return w.gc_s, w.compile_s, w.gc_collections, w.gc_gen2, w.compiles
+
+    # -- a step ------------------------------------------------------------
+
+    def begin(self) -> None:
+        """A step begins, with the cancellations: admission's seconds, under
+        no annotation. After a step that did work the stretch goes on (the
+        time since is `loop`); after an idle wait a new one begins here, and
+        what the watch counted meanwhile is not the steps'."""
+        now = time.perf_counter()
+        if self._mark is None:
+            self._base = [base + now_ - then for base, now_, then in zip(
+                self._base, self._totals(), self._idle)]
+            self._stretch_t0 = now
+            self.phases.clear()
+        else:
+            self.loop_s += now - self._mark
+            self.step_s += now - self._mark
+            self.phases.append((LOOP, now))
+        self._t_step = now
+        self._elsewhere0 = self.elsewhere
+        self.admitting = LOOP
+
+    def annotated(self) -> None:
+        """`serve_admit` begins: the cancellations are done."""
+        self.phases.append((LOOP, time.perf_counter()))
+        self.admitting = trace.SERVE_ADMIT
+
+    def admitted(self) -> None:
+        """`serve_admit` ended: the wall since the step began less what a
+        collection inside it (a cancellation's, a burst's deferred reads)
+        already gave other sums."""
+        now = time.perf_counter()
+        self.admit_s += (now - self._t_step) - (self.elsewhere
+                                                - self._elsewhere0)
+        self.phases.append((self.admitting, now))
+        self.admitting = None
+        self.tail = now
+
+    def unnamed_until(self, now: float) -> None:
+        """A named phase begins at `now`: the step's time since the last one
+        ended was under no annotation (inside admission everything is
+        admission's; outside a step nothing is the steps')."""
+        if self.admitting is None and self._t_step is not None:
+            self.loop_s += now - self.tail
+
+    def end(self, worked: bool) -> None:
+        """The step ends. `worked`: the time to the next step's start is
+        `loop`; else the thread goes idle and the stretch ends here."""
+        now = time.perf_counter()
+        self.step_s += now - self._t_step
+        self.loop_s += now - self.tail
+        self._t_step = None
+        if worked:
+            self.steps += 1
+            self._mark = now
+            return
+        self._mark = None
+        self.phases.append((LOOP, now))
+        self._idle = self._totals()
+        self._end_stretch(now)
+
+    # -- a device wait -------------------------------------------------------
+
+    def enter_wait(self, now: float) -> float:
+        """The stretch ends at a device wait (`now`: the clock where the
+        wait's code began; the time since the last boundary is under the
+        annotation it was under before). Returns the collector's seconds, to
+        hand `leave_wait`."""
+        self.phases.append((self.admitting or LOOP, now))
+        self._end_stretch(now)
+        return self.watch.gc_s
+
+    def leave_wait(self, phase: str, before: float, t_wait: float) -> float:
+        """The wait under `phase` returned; a new stretch begins. What the
+        collector's seconds grew by over the wait is the wait's (another
+        thread's collections: this one slept, and could not come back from
+        the wait until the collecting thread let the interpreter lock go),
+        and `STALL_S` of it is a record that says `in_wait`: the wait's own
+        length is the device's work and proves nothing. Returns the clock at
+        the wait's end."""
+        now = time.perf_counter()
+        gc_s = self.watch.gc_s - before
+        self.wait_gc_s += gc_s
+        self._base[0] += gc_s
+        if gc_s >= STALL_S:
+            held = min(now - t_wait, gc_s)
+            self._record(phase, now - held, now, {
+                "in_wait": 1, "wait_gc_s": gc_s, "other_s": 0.0})
+        self._stretch_t0 = now
+        return now
+
+    def tick_blocked(self, t_entry: float, t_block: float, t_blocked: float,
+                     behind: bool) -> None:
+        """`serve_tick_block` ran from `t_block` to `t_blocked`, in a wait
+        that began at `t_entry`; `behind`: a prefill unit is enqueued behind
+        the tick, so the device had work whenever the tick ended."""
+        self.block_s += t_blocked - t_entry
+        if t_blocked - t_block < READY_S and not behind:
+            self.found_ready += 1
+            self.ticks_found_ready += 1
+
+    # -- stalls --------------------------------------------------------------
+
+    def _end_stretch(self, now: float) -> None:
+        if now - self._stretch_t0 >= STALL_S:
+            start = self._stretch_t0
+            for phase, end in self.phases:
+                if end - start >= STALL_S:
+                    self._record(phase, start, end,
+                                 self.watch.held(start, end))
+                start = end
+        self.phases.clear()
+
+    def _record(self, phase: str, start: float, end: float,
+                fields: dict) -> None:
+        """One stall record: a `serve_host_stall` line and a warning now, and
+        a place on the pending `serve_decode_step` span (`start`, `end` on
+        `perf_counter`; `ts` is the wall clock at its start)."""
+        ts = time.time() - (time.perf_counter() - start)
+        rec = {"phase": phase, "ts": ts, "dur": end - start, **fields,
+               **self._context()}
+        self.host_stalls += 1
+        self.host_stall_s += rec["dur"]
+        if len(self.stalls) < MAX_STALLS:
+            self.stalls.append(rec)
+        else:
+            self.stalls_dropped += 1
+        trace.recorder().emit("serve_host_stall", **rec)
+        logger.warning(
+            "host stall: %.1f ms under %s at step %d%s: %s",
+            1e3 * rec["dur"], phase, rec["step"],
+            " (in a device wait)" if rec.get("in_wait") else "",
+            ", ".join(f"{k}={v:.4g}" for k, v in rec.items()
+                      if k not in ("phase", "ts", "dur", "step", "in_wait")))
+
+    # -- the span ------------------------------------------------------------
+
+    def flush(self) -> dict:
+        """`HOST_SUMS`, `HOST_COUNTS`, `gc_longest_s` (the process's longest
+        pause so far: not a sum), `stalls` and `stalls_dropped` since the
+        last flush; then zeroed."""
+        now = time.perf_counter()
+        if self._t_step is not None:    # inside a step: its wall so far
+            self.step_s += now - self._t_step
+            self._t_step = now
+        totals = self._totals()
+        gc_s, compile_s, collections, gen2, compiles = (
+            max(now_ - base, 0) for now_, base in zip(totals, self._base))
+        self._base = list(totals)
+        out = {
+            "admit_s": self.admit_s, "unit_wait_s": self.unit_wait_s,
+            "block_s": self.block_s, "loop_s": self.loop_s,
+            "step_s": self.step_s, "gc_s": gc_s, "compile_s": compile_s,
+            "wait_gc_s": self.wait_gc_s, "steps": self.steps,
+            "gc_collections": collections, "gc_gen2": gen2,
+            "compiles": compiles, "ticks_found_ready": self.found_ready,
+            "gc_longest_s": self.watch.gc_longest_s, "stalls": self.stalls,
+            "stalls_dropped": self.stalls_dropped}
+        self._zero()
+        self.stalls, self.stalls_dropped = [], 0
+        return out
+
+
 class ServeEngine:
     def __init__(self, params: dict, cfg, serve_cfg: ServeConfig,
                  metrics_writer=None, profiler=None,
@@ -501,6 +767,10 @@ class ServeEngine:
         # sums of the family's tick counters over the pending span (empty
         # for a family that returns none)
         self._tick_counters = dict.fromkeys(self._family.counters, 0)
+        # the thread's own seconds and what held it, folded into the same span
+        self._host = _HostThread(lambda: {
+            "step": self.steps, "active": len(self._occupants),
+            "units": int(self._unit is not None)})
 
     def _pages_per_kernel_step(self) -> int:
         """Pages the tick's attention takes under one grid step, asked of the
@@ -721,26 +991,33 @@ class ServeEngine:
         one decode tick over all slots, then collect the tick enqueued at the
         boundary before and the step's last prefill unit. Returns False when
         there was nothing to do (caller may sleep)."""
+        host = self._host
+        host.begin()
         self._cancel_abandoned()
         # admission and prefill chunks: the loop's host work outside the
         # decode tick, one profiler event a step (`serve_prefill` nests in it)
         with trace.annotate(trace.SERVE_ADMIT):
+            host.annotated()
             self._advance_prefill()
+        host.admitted()
         if not self._occupants:
             # rows that overran their eos may be all a tick in flight holds;
             # a step that only prefills reads its unit here
             self._collect()
             if self._prefilling:      # prefill-only tick is still work
                 self._tick_done()
+                host.end(worked=True)
                 return True
             self._flush_decode_span()  # idle boundary: publish the tail
             self._work.clear()
             # submit() may have raced the clear — don't sleep on a full queue
             if self.queue_depth():
                 self._work.set()
+            host.end(worked=False)     # an idle wait is not the loop's time
             return False
         self._decode_tick()
         self._tick_done()
+        host.end(worked=True)
         return True
 
     def _tick_done(self) -> None:
@@ -962,6 +1239,7 @@ class ServeEngine:
                            else self._family.paged_prefill_chunk)
                 c0, c1 = pf.done, pf.done + cost
                 self.slots.ensure_capacity(slot, c1)
+                t_call = time.perf_counter()
                 with trace.annotate(trace.PREFILL_ENQUEUE):
                     out = program(
                         self.params, jnp.asarray(pf.ids[:, c0:c1]),
@@ -970,16 +1248,19 @@ class ServeEngine:
                         jnp.asarray(self.slots.page_table[slot]),
                         jnp.int32(slot), self.slots.kv_mask, jnp.int32(c0),
                         self.cfg)
+                t_called = time.perf_counter()
                 self.slots.pool = out["pool"]
                 self.slots.kv_mask = out["kv_mask"]
                 pf.done = c1
             else:
                 # single shot: a row the bucket long, which write_pages
                 # pages
+                t_call = time.perf_counter()
                 with trace.annotate(trace.PREFILL_ENQUEUE):
                     out = self._family.prefill_prompt(
                         self.params, jnp.asarray(pf.ids),
                         jnp.asarray(pf.mask), self.cfg, pf.bucket)
+                t_called = time.perf_counter()
                 self.slots.admit(slot, out)
                 pf.done = pf.bucket
             vector = out.get("counters")
@@ -1006,9 +1287,12 @@ class ServeEngine:
                     emitted=0, t_admit=pf.t_admit, t_first=0.0,
                     first_unread=True)
                 self._occupants[slot] = row
+        t_handed = time.perf_counter()
+        self._host.phases += (
+            (trace.SERVE_ADMIT, t0), ("serve_prefill", t_call),
+            (trace.PREFILL_ENQUEUE, t_called), ("serve_prefill", t_handed))
         return _Unit(pf=pf, vector=vector, row=row, ts=ts, t0=t0,
-                     handover_s=time.perf_counter() - t0, offset=offset0,
-                     cost=cost)
+                     handover_s=t_handed - t0, offset=offset0, cost=cost)
 
     def _prev(self) -> jax.Array:
         """What the next tick takes as the tick before's vector."""
@@ -1025,22 +1309,45 @@ class ServeEngine:
         read, which tile with the tick's; `ahead`; `reads`); a final unit's
         first token is pushed, `t_first` stamped and the request trace's
         `first_token` written here, one hand-over after the device made it.
-        A read that raises fails the unit's own request and nobody else's."""
+        A read that raises fails the unit's own request and nobody else's.
+        The read is one of the thread's two device waits (`unit_wait_s`);
+        what follows it on the host is admission's (`serve_prefill_result`,
+        in `admit_s` wherever in the step it falls)."""
         unit, self._unit = self._unit, None
         if unit is None:
             return
-        pf, row = unit.pf, unit.row
-        t0 = time.perf_counter()
-        fetched = None
-        try:
-            if unit.vector is not None:
-                # the one place admission waits for the device
+        host = self._host
+        t0 = t_read = time.perf_counter()
+        host.unnamed_until(t0)
+        fetched = error = None
+        if unit.vector is not None:
+            # the one place admission waits for the device
+            before = host.enter_wait(t0)
+            try:
                 with trace.annotate(trace.PREFILL_FIRST):
                     fetched = np.asarray(unit.vector)
-        except Exception as e:
-            self._fail_prefill(pf, e)
-            return
-        t_read = time.perf_counter()
+            except Exception as e:
+                error = e
+            t_read = host.leave_wait(trace.PREFILL_FIRST, before, t0)
+            host.unit_wait_s += t_read - t0
+            host.elsewhere += t_read - t0
+        with trace.annotate(trace.PREFILL_RESULT):
+            if error is not None:
+                self._fail_prefill(unit.pf, error)
+            else:
+                self._unit_result(unit, fetched, ahead, t0, t_read)
+        t_done = time.perf_counter()
+        host.phases.append((trace.PREFILL_RESULT, t_done))
+        if host.admitting is None:
+            host.admit_s += t_done - t_read
+            host.tail = t_done
+
+    def _unit_result(self, unit: _Unit, fetched, ahead: bool, t0: float,
+                     t_read: float) -> None:
+        """What the host does with a unit's result once it is read (from
+        `t0` to `t_read`; or has nothing to read): the span, the anchor, the
+        first token."""
+        pf, row = unit.pf, unit.row
         counters = fetched
         if row is not None:
             token, chain, counters = tick_io.split_first(fetched)
@@ -1136,6 +1443,7 @@ class ServeEngine:
                 < r.request.gen.max_new_tokens]
         if not rows:
             return None
+        self._host.unnamed_until(t_entry)
         with trace.annotate(trace.TICK_STAGE):
             # fresh every tick: nothing writes a buffer the device was given
             staged = tick_io.stage(scfg.max_slots,
@@ -1198,6 +1506,11 @@ class ServeEngine:
                 r.write_pos += 1
                 r.in_flight += 1
         t_dispatched = time.perf_counter()
+        self._host.phases += (
+            (trace.TICK_STAGE, t0), (trace.TICK_GROW, t_grown),
+            (trace.TICK_H2D, t_copied), (trace.TICK_ENQUEUE, t_enqueued),
+            (trace.TICK_DISPATCH, t_dispatched))
+        self._host.tail = t_dispatched
         return _Tick(
             fetch=out["fetch"], rows=rows, ts=t_wall,
             ahead=before is not None,
@@ -1219,13 +1532,22 @@ class ServeEngine:
         annotation; the four phases, `h2d` and `enqueue` are also sums on the
         span (`TICK_SUMS`), whose `dur` stays dispatch + wait. One clock read
         a boundary: no phase is timed twice."""
+        host = self._host
         t_entry = time.perf_counter()
+        host.unnamed_until(t_entry)
         with trace.annotate(trace.TICK_WAIT):
+            # the thread's other device wait: the collector's seconds are
+            # read on either side of it, outside `block`'s own event
+            before = host.enter_wait(t_entry)
+            t_block = time.perf_counter()
             # block, then convert: the device's gap while the host sleeps
             # belongs to `block` (launch before the program's first
             # operation, wake after its last), not to the conversion
             with trace.annotate(trace.TICK_BLOCK):
                 jax.block_until_ready(tick.fetch)
+            t_blocked = host.leave_wait(trace.TICK_BLOCK, before, t_entry)
+            host.tick_blocked(t_entry, t_block, t_blocked,
+                              behind=self._unit is not None)
             with trace.annotate(trace.TICK_FETCH):
                 next_token, new_keys, counters = tick_io.split_result(
                     np.asarray(tick.fetch), self.serve_cfg.max_slots)
@@ -1254,10 +1576,14 @@ class ServeEngine:
                 if (gen.eos_token_id is not None and tok == gen.eos_token_id) \
                         or r.emitted >= gen.max_new_tokens:
                     self._finish(slot, r)
+        t_emitted = time.perf_counter()
+        host.phases += ((trace.TICK_FETCH, t_fetched),
+                        (trace.TICK_EMIT, t_emitted))
+        host.elsewhere += t_emitted - t_entry
         self._note_decode_tick(
             tick, counters.tolist(), overrun, d2h_copies,
-            wait_s=t_fetched - t_entry,
-            emit_s=time.perf_counter() - t_fetched)
+            wait_s=t_fetched - t_entry, emit_s=t_emitted - t_fetched)
+        host.tail = t_emitted   # folding it into the span is under no event
 
     def _note_decode_tick(self, tick: _Tick, counters: list, overrun: int,
                           d2h_copies: int, wait_s: float,
@@ -1324,7 +1650,7 @@ class ServeEngine:
                               h2d_copies=self._tick_copies[0],
                               d2h_copies=self._tick_copies[1],
                               **dict(zip(TICK_SUMS, self._tick_sums)),
-                              **self._tick_counters)
+                              **self._tick_counters, **self._host.flush())
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
         self._tick_overrun, self._tick_ahead = 0, 0
@@ -1414,6 +1740,15 @@ class ServeEngine:
         snap["queue_depth"] = self.queue_depth()
         snap["slot_allocations"] = self.slots.allocations
         snap["decode_steps"] = self.steps
+        # what held the engine's thread, as running totals: stall records
+        # and their seconds, the process's collections and programs compiled
+        # (`utils/trace.HostWatch`), ticks the host found ready
+        host = self._host
+        snap["host_stalls"] = host.host_stalls
+        snap["host_stall_s"] = round(host.host_stall_s, 6)
+        snap["gc_s"] = round(host.watch.gc_s, 6)
+        snap["compiles"] = host.watch.compiles
+        snap["ticks_found_ready"] = host.ticks_found_ready
         if self._degraded is not None:
             snap["degraded"] = self._degraded
         scfg = self.serve_cfg
@@ -1463,6 +1798,8 @@ class ServeEngine:
         engine. The decode tick and the prefill unit in flight are collected
         first: their tokens reach their handles (and may finish them) before
         the rest fail."""
+        self._host.begin()      # its waits are the thread's, like a step's
+        self._host.admitted()
         try:
             self._collect()
         except Exception:
@@ -1470,6 +1807,7 @@ class ServeEngine:
             # tick and the unit in flight cannot be read either
             logger.exception("what was in flight at shutdown is lost")
         self._flush_decode_span()
+        self._host.end(worked=False)
         if self._profiler is not None:
             self._profiler.close()  # finalize an open capture window
         err = EngineShutdown("serve engine shut down")

@@ -31,6 +31,14 @@ line (the serving tick's four and the parts nested in them) go through
 `annotate`, which only mirrors into the profiler; `wallclock_anchor` puts the
 host's wall clock on the profiler's clock, so a capture joins `spans.jsonl`.
 
+What can HOLD a Python thread has one process-wide watch (`HostWatch`,
+`host_watch()`): the collector's pauses (`gc.callbacks`) and the compiler's
+seconds (`jax.monitoring`'s duration events), the newest of both kept with
+their place in time to set against a stretch of a thread's time found too
+long. The serving engine reads the totals on either side of its two device
+waits, so a stall names its cause (`serve_host_stall`,
+docs/OBSERVABILITY.md).
+
 The module-level recorder is a process-global configured once per run
 (`configure(output_dir)`); instrumentation sites (`train._train_loop`,
 `data.loader.PrefetchIterator`, `ckpt.checkpoint.CheckpointManager`) call
@@ -41,10 +49,12 @@ annotate, they just aren't persisted.
 from __future__ import annotations
 
 import functools
+import gc
 import json
 import os
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Iterator
 
@@ -231,6 +241,11 @@ PREFILL_ENQUEUE = "serve_prefill_enqueue"  # in `serve_prefill`: a unit's call
 # round a unit's deferred read (counters; first token and chain), a hand-over
 # after its `serve_prefill` (the hand-over alone) ended: not nested in it
 PREFILL_FIRST = "serve_prefill_first"
+# round what follows that read on the host: the unit's span line, its anchor,
+# the first token's push (a finish, if it was the eos)
+PREFILL_RESULT = "serve_prefill_result"
+# a collection of generation 1 or 2, on the thread that ran it: `py_gc gen=<g>`
+GC_PREFIX = "py_gc gen="
 # an empty annotation NAMED `wallclock_us=<time.time() in microseconds>`
 WALLCLOCK_PREFIX = "wallclock_us="
 
@@ -390,6 +405,157 @@ def wallclock_anchor() -> None:
     trace runs."""
     with annotate(f"{WALLCLOCK_PREFIX}{int(time.time() * 1e6)}"):
         pass
+
+
+# -- what can hold a Python thread -------------------------------------------
+
+# `jax.monitoring`'s duration events the watch listens to. One COMPILE_EVENT a
+# program compiled OR fetched from the persistent cache (the fetch's own event
+# lies inside it: a line of its own, its seconds not counted twice); tracing
+# and lowering come in front of it and are seconds of the same stall.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_FETCH_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_SECONDS_EVENTS = (COMPILE_EVENT,
+                          "/jax/core/compile/jaxpr_trace_duration",
+                          "/jax/core/compile/jaxpr_to_mlir_module_duration")
+
+# collections and compiler events kept to place against a stall after the
+# fact (`HostWatch.held`). A window's collections are a handful; a program's
+# tracing fires an event for every jitted helper inside it, hundreds to
+# thousands of a few microseconds each, which would push a warm-up's real
+# events out before the stretch they lie in is looked at: a compiler event
+# shorter than `KEPT_EVENT_S` is seconds only (of a tiny engine's 764 events
+# the 35 of a millisecond or more hold 96% of the seconds)
+RECENT_EVENTS = 4096
+KEPT_EVENT_S = 1e-3
+
+
+class HostWatch:
+    """Process-wide totals of what can hold a Python thread, whichever
+    thread it is.
+
+    - The collector: a `gc.callbacks` hook. A collection of any generation
+      adds its seconds to `gc_s` and counts in `gc_collections` (`gc_gen2`:
+      the full ones; `gc_longest_s`: the longest pause so far); one of
+      generation 1 or 2 also runs inside a `py_gc gen=<g>` profiler
+      annotation, so a capture shows the pause on the thread that ran it.
+      Collections of one interpreter never overlap, and the thread that
+      collects holds the interpreter lock: every other Python thread stands
+      still for those seconds.
+    - The compiler: `jax.monitoring`'s duration events. `compile_s` sums
+      tracing, lowering and compiling (or fetching from the persistent
+      cache; tracing nested in tracing is counted at each level, so the
+      sum can pass the wall time it took), `compiles` counts the programs;
+      a program compiled, and one fetched, is also a retroactive
+      `jit_compile` line of `spans.jsonl` (`event`, `dur`).
+
+    The newest `RECENT_EVENTS` of both (every collection; a compiler event
+    of `KEPT_EVENT_S` or more) are kept with their place on `perf_counter`,
+    so that a stretch of a thread's time found too long can be set against
+    them afterwards (`held`), at no cost while none is.
+
+    One a process (`host_watch()`), installed by the first serving engine and
+    never removed; the totals only grow, and a reader takes differences."""
+
+    def __init__(self):
+        self.gc_s = 0.0
+        self.gc_collections = 0
+        self.gc_gen2 = 0
+        self.gc_longest_s = 0.0
+        self.compile_s = 0.0
+        self.compiles = 0
+        # (is the compiler's, start, end): appended by whichever thread
+        self._recent: deque = deque(maxlen=RECENT_EVENTS)
+        self._gc_t0: float | None = None
+        self._gc_annotation = None
+        self._annotation_class = None
+        self._installed = False
+        self._install_lock = threading.Lock()
+
+    def install(self) -> "HostWatch":
+        """Hook the collector and the compiler, once however often called."""
+        with self._install_lock:
+            if self._installed:
+                return self
+            self._installed = True
+            # resolved here and kept: a collection must not import anything
+            self._annotation_class = _annotation_class()
+            gc.callbacks.append(self._on_gc)
+            try:
+                import jax.monitoring as monitoring
+            except Exception:       # offline: the collector alone is watched
+                return self
+            monitoring.register_event_duration_secs_listener(self._on_duration)
+        return self
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if info["generation"] and self._annotation_class is not None:
+                self._gc_annotation = self._annotation_class(
+                    f"{GC_PREFIX}{info['generation']}")
+                self._gc_annotation.__enter__()
+            self._gc_t0 = time.perf_counter()
+            return
+        if self._gc_t0 is None:     # hooked while a collection ran
+            return
+        t0, now = self._gc_t0, time.perf_counter()
+        self._gc_t0 = None
+        if self._gc_annotation is not None:
+            self._gc_annotation.__exit__(None, None, None)
+            self._gc_annotation = None
+        self._recent.append((False, t0, now))
+        self.gc_s += now - t0
+        self.gc_collections += 1
+        self.gc_gen2 += info["generation"] == 2
+        self.gc_longest_s = max(self.gc_longest_s, now - t0)
+
+    def _on_duration(self, event: str, duration: float, **_) -> None:
+        if event in COMPILE_SECONDS_EVENTS:
+            if duration >= KEPT_EVENT_S:
+                now = time.perf_counter()
+                self._recent.append((True, now - duration, now))
+            self.compile_s += duration
+            self.compiles += event == COMPILE_EVENT
+        if event in (COMPILE_EVENT, CACHE_FETCH_EVENT):
+            # a line a program (and one more where it came from the cache);
+            # tracing and lowering fire for every jitted helper and are
+            # seconds only
+            _RECORDER.emit("jit_compile", ts=time.time() - duration,
+                           dur=duration, event=event)
+
+    def held(self, start: float, end: float) -> dict:
+        """What of `[start, end)` on `perf_counter` the collector and the
+        compiler held, from the events kept: `gc_s`, `compile_s` (each the
+        length of its events' union inside the interval: a program's nested
+        tracing is counted once) and `other_s`, what neither covers: never
+        negative, and the three cover the interval."""
+        inside = [(compiler, max(s, start), min(e, end))
+                  for compiler, s, e in tuple(self._recent)
+                  if e > start and s < end]
+        covered = lambda events: sum(
+            e - s for s, e in _merge((s, e) for _, s, e in events))
+        return {"gc_s": covered(ev for ev in inside if not ev[0]),
+                "compile_s": covered(ev for ev in inside if ev[0]),
+                "other_s": max(end - start - covered(inside), 0.0)}
+
+
+def _merge(intervals) -> list:
+    """The union of `intervals` as disjoint (start, end), in order."""
+    out: list = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+_WATCH = HostWatch()
+
+
+def host_watch() -> HostWatch:
+    """The process's one watch, installed on first use."""
+    return _WATCH.install()
 
 
 # -- goodput accounting ------------------------------------------------------

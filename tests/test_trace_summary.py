@@ -177,3 +177,67 @@ def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
     elsewhere.write_text(json.dumps(lines[1]) + "\n")
     trace_summary.main([capture, "--spans", str(elsewhere)])
     assert "tick in flight" not in capsys.readouterr().out
+
+
+def test_the_engine_threads_account_and_its_stall_records(tmp_path, capsys):
+    """From the run's `spans.jsonl` alone: the partition, what held the
+    thread, the ratios as the benchmark's readers compute them and the
+    records longest first; with a capture (a device idle from 1 to 6 ms, two
+    anchors), the record that lies over the gap beside the idle time in it;
+    a gap no record covers is short of 20 ms there and is not printed."""
+    import json
+
+    wall = 1_790_000_000.0
+    base = {"name": "serve_decode_step", "ticks": 32, "steps": 32,
+            "admit_s": 0.001, "stage_s": 0.001, "dispatch_s": 0.002,
+            "wait_s": 0.004, "unit_wait_s": 0.0, "emit_s": 0.001,
+            "loop_s": 0.0009, "step_s": 0.010, "block_s": 0.003,
+            "gc_s": 0.0002, "compile_s": 0.0, "wait_gc_s": 0.0,
+            "gc_collections": 3, "gc_gen2": 0, "compiles": 0,
+            "ticks_found_ready": 4, "gc_longest_s": 0.0001,
+            "stalls": [], "stalls_dropped": 0}
+    # on the profiler's clock of the capture below: [1.0, 6.0) ms
+    stall = {"phase": "serve_tick_emit", "ts": wall + 0.001 - 0.25e-6,
+             "dur": 0.005, "gc_s": 0.004, "compile_s": 0.0,
+             "other_s": 0.001, "step": 3, "active": 2, "units": 0}
+    short = dict(stall, phase="loop", ts=wall + 0.5, dur=0.002, gc_s=0.0,
+                 other_s=0.002)
+    lines = [dict(base, ts=wall, stalls=[stall]),
+             dict(base, ts=wall + 0.010, stalls=[short]),
+             {"name": "serve_decode_step", "ts": wall + 5.0, "ticks": 3}]
+    spans = tmp_path / "spans.jsonl"
+    spans.write_text("".join(json.dumps(r) + "\n" for r in lines))
+    found = trace_summary.host_thread(str(spans))
+    assert found["account"]["spans"] == 2
+    assert found["window_s"] == pytest.approx(0.020)
+    assert found["ratios"] == pytest.approx({
+        "host_stall_share.serve": 100.0 * 0.007 / 0.020,
+        "gc_pause_share.serve": 100.0 * 0.0004 / 0.020,
+        "host_bound_tick_share.serve": 100.0 * 8 / 64})
+    assert [r["phase"] for r in found["records"]] == ["serve_tick_emit",
+                                                      "loop"]
+    assert found["joined"] is None
+    trace_summary.main([str(tmp_path)])         # spans alone: no capture
+    out = capsys.readouterr().out
+    assert "no capture under" in out and "the engine's thread" in out
+    assert "unaccounted=1.00%" in out and "host_stall_share.serve 35.0000%" in out
+    assert "5.0 ms under serve_tick_emit at" in out
+    assert "ms by cause: collector 4.0, other 1.0" in out
+    assert out.index("under serve_tick_emit") < out.index("under loop")
+    # the capture of the test above: idle from 1 to 6 ms and from 10 to 16
+    ms = 1_000_000
+    ops = [_op("fusion.1", TICK + "kv_gather/gather", 0 * ms, 1 * ms),
+           _op("fusion.1", TICK + "kv_gather/gather", 6 * ms, 4 * ms),
+           _op("fusion.1", TICK + "kv_gather/gather", 16 * ms, 4 * ms)]
+    host = {"python": [("serve_tick_wait", None, 5 * ms, 6 * ms),
+                       ("serve_tick_emit", None, 1 * ms, 5 * ms)] + [
+        (f"wallclock_us={1_790_000_000_000_000 + at}", None, at * 1000 + 250, 0)
+        for at in (1_500, 11_500)]}
+    sx.write(tmp_path / "serve.xplane.pb", {"/device:TPU:0": {"XLA Ops": ops},
+                                            "/host:CPU": host})
+    trace_summary.main([str(tmp_path)])
+    out = capsys.readouterr().out
+    assert "1 record(s) inside the capture" in out
+    assert "device idle inside it 5.000 ms" in out
+    assert "5.000 of the first device plane's 11.000 idle ms" in out
+    assert "NO RECORD" not in out               # the other gap is 6 ms

@@ -35,7 +35,19 @@ ones the per-layer metrics report:
   and how often the prefill unit in flight did:
   units whose result was read after the next hand-over was enqueued
   (`ahead`) of all `serve_prefill` lines, and the round trips they made
-  (`reads`).
+  (`reads`);
+- from the same lines, the engine thread's own account (`serve/engine.py`
+  `HOST_SUMS` / `HOST_COUNTS`, benchmark/host_stall.py): each phase's share
+  of `step_s` and the unaccounted rest, what held the thread outside its two
+  device waits and inside them, the ratios as the benchmark's readers
+  compute them (`host_stall_share.serve`, `gc_pause_share.serve`,
+  `host_bound_tick_share.serve`; over the thread's own seconds the lines
+  cover, the sum of `step_s`) and the stall records longest first; with a
+  capture, each record that lies inside it beside the device idle time
+  inside it, and the idle gaps of 20 ms or more that no record covers.
+
+A directory that holds a `spans.jsonl` and no capture prints the sections
+that need none.
 
 Usage:
   python tools/trace_summary.py <trace_dir> [--top 15] [--spans spans.jsonl]
@@ -49,12 +61,13 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark import scopes, tick_gap, xplane  # noqa: E402
+from benchmark import host_stall, scopes, tick_gap, xplane  # noqa: E402
 
 
-def summarize(path: str, top: int = 15) -> dict:
-    """The summary as data: what `main` prints."""
-    trace = xplane.read(path)
+def summarize(path: str, top: int = 15, trace: dict | None = None) -> dict:
+    """The summary as data: what `main` prints (`trace`: `xplane.read(path)`
+    where the caller has it already)."""
+    trace = trace or xplane.read(path)
     if not any(trace["devices"].values()):
         raise SystemExit(
             f"{path} holds no device operation (planes named "
@@ -146,6 +159,54 @@ def unit_pipeline(spans_path: str):
     return {"units": len(rows), **{k: sum(r[k] for r in rows) for k in UNITS}}
 
 
+def host_thread(spans_path: str, trace: dict | None = None):
+    """The engine thread's account over the file's `serve_decode_step` lines
+    that carry it, as the benchmark's readers compute it: {"account",
+    "window_s" (the thread's own seconds the lines cover, the sum of their
+    `step_s`: a file has no window of a job's), "ratios" ({reader: percent
+    or None}), "records" (longest first), "joined" (`host_stall.join`
+    against `trace`; None without a capture)}; None where no line carries it
+    (a trainer's file, or a build before the engine kept the account)."""
+    rows = _span_lines(spans_path, "serve_decode_step", ("step_s",))
+    if not rows:
+        return None
+    rows.sort(key=lambda r: r["ts"])
+    obs = {"kind": "serve", "spans": rows, "xplane": trace,
+           "window": (rows[0]["ts"], rows[0]["ts"] + sum(
+               r["step_s"] for r in rows))}
+    records = host_stall.stalls_of(rows)
+    return {
+        "account": host_stall.account(rows),
+        "window_s": host_stall.window_s(obs),
+        "ratios": {
+            "host_stall_share.serve": host_stall.stall_share(obs),
+            "gc_pause_share.serve": host_stall.share_of_window(
+                obs, "gc_s", "wait_gc_s"),
+            "host_bound_tick_share.serve": host_stall.found_ready_share(
+                obs)},
+        "records": sorted(records, key=lambda r: -r["dur"]),
+        "joined": host_stall.join(obs, records) if trace else None}
+
+
+def _print_host_thread(found: dict, top: int) -> None:
+    acc = found["account"]
+    print(f"\n== the engine's thread, by its own account "
+          f"({acc['spans']} lines, {found['window_s']:.3f} s) ==\n"
+          f"  {host_stall.describe_partition(acc)}\n"
+          f"  {host_stall.describe_causes(acc)}")
+    for name, value in found["ratios"].items():
+        print(f"  {name} "
+              + ("not known" if value is None else f"{value:.4f}%"))
+    records = found["records"]
+    print(f"  {len(records)} stall record(s)"
+          f" ({acc.get('stalls_dropped', 0)} more dropped from full spans)")
+    for rec in records[:top]:
+        print(f"  {host_stall.describe_record(rec)}")
+    if found["joined"] is not None:
+        for line in host_stall.describe_joined(found["joined"]):
+            print(f"  capture: {line}")
+
+
 def _table(title: str, rows: dict) -> None:
     print(f"\n== {title} (% of busy time) ==")
     for name, share in sorted(rows.items(), key=lambda kv: -kv[1]):
@@ -164,11 +225,20 @@ def main(argv: list[str] | None = None) -> None:
     args = p.parse_args(argv)
 
     path = xplane.find_xplane(args.trace_dir)
+    spans_path = args.spans or find_spans(args.trace_dir)
     if path is None:
-        raise SystemExit(
-            f"no .xplane.pb under {args.trace_dir} (is this a jax.profiler "
-            f"output dir? expected plugins/profile/<time>/*.xplane.pb)")
-    s = summarize(path, args.top)
+        found = host_thread(spans_path) if spans_path else None
+        if found is None:
+            raise SystemExit(
+                f"no .xplane.pb under {args.trace_dir} (is this a "
+                f"jax.profiler output dir? expected "
+                f"plugins/profile/<time>/*.xplane.pb)")
+        print(f"no capture under {args.trace_dir}: the sections that need "
+              f"none, from {spans_path}")
+        _print_host_thread(found, args.top)
+        return
+    trace = xplane.read(path)
+    s = summarize(path, args.top, trace)
     print(f"trace: {path}")
     print(f"{s['chips']} chip(s), window {s['window_s']:.4f} s, busy "
           f"{s['busy_s']:.4f} s, idle {s['idle_percent']:.3f}%; "
@@ -191,7 +261,6 @@ def main(argv: list[str] | None = None) -> None:
                 print(f"  {tick_gap.ms_a_tick(part, name):10.3f} ms  {name}")
         print(f"  {tick_gap.describe_shift(s['device_clock_shift'])}")
     print(f"\nclock: {tick_gap.describe_clock(s['clock'])}")
-    spans_path = args.spans or find_spans(args.trace_dir)
     pipeline = tick_pipeline(spans_path) if spans_path else None
     if pipeline is not None:
         print(f"\n== the engine's tick in flight ({spans_path}) ==\n"
@@ -216,6 +285,9 @@ def main(argv: list[str] | None = None) -> None:
               f"({100.0 * units['ahead'] / units['units']:.2f}%)"
               f"\n  reads {units['reads']} "
               f"({units['reads'] / units['units']:.2f} a unit)")
+    found = host_thread(spans_path, trace) if spans_path else None
+    if found is not None:
+        _print_host_thread(found, args.top)
 
 
 if __name__ == "__main__":
