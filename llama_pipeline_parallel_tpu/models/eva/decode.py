@@ -241,9 +241,9 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
     complete the window of its first token, that window's chunks are pooled
     (ring entries before the chunk, the chunk's own after) and written to
     its summary pages; the queries read the slot's summaries, the ring as it
-    stood and the chunk's own keys in one softmax; then the chunk's keys and
-    values go to the ring at `p mod W`. Returns the LAST place's float32
-    logits, the pool, the mask as it came, and "counters"."""
+    stood and the chunk's own keys in one softmax; then its keys and values
+    go to the ring at `p mod W` (the engine runs no chunk of pads alone).
+    Returns the last float32 logits, the pool, the mask and "counters"."""
     del slot, write_start
     _, T = input_ids.shape
     W, per_window = cfg.window_size, cfg.chunks_per_window

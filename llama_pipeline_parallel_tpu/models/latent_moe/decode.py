@@ -430,9 +430,9 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
     chunk's own end reaches (a sort, a gather or an expansion of 4k places
     for a chunk that ends at 4k, not of the whole row); a sliding layer
     reads the ring for the window - 1 places before the chunk, attends, and
-    leaves the chunk's last places in the ring. A chunk of nothing but left
-    pads changes no visible state. Returns the LAST position's float32
-    logits, the stores, the mask, "counters" and "selection"."""
+    leaves its last places in the ring. A chunk of nothing but left pads
+    changes no visible state (the engine runs none). Returns the LAST place's
+    float32 logits, the stores, the mask, "counters" and "selection"."""
     _, C = input_ids.shape
     _, _, page, _ = pool["latent"].shape
     L = page_table_row.shape[0] * page

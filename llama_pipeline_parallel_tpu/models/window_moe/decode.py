@@ -335,8 +335,8 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
     axis ends at the chunk's own end); a window layer reads the ring for the
     places before the chunk, attends the band, and leaves the chunk's last
     places in the ring. A chunk of nothing but left pads changes no visible
-    state. Returns the LAST position's float32 logits, the stores, the mask
-    and "counters"."""
+    state, so the engine starts a row behind them. Returns the LAST
+    position's float32 logits, the stores, the mask and "counters"."""
     _, C = input_ids.shape
     G, dv = cfg.full_kv_heads, cfg.v_head_dim
     page = pool["v"].shape[2] // G

@@ -13,7 +13,11 @@ frontend), and at every `step()` boundary the engine
    then-join. With `prefill_chunk_tokens` set, a bucket larger than the
    budget instead prefills INCREMENTALLY: at most that many prompt tokens
    per tick (`paged_prefill_chunk`), so in-flight decodes keep producing a
-   token every tick — chunked batched prefill, no full-prefill stall;
+   token every tick — chunked batched prefill, no full-prefill stall. Such
+   a row starts at the first chunk that holds a real token: the chunks in
+   front of it hold nothing but left pads, whose places the slot's zeroed
+   mask row already hides, and are never handed to the device
+   (`chunks_skipped`; the last chunk always runs, it yields the logits);
 2. stages and enqueues ONE `paged_decode_step` over every slot (static
    shape, one compile) — per-row write positions, rope positions, rng
    chains, and sampling knobs, so requests at different depths and with
@@ -410,8 +414,13 @@ class _Prefilling:
     ids: np.ndarray          # [1, bucket] left-padded prompt
     mask: np.ndarray         # [1, bucket]
     positions: np.ndarray    # [1, bucket] rope positions
-    done: int                # prompt tokens prefilled so far
+    done: int                # places of the bucket behind the prefill
     t_admit: float
+    # where `done` started, and the leading chunks of nothing but left pads
+    # that a cold chunked row never ran to start there (on the span of the
+    # row's first unit)
+    start: int = 0
+    skipped: int = 0
     # prefix cache: the submit-time verdict (None = cache off), and
     # whether positions [0, done) at start came from shared pages — a warm
     # prefill recomputes only its tail via decode.paged_prefill_span
@@ -434,6 +443,7 @@ class _Unit:
     handover_s: float        # host seconds the hand-over took
     offset: int
     cost: int
+    first: bool              # of its request's units
 
 
 class _HostThread:
@@ -738,6 +748,7 @@ class ServeEngine:
         self.steps = 0
         self.prefill_chunks_last_tick = 0
         self.prefill_chunks_total = 0
+        self.prefill_chunks_skipped_total = 0
         self.prefill_tokens_total = 0
         # pending aggregated serve_decode_step span (decode_span_every)
         self._tick_ts = 0.0
@@ -1166,6 +1177,7 @@ class ServeEngine:
                                 None).astype(np.int32)
             chunk = self.serve_cfg.prefill_chunk_tokens
             warm = match is not None and match.tokens > 0
+            skipped = 0
             if warm:
                 # prefix-cache hit: positions [0, match.tokens) are served
                 # by shared pages already mapped into the slot's table row
@@ -1179,8 +1191,15 @@ class ServeEngine:
                     match.forked = True
                     self.slots.unpin_page(match.fork_src)
             elif chunk and bucket > chunk:
-                # incremental writes: the previous occupant's mask must die
+                # incremental writes: the previous occupant's mask must die.
+                # Behind a zeroed mask row nothing of the slot's stores is
+                # visible, which is all a chunk of left pads would leave:
+                # the row starts at its first chunk with a token in it (the
+                # last chunk runs whatever it holds: it yields the logits)
                 self.slots.reset_mask_row(slot)
+                skipped = min(pad // chunk, bucket // chunk - 1)
+                self.prefill_chunks_skipped_total += skipped
+            start = match.tokens if warm else skipped * chunk
             if self._reqtrace is not None:
                 b = self._reqtrace.begin(request)
                 b.admitted(t_admit, slot, bucket,
@@ -1192,8 +1211,8 @@ class ServeEngine:
             return _Prefilling(request=request, handle=handle, slot=slot,
                                bucket=bucket, ids=ids, mask=mask,
                                positions=positions,
-                               done=match.tokens if warm else 0,
-                               t_admit=t_admit, match=match, warm=warm)
+                               done=start, t_admit=t_admit, start=start,
+                               skipped=skipped, match=match, warm=warm)
         except Exception as e:
             logger.exception("admission of %s failed", request.request_id)
             self.stats.record_failed(request.tenant)
@@ -1292,7 +1311,8 @@ class ServeEngine:
             (trace.SERVE_ADMIT, t0), ("serve_prefill", t_call),
             (trace.PREFILL_ENQUEUE, t_called), ("serve_prefill", t_handed))
         return _Unit(pf=pf, vector=vector, row=row, ts=ts, t0=t0,
-                     handover_s=t_handed - t0, offset=offset0, cost=cost)
+                     handover_s=t_handed - t0, offset=offset0, cost=cost,
+                     first=offset0 == pf.start)
 
     def _prev(self) -> jax.Array:
         """What the next tick takes as the tick before's vector."""
@@ -1356,6 +1376,7 @@ class ServeEngine:
             request=pf.request.request_id, bucket=pf.bucket, slot=pf.slot,
             chunk=unit.cost, offset=unit.offset, ahead=int(ahead),
             reads=int(fetched is not None),
+            **({"chunks_skipped": pf.skipped} if unit.first else {}),
             **(dict(zip(self._family.counters, counters.tolist()))
                if counters is not None else {}))
         # like the tick's flush: a span line, an anchor (a cell of long
@@ -1771,6 +1792,8 @@ class ServeEngine:
         snap["prefilling"] = len(self._prefilling)
         snap["prefill_chunks_last_tick"] = self.prefill_chunks_last_tick
         snap["prefill_chunks_total"] = self.prefill_chunks_total
+        snap["prefill_chunks_skipped_total"] = \
+            self.prefill_chunks_skipped_total
         snap["prefill_tokens_total"] = self.prefill_tokens_total
         if self._prefix:
             # cache-off snapshots stay byte-identical to the plain

@@ -178,11 +178,14 @@ def chain_hashes(ids_row: np.ndarray, mask_row: np.ndarray,
                  page_size: int) -> list:
     """One chain hash per page_size block of the PADDED row: h_i =
     H(h_{i-1} || ids_block || mask_block). KV at row position j is a pure
-    function of row content [0, j] (pads are masked out of attention but
-    written deterministically), so an equal chain hash means bit-equal page
-    bytes for same-kernel writers — the sharing criterion. Hashing the mask
-    alongside the ids is what makes pad-layout differences (same prompt,
-    different bucket alignment) correctly NOT share."""
+    function of row content [0, j], so an equal chain hash means bit-equal
+    page bytes for same-kernel writers wherever the mask lets a reader look
+    — the sharing criterion. A pad's entry is what its writer left, or, in a
+    block of a chunk the engine never ran (`serve/engine.py`: the chunks of
+    nothing but left pads), what the page held before: every sharer's mask
+    hides it alike. Hashing the mask alongside the ids is what makes
+    pad-layout differences (same prompt, different bucket alignment)
+    correctly NOT share."""
     n = len(ids_row) // page_size
     out = []
     h = b""
@@ -546,7 +549,12 @@ class PagedKVCache:
                     same = (child.ids == blk_ids) & (child.mask == blk_mask)
                     c = ps if same.all() else int(np.argmin(same))
                     c = min(c, ps - 1)  # a full block match would have
-                    if c > best:        # matched by hash; cap defensively
+                    # matched by hash; cap defensively. A common prefix of
+                    # pads alone is not worth a fork, and may not be one: a
+                    # row that never ran its pad chunks left such a page as
+                    # it found it, and the span's tokens would saturate
+                    # against an int8 scale no write of either row set
+                    if c > best and blk_mask[:c].any():
                         best, fork_src = c, child.page
                 if fork_src is not None:
                     tokens += best

@@ -179,9 +179,10 @@ def test_prefill_then_ticks_through_the_three_stores_are_the_reference(chunk):
 
 
 def test_a_chunk_of_nothing_but_pads_changes_nothing_a_query_can_see():
-    """The engine left-pads to the bucket and runs every chunk of it: the
-    chunks before the prompt's first token count nothing, route nothing and
-    leave the slot's mask row empty."""
+    """The engine left-pads to the bucket and starts at the first chunk that
+    holds a token (tests/test_pad_chunks_skipped.py), and may: a chunk
+    before it counts nothing, routes nothing and leaves the slot's mask row
+    empty."""
     cfg = tiny.config()
     params, _, _ = tiny.both_sides()
     cache = _cache(cfg)

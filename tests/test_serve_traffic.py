@@ -73,6 +73,15 @@ def test_run_trace_against_chunked_paged_engine():
     assert summary["tokens_generated"] >= 4 * summary["submitted"] > 0
     assert "ttft_p50_ms" in summary
     assert summary["prefill_chunks_total"] >= summary["submitted"]
+    # a prompt of 8 is one whole bucket; one of 24 in the bucket of 32 is
+    # four chunks of 8, the first of them nothing but pads and never run
+    long = sum(1 for tr in trace_reqs if tr.prompt_len == 24)
+    assert summary["prefill_chunks_skipped_total"] <= long
+    if not shed:
+        assert summary["prefill_chunks_skipped_total"] == long
+        assert (summary["prefill_chunks_total"]
+                + summary["prefill_chunks_skipped_total"]
+                == (8 - long) + 4 * long)
     assert summary["pages_total"] == 24
 
 

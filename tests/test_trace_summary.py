@@ -133,7 +133,8 @@ def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
     `spans.jsonl`, found beside or above the capture (or named), the two
     ratios of the tick's paged attention from the lines that carry
     `kv_steps_visited`, and the
-    prefill units' `ahead` and `reads` from its `serve_prefill` lines; lines
+    prefill units' `ahead` and `reads` from its `serve_prefill` lines, with
+    the chunks never run (`chunks_skipped` on a request's first unit); lines
     of other spans and of a build that does not carry them are passed over,
     and a capture with no such file prints no such section."""
     import json
@@ -149,10 +150,13 @@ def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
               # rows of 536 pages walked 5 a step: 108 steps a whole table
               "kv_pages_live": 9800, "kv_pages_table": 53600,
               "kv_steps_visited": 2000, "kv_pages_per_step": 5},
-             {"name": "serve_prefill", "ahead": 1, "reads": 1},
+             {"name": "serve_prefill", "ahead": 1, "reads": 1,
+              "chunks_skipped": 2},
              {"name": "serve_prefill", "ahead": 1, "reads": 0},
-             {"name": "serve_prefill", "ahead": 0, "reads": 1},
-             {"name": "serve_request", "ahead": 1, "reads": 1}]
+             {"name": "serve_prefill", "ahead": 0, "reads": 1,
+              "chunks_skipped": 0},
+             {"name": "serve_request", "ahead": 1, "reads": 1,
+              "chunks_skipped": 9}]
     spans = tmp_path / "spans.jsonl"
     spans.write_text("".join(json.dumps(r) + "\n" for r in lines))
     assert trace_summary.find_spans(
@@ -161,7 +165,7 @@ def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
         "ticks": 40, "ticks_ahead": 39, "tokens": 600, "rows_overrun": 2,
         "rows_joined_fed": 3}
     assert trace_summary.unit_pipeline(str(spans)) == {
-        "units": 3, "ahead": 2, "reads": 2}
+        "units": 3, "ahead": 2, "reads": 2, "skipped": 2}
     assert trace_summary.kv_steps(str(spans)) == {
         "pages_live": 9800, "steps_visited": 2000, "steps_table": 10800}
     trace_summary.main([capture])
@@ -173,6 +177,7 @@ def test_the_tick_in_flight_is_summed_from_the_runs_spans(capture, tmp_path,
     assert "kv_steps_visited 2000 of 10800 steps" in out and "(0.185)" in out
     assert "units ahead 2 of 3 (66.67%)" in out
     assert "reads 2 (0.67 a unit)" in out
+    assert "units run 3 / skipped 2 (40.00% of both" in out
     elsewhere = tmp_path / "elsewhere.jsonl"
     elsewhere.write_text(json.dumps(lines[1]) + "\n")
     trace_summary.main([capture, "--spans", str(elsewhere)])

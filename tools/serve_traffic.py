@@ -284,7 +284,8 @@ def run_trace(engine, trace_requests, time_scale: float = 1.0,
            if k.startswith(("ttft_", "tpot_", "queue_wait_", "prefix_"))
            or k in ("requests_completed", "requests_failed",
                     "tokens_generated", "prefill_chunks_total",
-                    "prefill_tokens_total", "pages_total")},
+                    "prefill_chunks_skipped_total", "prefill_tokens_total",
+                    "pages_total")},
     }
     if submitted_by_tenant:
         summary["submitted_by_tenant"] = dict(

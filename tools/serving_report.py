@@ -129,7 +129,8 @@ def main(argv: list[str] | None = None) -> int:
                                              for k, v in pages.items()))
             chunks = {k: last.get(k) for k in
                       ("prefill_chunks_last_tick", "prefill_chunks_total",
-                       "prefill_tokens_total", "prefilling") if k in last}
+                       "prefill_chunks_skipped_total", "prefill_tokens_total",
+                       "prefilling") if k in last}
             if chunks:
                 print("  prefill:   " + " ".join(f"{k}={v}"
                                                  for k, v in chunks.items()))

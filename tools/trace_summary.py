@@ -35,7 +35,9 @@ ones the per-layer metrics report:
   and how often the prefill unit in flight did:
   units whose result was read after the next hand-over was enqueued
   (`ahead`) of all `serve_prefill` lines, and the round trips they made
-  (`reads`);
+  (`reads`), and beside the units run the chunks never run (`chunks_skipped`
+  on a request's first unit: the leading chunks of its bucket that held
+  nothing but left pads);
 - from the same lines, the engine thread's own account (`serve/engine.py`
   `HOST_SUMS` / `HOST_COUNTS`, benchmark/host_stall.py): each phase's share
   of `step_s` and the unaccounted rest, what held the thread outside its two
@@ -150,13 +152,15 @@ def kv_steps(spans_path: str):
 
 
 def unit_pipeline(spans_path: str):
-    """{"units", "ahead", "reads"} over the `serve_prefill` lines that carry
-    `UNITS`; None where the file holds none (a build whose prefill units
-    were each waited for)."""
+    """{"units", "ahead", "reads", "skipped"} over the `serve_prefill` lines
+    that carry `UNITS` (`skipped`: their `chunks_skipped`, which a request's
+    first unit carries); None where the file holds none (a build whose
+    prefill units were each waited for)."""
     rows = _span_lines(spans_path, "serve_prefill", UNITS)
     if not rows:
         return None
-    return {"units": len(rows), **{k: sum(r[k] for r in rows) for k in UNITS}}
+    return {"units": len(rows), **{k: sum(r[k] for r in rows) for k in UNITS},
+            "skipped": sum(r.get("chunks_skipped", 0) for r in rows)}
 
 
 def host_thread(spans_path: str, trace: dict | None = None):
@@ -280,11 +284,15 @@ def main(argv: list[str] | None = None) -> None:
               f"({walked['steps_visited'] / walked['steps_table']:.3f})")
     units = unit_pipeline(spans_path) if spans_path else None
     if units is not None:
+        both = units["units"] + units["skipped"]
         print(f"\n== the engine's prefill unit in flight ==\n"
               f"  units ahead {units['ahead']} of {units['units']} "
               f"({100.0 * units['ahead'] / units['units']:.2f}%)"
               f"\n  reads {units['reads']} "
-              f"({units['reads'] / units['units']:.2f} a unit)")
+              f"({units['reads'] / units['units']:.2f} a unit)"
+              f"\n  units run {units['units']} / skipped {units['skipped']} "
+              f"({100.0 * units['skipped'] / both:.2f}% of both were chunks "
+              f"of nothing but pads)")
     found = host_thread(spans_path, trace) if spans_path else None
     if found is not None:
         _print_host_thread(found, args.top)
