@@ -10,14 +10,17 @@ the latent-attention block (MLA layers, with or without an indexer and
 window layers, as its configuration says) and sparse experts
 (`models/latent_moe/`) the third, the compressed-window block (an exact
 window beside pooled chunk summaries, `models/eva/`) the fourth, the
-state-space / expert block (every layer ONE of a Mamba-2 mixer, a
-grouped-query softmax layer or a latent expert feed-forward, in a published
-order, `models/ssm_moe/`) the fifth, the window / full softmax block
+state-space block (every letter of a published order ONE of a Mamba-2 mixer,
+a grouped-query softmax layer, a latent expert feed-forward or a dense gated
+feed-forward: the expert block, and the dense block whose layer is a mixer
+AND a feed-forward half under four scalar multipliers and a tied head,
+`models/ssm_moe/`) the fifth, the window / full softmax block
 (grouped-query layers of two kinds in a published order, each kind with its
 own KV heads, rotary base and mask, the window kind with a learned sink in
 its softmax; keys wider than values; sigmoid-routed experts and nothing
-beside the routed sum, `models/window_moe/`) the sixth. A seventh registers
-its configuration class below.
+beside the routed sum, `models/window_moe/`) the sixth: six families, one of
+them (`ssm_moe`) serving two published shapes. A seventh registers its
+configuration class below.
 
 A family also states what a slot's PAGES are (`table_width`,
 `table_columns`): how wide a slot's row of the page table is and which of its
@@ -38,8 +41,11 @@ pages. What a family states may depend on the
 configuration: a latent model without window layers keeps no such store.
 Whether it can prefill in chunks is a separate fact (`paged_prefill_chunk`):
 the latent and the window block's chunks carry their rings forward from
-chunk to chunk; neither recurrent family's prefill takes the state and the convolution
-inputs a chunk before it left, so neither chunks yet.
+chunk to chunk, and of the two recurrent families the state-space block's
+chunk carries the slot's state and convolution inputs forward (a row whose
+mask holds no token before the chunk starts from zeros, whatever the last
+occupant left); the hybrid block's prefill still takes neither from the
+chunk before it, so it does not chunk yet.
 
 `GenerationConfig`, `sample_rowwise` and `sampler_branch` are the same for
 every family (the sampling of a row of logits, and what a batch's knobs ask of
@@ -145,7 +151,9 @@ class ServingFamily:
                            f"only)")
         if prefill_chunk_tokens and self.paged_prefill_chunk is None:
             refused.append("prefill_chunk_tokens > 0 (its programs cannot "
-                           "carry the slot's row from chunk to chunk yet)")
+                           "carry the slot's row from chunk to chunk yet: "
+                           "of the six families' recurrent ones ssm_moe "
+                           "does, hybrid_moe does not)")
         if prefix_cache and self.paged_prefill_span is None:
             refused.append(
                 "prefix_cache (a shared page holds what its layers page "
@@ -237,9 +245,14 @@ def _ssm_moe(cfg) -> ServingFamily:
     return ServingFamily(
         name="ssm_moe", prefill_prompt=decode.prefill_prompt,
         paged_decode_step=decode.paged_decode_step,
-        write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
+        # narrow KV heads packed a page row: the pages are matrices
+        write_pages=(decode.write_pages if cfg.kv_pack == 1
+                     else decode.write_packed_pages),
+        init_page_pool=decode.init_page_pool,
         init_recurrent_store=decode.init_recurrent_store,
-        init_params=model.init_params, counters=decode.COUNTERS)
+        init_params=model.init_params,
+        paged_prefill_chunk=decode.paged_prefill_chunk,
+        counters=decode.COUNTERS)
 
 
 _FAMILIES = {"llama": _llama, "hybrid_moe": _hybrid_moe,

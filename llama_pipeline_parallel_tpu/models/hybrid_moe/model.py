@@ -44,7 +44,10 @@ import jax
 import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.models.hybrid_moe.config import HybridMoEConfig
-from llama_pipeline_parallel_tpu.models.llama.model import cast_weight
+from llama_pipeline_parallel_tpu.models.llama.model import (
+    add_residual,
+    cast_weight,
+)
 from llama_pipeline_parallel_tpu.ops.grouped_matmul import (
     group_metadata,
     grouped_matmul,
@@ -468,4 +471,5 @@ def attn_output(layer: Params, x: jnp.ndarray, hidden: jnp.ndarray,
             attn_out = jax.nn.sigmoid(
                 hidden @ cast_weight(layer["wg"], cfg.dtype)) * attn_out
     with jax.named_scope(trace.SCOPE_ATTN_OUT):
-        return x + attn_out @ cast_weight(layer["wo"], cfg.dtype)
+        return add_residual(x, attn_out @ cast_weight(layer["wo"], cfg.dtype),
+                            cfg)

@@ -92,6 +92,16 @@ def cast_params(params: Params, dtype) -> Params:
 # Forward pieces (each maps onto one reference pipe-layer class)
 # ---------------------------------------------------------------------------
 
+def add_residual(x: jnp.ndarray, y: jnp.ndarray, cfg) -> jnp.ndarray:
+    """`x + m y`, `m` the configuration's `residual_multiplier`: a family
+    that has none, or states 1, adds no operation. The product is formed in
+    float32 and rounded once to `y`'s dtype."""
+    m = getattr(cfg, "residual_multiplier", 1.0)
+    if m == 1.0:
+        return x + y
+    return x + (y.astype(jnp.float32) * m).astype(y.dtype)
+
+
 def embed(params: Params, input_ids: jnp.ndarray, cfg: LlamaConfig) -> jnp.ndarray:
     """Token embedding (reference EmbeddingPipe, models/llama_ds_mp_wrap.py:128-132)."""
     with jax.named_scope(trace.SCOPE_EMBED):
@@ -184,7 +194,7 @@ def mlp_block(layer: Params, x: jnp.ndarray, cfg: LlamaConfig,
         mlp_out = (gate * up) @ cast_weight(layer["mlp"]["down"], dt)
         if tp_axis is not None:
             mlp_out = tp_reduce(mlp_out, tp_axis)
-        return residual + mlp_out
+        return add_residual(residual, mlp_out, cfg)
 
 
 def run_layers(

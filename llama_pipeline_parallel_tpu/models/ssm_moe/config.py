@@ -1,9 +1,19 @@
-"""Configuration of the state-space / expert block: every layer is ONE of
-three things with its own norm and residual, in the order a published string
-gives (`pattern`): `M` a Mamba-2 state-space mixer, `*` a grouped-query
-softmax layer without rotary embedding, `E` a sparse expert feed-forward
-whose routed experts work in a latent width beside one shared expert at the
-model's width.
+"""Configuration of the state-space / expert block: every letter of
+`pattern` is ONE thing with its own norm and residual, in the order a
+published string or list gives: `M` a Mamba-2 state-space mixer, `*` a
+grouped-query softmax layer without rotary embedding, `E` a sparse expert
+feed-forward whose routed experts work in a latent width beside one shared
+expert at the model's width, `-` a dense gated (SwiGLU) feed-forward. A
+published layer of TWO halves (a mixer and its feed-forward, each under its
+own norm) is two letters, `M-` or `*-`, and counts as one layer.
+
+Four scalar multipliers, each 1 (or, for the scores, `head_dim ** -0.5`)
+unless a configuration states another, and then adding NO operation to a
+program: `embedding_multiplier` on the embedded tokens, `residual_multiplier`
+on what every letter adds to the residual stream, `attention_multiplier` the
+scores' scale, `logits_scaling` the divisor of the logits. With
+`tie_word_embeddings` the head is the embedding table and the tree holds no
+`lm_head`.
 
 The fifth block family beside `models/llama/`. Named for what it is: any
 model of this shape is served by it (docs/SERVING.md "Block families").
@@ -16,7 +26,9 @@ from typing import Any
 
 import jax.numpy as jnp
 
-KINDS = "M*E"
+KINDS = "M*E-"
+MIXERS = "M*"                   # what a `-` stands behind
+LANES = 128                     # the last axis of a tile of the device's memory
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +58,14 @@ class SsmMoEConfig:
     routed_scaling_factor: float = 5.0
     expert_offset: int = 0              # first expert this process holds
     experts_held: int | None = None     # how many it holds; None -> all
+    # `-`: the dense gated feed-forward's width
+    dense_intermediate_size: int = 0
+    # the four multipliers and the tied head (the module's docstring)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None   # None: head_dim ** -0.5
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False
     rms_norm_eps: float = 1e-5
     # bf16 weights and activations as the family is published; the state,
     # `A_log`, `D`, `dt_bias` and the router are float32 whatever these say
@@ -59,6 +79,13 @@ class SsmMoEConfig:
         if not self.pattern or set(self.pattern) - set(KINDS):
             raise ValueError(f"pattern {self.pattern!r}: a layer is one of "
                              f"{tuple(KINDS)}")
+        if any(kind == "-" and (i == 0 or self.pattern[i - 1] not in MIXERS)
+               for i, kind in enumerate(self.pattern)):
+            raise ValueError(f"pattern {self.pattern!r}: a dense half `-` "
+                             f"stands behind its layer's mixer, `M` or `*`")
+        if "-" in self.pattern and self.dense_intermediate_size < 1:
+            raise ValueError("a pattern with dense halves needs "
+                             "dense_intermediate_size")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_attention_heads must be a multiple of "
                              "num_key_value_heads")
@@ -77,11 +104,24 @@ class SsmMoEConfig:
 
     @property
     def num_hidden_layers(self) -> int:
-        return len(self.pattern)
+        """Layers as published: a dense half belongs to the mixer before
+        it."""
+        return len(self.pattern) - self.pattern.count("-")
 
     @property
     def kv_heads(self) -> int:
         return self.num_key_value_heads
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads of one token that share a row of a page. Heads narrower
+        than a lane tile are stored side by side, as many as fill the tile (2
+        heads of 64), so that a page is a matrix of whole tiles which every
+        program reads and writes where it lies (models/ssm_moe/decode.py);
+        where they do not fill it in whole rows, and at a head of a tile or
+        more, 1: a head a row, the dense layout."""
+        pack = LANES // self.head_dim if LANES % self.head_dim == 0 else 1
+        return pack if self.num_key_value_heads % pack == 0 else 1
 
     @property
     def kv_cache_layers(self) -> int:
@@ -96,6 +136,12 @@ class SsmMoEConfig:
     @property
     def expert_layers(self) -> int:
         return self.pattern.count("E")
+
+    @property
+    def attn_scale(self) -> float:
+        """The factor of the softmax layers' scores."""
+        return (self.head_dim ** -0.5 if self.attention_multiplier is None
+                else self.attention_multiplier)
 
     @property
     def held(self) -> int:
@@ -122,7 +168,11 @@ class SsmMoEConfig:
         `moe_latent_size`, ...). `n_routed_experts` counts the experts HELD
         where `router_experts` gives the router's width beside it (one
         chip's share of an expert-parallel deployment, with
-        `expert_offset`)."""
+        `expert_offset`). A `granitemoehybrid` configuration (`layer_types`,
+        `mamba_n_heads`, `shared_intermediate_size`, the four multipliers) is
+        read by `_from_granite`."""
+        if config.get("model_type") == "granitemoehybrid":
+            return SsmMoEConfig._from_granite(config, **kw)
         pattern = config["hybrid_override_pattern"]
         if len(pattern) != config["num_hidden_layers"]:
             raise ValueError(
@@ -170,9 +220,79 @@ class SsmMoEConfig:
         return SsmMoEConfig(**base)
 
     @staticmethod
-    def tiny(**kw) -> "SsmMoEConfig":
+    def _from_granite(config: dict, **kw) -> "SsmMoEConfig":
+        """From the keys of a published `granitemoehybrid` `config.json`:
+        every layer a mixer (`layer_types`: `mamba` or `attention`) AND a
+        dense SwiGLU half of `shared_intermediate_size`, no experts beside
+        it, no positional embedding, heads of `hidden_size /
+        num_attention_heads`, the head tied to the table."""
+        letters = {"mamba": "M-", "attention": "*-"}
+        types = config["layer_types"]
+        if len(types) != config["num_hidden_layers"] or set(types) - set(letters):
+            raise ValueError(
+                f"layer_types gives {len(types)} layers of {sorted(set(types))} "
+                f"for {config['num_hidden_layers']} of {sorted(letters)}")
+        if config["num_local_experts"] or config["num_experts_per_tok"]:
+            raise ValueError("this reader takes the dense block: no experts "
+                             "beside the shared feed-forward")
+        if config["position_embedding_type"] != "nope":
+            raise ValueError("this block's softmax layers carry no positional "
+                             "embedding (`nope`)")
+        if config["hidden_act"] != "silu" or \
+                config["normalization_function"] != "rmsnorm":
+            raise ValueError("this block is SiLU-gated under RMSNorm")
+        if not config["mamba_conv_bias"] or config["mamba_proj_bias"] or \
+                config["attention_bias"]:
+            raise ValueError("this block has a bias on the convolution and "
+                             "nowhere else")
+        inner = config["mamba_n_heads"] * config["mamba_d_head"]
+        if inner != config["mamba_expand"] * config["hidden_size"]:
+            raise ValueError("mamba_n_heads x mamba_d_head is not "
+                             "mamba_expand x hidden_size")
+        if config["hidden_size"] % config["num_attention_heads"]:
+            raise ValueError("hidden_size is not whole heads")
+        base = dict(
+            vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+            pattern="".join(letters[t] for t in types),
+            num_attention_heads=config["num_attention_heads"],
+            num_key_value_heads=config["num_key_value_heads"],
+            head_dim=config["hidden_size"] // config["num_attention_heads"],
+            ssm_heads=config["mamba_n_heads"],
+            ssm_head_dim=config["mamba_d_head"],
+            ssm_state=config["mamba_d_state"],
+            ssm_groups=config["mamba_n_groups"],
+            ssm_conv=config["mamba_d_conv"],
+            ssm_chunk=config["mamba_chunk_size"],
+            dense_intermediate_size=config["shared_intermediate_size"],
+            embedding_multiplier=float(config["embedding_multiplier"]),
+            residual_multiplier=float(config["residual_multiplier"]),
+            attention_multiplier=float(config["attention_multiplier"]),
+            logits_scaling=float(config["logits_scaling"]),
+            tie_word_embeddings=bool(config["tie_word_embeddings"]),
+            rms_norm_eps=config["rms_norm_eps"])
+        base.update(kw)
+        return SsmMoEConfig(**base)
+
+    @staticmethod
+    def tiny(dense: bool = False, **kw) -> "SsmMoEConfig":
         """All three kinds of layer at a toy size for the CPU tests
-        (float32)."""
+        (float32); `dense`: the block of two halves instead (a mixer and a
+        dense gated feed-forward a layer, 2 + 1 + 2 layers, multipliers that
+        are not 1, the head tied, two KV heads a page row)."""
+        if dense:
+            # heads of 64, so that two KV heads pack a page row as they do at
+            # the published width
+            base = dict(
+                vocab_size=128, hidden_size=256, pattern="M-M-*-M-M-",
+                num_attention_heads=4, num_key_value_heads=2, head_dim=64,
+                ssm_heads=8, ssm_head_dim=64, ssm_state=16,
+                ssm_groups=1, ssm_chunk=8, dense_intermediate_size=128,
+                embedding_multiplier=3.0, residual_multiplier=0.6,
+                attention_multiplier=0.25, logits_scaling=2.0,
+                tie_word_embeddings=True,
+                dtype=jnp.float32, param_dtype=jnp.float32)
+            base.update(kw)
+            return SsmMoEConfig(**base)
         base = dict(
             vocab_size=128, hidden_size=32, pattern="MEM*EME",
             num_attention_heads=4, num_key_value_heads=2, head_dim=8,

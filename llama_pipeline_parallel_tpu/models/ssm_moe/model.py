@@ -1,7 +1,10 @@
 """Layers of the state-space / expert block (config.py): the Mamba-2 mixer
 in its chunked form (its one-step form is `ops/ssm_state_step.py`, which
 steps the decode tick's store in place), and the expert layer whose routed
-experts work in a latent width. The softmax layer's projections are the
+experts work in a latent width. The dense gated feed-forward (`-`) is the
+dense decoder's (`llama.model.mlp_block`), and so are the embedding, the
+final norm and the head, under the configuration's multipliers (`embed`,
+`logits`; `llama.model.add_residual`). The softmax layer's projections are the
 hybrid block's (`hybrid_moe.model.attn_project` / `attn_output`, without a
 gate), and so are the router, the sort by held expert and the combine
 (`route`, `dispatch_rows`, `combine_rows`): the expert half here is its own
@@ -16,7 +19,7 @@ unroll it. Nothing is stacked, so nothing is ever sliced: the grouped
 product (`ops/grouped_matmul.py`) takes a layer's routed experts as the
 buffers they are stored in.
 
-    embed.embedding [V, d]   norm [d]   lm_head [d, V]
+    embed.embedding [V, d]   norm [d]   lm_head [d, V] (absent where tied)
     layers[i], by kind:
       M  input_norm [d], in_proj [d, 2 HP + 2 GN + H], conv_w [width, HP + 2 GN],
          conv_b, dt_bias [H], A_log [H], D [H], gate_norm [HP], out_proj [HP, d]
@@ -24,8 +27,9 @@ buffers they are stored in.
       E  post_norm [d], router [d, R], router_bias [R], latent_in [d, l],
          up [held, l, f], down [held, f, l], latent_out [l, d],
          shared_up [d, fs], shared_down [fs, d]
+      -  post_norm [d], mlp.gate [d, fd], mlp.up [d, fd], mlp.down [fd, d]
 
-Every layer is `x <- x + f(rmsnorm(x))`. Mamba-2, per head h of H with P
+Every layer is `x <- x + m f(rmsnorm(x))`, `m` the residual multiplier. Mamba-2, per head h of H with P
 channels, state `S [P, N]` float32, group `g = h // (H / G)`:
 
     [z | xBC | dt] = u W_in;  xBC <- silu(conv1d_causal_depthwise(xBC) + bias)
@@ -45,9 +49,14 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid
-from llama_pipeline_parallel_tpu.models.llama.model import cast_weight
+from llama_pipeline_parallel_tpu.models.llama import model as llama
+from llama_pipeline_parallel_tpu.models.llama.model import (
+    add_residual,
+    cast_weight,
+)
 from llama_pipeline_parallel_tpu.models.ssm_moe.config import SsmMoEConfig
 from llama_pipeline_parallel_tpu.ops.grouped_matmul import (
     group_metadata,
@@ -60,7 +69,12 @@ Params = dict
 HIGHEST = jax.lax.Precision.HIGHEST
 INIT_STD = 0.02
 CONV_STD, CONV_BIAS_STD = 0.3, 0.1
-COUNTERS = hybrid.COUNTERS + ("ssm_rows",)
+# the expert layers' six, then the family's own, each summed over layers:
+# rows the Mamba-2 layers advanced one step, positions they scanned, entries
+# the softmax layers' queries read, and what a chunk carried in of its
+# slot's row (rows, bytes)
+COUNTERS = hybrid.COUNTERS + ("ssm_rows", "ssm_positions", "kv_entries_read",
+                              "state_carries", "state_bytes_carried")
 EXPERT_LEAVES = ("up", "down")           # the grouped product's operands
 
 
@@ -83,6 +97,7 @@ def init_params(rng: jax.Array, cfg: SsmMoEConfig) -> Params:
     kv_w = cfg.num_key_value_heads * cfg.head_dim
     lat, f, fs = (cfg.moe_latent_size, cfg.moe_intermediate_size,
                   cfg.shared_intermediate_size)
+    fd = cfg.dense_intermediate_size
 
     def ssm_layer():
         step = jnp.exp(jax.random.uniform(next(keys), (H,), jnp.float32,
@@ -108,10 +123,97 @@ def init_params(rng: jax.Array, cfg: SsmMoEConfig) -> Params:
                 "down": proj(cfg.held, f, lat), "latent_out": proj(lat, d),
                 "shared_up": proj(d, fs), "shared_down": proj(fs, d)}
 
-    make = {"M": ssm_layer, "*": attn_layer, "E": expert_layer}
-    return {"embed": {"embedding": proj(cfg.vocab_size, d)},
-            "layers": [make[kind]() for kind in cfg.pattern],
-            "norm": ones(d), "lm_head": proj(d, cfg.vocab_size)}
+    def dense_layer():
+        return {"post_norm": ones(d),
+                "mlp": {"gate": proj(d, fd), "up": proj(d, fd),
+                        "down": proj(fd, d)}}
+
+    make = {"M": ssm_layer, "*": attn_layer, "E": expert_layer,
+            "-": dense_layer}
+    params = {"embed": {"embedding": proj(cfg.vocab_size, d)},
+              "layers": [make[kind]() for kind in cfg.pattern],
+              "norm": ones(d)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = proj(d, cfg.vocab_size)
+    return params
+
+
+# -- the ends: embedding and head under their multipliers ---------------------
+
+def embed(params: Params, input_ids: jnp.ndarray,
+          cfg: SsmMoEConfig) -> jnp.ndarray:
+    """The embedded tokens times `embedding_multiplier` (1: the dense
+    decoder's `embed` and nothing more)."""
+    x = llama.embed(params, input_ids, cfg)
+    if cfg.embedding_multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(x.dtype)
+
+
+def logits(params: Params, x: jnp.ndarray, cfg: SsmMoEConfig) -> jnp.ndarray:
+    """float32 logits of normed hidden states: the dense decoder's `lm_head`
+    over the tree's own head, or over the embedding table where the head is
+    tied to it (the product contracts the table's second axis: nothing is
+    transposed in memory), divided by `logits_scaling`."""
+    if cfg.tie_word_embeddings:
+        params = {"lm_head": params["embed"]["embedding"].T}
+    out = llama.lm_head(params, x, cfg)
+    if cfg.logits_scaling == 1.0:
+        return out
+    return out * (1.0 / cfg.logits_scaling)
+
+
+# -- the softmax layer: a stated scale, narrow heads packed a page row --------
+
+def scaled_queries(q: jnp.ndarray, cfg: SsmMoEConfig) -> jnp.ndarray:
+    """The queries for an attention that scales its scores by `head_dim **
+    -0.5` itself (the chunk's kernel, which whole buckets run too): multiplied
+    by what `attention_multiplier` is over that, so the scores come out at
+    the stated scale (Granite's 1/64 over 1/8 is 1/8, exact in any dtype).
+    No multiplier stated: the queries as they are."""
+    if cfg.attention_multiplier is None:
+        return q
+    return q * jnp.asarray(cfg.attn_scale * cfg.head_dim ** 0.5, q.dtype)
+
+
+def _own_part(cfg: SsmMoEConfig) -> np.ndarray:
+    """[heads]: which of a packed row's `kv_pack` parts holds a query
+    head's KV head (head j reads KV head j // g, part (j // g) % kv_pack of
+    row (j // g) // kv_pack)."""
+    g = cfg.num_attention_heads // cfg.num_key_value_heads
+    return (np.arange(cfg.num_attention_heads) // g) % cfg.kv_pack
+
+
+def packed_kv(a: jnp.ndarray, cfg: SsmMoEConfig) -> jnp.ndarray:
+    """Keys or values [..., kv_h, hd] as a page keeps them: `kv_pack` heads
+    side by side a row, [..., kv_h / kv_pack, kv_pack * hd] (a reshape: heads
+    2p and 2p + 1 are neighbours)."""
+    return a.reshape(*a.shape[:-2], cfg.kv_heads // cfg.kv_pack,
+                     cfg.kv_pack * cfg.head_dim)
+
+
+def packed_queries(q: jnp.ndarray, cfg: SsmMoEConfig) -> jnp.ndarray:
+    """One-token queries [b, h, hd] against packed rows: [b, h, kv_pack *
+    hd], a head's numbers in the part of the row its KV head lies in and
+    zeros in the others, so that its product with a packed row is its
+    product with its own KV head's key."""
+    if cfg.kv_pack == 1:
+        return q
+    own = jnp.asarray(_own_part(cfg)[:, None] == np.arange(cfg.kv_pack),
+                      q.dtype)                               # [h, pack]
+    b, h, hd = q.shape
+    return (q[:, :, None, :] * own[None, :, :, None]).reshape(b, h, -1)
+
+
+def unpacked_heads(out: jnp.ndarray, cfg: SsmMoEConfig) -> jnp.ndarray:
+    """A packed attention's output [b, h, kv_pack * hd] -> [b, h, hd]: the
+    part that a head's own KV head's values fill (the other parts hold the
+    head's weights over its row-mates' values, and are dropped)."""
+    if cfg.kv_pack == 1:
+        return out
+    b, h, _ = out.shape
+    parts = out.reshape(b, h, cfg.kv_pack, cfg.head_dim)
+    return parts[:, np.arange(h), _own_part(cfg)]
 
 
 # -- Mamba-2 ------------------------------------------------------------------
@@ -174,7 +276,8 @@ def ssm_output(layer: Params, x: jnp.ndarray, y: jnp.ndarray, xs: jnp.ndarray,
         y = (y.reshape(b, s, -1)
              * layer["gate_norm"].astype(jnp.float32)).astype(cfg.dtype)
     with jax.named_scope(trace.SSM_PROJ):
-        return x + y @ cast_weight(layer["out_proj"], cfg.dtype)
+        return add_residual(x, y @ cast_weight(layer["out_proj"], cfg.dtype),
+                            cfg)
 
 
 def ssm_chunked(x, dt, A, B, C, state, chunk: int):
@@ -286,4 +389,4 @@ def latent_moe_block(layer: Params, x: jnp.ndarray, valid: jnp.ndarray,
     counters = jnp.stack([
         jnp.sum(ok) * k, jnp.sum(here), jnp.sum(sizes > 0), jnp.max(sizes),
         jnp.int32(held), meta.visits]).astype(jnp.int32)
-    return x + y.reshape(b, s, d).astype(x.dtype), counters
+    return add_residual(x, y.reshape(b, s, d).astype(x.dtype), cfg), counters

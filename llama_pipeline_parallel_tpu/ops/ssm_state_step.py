@@ -23,7 +23,11 @@ group's lanes are rotated to the front (`pltpu.roll` by the group's first
 head, a traced shift), so every lane index in the body is static and the
 program is one group long whatever the block (the heads unrolled across a
 block of 128 took 4 to 5 s to trace and lower for the five layers at every
-start of the server, compile cache or not: `setup_s`). A row that is not
+start of the server, compile cache or not: `setup_s`). A group wider than
+`_UNROLL` heads (one group of 64, the dense block's) is walked in runs of
+`_UNROLL`, each rotated to the front the same way and reading its group's B
+and C again, so 36 calls of such a layer cost what 36 calls of a 16-head
+group cost to trace and lower (docs/KERNELS.md has the seconds). A row that is not
 decoding has dt = 0: decay 1 and an update of 0 keep its state bit for bit,
 as the formula always did.
 
@@ -63,6 +67,10 @@ _COMPILER_PARAMS = compiler_params("parallel", "arbitrary")
 # state one grid step brings to VMEM and takes back (four buffers this size)
 _BLOCK_BYTES = 4 << 20
 _LANES = 128
+# heads unrolled in the loop's body: a group of no more is one pass (16 a
+# group in the expert block's cell); a wider group (64 in the dense block's)
+# is walked in runs of this many, its B and C read again a run
+_UNROLL = 16
 
 
 def head_block(heads: int, groups: int, head_dim: int, state: int) -> int:
@@ -82,15 +90,20 @@ def _kernel(decay_ref, xdt_ref, b_ref, c_ref, state_ref, out_ref, y_ref, *,
     H = xdt_ref.shape[1]                 # the heads' lanes, whole vregs
     lane = jax.lax.broadcasted_iota(jnp.int32, xdt_ref.shape, 1)
     ones = jnp.ones((N, H), jnp.float32)
+    # the heads one pass of the loop unrolls: a group, or `_UNROLL` of a
+    # wider group's (the program is that long whatever the block)
+    sub = min(per_group, _UNROLL)
+    passes_a_group = per_group // sub
 
-    def group(g, y):
-        first = j * hb + g * per_group       # the group's first head: a lane
-        # the group's heads to lanes [0, per_group): static lanes from here
+    def run(s, y):
+        first = j * hb + s * sub             # the run's first head: a lane
+        # the run's heads to lanes [0, sub): static lanes from here
         xdt = pltpu.roll(xdt_ref[...], (H - first) % H, 1)
+        g = s if passes_a_group == 1 else s // passes_a_group
         B, C = b_ref[g], c_ref[g]                           # [1, N]
-        y_group = jnp.zeros_like(xdt)
-        for h in range(per_group):
-            head = g * per_group + h
+        y_run = jnp.zeros_like(xdt)
+        for h in range(sub):
+            head = s * sub + h
             S = (decay_ref[slot, first + h] * state_ref[head]
                  + xdt[:, h:h + 1] * B)                     # [P, N]
             out_ref[head] = S
@@ -99,13 +112,13 @@ def _kernel(decay_ref, xdt_ref, b_ref, c_ref, state_ref, out_ref, y_ref, *,
                 S * C, ones, (((1,), (0,)), ((), ())),
                 precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)         # [P, H]
-            y_group = jnp.where(lane == h, summed, y_group)
-        mine = (lane >= first) & (lane < first + per_group)
-        return jnp.where(mine, pltpu.roll(y_group, first, 1), y)
+            y_run = jnp.where(lane == h, summed, y_run)
+        mine = (lane >= first) & (lane < first + sub)
+        return jnp.where(mine, pltpu.roll(y_run, first, 1), y)
 
     # y's block is the slot's, whichever heads' block this is: every head's
-    # lane of it is some group's by the slot's last block
-    y_ref[...] = jax.lax.fori_loop(0, hb // per_group, group, y_ref[...])
+    # lane of it is some run's by the slot's last block
+    y_ref[...] = jax.lax.fori_loop(0, hb // sub, run, y_ref[...])
 
 
 def ssm_state_step(store: jnp.ndarray, index: int, x: jnp.ndarray,

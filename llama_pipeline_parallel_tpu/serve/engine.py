@@ -750,6 +750,9 @@ class ServeEngine:
         self.prefill_chunks_total = 0
         self.prefill_chunks_skipped_total = 0
         self.prefill_tokens_total = 0
+        # units that started from what the unit before them left in the
+        # slot's row of a recurrent store (a family that counts them)
+        self.prefill_state_carries_total = 0
         # pending aggregated serve_decode_step span (decode_span_every)
         self._tick_ts = 0.0
         self._tick_accum = 0.0
@@ -1371,14 +1374,18 @@ class ServeEngine:
         counters = fetched
         if row is not None:
             token, chain, counters = tick_io.split_first(fetched)
+        counted = (dict(zip(self._family.counters, counters.tolist()))
+                   if counters is not None else {})
+        self.prefill_state_carries_total += bool(
+            counted.get("state_carries"))
         trace.recorder().emit(
             "serve_prefill", ts=unit.ts, dur=unit.handover_s + t_read - t0,
             request=pf.request.request_id, bucket=pf.bucket, slot=pf.slot,
+            prompt=len(pf.request.input_ids),
             chunk=unit.cost, offset=unit.offset, ahead=int(ahead),
             reads=int(fetched is not None),
             **({"chunks_skipped": pf.skipped} if unit.first else {}),
-            **(dict(zip(self._family.counters, counters.tolist()))
-               if counters is not None else {}))
+            **counted)
         # like the tick's flush: a span line, an anchor (a cell of long
         # chunks flushes too seldom to anchor a capture of seconds)
         trace.wallclock_anchor()
@@ -1795,6 +1802,8 @@ class ServeEngine:
         snap["prefill_chunks_skipped_total"] = \
             self.prefill_chunks_skipped_total
         snap["prefill_tokens_total"] = self.prefill_tokens_total
+        snap["prefill_state_carries_total"] = \
+            self.prefill_state_carries_total
         if self._prefix:
             # cache-off snapshots stay byte-identical to the plain
             # paged engine (the PR 13 pin) — these keys only exist

@@ -183,6 +183,12 @@ MOE_LATENT_IN = "moe_latent_in"      # token -> the width the routed experts rea
 MOE_LATENT_OUT = "moe_latent_out"    # their weighted sum -> the model's width
 SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_STEP, SSM_NORM, MOE_LATENT_IN,
               MOE_LATENT_OUT)
+# a prefill chunk's read of its slot's row of the recurrent store (zeros
+# where the row is the last occupant's) and its write of the row it leaves:
+# in the chunk program alone (models/ssm_moe/decode.py `paged_prefill_chunk`)
+STATE_CARRY_IN = "state_carry_in"
+STATE_CARRY_OUT = "state_carry_out"
+STATE_CARRY_SCOPES = (STATE_CARRY_IN, STATE_CARRY_OUT)
 
 # models/window_moe/ (reuses `attn_qkv`, `attn_out`, `kv_write`, `kv_gather`,
 # `ring_write`, `ring_gather`, `mlp`, `decode_mlp`, the `moe_*` names,

@@ -38,6 +38,12 @@ CASES = {
     # a window and a ring of 8
     "window_moe": ("window_moe", dict(_FOUR, page_size=window_tiny.PAGE,
                                       num_pages=24), 128, 12, 5),
+    # a recurrent state and the convolution's inputs carried from chunk to
+    # chunk: the long row leaves its own in the slot's row of the store
+    "ssm_moe": ("ssm_moe", dict(_FOUR, page_size=8, num_pages=12), 128, 12,
+                5),
+    "ssm_moe.dense": ("ssm_moe.dense", dict(_FOUR, page_size=8, num_pages=12),
+                      128, 12, 5),
 }
 
 

@@ -588,8 +588,8 @@ def test_a_state_space_tick_compiled_for_the_chip_keeps_its_stores_in_place(
     both Mamba-2 layers (no copy of a layer's state in front of the step and
     no `dynamic-update-slice` of one behind it, outputs aliased), the
     attention is the paged kernel at 16 query heads a KV head, and the host
-    fetches 3 a slot and seven counters. Mamba-2 and attention shapes as the
-    cell's, a small width."""
+    fetches 3 a slot and the family's counters. Mamba-2 and attention shapes
+    as the cell's, a small width."""
     from llama_pipeline_parallel_tpu.models import tick_io
     from llama_pipeline_parallel_tpu.models.ssm_moe import decode as ssm_decode
     from llama_pipeline_parallel_tpu.models.ssm_moe import model as ssm
@@ -613,7 +613,7 @@ def test_a_state_space_tick_compiled_for_the_chip_keeps_its_stores_in_place(
          pool, jax.ShapeDtypeStruct((slots, pmax * page), jnp.int32)),
         one_chip)
     lowered = tick_io.packed(ssm_decode.paged_decode_step).lower(*args, cfg)
-    assert lowered.out_info["fetch"].shape == (3 * slots + 7,)
+    assert lowered.out_info["fetch"].shape == (3 * slots + counters,)
     compiled = lowered.compile()
     analysis = compiled.memory_analysis()
 
@@ -635,6 +635,145 @@ def test_a_state_space_tick_compiled_for_the_chip_keeps_its_stores_in_place(
     assert not [line for line in text.splitlines()
                 if layer_state in line and ("dynamic-update-slice(" in line
                                             or " copy(" in line)]
+
+
+def _dense_block(slots: int):
+    """The dense state-space block at `serve-rag-48.granite4-h-micro`'s
+    Mamba-2 and attention shapes (one group of 64 heads of 64, state 128,
+    chunk 256; 32 query / 8 KV heads of 64, two of them a page row, scale
+    1/64; the four multipliers, the head tied), a small width and three
+    layers; its pool of 2048 pages and `slots` rows."""
+    from llama_pipeline_parallel_tpu.models.ssm_moe import decode as ssm_decode
+    from llama_pipeline_parallel_tpu.models.ssm_moe import model as ssm
+    from llama_pipeline_parallel_tpu.models.ssm_moe.config import SsmMoEConfig
+
+    cfg = SsmMoEConfig(
+        vocab_size=512, hidden_size=256, pattern="M-*-M-",
+        num_attention_heads=32, num_key_value_heads=8, head_dim=64,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+        ssm_chunk=256, dense_intermediate_size=512, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=1 / 64,
+        logits_scaling=8.0, tie_word_embeddings=True)
+    params = jax.eval_shape(lambda: ssm.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: {
+        **ssm_decode.init_page_pool(cfg, 2048, 64),
+        **ssm_decode.init_recurrent_store(cfg, slots)})
+    return cfg, params, pool
+
+
+def _dims(a) -> str:
+    return ",".join(str(n) for n in a.shape)
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_a_dense_state_space_program_compiled_for_the_chip_keeps_its_stores_in_place(
+        one_chip, mosaic, program):
+    """KV heads of 64, the first below a lane tile in any served
+    configuration: two lie side by side in a 128-lane row and a page is the
+    matrix `[64 x 4, 128]`, so the tick's kernel (through a view) and the
+    chunk's scatter and gather read and write the pool where it lies: no
+    instruction but the in-place writes makes an array of a pool's shape. The
+    state step runs at one group of 64 heads. A chunk reads and writes ONE
+    slot's row of the recurrent store: no copy of the `conv` store in the
+    convolution's own layout (three places padded to 128 lanes) stands in
+    front of it or behind it (`decode._own_layout`)."""
+    from llama_pipeline_parallel_tpu.models import tick_io
+    from llama_pipeline_parallel_tpu.models.ssm_moe import decode as ssm_decode
+
+    slots, pmax, page, C = 48, 64, 64, 2048
+    cfg, params, pool = _dense_block(slots)
+    assert pool["k"].shape == (1, 2049, page * 4, 128)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    kv_mask = i32(slots, pmax * page)
+    if program == "tick":
+        args = _described(
+            (params, i32(slots, tick_io.COLUMNS + pmax),
+             i32(3 * slots + len(ssm_decode.COUNTERS)), pool, kv_mask),
+            one_chip)
+        compiled = tick_io.packed(ssm_decode.paged_decode_step).lower(
+            *args, cfg).compile()
+        kernels = ("paged_decode_attn", "ssm_state_step")
+    else:
+        args = _described(
+            (params, i32(1, C), i32(1, C), i32(1, C), pool, i32(pmax), i32(),
+             kv_mask, i32()), one_chip)
+        compiled = ssm_decode.paged_prefill_chunk.lower(*args, cfg).compile()
+        kernels = ("full_chunk_attn",)
+    analysis = compiled.memory_analysis()
+    nbytes = lambda a: int(np.prod(a.shape)) * a.dtype.itemsize
+    assert analysis.alias_size_in_bytes >= sum(nbytes(a) for a in pool.values())
+    if program == "tick":
+        # a copy of the keys' pool is larger than everything the tick holds
+        assert analysis.temp_size_in_bytes < nbytes(pool["k"]) // 2, analysis
+    text = compiled.as_text()
+    assert all(name in text for name in kernels)
+    made = lambda a, ops: [
+        line for line in text.splitlines()
+        if f"[{_dims(a)}]" in line.split(" = ")[-1].split("(")[0]
+        and any(f" {op}(" in line for op in ops)]
+    assert not made(pool["k"], ("copy", "transpose"))
+    assert not made(pool["conv"], ("copy", "transpose"))
+    assert not made(pool["state"], ("copy", "transpose"))
+    # nor does a chunk slice a whole LAYER of either store out (every slot's
+    # row: 100 MB a layer at the cell's 48 slots, where it needs one row)
+    for name in ("state", "conv") if program == "chunk" else ():
+        layer = "[1," + _dims(pool[name]).split(",", 1)[1] + "]"
+        assert layer not in text, (name, layer)
+
+
+def test_mosaic_compiles_the_state_step_at_one_group_of_64_heads(
+        one_chip, mosaic):
+    """The dense block's store at four layers, float32 [4, 48, 64, 64, 128]:
+    one group of 64 heads is one block of 2 MB, walked in four runs of 16
+    unrolled heads; the store is aliased to the result and the program holds
+    nothing else of any size."""
+    layers, slots, H, P, G, N = 4, 48, 64, 64, 1, 128
+    shapes = _described(tuple(
+        jax.ShapeDtypeStruct(s, jnp.float32)
+        for s in ((layers, slots, H, P, N), (slots, H, P), (slots, H), (H,),
+                  (slots, G, N), (slots, G, N))), one_chip)
+    assert ssm_state_step.head_block(H, G, P, N) == H
+    compiled = jax.jit(
+        lambda store, *a: ssm_state_step.ssm_state_step(store, 2, *a),
+        donate_argnums=0).lower(*shapes).compile()
+    analysis = compiled.memory_analysis()
+    assert analysis.alias_size_in_bytes >= layers * slots * H * P * N * 4
+    assert analysis.temp_size_in_bytes < 8 << 20, analysis
+    assert "ssm_state_step" in compiled.as_text()
+
+
+def test_mosaic_compiles_the_chunks_attention_at_heads_of_64(one_chip, mosaic):
+    """A 2048-token chunk at the end of the cell's longest row: 32 query / 8
+    KV heads of 64 over 17,920 places (4 query heads stacked a program, the
+    output written 64 lanes a head)."""
+    T, S, H, G, d = 2048, 17920, 32, 8, 64
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    shapes = _described(
+        (bf16(1, T, H, d), bf16(1, S, G, d), bf16(1, S, G, d),
+         jax.ShapeDtypeStruct((1, S), jnp.int32),
+         jax.ShapeDtypeStruct((), jnp.int32)), one_chip)
+    compiled = jax.jit(gqa_prefill_attention.full_prefill_attention).lower(
+        *shapes).compile()
+    assert "full_chunk_attn" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_mosaic_compiles_a_whole_buckets_attention_at_the_reasoning_cells_shapes(
+        one_chip, mosaic, bucket):
+    """`serve-reason-64.nemotron3-super` prefills whole buckets of 128 to
+    1024 through the chunk's kernel too (32 query heads over 2 KV heads of
+    128, 16 stacked a program): no bucket's scores are formed."""
+    H, G, d = 32, 2, 128
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    shapes = _described(
+        (bf16(1, bucket, H, d), bf16(1, bucket, G, d), bf16(1, bucket, G, d),
+         jax.ShapeDtypeStruct((1, bucket), jnp.int32),
+         jax.ShapeDtypeStruct((), jnp.int32)), one_chip)
+    compiled = jax.jit(gqa_prefill_attention.full_prefill_attention).lower(
+        *shapes).compile()
+    assert "full_chunk_attn" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_mosaic_compiles_the_state_step_at_the_cells_shape_in_place(

@@ -259,10 +259,12 @@ def test_the_family_is_registered_beside_the_other_four():
     assert fam.write_pages is hybrid_decode.write_pages
     assert fam.init_params is ssm.init_params
     assert fam.serving_weights is None          # served as stored
-    assert fam.paged_prefill_chunk is None and fam.paged_prefill_span is None
+    # a chunk carries the slot's row forward (tests/test_granite_serving.py)
+    assert fam.paged_prefill_chunk is ssm_decode.paged_prefill_chunk
+    assert fam.paged_prefill_span is None
     assert fam.kv_quants == ("fp",)
     assert fam.counters == ssm.COUNTERS
-    assert fam.counters[-1] == "ssm_rows" and len(fam.counters) == 7
+    assert fam.counters[6] == "ssm_rows" and len(fam.counters) == 11
     assert {"llama", "hybrid_moe", "latent_moe", "eva", "ssm_moe"} <= set(
         families._FAMILIES)
 
@@ -380,7 +382,7 @@ def test_the_programs_name_their_work():
 
 @pytest.mark.parametrize("knobs,named", [
     (dict(prefix_cache=True), "prefix_cache"),
-    (dict(prefill_chunk_tokens=8), "prefill_chunk_tokens"),
+    (dict(prefix_cache=True, prefill_chunk_tokens=8), "prefix_cache"),
     (dict(kv_quant="int8"), "kv_quant: int8"),
 ])
 def test_what_recurrent_layers_cannot_run_is_refused_by_name(knobs, named):

@@ -38,6 +38,10 @@ ones the per-layer metrics report:
   (`reads`), and beside the units run the chunks never run (`chunks_skipped`
   on a request's first unit: the leading chunks of its bucket that held
   nothing but left pads);
+- where the lines carry a state-space family's counters
+  (`models/ssm_moe/model.py` COUNTERS), their sums over the ticks and over
+  the prefill units apart: `ssm_rows`, `ssm_positions`, `kv_entries_read`,
+  `state_carries`, `state_bytes_carried`;
 - from the same lines, the engine thread's own account (`serve/engine.py`
   `HOST_SUMS` / `HOST_COUNTS`, benchmark/host_stall.py): each phase's share
   of `step_s` and the unaccounted rest, what held the thread outside its two
@@ -100,6 +104,9 @@ JOINED = "rows_joined_fed"
 UNITS = ("ahead", "reads")
 KV_STEPS = ("tokens", "kv_pages_live", "kv_pages_table", "kv_steps_visited",
             "kv_pages_per_step")
+# a state-space family's own counters, on both kinds of line
+RECURRENT = ("ssm_rows", "ssm_positions", "kv_entries_read", "state_carries",
+             "state_bytes_carried")
 
 
 def find_spans(trace_dir: str):
@@ -161,6 +168,17 @@ def unit_pipeline(spans_path: str):
         return None
     return {"units": len(rows), **{k: sum(r[k] for r in rows) for k in UNITS},
             "skipped": sum(r.get("chunks_skipped", 0) for r in rows)}
+
+
+def recurrent_counters(spans_path: str):
+    """{"ticks": sums, "units": sums} of `RECURRENT` over the file's
+    `serve_decode_step` and `serve_prefill` lines that carry them; None where
+    no line does (another family's run, or a build before the counters)."""
+    sums = {where: {k: sum(r[k] for r in rows) for k in RECURRENT}
+            for where, name in (("ticks", "serve_decode_step"),
+                                ("units", "serve_prefill"))
+            if (rows := _span_lines(spans_path, name, RECURRENT))}
+    return sums or None
 
 
 def host_thread(spans_path: str, trace: dict | None = None):
@@ -293,6 +311,12 @@ def main(argv: list[str] | None = None) -> None:
               f"\n  units run {units['units']} / skipped {units['skipped']} "
               f"({100.0 * units['skipped'] / both:.2f}% of both were chunks "
               f"of nothing but pads)")
+    recurrent = recurrent_counters(spans_path) if spans_path else None
+    if recurrent is not None:
+        print("\n== the state-space family's counters, summed over layers ==")
+        for where, sums in recurrent.items():
+            print(f"  {where}: " + ", ".join(
+                f"{k} {sums[k]}" for k in RECURRENT))
     found = host_thread(spans_path, trace) if spans_path else None
     if found is not None:
         _print_host_thread(found, args.top)
