@@ -22,6 +22,14 @@ beside the routed sum, `models/window_moe/`) the sixth: six families, one of
 them (`ssm_moe`) serving two published shapes. A seventh registers its
 configuration class below.
 
+One family DRAFTS where its configuration says so (`drafts`: a latent model
+published with a multi-token-prediction module, `num_nextn_predict_layers`
+1): its tick runs the trunk on two queries a row, the row's last token and
+the module's draft for the next, emits one or two tokens, and the module
+leaves the next draft (`models/latent_moe/draft.py`); the emitted stream is
+that of one-token ticks. The other five families, and a latent model without
+a module, emit one token a row a tick.
+
 A family also states what a slot's PAGES are (`table_width`,
 `table_columns`): how wide a slot's row of the page table is and which of its
 columns hold pages once so many places of the row are written. Five families
@@ -124,6 +132,17 @@ class ServingFamily:
     # why a shared prefix page cannot serve this family, where the reason is
     # not the recurrent store's or a missing program's
     prefix_cache_why: str = ""
+    # whether the model DRAFTS: its tick verifies a drafted token beside the
+    # row's own and emits one or two tokens a row, the same stream as
+    # one-token ticks (the configuration says so, no option does). Then the
+    # tick's vector is `tick_io`'s wider one, a chunk takes the id that
+    # follows it (`next_id`), and a row may write one place past its budget
+    drafts: bool = False
+    # a drafting family: (params, the last unit's "hidden", the vector
+    # `first_token` wrote the row's token into, pool, the slot's table row,
+    # slot, kv_mask, rope position and place of the prompt's last token, cfg)
+    # -> the pool with the row's first draft
+    first_draft: Callable | None = None
 
     @property
     def recurrent(self) -> bool:
@@ -136,7 +155,15 @@ class ServingFamily:
         key fed back from the tick before on the device
         (`models/tick_io.py`), the same jitted program for every engine of
         the family."""
+        if self.drafts:
+            return tick_io.packed_drafting(self.paged_decode_step)
         return tick_io.packed(self.paged_decode_step)
+
+    @property
+    def fetch_rows(self) -> int:
+        """Parts of `slots` int32 in front of the counters of the tick's
+        fetched vector."""
+        return tick_io.fetch_rows(self.drafts)
 
     def check_serve_config(self, kv_quant: str, prefill_chunk_tokens: int,
                            prefix_cache: bool) -> None:
@@ -194,18 +221,24 @@ def _hybrid_moe(cfg) -> ServingFamily:
 
 
 def _latent_moe(cfg) -> ServingFamily:
-    from llama_pipeline_parallel_tpu.models.latent_moe import decode, model
+    from llama_pipeline_parallel_tpu.models.latent_moe import (
+        decode,
+        draft,
+        model,
+    )
 
     return ServingFamily(
         name="latent_moe", prefill_prompt=decode.prefill_prompt,
         paged_decode_step=decode.paged_decode_step,
         write_pages=decode.write_pages, init_page_pool=decode.init_page_pool,
-        # a ring a slot only where the model has window layers
+        # a ring a slot only where the model has window layers; the draft's
+        # row where it drafts
         init_recurrent_store=(decode.init_recurrent_store
-                              if cfg.window_layers else None),
+                              if cfg.window_layers or cfg.drafts else None),
         init_params=model.init_params,
         paged_prefill_chunk=decode.paged_prefill_chunk,
-        counters=decode.counters(cfg))
+        counters=decode.counters(cfg), drafts=cfg.drafts,
+        first_draft=draft.first_draft if cfg.drafts else None)
 
 
 def _eva(cfg) -> ServingFamily:

@@ -75,6 +75,11 @@ class LatentMoEConfig:
     routed_scaling_factor: float = 1.0
     expert_offset: int = 0             # first expert this process holds
     experts_held: int | None = None    # how many it holds; None -> all
+    # multi-token-prediction modules after the last layer (0 or 1). One: the
+    # model DRAFTS one token a row a tick with it and verifies the draft in
+    # the next tick (models/latent_moe/draft.py); the module is a whole full
+    # layer with an expert half, with latent and index pages of its own
+    num_nextn_predict_layers: int = 0
     rms_norm_eps: float = 1e-5
     # a slot's ring of the sliding layers holds the window rounded up to a
     # multiple of this many positions
@@ -126,6 +131,15 @@ class LatentMoEConfig:
                 f"held experts [{self.expert_offset}, "
                 f"{self.expert_offset + self.held}) outside the router's "
                 f"{self.router_experts}")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError(
+                f"num_nextn_predict_layers {self.num_nextn_predict_layers}: "
+                f"0 (no module) or 1 (one module drafting one token a tick); "
+                f"more than one drafted token a tick is not served")
+        if self.drafts and not (self.has_indexer and not self.window_layers):
+            raise ValueError(
+                "a multi-token-prediction module is served for a model of "
+                "full layers under an indexer (no sliding layers)")
 
     # -- the layout ----------------------------------------------------------
 
@@ -139,9 +153,20 @@ class LatentMoEConfig:
 
     @property
     def full_layers(self) -> int:
-        """Layers that keep latent pages (and index pages, under an
-        indexer): layer 0 and one a period. The page pool's depth."""
+        """The trunk's layers that keep latent pages (and index pages, under
+        an indexer): layer 0 and one a period."""
         return 1 + self.periods
+
+    @property
+    def drafts(self) -> bool:
+        """Whether the model drafts with a multi-token-prediction module."""
+        return self.num_nextn_predict_layers > 0
+
+    @property
+    def page_depth(self) -> int:
+        """The page pool's depth: the trunk's full layers and, behind them,
+        the module's one where the model has a module."""
+        return self.full_layers + self.num_nextn_predict_layers
 
     @property
     def window_layers(self) -> int:
@@ -213,7 +238,14 @@ class LatentMoEConfig:
         (absent: none); `rope_scaling` YaRN's numbers (null: none).
         `n_routed_experts` counts the experts HELD where `router_experts`
         gives the router's width beside it (one chip's share of an
-        expert-parallel deployment, with `expert_offset`)."""
+        expert-parallel deployment, with `expert_offset`).
+        `rope_parameters.rope_theta` is read where `rope_theta` is absent
+        (`glm_moe_dsa`). `first_k_dense_replace` counts the leading dense
+        layers of the PUBLISHED depth; this block runs exactly one (a cut of
+        a deeper model counts its leading dense layers once and says so in
+        its `reduced`), so 1 is the one value taken.
+        `num_nextn_predict_layers` (absent: 0) takes 0 or 1: one
+        multi-token-prediction module, which then drafts."""
         layers = config["num_hidden_layers"]
         kinds = tuple(t.split("_")[0] for t in config.get(
             "layer_types", ["full_attention"] * layers)[:layers])
@@ -225,7 +257,16 @@ class LatentMoEConfig:
                 f"layer_types[:{layers}] is not one full layer then whole "
                 f"periods of a full layer and its sliding layers: {kinds}")
         if config["first_k_dense_replace"] != 1:
-            raise ValueError("this block has exactly one leading dense layer")
+            raise ValueError(
+                f"first_k_dense_replace {config['first_k_dense_replace']}: "
+                f"this block runs exactly one leading dense layer (layer 0); "
+                f"a cut of a model with more states 1 and lists the key in "
+                f"its `reduced`")
+        nextn = config.get("num_nextn_predict_layers", 0)
+        if nextn not in (0, 1):
+            raise ValueError(
+                f"num_nextn_predict_layers {nextn}: 0 or 1 (one module, one "
+                f"drafted token a tick)")
         sliding = "sliding" in period
         gates = ("attention_gate_type",) + (
             ("swa_attention_gate_type",) if sliding else ())
@@ -236,6 +277,8 @@ class LatentMoEConfig:
         if config["scoring_func"] != "sigmoid":
             raise ValueError("the router scores with a sigmoid")
         scaling = config.get("rope_scaling")
+        theta = config["rope_theta"] if "rope_theta" in config else \
+            config["rope_parameters"]["rope_theta"]
         if scaling is not None:
             if scaling.get("type", scaling.get("rope_type")) != "yarn" or sliding:
                 raise ValueError(
@@ -260,7 +303,8 @@ class LatentMoEConfig:
             base["swa_rope_theta"] = float(config["swa_rope_theta"])
         base.update(
             period=period, index_topk=config.get("index_topk", 0),
-            rope_theta=float(config["rope_theta"]), rope_scaling=scaling,
+            rope_theta=float(theta), rope_scaling=scaling,
+            num_nextn_predict_layers=nextn,
             attention_gate=config.get("attention_gate_type") is not None,
             swa_attention_gate=config.get("swa_attention_gate_type") is not None,
             lora_rescale=bool(config.get("apply_mla_qkv_lora_rescale", False)),

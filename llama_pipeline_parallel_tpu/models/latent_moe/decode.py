@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid
+from llama_pipeline_parallel_tpu.models.latent_moe import draft
 from llama_pipeline_parallel_tpu.models.latent_moe import model as latent
 from llama_pipeline_parallel_tpu.models.latent_moe.config import LatentMoEConfig
 from llama_pipeline_parallel_tpu.models.llama import decode as dense_decode
@@ -77,7 +78,6 @@ from llama_pipeline_parallel_tpu.ops.paged_latent_attention import (
 from llama_pipeline_parallel_tpu.utils import trace
 
 Params = dict
-_N_MOE = len(hybrid.COUNTERS)
 KEY_REACHES = 16          # branches of a chunk's full layer, by its reach
 
 
@@ -87,9 +87,11 @@ def counters(cfg: LatentMoEConfig) -> tuple:
     its queries could see and the ones they selected; without one the
     positions they could see and therefore read (`latent_visible`). Summed
     over rows and full layers; pads and rows that are not decoding count for
-    nothing."""
+    nothing. A model that drafts adds what its verify tick counts
+    (`draft.COUNTERS`), its module's layer counted with the trunk's."""
     return hybrid.COUNTERS + (("index_visible", "index_selected")
-                              if cfg.has_indexer else ("latent_visible",))
+                              if cfg.has_indexer else ("latent_visible",)) + (
+                                  draft.COUNTERS if cfg.drafts else ())
 
 
 def _page_leaves(cfg: LatentMoEConfig) -> dict:
@@ -103,65 +105,26 @@ def _page_leaves(cfg: LatentMoEConfig) -> dict:
 def init_page_pool(cfg: LatentMoEConfig, num_pages: int, page_size: int,
                    quant: str = "fp") -> dict:
     """Zeroed pages of the full layers (latents, and index keys under an
-    indexer), with the garbage page (`models/llama/decode.init_page_pool`)."""
+    indexer; behind the trunk's, the multi-token-prediction module's layer
+    where the model has one), with the garbage page
+    (`models/llama/decode.init_page_pool`)."""
     if quant != "fp":
         raise ValueError(f"the latent block keeps fp pages only, got {quant!r}")
-    lead = (cfg.full_layers, num_pages + 1, page_size)
+    lead = (cfg.page_depth, num_pages + 1, page_size)
     return {name: jnp.zeros(lead + (width,), cfg.dtype)
             for name, width in _page_leaves(cfg).items()}
 
 
 def init_recurrent_store(cfg: LatentMoEConfig, max_slots: int) -> dict:
-    """The per-slot store: a zeroed ring a slot and sliding layer; nothing
-    for a model without sliding layers."""
+    """The per-slot store: a zeroed ring a slot and sliding layer for a
+    model with sliding layers; the draft for one that drafts
+    (`draft.init_store`); nothing for any other."""
+    if cfg.drafts:
+        return draft.init_store(cfg, max_slots)
     if not cfg.window_layers:
         return {}
     return {"ring": jnp.zeros((cfg.window_layers, max_slots, cfg.ring_len,
                                cfg.ring_store_width), cfg.dtype)}
-
-
-def _walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
-          cfg: LatentMoEConfig, full_layer, window_layer, mlp_scope: str):
-    """Run every layer with the stores in the carry. `full_layer(layer, h,
-    stores, depth) -> (h, stores, counted, selection)` (depth: the layer's
-    place in the latent and index pages; `counted`: the full layers' own
-    counters of `counters(cfg)`, int32[2] or int32[1]) and
-    `window_layer(layer, h, stores, index) -> (h, stores)` (index: its place
-    in the ring store) are the caller's mixers; a full layer's `selection`
-    is (chosen, ok) of each row's last query under an indexer, () without.
-    Layer 0 is followed by the dense feed-forward, every other layer by its
-    expert half, which takes the routed experts of every period whole and
-    the period's place among them. Returns the hidden state, the stores, the
-    counters summed over layers (`counters(cfg)`) and the selections stacked
-    over the full layers."""
-    n = len(cfg.period)
-    periods, experts = hybrid.split_experts(params["periods"])
-
-    h, stores, indexed, first_sel = full_layer(params["first"]["attn"], x,
-                                               stores, 0)
-    h = llama.mlp_block(params["first"], h, cfg, scope=mlp_scope)
-
-    def body(carry, xs):
-        h, stores, routed, indexed = carry
-        period, p = xs
-        h, stores, counted, sel = full_layer(period["full"], h, stores, 1 + p)
-        indexed = indexed + counted
-        for j in range(n):
-            if j:
-                h, stores = window_layer(period["win"][j - 1], h, stores,
-                                         p * (n - 1) + j - 1)
-            h, counted = hybrid.moe_block(period["moe"][j], experts[j], p, h,
-                                          valid, cfg)
-            routed = routed + counted
-        return (h, stores, routed, indexed), sel
-
-    zero = jnp.zeros((_N_MOE,), jnp.int32)
-    (h, stores, routed, indexed), sels = jax.lax.scan(
-        body, (h, stores, zero, indexed),
-        (periods, jnp.arange(cfg.periods)))
-    selection = jax.tree.map(lambda a, rest: jnp.concatenate([a[None], rest]),
-                             first_sel, sels)
-    return h, stores, jnp.concatenate([routed, indexed]), selection
 
 
 @partial(jax.jit, static_argnames=("cfg", "max_len"))
@@ -183,7 +146,7 @@ def prefill_prompt(params: Params, input_ids: jnp.ndarray,
     positions = jnp.clip(jnp.cumsum(mask, axis=1) - 1, 0, None).astype(jnp.int32)
     places = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (b, P))
     full, win = cfg.kind(False), cfg.kind(True)
-    lead = (cfg.full_layers, b, max_len)
+    lead = (cfg.page_depth, b, max_len)
     stores = {name: jnp.zeros(lead + (width,), cfg.dtype)
               for name, width in _page_leaves(cfg).items()}
     stores.update(init_recurrent_store(cfg, b))
@@ -224,15 +187,32 @@ def prefill_prompt(params: Params, input_ids: jnp.ndarray,
                                   cfg.ring_store_width))
         return h, {**stores, "ring": ring}
 
-    x, stores, counters, selection = _walk(
+    x, stores, counters, selection = latent.walk(
         params, x, valid, stores, cfg, full_layer, window_layer,
         trace.SCOPE_MLP)
+    if cfg.drafts:
+        # the module's entries of the prompt; its last position waits for
+        # the first token (the row's first tick: draft.py)
+        pr, known = draft.prompt_module(
+            params, x, input_ids, jnp.full((b,), draft.NO_DRAFT, jnp.int32),
+            valid, positions, cfg)
+        with jax.named_scope(trace.LATENT_WRITE):
+            at = (cfg.full_layers, slice(None), slice(0, P))
+            stores = {
+                **stores,
+                "latent": stores["latent"].at[at].set(
+                    latent.stored(pr["entry"], cfg.latent_store_width)),
+                "index": stores["index"].at[at].set(pr["index"][1]),
+                "mtp_draft": jnp.full((b,), draft.NO_DRAFT, jnp.int32)}
+        counters = draft.prefill_counters(counters, known)
+        hidden = {"hidden": x[:, -1]}
     x = llama.final_norm(params, x[:, -1:, :], cfg)
     logits = llama.lm_head(params, x, cfg)
     return {"logits": logits[:, -1], "cache": stores,
             "kv_mask": jnp.pad(mask, ((0, 0), (0, max_len - P))),
             "next_pos": jnp.sum(mask, axis=1).astype(jnp.int32),
-            "counters": counters, "selection": selection}
+            "counters": counters, "selection": selection,
+            **(hidden if cfg.drafts else {})}
 
 
 @partial(jax.jit, donate_argnames=("pool", "kv_mask"))
@@ -248,7 +228,7 @@ def write_pages(pool: dict, kv_mask: jnp.ndarray, slot: jnp.ndarray,
     n_pages = page_rows.shape[0]
     with jax.named_scope(trace.LATENT_WRITE):
         for name in row_cache:
-            if name == "ring":
+            if name == "ring" or name in draft.STORE:
                 continue
             depth, _, bucket, width = row_cache[name].shape
             blocks = row_cache[name].reshape(depth, n_pages, bucket // n_pages,
@@ -259,6 +239,11 @@ def write_pages(pool: dict, kv_mask: jnp.ndarray, slot: jnp.ndarray,
             out["ring"] = jax.lax.dynamic_update_slice(
                 out["ring"], row_cache["ring"].astype(out["ring"].dtype),
                 (0, slot, 0, 0))
+    for name in draft.STORE:
+        if name in row_cache:       # a drafting model's row of the slot
+            out[name] = jax.lax.dynamic_update_slice(
+                out[name], row_cache[name].astype(out[name].dtype),
+                (slot,) + (0,) * (out[name].ndim - 1))
     row = jnp.pad(row_kv_mask.astype(kv_mask.dtype),
                   ((0, 0), (0, kv_mask.shape[1] - row_kv_mask.shape[1])))
     return out, jax.lax.dynamic_update_slice(kv_mask, row, (slot, 0))
@@ -367,7 +352,7 @@ def tick_logits(params: Params, token: jnp.ndarray, pool: dict,
                           latent.unabsorb(layer, o, cfg), cfg)
         return h, {**stores, "ring": ring}
 
-    x, pool, counters, selection = _walk(
+    x, pool, counters, selection = latent.walk(
         params, x, valid, pool, cfg,
         indexed_layer if cfg.has_indexer else dense_layer, window_layer,
         trace.SCOPE_DECODE_MLP)
@@ -400,7 +385,13 @@ def paged_decode_step(params: Params, token: jnp.ndarray, pool: dict,
     outputs plus "counters" (`counters(cfg)`) and "selection" (the
     places each row's query selected in each full layer, and which of them
     hold a position: read by tests and by the benchmark's check, never by
-    the engine)."""
+    the engine). A model that drafts (`cfg.drafts`) runs its VERIFY tick
+    under the same thirteen arguments, two queries a row and up to two tokens
+    (`draft.verify_step`, which says what it returns)."""
+    if cfg.drafts:
+        return draft.verify_step(params, token, pool, page_table, pos,
+                                 write_pos, kv_mask, active, keys, temperature,
+                                 top_k, top_p, cfg)
     logits, pool, kv_mask, counters, selection = tick_logits(
         params, token, pool, page_table, pos, write_pos, kv_mask, active, cfg)
     with jax.named_scope(trace.SCOPE_SAMPLE):
@@ -418,7 +409,8 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
                         pool: dict, page_table_row: jnp.ndarray,
                         slot: jnp.ndarray, kv_mask: jnp.ndarray,
                         write_start: jnp.ndarray,
-                        cfg: LatentMoEConfig) -> dict:
+                        cfg: LatentMoEConfig,
+                        next_id: jnp.ndarray | None = None) -> dict:
     """One bounded prefill chunk of slot `slot`, the arguments of the dense
     `paged_prefill_chunk`: chunk tokens [1, C] at logical places
     [write_start, write_start + C), C a multiple of the page. A full layer
@@ -432,7 +424,11 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
     reads the ring for the window - 1 places before the chunk, attends, and
     leaves its last places in the ring. A chunk of nothing but left pads
     changes no visible state (the engine runs none). Returns the LAST place's
-    float32 logits, the stores, the mask, "counters" and "selection"."""
+    float32 logits, the stores, the mask, "counters" and "selection". For a
+    model that drafts, `next_id` (int32 [1]) is the id that follows the chunk
+    in its bucket, -1 behind the bucket's last chunk: the module keeps the
+    chunk's positions with the ids shifted by one, in its own pages, and the
+    chunk's last hidden state comes back as "hidden" (draft.py)."""
     _, C = input_ids.shape
     _, _, page, _ = pool["latent"].shape
     L = page_table_row.shape[0] * page
@@ -511,9 +507,26 @@ def paged_prefill_chunk(params: Params, input_ids: jnp.ndarray,
                               cfg.ring_store_width))
         return h, {**stores, "ring": ring}
 
-    x, pool, counters, selection = _walk(
+    x, pool, counters, selection = latent.walk(
         params, x, valid, pool, cfg, full_layer, window_layer, trace.SCOPE_MLP)
+    if cfg.drafts:
+        pr, known = draft.prompt_module(params, x, input_ids, next_id, valid,
+                                        positions, cfg)
+        with jax.named_scope(trace.LATENT_WRITE):
+            paged = lambda rows: rows[0].reshape(C // page, page, -1)
+            at = (cfg.full_layers, chunk_pages)
+            pool = {
+                **pool,
+                "latent": pool["latent"].at[at].set(paged(
+                    latent.stored(pr["entry"], cfg.latent_store_width))),
+                "index": pool["index"].at[at].set(paged(pr["index"][1])),
+                "mtp_draft": jax.lax.dynamic_update_slice(
+                    pool["mtp_draft"],
+                    jnp.full((1,), draft.NO_DRAFT, jnp.int32), (slot,))}
+        counters = draft.prefill_counters(counters, known)
+        hidden = {"hidden": x[:, -1]}
     x = llama.final_norm(params, x[:, -1:, :], cfg)
     logits = llama.lm_head(params, x, cfg)
     return {"logits": logits[:, -1], "pool": pool, "kv_mask": kv_mask,
-            "counters": counters, "selection": selection}
+            "counters": counters, "selection": selection,
+            **(hidden if cfg.drafts else {})}

@@ -18,6 +18,12 @@ a period separate leaves as in models/hybrid_moe/model.py):
     periods.win[j].*  [P, ...]     its j-th sliding layer (`cfg.period`'s
                                    sliding layers; none: an empty list)
     periods.moe[j].*  [P, ...]     the expert half of its j-th layer
+    mtp.*                          a model with a multi-token-prediction
+                                   module (`cfg.drafts`): `enorm`, `hnorm`
+                                   [d], `eh_proj` [2 d, d], `attn.*` (a full
+                                   layer's mixer), `moe.*` (an expert half,
+                                   a stack of one), `shared_head_norm` [d];
+                                   table and head are the trunk's
 
 A mixer's leaves (H heads, latents of rank rq / rkv, a head nope + rope
 wide, values v wide): `input_norm [d]`, `wqa [d, rq]`, `q_norm [rq]`,
@@ -47,10 +53,12 @@ import math
 import jax
 import jax.numpy as jnp
 
+from llama_pipeline_parallel_tpu.models.hybrid_moe import model as hybrid
 from llama_pipeline_parallel_tpu.models.latent_moe.config import (
     LatentMoEConfig,
     MixerDims,
 )
+from llama_pipeline_parallel_tpu.models.llama import model as llama
 from llama_pipeline_parallel_tpu.models.llama.model import cast_weight
 from llama_pipeline_parallel_tpu.ops.attention import NEG_INF
 from llama_pipeline_parallel_tpu.ops.latent_prefill_attention import (
@@ -103,7 +111,7 @@ def init_params(rng: jax.Array, cfg: LatentMoEConfig) -> Params:
                        ww=proj(*lead, d, nh))
         return out
 
-    def moe_layer() -> Params:
+    def moe_layer(P: int = P) -> Params:
         f, fs, held = (cfg.moe_intermediate_size, cfg.shared_intermediate_size,
                        cfg.held)
         return {"post_norm": jnp.ones((P, d), pd),
@@ -116,14 +124,19 @@ def init_params(rng: jax.Array, cfg: LatentMoEConfig) -> Params:
 
     ffn = cfg.intermediate_size
     n = len(cfg.period)
-    return {"embed": {"embedding": proj(cfg.vocab_size, d)},
-            "first": {"attn": mixer(False, ()), "post_norm": jnp.ones((d,), pd),
-                      "mlp": {"gate": proj(d, ffn), "up": proj(d, ffn),
-                              "down": proj(ffn, d)}},
-            "periods": {"full": mixer(False, (P,)),
-                        "win": [mixer(True, (P,)) for _ in range(n - 1)],
-                        "moe": [moe_layer() for _ in range(n)]},
-            "norm": jnp.ones((d,), pd), "lm_head": proj(d, cfg.vocab_size)}
+    out = {"embed": {"embedding": proj(cfg.vocab_size, d)},
+           "first": {"attn": mixer(False, ()), "post_norm": jnp.ones((d,), pd),
+                     "mlp": {"gate": proj(d, ffn), "up": proj(d, ffn),
+                             "down": proj(ffn, d)}},
+           "periods": {"full": mixer(False, (P,)),
+                       "win": [mixer(True, (P,)) for _ in range(n - 1)],
+                       "moe": [moe_layer() for _ in range(n)]},
+           "norm": jnp.ones((d,), pd), "lm_head": proj(d, cfg.vocab_size)}
+    if cfg.drafts:
+        out["mtp"] = {"enorm": jnp.ones((d,), pd), "hnorm": jnp.ones((d,), pd),
+                      "eh_proj": proj(2 * d, d), "attn": mixer(False, ()),
+                      "moe": moe_layer(1), "shared_head_norm": jnp.ones((d,), pd)}
+    return out
 
 
 # -- the mixer's projections ---------------------------------------------------
@@ -260,6 +273,26 @@ def output(layer: Params, x: jnp.ndarray, hidden: jnp.ndarray, o: jnp.ndarray,
             o = gate[..., None] * o.reshape(b, s, gate.shape[-1], -1)
     with jax.named_scope(trace.SCOPE_ATTN_OUT):
         return x + o.reshape(b, s, -1) @ cast_weight(layer["wo"], cfg.dtype)
+
+
+# -- the multi-token-prediction module -------------------------------------------
+
+def mtp_project(mtp: Params, next_ids: jnp.ndarray, hidden: jnp.ndarray,
+                params: Params, cfg: LatentMoEConfig) -> jnp.ndarray:
+    """The module's input at positions whose NEXT token is known: u_i =
+    [enorm(E[t_{i+1}]) | hnorm(h_i)] W_eh. next_ids: [b, s] (t_{i+1}; any id
+    in range where the position is not valid); hidden: [b, s, d], the
+    trunk's last layer's output at i, before its final norm; `params`: the
+    trunk's tree, whose table the module shares -> [b, s, d]."""
+    with jax.named_scope(trace.MTP_EMBED):
+        e = jnp.take(cast_weight(params["embed"]["embedding"], cfg.dtype),
+                     next_ids, axis=0)
+    with jax.named_scope(trace.MTP_PROJ):
+        both = jnp.concatenate(
+            [rms_norm(e, mtp["enorm"], cfg.rms_norm_eps),
+             rms_norm(hidden.astype(cfg.dtype), mtp["hnorm"],
+                      cfg.rms_norm_eps)], axis=-1)
+        return both @ cast_weight(mtp["eh_proj"], cfg.dtype)
 
 
 # -- the indexer ---------------------------------------------------------------
@@ -510,6 +543,52 @@ def ring_mask(newest: jnp.ndarray, row_valid: jnp.ndarray,
     inside = (held >= 0) & (held > newest[:, None] - cfg.sliding_window_size)
     valid = jnp.take_along_axis(row_valid, jnp.clip(held, 0, None), axis=1) > 0
     return inside & valid
+
+
+# -- the layers in order -----------------------------------------------------------
+
+def walk(params: Params, x: jnp.ndarray, valid: jnp.ndarray, stores: dict,
+         cfg: LatentMoEConfig, full_layer, window_layer, mlp_scope: str):
+    """Run every layer with the stores in the carry. `full_layer(layer, h,
+    stores, depth) -> (h, stores, counted, selection)` (depth: the layer's
+    place in the latent and index pages; `counted`: the full layers' own
+    counters of `decode.counters(cfg)`, int32[2] or int32[1]) and
+    `window_layer(layer, h, stores, index) -> (h, stores)` (index: its place
+    in the ring store) are the caller's mixers; a full layer's `selection`
+    is (chosen, ok) of each row's last query under an indexer, () without.
+    Layer 0 is followed by the dense feed-forward, every other layer by its
+    expert half, which takes the routed experts of every period whole and
+    the period's place among them. Returns the hidden state, the stores, the
+    counters summed over layers (the trunk's part of `decode.counters(cfg)`)
+    and the selections stacked over the full layers."""
+    n = len(cfg.period)
+    periods, experts = hybrid.split_experts(params["periods"])
+
+    h, stores, indexed, first_sel = full_layer(params["first"]["attn"], x,
+                                               stores, 0)
+    h = llama.mlp_block(params["first"], h, cfg, scope=mlp_scope)
+
+    def body(carry, xs):
+        h, stores, routed, indexed = carry
+        period, p = xs
+        h, stores, counted, sel = full_layer(period["full"], h, stores, 1 + p)
+        indexed = indexed + counted
+        for j in range(n):
+            if j:
+                h, stores = window_layer(period["win"][j - 1], h, stores,
+                                         p * (n - 1) + j - 1)
+            h, counted = hybrid.moe_block(period["moe"][j], experts[j], p, h,
+                                          valid, cfg)
+            routed = routed + counted
+        return (h, stores, routed, indexed), sel
+
+    zero = jnp.zeros((len(hybrid.COUNTERS),), jnp.int32)
+    (h, stores, routed, indexed), sels = jax.lax.scan(
+        body, (h, stores, zero, indexed),
+        (periods, jnp.arange(cfg.periods)))
+    selection = jax.tree.map(lambda a, rest: jnp.concatenate([a[None], rest]),
+                             first_sel, sels)
+    return h, stores, jnp.concatenate([routed, indexed]), selection
 
 
 def kept_positions(C: int, cfg: LatentMoEConfig) -> int:

@@ -34,6 +34,32 @@ with the rng chain's two words, over the row's slot of the vector the next
 tick takes as `prev`: the row joins that tick `fed`, like a row of the tick
 before. The host reads the unit's own small vector (`split_first`) one
 hand-over later.
+
+A family that DRAFTS (a verify tick that emits one or two tokens a row:
+`models/latent_moe/draft.py`) has a wider vector and another `fed`
+(`packed_drafting`, `DRAFT_ROWS`):
+
+    [S] the LAST token emitted (the next tick's input) | [S * 2] key words |
+    [S] the first token emitted | [S] how many were (1 or 2) |
+    [S] the next tick's pos | [S] its write_pos |
+    [S] the draft the tick verified (-1: none) |
+    [S] the second query's first choice | the family's counters
+
+The first three parts lie where the plain vector's lie, so `first_token`
+writes a prefilled row's token and chain into either. What the host no
+longer knows a tick ahead is how far a row in flight advanced: its `pos` and
+`write_pos` are then the tick's own results too, so a row staged `fed` 2
+takes all four from the vector on the device and the host stages upper
+bounds it grows pages by and learns the truth at the read; a row staged
+`fed` 1 (its first token is a prefill unit's, unread) takes token and key
+from the vector and `pos` / `write_pos` from the buffer, where the host,
+which knows the prompt, put them. The draft is never staged: it rides the
+family's per-slot store beside the pages from tick to tick. The last two
+parts are the tick's RECORD and feed nothing: what the module had drafted
+for this tick and what the second query made of it, whether or not the
+draft was accepted, so that what the module and the second query produced
+can be rated from the run that produced it (the engine puts them on the
+`serve_decode_step` span, `verify_rows`).
 """
 
 from __future__ import annotations
@@ -63,7 +89,9 @@ class Staged(NamedTuple):
     write_pos: np.ndarray
     active: np.ndarray
     top_k: np.ndarray
-    fed: np.ndarray             # 1: token and keys are the previous tick's
+    # 1: token and keys are the previous tick's (a drafting family: its first
+    # token's unit's; 2: token, keys, pos and write_pos are the tick's)
+    fed: np.ndarray
     keys: np.ndarray            # [S, 2] uint32
     temperature: np.ndarray     # [S] float32
     top_p: np.ndarray           # [S] float32
@@ -199,6 +227,64 @@ def packed(step):
         return {"fetch": pack_result(out["token"], out["keys"],
                                      out.get("counters")),
                 "pool": out["pool"], "kv_mask": out["kv_mask"]}
+
+    return jax.jit(paged_decode_step, static_argnames=("cfg",),
+                   donate_argnames=("pool", "kv_mask"))
+
+
+# -- a family whose tick emits one or two tokens a row --------------------------
+
+# int32 parts a slot in a drafting family's fetched vector (module docstring)
+DRAFT_ROWS = 9
+FED_TOKEN, FED_ALL = 1, 2
+
+
+def fetch_rows(drafts: bool) -> int:
+    """Parts of `slots` int32 in front of the counters of a fetched vector."""
+    return DRAFT_ROWS if drafts else 3
+
+
+def unpack_drafting(staged: jnp.ndarray, prev: jnp.ndarray) -> tuple:
+    """`unpack` for a drafting family: a row `fed` at all takes token and key
+    words from `prev`, one fed `FED_ALL` its `pos` and `write_pos` too."""
+    slots = staged.shape[0]
+    part = lambda j: prev[j * slots:(j + 1) * slots]
+    (token, page_table, pos, write_pos, active, keys, temperature, top_k,
+     top_p) = unpack(staged, prev)
+    whole = staged[:, _INTS - 1] == FED_ALL
+    return (token, page_table, jnp.where(whole, part(5), pos),
+            jnp.where(whole, part(6), write_pos), active, keys, temperature,
+            top_k, top_p)
+
+
+def split_drafting(fetched: np.ndarray, slots: int) -> tuple:
+    """On the host: ([S] last tokens, [S, 2] uint32 keys, [S] first tokens,
+    [S] counts, [S] next pos, [S] next write_pos, [S] drafts verified, [S]
+    second queries' first choices, the counters), as views."""
+    part = lambda j: fetched[j * slots:(j + 1) * slots]
+    return (part(0), fetched[slots:3 * slots].view(np.uint32).reshape(slots, 2),
+            *(part(j) for j in range(3, DRAFT_ROWS)),
+            fetched[DRAFT_ROWS * slots:])
+
+
+@functools.cache
+def packed_drafting(step):
+    """`packed` for a drafting family's `step` (its verify tick under the
+    thirteen arguments): the same program between `unpack_drafting` and the
+    wider vector, under the same name."""
+    body = step.__wrapped__
+
+    def paged_decode_step(params, staged, prev, pool, kv_mask, cfg):
+        (token, page_table, pos, write_pos, active, keys, temperature,
+         top_k, top_p) = unpack_drafting(staged, prev)
+        out = body(params, token, pool, page_table, pos, write_pos, kv_mask,
+                   active, keys, temperature, top_k, top_p, cfg)
+        fetch = jnp.concatenate([
+            out["token"],
+            jax.lax.bitcast_convert_type(out["keys"], jnp.int32).reshape(-1),
+            out["tokens"][:, 0], out["count"], out["pos"], out["write_pos"],
+            out["drafted"], out["second"], out["counters"]])
+        return {"fetch": fetch, "pool": out["pool"], "kv_mask": out["kv_mask"]}
 
     return jax.jit(paged_decode_step, static_argnames=("cfg",),
                    donate_argnames=("pool", "kv_mask"))

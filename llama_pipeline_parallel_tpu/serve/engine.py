@@ -39,6 +39,18 @@ length is known a tick ahead and left out. `shutdown()`, an idle boundary
 and a cancellation collect the tick in flight first, so every row-tick the
 device ran is a token a handle received, the overruns apart.
 
+A family that DRAFTS (`models/family.py` `drafts`; the model's configuration
+says so, nothing here does) runs a verify tick that emits one OR TWO tokens a
+row: the row's own and, where the module's draft was right, the one after
+it, the same stream as one-token ticks. The host then does not know a tick
+ahead how far a row in flight advanced: it stages upper bounds (two places a
+tick in flight), grows the row's pages for them, and the program takes the
+row's `pos` and `write_pos` from the tick before on the device, as it takes
+token and key; the host learns them at the read (`_collect_tick`). A row that
+reaches its eos or its budget on the first of two tokens drops the second;
+one whose budget ran out inside the tick in flight overruns once, as an eos
+does, inside the one place past its budget that its reservation covers.
+
 A prefill unit is not waited for either: it is handed to the device, and the
 host reads its result (its counters; a final unit's first token and rng
 chain) only after the NEXT hand-over is enqueued behind it, the next unit of
@@ -381,6 +393,10 @@ class _Running:
     # its first token is its prefill unit's result, which the host has not
     # read yet: a tick it joins meanwhile is fed token and key on the device
     first_unread: bool = False
+    # a drafting family: `pos` and `write_pos` are the row's as of the last
+    # tick READ (a tick in flight may advance them by up to two), and `limit`
+    # the places of its row that its reservation covers
+    limit: int = 0
 
 
 @dataclasses.dataclass
@@ -731,6 +747,14 @@ class ServeEngine:
         # a prefilled row's first token, drawn where the unit's logits lie
         self._first_token = tick_io.first_token(sample_rowwise,
                                                 serve_cfg.max_slots)
+        # a verify tick may write one place past a row's budget (its last
+        # token's own and a draft's behind it): room and pages for two
+        self._drafts = self._family.drafts
+        self._draft_room = 2 if self._drafts else 0
+        self.spec_offered_total = 0
+        self.spec_accepted_total = 0
+        self._spec_at = tuple(self._family.counters.index(name) for name in (
+            "spec_offered", "spec_accepted")) if self._drafts else ()
         # the tick's program: one staged buffer in, one fetched vector out
         self._tick_program = self._family.decode_tick
         # the tick in flight (None at start, after an idle boundary and
@@ -738,7 +762,8 @@ class ServeEngine:
         # tick no row of which is fed from one
         self._in_flight: _Tick | None = None
         self._no_fetch = jnp.zeros(
-            3 * serve_cfg.max_slots + len(self._family.counters), jnp.int32)
+            self._family.fetch_rows * serve_cfg.max_slots
+            + len(self._family.counters), jnp.int32)
         # the prefill unit in flight (None at every step boundary), and the
         # vector the step's tick takes as `prev` once a unit of the step
         # made a row: the tick in flight's with the new rows' slots holding
@@ -759,6 +784,15 @@ class ServeEngine:
         self._tick_count = 0
         self._tick_active = 0
         self._tick_tokens = 0            # rows that decoded, summed over ticks
+        # a drafting family: `_tick_tokens` counts the tokens the ticks MADE
+        # (one or two a row-tick); the row-ticks and the tokens made and not
+        # pushed (an overrun's, a second token behind an eos or the budget)
+        self._tick_row_ticks = 0
+        self._tick_discarded = 0
+        # and the row-ticks' own record, by request in tick order: (tokens
+        # made, the draft verified, the second query's first choice), the
+        # span's `verify_rows`
+        self._tick_verified: dict = {}
         # logical pages of the decoding rows that hold tokens, and that
         # their page-table rows have: the share of a whole-row read that
         # the tick's attention still makes (ops/paged_attention.py)
@@ -854,12 +888,15 @@ class ServeEngine:
         fits the slot capacity; RequestRejected when none can ever."""
         for bucket in self.serve_cfg.prompt_buckets:
             if (bucket >= prompt_len
-                    and bucket + max_new_tokens <= self.serve_cfg.max_len):
+                    and bucket + max_new_tokens + self._draft_room
+                    <= self.serve_cfg.max_len):
                 return bucket
         raise RequestRejected(
             f"prompt of {prompt_len} tokens + {max_new_tokens} new does not "
             f"fit any bucket {self.serve_cfg.prompt_buckets} within "
-            f"max_len {self.serve_cfg.max_len}")
+            f"max_len {self.serve_cfg.max_len}" + (
+                f" (a drafting model keeps {self._draft_room} places past "
+                f"the budget)" if self._drafts else ""))
 
     def submit(self, request: ServeRequest) -> RequestHandle:
         """Enqueue a request; returns its streaming handle. Raises
@@ -875,7 +912,7 @@ class ServeEngine:
             bucket = self.pick_bucket(len(request.input_ids),
                                       request.gen.max_new_tokens)
             demand = self.slots.demand_pages(
-                bucket, request.gen.max_new_tokens)
+                bucket, request.gen.max_new_tokens + self._draft_room)
             if demand > self.slots.num_pages:
                 raise RequestRejected(
                     f"worst-case demand of {demand} pages exceeds the "
@@ -1261,6 +1298,11 @@ class ServeEngine:
                            else self._family.paged_prefill_chunk)
                 c0, c1 = pf.done, pf.done + cost
                 self.slots.ensure_capacity(slot, c1)
+                # a drafting model's module keeps each position with the id
+                # AFTER it: the chunk takes the next chunk's first id
+                after = ({"next_id": jnp.asarray(
+                    pf.ids[0, c1:c1 + 1] if c1 < pf.bucket
+                    else np.full(1, -1, np.int32))} if self._drafts else {})
                 t_call = time.perf_counter()
                 with trace.annotate(trace.PREFILL_ENQUEUE):
                     out = program(
@@ -1269,7 +1311,7 @@ class ServeEngine:
                         jnp.asarray(pf.positions[:, c0:c1]), self.slots.pool,
                         jnp.asarray(self.slots.page_table[slot]),
                         jnp.int32(slot), self.slots.kv_mask, jnp.int32(c0),
-                        self.cfg)
+                        self.cfg, **after)
                 t_called = time.perf_counter()
                 self.slots.pool = out["pool"]
                 self.slots.kv_mask = out["kv_mask"]
@@ -1300,6 +1342,16 @@ class ServeEngine:
                         pf.request.seed, slot, gen.temperature, gen.top_k,
                         gen.top_p)),
                     self._prev(), vector)
+                if self._drafts:
+                    # the row's first draft, from the module at the prompt's
+                    # last position and the token just drawn, on the device
+                    self.slots.pool = self._family.first_draft(
+                        self.params, out["hidden"], self._feed,
+                        self.slots.pool,
+                        jnp.asarray(self.slots.page_table[slot]),
+                        jnp.int32(slot), self.slots.kv_mask,
+                        jnp.int32(pf.positions[0, -1]),
+                        jnp.int32(pf.bucket - 1), self.cfg)
                 # the rope position of the first generated token is the
                 # host's own count (the programs' `next_pos`, never read)
                 row = _Running(
@@ -1307,7 +1359,8 @@ class ServeEngine:
                     key=np.zeros(2, np.uint32),
                     pos=int(pf.positions[0, -1]) + 1, write_pos=pf.bucket,
                     emitted=0, t_admit=pf.t_admit, t_first=0.0,
-                    first_unread=True)
+                    first_unread=True,
+                    limit=pf.bucket + gen.max_new_tokens + self._draft_room - 1)
                 self._occupants[slot] = row
         t_handed = time.perf_counter()
         self._host.phases += (
@@ -1477,21 +1530,26 @@ class ServeEngine:
             staged = tick_io.stage(scfg.max_slots,
                                    self.slots.page_table.shape[1])
             pages_live = steps_visited = joined_fed = 0
+            ahead = 2 if self._drafts else 0   # places a tick in flight may add
             for slot, r in rows:
                 if r.in_flight or r.first_unread:
                     # its token and key are a result the host has not read:
-                    # the tick in flight's, or its prefill unit's
-                    staged.fed[slot] = 1
+                    # the tick in flight's, or its prefill unit's; behind a
+                    # verify tick in flight its position is one too
+                    staged.fed[slot] = (tick_io.FED_ALL
+                                        if self._drafts and r.in_flight
+                                        else tick_io.FED_TOKEN)
                     joined_fed += r.first_unread
                 else:
                     staged.token[slot] = r.token
                     staged.keys[slot] = r.key
-                staged.pos[slot] = r.pos
-                staged.write_pos[slot] = r.write_pos
+                write = r.write_pos + ahead * r.in_flight
+                staged.pos[slot] = r.pos + ahead * r.in_flight
+                staged.write_pos[slot] = write
                 staged.temperature[slot] = r.request.gen.temperature
                 staged.top_k[slot] = r.request.gen.top_k
                 staged.top_p[slot] = r.request.gen.top_p
-                live = r.write_pos // scfg.page_size + 1
+                live = write // scfg.page_size + 1
                 pages_live += live
                 steps_visited += -(-live // self._kv_step_pages)
             branch = int(sampler_branch(staged.temperature, staged.top_k,
@@ -1505,7 +1563,13 @@ class ServeEngine:
                 # submit-time reservation guarantees these allocations
                 # succeed
                 for slot, r in rows:
-                    self.slots.ensure_capacity(slot, r.write_pos + 1)
+                    if self._drafts:
+                        # two places past the furthest the row can stand, as
+                        # far as its reservation goes
+                        self.slots.ensure_capacity(slot, min(
+                            int(staged.write_pos[slot]) + 2, r.limit))
+                    else:
+                        self.slots.ensure_capacity(slot, r.write_pos + 1)
                     # only these rows may write/mark kv: a mid-prefill slot
                     # already owns live pages and mask spans this tick must
                     # not touch
@@ -1530,8 +1594,9 @@ class ServeEngine:
             del staged_d
             self.slots.update_from_step(out)
             for _, r in rows:
-                r.pos += 1
-                r.write_pos += 1
+                if not self._drafts:   # a verify tick's advance is read back
+                    r.pos += 1
+                    r.write_pos += 1
                 r.in_flight += 1
         t_dispatched = time.perf_counter()
         self._host.phases += (
@@ -1577,16 +1642,31 @@ class ServeEngine:
             host.tick_blocked(t_entry, t_block, t_blocked,
                               behind=self._unit is not None)
             with trace.annotate(trace.TICK_FETCH):
-                next_token, new_keys, counters = tick_io.split_result(
-                    np.asarray(tick.fetch), self.serve_cfg.max_slots)
+                fetched = np.asarray(tick.fetch)
+                if self._drafts:
+                    (next_token, new_keys, first_token, count, next_pos,
+                     next_write, drafted, second, counters) = \
+                        tick_io.split_drafting(fetched,
+                                               self.serve_cfg.max_slots)
+                else:
+                    next_token, new_keys, counters = tick_io.split_result(
+                        fetched, self.serve_cfg.max_slots)
                 d2h_copies = 1
         t_fetched = time.perf_counter()
         overrun = 0
+        made = discarded = 0
         with trace.annotate(trace.TICK_EMIT):
             for slot, r in tick.rows:
                 r.in_flight -= 1
+                if self._drafts:
+                    made += int(count[slot])
+                    self._tick_verified.setdefault(
+                        r.request.request_id, []).append(
+                            (int(count[slot]), int(drafted[slot]),
+                             int(second[slot])))
                 if r.finished:
                     overrun += 1
+                    discarded += int(count[slot]) if self._drafts else 0
                     continue
                 if self._reqtrace is not None:
                     # tick-rate but bounded by max_slots dict lookups; tracing
@@ -1595,27 +1675,43 @@ class ServeEngine:
                     b = self._rt.get(r.request.request_id)
                     if b is not None:
                         b.decode_tick(self.steps, len(tick.rows))
-                tok = int(next_token[slot])
-                r.token = tok
                 r.key = new_keys[slot]
-                r.emitted += 1
-                r.handle._push(tok)
                 gen = r.request.gen
-                if (gen.eos_token_id is not None and tok == gen.eos_token_id) \
-                        or r.emitted >= gen.max_new_tokens:
-                    self._finish(slot, r)
+                if self._drafts:
+                    # where the row stands is the tick's own result
+                    r.pos, r.write_pos = (int(next_pos[slot]),
+                                          int(next_write[slot]))
+                    toks = [int(first_token[slot])]
+                    if count[slot] == 2:
+                        toks.append(int(next_token[slot]))
+                else:
+                    toks = [int(next_token[slot])]
+                for i, tok in enumerate(toks):
+                    r.token = tok
+                    r.emitted += 1
+                    r.handle._push(tok)
+                    if (gen.eos_token_id is not None
+                            and tok == gen.eos_token_id) \
+                            or r.emitted >= gen.max_new_tokens:
+                        # an eos or the budget on the first of two tokens
+                        # drops the second
+                        discarded += len(toks) - i - 1
+                        self._finish(slot, r)
+                        break
         t_emitted = time.perf_counter()
         host.phases += ((trace.TICK_FETCH, t_fetched),
                         (trace.TICK_EMIT, t_emitted))
         host.elsewhere += t_emitted - t_entry
         self._note_decode_tick(
             tick, counters.tolist(), overrun, d2h_copies,
-            wait_s=t_fetched - t_entry, emit_s=t_emitted - t_fetched)
+            wait_s=t_fetched - t_entry, emit_s=t_emitted - t_fetched,
+            made=made if self._drafts else len(tick.rows),
+            discarded=discarded)
         host.tail = t_emitted   # folding it into the span is under no event
 
     def _note_decode_tick(self, tick: _Tick, counters: list, overrun: int,
-                          d2h_copies: int, wait_s: float,
-                          emit_s: float) -> None:
+                          d2h_copies: int, wait_s: float, emit_s: float,
+                          made: int, discarded: int) -> None:
         """Fold one collected decode tick into the pending aggregated
         `serve_decode_step` span; flush every `decode_span_every` ticks
         (and at idle boundaries / shutdown). The emitted span's `dur` is
@@ -1633,13 +1729,21 @@ class ServeEngine:
         (counted where the rows are staged), `h2d_copies` / `d2h_copies` (one
         each a tick, where they are made) and the family's counters are
         summed the same way, all of the SAME ticks: every one is folded here,
-        when its tick is collected."""
+        when its tick is collected. Under a drafting family `tokens` is what
+        the ticks MADE (`made`: one or two a row-tick, the device's own
+        counts), `row_ticks` the row-ticks, `tokens_discarded` the tokens
+        made and pushed to no handle (an overrun row's, a second token behind
+        an eos or the end of the budget), and `verify_rows` every row-tick's
+        record by request, in tick order: [tokens made, the draft verified,
+        the second query's first choice], overruns among them."""
         if self._tick_count == 0:
             self._tick_ts = tick.ts
         self._tick_accum += tick.dispatch_s + wait_s
         self._tick_count += 1
         self._tick_active = len(tick.rows)
-        self._tick_tokens += len(tick.rows)
+        self._tick_tokens += made
+        self._tick_row_ticks += len(tick.rows)
+        self._tick_discarded += discarded
         self._tick_overrun += overrun
         self._tick_ahead += tick.ahead
         self._tick_joined_fed += tick.joined_fed
@@ -1654,12 +1758,19 @@ class ServeEngine:
             self._tick_sums[i] += seconds
         for name, n in zip(self._family.counters, counters):
             self._tick_counters[name] += n
+        if self._drafts:
+            self.spec_offered_total += counters[self._spec_at[0]]
+            self.spec_accepted_total += counters[self._spec_at[1]]
         if self._tick_count >= self.serve_cfg.decode_span_every:
             self._flush_decode_span()
 
     def _flush_decode_span(self) -> None:
         if self._tick_count == 0:
             return
+        drafting = ({"row_ticks": self._tick_row_ticks,
+                     "tokens_discarded": self._tick_discarded,
+                     "verify_rows": self._tick_verified}
+                    if self._drafts else {})
         # the host's wall clock on a running capture's clock, one a span
         trace.wallclock_anchor()
         trace.recorder().emit("serve_decode_step", ts=self._tick_ts,
@@ -1678,7 +1789,10 @@ class ServeEngine:
                               h2d_copies=self._tick_copies[0],
                               d2h_copies=self._tick_copies[1],
                               **dict(zip(TICK_SUMS, self._tick_sums)),
-                              **self._tick_counters, **self._host.flush())
+                              **self._tick_counters, **drafting,
+                              **self._host.flush())
+        self._tick_row_ticks = self._tick_discarded = 0
+        self._tick_verified = {}
         self._tick_ts, self._tick_accum = 0.0, 0.0
         self._tick_count, self._tick_active, self._tick_tokens = 0, 0, 0
         self._tick_overrun, self._tick_ahead = 0, 0
@@ -1804,6 +1918,10 @@ class ServeEngine:
         snap["prefill_tokens_total"] = self.prefill_tokens_total
         snap["prefill_state_carries_total"] = \
             self.prefill_state_carries_total
+        if self._drafts:
+            # drafts verified and drafts that were right, over every tick read
+            snap["spec_offered_total"] = self.spec_offered_total
+            snap["spec_accepted_total"] = self.spec_accepted_total
         if self._prefix:
             # cache-off snapshots stay byte-identical to the plain
             # paged engine (the PR 13 pin) — these keys only exist

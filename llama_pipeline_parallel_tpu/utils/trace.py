@@ -209,6 +209,18 @@ FULL_PREFILL_ATTN = "full_prefill_attn"      # a prefill's or a chunk's queries,
 WINDOW_SCOPES = (WINDOW_DECODE_ATTN, WINDOW_PREFILL_ATTN, FULL_DECODE_ATTN,
                  FULL_PREFILL_ATTN)
 
+# a model that drafts with its multi-token-prediction module
+# (models/latent_moe/draft.py): the module's four parts (the layer's own
+# scopes nest under `mtp_layer`), and what decides a draft: the comparison,
+# the second draw, the advance of position and key. A tuple of their own, a
+# seventh vocabulary to merge
+MTP_EMBED = "mtp_embed"              # the next tokens' rows of the trunk's table
+MTP_PROJ = "mtp_proj"                # both norms, the concatenation, `eh_proj`
+MTP_LAYER = "mtp_layer"              # the module's one decoder layer
+MTP_HEAD = "mtp_head"                # its norm, the trunk's head, the draft
+SPEC_ACCEPT = "spec_accept"
+SPEC_SCOPES = (MTP_EMBED, MTP_PROJ, MTP_LAYER, MTP_HEAD, SPEC_ACCEPT)
+
 SCOPES = tuple(v for k, v in sorted(globals().items())
                if k.startswith("SCOPE_"))
 

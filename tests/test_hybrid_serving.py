@@ -217,9 +217,10 @@ def test_the_dense_family_is_handed_the_functions_it_always_called():
 
 
 def test_a_family_states_only_what_depends_on_its_layers():
-    """The fifteen fields, by name: a program that runs the layers, a store
+    """The seventeen fields, by name: a program that runs the layers, a store
     shaped by them, or a fact about them (since PR 36 what a slot's pages
-    are, and why a prefix cannot be shared). What touches only the mask or the
+    are, and why a prefix cannot be shared; since PR 55 whether the model
+    drafts, and the program that makes a row's first draft). What touches only the mask or the
     pool's page axis is `serve/pages.py`'s own, and the manager reaches no
     such thing through the family."""
     import inspect
@@ -231,7 +232,7 @@ def test_a_family_states_only_what_depends_on_its_layers():
         "init_page_pool", "init_recurrent_store", "init_params",
         "serving_weights", "paged_prefill_chunk", "paged_prefill_span",
         "kv_quants", "counters", "table_width", "table_columns",
-        "prefix_cache_why"]
+        "prefix_cache_why", "drafts", "first_draft"]
     own = ("copy_page", "reset_kv_mask_row", "set_kv_mask_row")
     source = inspect.getsource(pages)
     for name in own:

@@ -489,6 +489,69 @@ def _latent_programs(slots, pmax, page, pages):
     return latent_decode, cfg, params, pool
 
 
+@pytest.mark.parametrize("program", ["tick", "chunk", "first_draft"])
+def test_a_drafting_latent_program_compiled_for_the_chip_keeps_its_stores_in_place(
+        one_chip, mosaic, program):
+    """A latent model that drafts, at the published entry widths: the verify
+    tick (two queries a row, the module's layer behind the trunk's), a chunk
+    that keeps the module's entries, and the first draft, compiled for the
+    chip with pages many times the weights: the outputs are the donated
+    stores' buffers, nothing as large as the latent pages is made beside
+    them, and the sparse read is the kernel."""
+    from llama_pipeline_parallel_tpu.models import tick_io
+    from llama_pipeline_parallel_tpu.models.family import family_of
+    from llama_pipeline_parallel_tpu.models.latent_moe import decode as latent_decode
+    from llama_pipeline_parallel_tpu.models.latent_moe import draft
+    from llama_pipeline_parallel_tpu.models.latent_moe import model as latent
+    from llama_pipeline_parallel_tpu.models.latent_moe.config import (
+        LatentMoEConfig,
+    )
+
+    slots, pmax, page = 4, 16, 64
+    cfg = LatentMoEConfig(
+        vocab_size=256, hidden_size=256, num_hidden_layers=3, period=("full",),
+        intermediate_size=256, num_attention_heads=8, q_lora_rank=128,
+        v_head_dim=256, index_n_heads=4, index_topk=128, attention_gate=False,
+        lora_rescale=False, router_experts=16, experts_held=8,
+        num_experts_per_tok=4, moe_intermediate_size=64,
+        shared_intermediate_size=64, num_nextn_predict_layers=1)
+    fam = family_of(cfg)
+    params = jax.eval_shape(
+        lambda: latent.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: {
+        **latent_decode.init_page_pool(cfg, 4096, page),
+        **latent_decode.init_recurrent_store(cfg, slots)})
+    assert pool["latent"].shape[0] == 4 and pool["latent"].shape[-1] == 640
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    mask, prev = i32(slots, pmax * page), i32(
+        fam.fetch_rows * slots + len(fam.counters))
+    if program == "tick":
+        args = _described((params, i32(slots, tick_io.COLUMNS + pmax), prev,
+                           pool, mask), one_chip)
+        compiled = fam.decode_tick.lower(*args, cfg).compile()
+    elif program == "chunk":
+        ids = i32(1, 256)
+        args = _described((params, ids, ids, ids, pool, i32(pmax), i32(),
+                           mask, i32()), one_chip)
+        compiled = latent_decode.paged_prefill_chunk.lower(
+            *args, cfg, next_id=_described(i32(1), one_chip)).compile()
+    else:
+        args = _described(
+            (params, jax.ShapeDtypeStruct((1, 256), jnp.bfloat16), prev, pool,
+             i32(pmax), i32(), mask, i32(), i32()), one_chip)
+        compiled = draft.first_draft.lower(*args, cfg).compile()
+    analysis = compiled.memory_analysis()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    assert nbytes(pool["latent"]) > 5 * nbytes(params)
+    assert analysis.alias_size_in_bytes >= nbytes(pool)
+    assert analysis.temp_size_in_bytes < nbytes(pool["latent"]) // 4, analysis
+    assert "sparse_latent_attn" in compiled.as_text()
+
+
 @pytest.mark.parametrize("program", ["tick", "chunk"])
 def test_a_latent_program_compiled_for_the_chip_keeps_its_stores_in_place(
         one_chip, mosaic, program):

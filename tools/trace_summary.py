@@ -48,6 +48,11 @@ ones the per-layer metrics report:
   (`models/ssm_moe/model.py` COUNTERS), their sums over the ticks and over
   the prefill units apart: `ssm_rows`, `ssm_positions`, `kv_entries_read`,
   `state_carries`, `state_bytes_carried`;
+- where the `serve_decode_step` lines carry a drafting family's counters
+  (`models/latent_moe/draft.py` COUNTERS), the acceptance (`spec_accepted`
+  of `spec_offered`), the tokens a row-tick (`tokens / row_ticks`), the
+  tokens made and discarded, the cache places written and not kept, and the
+  module's positions in ticks and in prefill units;
 - from the same lines, the engine thread's own account (`serve/engine.py`
   `HOST_SUMS` / `HOST_COUNTS`, benchmark/host_stall.py): each phase's share
   of `step_s` and the unaccounted rest, what held the thread outside its two
@@ -191,6 +196,25 @@ def recurrent_counters(spans_path: str):
     return sums or None
 
 
+DRAFTING = ("spec_offered", "spec_accepted", "spec_tokens",
+            "spec_dead_entries", "mtp_positions")
+
+
+def drafting_counters(spans_path: str):
+    """Sums of `DRAFTING`, `tokens`, `row_ticks` and `tokens_discarded` over
+    the `serve_decode_step` lines that carry them, and "unit_positions", the
+    `mtp_positions` of the `serve_prefill` lines; None where no tick line
+    does (a family that does not draft)."""
+    rows = _span_lines(spans_path, "serve_decode_step",
+                       DRAFTING + ("row_ticks", "tokens_discarded"))
+    if not rows:
+        return None
+    keys = DRAFTING + ("tokens", "row_ticks", "tokens_discarded")
+    return {**{k: sum(r[k] for r in rows) for k in keys},
+            "unit_positions": sum(r["mtp_positions"] for r in _span_lines(
+                spans_path, "serve_prefill", ("mtp_positions",)))}
+
+
 def host_thread(spans_path: str, trace: dict | None = None):
     """The engine thread's account over the file's `serve_decode_step` lines
     that carry it, as the benchmark's readers compute it: {"account",
@@ -325,6 +349,20 @@ def main(argv: list[str] | None = None) -> None:
               f"\n  units run {units['units']} / skipped {units['skipped']} "
               f"({100.0 * units['skipped'] / both:.2f}% of both were chunks "
               f"of nothing but pads)")
+    drafted = drafting_counters(spans_path) if spans_path else None
+    if drafted is not None:
+        print(f"\n== drafting with the multi-token-prediction module ==\n"
+              f"  spec_accepted {drafted['spec_accepted']} of "
+              f"{drafted['spec_offered']} drafts offered "
+              f"({100.0 * drafted['spec_accepted'] / max(drafted['spec_offered'], 1):.4f}%)"
+              f"\n  tokens {drafted['tokens']} over {drafted['row_ticks']} "
+              f"row-ticks "
+              f"({drafted['tokens'] / max(drafted['row_ticks'], 1):.5f} a "
+              f"row-tick), {drafted['tokens_discarded']} discarded"
+              f"\n  spec_dead_entries {drafted['spec_dead_entries']} cache "
+              f"places written and not kept"
+              f"\n  mtp_positions {drafted['mtp_positions']} in ticks, "
+              f"{drafted['unit_positions']} in prefill units")
     recurrent = recurrent_counters(spans_path) if spans_path else None
     if recurrent is not None:
         print("\n== the state-space family's counters, summed over layers ==")
